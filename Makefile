@@ -4,9 +4,10 @@
 
 PYTEST := env JAX_PLATFORMS=cpu python -m pytest
 
-.PHONY: tier1 tier1-budget faults chaos tpu perf-smoke kvcache obs overload lint lint-invariants mesh-serve fleet elastic bench-compare check kernels
+.PHONY: tier1 tier1-budget faults chaos tpu chip-smoke perf-smoke kvcache obs overload lint lint-invariants mesh-serve fleet elastic bench-compare check kernels
 
-# The gating suite: everything not marked slow, under the 870 s budget.
+# The gating suite: everything not marked slow (the driver runs it with
+# six xdist workers: add `-p xdist -n 6 --dist loadfile`).
 tier1:
 	$(PYTEST) tests/ -q -m 'not slow' --continue-on-collection-errors
 
@@ -163,8 +164,14 @@ bench-compare:
 lint: lint-invariants
 
 # On-chip kernel regressions (run on a TPU host; self-skip elsewhere).
+# ONE process: a chip belongs to one process at a time, so no xdist and
+# no child that needs the chip.  chip-smoke's parent stays off jax and
+# runs its children strictly one after another.
 tpu:
-	python -m pytest tests/ -q -m tpu
+	env JAX_PLATFORMS=tpu python -m pytest tests/test_tpu_compiled.py tests/test_kernels.py -q -m tpu -p no:xdist
+
+chip-smoke:
+	python chip_smoke.py
 
 # Kernel-selection layer (ops/kernels.py): the CPU-runnable parity
 # suite (splash-mha prefill + stock paged-attention decode in Pallas

@@ -68,10 +68,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..config import LLaMAConfig
 from ..ops.attention import attention_bias, dropout as _dropout, sdpa, sdpa_cached
-from ..ops.flash_attention import flash_attention, flash_attention_quantized
+from ..ops.flash_attention import flash_attention, flash_attention_sharded
 from ..ops.norm import rms_norm
 from ..ops.quant import QuantizedTensor as _QuantizedTensor
 from ..ops.quant import matmul as _quant_matmul
@@ -301,6 +302,26 @@ def _constrain_pool_plane(plane: jnp.ndarray) -> jnp.ndarray:
     return _constrain_heads(plane, 1)
 
 
+def _pin_pool_layout(plane: jnp.ndarray) -> jnp.ndarray:
+    """Pin a written KV plane to the default (row-major) device layout.
+
+    Inside the chunk programs the pool is a ``lax.scan`` carry, whose
+    layout the compiler is free to choose: XLA:TPU (jaxlib 0.9) picks the
+    one the slab writes like — KVH next to d — for the whole loop, and
+    since the Pallas paged-attention kernel's operand must be row-major
+    it then copies BOTH full planes back on every decode iteration
+    (compiled for a v5e at [16, 8, 64, 128, 128] bf16: six pool-sized
+    copies in ``_paged_decode_chunk`` and a whole pool of temp memory;
+    none with the pin — tests/test_tpu_compiled.py
+    ``*_no_full_pool_copies_compiled``).  [NB, BLK] position planes are
+    small and stay free."""
+    if plane.ndim < 4:
+        return plane
+    return with_layout_constraint(
+        plane, Layout(major_to_minor=tuple(range(plane.ndim)))
+    )
+
+
 def paged_pool_write(
     plane: jnp.ndarray,
     upd: jnp.ndarray,
@@ -382,7 +403,7 @@ def paged_pool_write(
             plane = _constrain_pool_plane(
                 lax.dynamic_update_slice(plane, u, start)
             )
-    return plane
+    return _pin_pool_layout(plane)
 
 
 def lm_head_logits(
@@ -797,9 +818,9 @@ def _block(
         cache_v_scale = lax.dynamic_update_slice(
             cache_v_scale, vs, (0, cache_index, 0)
         )
-        attn = flash_attention_quantized(
-            q, cache_k, cache_v, cache_k_scale, cache_v_scale,
-            positions, slot_pos,
+        attn = flash_attention_sharded(
+            q, cache_k, cache_v, positions, slot_pos,
+            k_scale=cache_k_scale, v_scale=cache_v_scale,
         )
     else:
         if cache_k is not None:
@@ -865,7 +886,9 @@ def _block(
                     ),
                 )
             else:
-                attn = flash_attention(q, kk, vv, positions, slot_pos)
+                attn = flash_attention_sharded(
+                    q, kk, vv, positions, slot_pos
+                )
         else:
             attn = sdpa(
                 q, kk, vv, bias, softmax_dtype=softmax_dtype,

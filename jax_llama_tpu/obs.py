@@ -37,9 +37,11 @@ questions (and ROADMAP item 5's online chunk controller) need:
     windows turn measured dispatch wall time into live roofline
     utilization and a wall-vs-device-estimate host-overhead ratio —
     the ~20-26x device-vs-wall gap BENCH_r05 measured offline, now a
-    scrapeable gauge.  Peaks default to the v5e single-chip numbers
-    bench.py rooflines against (197 bf16 TFLOPs, 819 GB/s HBM);
-    run.py ``--peak-tflops`` / ``--peak-hbm-gbps`` repin them.
+    scrapeable gauge.  Peaks come from :data:`DEVICE_PEAKS`, looked up
+    by run.py from the attached device's ``device_kind`` (or named
+    with ``--peak-tflops`` / ``--peak-hbm-gbps``); with no peak — the
+    ctor default, and any device the table does not list — the
+    utilization gauges are off.
   * **Jit-cache observability**.  A ``jax.monitoring`` listener turns
     every backend compile into a ``compile_ms`` observation, a span in
     the trace (its own ``jit compiles`` track), and a per-program
@@ -130,12 +132,16 @@ DISPATCH_KINDS = frozenset({
     "decode:stock-paged", "insert:splash",
 })
 
-# Default hardware peaks for the utilization gauges: the public TPU
-# v5e single-chip numbers bench.py's rooflines use (BENCH_r05's
-# denominators).  run.py --peak-tflops / --peak-hbm-gbps repin them
-# for other chips; 0 disables the corresponding gauge.
-DEFAULT_PEAK_FLOPS = 197e12        # bf16 MXU peak (FLOP/s)
-DEFAULT_PEAK_BYTES_PER_S = 819e9   # HBM bandwidth (B/s)
+# Hardware peaks for the utilization gauges, keyed by
+# ``jax.devices()[0].device_kind``: (bf16 FLOP/s, HBM bytes/s) of ONE
+# chip.  Source: Google Cloud documentation, "TPU v5e" system
+# architecture page (197 TFLOP/s bf16, 819 GB/s HBM per chip; JAX calls
+# the device "TPU v5 lite").  A kind that is not listed gets NO
+# utilization gauges — never another device's numbers; run.py
+# --peak-tflops / --peak-hbm-gbps name a peak explicitly.
+DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
+    "TPU v5 lite": (197e12, 819e9),
+}
 
 # ---------------------------------------------------------------------------
 # Histograms (Prometheus cumulative buckets)
@@ -879,8 +885,8 @@ class Observability:
         max_timelines: int = 1024,
         max_events: int = 256,
         slo_window: int = 256,
-        peak_flops: float = DEFAULT_PEAK_FLOPS,
-        peak_bytes_per_s: float = DEFAULT_PEAK_BYTES_PER_S,
+        peak_flops: float = 0.0,
+        peak_bytes_per_s: float = 0.0,
         util_window: int = 64,
         decision_ring: int = 512,
         max_snapshots: int = 128,

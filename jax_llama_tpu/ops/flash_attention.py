@@ -50,12 +50,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; accept
-# either so the kernels run across the version skew (same fields).
-_CompilerParams = getattr(
-    pltpu, "CompilerParams", getattr(pltpu, "TPUCompilerParams", None)
-)
-
 # Finite stand-in for -inf: fully-masked tiles then accumulate a bogus-but-
 # finite (l, acc) that the online-softmax rescale zeroes out the moment a
 # real score arrives (exp(MASK - real) == 0), and rows that stay fully
@@ -685,6 +679,71 @@ def flash_attention_quantized(
     )
 
 
+def flash_attention_sharded(
+    q: jnp.ndarray,        # [B, T, H, d]
+    k: jnp.ndarray,        # [B, S, KVH, d] (int8 with scales)
+    v: jnp.ndarray,
+    q_pos: jnp.ndarray,    # [B, T]
+    kv_pos: jnp.ndarray,   # [B, S]
+    k_scale: Optional[jnp.ndarray] = None,   # [B, S, KVH] fp32 (int8 KV)
+    v_scale: Optional[jnp.ndarray] = None,
+) -> jnp.ndarray:
+    """Mesh-aware entry point for the inference (no-dropout) flash
+    kernels — the prefill path of ``models.forward``.
+
+    A Mosaic kernel is not partitioned by GSPMD ("Mosaic kernels cannot
+    be automatically partitioned" — what the TPU compiler answers for a
+    bare ``flash_attention`` under ``--tensor 4`` / ``--serve-mesh 1,4``;
+    interpret mode on the CPU is plain HLO and never showed it), so under
+    an active mesh the kernel runs per-shard inside ``shard_map``: heads
+    over "tensor" (contiguous H chunks == contiguous KVH chunks under
+    the h = kvh*G + g layout), rows over the batch axes when they divide
+    — the same placement as the paged and splash kernels.  No
+    collectives: every (row, head) is independent and the caller's
+    o-projection all-reduce recombines heads.  Meshes the placement does
+    not cover (seq/stage axes, heads not divisible) call the kernel
+    directly, as before.
+    """
+    from ..parallel.mesh import current_mesh
+
+    def call(q, k, v, q_pos, kv_pos, *scales):
+        if scales:
+            return flash_attention_quantized(
+                q, k, v, scales[0], scales[1], q_pos, kv_pos
+            )
+        return flash_attention(q, k, v, q_pos, kv_pos)
+
+    scales = () if k_scale is None else (k_scale, v_scale)
+    mesh = current_mesh()
+    if (
+        mesh is None
+        or mesh.shape.get("seq", 1) > 1
+        or mesh.shape.get("stage", 1) > 1
+    ):
+        return call(q, k, v, q_pos, kv_pos, *scales)
+    from jax.sharding import PartitionSpec as P
+
+    tp = mesh.shape.get("tensor", 1)
+    heads_ok = tp > 1 and q.shape[2] % tp == 0 and k.shape[2] % tp == 0
+    row_axes = tuple(
+        a for a in ("data", "fsdp") if mesh.shape.get(a, 1) > 1
+    )
+    n_rows = int(np.prod([mesh.shape[a] for a in row_axes]))
+    rows = row_axes if row_axes and q.shape[0] % n_rows == 0 else None
+    if not heads_ok and rows is None:
+        return call(q, k, v, q_pos, kv_pos, *scales)
+    tens = "tensor" if heads_ok else None
+    head4 = P(rows, None, tens, None)
+    pos2 = P(rows, None)
+    fn = jax.shard_map(
+        call, mesh=mesh,
+        in_specs=(head4, head4, head4, pos2, pos2)
+        + (P(rows, None, tens),) * len(scales),
+        out_specs=head4, check_vma=False,
+    )
+    return fn(q, k, v, q_pos, kv_pos, *scales)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
 def _flash(q, k, v, q_pos, kv_pos, seed, block_q, block_k, interpret,
            dropout_rate=0.0):
@@ -886,7 +945,7 @@ def _flash_forward(
         # carries state through scratch ("arbitrary").  Without this hint
         # Mosaic treats the whole grid as sequential and cannot pipeline
         # block DMA against compute — measured ~4x slower at 16k context.
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
             # The default 16 MiB scoped-vmem budget blocks the larger
             # tiles (s lives at [block_q, block_k] fp32); v5e VMEM is
@@ -1256,7 +1315,7 @@ def _flash_backward(
                 scratch_shapes=scratch_shapes,
             ),
             out_shape=out_shape,
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=(
                     "parallel", "parallel", "parallel", "arbitrary"
                 ),

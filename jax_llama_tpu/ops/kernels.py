@@ -54,7 +54,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _CompilerParams, _resolve_interpret
+from .flash_attention import _resolve_interpret
 
 # ---------------------------------------------------------------------------
 # Selection registry
@@ -322,9 +322,7 @@ def splash_prefill_attention(
                     interpret=interpret,
                 )
 
-            from ..parallel.mesh import shard_map_compat
-
-            fn = shard_map_compat(
+            fn = jax.shard_map(
                 body, mesh=mesh, in_specs=(spec, spec, spec),
                 out_specs=spec, check_vma=False,
             )
@@ -409,9 +407,9 @@ def _stock_launch(
     grid = (1, B, 1)  # (num_cores, batch, kv heads) — one head per call
     in_specs = [
         q_block_spec,
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
         None,
-        pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
         None,
     ]
     scratch_shapes = (
@@ -423,7 +421,8 @@ def _stock_launch(
             (2, pages_per_compute_block, page_size, d), v_pages.dtype
         ),
         None,
-        pltpu.SemaphoreType.DMA,
+        pltpu.SemaphoreType.DMA((2,)),  # k_sems
+        pltpu.SemaphoreType.DMA((2,)),  # v_sems
     )
     out, m, l = pl.pallas_call(
         functools.partial(
@@ -442,7 +441,7 @@ def _stock_launch(
             grid=grid,
             scratch_shapes=scratch_shapes,
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
         ),
         out_shape=[
@@ -455,7 +454,7 @@ def _stock_launch(
         lengths,
         page_indices.reshape(-1),
         jnp.zeros((1,), jnp.int32),  # buffer index
-        jnp.zeros((1,), jnp.int32),  # step
+        jnp.ones((1,), jnp.int32),  # init flag
         launch_q.astype(q_dtype),
         k_pages,
         None,
@@ -653,9 +652,7 @@ def stock_paged_decode_attention(
                     layer, interpret=interpret,
                 )
 
-            from ..parallel.mesh import shard_map_compat
-
-            fn = shard_map_compat(
+            fn = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(
                     head4, head4, head4, pooled, pooled,

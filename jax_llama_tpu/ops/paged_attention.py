@@ -62,7 +62,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import (
     MASK_VALUE,
-    _CompilerParams,
     _LANES,
     _SUBLANES,
     _resolve_interpret,
@@ -398,7 +397,7 @@ def paged_pool_attention(
             jax.ShapeDtypeStruct((B, KVH, TG8, d), jnp.float32),
             jax.ShapeDtypeStruct((B, KVH, TG8, _LANES), jnp.float32),
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -501,9 +500,7 @@ def paged_decode_attention(
                     q_pos, k_scale, v_scale, layer, interpret,
                 )
 
-            from ..parallel.mesh import shard_map_compat
-
-            fn = shard_map_compat(
+            fn = jax.shard_map(
                 body, mesh=mesh, in_specs=tuple(in_specs),
                 out_specs=head4, check_vma=False,
             )

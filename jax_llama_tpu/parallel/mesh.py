@@ -45,36 +45,6 @@ LOGICAL_RULES = {
 _local = threading.local()
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=None,
-                     axis_names=None):
-    """``jax.shard_map`` across the API rename: newer jax exposes it
-    top-level with ``check_vma``/``axis_names``; older jax has
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep`` and the
-    complementary ``auto`` axis set.  Same manual-sharding semantics —
-    this wrapper only translates the spelling, so the parallel code is
-    written once against the current API and still runs on the older
-    runtime this image bakes in."""
-    if hasattr(jax, "shard_map"):
-        kwargs = {}
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kwargs = {}
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    if axis_names is not None:
-        kwargs["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
-
 def make_mesh(
     data: int = 1,
     stage: int = 1,

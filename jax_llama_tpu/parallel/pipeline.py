@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .mesh import shard_map_compat
 
 StageFn = Callable[
     [Any, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray], jnp.ndarray
@@ -130,7 +129,7 @@ def pipeline_blocks(
                 )
         return outs
 
-    out = shard_map_compat(
+    out = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(axis_name), P(), P(), P()),

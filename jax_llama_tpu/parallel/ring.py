@@ -51,7 +51,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import attention_bias, repeat_kv, sdpa
 from ..ops.flash_attention import MASK_VALUE, _mix32, _normalize_seed
-from .mesh import current_mesh, shard_map_compat
+from .mesh import current_mesh
 
 BATCH_AXES = ("data", "fsdp")
 
@@ -348,7 +348,7 @@ def ring_sdpa(
     # (device-invariant) accumulators and becomes device-varying after the
     # first ppermute, which the varying-manual-axes checker rejects even
     # though the program is correct.
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec4, spec4, spec4, spec2, spec2, P(None)),
@@ -502,7 +502,7 @@ def ring_decode(
     head4 = P(BATCH_AXES, None, "tensor", None)
     cache4 = P(BATCH_AXES, axis_name, "tensor", None)
     scale3 = P(BATCH_AXES, axis_name, "tensor")
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(
             _ring_decode_body, axis_name=axis_name, scale=scale,
             softmax_dtype=softmax_dtype, quantized=quantized,

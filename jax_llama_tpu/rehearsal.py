@@ -317,7 +317,9 @@ def main() -> None:
     ap.add_argument("--max-gen-len", type=int, default=32)
     ap.add_argument("--parity-atol", type=float, default=1e-3)
     args = ap.parse_args()
+    from .utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.synthetic:
         rehearse_synthetic()
     if args.shapes_8b:

@@ -323,16 +323,19 @@ def main() -> None:
                     help="idle KV blocks proactively demoted to the "
                          "host tier on entering brownout-1 and deeper "
                          "(no-op without --host-kv-blocks)")
-    ap.add_argument("--peak-tflops", type=float, default=197.0,
+    ap.add_argument("--peak-tflops", type=float, default=None,
                     help="accelerator MXU peak in TFLOP/s for the "
                          "/metrics llm_mxu_utilization and "
                          "llm_host_overhead_ratio gauges (default: "
-                         "the v5e bf16 peak bench.py rooflines "
-                         "against); 0 disables the FLOPs-side gauges")
-    ap.add_argument("--peak-hbm-gbps", type=float, default=819.0,
+                         "looked up from obs.DEVICE_PEAKS by the "
+                         "attached device's kind; a device the table "
+                         "does not list gets no utilization gauges); "
+                         "0 disables the FLOPs-side gauges")
+    ap.add_argument("--peak-hbm-gbps", type=float, default=None,
                     help="accelerator HBM bandwidth in GB/s for the "
                          "/metrics llm_hbm_utilization gauge "
-                         "(default: the v5e peak); 0 disables it")
+                         "(default: looked up like --peak-tflops); "
+                         "0 disables it")
     ap.add_argument("--no-cost-models", action="store_true",
                     help="skip the per-program static cost models "
                          "(jit lowering cost_analysis at the live "
@@ -400,9 +403,38 @@ def main() -> None:
     from .convert.checkpoint import load_checkpoint
     from .generation import LLaMA
     from .parallel.mesh import make_mesh
+    from .utils.compile_cache import enable_compile_cache
     from .utils.profiling import DecodeStats, Timer
 
+    if args.replicas > 1 and args.serve_mesh is not None:
+        # Replicas on their own device slices compile cold.  On jaxlib
+        # 0.9.0 / libtpu 0.0.34 an executable compiled for a slice that
+        # does not start at device 0 and then READ BACK from the
+        # persistent cache halts the core (PR 21, four-chip host: a
+        # two-device matmul on devices [2, 3] — cold ok, warm "Core
+        # halted unexpectedly ... enhanced-barrier"; devices [0, 1] are
+        # fine either way).  Off here even when the environment placed
+        # a cache: a slower start beats a dead replica.
+        jax.config.update("jax_enable_compilation_cache", False)
+        cache_dir = None
+        log.log(
+            "compile_cache_off",
+            "replicas own device slices; executables for a slice past "
+            "device 0 do not survive the persistent cache on this jaxlib",
+        )
+    else:
+        cache_dir = enable_compile_cache()
     n = len(jax.devices())
+    # The device this process actually holds, stated once at start-up:
+    # every non-TPU backend interprets the Pallas kernels
+    # (ops/flash_attention._resolve_interpret), so an operator (and
+    # chip_smoke.py) must be able to tell which one is serving.
+    dev0 = jax.devices()[0]
+    log.log(
+        "devices", platform=dev0.platform, device_kind=dev0.device_kind,
+        count=n, compile_cache=cache_dir,
+    )
+    _resolve_peaks(args, dev0.device_kind, log)
     tensor = args.tensor or n // (args.data * args.fsdp)
     # Use exactly the devices the mesh needs — a smaller-than-host mesh
     # (e.g. --tensor 2 on an 8-device host) is valid for smoke runs.
@@ -556,6 +588,65 @@ def main() -> None:
     print(f"\n[{stats.summary()}] (incl. compile)")
 
 
+def _resolve_peaks(args, device_kind: str, log) -> None:
+    """Fill unset --peak-tflops / --peak-hbm-gbps from the peaks table
+    (obs.DEVICE_PEAKS) for the attached device.  A device the table
+    does not know gets 0 — utilization gauges off, with a log line —
+    never another device's peaks."""
+    from .obs import DEVICE_PEAKS
+
+    if args.peak_tflops is not None and args.peak_hbm_gbps is not None:
+        return
+    peaks = DEVICE_PEAKS.get(device_kind)
+    if peaks is None:
+        log.log(
+            "utilization_gauges_off",
+            "no peaks known for this device; name them with "
+            "--peak-tflops / --peak-hbm-gbps",
+            device_kind=device_kind,
+        )
+        peaks = (0.0, 0.0)
+    if args.peak_tflops is None:
+        args.peak_tflops = peaks[0] / 1e12
+    if args.peak_hbm_gbps is None:
+        args.peak_hbm_gbps = peaks[1] / 1e9
+
+
+def _param_bytes_by_device(params) -> dict:
+    """{device id: weight bytes whose shards live there}."""
+    import jax
+
+    held: dict = {}
+    for leaf in jax.tree_util.tree_leaves(params):
+        for sh in leaf.addressable_shards:
+            held[sh.device.id] = held.get(sh.device.id, 0) + sh.data.nbytes
+    return held
+
+
+def _log_device_memory(logger, when: str, params=None) -> None:
+    """One ``device_memory`` log line: per local device, the bytes the
+    backend reports in use / at peak / as its limit, and (given the
+    param tree) the weight bytes placed there.  Only the process that
+    holds the devices can read these, so the server states them itself
+    (once serving, once drained); chip_smoke.py reads placement and the
+    peak from here.  Backends that report no memory stats (the CPU) log
+    ``null`` for them."""
+    import jax
+
+    held = None if params is None else _param_bytes_by_device(params)
+    stats = []
+    for d in jax.local_devices():
+        ms = d.memory_stats() or {}
+        stats.append({
+            "id": d.id,
+            "bytes_in_use": ms.get("bytes_in_use"),
+            "peak_bytes_in_use": ms.get("peak_bytes_in_use"),
+            "bytes_limit": ms.get("bytes_limit"),
+            "param_bytes": None if held is None else held.get(d.id, 0),
+        })
+    logger.log("device_memory", when=when, devices=stats)
+
+
 def _chat_format_for(tokenizer):
     """The ONE 'is this a llama-3 chat tokenizer' heuristic: both the
     single-server /chat endpoint and the router's cache-aware /chat
@@ -653,8 +744,8 @@ def _serve_http(params, config, tokenizer, mesh, args, _test_hook=None,
     obs = Observability(
         slo_ttft_ms=getattr(args, "slo_ttft_ms", 0.0) or None,
         slo_itl_ms=getattr(args, "slo_itl_ms", 0.0) or None,
-        peak_flops=getattr(args, "peak_tflops", 197.0) * 1e12,
-        peak_bytes_per_s=getattr(args, "peak_hbm_gbps", 819.0) * 1e9,
+        peak_flops=(getattr(args, "peak_tflops", None) or 0.0) * 1e12,
+        peak_bytes_per_s=(getattr(args, "peak_hbm_gbps", None) or 0.0) * 1e9,
     )
     cb = ContinuousBatcher(
         params, config, n_slots=args.slots,
@@ -731,6 +822,7 @@ def _serve_http(params, config, tokenizer, mesh, args, _test_hook=None,
             if _test_hook is not None:
                 _test_hook(srv)
                 return
+            _log_device_memory(logger, "serving", params)
             # Drain-on-signal: SIGTERM (orchestrator shutdown) and the
             # first Ctrl-C flip the server into drain mode — in-flight
             # requests finish, new POSTs 503 with Retry-After, bounded
@@ -764,10 +856,12 @@ def _serve_http(params, config, tokenizer, mesh, args, _test_hook=None,
                     "in-flight requests finish, new requests 503",
                     timeout_s=drain_timeout_s,
                 )
-                if srv.wait_drained(drain_timeout_s + 10):
-                    logger.log("drained", "shutting down")
-                else:
-                    logger.log("drain_timeout", "shutting down")
+                drained = srv.wait_drained(drain_timeout_s + 10)
+                _log_device_memory(logger, "drained")
+                logger.log(
+                    "drained" if drained else "drain_timeout",
+                    "shutting down",
+                )
             except KeyboardInterrupt:
                 srv.begin_drain(timeout_s=0.0)
                 logger.log("hard_shutdown", "second interrupt")
@@ -876,7 +970,14 @@ def _serve_router(params, config, tokenizer, mesh, args,
         _geom_cache[i] = (m, p, d)
         return m, p, d
 
-    if spec is not None and len(devs) < args.replicas * per:
+    if spec is None:
+        logger.log(
+            "serve_mesh_shared",
+            "no --serve-mesh: every replica time-shares the "
+            f"{mesh.devices.size}-device --data/--fsdp/--tensor mesh "
+            "(give --serve-mesh DP,TP for a device slice per replica)",
+        )
+    elif len(devs) < args.replicas * per:
         logger.log(
             "serve_mesh_shared",
             f"host has {len(devs)} devices < replicas x mesh "
@@ -889,12 +990,19 @@ def _serve_router(params, config, tokenizer, mesh, args,
         a scale-up gets the next index's geometry and a distinct
         sampling seed, everything else identical to the seed fleet."""
         m, p, d = _geometry(i)
+        # Where this replica's weights actually live — what a fleet
+        # operator (and chip_smoke.py's four-chip run) checks for
+        # disjoint device slices.
+        logger.log(
+            "replica_placed", replica=i, mesh=str(dict(m.shape)),
+            param_devices=sorted(_param_bytes_by_device(p)),
+        )
         obs = Observability(
             slo_ttft_ms=getattr(args, "slo_ttft_ms", 0.0) or None,
             slo_itl_ms=getattr(args, "slo_itl_ms", 0.0) or None,
-            peak_flops=getattr(args, "peak_tflops", 197.0) * 1e12,
+            peak_flops=(getattr(args, "peak_tflops", None) or 0.0) * 1e12,
             peak_bytes_per_s=(
-                getattr(args, "peak_hbm_gbps", 819.0) * 1e9
+                (getattr(args, "peak_hbm_gbps", None) or 0.0) * 1e9
             ),
         )
         cb = ContinuousBatcher(
@@ -986,6 +1094,7 @@ def _serve_router(params, config, tokenizer, mesh, args,
             if _test_hook is not None:
                 _test_hook(router, servers)
                 return
+            _log_device_memory(logger, "serving")
             state = {"signaled": False}
 
             def _on_signal(signum, frame):
@@ -1008,6 +1117,7 @@ def _serve_router(params, config, tokenizer, mesh, args,
                     srv.begin_drain()
                 for srv in servers:
                     srv.wait_drained(drain_s + 10)
+                _log_device_memory(logger, "drained")
                 logger.log("drained", "shutting down")
             except KeyboardInterrupt:
                 for srv in servers:

@@ -16,6 +16,7 @@ Two halves:
     lint invocation; tier-1 keeps the fast static gates.
 """
 
+import os
 import subprocess
 import sys
 
@@ -655,11 +656,15 @@ class TestCLI:
 
     @pytest.mark.slow
     def test_module_entrypoint_subprocess(self):
-        # the acceptance-criteria invocation, end to end
+        # the acceptance-criteria invocation, end to end.  CPU ONLY: a
+        # child that imports jax from a process that already holds it is
+        # harmless here and hangs or fails on a chip (one process per
+        # chip) — never copy this into anything that runs there.
         proc = subprocess.run(
             [sys.executable, "-m", "jax_llama_tpu.analysis",
              "--no-trace"],
             capture_output=True, text=True, timeout=300,
+            env=dict(os.environ, JAX_PLATFORMS="cpu"),
         )
         assert proc.returncode == 0, proc.stdout + proc.stderr
 

@@ -1,0 +1,139 @@
+"""Rehearsal 3 as a test: the main path's kernels compile for a v5e.
+
+The TPU compiler is installed on CPU-only machines and compiles for a
+chip that is *described*, not attached (on-chip-measurement guide,
+section 2).  Each case lowers one Pallas kernel at Llama-3-8B head
+geometry (32 query / 8 KV heads, head_dim 128, bf16; the geometry
+chip_smoke.py serves) with ``interpret=False`` onto a described
+``v5e:2x2`` device and asserts the Mosaic custom call is in the compiled
+program.  Interpret-mode tests cannot see what this sees: unaligned
+slices, VMEM limits, lowering errors.  Nothing executes — a compile that
+passes is not a chip run.
+
+The topology is described inside a module-scoped fixture, never at
+import, in a skipif or in parametrize arguments: only one process may
+load libtpu, and every xdist worker imports this file.  All such tests
+live in THIS file (a second file could land on another worker, whose
+fixture would then skip), and compile in the test's own process.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+H, KVH, D = 32, 8, 128          # llama3-8b heads
+B, BLK, MB, L = 8, 128, 16, 2   # decode rows, block, blocks/row, pool layers
+NB = B * MB
+S = 2048                        # prefill length
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache off around these.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    """ShapeDtypeStruct factory placing every operand on one described chip."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return make
+
+
+def _assert_mosaic(lowered):
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def _flash_operands(sds):
+    return (
+        sds((1, S, H, D), jnp.bfloat16), sds((1, S, KVH, D), jnp.bfloat16),
+        sds((1, S, KVH, D), jnp.bfloat16), sds((1, S), jnp.int32),
+        sds((1, S), jnp.int32),
+    )
+
+
+def test_flash_forward_compiles_for_v5e(sds):
+    from jax_llama_tpu.ops.flash_attention import flash_attention
+
+    _assert_mosaic(flash_attention.lower(*_flash_operands(sds), interpret=False))
+
+
+def test_flash_vjp_compiles_for_v5e(sds):
+    from jax_llama_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v, q_pos, kv_pos):
+        out = flash_attention(q, k, v, q_pos, kv_pos, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    _assert_mosaic(grad.lower(*_flash_operands(sds)))
+
+
+@pytest.mark.parametrize(
+    "quantized,t_tokens",
+    [(False, 1), (True, 1), (False, 5)],
+    ids=["bf16", "int8", "bf16-verify-t5"],
+)
+def test_paged_decode_compiles_for_v5e(sds, quantized, t_tokens):
+    """The custom paged kernel over a layer-indexed pool: bf16, int8
+    (in-kernel scale folding), and the multi-token speculative-verify
+    sweep (t_tokens > 1)."""
+    from jax_llama_tpu.ops.paged_attention import paged_pool_attention
+
+    G = H // KVH
+    pool_dtype = jnp.int8 if quantized else jnp.bfloat16
+    pool = sds((L, KVH, NB, BLK, D), pool_dtype)
+    scale = sds((L, KVH, NB, BLK), jnp.float32) if quantized else None
+    lowered = paged_pool_attention.lower(
+        sds((B, KVH, t_tokens * G, D), jnp.bfloat16), pool, pool,
+        sds((NB, BLK), jnp.int32), sds((B, MB), jnp.int32),
+        sds((B,), jnp.int32), k_scale=scale, v_scale=scale,
+        t_tokens=t_tokens, layer=sds((), jnp.int32), interpret=False,
+    )
+    _assert_mosaic(lowered)
+
+
+def test_splash_prefill_compiles_for_v5e(sds):
+    from jax_llama_tpu.ops.kernels import splash_prefill
+
+    q, k, v, _, _ = _flash_operands(sds)
+    _assert_mosaic(
+        splash_prefill.lower(q, k, v, chunk_offset=0, interpret=False)
+    )
+
+
+def test_stock_paged_decode_compiles_for_v5e(sds):
+    from jax_llama_tpu.ops.kernels import stock_paged_decode
+
+    pool = sds((L, KVH, NB, BLK, D), jnp.bfloat16)
+    new = sds((B, 1, KVH, D), jnp.bfloat16)
+    lowered = stock_paged_decode.lower(
+        sds((B, 1, H, D), jnp.bfloat16), new, new, pool, pool,
+        sds((B, MB), jnp.int32), sds((B,), jnp.int32),
+        sds((), jnp.int32), interpret=False,
+    )
+    _assert_mosaic(lowered)

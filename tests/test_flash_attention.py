@@ -187,6 +187,55 @@ def test_flash_quantized_matches_dequantized_reference():
 
 
 @pytest.mark.slow  # interpret-mode Pallas / long decode on CPU; out of the tier-1 budget (plain `pytest tests/` still runs it)
+@pytest.mark.parametrize("data,tensor", [(1, 4), (2, 2), (2, 1)])
+def test_flash_sharded_matches_unsharded_on_mesh(data, tensor):
+    """``flash_attention_sharded`` under a data x tensor mesh (the kernel
+    per shard inside shard_map: heads over tensor, rows over data when
+    they divide) equals the plain kernel — bf16/int8-scale paths alike.
+    On a TPU a bare Mosaic call under such a mesh does not lower at all;
+    interpret mode cannot show that, this pins the wrapper's math."""
+    import jax
+    from jax_llama_tpu.ops.flash_attention import (
+        flash_attention_quantized, flash_attention_sharded,
+    )
+    from jax_llama_tpu.models.llama import quantize_kv
+    from jax_llama_tpu.parallel import make_mesh, use_mesh
+
+    B, T, S, H, KVH, D = 2, 32, 48, 8, 4, 16
+    q, k, v = (jnp.asarray(a) for a in _rand(B, T, S, H, KVH, D))
+    q_pos = jnp.tile(jnp.arange(S - T, S, dtype=jnp.int32), (B, 1))
+    kv_pos = jnp.tile(jnp.arange(S, dtype=jnp.int32), (B, 1))
+    mesh = make_mesh(
+        data=data, tensor=tensor, devices=jax.devices()[: data * tensor]
+    )
+
+    @jax.jit
+    def sharded(*a, **kw):
+        with use_mesh(mesh):
+            return flash_attention_sharded(*a, **kw)
+
+    np.testing.assert_allclose(
+        np.asarray(sharded(q, k, v, q_pos, kv_pos)),
+        np.asarray(flash_attention(q, k, v, q_pos, kv_pos)),
+        atol=1e-5, rtol=1e-5,
+    )
+    # One row cannot split over data=2: rows replicate, heads still shard.
+    np.testing.assert_allclose(
+        np.asarray(sharded(q[:1], k[:1], v[:1], q_pos[:1], kv_pos[:1])),
+        np.asarray(flash_attention(q[:1], k[:1], v[:1], q_pos[:1],
+                                   kv_pos[:1])),
+        atol=1e-5, rtol=1e-5,
+    )
+    (kq, ks), (vq, vs) = quantize_kv(k), quantize_kv(v)
+    np.testing.assert_allclose(
+        np.asarray(sharded(q, kq, vq, q_pos, kv_pos, k_scale=ks,
+                           v_scale=vs)),
+        np.asarray(flash_attention_quantized(q, kq, vq, ks, vs, q_pos,
+                                             kv_pos)),
+        atol=1e-5, rtol=1e-5,
+    )
+
+
 def test_model_forward_flash_matches_xla():
     import jax
 

@@ -63,10 +63,16 @@ from jax_llama_tpu.ops.paged_attention import paged_decode_attention
 from jax_llama_tpu.server import LLMServer
 from jax_llama_tpu.serving import ContinuousBatcher
 
-requires_tpu = pytest.mark.skipif(
-    jax.default_backend() != "tpu",
-    reason="needs the real TPU chip (run: pytest -m tpu)",
-)
+
+@pytest.fixture(scope="module")
+def tpu_chip():
+    """Skip unless this process holds a TPU — probed when a test that
+    asks for it starts, never while the file is imported (every xdist
+    worker imports it; an import-time probe makes workers collect
+    different tests)."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs the real TPU chip (JAX_PLATFORMS=tpu, -m tpu)")
+
 
 # The stock kernel's tiny serving geometry (d=16 — identical to
 # test_degrade's): the stock decode path has no lane-alignment
@@ -174,6 +180,32 @@ def test_resolution_auto_policies(model, splash_model):
         resolve_decode_kernel("nosuch", small)
 
 
+def test_describe_names_the_resolved_kernels(model):
+    """/debug/bundle's batcher section says which kernels the ctor
+    RESOLVED and whether the paged kernel can run at this geometry —
+    what chip_smoke.py reads to refuse a run that never touched Pallas."""
+    params, config = model
+    d = ContinuousBatcher(
+        params, config, n_slots=2, max_len=64, prefill_kernel="auto",
+        decode_kernel="stock-paged",
+    ).describe()
+    assert (d["prefill_kernel"], d["decode_kernel"]) == (
+        "flash", "stock-paged"
+    )
+    assert d["attn_impl"] == config.attn_impl
+    assert d["use_pallas_kernel"] is True
+    assert d["paged_kernel_eligible"] is True
+    g = ContinuousBatcher(
+        params, config, n_slots=2, max_len=64, decode_kernel="gathered",
+    ).describe()
+    # "gathered" is the paged path with the Pallas kernel off.
+    assert g["use_pallas_kernel"] is False and g["decode_kernel"] == "paged"
+    odd = ContinuousBatcher(
+        params, config, n_slots=2, max_len=64, block_size=12,
+    ).describe()
+    assert odd["paged_kernel_eligible"] is False   # 12 % 8 != 0
+
+
 def test_splash_eligibility_gates(splash_model):
     _, cfg = splash_model
     cfg = cfg.replace(prefill_kernel="splash")
@@ -233,9 +265,9 @@ def _pool_state(rng, B, KVH, d, L, NB, BLK, MB, fills):
     return kp, vp, pool_pos, table
 
 
-def _stock_case(seed=0):
+def _stock_case(seed=0, d=32):
     rng = np.random.RandomState(seed)
-    B, H, KVH, d = 4, 8, 2, 32
+    B, H, KVH = 4, 8, 2
     L, NB, BLK, MB = 2, 12, 16, 5
     # multi-block, empty (inactive), one block, partial block
     fills = [40, 0, 16, 7]
@@ -550,8 +582,7 @@ def test_splash_quarantine_falls_back_to_flash(
 
 @pytest.mark.tpu
 @pytest.mark.slow
-@requires_tpu
-def test_tpu_splash_prefill_compiled_matches_dense():
+def test_tpu_splash_prefill_compiled_matches_dense(tpu_chip):
     B, T, S, H, KVH, d = 1, 128, 256, 4, 2, 128
     rng = np.random.RandomState(7)
     q = rng.randn(B, T, H, d).astype(np.float32) * 0.5
@@ -575,9 +606,11 @@ def test_tpu_splash_prefill_compiled_matches_dense():
 
 @pytest.mark.tpu
 @pytest.mark.slow
-@requires_tpu
-def test_tpu_stock_decode_compiled_tracks_custom():
-    q, kn, vn, kp, vp, pool_pos, table, qpos = _stock_case(seed=11)
+def test_tpu_stock_decode_compiled_tracks_custom(tpu_chip):
+    # head_dim 128: compiled, the stock kernel's (m, l) outputs need a
+    # lane-aligned last block dim (first chip run, PR 21: d=32 is refused
+    # by the Pallas TPU lowering; interpret mode has no such rule).
+    q, kn, vn, kp, vp, pool_pos, table, qpos = _stock_case(seed=11, d=128)
     got = np.asarray(stock_paged_decode(
         jnp.asarray(q), jnp.asarray(kn), jnp.asarray(vn),
         jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(table),

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from jax_llama_tpu import get_config, init_params
+from jax_llama_tpu.obs import Observability
 from jax_llama_tpu.serving import ContinuousBatcher
 
 CFG = dict(
@@ -481,9 +482,12 @@ def test_attribution_adds_zero_device_dispatches_and_host_syncs(model):
     from jax_llama_tpu.serving import _COST_MODELS
 
     params, config = model
+    # Peaks are named: off a listed device the ctor default is "no
+    # peaks, no utilization gauges" (obs.DEVICE_PEAKS).
     cb = ContinuousBatcher(
         params, config, n_slots=2, max_len=128, decode_chunk=4,
         cost_models=True,
+        obs=Observability(peak_flops=1e12, peak_bytes_per_s=1e12),
     )
     cb.submit(list(np.random.RandomState(3).randint(1, 128, 9)),
               max_new_tokens=40)

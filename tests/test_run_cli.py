@@ -226,6 +226,11 @@ def test_run_cli_serve_mesh_and_replicas(tmp_path, capsys, monkeypatch):
     # own device slice on its own 1x2 serving mesh, placement active.
     assert all(m and m.get("tensor") == 2 for m in hits["meshes"])
     assert hits["placed"] == [True, True]
+    # Start-up states where each replica's weights live, and that a
+    # sliced fleet runs without the persistent compile cache (run.main).
+    out = capsys.readouterr().out
+    assert "compile_cache_off" in out
+    assert "param_devices=[0, 1]" in out and "param_devices=[2, 3]" in out
 
 
 def test_run_cli_serve_mesh_flag_validation(tmp_path, monkeypatch):
@@ -346,3 +351,30 @@ def test_run_cli_cache_aware_disaggregation(
     assert h["policy"] == "cache-aware"
     assert h["roles"] == ["prefill", "decode"]
     assert h["handoff"]["completed_total"] >= 1
+
+
+def test_peaks_come_from_the_device_table_or_are_off():
+    """--peak-tflops / --peak-hbm-gbps default to a lookup by device_kind
+    (obs.DEVICE_PEAKS).  A device the table does not list — the CPU these
+    tests run on — gets 0 (no utilization gauges) and a log line, never
+    the v5e's numbers; explicit flags are kept."""
+    import io
+    from types import SimpleNamespace
+
+    from jax_llama_tpu.obs import DEVICE_PEAKS, StructuredLogger
+
+    assert DEVICE_PEAKS["TPU v5 lite"] == (197e12, 819e9)
+    assert "cpu" not in DEVICE_PEAKS
+
+    def resolve(kind, tflops=None, gbps=None):
+        buf = io.StringIO()
+        args = SimpleNamespace(peak_tflops=tflops, peak_hbm_gbps=gbps)
+        run_cli._resolve_peaks(args, kind, StructuredLogger(stream=buf))
+        return args.peak_tflops, args.peak_hbm_gbps, buf.getvalue()
+
+    assert resolve("TPU v5 lite")[:2] == (197.0, 819.0)
+    tf, bw, log = resolve("cpu")
+    assert (tf, bw) == (0.0, 0.0) and "utilization_gauges_off" in log
+    tf, bw, log = resolve("cpu", tflops=10.0, gbps=20.0)
+    assert (tf, bw) == (10.0, 20.0) and log == ""
+    assert resolve("TPU v5 lite", tflops=100.0)[:2] == (100.0, 819.0)

@@ -8,15 +8,12 @@ the canonical one (KV heads over ``tensor``, state rows over ``data``)
 and STABLE across dispatches (the donated-alias precondition the
 lowering auditor's mesh pass proves per-program), and the
 prefill/decode disaggregation handoff moves prefix KV between batchers
-token-identically.  The first sharded dispatch in each test runs under
-``conftest.mesh_guarded`` so this image's known PartitionId/SPMD skew
-skips cleanly instead of failing."""
+token-identically."""
 
 import jax
 import numpy as np
 import pytest
 
-from conftest import mesh_guarded
 from jax_llama_tpu import get_config, init_params
 from jax_llama_tpu.parallel import serve_mesh as smesh
 from jax_llama_tpu.parallel.mesh import make_mesh
@@ -78,7 +75,7 @@ def _serve(params, config, mesh, *, prefill_budget=0, logprobs=False,
                 if logprobs:
                     lps.setdefault(ev[0], []).append(ev[3])
 
-    mesh_guarded(drain_some, 2)
+    drain_some(2)
     if fused_admission:
         # Long prompt lands while rows decode -> the fused prefill lane
         # (or, at prefill_budget=0, a classic mid-decode insert).
@@ -163,7 +160,7 @@ def test_pool_and_state_placement_stable(model, mesh22):
     assert cb.pool.k.sharding.is_equivalent_to(
         want_pool, cb.pool.k.ndim
     )
-    mesh_guarded(cb.step)
+    cb.step()
     first = {
         "k": spec_of(cb.pool.k), "pos": spec_of(cb.pool.pos),
         "fill": spec_of(cb.d_fill), "table": spec_of(cb.d_table),
@@ -313,7 +310,7 @@ def test_sharded_host_tier_restore_on_mesh(model, mesh22):
     want = serve(base_cb)
 
     cb = mk(sp, mesh)
-    got = mesh_guarded(serve, cb)
+    got = serve(cb)
     assert got == want
     n = cb.demote_idle(8)
     assert n > 0
@@ -371,6 +368,6 @@ def test_sharded_spec_chunk_parity(model, mesh22):
         return [out[r] for r in rids], cb
 
     base, _ = run(params, None)
-    got, cb = mesh_guarded(run, sp, mesh)
+    got, cb = run(sp, mesh)
     assert got == base
     assert cb._mesh_placed

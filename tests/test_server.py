@@ -759,8 +759,13 @@ def test_metrics_exposition_valid_prometheus(model):
     consistent with semantics, and the histogram families obey the
     cumulative-bucket invariants."""
     params, config = model
+    from jax_llama_tpu.obs import Observability
+
+    # Peaks are named: without them (the ctor default, and any device
+    # obs.DEVICE_PEAKS does not list) the utilization gauges are off.
     cb = ContinuousBatcher(
         params, config, n_slots=2, max_len=64, cost_models=True,
+        obs=Observability(peak_flops=1e12, peak_bytes_per_s=1e12),
     )
     with LLMServer(cb, tokenizer=ByteTokenizer()) as srv:
         status, _ = _post(

@@ -4,7 +4,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from conftest import xfail_if_remat_ulp_skew
 
 from jax_llama_tpu import config as cfg_lib
 from jax_llama_tpu.models import init_params
@@ -283,19 +282,10 @@ def test_remat_policies_identical_gradients():
         loss, grads = jax.value_and_grad(lm_loss)(params, toks, config)
         results[label] = (float(loss), jax.tree_util.tree_leaves(grads))
     base_loss, base_grads = results["none"]
-    skewed = False
     for label in ("full", "dots"):
         loss, grads = results[label]
         assert loss == base_loss, (label, loss, base_loss)
         for a, b in zip(grads, base_grads):
-            skewed |= xfail_if_remat_ulp_skew(
-                np.asarray(a), np.asarray(b), label
+            np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(b), err_msg=label
             )
-    if skewed:
-        pytest.xfail(
-            "environment XLA:CPU skew (detected): rematerialized "
-            "backward gradients differ from the unrematted ones at "
-            "rounding scale on this jaxlib (every diff passed the "
-            "tight allclose above; bit-identical on current jax/XLA, "
-            "pre-existing at the seed of this image)"
-        )
