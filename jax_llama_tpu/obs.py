@@ -8,8 +8,10 @@ questions (and ROADMAP item 5's online chunk controller) need:
 
   * **Event timeline** (:class:`Observability`).  Every request owns a
     bounded span timeline through the admission state machine —
-    ``queued -> prefilling -> restoring -> decoding ->
-    finished/failed/cancelled`` (the PR 5/6 states) — and every jitted
+    ``received -> queued -> prefilling -> restoring -> decoding ->
+    finished/failed/cancelled`` (``received``: POST accepted to
+    ``submit``, where the server's TTFT clock starts; then the PR 5/6
+    states) — and every jitted
     serving dispatch gets a span in a bounded ring recording its kind
     (``decode`` / ``fused`` / ``spec`` / ``insert`` / ``suffix_insert``
     / ``adopt``), effective K/R, slot occupancy, prompt tokens advanced
@@ -19,6 +21,15 @@ questions (and ROADMAP item 5's online chunk controller) need:
     they rode in (span.dispatches lists dispatch seq numbers), so a
     timeline answers "which chunk dispatches carried my prefill" and a
     dispatch answers "whose tokens did I emit".
+  * **Loop phases** (:meth:`Observability.loop_phase`,
+    :data:`LOOP_PHASES`).  The serving-loop thread names what it does
+    BETWEEN dispatch records; the phases tile that time, and each
+    dispatch record carries its own gap (``gap_ms``, ``host_ms`` per
+    phase, ``gap_cpu_ms``, ``compiles``).  Every phase and every
+    dispatch is also a ``jax.profiler.TraceAnnotation``
+    (``llm.loop.<phase>`` / ``llm.dispatch`` with the record's
+    ``seq``), so a profiler session holds them on the device trace's
+    clock and a device idle gap is named by overlap.
   * **Latency histograms** (:class:`Histogram`).  Prometheus cumulative-
     bucket histograms for TTFT, inter-token latency, queue wait,
     prefill-chunk latency, swap-in latency, jit compile time, and
@@ -106,6 +117,7 @@ writes, HTTP handler threads snapshot).
 from __future__ import annotations
 
 import bisect
+import functools
 import json
 import math
 import sys
@@ -130,6 +142,27 @@ from .faults import SITES
 DISPATCH_KINDS = frozenset({
     "decode", "fused", "spec", "insert", "suffix_insert", "adopt",
     "decode:stock-paged", "insert:splash",
+})
+
+# Serving-loop phases (Observability.loop_phase): what the loop thread —
+# the batcher's single owner — is doing BETWEEN two dispatch records.
+# The thread is always in exactly one, so they tile the gap with no
+# holes; validated like the kinds above (a typo raises).  Server side
+# (server.py::LLMServer._loop): ``control`` (heartbeat, flight snapshot,
+# control calls, probe rebuilds, recovery), ``intake`` (inbox drain,
+# pre-admission reaping, the overload ladder, shed, submit, reap),
+# ``idle`` (blocked on an empty inbox with nothing pending — waiting
+# for work, not overhead), ``deliver`` (failed-request pops and the
+# per-token loop: TTFT/ITL, ``chunks.put``, finalize).  Scheduler side
+# (serving.py::ContinuousBatcher): ``barrier`` (deferred-error fetches —
+# a device wait), ``admit`` (``_admit`` less its own dispatch records:
+# chain hashing, prefix match, block allocation, prefill set-up and
+# uploads, restore polling), ``prep`` (chunk pick, dirty-row sync,
+# fault sites, cost hook), ``emit`` (replay of the packed block,
+# ``request_end``, slot frees).
+LOOP_PHASES = frozenset({
+    "control", "intake", "idle", "deliver",
+    "barrier", "admit", "prep", "emit",
 })
 
 # Hardware peaks for the utilization gauges, keyed by
@@ -494,6 +527,23 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "program_compiles_total": _reg(
         "counter", "Backend jit compiles attributed to each serving "
                    "program (per program)"),
+    # -- serving-loop phases (the measured host share of a step) -------------
+    "loop_phase_ms_total": _reg(
+        "counter", "Serving-loop thread time between dispatch records, "
+                   "per phase (ms; the phases tile the gaps, idle = "
+                   "blocked on an empty inbox; folded in at each "
+                   "dispatch record)"),
+    "loop_gap_ms_total": _reg(
+        "counter", "Summed gap_ms of the dispatch records: end of one "
+                   "record to the start of the next on the loop "
+                   "thread's clock (ms; idle included — subtract "
+                   "loop_phase_ms_total{phase=\"idle\"} for the host's "
+                   "share of a step)"),
+    "loop_gap_cpu_ms_total": _reg(
+        "counter", "Loop-thread CPU time (time.thread_time) over the "
+                   "same gaps (ms; gap - idle - cpu = runnable or "
+                   "blocked but not running: the GIL, a descheduled "
+                   "core, a profiler)"),
     "jit_cache_entries": _reg(
         "gauge", "Live jit-cache entries per registered serving "
                  "program (a runaway series here is a bucketing bug "
@@ -675,6 +725,27 @@ def install_compile_listener() -> bool:
         return True
 
 
+def _annotate(name: str, **args) -> Any:
+    """An ENTERED ``jax.profiler.TraceAnnotation`` (an inactive TraceMe
+    — a few hundred ns — unless a profiler session is running), or
+    None where jax is absent; the caller ``__exit__``s it."""
+    cls = _annotation_cls()
+    if cls is None:
+        return None
+    ann = cls(name, **args)
+    ann.__enter__()
+    return ann
+
+
+@functools.lru_cache(maxsize=None)
+def _annotation_cls() -> Any:
+    try:  # lazy: this module stays importable without jax
+        from jax.profiler import TraceAnnotation
+    except Exception:
+        return None
+    return TraceAnnotation
+
+
 # ---------------------------------------------------------------------------
 # Decision audit log + anomaly-detection building block
 # ---------------------------------------------------------------------------
@@ -820,13 +891,16 @@ class EwmaDetector:
 # ---------------------------------------------------------------------------
 
 # Request lifecycle states (the PR 5/6 admission state machine) plus
-# terminal outcomes.
-STATES = ("queued", "prefilling", "restoring", "decoding")
+# terminal outcomes.  ``received`` is the server's part before the
+# batcher sees the request (POST accepted -> ``submit``: inbox and
+# class queue), opened already closed by ``request_queued``.
+STATES = ("received", "queued", "prefilling", "restoring", "decoding")
 OUTCOMES = ("finished", "failed", "cancelled")
 
 _MAX_SPANS = 64            # per timeline (replays append; bound them)
 _MAX_SPAN_DISPATCHES = 512  # dispatch links per span
 _MAX_RIDS = 8              # batcher incarnations indexed per timeline
+_PHASE_RING = 4096         # loop-phase intervals kept for trace_json
 
 
 class _Span:
@@ -960,6 +1034,28 @@ class Observability:
         self._slo_window: "deque[Tuple[bool, bool, bool]]" = deque(
             maxlen=slo_window
         )
+        # Serving-loop phases (loop_phase / dispatch_begin /
+        # record_dispatch).  ONE writer — the loop thread — so none of
+        # this takes the lock; only the *_total folds below do, inside
+        # record_dispatch's existing critical section.  Times are
+        # seconds on ``clock``.
+        self.loop_phases: "deque[Tuple[str, float, float]]" = deque(
+            maxlen=_PHASE_RING
+        )
+        self._ph_name: Optional[str] = None   # open phase; None inside a
+        self._ph_t = 0.0                      # dispatch; and its start
+        self._ph_ann: Any = None              # the open TraceAnnotation
+        self._ph_resume: Optional[str] = None  # set while a dispatch is open
+        self._ph_acc: Dict[str, float] = {}   # phase -> s since last record
+        self._ph_prev_end: Optional[float] = None  # last record's end
+        self._ph_cpu0 = 0.0                   # thread_time() at that end
+        self._ph_cpu1 = 0.0                   # ... at dispatch_begin
+        self._ph_tid = 0                      # thread that wrote that end
+        self._ph_compiles0 = 0                # compiles_total at that end
+        self._ph_seq = 0                      # the next record's ring number
+        self.loop_phase_ms_total: Dict[str, float] = {}
+        self.loop_gap_ms_total = 0.0
+        self.loop_gap_cpu_ms_total = 0.0
 
     # -- internal helpers ---------------------------------------------------
 
@@ -989,8 +1085,10 @@ class Observability:
         return tl.spans[-1] if tl.spans else None
 
     def _begin_span_locked(self, tl: _Timeline, state: str,
-                           note: Optional[str] = None) -> None:
-        t = self._now_ms()
+                           note: Optional[str] = None,
+                           t: Optional[float] = None) -> None:
+        if t is None:
+            t = self._now_ms()
         cur = self._current_span(tl)
         if cur is not None and cur.t1 is None:
             cur.t1 = t
@@ -1004,15 +1102,27 @@ class Observability:
 
     # -- request lifecycle (called by the batcher / server) -----------------
 
-    def request_queued(self, rid: int, prompt_tokens: int) -> None:
+    def request_queued(self, rid: int, prompt_tokens: int,
+                       received_at: Optional[float] = None) -> None:
         """A request entered the batcher queue (``submit``); creates a
         timeline under the provisional id ``r<rid>`` until the server
-        binds the external one."""
+        binds the external one.  ``received_at`` (seconds on this
+        instance's clock: the server's POST-arrival stamp, where its
+        TTFT starts) opens the timeline with a closed ``received`` span
+        up to now — the wait in the server's inbox and class queue —
+        so the spans start where the client's clock does; ``queued``
+        keeps its meaning (batcher queue -> prefill begins)."""
         with self._lock:
-            tl = _Timeline(f"r{rid}", rid, prompt_tokens, self._clock())
+            now = self._clock()
+            tl = _Timeline(f"r{rid}", rid, prompt_tokens, now)
             self._timelines[tl.request_id] = tl
             self._by_rid[rid] = tl
-            self._begin_span_locked(tl, "queued")
+            t = (now - self.t0) * 1000.0
+            if received_at is not None:
+                sp = _Span("received", (received_at - self.t0) * 1000.0)
+                sp.t1 = t
+                tl.spans.append(sp)
+            self._begin_span_locked(tl, "queued", t=t)
             self._evict_locked()
 
     def bind(self, rid: int, request_id: str,
@@ -1132,6 +1242,149 @@ class Observability:
             self._timelines[request_id] = tl
             self._evict_locked()
 
+    # -- serving-loop phases -------------------------------------------------
+
+    def _ph_close(self, t: float) -> None:
+        """End the open phase (and whatever annotation is open) at
+        ``t``: one accumulator add and one ring entry."""
+        cur = self._ph_name
+        if cur is not None:
+            self._ph_acc[cur] = (
+                self._ph_acc.get(cur, 0.0) + (t - self._ph_t)
+            )
+            self.loop_phases.append((cur, self._ph_t, t))
+        ann = self._ph_ann
+        if ann is not None:
+            self._ph_ann = None
+            ann.__exit__(None, None, None)
+
+    def _ph_open(self, name: Optional[str], t: float) -> None:
+        self._ph_name = name
+        self._ph_t = t
+        if name is not None:
+            self._ph_ann = _annotate("llm.loop." + name)
+
+    def _ph_abandon_dispatch(self) -> None:
+        """A dispatch began and never recorded (it raised): its time
+        goes back to the phase it interrupted."""
+        self._ph_close(self._ph_t)  # the llm.dispatch annotation
+        resume, self._ph_resume = self._ph_resume, None
+        self._ph_open(resume, self._ph_t)
+
+    def loop_phase(self, name: str) -> None:
+        """The serving-loop thread says "I am in phase ``name`` from
+        now"; the previous phase ends at the same instant, so phases
+        tile the thread's time between dispatch records with no holes
+        (``record_dispatch`` turns them into ``gap_ms`` / ``host_ms``).
+        Also opens ``llm.loop.<name>`` as a ``jax.profiler``
+        annotation, so a profiler session holds the phases on the
+        device trace's clock.  Loop thread only — the batcher's single
+        owner; takes no lock, always on: one clock read, one ring
+        entry and one inactive annotation per change of phase."""
+        if name not in LOOP_PHASES:
+            raise ValueError(
+                f"unknown loop phase {name!r}; have {sorted(LOOP_PHASES)}"
+            )
+        if self._ph_resume is not None:
+            self._ph_abandon_dispatch()
+        if name == self._ph_name:
+            return
+        t = self._clock()
+        self._ph_close(t)
+        self._ph_open(name, t)
+
+    def dispatch_begin(self, kind: str, program: Optional[str] = None,
+                       k: int = 1) -> None:
+        """The loop thread is about to submit a jitted dispatch that
+        ``record_dispatch`` will record: ends the open phase and opens
+        the ``llm.dispatch`` annotation carrying the record's ring
+        number (this thread is the ring's only writer, so the next
+        ``seq`` is known before the submit — an xplane event is joined
+        to its record by identity, never by time)."""
+        if self._ph_resume is not None:
+            self._ph_abandon_dispatch()
+        t = self._clock()
+        resume = self._ph_name
+        self._ph_close(t)
+        self._ph_cpu1 = time.thread_time()
+        # ``_ph_t`` keeps the begin instant until the record lands: an
+        # abandoned dispatch falls back into the interrupted phase
+        # from there.
+        self._ph_t = t
+        self._ph_resume = resume if resume is not None else "prep"
+        self._ph_name = None
+        self._ph_ann = _annotate(
+            "llm.dispatch", seq=self._ph_seq, kind=kind,
+            program=program or "", k=int(k),
+        )
+
+    def _ph_record(self, now: float, start: float,
+                   then: Optional[str]) -> Dict[str, Any]:
+        """Close the tiling at a dispatch record that ran ``start`` to
+        ``now``: the record's gap fields, and the phase the thread is
+        in from ``now`` (``then``, default the one the dispatch
+        interrupted)."""
+        resume = self._ph_resume
+        if resume is None:
+            # No dispatch_begin (a direct caller): the open phase ran
+            # up to the record's start, and the CPU reading below
+            # includes the dispatch's own.
+            resume = self._ph_name
+            self._ph_close(max(start, self._ph_t))
+            cpu1 = time.thread_time()
+        else:
+            self._ph_resume = None
+            self._ph_close(now)  # the llm.dispatch annotation
+            cpu1 = self._ph_cpu1
+        out: Dict[str, Any] = {}
+        tid = threading.get_ident()
+        if self._ph_prev_end is not None and resume is not None:
+            gap = start - self._ph_prev_end
+            acc = self._ph_acc
+            # The dispatch's start is read on the caller's clock a few
+            # hundred ns after dispatch_begin's; that sliver belongs to
+            # the interrupted phase, and the tiling is exact.
+            acc[resume] = (
+                acc.get(resume, 0.0) + gap - sum(acc.values())
+            )
+            out["gap_ms"] = round(gap * 1000.0, 3)
+            out["host_ms"] = {
+                p: round(v * 1000.0, 3) for p, v in acc.items()
+            }
+            if tid == self._ph_tid:
+                out["gap_cpu_ms"] = round(
+                    (cpu1 - self._ph_cpu0) * 1000.0, 3
+                )
+        self._ph_acc = {}
+        self._ph_prev_end = now
+        self._ph_tid = tid
+        self._ph_open(then if then is not None else resume, now)
+        self._ph_cpu0 = time.thread_time()
+        return out
+
+    def loop_phases_json(self) -> List[Tuple[str, float, float]]:
+        """(phase, start_ms, end_ms) of the recent phase intervals, on
+        the same origin as every ``start_ms`` here."""
+        # No lock: the ring has one writer, and copying a deque of
+        # tuples runs no bytecode, so the GIL makes the copy atomic.
+        t0 = self.t0
+        return [
+            (name, (a - t0) * 1000.0, (b - t0) * 1000.0)
+            for name, a, b in list(self.loop_phases)
+        ]
+
+    def loop_phase_metrics(
+        self,
+    ) -> List[Tuple[str, Dict[str, str], float]]:
+        """``loop_phase_ms_total{phase=...}`` samples for /metrics
+        (``(family, labels, value)`` like ``utilization_metrics``)."""
+        with self._lock:
+            totals = sorted(self.loop_phase_ms_total.items())
+        return [
+            ("loop_phase_ms_total", {"phase": p}, round(v, 3))
+            for p, v in totals
+        ]
+
     # -- dispatch spans ------------------------------------------------------
 
     def record_dispatch(
@@ -1147,6 +1400,7 @@ class Observability:
         program: Optional[str] = None,
         flops: Optional[float] = None,
         bytes_accessed: Optional[float] = None,
+        then: Optional[str] = None,
     ) -> int:
         """Record one jitted serving dispatch and link it into the
         CURRENT span of every request that rode it.  Returns the
@@ -1156,13 +1410,30 @@ class Observability:
         ``program`` names the jitted program; ``flops`` /
         ``bytes_accessed`` are its static cost model (CostModelCache) —
         when present the record carries a roofline device-time estimate
-        and feeds the per-kind utilization window."""
+        and feeds the per-kind utilization window.
+
+        The record also carries the gap that led to it, from the loop
+        phases: ``gap_ms`` (end of the previous record -> this one's
+        start; absent on the first record and while no phase was ever
+        marked), ``host_ms`` (``{phase: ms}``, summing to ``gap_ms`` —
+        ``idle`` included so the tiling holds; readers leave it out),
+        ``gap_cpu_ms`` (``time.thread_time()`` over the same interval;
+        absent when the previous record came from another thread) and
+        ``compiles`` (backend compiles booked since the previous record
+        ended).  ``then`` names the phase the loop thread is in once
+        the dispatch ends (default: the one it interrupted)."""
         if kind not in DISPATCH_KINDS:
             raise ValueError(
                 f"unknown dispatch kind {kind!r}; have "
                 f"{sorted(DISPATCH_KINDS)}"
             )
-        t = self._now_ms()
+        if then is not None and then not in LOOP_PHASES:
+            raise ValueError(
+                f"unknown loop phase {then!r}; have {sorted(LOOP_PHASES)}"
+            )
+        now = self._clock()
+        t = (now - self.t0) * 1000.0
+        gap = self._ph_record(now, now - wall_ms / 1000.0, then)
         rec = {
             "seq": -1, "kind": kind, "k": int(k),
             "occupancy": int(occupancy),
@@ -1175,6 +1446,7 @@ class Observability:
         }
         if program is not None:
             rec["program"] = program
+        rec.update(gap)
         est_ms = None
         if flops is not None and bytes_accessed is not None:
             est = 0.0
@@ -1194,8 +1466,17 @@ class Observability:
         with self._lock:
             seq = self._seq
             self._seq += 1
+            self._ph_seq = seq + 1  # the loop thread's own copy
             rec["seq"] = seq
+            rec["compiles"] = self.compiles_total - self._ph_compiles0
+            self._ph_compiles0 = self.compiles_total
             self.dispatches.append(rec)
+            if "gap_ms" in gap:
+                self.loop_gap_ms_total += gap["gap_ms"]
+                self.loop_gap_cpu_ms_total += gap.get("gap_cpu_ms", 0.0)
+                totals = self.loop_phase_ms_total
+                for p, v in gap["host_ms"].items():
+                    totals[p] = totals.get(p, 0.0) + v
             h = self.hist_dispatch.get(kind)
             if h is None:
                 h = self.hist_dispatch[kind] = Histogram(
@@ -1388,6 +1669,10 @@ class Observability:
                 "requests_cancelled_total": self.requests_cancelled_total,
                 "decision_events_total": decisions_total,
                 "compiles_total": self.compiles_total,
+                "loop_gap_ms_total": round(self.loop_gap_ms_total, 3),
+                "loop_gap_cpu_ms_total": round(
+                    self.loop_gap_cpu_ms_total, 3
+                ),
                 "slo_ttft_ms": self.slo_ttft_ms or 0.0,
                 "slo_itl_ms": self.slo_itl_ms or 0.0,
                 "requests_slo_ok_total": self.requests_slo_ok_total,
@@ -1532,8 +1817,9 @@ class Observability:
     def trace_json(self, window_ms: Optional[float] = None) -> Dict[str, Any]:
         """Chrome/Perfetto ``trace_event`` JSON for the recent serving
         window (default: everything the rings still hold).  Dispatches
-        render on pid 1 / tid 1, request lifecycles on one tid per
-        request, annotations as instant events — load the payload in
+        render on pid 1 / tid 1, the loop thread's phases between them
+        on the ``serving loop`` track (tid 2), request lifecycles on
+        one tid per request, annotations as instant events — load the payload in
         chrome://tracing or https://ui.perfetto.dev."""
         horizon = None
         if window_ms is not None:
@@ -1545,6 +1831,7 @@ class Observability:
         # and annotation dicts are created once and never mutated, so
         # the list copies are reference-shallow; only the mutable
         # _Span fields are copied out.
+        phases = self.loop_phases_json()
         with self._lock:
             dispatches = list(self.dispatches)
             events = list(self.events)
@@ -1562,7 +1849,18 @@ class Observability:
              "args": {"name": "dispatches"}},
             {"ph": "M", "pid": 1, "tid": 0, "name": "thread_name",
              "args": {"name": "jit compiles"}},
+            {"ph": "M", "pid": 1, "tid": 2, "name": "thread_name",
+             "args": {"name": "serving loop"}},
         ]
+        # What the loop thread did between the dispatch spans above.
+        for name, a, b in phases:
+            if horizon is not None and b < horizon:
+                continue
+            ev.append({
+                "name": name, "cat": "loop", "ph": "X", "pid": 1,
+                "tid": 2, "ts": round(a * 1000.0, 1),
+                "dur": max(1, round((b - a) * 1000.0)),
+            })
         for d in dispatches:
             if horizon is not None and d["start_ms"] < horizon:
                 continue
@@ -1575,7 +1873,8 @@ class Observability:
                     k: d[k] for k in (
                         "seq", "occupancy", "prefill_tokens",
                         "fetch_ms", "swap_inflight", "rids",
-                        "program", "device_est_ms",
+                        "program", "device_est_ms", "gap_ms",
+                        "host_ms", "gap_cpu_ms", "compiles",
                     ) if k in d
                 },
             })
@@ -1590,7 +1889,7 @@ class Observability:
                 "dur": max(1, round(c["dur_ms"] * 1000.0)),
                 "args": {"program": c["program"]},
             })
-        tid = 2
+        tid = 3
         for request_id, outcome, spans in timelines:
             spans = [
                 sp for sp in spans

@@ -189,6 +189,15 @@ per registered serving program), ``llm_compiles_total`` +
 histogram (every backend compile, attributed to the program whose
 dispatch triggered it via the jax.monitoring listener).
 
+Serving-loop phases (obs.LOOP_PHASES — always on): the MEASURED host
+share of a step, where ``llm_host_overhead_ratio`` estimates one from a
+cost model.  ``llm_loop_phase_ms_total{phase="control"|"intake"|"idle"|
+"deliver"|"barrier"|"admit"|"prep"|"emit"}`` (loop-thread time between
+dispatch records, by what the thread was doing), ``llm_loop_gap_ms_total``
+(the sum of those gaps, idle included) and ``llm_loop_gap_cpu_ms_total``
+(the thread's CPU time over them: gap - idle - cpu is time it was
+runnable or blocked but not running).
+
 SLO accounting (run.py ``--slo-ttft-ms`` / ``--slo-itl-ms``; a 0/unset
 dimension always passes): ``llm_slo_ttft_attainment`` /
 ``llm_slo_itl_attainment`` / ``llm_slo_attainment`` gauges (fraction of
@@ -206,7 +215,8 @@ evicted)::
       "prompt_tokens": int,
       "outcome": "finished"|"failed"|"cancelled"|null,
       "error": str|null,
-      "spans": [{"state": "queued"|"prefilling"|"restoring"|"decoding",
+      "spans": [{"state": "received"|"queued"|"prefilling"|"restoring"|
+                          "decoding",   # received: POST -> submit
                  "start_ms": float, "end_ms": float|null,
                  "duration_ms": float|null,
                  "dispatches": [seq, ...],     # causal links
@@ -226,28 +236,40 @@ outcome).  ``GET /debug/dispatches?n=128`` returns the dispatch ring::
                      "start_ms": float, "wall_ms": float,
                      "fetch_ms": float,        # the packed np.asarray
                      "swap_inflight": int,     # decode/swap overlap
-                     "rids": [int, ...]}, ...]}
+                     "rids": [int, ...],
+                     # the gap that led to this record (loop phases;
+                     # absent on an Observability's first record):
+                     "gap_ms": float,          # prev record's end -> start
+                     "host_ms": {phase: ms},   # sums to gap_ms (idle too)
+                     "gap_cpu_ms": float,      # loop-thread CPU in the gap
+                     "compiles": int}, ...]}   # since the prev record
 
 ``GET /debug/trace[?window_s=S]`` emits Chrome ``trace_event`` JSON
 (``{"traceEvents": [...]}``) — load in chrome://tracing or
-https://ui.perfetto.dev: dispatches on one track, request lifecycles on
-per-request tracks, fault/quarantine/kv-tier annotations as instant
+https://ui.perfetto.dev: dispatches on one track, the loop thread's
+phases between them on the ``serving loop`` track, request lifecycles
+on per-request tracks, fault/quarantine/kv-tier annotations as instant
 events, jit compiles on their own track, and the document carries a
 ``t0_unix_s`` wall-clock anchor — the router's fleet-merged
 ``/debug/trace`` uses it to shift this replica's timestamps into one
 frame (clock-offset normalization; see router.py for the merged
 schema).  ``POST /debug/profiler`` ``{"action": "start", "log_dir":
 D}`` / ``{"action": "stop"}`` brackets a ``jax.profiler`` xplane
-session around live traffic (the device-side complement);
+session around live traffic (the device-side complement; the capture's
+host plane holds every loop phase and dispatch as ``llm.loop.<phase>``
+/ ``llm.dispatch`` events on the device events' clock);
 ``GET /debug/profile/summary[?log_dir=D]`` then parses the completed
-capture into per-program attribution::
+capture (``jax.profiler.ProfileData``) into per-program attribution and
+the device's idle time by what the loop thread was doing::
 
     {"xplane": path, "log_dir": D,
      "programs": {"<program>": {"device_ms": F, "host_ms": F}, ...},
-     "total_device_ms": F, "total_host_ms": F}
+     "total_device_ms": F, "total_host_ms": F,
+     "busy_ms": F, "idle_ms": F,          # union of XLA Ops, device 0
+     "idle_by_phase_ms": {"<phase>"|"in dispatch"|"unnamed": F, ...}}
 
-(404 with no completed session, 409 while one is active, 501 without
-the xplane protos).  Dispatch records (/debug/dispatches) gain
+(404 with no completed session, 409 while one is active).  Dispatch
+records (/debug/dispatches) gain
 ``program`` and — with cost models on — ``flops`` /
 ``bytes_accessed`` / ``device_est_ms`` (the roofline estimate the
 host_overhead_ratio gauge divides by).
@@ -1554,7 +1576,12 @@ class LLMServer:
             stops = getattr(self.tokenizer, "stop_tokens", None)
             if stops:
                 kwargs["stop_tokens"] = tuple(int(t) for t in stops)
-        rid = self.batcher.submit(tokens, **kwargs)
+        # received_at: the timeline starts where the client's clock (and
+        # this server's TTFT) does — the inbox and class-queue wait is
+        # its ``received`` span.
+        rid = self.batcher.submit(
+            tokens, received_at=p.received_at, **kwargs
+        )
         p.request_id = rid
         if p.priority == CANARY:
             self.canary_requests_total += 1
@@ -2050,8 +2077,6 @@ class LLMServer:
             from .utils.profiling import summarize_xplane
 
             summary = summarize_xplane(log_dir)
-        except ImportError as e:
-            return 501, {"error": f"xplane protos unavailable: {e}"}
         except FileNotFoundError as e:
             return 404, {"error": str(e)}
         except Exception as e:  # surface a parse failure, never crash
@@ -2066,6 +2091,11 @@ class LLMServer:
         reason, code = "server shutting down", 503
         try:
             while not self._stop.is_set():
+                # Loop phases (obs.LOOP_PHASES): this thread names what
+                # it does between dispatch records — control / intake /
+                # idle / deliver here, the scheduler's own inside
+                # step().
+                self.obs.loop_phase("control")
                 self._heartbeat = time.monotonic()
                 # Flight recorder: one compact metric snapshot per
                 # flight_interval_s (host-side dict building only) —
@@ -2128,17 +2158,23 @@ class LLMServer:
                 # queues (strict interactive-first ordering lives
                 # there); block briefly when fully idle so shutdown
                 # and new work are both responsive.
+                self.obs.loop_phase("intake")
                 try:
                     block = (
                         not self.batcher.pending()
                         and self.overload.queued_total() == 0
                     )
+                    if block:
+                        # Nothing to run: the wait below is for work,
+                        # not overhead.
+                        self.obs.loop_phase("idle")
                     while True:
                         p = self._inbox.get(block=block, timeout=0.05)
                         block = False
+                        self.obs.loop_phase("intake")  # the wait is over
                         self.overload.push(p)
                 except queue.Empty:
-                    pass
+                    self.obs.loop_phase("intake")
                 self._reap_preadmission()
                 # Brownout ladder (overload.py): evaluate the rung,
                 # apply its knobs on a transition, shed queued batch
@@ -2237,9 +2273,11 @@ class LLMServer:
                     # onto a fallback path when the failure quarantined
                     # a feature; past the retry budget, re-raise into
                     # the hard drain.
+                    self.obs.loop_phase("control")
                     if self._recover(e):
                         continue
                     raise
+                self.obs.loop_phase("deliver")
                 # Probe-success recording runs ONE STEP BEHIND: jax
                 # dispatch is async, so step N's device work is only
                 # proven good once step N+1's host sync (the emit scan's
@@ -2488,11 +2526,12 @@ class LLMServer:
         # while a family has no samples yet, so dashboards can discover
         # them before traffic.
         labeled = list(self.obs.utilization_metrics())
+        labeled.extend(self.obs.loop_phase_metrics())
         for prog, n in sorted(serving_mod.jit_cache_entries().items()):
             labeled.append(("jit_cache_entries", {"program": prog}, n))
         for family in ("mxu_utilization", "hbm_utilization",
                        "host_overhead_ratio", "program_compiles_total",
-                       "jit_cache_entries"):
+                       "jit_cache_entries", "loop_phase_ms_total"):
             kind, help_text = metric_meta(family)
             lines.append(f"# HELP llm_{family} {help_text}")
             lines.append(f"# TYPE llm_{family} {kind}")

@@ -2342,12 +2342,16 @@ class ContinuousBatcher:
         top_p: Optional[float] = None,
         top_k: Optional[int] = None,
         seed: Optional[int] = None,
+        received_at: Optional[float] = None,
     ) -> int:
         """Queue a request; returns its id.  Tokens only — tokenize first.
 
         temperature/top_p/top_k default to the pool-level policy; ``seed``
         starts the request's own PRNG chain (default: derived from the
-        pool seed and request id).
+        pool seed and request id).  ``received_at`` (``time.monotonic()``
+        when the caller accepted the request — the server's POST
+        arrival) starts the request's timeline there, with a
+        ``received`` span ahead of ``queued``.
         """
         if not prompt_tokens:
             raise ValueError("empty prompt")
@@ -2399,7 +2403,7 @@ class ContinuousBatcher:
         # a burst of submits is admitted as ONE batched prefill dispatch
         # instead of k serialized ones.
         self.queue.append(req)
-        self.obs.request_queued(rid, len(req.tokens))
+        self.obs.request_queued(rid, len(req.tokens), received_at)
         return rid
 
     def pending(self) -> bool:
@@ -2751,6 +2755,7 @@ class ContinuousBatcher:
             # admit through the same classic insert program even with
             # the queue empty (``_restored_ready``), so in-flight and
             # landed restores arm the barrier too.
+            self.obs.loop_phase("barrier")
             # audit: host-fetch(deferred-error barrier before admission
             # overwrites dispatch attribution; counted)
             np.asarray(self.tau)
@@ -2878,9 +2883,11 @@ class ContinuousBatcher:
             # Surface any async admission-dispatch error NOW, while
             # last_dispatch_features still names the insert (the chunk's
             # _record_dispatch below would otherwise steal attribution).
+            self.obs.loop_phase("barrier")
             # audit: host-fetch(post-admission error barrier; counted)
             np.asarray(self.tau)
             self.host_syncs_total += 1
+        self.obs.loop_phase("prep")
         self._admits_at_last_chunk = self._admit_dispatches
         pf = self._pf
         if pf is not None and not bool(np.any(self.active)):
@@ -2996,6 +3003,17 @@ class ContinuousBatcher:
                     placed=self._mesh_placed,
                 ),
             )
+        # Per-kernel MXU attribution: a stock-paged pure-decode chunk
+        # books under its own kind, so llm_mxu_utilization
+        # {kind="decode:stock-paged"} vs {kind="decode"} IS the live A/B
+        # gauge.  Fused chunks keep one kind — their FLOPs mix prefill
+        # and decode, so splitting them per-kernel would attribute
+        # flash work to the decode kernel.
+        kind = (
+            ("decode:stock-paged" if "stock_paged" in feats else "decode")
+            if pf_adv == 0 else "fused"
+        )
+        self.obs.dispatch_begin(kind, prog, K)
         t0_obs = time.monotonic()
         if pf is None:
             (packed, self.tau, self.d_tau_lp, self.d_fill, self.d_pos,
@@ -3061,22 +3079,13 @@ class ContinuousBatcher:
         self.host_syncs_total += 1
         now_obs = time.monotonic()
         self.obs.record_dispatch(
-            # Per-kernel MXU attribution: a stock-paged pure-decode
-            # chunk books under its own kind, so llm_mxu_utilization
-            # {kind="decode:stock-paged"} vs {kind="decode"} IS the live
-            # A/B gauge.  Fused chunks keep one kind — their FLOPs mix
-            # prefill and decode, so splitting them per-kernel would
-            # attribute flash work to the decode kernel.
-            kind=(
-                ("decode:stock-paged" if "stock_paged" in feats
-                 else "decode")
-                if pf_adv == 0 else "fused"
-            ),
+            kind=kind,
             k=K, occupancy=len(obs_rids), prefill_tokens=pf_adv,
             wall_ms=(now_obs - t0_obs) * 1000.0,
             fetch_ms=(now_obs - tf_obs) * 1000.0,
             swap_inflight=len(self._restoring), rids=obs_rids,
             program=prog, flops=cost_fl, bytes_accessed=cost_by,
+            then="emit",
         )
         if pf_done_rid is not None:
             # The prefill's last chunk linked into the prefilling span
@@ -3157,11 +3166,13 @@ class ContinuousBatcher:
         # the round so a completing request doesn't pay for one more
         # forward whose output would be discarded.
         out: List[Tuple] = []
+        self.obs.loop_phase("barrier")
         # audit: host-fetch(classic spec path: per-round pending-tau
         # emit fetch; counted)
         taus = np.asarray(self.tau)
         self.host_syncs_total += 1
         self.spec_host_syncs_total += 1
+        self.obs.loop_phase("emit")
         # Non-finite guard: a -1 tau is the step programs' sentinel for
         # "this row's logits contained NaN/Inf" — fail just that request
         # with a clean error instead of streaming a garbage token.  An
@@ -3203,6 +3214,7 @@ class ContinuousBatcher:
             # The kernel/spec sites fire after "step" (same dispatch,
             # finer attribution: their exceptions carry a site name the
             # degradation layer maps to a quarantinable feature).
+            self.obs.loop_phase("prep")
             feats: List[str] = ["spec_decode"]
             if self._spec_kernel_ok():
                 feats.append("paged_kernel")
@@ -3247,10 +3259,12 @@ class ContinuousBatcher:
             # Surface any async admission-dispatch error NOW, while
             # last_dispatch_features still names the insert (see
             # _step_chunked).
+            self.obs.loop_phase("barrier")
             # audit: host-fetch(post-admission error barrier; counted)
             np.asarray(self.tau)
             self.host_syncs_total += 1
             self.spec_host_syncs_total += 1
+        self.obs.loop_phase("prep")
         self._admits_at_last_chunk = self._admit_dispatches
         R = self._pick_chunk(admitted, cap=self.spec_rounds)
         self._sync_device_rows()
@@ -3300,6 +3314,7 @@ class ContinuousBatcher:
                 with_logprobs=self.logprobs, placed=self._mesh_placed,
             ),
         )
+        self.obs.dispatch_begin("spec", "_spec_rounds_chunk", R)
         t0_obs = time.monotonic()
         (packed, self.tau, self.d_tau_lp, self.d_fill, self.d_pos,
          self.d_active, self.d_remaining, self.keys, self.pool,
@@ -3329,7 +3344,7 @@ class ContinuousBatcher:
             fetch_ms=(now_obs - tf_obs) * 1000.0,
             swap_inflight=len(self._restoring), rids=obs_rids,
             program="_spec_rounds_chunk", flops=cost_fl,
-            bytes_accessed=cost_by,
+            bytes_accessed=cost_by, then="emit",
         )
         G = self.n_draft
         toks = arr[:, :, : G + 1]
@@ -3488,6 +3503,7 @@ class ContinuousBatcher:
                 with_logprobs=self.logprobs, placed=self._mesh_placed,
             ),
         )
+        self.obs.dispatch_begin("spec", "_spec_round")
         t0_obs = time.monotonic()
         outs, acc, lps, self.keys, self.pool, self.draft_pool = _spec_round(
             self.params, self.draft_params, self.pool, self.draft_pool,
@@ -3522,7 +3538,7 @@ class ContinuousBatcher:
             fetch_ms=(now_obs - tf_obs) * 1000.0,
             swap_inflight=len(self._restoring), rids=obs_rids,
             program="_spec_round", flops=cost_fl,
-            bytes_accessed=cost_by,
+            bytes_accessed=cost_by, then="emit",
         )
         round_proposed = round_accepted = 0
         # NOTE: the per-row fill/pos advances below touch the numpy
@@ -4197,6 +4213,7 @@ class ContinuousBatcher:
                 placed=self._mesh_placed,
             ),
         )
+        self.obs.dispatch_begin("suffix_insert", "_paged_suffix_insert", k)
         t0_obs = time.monotonic()
         self._record_dispatch(["prefix_cache"])
         self._fault("suffix_insert")
@@ -4327,6 +4344,7 @@ class ContinuousBatcher:
         prefix includes host-tier blocks moves to ``restoring``
         instead (either path) — later queue entries keep admitting
         while its swap-in flies."""
+        self.obs.loop_phase("admit")
         self._poll_restores()
         self._admit_restored_ready()
         if self._fused_scheduling():
@@ -4458,6 +4476,7 @@ class ContinuousBatcher:
                 "_adopt_jit", (len(r.staged["ids"]),),
                 lambda: adopt_lower(self.pool, r.staged),
             )
+            self.obs.dispatch_begin("adopt", "_adopt_jit", len(r.fresh))
             t_adopt = time.monotonic()
             self.pool = adopt_into_pool(self.pool, r.staged)
             if self.spec:
@@ -4858,6 +4877,10 @@ class ContinuousBatcher:
                     mesh=self.mesh, with_logprobs=self.logprobs,
                     placed=self._mesh_placed,
                 ),
+            )
+            self.obs.dispatch_begin(
+                "insert:splash" if splash_used else "insert",
+                "_paged_insert", k,
             )
             t0_obs = time.monotonic()
             feats_ins: List[str] = ["flash_attention"] if flash else []

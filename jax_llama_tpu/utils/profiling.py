@@ -10,6 +10,7 @@ BASELINE.json metric).
 
 from __future__ import annotations
 
+import bisect
 import collections
 import contextlib
 import dataclasses
@@ -19,7 +20,7 @@ import re
 import shutil
 import tempfile
 import time
-from typing import Callable, Dict, Iterator
+from typing import Callable, Dict, Iterable, Iterator, List, Sequence, Tuple
 
 import jax
 
@@ -127,10 +128,18 @@ def device_op_times(
 # Event-name spellings that carry a jitted-program identity in an
 # xplane capture: the host plane's python line traces dispatch frames
 # as ``PjitFunction(<name>)``, and device planes' "XLA Modules" line
-# names executables ``jit_<name>`` (sometimes with a ``.N`` or
-# ``(...)`` specialization suffix).
+# names executables ``jit_<name>`` (with a ``(<run id>)`` and sometimes
+# a ``.N`` specialization suffix).
 _PJIT_RE = re.compile(r"^PjitFunction\((.+)\)$")
-_JIT_MODULE_RE = re.compile(r"^jit_(.+?)(?:\.\d+)?$")
+_JIT_MODULE_RE = re.compile(r"^jit_(.+?)(?:\(\d+\))?(?:\.\d+)?$")
+
+# The serving loop's own annotations (obs.Observability.loop_phase /
+# dispatch_begin): one host event per phase and per dispatch, on the
+# loop thread, on the clock the device events are on.
+LOOP_PREFIX = "llm.loop."
+DISPATCH_EVENT = "llm.dispatch"
+
+Interval = Tuple[float, float]
 
 
 def normalize_program_name(event_name: str):
@@ -146,20 +155,68 @@ def normalize_program_name(event_name: str):
     return None
 
 
-def summarize_xplane(log_dir: str) -> Dict[str, object]:
-    """Aggregate the newest xplane capture under ``log_dir`` into
-    per-jitted-program time attribution.
+def busy_and_gaps(intervals: Iterable[Interval]) -> Tuple[float, List[Interval]]:
+    """The union of ``intervals`` (they nest: a ``while`` around its
+    body) and the gaps inside it, first start to last end."""
+    busy, gaps = 0.0, []
+    cur_s = cur_e = None
+    for s, e in sorted(intervals):
+        if cur_e is None or s > cur_e:
+            if cur_e is not None:
+                busy += cur_e - cur_s
+                gaps.append((cur_e, s))
+            cur_s, cur_e = s, e
+        else:
+            cur_e = max(cur_e, e)
+    if cur_e is not None:
+        busy += cur_e - cur_s
+    return busy, gaps
 
-    Device planes (name contains TPU/GPU) attribute their "XLA
-    Modules" line — executable-granular device time, the number the
-    MXU-gap investigation needs; the host plane's ``PjitFunction``
-    frames attribute host-side dispatch time (on a CPU-only capture
-    that is the only signal, and it still answers "which program").
-    Raises ImportError when the TensorFlow xplane protos are absent
-    and FileNotFoundError when ``log_dir`` holds no capture — the
-    /debug/profile/summary endpoint maps both to clean HTTP errors.
+
+def split_by_overlap(
+    gaps: Sequence[Interval], named: Sequence[Tuple[str, float, float]],
+    rest: str = "unnamed",
+) -> Dict[str, float]:
+    """Each gap's length by the ``named`` intervals ``(label, start,
+    end)`` it overlaps (they do not overlap one another: one thread's
+    phases); what none covers goes to ``rest``."""
+    out: Dict[str, float] = collections.defaultdict(float)
+    named = sorted(named, key=lambda n: n[1])
+    starts = [n[1] for n in named]
+    for gs, ge in gaps:
+        covered = 0.0
+        i = max(0, bisect.bisect_right(starts, gs) - 1)
+        while i < len(named) and named[i][1] < ge:
+            label, s, e = named[i]
+            ov = min(e, ge) - max(s, gs)
+            if ov > 0:
+                out[label] += ov
+                covered += ov
+            i += 1
+        if ge - gs > covered:
+            out[rest] += ge - gs - covered
+    return dict(out)
+
+
+def summarize_xplane(log_dir: str) -> Dict[str, object]:
+    """Aggregate the newest xplane capture under ``log_dir``, read with
+    ``jax.profiler.ProfileData`` alone.
+
+    Per jitted program: device planes (name contains TPU/GPU)
+    attribute their "XLA Modules" line — executable-granular device
+    time; the host plane's ``PjitFunction`` frames attribute host-side
+    dispatch time (on a CPU-only capture that is the only signal, and
+    it still answers "which program").  For the first device:
+    ``busy_ms`` / ``idle_ms`` (the union of its "XLA Ops" intervals and
+    the gaps in it) and ``idle_by_phase_ms`` — each idle gap split by
+    overlap with the serving loop's own annotations in the same file
+    (``llm.loop.<phase>`` by phase, ``llm.dispatch`` as ``in
+    dispatch``, the rest ``unnamed``), so the attribution of device
+    idle time to host work comes from the running server with no clock
+    join.  Raises FileNotFoundError when ``log_dir`` holds no capture —
+    the /debug/profile/summary endpoint maps it to a clean 404.
     """
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2
+    from jax.profiler import ProfileData
 
     paths = sorted(
         glob.glob(f"{log_dir}/**/*.xplane.pb", recursive=True),
@@ -170,25 +227,37 @@ def summarize_xplane(log_dir: str) -> Dict[str, object]:
             f"no .xplane.pb capture under {log_dir!r}"
         )
     path = paths[-1]
-    space = xplane_pb2.XSpace()
-    with open(path, "rb") as f:
-        space.ParseFromString(f.read())
     device_ms: Dict[str, float] = collections.defaultdict(float)
     host_ms: Dict[str, float] = collections.defaultdict(float)
-    for plane in space.planes:
+    loop: List[Tuple[str, float, float]] = []
+    ops: List[Interval] = []  # of the first device plane that has any
+    for plane in ProfileData.from_file(path).planes:
         is_device = any(t in plane.name for t in ("TPU", "GPU"))
-        names = {k: v.name for k, v in plane.event_metadata.items()}
         for line in plane.lines:
+            if is_device and line.name == "XLA Ops" and not ops:
+                ops = [
+                    (e.start_ns, e.start_ns + e.duration_ns)
+                    for e in line.events
+                ]
             if is_device and line.name != "XLA Modules":
                 continue  # per-op lines double-count their module
+            sink = device_ms if is_device else host_ms
             for e in line.events:
-                prog = normalize_program_name(
-                    names.get(e.metadata_id, "")
-                )
-                if prog is None:
+                name = e.name
+                label = None
+                if name == DISPATCH_EVENT:
+                    label = "in dispatch"
+                elif name.startswith(LOOP_PREFIX):
+                    label = name[len(LOOP_PREFIX):]
+                if label is not None and not is_device:
+                    loop.append(
+                        (label, e.start_ns, e.start_ns + e.duration_ns)
+                    )
                     continue
-                sink = device_ms if is_device else host_ms
-                sink[prog] += e.duration_ps / 1e9
+                prog = normalize_program_name(name)
+                if prog is not None:
+                    sink[prog] += e.duration_ns / 1e6
+    busy_ns, gaps = busy_and_gaps(ops)
     programs = sorted(set(device_ms) | set(host_ms))
     return {
         "xplane": path,
@@ -201,6 +270,12 @@ def summarize_xplane(log_dir: str) -> Dict[str, object]:
         },
         "total_device_ms": round(sum(device_ms.values()), 3),
         "total_host_ms": round(sum(host_ms.values()), 3),
+        "busy_ms": round(busy_ns / 1e6, 3),
+        "idle_ms": round(sum(e - s for s, e in gaps) / 1e6, 3),
+        "idle_by_phase_ms": {
+            k: round(v / 1e6, 3)
+            for k, v in sorted(split_by_overlap(gaps, loop).items())
+        },
     }
 
 

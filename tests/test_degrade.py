@@ -545,7 +545,12 @@ def test_drain_finishes_inflight_and_503s_new(model, reference):
 
         t = threading.Thread(target=call)
         t.start()
-        time.sleep(0.15)
+        # Mid-flight means admitted: on a starved core the POST can take
+        # longer than any fixed sleep to reach the loop, and a drain
+        # that starts first finds the server idle and closes it.
+        deadline = time.monotonic() + 60.0
+        while not srv._active and time.monotonic() < deadline:
+            time.sleep(0.005)
         srv.begin_drain()
         try:
             _post(srv.address, {"prompt": [1, 2], "max_new_tokens": 2})

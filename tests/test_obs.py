@@ -22,6 +22,7 @@ from jax_llama_tpu import get_config, init_params
 from jax_llama_tpu.obs import (
     HISTOGRAMS,
     LABELED_HISTOGRAMS,
+    LOOP_PHASES,
     METRICS,
     CostModelCache,
     Histogram,
@@ -123,6 +124,214 @@ def test_metric_registry_shape():
                 "host_overhead_ratio", "jit_cache_entries",
                 "program_compiles_total", "compiles_total"):
         assert metric_meta(fam) is not None, fam
+
+
+# ---------------------------------------------------------------------------
+# Loop phases: the gap before a dispatch record, by what the loop thread did
+# ---------------------------------------------------------------------------
+
+def _assert_tiles(recs, tol=0.01):
+    """Every record after the first carries its gap; the phases sum to
+    it, and it is this record's start less the previous record's end."""
+    assert "gap_ms" not in recs[0] and "host_ms" not in recs[0]
+    for prev, rec in zip(recs, recs[1:]):
+        assert abs(sum(rec["host_ms"].values()) - rec["gap_ms"]) <= tol, rec
+        prev_end = prev["start_ms"] + prev["wall_ms"]
+        assert abs(rec["start_ms"] - prev_end - rec["gap_ms"]) <= tol, rec
+        assert set(rec["host_ms"]) <= LOOP_PHASES
+
+
+def _one_iteration(obs, clk, kind="decode", idle=0.0):
+    """One serving-loop iteration on a fake clock: 1 ms in each server
+    phase, 2 ms in each scheduler phase, a 100 ms dispatch."""
+    for phase in ("control", "intake"):
+        obs.loop_phase(phase)
+        clk.advance(0.001)
+    if idle:
+        obs.loop_phase("idle")
+        clk.advance(idle)
+        obs.loop_phase("intake")
+    for phase in ("admit", "prep"):
+        obs.loop_phase(phase)
+        clk.advance(0.002)
+    obs.dispatch_begin(kind, "_paged_decode_chunk", 8)
+    clk.advance(0.1)
+    seq = obs.record_dispatch(kind, k=8, wall_ms=100.0, then="emit")
+    clk.advance(0.002)
+    obs.loop_phase("deliver")
+    clk.advance(0.001)
+    return seq
+
+
+def test_loop_phases_tile_the_gap():
+    clk = FakeClock()
+    obs = Observability(clock=clk)
+    _one_iteration(obs, clk)
+    _one_iteration(obs, clk, idle=0.05)
+    _one_iteration(obs, clk, kind="fused")
+    recs = obs.dispatches_json()["dispatches"]
+    _assert_tiles(recs)
+    assert recs[1]["host_ms"] == pytest.approx({
+        "emit": 2.0, "deliver": 1.0, "control": 1.0, "intake": 1.0,
+        "idle": 50.0, "admit": 2.0, "prep": 2.0,
+    })
+    assert recs[1]["gap_ms"] == pytest.approx(59.0)
+    # Idle is part of the tiling and absent where the loop never waited.
+    assert "idle" not in recs[2]["host_ms"]
+    assert recs[2]["gap_ms"] == pytest.approx(9.0)
+    # The loop thread's CPU time over the gap rides along (a fake clock
+    # does not move it: real, tiny, never negative).
+    assert 0.0 <= recs[2]["gap_cpu_ms"] < 50.0
+    assert recs[2]["compiles"] == 0
+    # Every consumer of the record sees the same fields.
+    seen = []
+    obs.on_dispatch = seen.append
+    _one_iteration(obs, clk)
+    assert seen[0]["host_ms"] and seen[0]["gap_ms"] == pytest.approx(9.0)
+
+
+def test_compiles_since_the_previous_record_ride_the_record():
+    clk = FakeClock()
+    obs = Observability(clock=clk)
+    _one_iteration(obs, clk)
+    obs.record_compile("_fused_chunk", 1200.0)
+    obs.record_compile("_fused_chunk", 800.0)
+    _one_iteration(obs, clk)
+    _one_iteration(obs, clk)
+    assert [d["compiles"] for d in obs.dispatches] == [0, 2, 0]
+
+
+@pytest.mark.parametrize("call", [
+    lambda o: o.loop_phase("deliverr"),
+    lambda o: o.record_dispatch("decode", then="emitt"),
+])
+def test_unknown_loop_phase_raises(call):
+    """A typo must not mint a phantom phase (as record_dispatch's kinds)."""
+    with pytest.raises(ValueError, match="unknown loop phase"):
+        call(Observability(clock=FakeClock()))
+
+
+def test_record_without_dispatch_begin_still_tiles():
+    """A direct caller of record_dispatch (no dispatch_begin): the open
+    phase ran up to the record's start, wall_ms before now."""
+    clk = FakeClock()
+    obs = Observability(clock=clk)
+    obs.record_dispatch("insert", wall_ms=1.0)
+    obs.loop_phase("admit")
+    clk.advance(0.010)
+    obs.record_dispatch("insert", wall_ms=4.0)
+    clk.advance(0.003)
+    obs.loop_phase("prep")
+    clk.advance(0.007)
+    obs.record_dispatch("decode", wall_ms=5.0, then="emit")
+    first, second, third = obs.dispatches
+    assert "gap_ms" not in first  # nothing before it
+    assert second["host_ms"] == pytest.approx({"admit": 6.0})
+    assert third["host_ms"] == pytest.approx({"admit": 3.0, "prep": 2.0})
+    assert third["gap_ms"] == pytest.approx(5.0)
+
+
+def test_abandoned_dispatch_returns_its_time_to_the_phase():
+    """A dispatch that raised never records: the time since its begin
+    goes back to the phase it interrupted, and the tiling holds."""
+    clk = FakeClock()
+    obs = Observability(clock=clk)
+    _one_iteration(obs, clk)
+    obs.loop_phase("prep")
+    clk.advance(0.002)
+    obs.dispatch_begin("decode", "_paged_decode_chunk", 8)
+    clk.advance(0.030)          # ... and the dispatch raises
+    obs.loop_phase("control")   # the server's recovery
+    clk.advance(0.004)
+    _one_iteration(obs, clk)
+    recs = obs.dispatches_json()["dispatches"]
+    _assert_tiles(recs)
+    assert recs[1]["host_ms"]["prep"] == pytest.approx(2.0 + 30.0 + 2.0)
+    assert recs[1]["host_ms"]["control"] == pytest.approx(4.0 + 1.0)
+
+
+def test_loop_metrics_registered_and_folded_at_records():
+    for fam in ("loop_phase_ms_total", "loop_gap_ms_total",
+                "loop_gap_cpu_ms_total"):
+        assert metric_meta(fam)[0] == "counter", fam
+    clk = FakeClock()
+    obs = Observability(clock=clk)
+    _one_iteration(obs, clk)
+    _one_iteration(obs, clk, idle=0.02)
+    m = obs.metrics()
+    assert m["loop_gap_ms_total"] == pytest.approx(29.0)
+    assert m["loop_gap_cpu_ms_total"] >= 0.0
+    phases = {
+        lab["phase"]: v for fam, lab, v in obs.loop_phase_metrics()
+        if fam == "loop_phase_ms_total"
+    }
+    assert phases["idle"] == pytest.approx(20.0)
+    assert sum(phases.values()) == pytest.approx(m["loop_gap_ms_total"])
+
+
+def test_trace_json_serving_loop_track():
+    clk = FakeClock()
+    obs = Observability(clock=clk)
+    _one_iteration(obs, clk)
+    _one_iteration(obs, clk)
+    doc = obs.trace_json()
+    tracks = {
+        e["args"]["name"]: e["tid"] for e in doc["traceEvents"]
+        if e.get("ph") == "M" and e.get("name") == "thread_name"
+    }
+    assert "serving loop" in tracks
+    loop = [e for e in doc["traceEvents"] if e.get("cat") == "loop"]
+    assert {e["tid"] for e in loop} == {tracks["serving loop"]}
+    assert {e["name"] for e in loop} >= {
+        "control", "intake", "admit", "prep", "emit", "deliver",
+    }
+    # The phases lie between the dispatch spans, never inside one.
+    disp = sorted(
+        (e["ts"], e["ts"] + e["dur"]) for e in doc["traceEvents"]
+        if e.get("cat") == "dispatch"
+    )
+    for e in loop:
+        assert not any(
+            a < e["ts"] + e["dur"] / 2 < b for a, b in disp
+        ), e
+    # The dispatch spans carry the gap too.
+    args = [e["args"] for e in doc["traceEvents"]
+            if e.get("cat") == "dispatch"]
+    assert "gap_ms" in args[1] and "host_ms" in args[1]
+    # An old-enough horizon drops the first iteration's phases.
+    clk.advance(10.0)
+    _one_iteration(obs, clk)
+    recent = [e for e in obs.trace_json(window_ms=5000.0)["traceEvents"]
+              if e.get("cat") == "loop"]
+    assert 0 < len(recent) < len(loop)
+
+
+def test_received_span_opens_the_timeline():
+    """submit(received_at=...) starts the timeline where the server's
+    TTFT clock does; ``queued`` keeps its meaning and its histogram."""
+    clk = FakeClock()
+    obs = Observability(clock=clk)
+    t_post = clk()
+    clk.advance(0.075)  # inbox + class queue
+    obs.request_queued(3, prompt_tokens=8, received_at=t_post)
+    clk.advance(0.020)
+    obs.begin_span(3, "prefilling")
+    clk.advance(0.150)
+    obs.begin_span(3, "decoding")
+    spans = obs.timeline_json("r3")["spans"]
+    assert [sp["state"] for sp in spans] == [
+        "received", "queued", "prefilling", "decoding",
+    ]
+    assert [sp["duration_ms"] for sp in spans[:3]] == pytest.approx(
+        [75.0, 20.0, 150.0]
+    )
+    assert spans[0]["end_ms"] == spans[1]["start_ms"]
+    assert obs.hist["queue_wait_ms"].sum == pytest.approx(20.0)
+    # Without received_at (a direct submit) nothing changes.
+    obs.request_queued(4, prompt_tokens=8)
+    assert [sp["state"] for sp in obs.timeline_json("r4")["spans"]] == [
+        "queued"
+    ]
 
 
 # ---------------------------------------------------------------------------
@@ -790,6 +999,99 @@ def test_spec_admission_span_lifecycle(model):
         if d["seq"] in dec["dispatches"]
     ]
     assert spec and all(d["kind"] == "spec" for d in spec)
+
+
+@pytest.mark.parametrize("budget", [32, 0], ids=["fused", "classic"])
+def test_batcher_marks_the_scheduler_phases(model, budget):
+    """Driven without a server, the batcher's own phases tile every
+    gap between its dispatch records (real clock): ``admit``, ``prep``
+    and ``emit`` are in every steady record, and the same phases are
+    marked whether a prompt rides the fused lane or a classic insert."""
+    params, config = model
+    cb = ContinuousBatcher(params, config, n_slots=2, max_len=128,
+                           decode_chunk=4, prefill_budget=budget)
+    cb.submit(list(np.random.RandomState(0).randint(1, 128, 9)),
+              max_new_tokens=40)
+    for _ in range(3):
+        cb.step()
+    cb.submit(list(np.random.RandomState(1).randint(1, 128, 40)),
+              max_new_tokens=6)
+    cb.run_to_completion()
+    recs = cb.obs.dispatches_json(512)["dispatches"]
+    _assert_tiles(recs)
+    kinds = {d["kind"] for d in recs}
+    assert ("fused" in kinds) == bool(budget) and "insert" in kinds
+    steady = [  # a chunk behind a chunk: emit, admit, prep, dispatch
+        b for a, b in zip(recs, recs[1:])
+        if {a["kind"], b["kind"]} <= {"decode", "fused"}
+    ]
+    assert steady
+    for d in steady:
+        assert {"admit", "prep", "emit"} <= set(d["host_ms"]), d
+        assert "gap_cpu_ms" in d and "compiles" in d
+    # No server drove this batcher: none of its phases appear.
+    assert not {"control", "intake", "idle", "deliver"} & {
+        p for d in recs[1:] for p in d["host_ms"]
+    }
+    # A chunk right behind a classic insert pays the error barrier.
+    after_insert = [
+        b for a, b in zip(recs, recs[1:])
+        if a["kind"] == "insert" and b["kind"] == "decode"
+    ]
+    assert all("barrier" in d["host_ms"] for d in after_insert)
+
+
+def test_profiler_capture_holds_the_loop_on_the_trace_clock(model, tmp_path):
+    """With a jax.profiler session open, every phase and dispatch is a
+    host event of the capture: ``llm.dispatch`` carries the ring
+    number of its record (joined by identity, not by time) and the
+    ``llm.loop.*`` events lie between the dispatch events."""
+    from jax.profiler import ProfileData
+
+    params, config = model
+    cb = ContinuousBatcher(params, config, n_slots=2, max_len=128,
+                           decode_chunk=4, prefill_budget=32)
+    cb.submit([5, 6, 7, 8, 9], max_new_tokens=40)
+    for _ in range(3):
+        cb.step()  # compiled and steady before the capture
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        first = cb.obs.dispatches[-1]["seq"] + 1
+        for _ in range(4):
+            cb.step()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.glob("**/*.xplane.pb"))
+    events = [
+        e for plane in ProfileData.from_file(str(path)).planes
+        for line in plane.lines for e in line.events
+        if e.name.startswith("llm.")
+    ]
+    disp = sorted(
+        (e for e in events if e.name == "llm.dispatch"),
+        key=lambda e: e.start_ns,
+    )
+    ring = {d["seq"]: d for d in cb.obs.dispatches}
+    assert [dict(e.stats)["seq"] for e in disp] == list(
+        range(first, first + 4)
+    )
+    for e in disp:
+        st = dict(e.stats)
+        rec = ring[st["seq"]]
+        assert (st["kind"], st["program"], st["k"]) == (
+            rec["kind"], rec["program"], rec["k"]
+        )
+        # One clock for both: the event is as long as its record.
+        assert e.duration_ns / 1e6 == pytest.approx(rec["wall_ms"], abs=1.0)
+    phases = [e for e in events if e.name.startswith("llm.loop.")]
+    assert {e.name for e in phases} >= {
+        "llm.loop.admit", "llm.loop.prep", "llm.loop.emit",
+    }
+    for e in phases:
+        mid = e.start_ns + e.duration_ns / 2
+        assert not any(
+            d.start_ns < mid < d.start_ns + d.duration_ns for d in disp
+        )
 
 
 def test_failed_request_timeline_records_error(model):

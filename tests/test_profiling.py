@@ -57,3 +57,50 @@ def test_normalize_program_name():
     assert normalize_program_name("%fusion.12") is None
     assert normalize_program_name("Thread dispatch") is None
     assert normalize_program_name("") is None
+
+
+def test_busy_is_a_union_and_idle_splits_by_overlap():
+    """Device busy time is the UNION of op intervals (ops nest), idle
+    the gaps inside it; a gap is named by the loop-thread intervals it
+    overlaps and the rest is ``unnamed``."""
+    from jax_llama_tpu.utils.profiling import busy_and_gaps, split_by_overlap
+
+    busy, gaps = busy_and_gaps([(0, 10), (2, 5), (12, 15), (20, 21)])
+    assert busy == 14 and gaps == [(10, 12), (15, 20)]
+    assert busy_and_gaps([]) == (0.0, [])
+    named = [("emit", 9, 11), ("deliver", 11, 16), ("in dispatch", 18, 30)]
+    assert split_by_overlap(gaps, named) == {
+        "emit": 1.0, "deliver": 2.0, "in dispatch": 2.0, "unnamed": 2.0,
+    }
+    assert split_by_overlap(gaps, []) == {"unnamed": 7.0}
+
+
+def test_summarize_xplane_reads_a_capture_with_jax_alone(tmp_path):
+    """summarize_xplane parses with jax.profiler.ProfileData (no
+    TensorFlow protos): host-side time lands on the jitted program, and
+    a CPU capture — no device plane — has no busy, idle or phases."""
+    import pytest
+
+    from jax_llama_tpu.utils.profiling import summarize_xplane
+
+    with pytest.raises(FileNotFoundError):
+        summarize_xplane(str(tmp_path / "nothing"))
+
+    @jax.jit
+    def myprog(x):
+        return (x @ x).sum()
+
+    x = jnp.ones((64, 64))
+    myprog(x).block_until_ready()
+    d = str(tmp_path / "trace")
+    with trace(d):
+        with jax.profiler.TraceAnnotation("llm.loop.prep"):
+            pass
+        with jax.profiler.TraceAnnotation("llm.dispatch", seq=0):
+            myprog(x).block_until_ready()
+    out = summarize_xplane(d)
+    assert out["xplane"].endswith(".xplane.pb")
+    assert out["programs"]["myprog"]["host_ms"] > 0
+    assert out["total_device_ms"] == 0.0
+    assert out["busy_ms"] == 0.0 and out["idle_ms"] == 0.0
+    assert out["idle_by_phase_ms"] == {}

@@ -3,6 +3,7 @@ must match standalone batcher output, and /metrics must expose counters."""
 
 import json
 import threading
+import time
 import urllib.parse
 import urllib.request
 
@@ -27,10 +28,13 @@ def model():
     return params, config
 
 
-def _post(url, payload, timeout=300):
+def _post(url, payload, timeout=300, request_id=None):
+    headers = {"Content-Type": "application/json"}
+    if request_id is not None:
+        headers["X-Request-Id"] = request_id
     req = urllib.request.Request(
         url + "/generate", data=json.dumps(payload).encode(),
-        headers={"Content-Type": "application/json"},
+        headers=headers,
     )
     with urllib.request.urlopen(req, timeout=timeout) as r:
         return r.status, json.loads(r.read())
@@ -873,6 +877,18 @@ def test_metrics_exposition_valid_prometheus(model):
     )
     assert len(cache_progs) == 10  # all registered serving programs
     assert samples["llm_compiles_total"] >= 0
+    # Loop phases: the measured host share of a step, by phase.
+    assert types["llm_loop_phase_ms_total"] == "counter"
+    assert types["llm_loop_gap_ms_total"] == "counter"
+    assert types["llm_loop_gap_cpu_ms_total"] == "counter"
+    phase_ms = {
+        n: v for n, v in samples.items()
+        if n.startswith("llm_loop_phase_ms_total{")
+    }
+    assert 'llm_loop_phase_ms_total{phase="deliver"}' in phase_ms
+    assert sum(phase_ms.values()) == pytest.approx(
+        samples["llm_loop_gap_ms_total"], abs=0.5
+    )
     # SLO gauges present (unset deadlines -> 0 / attainment 1.0).
     assert samples["llm_slo_ttft_ms"] == 0.0
     assert samples["llm_slo_attainment"] == 1.0
@@ -978,7 +994,10 @@ def test_debug_endpoints_and_slo_gauges(model):
         assert tl["request_id"] == "dbg-1"
         assert tl["outcome"] == "finished"
         states = [sp["state"] for sp in tl["spans"]]
-        assert states[0] == "queued" and "decoding" in states
+        # The timeline starts at the POST (``received``), where the
+        # server's own TTFT clock does.
+        assert states[:2] == ["received", "queued"]
+        assert "decoding" in states
         ring = {d["seq"] for d in tl["dispatch_spans"]}
         linked = [s for sp in tl["spans"] for s in sp["dispatches"]]
         assert linked and set(linked) <= ring
@@ -1011,6 +1030,79 @@ def test_debug_endpoints_and_slo_gauges(model):
         assert samples["llm_slo_attainment"] == 1.0
         assert samples["llm_requests_slo_ok_total"] >= 1
         assert samples["llm_goodput_tokens_total"] >= 5
+
+
+@pytest.mark.obs
+def test_served_timeline_starts_at_the_post(model):
+    """A served request's timeline begins with ``received`` (POST
+    accepted -> submit), and ``received + queued + prefilling`` is the
+    server's own TTFT up to one step: the dispatch that emits the first
+    token, its replay and its delivery."""
+    params, config = model
+    cb = ContinuousBatcher(params, config, n_slots=2, max_len=64)
+    with LLMServer(cb) as srv:
+        payload = {"prompt": [7, 8, 9, 10], "max_new_tokens": 5}
+        _post(srv.address, payload, request_id="warm")  # compiles
+        before = srv.obs.hist["ttft_ms"].sum
+        _post(srv.address, payload, request_id="timed")
+        ttft_ms = srv.obs.hist["ttft_ms"].sum - before
+        tl = json.loads(_get(srv.address, "/debug/requests/timed")[1])
+    spans = tl["spans"]
+    assert [sp["state"] for sp in spans[:3]] == [
+        "received", "queued", "prefilling",
+    ]
+    assert spans[0]["end_ms"] == spans[1]["start_ms"]
+    to_first = sum(sp["duration_ms"] for sp in spans[:3])
+    one_step = max(d["wall_ms"] for d in tl["dispatch_spans"]) + 25.0
+    assert 0.0 <= ttft_ms - to_first <= one_step, (ttft_ms, to_first)
+
+
+@pytest.mark.obs
+def test_fused_run_names_every_phase_of_the_gap(model):
+    """Two requests through the live server, the second admitted
+    through the fused lane while the first decodes: the records carry
+    ``emit``, ``deliver``, ``intake``, ``admit`` and ``prep`` (server
+    and scheduler phases on one tiling), every gap sums to its phases,
+    and /metrics and /debug/trace expose the same data."""
+    params, config = model
+    cb = ContinuousBatcher(params, config, n_slots=2, max_len=128,
+                           decode_chunk=4, prefill_budget=32)
+    with LLMServer(cb) as srv:
+        holder = threading.Thread(target=_post, args=(
+            srv.address,
+            {"prompt": list(range(3, 12)), "max_new_tokens": 100},
+        ))
+        holder.start()
+        while not cb.obs.dispatches:  # the holder is decoding
+            time.sleep(0.01)
+        _post(srv.address, {"prompt": list(range(20, 60)),
+                            "max_new_tokens": 6})
+        holder.join(timeout=300)
+        assert not holder.is_alive()
+        recs = json.loads(
+            _get(srv.address, "/debug/dispatches?n=512")[1]
+        )["dispatches"]
+        text = _get(srv.address, "/metrics")[1]
+        doc = json.loads(_get(srv.address, "/debug/trace")[1])
+    assert "fused" in {d["kind"] for d in recs}
+    for d in recs[1:]:
+        assert abs(sum(d["host_ms"].values()) - d["gap_ms"]) <= 0.01, d
+    want = {"emit", "deliver", "intake", "admit", "prep"}
+    full = [d for d in recs[1:] if want <= set(d["host_ms"])]
+    assert full and any(d["kind"] == "fused" for d in full)
+    _, _, samples = _parse_exposition(text)
+    for phase in want | {"control"}:
+        assert samples[
+            f'llm_loop_phase_ms_total{{phase="{phase}"}}'
+        ] > 0.0, phase
+    assert samples["llm_loop_gap_ms_total"] > 0.0
+    names = {
+        e["args"]["name"] for e in doc["traceEvents"]
+        if e.get("ph") == "M" and e.get("name") == "thread_name"
+    }
+    assert "serving loop" in names
+    assert {e["name"] for e in doc["traceEvents"]
+            if e.get("cat") == "loop"} >= want
 
 
 @pytest.mark.obs
@@ -1152,14 +1244,13 @@ def test_debug_profiler_endpoint(model, tmp_path):
 
 
 @pytest.mark.obs
-@pytest.mark.slow
 def test_debug_profile_summary_attributes_programs(model, tmp_path):
     """GET /debug/profile/summary parses the completed xplane session
-    into per-program time attribution: the serving programs the
-    bracketed traffic dispatched appear with nonzero host/device ms.
-    Slow-marked: the xplane proto import (tensorflow.tsl) costs
-    seconds; self-skips where the protos are unavailable."""
-    pytest.importorskip("tensorflow.tsl.profiler.protobuf.xplane_pb2")
+    (``jax.profiler.ProfileData`` — no TensorFlow protos) into
+    per-program time attribution: the serving programs the bracketed
+    traffic dispatched appear with nonzero host/device ms; the device
+    busy/idle split and the idle-by-phase attribution are present (and
+    empty on a CPU capture, which has no device plane)."""
     params, config = model
     cb = ContinuousBatcher(params, config, n_slots=1, max_len=64)
 
@@ -1199,6 +1290,8 @@ def test_debug_profile_summary_attributes_programs(model, tmp_path):
     )
     assert attributed > 0
     assert summary["total_host_ms"] + summary["total_device_ms"] > 0
+    assert summary["busy_ms"] == 0.0 and summary["idle_ms"] == 0.0
+    assert summary["idle_by_phase_ms"] == {}
 
 
 def test_http_overload_refusal_503_carries_retry_after(model):
