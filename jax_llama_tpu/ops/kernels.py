@@ -130,10 +130,12 @@ def resolve_prefill_kernel(name: Optional[str], config) -> str:
 
 def resolve_decode_kernel(name: Optional[str], config) -> str:
     """Map a CLI/ctor decode-kernel name to a concrete kernel name.
-    Auto resolves to the custom paged kernel: it keeps int8 pools,
-    multi-token (speculative verify) sweeps, and the measured
-    one-cell-per-block grid; stock-paged is the A/B alternative until a
-    TPU round shows it ahead."""
+    Auto resolves to the custom paged kernel: it keeps int8 pools and
+    multi-token (speculative verify) sweeps, and its grid of live
+    multi-block steps measured level with or ahead of stock-paged at
+    every served geometry but an all-idle batch (v5e, PERF.md section 6,
+    PR 25); stock-paged stays as the A/B alternative until ROADMAP C4
+    decides."""
     name = name or "auto"
     if name == "auto":
         return "paged"

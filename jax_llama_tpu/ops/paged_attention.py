@@ -13,13 +13,24 @@ the BlockSpec index_map, the DMA pipeline does the pointer-chasing).
 
 Layout contract: the pool is [KVH, NB, BLK, hd] per layer — KV-head
 major, so a block's tile is a clean ``(KVH, BLK, hd)`` VMEM page.
-Grid is ``(B, MB)`` with the per-row block sweep innermost; ONE grid
-cell covers all KV heads of a block via a statically-unrolled in-kernel
-loop (a finer (B, KVH, MB) grid was measured SLOWER than the gathered
-view it replaces — per-cell overhead beat the bandwidth saving).
-Online softmax state lives in VMEM scratch across the sweep, exactly
-like ``ops.flash_attention``.  GQA: the ``group`` query heads of each
-KV head ride the sublane axis of that head's q rows (padded to 8), so
+The grid is ONE dimension whose length is a value, not a shape: the
+live steps of each row in turn, a step being ``P`` consecutive table
+entries of the row (``_blocks_per_step``: about 512 tokens, so 4 at
+128-token blocks and 1 at 512).  The pool is passed ``P`` times, entry
+``j`` of a step through operand ``j``, so Pallas's own pipeline still
+does the pointer chasing and the double buffering; ``_fetch_plan``
+builds the step list and what each operand names in every step, and a
+dead entry names the block its operand already holds, so no dead block
+is ever fetched.  A call therefore costs what its live blocks cost — a
+``(B, MB)`` grid of one block a step paid ~0.39 µs for each of its dead
+steps and ~1.55 µs for a live one whose DMA is 0.64 µs (v5e, PERF.md
+section 6, PR 25).  One grid step covers all KV heads of its blocks via
+statically unrolled in-kernel loops (a finer (B, KVH, MB) grid was
+measured SLOWER than the gathered view it replaces — per-cell overhead
+beat the bandwidth saving).  Online softmax state lives in VMEM scratch
+across a row's steps, exactly like ``ops.flash_attention``, and moves
+once per (KV head, step).  GQA: the ``group`` query heads of each KV
+head ride the sublane axis of that head's q rows (padded to 8), so
 decode reads each KV block once — never per query head.
 
 The kernel attends the POOL only and emits a normalized output plus the
@@ -28,16 +39,18 @@ always attendable) at the scores level — the same two-source softmax
 split as ``ops.attention.sdpa_cached``, so the pool stays immutable
 through the layer scan and the decode step applies one scatter per step.
 
-Scan compatibility: everything dynamic the kernel consumes — the block
-table, per-row query positions, the derived live-block grid bounds, the
-layer index, and the pool planes themselves — enters as traced operands
-(scalar-prefetch or BlockSpec-mapped), so the whole op nests inside
-``lax.scan`` loops without re-tracing: the model's layer scan selects
-planes via ``layer``, and serving's fused decode chunk
+Scan compatibility: everything dynamic the kernel consumes — the step
+list derived from the block table and the per-row query positions, its
+length (the grid bound), the layer index, and the pool planes
+themselves — enters as traced operands (scalar-prefetch, grid bound or
+BlockSpec-mapped), so the whole op nests inside ``lax.scan`` loops
+without re-tracing: the model's layer scan selects planes via
+``layer`` (the step list does not depend on it, so XLA hoists its
+derivation out of that scan), and serving's fused decode chunk
 (``serving._paged_decode_chunk``) additionally scans K decode
-iterations around the layer scan, re-deriving positions/bounds per
-iteration on device.  Under a mesh the shard_map wrapper nests inside
-those scans the same way.
+iterations around the layer scan, re-deriving positions and the step
+list per iteration on device.  Under a mesh the shard_map wrapper nests
+inside those scans the same way.
 
 Fused prefill-decode scheduling (``serving._fused_chunk``) runs this
 kernel's decode scan WHILE an admission's prompt is mid-prefill in the
@@ -45,8 +58,9 @@ same dispatch: the prefilling row rides the decode grid masked (its
 query position is -1 until its last prompt chunk lands, so it attends
 nothing and its write-back resolves to the sentinel block and drops) —
 the standard idle-row contract, no new kernel case.  Its partially
-written blocks are safe for the OTHER rows by construction: the table
-walk only visits each row's own blocks.
+written blocks are safe for the OTHER rows by construction: only a
+row's own blocks ever carry weight in its softmax (a dead entry may
+hold another row's block in VMEM, masked to zero and dropped).
 """
 
 from __future__ import annotations
@@ -75,142 +89,183 @@ def _maybe_fault() -> None:
     fire_trace("paged_kernel")
 
 
+# One grid step covers several table entries of a row.  Each step pays a
+# fixed cost (grid bookkeeping, every operand's index map and DMA issue,
+# the softmax state's read-modify-write) that at one 128-token block a
+# step was twice the block's own HBM time, so a step should hold about
+# this many tokens.  More is worse: the dead entries of a row's last
+# step are computed (and masked), so short rows pay for the width — at
+# 128-token blocks 4 a step measured best from 2 to 8 KV heads, 16 a
+# step twice as slow (PERF.md section 6, PR 25).  The unroll cap bounds
+# the kernel body (KV heads x entries dots of each kind), the buffer cap
+# the VMEM the pipeline's double buffers take (twice this).
+_STEP_TOKENS = 512
+_STEP_BUFFER_BYTES = 4 << 20
+_STEP_UNROLL = 32
+
+
+def _blocks_per_step(blk: int, mb: int, kvh: int, d: int, itemsize: int) -> int:
+    """Table entries ``P`` one grid step covers, from the shapes alone.
+
+    Enough blocks that a step holds ``_STEP_TOKENS`` tokens, capped by
+    the unrolled body, by VMEM and by the row's table; then evened out
+    over the ``ceil(mb / P)`` steps a row takes, so the sentinel padding
+    of the last step is the least it can be.  128-token blocks give 4; a
+    512-token block gives 1 (the one-block step this kernel had before,
+    already near its streaming floor there).
+    """
+    block_bytes = 2 * kvh * blk * d * itemsize
+    cap = min(_STEP_UNROLL // kvh, _STEP_BUFFER_BYTES // block_bytes, mb)
+    p = max(1, min(-(-_STEP_TOKENS // blk), cap))
+    return -(-mb // -(-mb // p))
+
+
+# Scalar-prefetched per-step flags (``_fetch_plan``).
+_FIRST, _LAST, _LIVE = 1, 2, 4
+
+
 def _paged_kernel(
-    tbl_ref,    # [B * MB] int32 scalar-prefetch: physical block id (NB = dead)
+    fetch_ref,  # [S * P] int32 scalar-prefetch: block id of entry j of grid
+    #             step t if >= 0; -1 - (the block that operand already
+    #             holds) if the entry is dead
+    flag_ref,   # [S] int32 scalar-prefetch: _FIRST | _LAST step of its row,
+    #             _LIVE if any entry is live
+    src_ref,    # [S] int32 scalar-prefetch: b * NS + s, the step's row and
+    #             its place in the row (and in the position plane)
     qpos_ref,   # [B] int32 scalar-prefetch: FIRST token's query position
     #             (-1 = inactive row; token t sits at qpos + t)
-    bound_ref,  # [B] int32 scalar-prefetch: live-block grid bound per row
     layer_ref,  # [1] int32 scalar-prefetch: pool layer this call reads
     q_ref,      # [1, KVH, TG8, d] — sublane row r = t*group + g
-    k_ref,      # [1, KVH, 1, BLK, d] (int8 when quantized)
-    v_ref,      # [1, KVH, 1, BLK, d] (int8 when quantized)
-    pos_ref,    # [1, 1, BLK] int32 slot positions of the block
-    *rest,      # [k_scale_ref, v_scale_ref] when quantized
-    #             ([1, KVH, 1, 1, BLK] fp32); o_ref; lse_ref; scratch
+    *rest,      # P k refs, P v refs [1, KVH, 1, BLK, d] (int8 when
+    #             quantized); pos ref [1, P, BLK] int32 (-1 = masked slot);
+    #             when quantized P k-scale and P v-scale refs
+    #             [1, KVH, 1, 1, BLK] fp32; o_ref; lse_ref; scratch m, l, acc
     scale: float,
-    n_blocks: int,
+    n_entries: int,
+    row_steps: int,
     kvh: int,
     tg8: int,
     t_tokens: int,
     group: int,
     quantized: bool = False,
 ):
-    """Online-softmax sweep of one row's pool blocks.
+    """Online-softmax sweep of one row's pool blocks, ``n_entries`` table
+    entries a grid step.
+
+    The softmax state moves once per (KV head, step): the step's entries
+    share one running max, one ``exp`` rescale of ``acc`` and one write
+    of the three scratch planes.  A dead entry inside a live step (past
+    the row's last attendable block, a sentinel, an all-masked block)
+    holds whatever block its operand fetched last — possibly another
+    row's — so it is masked to exactly zero weight and its P·V product
+    is dropped whole (0 x a non-finite stale value would not be 0).
 
     ``t_tokens`` queries per (row, query head) ride the sublane axis
     (row r = t*group + g); their positions are CONSECUTIVE — token t at
     ``qpos + t`` — so per-token masks derive from a sublane iota and no
-    per-token position plane is needed.  T=1 keeps the original
-    whole-tile skip for fully-masked tiles; T>1 additionally zeroes
-    masked probabilities explicitly, because one tile can be live for a
-    late token but fully masked for an early one (the skip guard is
-    per-tile, not per-sublane).
+    per-token position plane is needed.  T=1 needs no explicit zeroing:
+    a live step holds a slot its one query may attend, so the step's max
+    is a real score and every masked ``exp`` underflows to 0.  T>1 zeroes
+    masked probabilities explicitly, because an entry can be live for a
+    late token but fully masked for an early one.
     """
+    P = n_entries
+    k_refs, v_refs, pos_ref = rest[:P], rest[P:2 * P], rest[2 * P]
+    rest = rest[2 * P + 1:]
     if quantized:
-        k_scale_ref, v_scale_ref, *rest = rest
-    else:
-        k_scale_ref = v_scale_ref = None
+        k_scale_refs, v_scale_refs, rest = rest[:P], rest[P:2 * P], rest[2 * P:]
     o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
-    b = pl.program_id(0)
-    mb = pl.program_id(1)
-    nmb = pl.num_programs(1)
+    step = pl.program_id(0)
+    flags = flag_ref[step]
 
-    @pl.when(mb == 0)
+    @pl.when(flags & _FIRST != 0)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, MASK_VALUE)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    qp = qpos_ref[b]
-    qp_last = qp + t_tokens - 1
-    kp = pos_ref[0, :1, :]  # [1, BLK]
-    # Three dead-block guards, all mandatory:
-    #   * mb >= bound: past the row's last attendable block — the index
-    #     maps clamped the fetch (no new DMA); the tile is a repeat.
-    #   * table sentinel / inactive row.
-    #   * all-masked tile (min live kp > last token's position):
-    #     processing it would add p = exp(MASK - MASK) = 1 garbage into
-    #     l/acc — the block must be SKIPPED, not merely masked (same
-    #     invariant as flash block_live).
-    live_kp = jnp.where(kp >= 0, kp, jnp.iinfo(jnp.int32).max)
-    live = (
-        (mb < bound_ref[b])
-        & (tbl_ref[b * nmb + mb] < n_blocks)
-        & (qp >= 0)
-        & (jnp.min(live_kp) <= qp_last)
-    )
-
-    if t_tokens > 1:
-        # Per-sublane query position: row r holds token r // group.
-        # (Pad rows past t_tokens*group get later tokens' looser masks;
-        # their q rows are zero-padding and their outputs are sliced off.)
-        qp_rows = qp + jax.lax.broadcasted_iota(
-            jnp.int32, (tg8, 1), 0
-        ) // group  # [TG8, 1]
-    else:
-        qp_rows = None
-
-    @pl.when(live)
+    # The grid holds a row's live steps only (and one step of a row that
+    # has none, which stops at this scalar compare).  Skipping is
+    # mandatory, not an optimisation: a step with no attendable slot
+    # would add p = exp(MASK - MASK) = 1 garbage into l/acc (same
+    # invariant as flash block_live).
+    @pl.when(flags & _LIVE != 0)
     def _compute():
-        # One grid cell covers ALL KV heads of the block (the loop
-        # unrolls statically): grid cells are B × MB, not B × KVH × MB —
-        # measured ~1 µs of per-cell overhead made the finer grid SLOWER
-        # than the gathered-view fallback it replaces.
+        qp = qpos_ref[src_ref[step] // row_steps]
+        if t_tokens > 1:
+            # Per-sublane query position: row r holds token r // group.
+            # (Pad rows past t_tokens*group get later tokens' looser
+            # masks; their q rows are zero-padding, outputs sliced off.)
+            qp = qp + jax.lax.broadcasted_iota(
+                jnp.int32, (tg8, 1), 0
+            ) // group  # [TG8, 1]
+        entry_ok, allowed = [], []
+        for j in range(P):
+            kp = pos_ref[0, j:j + 1, :]  # [1, BLK]; a dead entry's is all -1
+            entry_ok.append(fetch_ref[step * P + j] >= 0)
+            allowed.append((kp >= 0) & (kp <= qp))  # [1 | TG8, BLK]
+        # One grid step covers ALL KV heads of its blocks (the loops
+        # unroll statically): measured ~1 µs of per-cell overhead made a
+        # (B, KVH, MB) grid SLOWER than the gathered-view fallback.
         for h in range(kvh):
             sl = slice(h * tg8, (h + 1) * tg8)
             q = q_ref[0, h]
-            if quantized:
-                # int8 pool: cast the tile in VMEM (int8 magnitudes are
-                # exact in bf16) and fold the per-slot dequant scales at
-                # the scores / probability level — the same commuting
-                # trick as flash_attention_quantized, so HBM streams the
-                # int8 bytes.
-                k = k_ref[0, h, 0].astype(q.dtype)
-                ksc = k_scale_ref[0, h, 0, :1, :]  # [1, BLK] fp32
-            else:
-                k = k_ref[0, h, 0]
-                ksc = None
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # [TG8, BLK]
-            if quantized:
-                s = s * ksc
-            if t_tokens > 1:
-                allowed = (kp >= 0) & (kp <= qp_rows)  # [TG8, BLK]
-            else:
-                allowed = (kp >= 0) & (kp <= qp)       # [1, BLK]
-            s = jnp.where(allowed, s, MASK_VALUE)
+            scores = []
+            for j in range(P):
+                if quantized:
+                    # int8 pool: cast the tile in VMEM (int8 magnitudes
+                    # are exact in bf16) and fold the per-slot dequant
+                    # scales at the scores / probability level — the
+                    # same commuting trick as flash_attention_quantized,
+                    # so HBM streams the int8 bytes.
+                    k = k_refs[j][0, h, 0].astype(q.dtype)
+                else:
+                    k = k_refs[j][0, h, 0]
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale  # [TG8, BLK]
+                if quantized:
+                    s = s * k_scale_refs[j][0, h, 0, :1, :]
+                scores.append(jnp.where(allowed[j], s, MASK_VALUE))
             m_prev = m_ref[sl, :1]
             m_new = jnp.maximum(
-                m_prev, jnp.max(s, axis=-1, keepdims=True)
+                m_prev,
+                jnp.max(
+                    functools.reduce(jnp.maximum, scores),
+                    axis=-1, keepdims=True,
+                ),
             )
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            if t_tokens > 1:
-                # A tile can be live for token T-1 yet fully masked for
-                # token 0: that token's m_new stays MASK_VALUE and
-                # exp(MASK - MASK) = 1 would poison l/acc — zero masked
-                # probabilities explicitly (the T=1 path never hits this:
-                # its one qp makes tile-liveness == row-liveness).
-                p = jnp.where(allowed, p, 0.0)
+            probs, pv = [], None
+            for j in range(P):
+                p = jnp.exp(scores[j] - m_new)
+                if t_tokens > 1:
+                    p = jnp.where(allowed[j], p, 0.0)
+                probs.append(p)
+                if quantized:
+                    pj = (p * v_scale_refs[j][0, h, 0, :1, :]).astype(q.dtype)
+                    vb = v_refs[j][0, h, 0].astype(q.dtype)
+                else:
+                    pj = p.astype(v_refs[j].dtype)
+                    vb = v_refs[j][0, h, 0]
+                term = jax.lax.dot_general(
+                    pj, vb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                if P > 1:
+                    term = jnp.where(entry_ok[j], term, 0.0)
+                pv = term if pv is None else pv + term
             l_ref[sl] = jnp.broadcast_to(
-                alpha * l_ref[sl, :1] + jnp.sum(p, axis=-1, keepdims=True),
+                alpha * l_ref[sl, :1] + jnp.sum(
+                    functools.reduce(jnp.add, probs), axis=-1, keepdims=True
+                ),
                 (tg8, l_ref.shape[1]),
             )
-            if quantized:
-                pv = (p * v_scale_ref[0, h, 0, :1, :]).astype(q.dtype)
-                vb = v_ref[0, h, 0].astype(q.dtype)
-            else:
-                pv = p.astype(v_ref.dtype)
-                vb = v_ref[0, h, 0]
-            acc_ref[sl] = alpha * acc_ref[sl] + jax.lax.dot_general(
-                pv, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
+            acc_ref[sl] = alpha * acc_ref[sl] + pv
             m_ref[sl] = jnp.broadcast_to(m_new, (tg8, m_ref.shape[1]))
 
-    @pl.when(mb == nmb - 1)
+    @pl.when(flags & _LAST != 0)
     def _finalize():
         l = l_ref[:, :1]
         o_ref[0] = (
@@ -225,6 +280,87 @@ def _paged_kernel(
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
+
+
+def _fetch_plan(pool_pos, table, q_pos, t_tokens: int, n_entries: int):
+    """The grid of one call: which (row, step) pairs run, in order, and
+    what each fetches.  Depends on ``(pool_pos, table, q_pos)`` only, not
+    on the layer, so XLA computes it once per decode iteration, outside
+    the model's layer scan.
+
+    A table entry is live when its block holds a slot the row's LAST
+    query may attend (so sentinel entries, the reserved-but-unwritten
+    tail, all-masked blocks and every entry of an inactive row are
+    dead); a step — ``P`` consecutive entries of a row — is live when
+    one of its entries is.  The grid runs the live steps and the first
+    step of every row (a row with no live step still has to write its
+    empty output), rows in order: its length ``n_steps`` is a value, not
+    a shape, so a call costs what its live steps cost.
+
+    The kernel takes entry ``j`` of every step through operand ``j``,
+    whose pipeline skips the DMA when two consecutive grid steps name
+    the same block: a dead entry therefore names the block that operand
+    fetched LAST — in this row or an earlier one — encoded as
+    ``-1 - block`` so the kernel also reads the entry's liveness from
+    it.  No dead block is ever fetched (operands with no live entry yet
+    at the head of the grid name block 0 once).
+
+    Returns ``(n_steps, fetch [S*P], flags [S], src [S], kpos [S, P,
+    BLK])`` with ``S = B * NS`` the most steps a call can take; entries
+    past ``n_steps`` are never read.  ``src`` is the step's place
+    ``b * NS + s`` before the dead steps were dropped: it names the row
+    and indexes ``kpos``, which holds -1 in every slot of a dead entry.
+    """
+    NB, BLK = pool_pos.shape
+    B, MB = table.shape
+    P = n_entries
+    NS = -(-MB // P)
+    S = B * NS
+    imax = jnp.iinfo(jnp.int32).max
+    blk_min = jnp.min(jnp.where(pool_pos >= 0, pool_pos, imax), axis=1)
+    blk_min = jnp.concatenate(
+        [blk_min, jnp.full((1,), imax, jnp.int32)]
+    )  # [NB + 1] min live position per block; sentinel NB never attendable
+    table = jnp.pad(
+        jnp.minimum(table.astype(jnp.int32), NB),
+        ((0, 0), (0, NS * P - MB)), constant_values=NB,
+    ).reshape(S, P)
+    qp = jnp.repeat(q_pos, NS)[:, None]
+    ok = (blk_min[table] <= qp + (t_tokens - 1)) & (qp >= 0)
+    kpos = jnp.where(
+        ok[:, :, None], pool_pos[jnp.minimum(table, NB - 1)], -1
+    )
+    step = jnp.arange(S, dtype=jnp.int32)
+    last_fetch = jax.lax.cummax(jnp.where(ok, step[:, None], -1), axis=0)
+    held = jnp.where(
+        last_fetch >= 0,
+        jnp.take_along_axis(table, jnp.maximum(last_fetch, 0), axis=0),
+        0,
+    )
+    fetch = jnp.where(ok, table, -1 - held)
+
+    live = jnp.any(ok, axis=1)
+    first = step % NS == 0
+    keep = live | first
+    # The row's last kept step: no kept step follows it inside the row.
+    kept_s = jnp.where(keep, step % NS, -1).reshape(B, NS)
+    last = (step % NS) == jnp.repeat(jnp.max(kept_s, axis=1), NS)
+    flags = (
+        jnp.where(first, _FIRST, 0) | jnp.where(last, _LAST, 0)
+        | jnp.where(live, _LIVE, 0)
+    )
+    # Compaction: grid step t is the kept step of rank t.  S is at most a
+    # few hundred, so an S x S compare-and-sum is one small fusion (a
+    # sort of 64 keys measured 47 µs on the v5e, PERF.md section 6).
+    rank = jnp.cumsum(keep, dtype=jnp.int32) - 1
+    src = jnp.sum(
+        jnp.where(keep[None, :] & (rank[None, :] == step[:, None]), step, 0),
+        axis=1,
+    )
+    return (
+        jnp.sum(keep, dtype=jnp.int32), fetch[src].reshape(-1), flags[src],
+        src, kpos,
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("t_tokens", "interpret"))
@@ -301,84 +437,63 @@ def paged_pool_attention(
     qg = jnp.pad(q, ((0, 0), (0, 0), (0, TG8 - TG), (0, 0)))
     scale = 1.0 / (d ** 0.5)
 
-    # Narrow-sublane position plane [NB, 1, BLK]: a free expand_dims
-    # view — Mosaic accepts 1-row tiles here (verified compiled), so no
-    # sublane replication and no per-step materialization is needed.
-    pos_r = pool_pos[:, None, :]
-    tbl_flat = table.astype(jnp.int32).reshape(B * MB)
+    P = _blocks_per_step(BLK, MB, KVH, d, k_pool.dtype.itemsize)
     q_pos = q_pos.astype(jnp.int32)
-    qp_last = q_pos + (t_tokens - 1)
+    NS = -(-MB // P)
+    n_steps, fetch, flags, src, kpos = _fetch_plan(
+        pool_pos, table, q_pos, t_tokens, P
+    )
 
-    # Per-row live-block grid bound: 1 + the last table slot whose block
-    # holds any slot this row's LAST query may attend.  Blocks at/after
-    # the bound (reserved-but-unwritten tail, sentinel entries) are
-    # clamped in the index maps — consecutive grid steps fetch the SAME
-    # tile, so the pipeline skips the DMA — and the kernel skips their
-    # compute.
-    blk_min = jnp.min(
-        jnp.where(pool_pos >= 0, pool_pos, jnp.iinfo(jnp.int32).max),
-        axis=1,
-    )  # [NB] min live position per physical block
-    blk_min = jnp.concatenate(
-        [blk_min, jnp.full((1,), jnp.iinfo(jnp.int32).max, jnp.int32)]
-    )  # sentinel id NB -> never attendable
-    row_min = blk_min[jnp.minimum(table, NB)]  # [B, MB]
-    attendable = row_min <= qp_last[:, None]
-    bound = 1 + jnp.max(
-        jnp.where(
-            attendable, jnp.arange(MB, dtype=jnp.int32)[None, :], -1
-        ),
-        axis=1,
-    )  # [B] in [0, MB]
+    # Index maps; the scalar-prefetch refs follow the grid index in the
+    # kernel's order: fetch, flags, src, qpos, layer.
+    def row_map(t, fetch, flags, src, *_):
+        return (src[t] // NS, 0, 0, 0)
 
-    def _clamp_mb(b, mb, tbl, bound):
-        mb = jnp.minimum(mb, jnp.maximum(bound[b] - 1, 0))
-        return jnp.minimum(tbl[b * MB + mb], NB - 1)
+    def kv_map(j):
+        def index(t, fetch, flags, src, qpos, layer):
+            f = fetch[t * P + j]
+            return (layer[0], 0, jnp.where(f < 0, -1 - f, f), 0, 0)
+        return index
 
-    def kv_map(b, mb, tbl, qpos, bound, layer):
-        return (layer[0], 0, _clamp_mb(b, mb, tbl, bound), 0, 0)
+    def pos_map(t, fetch, flags, src, *_):
+        return (src[t], 0, 0)
 
-    def pos_map(b, mb, tbl, qpos, bound, layer):
-        return (_clamp_mb(b, mb, tbl, bound), 0, 0)
-
-    def q_map(b, mb, tbl, qpos, bound, layer):
-        return (b, 0, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, KVH, TG8, d), q_map),
-        pl.BlockSpec((1, KVH, 1, BLK, d), kv_map),
-        pl.BlockSpec((1, KVH, 1, BLK, d), kv_map),
-        pl.BlockSpec((1, 1, BLK), pos_map),
+    kv_specs = [
+        pl.BlockSpec((1, KVH, 1, BLK, d), kv_map(j)) for j in range(P)
     ]
-    operands = [qg, k_pool, v_pool, pos_r]
+    in_specs = [
+        pl.BlockSpec((1, KVH, TG8, d), row_map), *kv_specs, *kv_specs,
+        pl.BlockSpec((1, P, BLK), pos_map),
+    ]
+    operands = [qg, *[k_pool] * P, *[v_pool] * P, kpos]
     if quantized:
         # Narrow-sublane scale planes [L, KVH, NB, 1, BLK]: free
         # expand_dims views of the long-lived pool scales — NOT sublane-
         # replicated copies, which would re-materialize (and stream) 8x
         # the scale bytes per layer per step on the path this kernel
         # exists to make bandwidth-lean.
-        def scale_map(b, mb, tbl, qpos, bound, layer):
-            return (layer[0], 0, _clamp_mb(b, mb, tbl, bound), 0, 0)
-
-        scale_spec = pl.BlockSpec((1, KVH, 1, 1, BLK), scale_map)
-        in_specs += [scale_spec, scale_spec]
+        scale_specs = [
+            pl.BlockSpec((1, KVH, 1, 1, BLK), kv_map(j)) for j in range(P)
+        ]
+        in_specs += [*scale_specs, *scale_specs]
         operands += [
-            k_scale.astype(jnp.float32)[:, :, :, None, :],
-            v_scale.astype(jnp.float32)[:, :, :, None, :],
+            *[k_scale.astype(jnp.float32)[:, :, :, None, :]] * P,
+            *[v_scale.astype(jnp.float32)[:, :, :, None, :]] * P,
         ]
 
     out, lse = pl.pallas_call(
         functools.partial(
-            _paged_kernel, scale=scale, n_blocks=NB, kvh=KVH, tg8=TG8,
+            _paged_kernel, scale=scale, n_entries=P, row_steps=NS, kvh=KVH,
+            tg8=TG8,
             t_tokens=t_tokens, group=group, quantized=quantized,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(B, MB),
+            num_scalar_prefetch=5,
+            grid=(n_steps,),
             in_specs=in_specs,
             out_specs=(
-                pl.BlockSpec((1, KVH, TG8, d), q_map),
-                pl.BlockSpec((1, KVH, TG8, _LANES), q_map),
+                pl.BlockSpec((1, KVH, TG8, d), row_map),
+                pl.BlockSpec((1, KVH, TG8, _LANES), row_map),
             ),
             scratch_shapes=[
                 pltpu.VMEM((KVH * TG8, _LANES), jnp.float32),
@@ -398,10 +513,10 @@ def paged_pool_attention(
             jax.ShapeDtypeStruct((B, KVH, TG8, _LANES), jnp.float32),
         ),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(tbl_flat, q_pos, bound, layer_arr, *operands)
+    )(fetch, flags, src, q_pos, layer_arr, *operands)
     return out[:, :, :TG, :], lse[:, :, :TG, 0]
 
 

@@ -93,25 +93,50 @@ def test_flash_vjp_compiles_for_v5e(sds):
     _assert_mosaic(grad.lower(*_flash_operands(sds)))
 
 
+# (rows, blocks a row, local KV heads, pool layers): the smoke's geometry,
+# the benchmark's two cells (24-layer Mistral-7B pool of 256 blocks:
+# 16 slots x 2048 and 8 slots x 4096), and what one of four chips holds
+# of Codestral-22B under --serve-mesh 1,4 (8 KV heads / 4, 56 layers).
+_PAGED_SHAPES = {
+    "smoke": (B, MB, KVH, L),
+    "cell1": (16, 16, 8, 24),
+    "cell2": (8, 32, 8, 24),
+    "tp4": (16, 16, 2, 56),
+}
+
+
 @pytest.mark.parametrize(
-    "quantized,t_tokens",
-    [(False, 1), (True, 1), (False, 5)],
-    ids=["bf16", "int8", "bf16-verify-t5"],
+    "shape,quantized,t_tokens",
+    [
+        ("smoke", False, 1), ("smoke", True, 1), ("smoke", False, 5),
+        ("cell1", False, 1), ("cell2", False, 1), ("tp4", False, 1),
+        ("cell1", True, 1), ("cell1", False, 5), ("tp4", True, 5),
+    ],
+    ids=[
+        "bf16", "int8", "bf16-verify-t5",
+        "cell1-b16xmb16", "cell2-b8xmb32", "tp4-kvh2-l56",
+        "cell1-int8", "cell1-verify-t5", "tp4-int8-verify-t5",
+    ],
 )
-def test_paged_decode_compiles_for_v5e(sds, quantized, t_tokens):
+def test_paged_decode_compiles_for_v5e(sds, shape, quantized, t_tokens):
     """The custom paged kernel over a layer-indexed pool: bf16, int8
     (in-kernel scale folding), and the multi-token speculative-verify
-    sweep (t_tokens > 1)."""
+    sweep (t_tokens > 1).  A grid step holds several blocks of K and V,
+    double-buffered, and how many follows from the shapes: the VMEM that
+    takes is what interpret mode cannot see, so every served geometry
+    compiles here."""
     from jax_llama_tpu.ops.paged_attention import paged_pool_attention
 
+    rows, mb, kvh, layers = _PAGED_SHAPES[shape]
+    nb = rows * mb
     G = H // KVH
     pool_dtype = jnp.int8 if quantized else jnp.bfloat16
-    pool = sds((L, KVH, NB, BLK, D), pool_dtype)
-    scale = sds((L, KVH, NB, BLK), jnp.float32) if quantized else None
+    pool = sds((layers, kvh, nb, BLK, D), pool_dtype)
+    scale = sds((layers, kvh, nb, BLK), jnp.float32) if quantized else None
     lowered = paged_pool_attention.lower(
-        sds((B, KVH, t_tokens * G, D), jnp.bfloat16), pool, pool,
-        sds((NB, BLK), jnp.int32), sds((B, MB), jnp.int32),
-        sds((B,), jnp.int32), k_scale=scale, v_scale=scale,
+        sds((rows, kvh, t_tokens * G, D), jnp.bfloat16), pool, pool,
+        sds((nb, BLK), jnp.int32), sds((rows, mb), jnp.int32),
+        sds((rows,), jnp.int32), k_scale=scale, v_scale=scale,
         t_tokens=t_tokens, layer=sds((), jnp.int32), interpret=False,
     )
     _assert_mosaic(lowered)

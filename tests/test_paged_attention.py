@@ -201,6 +201,123 @@ def test_paged_kernel_multi_token_first_token_empty_pool():
         np.testing.assert_allclose(got[b], want, atol=1e-5, rtol=1e-5)
 
 
+def _step_edge_state(rng, MB, pool):
+    """One batch holding every block-count edge of the multi-block grid
+    step: rows of 0, 1, P-1, P, P+1 and MB live blocks (P derived from
+    the shapes, as the kernel derives it), an inactive row that still
+    owns written blocks between live rows, and a row with a sentinel
+    table entry in the middle of a live step.  Returns the operands as
+    the kernel takes them and as the dense reference reads them."""
+    from jax_llama_tpu.models.llama import quantize_kv
+    from jax_llama_tpu.ops.paged_attention import _blocks_per_step
+
+    H, KVH, d, BLK = 16, 8, 32, 16
+    itemsize = {"fp32": 4, "bf16": 2, "int8": 1}[pool]
+    P = _blocks_per_step(BLK, MB, KVH, d, itemsize)
+    assert 1 < P < MB, (P, MB)  # the cases below need several steps a row
+    # (live blocks, active) per row; the hole row is appended below.
+    rows = [(0, True), (1, True), (P - 1, True), (3, False), (P, True),
+            (P + 1, True), (MB, True)]
+    fills = [n * BLK - (5 if n else 0) for n, _ in rows] + [0]
+    B = len(fills)
+    NB = sum(n for n, _ in rows) + P + 2
+    kp, vp, pool_pos, table = _random_pool_state(
+        rng, B, KVH, d, NB, BLK, MB, fills
+    )
+    qpos = np.array(
+        [f if act else -1 for f, (_, act) in zip(fills, rows)] + [0],
+        np.int32,
+    )
+    # Hole row: P + 1 written blocks, the second table entry a sentinel.
+    hole = B - 1
+    blocks = [b for b in range(NB) if b not in set(table.ravel())][:P + 1]
+    slots = [j for j in range(P + 2) if j != 1]
+    for j, blk in zip(slots, blocks):
+        table[hole, j] = blk
+        pool_pos[blk] = np.arange(j * BLK, (j + 1) * BLK)
+    qpos[hole] = (P + 2) * BLK
+    scales = {}
+    if pool == "int8":
+        kq, ks = quantize_kv(jnp.asarray(kp))
+        vq, vs = quantize_kv(jnp.asarray(vp))
+        scales = dict(k_scale=ks, v_scale=vs)
+        kp = np.asarray(kq, np.float32) * np.asarray(ks)[..., None]
+        vp = np.asarray(vq, np.float32) * np.asarray(vs)[..., None]
+        k_dev, v_dev = kq, vq
+    elif pool == "bf16":
+        k_dev, v_dev = (jnp.asarray(x, jnp.bfloat16) for x in (kp, vp))
+        kp, vp = (np.asarray(x, np.float32) for x in (k_dev, v_dev))
+    else:
+        k_dev, v_dev = jnp.asarray(kp), jnp.asarray(vp)
+    return dict(
+        B=B, H=H, KVH=KVH, d=d, P=P, qpos=qpos, table=table,
+        pool_pos=pool_pos, kp=kp, vp=vp, k_dev=k_dev, v_dev=v_dev,
+        scales=scales,
+    )
+
+
+@pytest.mark.parametrize(
+    "pool,T,MB",
+    [
+        ("fp32", 1, 8),  # P divides MB; every other case pads the table
+        ("fp32", 1, 10), ("fp32", 3, 10), ("bf16", 1, 10), ("bf16", 3, 10),
+        ("int8", 1, 10), ("int8", 3, 10),
+    ],
+)
+def test_paged_kernel_step_edges_match_dense(pool, T, MB):
+    """The grid step covers P table entries: every way a row's live
+    blocks can sit against the step boundary must match dense attention
+    over the gathered blocks — including MB that P does not divide (the
+    table is padded with sentinels), dead entries inside a live step
+    (they hold another row's block and must weigh exactly zero) and dead
+    steps between live rows."""
+    rng = np.random.RandomState(11)
+    st = _step_edge_state(rng, MB, pool)
+    B, H, KVH, d = st["B"], st["H"], st["KVH"], st["d"]
+    qdt = jnp.bfloat16 if pool == "bf16" else jnp.float32
+    q = np.asarray(jnp.asarray(rng.randn(B, T, H, d), qdt), np.float32)
+    kn = np.asarray(jnp.asarray(rng.randn(B, T, KVH, d), qdt), np.float32)
+    vn = np.asarray(jnp.asarray(rng.randn(B, T, KVH, d), qdt), np.float32)
+    got = np.asarray(paged_decode_attention(
+        jnp.asarray(q, qdt), jnp.asarray(kn, qdt), jnp.asarray(vn, qdt),
+        st["k_dev"], st["v_dev"], jnp.asarray(st["pool_pos"]),
+        jnp.asarray(st["table"]), jnp.asarray(st["qpos"]), **st["scales"],
+    ), np.float32)
+    assert np.isfinite(got).all()
+    # bf16: the kernel rounds probabilities to the pool's dtype before
+    # the P.V product and returns bf16; the reference is fp32 throughout.
+    tol = 3e-2 if pool == "bf16" else 1e-5
+    for b in range(B):
+        if st["qpos"][b] < 0:
+            continue
+        want = _reference_multi(
+            q, kn, vn, st["kp"], st["vp"], st["pool_pos"], st["table"],
+            st["qpos"], b, T,
+        )
+        np.testing.assert_allclose(got[b], want, atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize(
+    "blk,mb,kvh,d,itemsize,want",
+    [
+        (128, 16, 8, 128, 2, 4),    # mistral7b-chat-rate80: 16 slots x 2048
+        (128, 32, 8, 128, 2, 4),    # mistral7b-docqa-batch: 8 slots x 4096
+        (512, 32, 8, 128, 2, 1),    # a 512-token block is a step already
+        (128, 16, 2, 128, 2, 4),    # KVH / tensor = 2 on four chips
+        (128, 16, 8, 128, 1, 4),    # int8 pool: tokens, not bytes, set P
+        (256, 16, 8, 128, 2, 2),
+        (128, 16, 32, 128, 2, 1),   # 32 KV heads: the unroll cap holds P
+        (16, 10, 8, 32, 4, 4),      # 10 entries in 3 steps of 4, 2 padded
+        (16, 5, 2, 32, 4, 5),       # a short table is one step
+        (128, 1, 8, 128, 2, 1),
+    ],
+)
+def test_blocks_per_step_follows_the_shapes(blk, mb, kvh, d, itemsize, want):
+    from jax_llama_tpu.ops.paged_attention import _blocks_per_step
+
+    assert _blocks_per_step(blk, mb, kvh, d, itemsize) == want
+
+
 @pytest.mark.slow  # interpret-mode Pallas / long decode on CPU; out of the tier-1 budget (plain `pytest tests/` still runs it)
 def test_paged_forward_multi_token_matches_gathered_view():
     """paged_forward at T=3 (the verify shape) vs the gathered-view
