@@ -11,6 +11,12 @@ _BYTES = {"bfloat16": 2, "float32": 4}
 
 
 def layer_params(cfg: Dict[str, Any]) -> int:
+    """Parameters of one layer of the dense GQA + SwiGLU block, and of no
+    other: a block with its own reference brings its own count."""
+    if cfg.get("reference") != "dense_gqa":
+        raise ValueError(
+            f"roofline.layer_params counts the dense_gqa block, not {cfg.get('reference')!r}"
+        )
     D, F = cfg["hidden_size"], cfg["intermediate_size"]
     H, KVH, hd = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["head_dim"]
     return D * (H + 2 * KVH) * hd + H * hd * D + 3 * D * F + 2 * D

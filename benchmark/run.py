@@ -158,6 +158,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     server = work["server"]
     # `config_overrides` exists only under a workload's `rehearse` object.
     raw_config = merge(load_json(ROOT / cfg_entry["file"]), work.get("config_overrides"))
+    reference.load(raw_config)  # a file that names no reference stops here, not after set-up
     config = system.load_config(raw_config, server)
     mesh = system.build_mesh(server, chips)
     t = time.monotonic()
@@ -432,6 +433,24 @@ def main(argv: Optional[List[str]] = None) -> int:
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
         out["breakdown"] = reduced["breakdown"]
+    # Each number compared beside its limit, as the last lines of standard
+    # error: of a run that is not correct, the end of that is what is kept.
+    chk = result["check"]
+    limits = chk.get("limits") or [None, None]
+    compared = [
+        ("check max_deficit", chk.get("max_deficit"), f"<= {limits[0]}"),
+        ("check mean_deficit", chk.get("mean_deficit"), f"<= {limits[1]}"),
+        ("check positions", chk.get("positions"), "> 0"),
+        ("check served beside a holder", chk.get("beside_a_holder"), "is True"),
+        ("check prefill dispatch kinds", chk.get("prefill_dispatch_kinds"), "fused only"),
+        ("check re-ask prefix hit tokens", chk.get("reask_hit_tokens"), "each > 0"),
+        ("check error", chk.get("error"), "is None"),
+        ("window compiles", compiles, "== 0"),
+        ("window hung requests", counts["hung"], "== 0"),
+    ]
+    for what, got, limit in compared:
+        print(f"compared: {what} = {got}  limit {limit}", file=sys.stderr)
+    print(f"compared: correct = {out['correct']}", file=sys.stderr, flush=True)
     if args.rehearse:
         say("rehearsal_end", result=out)
         return 0

@@ -1,10 +1,13 @@
-"""The only file of the benchmark that calls into the system under test.
+"""The file of the benchmark that calls into the system under test: the only
+one, but for `published.py`, which holds a function that is the program's to
+own until the program has it.
 
-It builds the model configuration from its file, makes the weights on the
-device from the seed, and starts `LLMServer` over a `ContinuousBatcher`
-in-process through `jax_llama_tpu.run._serve_http`, the same function
-`python -m jax_llama_tpu.run --http` ends in, so that every server setting a
-cell does not name is `run.py`'s own default.
+It has the program build its configuration from the published keys of the
+configuration file, makes the weights on the device from the seed with the
+builder the program gives that configuration, and starts `LLMServer` over a
+`ContinuousBatcher` in-process through `jax_llama_tpu.run._serve_http`, the
+same function `python -m jax_llama_tpu.run --http` ends in, so that every
+server setting a cell does not name is `run.py`'s own default.
 """
 
 from __future__ import annotations
@@ -13,32 +16,27 @@ import sys
 import types
 from typing import Any, Callable, Dict
 
-# published config.json key -> LLaMAConfig field
-_KEYS = {
-    "hidden_size": "dim", "num_hidden_layers": "n_layers",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "intermediate_size", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "rms_norm_eps": "rms_norm_eps",
-    "tie_word_embeddings": "tie_word_embeddings",
-}
-_DTYPES = {"bfloat16": "bfloat16", "float32": "float32"}
+# keys of a configuration file that are the benchmark's own, not the model's
+_BOOKKEEPING = ("source", "architecture", "reference", "reduced", "assumed", "deployment")
 
 
 def load_config(raw: Dict[str, Any], server: Dict[str, Any]):
-    """The `LLaMAConfig` of a configuration (a dict of published
-    `config.json` keys) at a cell's `max_seq_len`."""
-    from jax_llama_tpu.config import LLaMAConfig
+    """The program's configuration of a configuration file (a dict of
+    published `config.json` keys beside the file's bookkeeping) at a cell's
+    `max_seq_len`.  The map is the program's `config.from_published` and,
+    until it has one, the copy in `published.py`; both refuse a key they do
+    not know."""
+    from jax_llama_tpu import config as program
 
-    if raw.get("sliding_window") is not None:
-        raise SystemExit("sliding-window attention is not in the program")
-    kw = {ours: raw[theirs] for theirs, ours in _KEYS.items()}
-    if raw["head_dim"] * raw["num_attention_heads"] != raw["hidden_size"]:
-        raise SystemExit("head_dim * heads != hidden_size; the program has no separate head size")
-    dtype = _DTYPES[raw["torch_dtype"]]
-    config = LLaMAConfig(
-        **kw, dtype=dtype, param_dtype=dtype,
-        max_seq_len=int(server["max_seq_len"]), attn_impl=server.get("attn", "auto"),
-    )
+    build = getattr(program, "from_published", None)
+    if build is None:
+        from .published import from_published as build
+    published = {k: v for k, v in raw.items() if k not in _BOOKKEEPING}
+    try:
+        config = build(published, max_seq_len=int(server["max_seq_len"]),
+                       attn_impl=server.get("attn", "auto"))
+    except ValueError as e:
+        raise SystemExit(f"the configuration is refused: {e}")
     config.validate()
     return config
 
@@ -66,7 +64,7 @@ def make_params(config, mesh, seed: int):
     they are served in, each leaf born in its shard."""
     import jax
 
-    from jax_llama_tpu.models.llama import init_params
+    from jax_llama_tpu.models import init_params
     from jax_llama_tpu.parallel.partition import shard_abstract
 
     key = jax.random.PRNGKey(seed)

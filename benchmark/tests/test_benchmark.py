@@ -10,7 +10,7 @@ from pathlib import Path
 
 import pytest
 
-from benchmark import roofline, stats, trace
+from benchmark import reference, roofline, stats, trace
 from benchmark.traffic import doc_sessions, poisson_lognormal
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -170,6 +170,12 @@ def test_decode_roofline_counts_low_and_reads_under_100():
     kv = roofline.kv_bytes_per_token(cfg) * (300 + 100 * 0.9995 / 2.0 - 16) / peaks["hbm_bytes_per_s"]
     assert got["value"] == pytest.approx(100 * (w + kv) / (1.1 * w), rel=1e-3)
     assert got["value"] < 100 and got["note"]["rows_counted"] == 1
+    # the counts are the dense block's: a configuration of another block gets none
+    for other in (dict(cfg, reference="latent_moe"), {k: v for k, v in cfg.items() if k != "reference"}):
+        with pytest.raises(ValueError, match="dense_gqa"):
+            roofline.decode_iter_bytes(other, [100.0])
+        with pytest.raises(ValueError, match="dense_gqa"):
+            roofline.decode_iter_flops(other, [100.0])
 
 
 # -- traffic -----------------------------------------------------------------
@@ -245,7 +251,8 @@ def test_ttft_counts_from_due_and_a_failure_is_a_miss():
 
 TINY = {"hidden_size": 32, "intermediate_size": 96, "num_attention_heads": 4,
         "num_key_value_heads": 2, "head_dim": 8, "num_hidden_layers": 4, "vocab_size": 256,
-        "rope_theta": 10000.0, "rms_norm_eps": 1e-5, "tie_word_embeddings": False}
+        "rope_theta": 10000.0, "rms_norm_eps": 1e-5, "tie_word_embeddings": False,
+        "reference": "dense_gqa"}
 
 
 @pytest.fixture(scope="module")
@@ -273,11 +280,9 @@ def tiny():
 def test_reference_agrees_with_the_program(tiny):
     import numpy as np
 
-    from benchmark import reference
-
     params, toks, want, served = tiny
     assert TINY["intermediate_size"] == params["layers"]["down"].shape[1]
-    got = np.asarray(reference.logits(params, toks, TINY, 0))
+    got = np.asarray(reference.load(TINY).logits(params, toks, TINY, 0))
     assert np.abs(got - want).max() < 2e-4
     d = reference.deficits(params, toks[:, :16].tolist(), served, TINY)
     assert d.shape == (2, 8) and d.max() < 1e-3
@@ -289,8 +294,6 @@ def test_reference_fails_a_wrong_rope_base_or_a_dropped_kv_head(tiny, fault):
     to the limits scaled to these logits: tiny's largest logit stands less far
     above the mean than the real models' (~4.2 there)."""
     import numpy as np
-
-    from benchmark import reference
 
     params, toks, want, served = tiny
     cfg = dict(TINY)
@@ -305,8 +308,9 @@ def test_reference_fails_a_wrong_rope_base_or_a_dropped_kv_head(tiny, fault):
         cfg.update(fault)
     d = reference.deficits(params, toks[:, :16].tolist(), served, cfg)
     spread = want.max(-1).mean() - want.mean()
-    assert d.max() > reference.MAX_DEFICIT * spread / 4.2
-    assert d.mean() > reference.MEAN_DEFICIT * spread / 4.2
+    ref = reference.load(TINY)
+    assert d.max() > ref.MAX_DEFICIT * spread / 4.2
+    assert d.mean() > ref.MEAN_DEFICIT * spread / 4.2
 
 
 @pytest.mark.parametrize("cast,passes", [
@@ -319,7 +323,6 @@ def test_reference_limits_pass_bf16_and_fail_eight_bits(tiny, cast, passes):
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmark import reference
     from jax_llama_tpu import forward, get_config
 
     params, toks, want, _ = tiny
@@ -334,15 +337,14 @@ def test_reference_limits_pass_bf16_and_fail_eight_bits(tiny, cast, passes):
         seq = np.concatenate([seq, nxt[:, None].astype(np.int32)], axis=1)
     d = reference.deficits(params, prompts, seq[:, 16:].tolist(), TINY)
     scale = (want.max(-1).mean() - want.mean()) / 4.2
-    inside = d.max() <= reference.MAX_DEFICIT * scale and d.mean() <= reference.MEAN_DEFICIT * scale
+    ref = reference.load(TINY)
+    inside = d.max() <= ref.MAX_DEFICIT * scale and d.mean() <= ref.MEAN_DEFICIT * scale
     assert inside == passes, (d.max(), d.mean(), scale)
     if not passes:
-        assert d.max() > reference.MAX_DEFICIT * scale and d.mean() > reference.MEAN_DEFICIT * scale
+        assert d.max() > ref.MAX_DEFICIT * scale and d.mean() > ref.MEAN_DEFICIT * scale
 
 
 def test_check_requests_reask_shares_a_prefix():
-    from benchmark import reference
-
     spec = {"prompts": 3, "prompt_tokens": 40, "shared_tokens": 32, "new_tokens": 4}
     fresh, reask = reference.check_requests(spec, 7, 512)
     assert (fresh, reask) == reference.check_requests(spec, 7, 512)
@@ -351,6 +353,161 @@ def test_check_requests_reask_shares_a_prefix():
         assert len(f["prompt"]) == len(r["prompt"]) == 40
         assert f["prompt"][:32] == r["prompt"][:32] and f["prompt"][32:] != r["prompt"][32:]
     assert len({q["id"] for q in fresh + reask}) == 6
+
+
+# -- the seam: a configuration file -> the program's configuration, its reference
+
+MISTRAL = ROOT / "benchmark" / "configs" / "mistral-7b-v0.3.json"
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
+def _server(cell):
+    return json.loads((ROOT / "benchmark" / "workloads" / f"{cell}.json").read_text())["server"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_load_config_is_the_old_map(cell):
+    """What `system.load_config` gave before the map became strict, written
+    out by hand: nine fields, the two types, the cell's length and kernel."""
+    from benchmark import system
+    from jax_llama_tpu.config import LLaMAConfig
+
+    raw, server = json.loads(MISTRAL.read_text()), _server(cell)
+    want = LLaMAConfig(
+        dim=raw["hidden_size"], n_layers=raw["num_hidden_layers"], n_heads=raw["num_attention_heads"],
+        n_kv_heads=raw["num_key_value_heads"], intermediate_size=raw["intermediate_size"],
+        vocab_size=raw["vocab_size"], rope_theta=raw["rope_theta"], rms_norm_eps=raw["rms_norm_eps"],
+        tie_word_embeddings=raw["tie_word_embeddings"], dtype="bfloat16", param_dtype="bfloat16",
+        max_seq_len=server["max_seq_len"], attn_impl=server["attn"],
+    )
+    assert system.load_config(raw, server) == want
+    assert (want.dim, want.n_layers, want.kv_heads, want.head_dim, want.ffn_dim) == (4096, 24, 8, 128, 14336)
+
+
+@pytest.mark.parametrize("key,value,named", [
+    ("n_routed_experts", 128, "n_routed_experts"),
+    ("kv_lora_rank", 512, "kv_lora_rank"),
+    ("first_k_dense_replace", 1, "first_k_dense_replace"),
+    ("sliding_window", 4096, "sliding_window"),
+    ("head_dim", 64, "head_dim"),
+    ("torch_dtype", "float16", "torch_dtype"),
+    ("rope_theta", None, "rope_theta"),          # a key of the map taken out
+])
+def test_a_key_the_map_does_not_understand_is_refused_by_name(key, value, named):
+    from benchmark import system
+
+    raw = json.loads(MISTRAL.read_text())
+    if value is None:
+        del raw[key]
+    else:
+        raw[key] = value
+    with pytest.raises(SystemExit, match=named):
+        system.load_config(raw, _server(CELLS[0]))
+
+
+def test_published_keys_give_the_fields_and_bookkeeping_is_not_a_key():
+    from benchmark import published, system
+
+    raw = json.loads(MISTRAL.read_text())
+    keys = {k: v for k, v in raw.items() if k not in system._BOOKKEEPING}
+    got = published.from_published(keys, max_seq_len=2048, attn_impl="auto")
+    assert (got.dim, got.n_heads, got.kv_heads, got.ffn_dim, got.vocab_size) == (4096, 32, 8, 14336, 32768)
+    assert (got.rope_theta, got.rms_norm_eps, got.tie_word_embeddings) == (1e6, 1e-5, False)
+    assert (got.dtype, got.param_dtype, got.max_seq_len, got.attn_impl) == ("bfloat16", "bfloat16", 2048, "auto")
+    with pytest.raises(ValueError, match="'source'"):      # the file's own keys are stripped by the caller
+        published.from_published(raw, max_seq_len=2048, attn_impl="auto")
+    # head_dim is not a key of every published file; absent, it is hidden / heads
+    assert published.from_published({k: v for k, v in keys.items() if k != "head_dim"},
+                                    max_seq_len=2048, attn_impl="auto") == got
+
+
+def test_the_programs_own_map_is_used_once_it_has_one(monkeypatch):
+    from benchmark import system
+    from jax_llama_tpu import config as program
+
+    seen = {}
+
+    def from_published(raw, *, max_seq_len, attn_impl):
+        seen.update(raw=raw, max_seq_len=max_seq_len, attn_impl=attn_impl)
+        if "n_routed_experts" not in raw:
+            raise ValueError("n_routed_experts is missing")
+        return program.tiny()
+
+    monkeypatch.setattr(program, "from_published", from_published, raising=False)
+    raw = dict(json.loads(MISTRAL.read_text()), n_routed_experts=128)
+    assert system.load_config(raw, {"max_seq_len": 512}) == program.tiny()
+    assert seen["max_seq_len"] == 512 and seen["attn_impl"] == "auto"
+    assert "n_routed_experts" in seen["raw"] and not set(seen["raw"]) & set(system._BOOKKEEPING)
+    with pytest.raises(SystemExit, match="n_routed_experts"):
+        system.load_config(json.loads(MISTRAL.read_text()), {"max_seq_len": 512})
+
+
+def test_a_reference_is_added_as_a_file(tiny, tmp_path, monkeypatch):
+    """A second reference, written here into a `references` directory, is
+    found through the configuration's `reference` key alone.  It is the dense
+    block without its rope, so the program's own tokens fail it, and it
+    brings limits of its own."""
+    params, toks, want, served = tiny
+    src = (reference.REFERENCES / "dense_gqa.py").read_text()
+    assert "    q, k = _rope(q, theta), _rope(k, theta)\n" in src
+    src = src.replace("    q, k = _rope(q, theta), _rope(k, theta)\n", "")
+    src = src.replace("MAX_DEFICIT = 0.15", "MAX_DEFICIT = 0.25").replace("MEAN_DEFICIT = 0.005", "MEAN_DEFICIT = 0.01")
+    (tmp_path / "no_rope.py").write_text(src)
+    monkeypatch.setattr(reference, "REFERENCES", tmp_path)
+    cfg = dict(TINY, reference="no_rope")
+    records = [{"ok": True, "tokens": s, "error": None, "status": 200} for s in served]
+    requests = [{"prompt": p} for p in toks[:, :16].tolist()]
+    got = reference.judge(params, cfg, requests, records)
+    assert got["ok"] is False and got["limits"] == [0.25, 0.01] and got["positions"] == 16
+    assert got["max_deficit"] > 0.25 and got["argmax_agree"] < 1.0
+    # the same tokens under the reference the program's block has
+    (tmp_path / "dense_gqa.py").write_text((ROOT / "benchmark" / "references" / "dense_gqa.py").read_text())
+    got = reference.judge(params, TINY, requests, records)
+    assert got["max_deficit"] < 1e-3 and got["limits"] == [0.15, 0.005]
+
+
+@pytest.mark.parametrize("cfg,says", [
+    ({k: v for k, v in TINY.items() if k != "reference"}, "names no reference"),
+    (dict(TINY, reference="latent_moe"), "references/latent_moe.py"),
+])
+def test_a_missing_reference_is_refused_with_the_path(cfg, says):
+    with pytest.raises(SystemExit, match=says):
+        reference.load(cfg)
+
+
+def test_a_reference_without_its_limits_is_refused(tmp_path, monkeypatch):
+    (tmp_path / "bare.py").write_text("def logits(params, tokens, cfg, first):\n    return None\n")
+    monkeypatch.setattr(reference, "REFERENCES", tmp_path)
+    with pytest.raises(SystemExit, match="MAX_DEFICIT"):
+        reference.load({"reference": "bare"})
+
+
+def test_a_run_that_serves_other_weights_is_not_correct(monkeypatch, capsys):
+    """The whole run of a cell but the harness's look for a chip (a rehearsal:
+    CPU, tiny size, about two minutes), with the timed path broken underneath:
+    the server is handed the weights of another seed, so every token it
+    produces is another model's.  The check must say so, on the window's own
+    path, and `correct` must come out false."""
+    from benchmark import run, system
+
+    serve = system.serve
+
+    def serve_other_weights(params, config, mesh, server, seed, body):
+        serve(system.make_params(config, mesh, seed + 1), config, mesh, server, seed, body)
+
+    monkeypatch.setattr(system, "serve", serve_other_weights)
+    assert run.main(["--workload", CELLS[0], "--seed", "2147483659", "--seconds", "2", "--rehearse"]) == 0
+    said = capsys.readouterr()
+    lines = [json.loads(l) for l in said.out.splitlines() if l.startswith('{"bench"')]
+    check = next(l for l in lines if l["bench"] == "check")
+    assert check["ok"] is False and check["max_deficit"] > check["limits"][0]
+    assert set(check["prefill_dispatch_kinds"]) == {"fused"} and min(check["reask_hit_tokens"]) > 0
+    assert lines[-1]["bench"] == "rehearsal_end" and lines[-1]["result"]["correct"] is False
+    # standard error ends with each number compared beside its limit
+    tail = [l for l in said.err.splitlines() if l.strip()][-10:]
+    assert all(l.startswith("compared: ") for l in tail) and tail[-1] == "compared: correct = False"
+    assert tail[0] == f"compared: check max_deficit = {check['max_deficit']}  limit <= {check['limits'][0]}"
+    assert "compared: window compiles = 0  limit == 0" in tail
 
 
 # -- BENCHMARK.json ----------------------------------------------------------
@@ -384,6 +541,9 @@ def test_names_units_and_files():
         assert (ROOT / c["file"]).exists() and len(c["why"]) <= 200
         raw = json.loads((ROOT / c["file"]).read_text())
         assert raw["source"] == c["source"] and set(raw["reduced"]) == set(c["reduced"])
+        ref = reference.load(raw)
+        assert ref.__file__ == str(ROOT / "benchmark" / "references" / f"{raw['reference']}.py")
+        assert callable(ref.logits) and 0 < ref.MEAN_DEFICIT < ref.MAX_DEFICIT and ref.__doc__
     assert all(p in "benchmark.run" or not (ROOT / p).exists() for p in BENCH["command"][1:])
 
 
@@ -393,3 +553,19 @@ def test_run_py_names_no_cell_configuration_or_metric():
              + [w["name"] for w in BENCH["workloads"]] + [c["name"] for c in BENCH["configs"]])
     word = lambda n: re.search(r"(?<![\w.\-])" + re.escape(n) + r"(?![\w.\-])", src)  # noqa: E731
     assert [n for n in names if word(n)] == []
+
+
+def test_the_harness_names_no_published_key_of_a_block():
+    """`system.py`, `reference.py` and `run.py` know no block: the keys of one
+    are read by its reference and mapped by the program (`published.py` until
+    the program has the map)."""
+    from benchmark import published
+
+    keys = (set(published._FIELDS) | set(published._OTHER) | {
+        "n_routed_experts", "kv_lora_rank", "first_k_dense_replace", "num_experts_per_tok"}
+    ) - {"vocab_size"}      # `run.py` reads the program's own `config.vocab_size` to draw token ids
+    for name in ("system.py", "reference.py", "run.py"):
+        src = (ROOT / "benchmark" / name).read_text()
+        assert [k for k in sorted(keys) if re.search(r"(?<![\w])" + re.escape(k) + r"(?![\w])", src)] == [], name
+        assert "models.llama" not in src and "LLaMAConfig(" not in src, name
+
