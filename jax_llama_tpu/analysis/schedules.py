@@ -454,6 +454,7 @@ def _make_batcher_stub():
     s.prefill_budget = 16
     s._pf = None
     s.prefill_chunks_total = 0
+    s.moe_totals = {}
     s.fused_admissions_total = 0
     s.decode_stall_ms_total = 0.0
     s.prefix_index = "radix"

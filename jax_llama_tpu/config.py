@@ -111,6 +111,22 @@ class LLaMAConfig:
                                           #   (upstream Pallas kernel, T=1
                                           #   non-int8 dispatches) | "auto"
 
+    # --- latent attention (MLA) and routed experts.  All zero: the dense
+    # GQA + SwiGLU block.  kv_lora_rank > 0 selects the block of
+    # models/mla_moe.py (deepseek_v3-style: one cached latent row of
+    # kv_lora_rank + qk_rope_head_dim values a token a layer, a leading
+    # run of dense layers, then sigmoid-routed experts beside shared ones).
+    kv_lora_rank: int = 0                 # width of the normed KV latent
+    qk_nope_head_dim: int = 0             # per-head q/k width without rope
+    qk_rope_head_dim: int = 0             # rotated width (ONE shared key head)
+    v_head_dim: int = 0                   # per-head value width
+    n_routed_experts: int = 0
+    n_experts_per_tok: int = 0
+    n_shared_experts: int = 0             # one SwiGLU of this many widths
+    moe_intermediate_size: int = 0        # width of one expert
+    routed_scaling_factor: float = 1.0
+    first_k_dense: int = 0                # leading layers with a dense FFN
+
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
@@ -119,6 +135,43 @@ class LLaMAConfig:
     def head_dim(self) -> int:
         assert self.dim % self.n_heads == 0
         return self.dim // self.n_heads
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_heads(self) -> int:
+        """Heads of one cached row: the KV heads, or 1 for the latent."""
+        return 1 if self.latent_attention else self.kv_heads
+
+    @property
+    def cache_width(self) -> int:
+        """Width of a cache head's row in the `k` plane: the head size, or
+        the normed latent beside the rotated shared key (`latent_dim`
+        values; the latent cache has no `v` plane: the value is the latent
+        itself)."""
+        if self.latent_attention:
+            # Stored lane-aligned (576 -> 640 here), zeros behind the values:
+            # a row that is no multiple of the 128 lanes makes XLA:TPU lay
+            # the pool out block-size-minor, and the paged kernel's row-major
+            # operand then costs a copy of the whole pool in and out of
+            # every dispatch (compiled for a v5e, PR 27).
+            return -(-self.latent_dim // 128) * 128
+        return self.head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        """Values a token a layer the latent cache holds."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def rope_dim(self) -> int:
+        return self.qk_rope_head_dim if self.latent_attention else self.head_dim
 
     @property
     def ffn_dim(self) -> int:
@@ -139,6 +192,8 @@ class LLaMAConfig:
 
     def validate(self) -> None:
         assert self.dim % self.n_heads == 0, "n_heads must divide dim"
+        if self.latent_attention:
+            self._validate_latent()
         assert self.n_heads % self.kv_heads == 0, (
             "n_heads must be a multiple of n_kv_heads (GQA group size)"
         )
@@ -173,6 +228,124 @@ class LLaMAConfig:
                 f"unknown decode_kernel {self.decode_kernel!r}; "
                 "expected 'paged', 'stock-paged', or 'auto'"
             )
+
+    def _validate_latent(self) -> None:
+        """The latent-attention block: what it needs, and what it does not
+        get yet — refused by name here, never served wrongly."""
+        for name in ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
+                     "n_routed_experts", "n_experts_per_tok",
+                     "moe_intermediate_size"):
+            if getattr(self, name) <= 0:
+                raise ValueError(f"latent attention needs {name} > 0")
+        if not 0 < self.first_k_dense < self.n_layers:
+            raise ValueError(
+                "the latent-attention block has a leading run of dense "
+                f"layers then expert layers; first_k_dense={self.first_k_dense} "
+                f"of n_layers={self.n_layers} leaves one of them empty"
+            )
+        if self.n_experts_per_tok > self.n_routed_experts:
+            raise ValueError("n_experts_per_tok exceeds n_routed_experts")
+        if self.kv_cache_dtype == "int8":
+            raise ValueError(
+                "kv_cache_dtype='int8' is not supported with latent "
+                "attention: the latent row has no per-head scale"
+            )
+        if self.attn_impl == "ring":
+            raise ValueError(
+                "attn_impl='ring' is not supported with latent attention"
+            )
+        if self.tie_word_embeddings or self.use_scaled_rope:
+            raise ValueError(
+                "tie_word_embeddings / use_scaled_rope are not supported "
+                "with latent attention"
+            )
+
+
+# ---------------------------------------------------------------------------
+# Published config.json keys -> LLaMAConfig.  Strict: a key this map does
+# not know is refused by name, never dropped — a file that carries an expert
+# count, a latent rank or a window would otherwise be served as the dense
+# block of the same hidden size, under the model's name.  The guard against
+# a wrong map is the benchmark's plain reference, which reads the
+# configuration FILE and never the object made here.
+# ---------------------------------------------------------------------------
+
+# published key -> configuration field (every block)
+_PUBLISHED = {
+    "hidden_size": "dim", "num_hidden_layers": "n_layers",
+    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+    "intermediate_size": "intermediate_size", "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta", "rms_norm_eps": "rms_norm_eps",
+    "tie_word_embeddings": "tie_word_embeddings",
+}
+_PUBLISHED_DTYPES = ("bfloat16", "float32")
+# checked or accepted below, mapped to no field of their own
+_PUBLISHED_OTHER = ("head_dim", "torch_dtype", "sliding_window",
+                    "max_position_embeddings")
+# the deepseek_v3 block (latent attention, routed + shared experts)
+_PUBLISHED_LATENT = {
+    "kv_lora_rank": "kv_lora_rank", "qk_nope_head_dim": "qk_nope_head_dim",
+    "qk_rope_head_dim": "qk_rope_head_dim", "v_head_dim": "v_head_dim",
+    "n_routed_experts": "n_routed_experts",
+    "num_experts_per_tok": "n_experts_per_tok",
+    "n_shared_experts": "n_shared_experts",
+    "moe_intermediate_size": "moe_intermediate_size",
+    "routed_scaling_factor": "routed_scaling_factor",
+    "first_k_dense_replace": "first_k_dense",
+}
+# its keys with ONE accepted value: the block as the program computes it
+_PUBLISHED_LATENT_FIXED = {
+    "model_type": "deepseek_v3", "attention_bias": False, "hidden_act": "silu",
+    "moe_layer_freq": 1, "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+    "q_lora_rank": None, "rope_interleave": True, "rope_scaling": None,
+    "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+}
+
+
+def from_published(raw, *, max_seq_len: int, attn_impl: str) -> LLaMAConfig:
+    """The `LLaMAConfig` of a model's published `config.json` keys `raw`, or
+    `ValueError` naming the key that stands in the way.
+    `max_position_embeddings` is accepted and unused: a server serves at its
+    own `max_seq_len`.  A file with `kv_lora_rank` is the deepseek_v3 block;
+    every other file is the dense block."""
+    latent = "kv_lora_rank" in raw
+    fields = dict(_PUBLISHED, **(_PUBLISHED_LATENT if latent else {}))
+    known = set(fields) | set(_PUBLISHED_OTHER)
+    if latent:
+        known |= set(_PUBLISHED_LATENT_FIXED) | {"qk_head_dim"}
+    unknown = sorted(set(raw) - known)
+    if unknown:
+        raise ValueError(f"the program understands no published key {', '.join(map(repr, unknown))}")
+    missing = sorted(k for k in (*fields, "torch_dtype") if k not in raw)
+    if missing:
+        raise ValueError(
+            f"published key {missing[0]!r} is missing" + (
+                " (a file with 'kv_lora_rank' is the latent-attention "
+                "block, which needs it)" if latent else ""))
+    if raw.get("sliding_window") is not None:
+        raise ValueError("sliding_window: sliding-window attention is not in the program")
+    heads, hidden = raw["num_attention_heads"], raw["hidden_size"]
+    if raw.get("head_dim", hidden // heads) * heads != hidden:
+        raise ValueError("head_dim * heads != hidden_size; the program has no separate head size")
+    if raw["torch_dtype"] not in _PUBLISHED_DTYPES:
+        raise ValueError(f"torch_dtype {raw['torch_dtype']!r} is not one the program serves in")
+    if latent:
+        for key, only in _PUBLISHED_LATENT_FIXED.items():
+            if key in raw and raw[key] != only:
+                raise ValueError(
+                    f"{key}: {raw[key]!r} is not in the program; its "
+                    f"latent-attention block computes {only!r} only"
+                )
+        if raw.get("qk_head_dim", raw["qk_nope_head_dim"] + raw["qk_rope_head_dim"]) != (
+                raw["qk_nope_head_dim"] + raw["qk_rope_head_dim"]):
+            raise ValueError("qk_head_dim != qk_nope_head_dim + qk_rope_head_dim")
+        if raw["num_key_value_heads"] != heads:
+            raise ValueError("num_key_value_heads != num_attention_heads under latent attention")
+    return LLaMAConfig(
+        **{ours: raw[theirs] for theirs, ours in fields.items()},
+        dtype=raw["torch_dtype"], param_dtype=raw["torch_dtype"],
+        max_seq_len=max_seq_len, attn_impl=attn_impl,
+    )
 
 
 # ---------------------------------------------------------------------------

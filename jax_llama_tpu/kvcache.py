@@ -992,7 +992,9 @@ _POOL_FIELDS = ("k", "v", "pos", "k_scale", "v_scale")
 
 
 def _pool_names(pool) -> Tuple[str, ...]:
-    return _POOL_FIELDS if pool.k_scale is not None else _POOL_FIELDS[:3]
+    """The planes this pool has, by field name: a pool is described by its
+    planes (a latent-attention pool is `k` and `pos` alone)."""
+    return tuple(n for n in _POOL_FIELDS if getattr(pool, n) is not None)
 
 
 def pool_block_bytes(pool) -> int:

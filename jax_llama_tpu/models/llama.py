@@ -73,6 +73,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from ..config import LLaMAConfig
 from ..ops.attention import attention_bias, dropout as _dropout, sdpa, sdpa_cached
 from ..ops.flash_attention import flash_attention, flash_attention_sharded
+from ..ops.moe import N_STATS as _MOE_N_STATS
 from ..ops.norm import rms_norm
 from ..ops.quant import QuantizedTensor as _QuantizedTensor
 from ..ops.quant import matmul as _quant_matmul
@@ -116,7 +117,7 @@ def qeinsum(
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["k", "v", "pos", "index", "k_scale", "v_scale"],
+    data_fields=["k", "v", "pos", "index", "k_scale", "v_scale", "stats"],
     meta_fields=[],
 )
 @dataclasses.dataclass
@@ -134,14 +135,22 @@ class KVCache:
            along head_dim, so dequantization commutes with the attention
            contractions — sdpa_cached folds them into scores/weights and
            the int8 payload is never materialized at full precision.
+
+    A cache is described by the planes it has.  Latent attention
+    (``config.latent_attention``, models/mla_moe.py) keeps ONE plane: ``k``
+    is [L, B, S_max, 1, kv_lora_rank + qk_rope_head_dim], the normed latent
+    beside the rotated shared key, and ``v`` is None — nothing per head.
+    stats: [ops.moe.N_STATS] int32 routing counts a forward adds to (routed
+           experts only; None otherwise).
     """
 
     k: jnp.ndarray
-    v: jnp.ndarray
+    v: Optional[jnp.ndarray]
     pos: jnp.ndarray
     index: jnp.ndarray
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
+    stats: Optional[jnp.ndarray] = None
 
     @property
     def max_len(self) -> int:
@@ -161,7 +170,9 @@ class KVCache:
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["k", "v", "pos", "table", "fill", "k_scale", "v_scale"],
+    data_fields=[
+        "k", "v", "pos", "table", "fill", "k_scale", "v_scale", "stats",
+    ],
     meta_fields=[],
 )
 @dataclasses.dataclass
@@ -183,15 +194,18 @@ class PagedKVCache:
            advances it after each step, like the gathered-view path).
     k_scale, v_scale: [L, KVH, NB, BLK] fp32 per-slot-per-head dequant
            scales (int8 pool only; None otherwise) — folded in-kernel.
+    Latent attention: ``k`` is the one latent plane [L, 1, NB, BLK, w] and
+    ``v`` is None; ``stats`` as in ``KVCache``.
     """
 
     k: jnp.ndarray
-    v: jnp.ndarray
+    v: Optional[jnp.ndarray]
     pos: jnp.ndarray
     table: jnp.ndarray
     fill: jnp.ndarray
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
+    stats: Optional[jnp.ndarray] = None
 
     @property
     def n_blocks(self) -> int:
@@ -450,6 +464,11 @@ def quantize_kv(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return q, scale
 
 
+def moe_stats_zero() -> jnp.ndarray:
+    """Empty routing counts (``ops.moe.STATS``) of a cache or a pool."""
+    return jnp.zeros((_MOE_N_STATS,), jnp.int32)
+
+
 def init_cache(
     config: LLaMAConfig,
     batch: int,
@@ -462,10 +481,15 @@ def init_cache(
     max_len = max_len or config.max_seq_len
     int8_kv = config.kv_cache_dtype == "int8" and dtype is None
     dtype = jnp.int8 if int8_kv else (dtype or config.activation_dtype)
-    shape = (config.n_layers, batch, max_len, config.kv_heads, config.head_dim)
+    shape = (
+        config.n_layers, batch, max_len, config.cache_heads,
+        config.cache_width,
+    )
+    latent = config.latent_attention
     return KVCache(
         k=jnp.zeros(shape, dtype=dtype),
-        v=jnp.zeros(shape, dtype=dtype),
+        v=None if latent else jnp.zeros(shape, dtype=dtype),
+        stats=moe_stats_zero() if latent else None,
         pos=jnp.full((batch, max_len), -1, dtype=jnp.int32),
         index=jnp.zeros((), dtype=jnp.int32),
         k_scale=jnp.zeros(shape[:-1], jnp.float32) if int8_kv else None,
@@ -479,8 +503,13 @@ def init_cache(
 
 def init_params(rng: jax.Array, config: LLaMAConfig) -> Params:
     """Random init matching standard LLaMA scaling (normal, 0.02 std for
-    embeddings; Lecun-style fan-in scaling for projections)."""
+    embeddings; Lecun-style fan-in scaling for projections).  The block
+    follows from the configuration (see ``forward``)."""
     config.validate()
+    if config.latent_attention:
+        from . import mla_moe
+
+        return mla_moe.init_params(rng, config)
     D, H, KVH, hd, F, V, L = (
         config.dim, config.n_heads, config.kv_heads, config.head_dim,
         config.ffn_dim, config.vocab_size, config.n_layers,
@@ -631,6 +660,18 @@ def param_count(params: Params) -> int:
 # Forward
 # ---------------------------------------------------------------------------
 
+def _swiglu(h: jnp.ndarray, gate_up: Any, down: Any) -> jnp.ndarray:
+    """SwiGLU of normed hidden states h [B, T, D] through a fused
+    gate_up [2, D, F] and down [F, D] — the dense block's FFN, a leading
+    dense layer's and a shared expert's (models/mla_moe.py)."""
+    adt = h.dtype
+    gu = qeinsum(h, gate_up, "btd,cdf->btcf", adt)
+    gu = constrain(gu, "data", "seq", None, "tensor")
+    hidden = jax.nn.silu(gu[..., 0, :]) * gu[..., 1, :]
+    out = qeinsum(hidden, down, "btf,fd->btd", adt)
+    return constrain(out, "data", "seq", None)
+
+
 @functools.lru_cache(maxsize=8)
 def _rope_tables(head_dim: int, max_positions: int, theta: float, scaled: bool):
     return rope_table(head_dim, max_positions, theta, use_scaled_rope=scaled)
@@ -685,226 +726,227 @@ def _block(
     attn_weights = None
 
     # --- attention ---
-    h = rms_norm(x, lp["attn_norm"], config.rms_norm_eps)
-    # One fused QKV matmul (see module docstring): [B,T,KVH,G+2,hd],
-    # slots [q_0..q_{G-1}, k, v] per KV head.  Sharded over KVH on
-    # "tensor", so the slice/reshape below are shard-local.
-    G = config.n_heads // config.kv_heads
-    qkv = qeinsum(h, lp["qkv"], "btd,cgdk->btcgk", adt)
-    qkv = constrain(qkv, "data", "seq", "tensor", None, None)
-    q = qkv[..., :G, :].reshape(B, T, config.n_heads, config.head_dim)
-    k = qkv[..., G, :]
-    v = qkv[..., G + 1, :]
-    q = constrain(q, "data", "seq", "tensor", None)
-    k = constrain(k, "data", "seq", "tensor", None)
-    v = constrain(v, "data", "seq", "tensor", None)
+    with jax.named_scope("dense.attention"):
+        h = rms_norm(x, lp["attn_norm"], config.rms_norm_eps)
+        # One fused QKV matmul (see module docstring): [B,T,KVH,G+2,hd],
+        # slots [q_0..q_{G-1}, k, v] per KV head.  Sharded over KVH on
+        # "tensor", so the slice/reshape below are shard-local.
+        G = config.n_heads // config.kv_heads
+        qkv = qeinsum(h, lp["qkv"], "btd,cgdk->btcgk", adt)
+        qkv = constrain(qkv, "data", "seq", "tensor", None, None)
+        q = qkv[..., :G, :].reshape(B, T, config.n_heads, config.head_dim)
+        k = qkv[..., G, :]
+        v = qkv[..., G + 1, :]
+        q = constrain(q, "data", "seq", "tensor", None)
+        k = constrain(k, "data", "seq", "tensor", None)
+        v = constrain(v, "data", "seq", "tensor", None)
 
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
 
-    softmax_dtype = jnp.dtype(config.attn_softmax_dtype)
-    if cache_k is not None and impl == "ring_decode":
-        # Seq-sharded cached decode: the cache never moves (each seq
-        # shard reduces its own slots; one pmax + two psums combine) and
-        # stays immutable through the layer scan — same append-free
-        # contract as the xla path below.  ``slot_pos`` here is the
-        # PRE-step cache positions; the step's own tokens merge at the
-        # softmax level inside ring_decode via ``ring_new_pos``.
-        from ..parallel.ring import ring_decode
+        softmax_dtype = jnp.dtype(config.attn_softmax_dtype)
+        if cache_k is not None and impl == "ring_decode":
+            # Seq-sharded cached decode: the cache never moves (each seq
+            # shard reduces its own slots; one pmax + two psums combine) and
+            # stays immutable through the layer scan — same append-free
+            # contract as the xla path below.  ``slot_pos`` here is the
+            # PRE-step cache positions; the step's own tokens merge at the
+            # softmax level inside ring_decode via ``ring_new_pos``.
+            from ..parallel.ring import ring_decode
 
-        if cache_k_scale is not None:
-            # int8 seq-sharded cache: payload + scales stay int8/fp32 in
-            # HBM, sharded along S; scales fold per shard inside the body.
-            attn = ring_decode(
-                q, cache_k, cache_v, slot_pos, k, v, positions,
-                ring_new_pos, softmax_dtype=softmax_dtype,
-                k_scale=cache_k_scale, v_scale=cache_v_scale,
-            )
-        else:
-            attn = ring_decode(
-                q, cache_k.astype(adt), cache_v.astype(adt), slot_pos,
-                k, v, positions, ring_new_pos, softmax_dtype=softmax_dtype,
-            )
-        cache_k, cache_v = k, v
-    elif cache_k is not None and impl == "xla":
-        # Append-free decode: the cache stays immutable through the layer
-        # scan; sdpa_cached softmaxes jointly over (cache slots, new
-        # tokens) at the scores level, and the caller applies ONE in-place
-        # dynamic-update-slice per step after the scan.  Mutating the
-        # cache per layer inside scan/while forced XLA into a full-cache
-        # double-buffer copy every decode step.  GQA replication stays
-        # inside the attention op, after the cache (parity with reference
-        # model.py:269-270).  ``bias`` masks the cache (unwritten slots
-        # carry pos -1), ``bias_new`` masks/causes the new tokens.
-        if cache_k_scale is not None:
-            attn = sdpa_cached(
-                q, cache_k, cache_v, k, v, bias, bias_new,
-                softmax_dtype=softmax_dtype,
-                k_scale=cache_k_scale, v_scale=cache_v_scale,
-                return_weights=output_attentions,
-            )
-        else:
-            attn = sdpa_cached(
-                q, cache_k.astype(adt), cache_v.astype(adt), k, v,
-                bias, bias_new, softmax_dtype=softmax_dtype,
-                return_weights=output_attentions,
-            )
-        if output_attentions:
-            attn, attn_weights = attn
-        # ys: just this step's projections; forward writes them into the
-        # cache once, outside the scan.
-        cache_k, cache_v = k, v
-    elif impl == "paged":
-        # Paged decode: ``paged_pools`` is the FULL [L, KVH, NB, BLK, hd]
-        # block pool (+ scales when int8) bound once outside the layer
-        # scan, and ``paged_layer`` (the scan's loop index) selects the
-        # plane inside the kernel's index maps — slicing pool[i] here
-        # would materialize each layer's whole plane as the custom-call
-        # operand, ~3x the kernel's own time at 16k contexts (r4,
-        # xplane).  The new token's slot merges at the softmax level.
-        # Pool stays immutable through the scan — paged_forward scatters
-        # the ys once per step.  int8 pools fold their scales in-kernel;
-        # the step's projections get quantized for the scatter but merge
-        # at full precision (matching sdpa_cached's treatment of
-        # same-step tokens).
-        pool_k, pool_v, pool_ks, pool_vs = paged_pools
-        if (
-            config.decode_kernel == "stock-paged"
-            and T == 1
-            and pool_ks is None
-        ):
-            # Selected stock Pallas kernel (ops/kernels.py): T == 1
-            # non-int8 dispatches only — the decode halves of
-            # _chunk_scan/_fused_chunk and speculative DRAFT steps.
-            # T > 1 (speculative verify) and int8 pools keep the custom
-            # kernel (its native multi-token sweep / in-kernel scale
-            # folding); the static predicate here makes that split a
-            # trace-time decision, mirrored by serving's host-side
-            # feature accounting.
-            from ..ops.kernels import stock_paged_decode_attention
-
-            attn = stock_paged_decode_attention(
-                q, k, v, pool_k, pool_v, paged_table, paged_qpos,
-                layer=paged_layer,
-            )
-        else:
-            from ..ops.paged_attention import paged_decode_attention
-
-            attn = paged_decode_attention(
-                q, k, v, pool_k, pool_v, paged_pos, paged_table,
-                paged_qpos, k_scale=pool_ks, v_scale=pool_vs,
-                layer=paged_layer,
-            )
-        if pool_ks is not None:
-            k, cache_k_scale = quantize_kv(k)
-            v, cache_v_scale = quantize_kv(v)
-        cache_k, cache_v = k, v
-    elif cache_k is not None and cache_k_scale is not None:
-        # int8 cache on the flash path: quantize this chunk's projections,
-        # land payload + scales at [cache_index, cache_index+T), and
-        # attend the whole cache with in-kernel scale folding — the int8
-        # bytes stream straight from HBM, never dequantized in memory.
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        cache_k = lax.dynamic_update_slice(
-            cache_k, kq, (0, cache_index, 0, 0)
-        )
-        cache_v = lax.dynamic_update_slice(
-            cache_v, vq, (0, cache_index, 0, 0)
-        )
-        cache_k_scale = lax.dynamic_update_slice(
-            cache_k_scale, ks, (0, cache_index, 0)
-        )
-        cache_v_scale = lax.dynamic_update_slice(
-            cache_v_scale, vs, (0, cache_index, 0)
-        )
-        attn = flash_attention_sharded(
-            q, cache_k, cache_v, positions, slot_pos,
-            k_scale=cache_k_scale, v_scale=cache_v_scale,
-        )
-    else:
-        if cache_k is not None:
-            # Flash path: write the T new KV entries at
-            # [cache_index, cache_index+T), then attend the full cache.
-            cache_k = lax.dynamic_update_slice(
-                cache_k, k.astype(cache_k.dtype), (0, cache_index, 0, 0)
-            )
-            cache_v = lax.dynamic_update_slice(
-                cache_v, v.astype(cache_v.dtype), (0, cache_index, 0, 0)
-            )
-            kk, vv = cache_k.astype(adt), cache_v.astype(adt)
-        else:
-            kk, vv = k, v
-        if impl == "ring" and cache_k is None:
-            # Sequence-parallel path (training / scoring / cache-free
-            # prefill): ring over the seq mesh axis.  attn_pdrop composes:
-            # the mask is a position-keyed counter hash (ring.dropout_keep)
-            # — invariant to chunking and ring layout by construction.
-            from ..parallel.ring import ring_sdpa
-
-            attn = ring_sdpa(
-                q, kk, vv, positions, slot_pos,
-                dropout_rng=(
-                    jax.random.fold_in(dropout_rng, 0)
-                    if dropout_rng is not None and config.attn_pdrop > 0.0
-                    else None
-                ),
-                dropout_rate=config.attn_pdrop,
-            )
-        elif impl in ("flash", "ring"):
-            from ..ops.kernels import splash_eligible
-
-            if cache_k is not None and splash_eligible(
-                config, batch=B, q_len=T, kv_len=kk.shape[1],
-                chunk_offset=chunk_offset,
-            ):
-                # Selected splash prefill (ops/kernels.py): the insert
-                # path's chunk offset is a static Python int (the chunk
-                # loop variable), so the chunk's causal window is a pure
-                # static CausalMask — splash's whole mask surface.
-                # Per-chunk shape eligibility (128-multiples) falls back
-                # to flash HERE, statically, chunk by chunk; the fused
-                # prefill window's TRACED base can never reach this
-                # branch (chunk_offset stays None there).  Dropout
-                # cannot co-occur (cached forwards reject dropout_rng).
-                from ..ops.kernels import splash_prefill_attention
-
-                attn = splash_prefill_attention(
-                    q, kk, vv, chunk_offset=chunk_offset
-                )
-            elif dropout_rng is not None and config.attn_pdrop > 0.0:
-                # In-kernel probability dropout: the mask is generated
-                # blockwise inside the flash forward AND rebuilt
-                # bit-identically in the backward kernels — O(S·d) memory
-                # stands, so attention-dropout training works at long
-                # context (the xla path materializes [B, H, T, S]).
-                attn = flash_attention(
-                    q, kk, vv, positions, slot_pos,
-                    dropout_rate=config.attn_pdrop,
-                    dropout_seed=jax.random.bits(
-                        jax.random.fold_in(dropout_rng, 0), (2,), "uint32"
-                    ),
+            if cache_k_scale is not None:
+                # int8 seq-sharded cache: payload + scales stay int8/fp32 in
+                # HBM, sharded along S; scales fold per shard inside the body.
+                attn = ring_decode(
+                    q, cache_k, cache_v, slot_pos, k, v, positions,
+                    ring_new_pos, softmax_dtype=softmax_dtype,
+                    k_scale=cache_k_scale, v_scale=cache_v_scale,
                 )
             else:
-                attn = flash_attention_sharded(
-                    q, kk, vv, positions, slot_pos
+                attn = ring_decode(
+                    q, cache_k.astype(adt), cache_v.astype(adt), slot_pos,
+                    k, v, positions, ring_new_pos, softmax_dtype=softmax_dtype,
                 )
-        else:
-            attn = sdpa(
-                q, kk, vv, bias, softmax_dtype=softmax_dtype,
-                dropout_rng=(
-                    jax.random.fold_in(dropout_rng, 0)
-                    if dropout_rng is not None and config.attn_pdrop > 0.0
-                    else None
-                ),
-                dropout_rate=config.attn_pdrop,
-                return_weights=output_attentions,
-            )
+            cache_k, cache_v = k, v
+        elif cache_k is not None and impl == "xla":
+            # Append-free decode: the cache stays immutable through the layer
+            # scan; sdpa_cached softmaxes jointly over (cache slots, new
+            # tokens) at the scores level, and the caller applies ONE in-place
+            # dynamic-update-slice per step after the scan.  Mutating the
+            # cache per layer inside scan/while forced XLA into a full-cache
+            # double-buffer copy every decode step.  GQA replication stays
+            # inside the attention op, after the cache (parity with reference
+            # model.py:269-270).  ``bias`` masks the cache (unwritten slots
+            # carry pos -1), ``bias_new`` masks/causes the new tokens.
+            if cache_k_scale is not None:
+                attn = sdpa_cached(
+                    q, cache_k, cache_v, k, v, bias, bias_new,
+                    softmax_dtype=softmax_dtype,
+                    k_scale=cache_k_scale, v_scale=cache_v_scale,
+                    return_weights=output_attentions,
+                )
+            else:
+                attn = sdpa_cached(
+                    q, cache_k.astype(adt), cache_v.astype(adt), k, v,
+                    bias, bias_new, softmax_dtype=softmax_dtype,
+                    return_weights=output_attentions,
+                )
             if output_attentions:
                 attn, attn_weights = attn
+            # ys: just this step's projections; forward writes them into the
+            # cache once, outside the scan.
+            cache_k, cache_v = k, v
+        elif impl == "paged":
+            # Paged decode: ``paged_pools`` is the FULL [L, KVH, NB, BLK, hd]
+            # block pool (+ scales when int8) bound once outside the layer
+            # scan, and ``paged_layer`` (the scan's loop index) selects the
+            # plane inside the kernel's index maps — slicing pool[i] here
+            # would materialize each layer's whole plane as the custom-call
+            # operand, ~3x the kernel's own time at 16k contexts (r4,
+            # xplane).  The new token's slot merges at the softmax level.
+            # Pool stays immutable through the scan — paged_forward scatters
+            # the ys once per step.  int8 pools fold their scales in-kernel;
+            # the step's projections get quantized for the scatter but merge
+            # at full precision (matching sdpa_cached's treatment of
+            # same-step tokens).
+            pool_k, pool_v, pool_ks, pool_vs = paged_pools
+            if (
+                config.decode_kernel == "stock-paged"
+                and T == 1
+                and pool_ks is None
+            ):
+                # Selected stock Pallas kernel (ops/kernels.py): T == 1
+                # non-int8 dispatches only — the decode halves of
+                # _chunk_scan/_fused_chunk and speculative DRAFT steps.
+                # T > 1 (speculative verify) and int8 pools keep the custom
+                # kernel (its native multi-token sweep / in-kernel scale
+                # folding); the static predicate here makes that split a
+                # trace-time decision, mirrored by serving's host-side
+                # feature accounting.
+                from ..ops.kernels import stock_paged_decode_attention
 
-    attn_out = qeinsum(attn, lp["o"], "bthk,hkd->btd", adt)
-    attn_out = constrain(attn_out, "data", "seq", None)
+                attn = stock_paged_decode_attention(
+                    q, k, v, pool_k, pool_v, paged_table, paged_qpos,
+                    layer=paged_layer,
+                )
+            else:
+                from ..ops.paged_attention import paged_decode_attention
+
+                attn = paged_decode_attention(
+                    q, k, v, pool_k, pool_v, paged_pos, paged_table,
+                    paged_qpos, k_scale=pool_ks, v_scale=pool_vs,
+                    layer=paged_layer,
+                )
+            if pool_ks is not None:
+                k, cache_k_scale = quantize_kv(k)
+                v, cache_v_scale = quantize_kv(v)
+            cache_k, cache_v = k, v
+        elif cache_k is not None and cache_k_scale is not None:
+            # int8 cache on the flash path: quantize this chunk's projections,
+            # land payload + scales at [cache_index, cache_index+T), and
+            # attend the whole cache with in-kernel scale folding — the int8
+            # bytes stream straight from HBM, never dequantized in memory.
+            kq, ks = quantize_kv(k)
+            vq, vs = quantize_kv(v)
+            cache_k = lax.dynamic_update_slice(
+                cache_k, kq, (0, cache_index, 0, 0)
+            )
+            cache_v = lax.dynamic_update_slice(
+                cache_v, vq, (0, cache_index, 0, 0)
+            )
+            cache_k_scale = lax.dynamic_update_slice(
+                cache_k_scale, ks, (0, cache_index, 0)
+            )
+            cache_v_scale = lax.dynamic_update_slice(
+                cache_v_scale, vs, (0, cache_index, 0)
+            )
+            attn = flash_attention_sharded(
+                q, cache_k, cache_v, positions, slot_pos,
+                k_scale=cache_k_scale, v_scale=cache_v_scale,
+            )
+        else:
+            if cache_k is not None:
+                # Flash path: write the T new KV entries at
+                # [cache_index, cache_index+T), then attend the full cache.
+                cache_k = lax.dynamic_update_slice(
+                    cache_k, k.astype(cache_k.dtype), (0, cache_index, 0, 0)
+                )
+                cache_v = lax.dynamic_update_slice(
+                    cache_v, v.astype(cache_v.dtype), (0, cache_index, 0, 0)
+                )
+                kk, vv = cache_k.astype(adt), cache_v.astype(adt)
+            else:
+                kk, vv = k, v
+            if impl == "ring" and cache_k is None:
+                # Sequence-parallel path (training / scoring / cache-free
+                # prefill): ring over the seq mesh axis.  attn_pdrop composes:
+                # the mask is a position-keyed counter hash (ring.dropout_keep)
+                # — invariant to chunking and ring layout by construction.
+                from ..parallel.ring import ring_sdpa
+
+                attn = ring_sdpa(
+                    q, kk, vv, positions, slot_pos,
+                    dropout_rng=(
+                        jax.random.fold_in(dropout_rng, 0)
+                        if dropout_rng is not None and config.attn_pdrop > 0.0
+                        else None
+                    ),
+                    dropout_rate=config.attn_pdrop,
+                )
+            elif impl in ("flash", "ring"):
+                from ..ops.kernels import splash_eligible
+
+                if cache_k is not None and splash_eligible(
+                    config, batch=B, q_len=T, kv_len=kk.shape[1],
+                    chunk_offset=chunk_offset,
+                ):
+                    # Selected splash prefill (ops/kernels.py): the insert
+                    # path's chunk offset is a static Python int (the chunk
+                    # loop variable), so the chunk's causal window is a pure
+                    # static CausalMask — splash's whole mask surface.
+                    # Per-chunk shape eligibility (128-multiples) falls back
+                    # to flash HERE, statically, chunk by chunk; the fused
+                    # prefill window's TRACED base can never reach this
+                    # branch (chunk_offset stays None there).  Dropout
+                    # cannot co-occur (cached forwards reject dropout_rng).
+                    from ..ops.kernels import splash_prefill_attention
+
+                    attn = splash_prefill_attention(
+                        q, kk, vv, chunk_offset=chunk_offset
+                    )
+                elif dropout_rng is not None and config.attn_pdrop > 0.0:
+                    # In-kernel probability dropout: the mask is generated
+                    # blockwise inside the flash forward AND rebuilt
+                    # bit-identically in the backward kernels — O(S·d) memory
+                    # stands, so attention-dropout training works at long
+                    # context (the xla path materializes [B, H, T, S]).
+                    attn = flash_attention(
+                        q, kk, vv, positions, slot_pos,
+                        dropout_rate=config.attn_pdrop,
+                        dropout_seed=jax.random.bits(
+                            jax.random.fold_in(dropout_rng, 0), (2,), "uint32"
+                        ),
+                    )
+                else:
+                    attn = flash_attention_sharded(
+                        q, kk, vv, positions, slot_pos
+                    )
+            else:
+                attn = sdpa(
+                    q, kk, vv, bias, softmax_dtype=softmax_dtype,
+                    dropout_rng=(
+                        jax.random.fold_in(dropout_rng, 0)
+                        if dropout_rng is not None and config.attn_pdrop > 0.0
+                        else None
+                    ),
+                    dropout_rate=config.attn_pdrop,
+                    return_weights=output_attentions,
+                )
+                if output_attentions:
+                    attn, attn_weights = attn
+
+        attn_out = qeinsum(attn, lp["o"], "bthk,hkd->btd", adt)
+        attn_out = constrain(attn_out, "data", "seq", None)
     if dropout_rng is not None and config.resid_pdrop > 0.0:
         attn_out = _dropout(
             jax.random.fold_in(dropout_rng, 1), attn_out, config.resid_pdrop
@@ -915,11 +957,8 @@ def _block(
     # fusion — the F axis stays "tensor"-sharded like the separate
     # layout) ---
     h = rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)
-    gate_up = qeinsum(h, lp["gate_up"], "btd,cdf->btcf", adt)
-    gate_up = constrain(gate_up, "data", "seq", None, "tensor")
-    hidden = jax.nn.silu(gate_up[..., 0, :]) * gate_up[..., 1, :]
-    down = qeinsum(hidden, lp["down"], "btf,fd->btd", adt)
-    down = constrain(down, "data", "seq", None)
+    with jax.named_scope("dense.ffn"):
+        down = _swiglu(h, lp["gate_up"], lp["down"])
     if dropout_rng is not None and config.resid_pdrop > 0.0:
         down = _dropout(
             jax.random.fold_in(dropout_rng, 2), down, config.resid_pdrop
@@ -1033,6 +1072,19 @@ def forward(
       flag is set, a third ``AuxOutput`` element is appended:
       (logits, cache, aux).
     """
+    if config.latent_attention:
+        # The block follows from the configuration: latent attention over
+        # a latent cache, routed experts behind leading dense layers.
+        from . import mla_moe
+
+        return mla_moe.forward(
+            params, tokens, positions, config, cache=cache,
+            attn_mask=attn_mask, compute_logits=compute_logits,
+            dropout_rng=dropout_rng,
+            output_hidden_states=output_hidden_states,
+            output_attentions=output_attentions,
+            output_last_hidden=output_last_hidden,
+        )
     collect = output_hidden_states or output_attentions
     if isinstance(cache, PagedKVCache):
         if dropout_rng is not None:

@@ -128,6 +128,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from .degrade import FEATURES
 from .faults import SITES
+from .ops.moe import STATS as _MOE_STATS
 
 # Dispatch kinds serving.py records — each owns a labeled dispatch_ms
 # histogram series and a device-time attribution window.
@@ -440,6 +441,17 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "Chunk dispatches that carried a prefill lane"),
     "fused_admissions_total": _reg(
         "counter", "Admissions routed through the fused prefill lane"),
+    # -- routed experts (ops/moe.py; zero on a configuration without) -------
+    "moe_assignments_total": _reg(
+        "counter", "(token, expert) pairs the router assigned"),
+    "moe_experts_touched_total": _reg(
+        "counter", "Distinct experts touched, summed over expert-layer "
+                   "calls"),
+    "moe_layer_calls_total": _reg(
+        "counter", "Expert-layer calls (one a layer a forward)"),
+    "moe_max_load_total": _reg(
+        "counter", "Largest per-expert token count, summed over "
+                   "expert-layer calls"),
     "decode_stall_ms_total": _reg(
         "counter", "Wall time classic whole-prompt admissions stalled "
                    "decoding rows (ms)"),
@@ -1401,6 +1413,7 @@ class Observability:
         flops: Optional[float] = None,
         bytes_accessed: Optional[float] = None,
         then: Optional[str] = None,
+        moe: Optional[Sequence[int]] = None,
     ) -> int:
         """Record one jitted serving dispatch and link it into the
         CURRENT span of every request that rode it.  Returns the
@@ -1420,7 +1433,10 @@ class Observability:
         ``gap_cpu_ms`` (``time.thread_time()`` over the same interval;
         absent when the previous record came from another thread) and
         ``compiles`` (backend compiles booked since the previous record
-        ended).  ``then`` names the phase the loop thread is in once
+        ended).  ``moe`` (routed-expert configurations) is the fetch's
+        routing counts, in ``ops.moe.STATS``' order, summed over the
+        expert-layer calls.  ``then`` names the phase the loop thread is
+        in once
         the dispatch ends (default: the one it interrupted)."""
         if kind not in DISPATCH_KINDS:
             raise ValueError(
@@ -1446,6 +1462,8 @@ class Observability:
         }
         if program is not None:
             rec["program"] = program
+        if moe is not None:
+            rec["moe"] = dict(zip(_MOE_STATS, map(int, moe)))
         rec.update(gap)
         est_ms = None
         if flops is not None and bytes_accessed is not None:

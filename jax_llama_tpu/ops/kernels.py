@@ -113,6 +113,15 @@ def resolve_prefill_kernel(name: Optional[str], config) -> str:
     auto-splash config silently runs flash for non-128-multiple chunks.
     """
     name = name or "auto"
+    if config.latent_attention:
+        # q/k and v widths differ and the key is rebuilt from a latent:
+        # the custom flash kernel only.
+        if name not in ("auto", "flash"):
+            raise ValueError(
+                f"prefill kernel {name!r} is not supported with latent "
+                "attention; it runs 'flash'"
+            )
+        return "flash"
     if name == "auto":
         return (
             "splash"
@@ -137,6 +146,11 @@ def resolve_decode_kernel(name: Optional[str], config) -> str:
     PR 25); stock-paged stays as the A/B alternative until ROADMAP C4
     decides."""
     name = name or "auto"
+    if config.latent_attention and name not in ("auto", "paged"):
+        raise ValueError(
+            f"decode kernel {name!r} is not supported with latent "
+            "attention; its one shared key/value row runs 'paged'"
+        )
     if name == "auto":
         return "paged"
     if name not in DECODE_KERNELS:

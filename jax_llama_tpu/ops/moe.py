@@ -1,0 +1,142 @@
+"""Routed experts: a sigmoid router, top-k with a selection-only bias, and a
+grouped matmul over the experts a batch touches.
+
+The layer (deepseek_v3's, as `config.from_published` maps it):
+
+    s = sigmoid(h @ W_g)                      float32, [N, E]
+    selected = top_k(s + b)                   b moves the SELECTION only
+    w = s[selected] / sum(s[selected]) * routed_scaling_factor
+    y = sum_e w_e * down_e(silu(gate_e h) * up_e h)
+
+No capacity factor and no dropped token: the (token, expert) pairs are sorted
+by expert and each expert multiplies exactly the rows routed to it, however
+uneven the split (`group_sizes` is a value, not a shape).  On a TPU the
+product is the upstream Pallas grouped matmul (`megablox.gmm`), which visits
+only the non-empty groups, so a decode batch of 8 rows reads the ~40 experts
+it touches and not all 128; everywhere else it is `lax.ragged_dot`.
+
+Rows that are not tokens (a masked decode row, prompt padding) are routed to
+no expert: they sort behind every real pair, belong to no group, and get a
+zero result.  They count in none of the routing statistics.
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+# Rows of one grouped-matmul tile.  Every (non-empty expert, row tile) pair
+# is one pass over that expert's weight, computed at the full tile whatever
+# the rows it holds: at ~96 rows an expert (a 2048-token chunk over 128
+# experts) a wider tile multiplies mostly masked rows.
+_TILE_M = 128
+# What a call counts of its routing, in this order: (token, expert) pairs,
+# distinct experts touched, the call itself, and the largest per-expert
+# count — each summed over calls by whoever accumulates them.
+STATS = ("assignments", "experts_touched", "layer_calls", "max_load")
+N_STATS = len(STATS)
+
+
+def route(
+    h: jnp.ndarray,            # [N, D]
+    w_gate: jnp.ndarray,       # [D, E]
+    bias: jnp.ndarray,         # [E] selection-only correction
+    *,
+    top_k: int,
+    scale: float,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(expert ids [N, k] int32, weights [N, k] float32).  Scores and
+    weights are float32 whatever the activations are: the sixth and seventh
+    scores of 128 lie close, and a bfloat16 sigmoid would pick by rounding."""
+    s = jax.nn.sigmoid(jnp.einsum(
+        "nd,de->ne", h.astype(jnp.float32), w_gate.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    ))
+    _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, idx, axis=1)
+    w = w / jnp.sum(w, axis=1, keepdims=True) * scale
+    return idx.astype(jnp.int32), w
+
+
+def _tiling(k: int, n: int) -> Tuple[int, int, int]:
+    tk = next(t for t in (1024, 768, 512, 256, 128, k) if k % t == 0)
+    tn = next(t for t in (512, 256, 128, n) if n % t == 0)
+    return _TILE_M, tk, tn
+
+
+def grouped_matmul(
+    x: jnp.ndarray,            # [M, K], rows sorted by group
+    w: jnp.ndarray,            # [L, E, K, N]: every expert layer's experts
+    layer: jnp.ndarray,        # int32: which of the L layers
+    group_sizes: jnp.ndarray,  # [E] int32, sum <= M
+) -> jnp.ndarray:
+    """x[rows of group e] @ w[layer, e] for every non-empty group; rows past
+    the last group are unspecified (the caller masks them).
+
+    The kernel is handed ALL layers' experts as one [L*E, K, N] operand (a
+    free reshape of the stacked weight) and group sizes that are zero outside
+    `layer`: it visits the non-empty groups only, so it reads what this layer
+    touched.  Slicing `w[layer]` inside the layer scan instead materializes
+    the layer's 128 experts as the custom call's operand, a 1.2 GB copy a
+    layer a call — half the device's time in the first traced run of
+    `kanana2-docqa-long` (v5e, PERF.md section 6, PR 27)."""
+    L, E = w.shape[:2]
+    if jax.default_backend() != "tpu":
+        return lax.ragged_dot(
+            x, lax.dynamic_index_in_dim(w, layer, 0, keepdims=False), group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    sizes = lax.dynamic_update_slice(
+        jnp.zeros((L * E,), jnp.int32), group_sizes, (layer * E,))
+    return gmm(
+        x, w.reshape((L * E,) + w.shape[2:]), sizes,
+        preferred_element_type=x.dtype, tiling=_tiling(w.shape[2], w.shape[3]),
+    )
+
+
+def routed_experts(
+    h: jnp.ndarray,            # [N, D] normed hidden states
+    valid: Optional[jnp.ndarray],  # [N] bool: rows that are tokens
+    w_gate: jnp.ndarray,       # [D, E]
+    bias: jnp.ndarray,         # [E]
+    gate_up: jnp.ndarray,      # [L, E, D, 2F]  (gate | up on the last axis)
+    down: jnp.ndarray,         # [L, E, F, D]
+    layer: jnp.ndarray,        # int32: which of the L expert layers
+    *,
+    top_k: int,
+    scale: float,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The routed experts' sum [N, D] and the call's routing statistics
+    [N_STATS] int32 (see `STATS`)."""
+    N, D = h.shape
+    E = w_gate.shape[1]
+    F = down.shape[2]
+    with jax.named_scope("moe.route"):
+        idx, w = route(h, w_gate, bias, top_k=top_k, scale=scale)
+        if valid is not None:
+            idx = jnp.where(valid[:, None], idx, E)  # no expert: sorts last
+        flat = idx.reshape(-1)                               # [N*k]
+        order = jnp.argsort(flat, stable=True)
+        group_sizes = jnp.bincount(flat, length=E + 1)[:E].astype(jnp.int32)
+        stats = jnp.stack([
+            jnp.sum(group_sizes), jnp.sum(group_sizes > 0),
+            jnp.ones((), jnp.int32), jnp.max(group_sizes),
+        ]).astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        M = N * top_k
+        Mp = -(-M // _TILE_M) * _TILE_M
+        rows = jnp.pad(order // top_k, (0, Mp - M))
+        x = jnp.take(h, rows, axis=0)                        # [Mp, D]
+        gu = grouped_matmul(x, gate_up, layer, group_sizes)  # [Mp, 2F]
+        act = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(h.dtype)
+        y = grouped_matmul(act, down, layer, group_sizes)[:M]  # [M, D]
+        y = jnp.take(y, jnp.argsort(order), axis=0).reshape(N, top_k, D)
+        if valid is not None:
+            w = jnp.where(valid[:, None], w, 0.0)
+        # a row of no group holds whatever the product left there
+        y = jnp.where((w > 0)[..., None], y.astype(jnp.float32), 0.0)
+        out = jnp.sum(y * w[..., None], axis=1).astype(h.dtype)
+    return out, stats

@@ -148,9 +148,14 @@ def _paged_kernel(
     t_tokens: int,
     group: int,
     quantized: bool = False,
+    v_width: int = 0,
 ):
     """Online-softmax sweep of one row's pool blocks, ``n_entries`` table
     entries a grid step.
+
+    ``v_width`` > 0 is the latent-attention row: there are no v refs, and a
+    slot's value is the first ``v_width`` columns of its key row (the normed
+    latent; the rotated shared key behind it only scores).
 
     The softmax state moves once per (KV head, step): the step's entries
     share one running max, one ``exp`` rescale of ``acc`` and one write
@@ -170,8 +175,11 @@ def _paged_kernel(
     late token but fully masked for an early one.
     """
     P = n_entries
-    k_refs, v_refs, pos_ref = rest[:P], rest[P:2 * P], rest[2 * P]
-    rest = rest[2 * P + 1:]
+    if v_width:
+        k_refs, v_refs, pos_ref, rest = rest[:P], None, rest[P], rest[P + 1:]
+    else:
+        k_refs, v_refs, pos_ref = rest[:P], rest[P:2 * P], rest[2 * P]
+        rest = rest[2 * P + 1:]
     if quantized:
         k_scale_refs, v_scale_refs, rest = rest[:P], rest[P:2 * P], rest[2 * P:]
     o_ref, lse_ref, m_ref, l_ref, acc_ref = rest
@@ -246,6 +254,9 @@ def _paged_kernel(
                 if quantized:
                     pj = (p * v_scale_refs[j][0, h, 0, :1, :]).astype(q.dtype)
                     vb = v_refs[j][0, h, 0].astype(q.dtype)
+                elif v_width:
+                    pj = p.astype(k_refs[j].dtype)
+                    vb = k_refs[j][0, h, 0][:, :v_width]
                 else:
                     pj = p.astype(v_refs[j].dtype)
                     vb = v_refs[j][0, h, 0]
@@ -363,7 +374,9 @@ def _fetch_plan(pool_pos, table, q_pos, t_tokens: int, n_entries: int):
     )
 
 
-@functools.partial(jax.jit, static_argnames=("t_tokens", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("t_tokens", "interpret", "v_width", "scale")
+)
 def paged_pool_attention(
     q: jnp.ndarray,        # [B, KVH, T*G, d]  (packed queries, r = t*G + g)
     k_pool: jnp.ndarray,   # [L, KVH, NB, BLK, d] (or [KVH, NB, BLK, d])
@@ -376,8 +389,16 @@ def paged_pool_attention(
     t_tokens: int = 1,
     layer: Optional[jnp.ndarray] = None,    # int32 layer index into L
     interpret: Optional[bool] = None,
+    v_width: int = 0,
+    scale: Optional[float] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Attend each row's table-mapped pool blocks; no gather, pool read once.
+
+    ``v_width`` > 0 (latent attention): ``v_pool`` is None, a slot's value
+    is the first ``v_width`` columns of its ``k_pool`` row, the output is
+    ``[B, KVH, T*G, v_width]`` and the pool streams once, not twice.
+    ``scale`` overrides the ``1 / sqrt(d)`` score scale (the latent row's
+    width is not the head size the published scale divides by).
 
     The pool carries its LAYER axis and ``layer`` (a traced scalar — the
     layer scan's loop index) selects the plane inside the kernel's index
@@ -404,7 +425,7 @@ def paged_pool_attention(
     """
     _maybe_fault()
     if k_pool.ndim == 4:
-        k_pool, v_pool = k_pool[None], v_pool[None]
+        k_pool, v_pool = k_pool[None], None if v_pool is None else v_pool[None]
         if k_scale is not None:
             k_scale, v_scale = k_scale[None], v_scale[None]
         layer = None
@@ -435,7 +456,10 @@ def paged_pool_attention(
     interpret = _resolve_interpret(interpret)
     TG8 = _round_up(TG, _SUBLANES)
     qg = jnp.pad(q, ((0, 0), (0, 0), (0, TG8 - TG), (0, 0)))
-    scale = 1.0 / (d ** 0.5)
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    assert not (v_width and quantized), "the latent row has no int8 form"
+    dv = v_width or d
 
     P = _blocks_per_step(BLK, MB, KVH, d, k_pool.dtype.itemsize)
     q_pos = q_pos.astype(jnp.int32)
@@ -461,11 +485,12 @@ def paged_pool_attention(
     kv_specs = [
         pl.BlockSpec((1, KVH, 1, BLK, d), kv_map(j)) for j in range(P)
     ]
+    v_specs = [] if v_width else kv_specs
     in_specs = [
-        pl.BlockSpec((1, KVH, TG8, d), row_map), *kv_specs, *kv_specs,
+        pl.BlockSpec((1, KVH, TG8, d), row_map), *kv_specs, *v_specs,
         pl.BlockSpec((1, P, BLK), pos_map),
     ]
-    operands = [qg, *[k_pool] * P, *[v_pool] * P, kpos]
+    operands = [qg, *[k_pool] * P, *[v_pool] * len(v_specs), kpos]
     if quantized:
         # Narrow-sublane scale planes [L, KVH, NB, 1, BLK]: free
         # expand_dims views of the long-lived pool scales — NOT sublane-
@@ -486,19 +511,20 @@ def paged_pool_attention(
             _paged_kernel, scale=scale, n_entries=P, row_steps=NS, kvh=KVH,
             tg8=TG8,
             t_tokens=t_tokens, group=group, quantized=quantized,
+            v_width=v_width,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(n_steps,),
             in_specs=in_specs,
             out_specs=(
-                pl.BlockSpec((1, KVH, TG8, d), row_map),
+                pl.BlockSpec((1, KVH, TG8, dv), row_map),
                 pl.BlockSpec((1, KVH, TG8, _LANES), row_map),
             ),
             scratch_shapes=[
                 pltpu.VMEM((KVH * TG8, _LANES), jnp.float32),
                 pltpu.VMEM((KVH * TG8, _LANES), jnp.float32),
-                pltpu.VMEM((KVH * TG8, d), jnp.float32),
+                pltpu.VMEM((KVH * TG8, dv), jnp.float32),
             ],
         ),
         out_shape=(
@@ -509,7 +535,7 @@ def paged_pool_attention(
             # widens the T=1-vs-T=G+1 numerical gap that flips greedy
             # argmax at near-ties (speculative self-draft acceptance).
             # Decode-sized output: the extra bytes are noise.
-            jax.ShapeDtypeStruct((B, KVH, TG8, d), jnp.float32),
+            jax.ShapeDtypeStruct((B, KVH, TG8, dv), jnp.float32),
             jax.ShapeDtypeStruct((B, KVH, TG8, _LANES), jnp.float32),
         ),
         compiler_params=pltpu.CompilerParams(
@@ -533,9 +559,17 @@ def paged_decode_attention(
     v_scale: Optional[jnp.ndarray] = None,
     layer: Optional[jnp.ndarray] = None,    # int32 index into L
     interpret: Optional[bool] = None,
+    v_width: int = 0,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
     """One decode step of attention over (pool blocks ∪ the step's T new
     slots).
+
+    Latent attention (``v_width`` > 0): ``v_new`` and ``v_pool`` are None,
+    every slot's value is the first ``v_width`` columns of its key row, and
+    the result is ``[B, T, H, v_width]``; ``scale`` is the published score
+    scale (the row's width is not the head size).  One program, not the
+    mesh's: the latent block is refused under a sharded mesh.
 
     The pool pass runs in the Pallas kernel (T consecutive-position
     queries per row share ONE pool sweep — the speculative-verify shape);
@@ -565,7 +599,7 @@ def paged_decode_attention(
     from ..parallel.mesh import current_mesh
 
     mesh = current_mesh()
-    if mesh is not None:
+    if mesh is not None and not v_width:
         from jax.sharding import PartitionSpec as P
 
         tp = mesh.shape.get("tensor", 1)
@@ -623,20 +657,24 @@ def paged_decode_attention(
 
     return _paged_decode_local(
         q, k_new, v_new, k_pool, v_pool, pool_pos, table, q_pos,
-        k_scale, v_scale, layer, interpret,
+        k_scale, v_scale, layer, interpret, v_width, scale,
     )
 
 
 def _paged_decode_local(
     q, k_new, v_new, k_pool, v_pool, pool_pos, table, q_pos,
-    k_scale, v_scale, layer, interpret,
+    k_scale, v_scale, layer, interpret, v_width=0, scale=None,
 ):
     """Single-shard body of ``paged_decode_attention`` (also the whole op
     when no mesh is active)."""
     B, T, H, d = q.shape
     KVH = k_new.shape[2]
     G = H // KVH
-    scale = 1.0 / (d ** 0.5)
+    if scale is None:
+        scale = 1.0 / (d ** 0.5)
+    if v_width:
+        v_new = k_new[..., :v_width]
+    dv = v_width or d
 
     # Head layout h = kvh * G + g (same contract as flash GQA packing);
     # kernel sublane packing r = t*G + g.
@@ -645,9 +683,9 @@ def _paged_decode_local(
     out_pool, lse = paged_pool_attention(
         qg, k_pool, v_pool, pool_pos, table, q_pos,
         k_scale=k_scale, v_scale=v_scale, t_tokens=T, layer=layer,
-        interpret=interpret,
+        interpret=interpret, v_width=v_width, scale=scale,
     )
-    out_pool = out_pool.reshape(B, KVH, T, G, d)
+    out_pool = out_pool.reshape(B, KVH, T, G, dv)
     lse = lse.reshape(B, KVH, T, G)
 
     # New-slot scores [B, KVH, T, G, T]: token t attends the step's own
@@ -672,5 +710,5 @@ def _paged_decode_local(
     out = (
         out_pool.astype(jnp.float32) * w_pool[..., None] + new_contrib
     ) / denom[..., None]
-    out = jnp.swapaxes(out, 1, 2).reshape(B, T, H, d)
+    out = jnp.swapaxes(out, 1, 2).reshape(B, T, H, dv)
     return out.astype(q.dtype)

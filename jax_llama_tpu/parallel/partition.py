@@ -44,6 +44,8 @@ def param_partition_specs(
     """
     f = "fsdp" if fsdp else None
     s = "stage" if pp else None
+    if config.latent_attention:
+        return _latent_specs(config)
     specs: Dict[str, Any] = {
         # Vocab-sharded over BOTH model axes, hidden dim unsharded: a
         # vocab-sharded table lowers the token gather to masked-gather +
@@ -70,6 +72,42 @@ def param_partition_specs(
     return specs
 
 
+# The mesh axis the routed experts' E axis lies on.  `validate_tp` holds the
+# latent-attention block to one chip, so it is named here and unused: experts
+# spread over chips need the token exchange this program does not have yet.
+EXPERT_AXIS = "tensor"
+
+
+def _latent_specs(config: LLaMAConfig) -> Dict[str, Any]:
+    """Specs mirroring `models.mla_moe.init_params`: heads and the dense
+    FFN's F axis over ``tensor`` as in the dense block, the latent
+    projections replicated (the latent is shared by every head), experts
+    over ``EXPERT_AXIS``."""
+    t = "tensor"
+    attention = {
+        "attn_norm": P(None, None), "q": P(None, t, None, None),
+        "kv_a": P(None, None, None), "kv_norm": P(None, None),
+        "kv_b": P(None, t, None, None), "o": P(None, t, None, None),
+        "mlp_norm": P(None, None),
+    }
+    moe = dict(
+        attention,
+        router=P(None, None, None), router_bias=P(None, None),
+        experts_gate_up=P(None, EXPERT_AXIS, None, None),
+        experts_down=P(None, EXPERT_AXIS, None, None),
+    )
+    if config.n_shared_experts:
+        moe.update(shared_gate_up=P(None, None, None, t),
+                   shared_down=P(None, t, None))
+    return {
+        "embed": {"embedding": P(t, None)},
+        "dense_layers": dict(
+            attention, gate_up=P(None, None, None, t), down=P(None, t, None)),
+        "moe_layers": moe,
+        "final_norm": P(None), "lm_head": P(None, t),
+    }
+
+
 def validate_tp(config: LLaMAConfig, mesh: Mesh, *, fsdp: bool = False) -> None:
     """Check mesh axes divide the dims they shard — a clear error here
     beats the opaque one device_put raises mid-tree.
@@ -78,6 +116,13 @@ def validate_tp(config: LLaMAConfig, mesh: Mesh, *, fsdp: bool = False) -> None:
     own: its sharding propagates from the constrained k/v projections that
     write it.)
     """
+    if config.latent_attention and (
+        fsdp or any(n > 1 for n in mesh.shape.values())
+    ):
+        raise ValueError(
+            "the latent-attention block runs on one chip: tensor / serve-mesh "
+            f"sharding (mesh {dict(mesh.shape)}, fsdp={fsdp}) is not supported"
+        )
     st = mesh.shape.get("stage", 1)
     if config.n_layers % st:
         raise ValueError(

@@ -206,6 +206,10 @@ def mesh_shape(mesh: Optional[Mesh]) -> Dict[str, int]:
 # Canonical partition specs
 # ---------------------------------------------------------------------------
 
+# The leaves of a BlockPool that are placed (its routing counts replicate).
+_POOL_LEAVES = ("k", "v", "pos", "k_scale", "v_scale")
+
+
 def pool_pspec(name: str, ndim: int) -> P:
     """Spec for one BlockPool leaf (or its staged-restore twin, which
     shares the layout): k/v ``[L, KVH, NB, BLK, hd]`` and scales
@@ -236,11 +240,7 @@ def shard_pool(pool, mesh: Mesh):
             arr, NamedSharding(mesh, pool_pspec(name, arr.ndim))
         )
 
-    return dataclasses.replace(
-        pool,
-        k=put("k"), v=put("v"), pos=put("pos"),
-        k_scale=put("k_scale"), v_scale=put("v_scale"),
-    )
+    return dataclasses.replace(pool, **{n: put(n) for n in _POOL_LEAVES})
 
 
 def place_rows(mesh: Optional[Mesh], x) -> jax.Array:
@@ -327,7 +327,7 @@ def constrain_view(view):
     return dataclasses.replace(
         view,
         k=_constrain(view.k, spec_kv),
-        v=_constrain(view.v, spec_kv),
+        v=None if view.v is None else _constrain(view.v, spec_kv),
         pos=_constrain(view.pos, P(rows, None)),
         k_scale=(
             None if view.k_scale is None
@@ -347,22 +347,12 @@ def constrain_pool(pool):
     No-op when no mesh is active (the single-chip trace is unchanged)."""
     if current_mesh() is None:
         return pool
-    return dataclasses.replace(
-        pool,
-        k=_constrain(pool.k, pool_pspec("k", pool.k.ndim)),
-        v=_constrain(pool.v, pool_pspec("v", pool.v.ndim)),
-        pos=_constrain(pool.pos, pool_pspec("pos", pool.pos.ndim)),
-        k_scale=_constrain(
-            pool.k_scale,
-            None if pool.k_scale is None
-            else pool_pspec("k_scale", pool.k_scale.ndim),
-        ),
-        v_scale=_constrain(
-            pool.v_scale,
-            None if pool.v_scale is None
-            else pool_pspec("v_scale", pool.v_scale.ndim),
-        ),
-    )
+    # A pool is described by the planes it has (a latent pool: k and pos).
+    return dataclasses.replace(pool, **{
+        name: _constrain(plane, pool_pspec(name, plane.ndim))
+        for name in _POOL_LEAVES
+        for plane in [getattr(pool, name)] if plane is not None
+    })
 
 
 def constrain_rows(*arrays) -> Tuple:

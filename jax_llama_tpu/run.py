@@ -217,9 +217,11 @@ def main() -> None:
                          "overlapped on the decode chunk (a restoring "
                          "request waits; decode rows never stall).  "
                          "0 (default) disables the tier; size it to "
-                         "taste — each block holds "
-                         "2*n_layers*kv_heads*block_size*head_dim KV "
-                         "entries per model")
+                         "taste — a block's bytes follow from the "
+                         "model's cache planes "
+                         "(kvcache.pool_block_bytes; the server's "
+                         "start-up line and describe() give it as "
+                         "block_bytes)")
     ap.add_argument("--logprobs", action="store_true",
                     help="compute per-token model logprobs so HTTP "
                          "requests may ask for them (\"logprobs\": true)")

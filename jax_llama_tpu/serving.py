@@ -209,10 +209,12 @@ from .models.llama import (
     forward,
     init_cache,
     lm_head_logits,
+    moe_stats_zero,
     paged_pool_write,
     paged_write_indices,
 )
 from .ops import kernels as _kernels_mod
+from .ops.moe import STATS as _MOE_STATS
 from .ops.attention import NEG_INF
 from .ops.sampling import stop_token_hits
 from .parallel.mesh import use_mesh
@@ -232,7 +234,7 @@ from .spec_decode import (
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["k", "v", "pos", "k_scale", "v_scale"],
+    data_fields=["k", "v", "pos", "k_scale", "v_scale", "stats"],
     meta_fields=[],
 )
 @dataclasses.dataclass
@@ -245,13 +247,23 @@ class BlockPool:
     pos:  [n_blocks, block_size] int32 absolute position per cache slot;
           -1 marks invalid (free block / unwritten / rolled back).
     k_scale, v_scale: [L, KVH, n_blocks, block_size] fp32 (int8 pool only).
+
+    A pool is described by the planes it has (``_PLANES``; a field that is
+    None is a plane the pool lacks).  Latent attention keeps ONE: ``k`` is
+    [L, 1, n_blocks, block_size, kv_lora_rank + qk_rope_head_dim] — the
+    normed latent beside the rotated shared key, nothing per head — and
+    ``v`` is None.  The allocator, the block tables and the prefix store
+    see blocks, not planes, and are the same for both.
+    stats: [ops.moe.N_STATS] int32 routing counts since the last packed
+          fetch took them (routed-expert configurations only).
     """
 
     k: jnp.ndarray
-    v: jnp.ndarray
+    v: Optional[jnp.ndarray]
     pos: jnp.ndarray
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
+    stats: Optional[jnp.ndarray] = None
 
     @property
     def n_blocks(self) -> int:
@@ -273,16 +285,31 @@ def init_pool(
     int8_kv = config.kv_cache_dtype == "int8"
     dtype = jnp.int8 if int8_kv else config.activation_dtype
     shape = (
-        config.n_layers, config.kv_heads, n_blocks, block_size,
-        config.head_dim,
+        config.n_layers, config.cache_heads, n_blocks, block_size,
+        config.cache_width,
     )
+    latent = config.latent_attention
     return BlockPool(
         k=jnp.zeros(shape, dtype=dtype),
-        v=jnp.zeros(shape, dtype=dtype),
+        v=None if latent else jnp.zeros(shape, dtype=dtype),
         pos=jnp.full((n_blocks, block_size), -1, jnp.int32),
         k_scale=jnp.zeros(shape[:-1], jnp.float32) if int8_kv else None,
         v_scale=jnp.zeros(shape[:-1], jnp.float32) if int8_kv else None,
+        stats=moe_stats_zero() if latent else None,
     )
+
+
+# The per-(layer, head) planes a pool may have; ``pos`` is per block only.
+_PLANES = ("k", "v", "k_scale", "v_scale")
+
+
+def _map_planes(fn, pool_like, *others):
+    """{name: fn(plane, *others' planes)} over the planes ``pool_like`` has
+    (a BlockPool or a cache of the same field names)."""
+    return {
+        n: fn(getattr(pool_like, n), *(getattr(o, n) for o in others))
+        for n in _PLANES if getattr(pool_like, n) is not None
+    }
 
 
 def _gather_cache(
@@ -311,15 +338,12 @@ def _gather_cache(
         out = out.reshape(a.shape[:2] + (B, MB * BLK) + a.shape[4:])
         return jnp.moveaxis(out, 1, 3)
 
-    kg, vg = g(pool.k), g(pool.v)
     posg = take(pool.pos, table, axis=0).reshape(B, MB * BLK)
     valid = jnp.arange(MB, dtype=jnp.int32)[None, :] < n_alloc[:, None]
     posg = jnp.where(jnp.repeat(valid, BLK, axis=1), posg, -1)
-    ks = vs = None
-    if pool.quantized:
-        ks, vs = g(pool.k_scale), g(pool.v_scale)
     view = KVCache(
-        k=kg, v=vg, pos=posg, index=fill, k_scale=ks, v_scale=vs
+        **{"v": None, **_map_planes(g, pool)}, pos=posg, index=fill,
+        stats=pool.stats,
     )
     if placed:
         # Pin the gathered view to the pool's own KV-head sharding:
@@ -350,34 +374,23 @@ def _scatter_back(
     blk, off, safe_cols = paged_write_indices(
         table, fill, active, T, NB, BLK
     )
-    # view slices are [L, B, T, KVH, ...]; the pool wants KVH-major.
-    nk = jnp.moveaxis(view.k[:, rows, safe_cols], 3, 1)   # [L, KVH, B, T, hd]
-    nv = jnp.moveaxis(view.v[:, rows, safe_cols], 3, 1)
     npos = view.pos[rows, safe_cols]       # [B, T]
-    # paged_pool_write = unrolled in-place dynamic_update_slices; the
-    # batched scatter form forced four full-pool layout copies per step
-    # (see its docstring).
-    new = dataclasses.replace(
+    # view slices are [L, B, T, KVH, ...]; the pool wants KVH-major
+    # ([L, KVH, B, T, ...]).  paged_pool_write = unrolled in-place
+    # dynamic_update_slices; the batched scatter form forced four
+    # full-pool layout copies per step (see its docstring).
+    return dataclasses.replace(
         pool,
-        k=paged_pool_write(pool.k, nk, blk, off),
-        v=paged_pool_write(pool.v, nv, blk, off),
+        **_map_planes(
+            lambda plane, seen: paged_pool_write(
+                plane, jnp.moveaxis(seen[:, rows, safe_cols], 3, 1),
+                blk, off,
+            ),
+            pool, view,
+        ),
         pos=paged_pool_write(pool.pos, npos, blk, off),
+        stats=view.stats,
     )
-    if pool.quantized:
-        new = dataclasses.replace(
-            new,
-            k_scale=paged_pool_write(
-                pool.k_scale,
-                jnp.moveaxis(view.k_scale[:, rows, safe_cols], 3, 1),
-                blk, off,
-            ),
-            v_scale=paged_pool_write(
-                pool.v_scale,
-                jnp.moveaxis(view.v_scale[:, rows, safe_cols], 3, 1),
-                blk, off,
-            ),
-        )
-    return new
 
 
 # ---------------------------------------------------------------------------
@@ -511,19 +524,12 @@ def _decode_step_core(
     its model logprob or None, carried keys, updated pool)."""
     positions = jnp.where(active, pos, -1)[:, None]
     if use_kernel:
-        pcache = PagedKVCache(
-            k=pool.k, v=pool.v, pos=pool.pos,
-            table=table, fill=fill,
-            k_scale=pool.k_scale, v_scale=pool.v_scale,
-        )
         logits, pcache = forward(
-            params, tau[:, None], positions, config, cache=pcache,
+            params, tau[:, None], positions, config,
+            cache=_pool_as_cache(pool, table, fill),
             attn_mask=active[:, None],
         )
-        pool = dataclasses.replace(
-            pool, k=pcache.k, v=pcache.v, pos=pcache.pos,
-            k_scale=pcache.k_scale, v_scale=pcache.v_scale,
-        )
+        pool = _cache_into_pool(pool, pcache)
     else:
         view = _gather_cache(pool, table, n_alloc, fill, placed=placed)
         logits, view = forward(
@@ -751,6 +757,7 @@ def _chunk_scan(
         packed = jnp.stack([toks, lp_bits])  # [2, B, K]
     else:
         packed = toks[None]  # [1, B, K]
+    packed, pool = _pack_stats(packed, pool)
     return (
         packed, tau, tau_lp, fill, pos, active, remaining, keys, pool
     )
@@ -999,37 +1006,27 @@ def _paged_insert(
         # failed by the host at the next emit boundary.
         tau = jnp.where(finite_rows(logits_last), tau, -1)
 
-        L, KVH, _, _, hd = pool.k.shape
+        L, KVH = pool.k.shape[:2]
         nb = P // BLK
 
-        def to_blocks(a):  # [L, k, P, KVH, ...] -> [L, KVH, k, nb, BLK, ...]
-            return jnp.moveaxis(a, 3, 1).reshape(
-                (L, KVH, k_rows, nb, BLK) + a.shape[4:]
+        def land(plane, a):
+            # [L, k, P, KVH, ...] -> [L, KVH, k, nb, BLK, ...]; block_ids is
+            # [k, nb] and its sentinel entries (NB) drop their update.
+            return plane.at[:, :, block_ids].set(
+                jnp.moveaxis(a, 3, 1).reshape(
+                    (L, KVH, k_rows, nb, BLK) + a.shape[4:]
+                ),
+                mode="drop",
             )
 
-        # block_ids is [k, nb]; sentinel entries (NB) drop their update.
         pool = dataclasses.replace(
             pool,
-            k=pool.k.at[:, :, block_ids].set(
-                to_blocks(sub.k), mode="drop"
-            ),
-            v=pool.v.at[:, :, block_ids].set(
-                to_blocks(sub.v), mode="drop"
-            ),
+            **_map_planes(land, pool, sub),
             pos=pool.pos.at[block_ids].set(
                 sub.pos.reshape(k_rows, nb, BLK), mode="drop"
             ),
+            stats=_add_stats(pool.stats, sub.stats),
         )
-        if pool.quantized:
-            pool = dataclasses.replace(
-                pool,
-                k_scale=pool.k_scale.at[:, :, block_ids].set(
-                    to_blocks(sub.k_scale), mode="drop"
-                ),
-                v_scale=pool.v_scale.at[:, :, block_ids].set(
-                    to_blocks(sub.v_scale), mode="drop"
-                ),
-            )
         # Serving-mesh placement: the donated pool leaves the insert
         # with the same canonical sharding it arrived with (``placed``
         # is the ctor's decision — the SAME predicate every other
@@ -1131,14 +1128,61 @@ def _release_blocks(pos, block_ids):
 def _pool_as_cache(pool: BlockPool, table, fill) -> PagedKVCache:
     return PagedKVCache(
         k=pool.k, v=pool.v, pos=pool.pos, table=table, fill=fill,
-        k_scale=pool.k_scale, v_scale=pool.v_scale,
+        k_scale=pool.k_scale, v_scale=pool.v_scale, stats=pool.stats,
     )
 
 
 def _cache_into_pool(pool: BlockPool, pcache: PagedKVCache) -> BlockPool:
     return dataclasses.replace(
         pool, k=pcache.k, v=pcache.v, pos=pcache.pos,
-        k_scale=pcache.k_scale, v_scale=pcache.v_scale,
+        k_scale=pcache.k_scale, v_scale=pcache.v_scale, stats=pcache.stats,
+    )
+
+
+def _refuse_latent_extras(params, draft_params, mesh) -> None:
+    """What the latent-attention block does not get yet is refused at
+    server start, by name, never served wrongly."""
+    from .ops.quant import QuantizedTensor
+
+    if draft_params is not None:
+        raise ValueError(
+            "speculative decoding (--draft-*) is not supported with latent "
+            "attention"
+        )
+    if any(
+        isinstance(x, QuantizedTensor) for x in jax.tree_util.tree_leaves(
+            params, is_leaf=lambda x: isinstance(x, QuantizedTensor))
+    ):
+        raise ValueError(
+            "--quantize (int8 weights) is not supported with latent "
+            "attention and routed experts"
+        )
+    if mesh is not None and any(n > 1 for n in mesh.shape.values()):
+        raise ValueError(
+            "--serve-mesh / tensor sharding is not supported with latent "
+            f"attention: it runs on one chip (mesh {dict(mesh.shape)})"
+        )
+
+
+def _add_stats(a, b):
+    return None if a is None else a + b
+
+
+def _pack_stats(packed: jnp.ndarray, pool: BlockPool):
+    """Routed-expert configurations: the routing counts since the last
+    fetch ride the chunk's ONE packed fetch as trailing int32 planes (as
+    many [B, K] planes as N_STATS values need), and the pool's counters
+    start again from zero.  Every other pool: unchanged."""
+    if pool.stats is None:
+        return packed, pool
+    _, B, K = packed.shape
+    n = -(-pool.stats.shape[0] // (B * K))
+    planes = jnp.pad(
+        pool.stats, (0, n * B * K - pool.stats.shape[0])
+    ).reshape(n, B, K)
+    return (
+        jnp.concatenate([packed, planes], axis=0),
+        dataclasses.replace(pool, stats=jnp.zeros_like(pool.stats)),
     )
 
 
@@ -1923,6 +1967,8 @@ class ContinuousBatcher:
             )
         self.spec = draft_params is not None
         self.logprobs = logprobs
+        if config.latent_attention:
+            _refuse_latent_extras(params, draft_params, mesh)
         if self.spec:
             if draft_config is None:
                 raise ValueError("draft_params requires draft_config")
@@ -2226,6 +2272,10 @@ class ContinuousBatcher:
         # prefill_budget > 0; approximate on the suffix path, whose
         # dispatch is async).
         self.prefill_chunks_total = 0
+        # Routed experts: ``ops.moe.STATS`` from the router's own output,
+        # summed over the chunk fetches that brought them.  Zero on a
+        # configuration without.
+        self.moe_totals = dict.fromkeys(_MOE_STATS, 0)
         self.fused_admissions_total = 0
         self.decode_stall_ms_total = 0.0
 
@@ -2632,6 +2682,7 @@ class ContinuousBatcher:
                 pf.remaining_tokens if pf is not None else 0
             ),
             "prefill_chunks_total": self.prefill_chunks_total,
+            **{f"moe_{k}_total": v for k, v in self.moe_totals.items()},
             "fused_admissions_total": self.fused_admissions_total,
             "decode_stall_ms_total": round(self.decode_stall_ms_total, 3),
         })
@@ -3078,6 +3129,15 @@ class ContinuousBatcher:
         arr = np.asarray(packed)
         self.host_syncs_total += 1
         now_obs = time.monotonic()
+        moe_counts = None
+        if self.pool.stats is not None:
+            # Trailing planes of the same fetch (``_pack_stats``).
+            moe_counts = [
+                int(v) for v in arr[2 if self.logprobs else 1:]
+                .reshape(-1)[:len(_MOE_STATS)]
+            ]
+            for name, v in zip(_MOE_STATS, moe_counts):
+                self.moe_totals[name] += v
         self.obs.record_dispatch(
             kind=kind,
             k=K, occupancy=len(obs_rids), prefill_tokens=pf_adv,
@@ -3085,7 +3145,7 @@ class ContinuousBatcher:
             fetch_ms=(now_obs - tf_obs) * 1000.0,
             swap_inflight=len(self._restoring), rids=obs_rids,
             program=prog, flops=cost_fl, bytes_accessed=cost_by,
-            then="emit",
+            then="emit", moe=moe_counts,
         )
         if pf_done_rid is not None:
             # The prefill's last chunk linked into the prefilling span

@@ -162,6 +162,12 @@ def train_step(
     """
     from .parallel.mesh import current_mesh, use_mesh
 
+    if config.latent_attention:
+        raise NotImplementedError(
+            "the training step is not supported with latent attention and "
+            "routed experts: the block is served, not trained (no router "
+            "balance loss, no grouped-matmul gradient)"
+        )
     if mesh is None and current_mesh() is not None:
         # Entering use_mesh(None) here would silently disable every
         # sharding constraint the ambient mesh was meant to drive; fail

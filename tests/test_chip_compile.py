@@ -162,3 +162,44 @@ def test_stock_paged_decode_compiles_for_v5e(sds):
         sds((), jnp.int32), interpret=False,
     )
     _assert_mosaic(lowered)
+
+
+def test_latent_paged_decode_compiles_for_v5e(sds):
+    """The paged kernel over a LATENT pool at kanana-2-30b-a3b's geometry
+    and the benchmark cell's (8 rows x 32 blocks of 512, 8 layers): one
+    cache head whose 640-wide row (512 latent + 64 rope + lane padding)
+    is key and, in its first 512 columns, value; 32 query heads share it."""
+    from jax_llama_tpu.ops.paged_attention import paged_pool_attention
+
+    rows, mb, blk, layers, width = 8, 32, 512, 8, 640
+    nb = rows * mb
+    lowered = paged_pool_attention.lower(
+        sds((rows, 1, 32, width), jnp.bfloat16),
+        sds((layers, 1, nb, blk, width), jnp.bfloat16), None,
+        sds((nb, blk), jnp.int32), sds((rows, mb), jnp.int32),
+        sds((rows,), jnp.int32), t_tokens=1, layer=sds((), jnp.int32),
+        interpret=False, v_width=512, scale=192 ** -0.5,
+    )
+    _assert_mosaic(lowered)
+
+
+@pytest.mark.parametrize(
+    "m,k,n", [(128, 2048, 1536), (12416, 2048, 1536), (12416, 768, 2048)],
+    ids=["decode-gate_up", "chunk-gate_up", "chunk-down"],
+)
+def test_grouped_expert_matmul_compiles_for_v5e(sds, m, k, n):
+    """The grouped matmul of ops/moe.py (upstream megablox) at the tilings
+    `_tiling` picks for kanana-2-30b-a3b's experts (128 of 2048 x 1536 and
+    768 x 2048 in each of 7 expert layers, all handed to the kernel): 48
+    decode rows padded to one tile, and a 2048-token chunk's 12,336 (token,
+    expert) pairs."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from jax_llama_tpu.ops.moe import _tiling
+
+    fn = jax.jit(lambda x, w, g: gmm(
+        x, w, g, preferred_element_type=jnp.bfloat16, tiling=_tiling(k, n)))
+    _assert_mosaic(fn.lower(
+        sds((m, k), jnp.bfloat16), sds((7 * 128, k, n), jnp.bfloat16),
+        sds((7 * 128,), jnp.int32),
+    ))
