@@ -455,6 +455,8 @@ def _make_batcher_stub():
     s._pf = None
     s.prefill_chunks_total = 0
     s.moe_totals = {}
+    s.prefill_ctx_slots_attended_total = 0
+    s.prefill_ctx_slots_view_total = 0
     s.fused_admissions_total = 0
     s.decode_stall_ms_total = 0.0
     s.prefix_index = "radix"

@@ -20,7 +20,9 @@ no `v` plane.  Two forms of the same attention:
 
 - decompressed (prompt chunks): k_nope and v of every attendable slot are
   rebuilt from the cached latent, then ordinary multi-head attention — the
-  flash kernel with v zero-padded to the q/k width, or plain XLA;
+  flash kernel with v zero-padded to the q/k width, or plain XLA.  Behind a
+  cache with a scalar index the flash form rebuilds the live context only,
+  a tile at a time for a trip count that is a value (`attend_tiled`);
 - absorbed (decode): W_kvb's key half is folded into the query and its
   value half applied after the sum, so the 32 heads attend the latent rows
   themselves as one shared key/value head (`paged_decode_attention` with
@@ -61,7 +63,9 @@ from jax import lax
 from ..config import LLaMAConfig
 from ..ops import moe
 from ..ops.attention import attention_bias, sdpa
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import (
+    flash_attention, flash_attention_lse, merge_attention,
+)
 from ..ops.norm import rms_norm
 from ..ops.rope import apply_rope
 
@@ -153,18 +157,24 @@ def attend_absorbed(q_abs, latent, bias, r: int, scale: float):
     return jnp.einsum("bhts,bsc->bthc", p.astype(latent.dtype), latent[..., :r])
 
 
-def attend_decompressed(q_nope, q_rope, latent, kv_b, q_pos, kv_pos, bias,
-                        config: LLaMAConfig, use_flash: bool):
-    """Multi-head attention over K/V rebuilt from latent rows [B,S,w];
-    [B,T,H,dv].  `bias` is used by the XLA path, the positions by flash."""
-    r, dn, dv = config.kv_lora_rank, config.qk_nope_head_dim, config.v_head_dim
+def _decompress(latent, kv_b, config: LLaMAConfig):
+    """Per-head keys [B,S,H,nope+rope] and values [B,S,H,dv] rebuilt from
+    latent rows [B,S,w]: k_nope | v = c W_kvb, the shared rope key on every head."""
+    r, dn = config.kv_lora_rank, config.qk_nope_head_dim
     H, dr = config.n_heads, config.qk_rope_head_dim
     kv = jnp.einsum("bsc,hck->bshk", latent[..., :r], kv_b.astype(latent.dtype))
     k_rope = jnp.broadcast_to(
         latent[:, :, None, r:r + dr], latent.shape[:2] + (H, dr))
+    return jnp.concatenate([kv[..., :dn], k_rope], axis=-1), kv[..., dn:]
+
+
+def attend_decompressed(q_nope, q_rope, latent, kv_b, q_pos, kv_pos, bias,
+                        config: LLaMAConfig, use_flash: bool):
+    """Multi-head attention over K/V rebuilt from latent rows [B,S,w];
+    [B,T,H,dv].  `bias` is used by the XLA path, the positions by flash."""
+    dv = config.v_head_dim
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate([kv[..., :dn], k_rope], axis=-1)
-    v = kv[..., dn:]
+    k, v = _decompress(latent, kv_b, config)
     if not use_flash:
         return sdpa(q, k, _pad_last(v, q.shape[-1]), bias,
                     softmax_dtype=jnp.dtype(config.attn_softmax_dtype))[..., :dv]
@@ -172,6 +182,57 @@ def attend_decompressed(q_nope, q_rope, latent, kv_b, q_pos, kv_pos, bias,
     # which is also the width the published scale divides by.
     out = flash_attention(q, k, _pad_last(v, q.shape[-1]), q_pos, kv_pos)
     return out[..., :dv]
+
+
+# The context walk's tile: the flash kernel's key block.
+CTX_TILE = 2048
+
+
+def ctx_tiles(index, view: int):
+    """(tile, trips) of the walk over a cached context: fixed tiles of
+    `CTX_TILE` slots (the whole view where that is narrower), as many as
+    hold a slot below `index`.  One rule for the device's loop (`index`
+    traced) and the host's counters (`index` an int)."""
+    tile = min(CTX_TILE, view)
+    return tile, (index + tile - 1) // tile
+
+
+def attend_tiled(q_nope, q_rope, latent, kv_b, q_pos, new_pos, cache, layer,
+                 config: LLaMAConfig):
+    """`attend_decompressed`'s flash form for a chunk [B,T] behind a cache
+    with a scalar index, doing work for the live context only: the chunk
+    attends itself, then the cached rows below `cache.index` a tile at a
+    time — each tile sliced from the cache, decompressed, attended by the
+    flash kernel and merged by its row log-sum-exp (float32) — for
+    `ctx_tiles` trips, a value.  Nothing of the view's width is rebuilt;
+    the dead slots of the last tile are masked by their position, -1."""
+    dv, adt = config.v_head_dim, latent.dtype
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+
+    def part(rows, kv_pos):
+        k, v = _decompress(rows, kv_b, config)
+        out, lse = flash_attention_lse(
+            q, k, _pad_last(v, q.shape[-1]), q_pos, kv_pos)
+        return out[..., :dv], lse
+
+    B, view, w = q.shape[0], cache.max_len, cache.k.shape[-1]
+    tile, trips = ctx_tiles(cache.index, view)
+
+    def trip(t, acc):
+        # A view that is no multiple of the tile: the last tile is moved
+        # back inside it, and the slots it then shares with the tile before
+        # are masked.
+        start = jnp.minimum(t * tile, view - tile)
+        rows = lax.dynamic_slice(
+            cache.k, (layer, 0, start, 0, 0), (1, B, tile, 1, w))[0, :, :, 0]
+        pos = lax.dynamic_slice(cache.pos, (0, start), (B, tile))
+        own = start + jnp.arange(tile, dtype=jnp.int32) >= t * tile
+        return merge_attention(
+            *acc, *part(rows.astype(adt), jnp.where(own[None], pos, -1)))
+
+    out, lse = part(latent, new_pos)
+    out, _ = lax.fori_loop(0, trips, trip, (out.astype(jnp.float32), lse))
+    return out.astype(adt)
 
 
 def _ffn_moe(h, lp, experts, layer, valid, config: LLaMAConfig):
@@ -256,7 +317,9 @@ def forward(
     absorbed = cache is not None and T <= FLASH_MIN_SEQ
     use_flash = (not absorbed and T > FLASH_MIN_SEQ
                  and config.attn_impl in ("flash", "auto"))
-    if not paged:
+    # A scalar index says which cached slots are live: the walk by tiles.
+    tiled = use_flash and not paged and cache is not None and not cache.per_row_index
+    if not paged and not tiled:
         kv_pos = new_pos if cache is None else jnp.concatenate(
             [cache.pos, new_pos], axis=1)
         bias = None if use_flash else attention_bias(q_positions, kv_pos, kv_pos >= 0)
@@ -283,6 +346,11 @@ def forward(
                     absorb_query(q_nope, q_rope, kv_b, dn, config.cache_width), latent[:, :, None, :],
                     None, cache.k, None, cache.pos, cache.table, q_pos_row,
                     layer=li, v_width=r, scale=scale)
+        elif tiled:
+            with jax.named_scope("mla.attend_prefill"):
+                attn = attend_tiled(
+                    q_nope, q_rope, latent, kv_b, q_positions, new_pos,
+                    cache, li, config)
         else:
             seen = latent if cache is None else jnp.concatenate([
                 lax.dynamic_index_in_dim(cache.k, li, 0, keepdims=False)[:, :, 0]
@@ -291,14 +359,14 @@ def forward(
                 with jax.named_scope("mla.attend_decode"):
                     o_lat = attend_absorbed(
                         absorb_query(q_nope, q_rope, kv_b, dn, config.cache_width), seen, bias, r, scale)
+            else:
+                with jax.named_scope("mla.attend_prefill"):
+                    attn = attend_decompressed(
+                        q_nope, q_rope, seen, kv_b, q_positions, kv_pos, bias,
+                        config, use_flash)
         if paged or absorbed:
             with jax.named_scope("mla.project"):
                 attn = jnp.einsum("bthc,hck->bthk", o_lat, kv_b[..., dn:].astype(adt))
-        else:
-            with jax.named_scope("mla.attend_prefill"):
-                attn = attend_decompressed(
-                    q_nope, q_rope, seen, kv_b, q_positions, kv_pos, bias,
-                    config, use_flash)
         with jax.named_scope("mla.project"):
             x = x + qeinsum(attn, lp["o"], "bthk,hkd->btd", adt)
         h = rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)

@@ -441,6 +441,13 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "Chunk dispatches that carried a prefill lane"),
     "fused_admissions_total": _reg(
         "counter", "Admissions routed through the fused prefill lane"),
+    "prefill_ctx_slots_attended_total": _reg(
+        "counter", "Slots of the prefilling row's view that fused prefill "
+                   "attention did work for (live tiles for the latent "
+                   "block, the whole view otherwise)"),
+    "prefill_ctx_slots_view_total": _reg(
+        "counter", "Slots of the prefilling row's view, summed over "
+                   "dispatches that carried a prefill lane"),
     # -- routed experts (ops/moe.py; zero on a configuration without) -------
     "moe_assignments_total": _reg(
         "counter", "(token, expert) pairs the router assigned"),
@@ -1414,6 +1421,7 @@ class Observability:
         bytes_accessed: Optional[float] = None,
         then: Optional[str] = None,
         moe: Optional[Sequence[int]] = None,
+        prefill_ctx: Optional[Tuple[int, int]] = None,
     ) -> int:
         """Record one jitted serving dispatch and link it into the
         CURRENT span of every request that rode it.  Returns the
@@ -1435,8 +1443,10 @@ class Observability:
         ``compiles`` (backend compiles booked since the previous record
         ended).  ``moe`` (routed-expert configurations) is the fetch's
         routing counts, in ``ops.moe.STATS``' order, summed over the
-        expert-layer calls.  ``then`` names the phase the loop thread is
-        in once
+        expert-layer calls.  ``prefill_ctx`` (dispatches with a prefill
+        lane) is the (attended, view) slots of the prefilling row's view:
+        what prefill attention did work for, and the view's width.
+        ``then`` names the phase the loop thread is in once
         the dispatch ends (default: the one it interrupted)."""
         if kind not in DISPATCH_KINDS:
             raise ValueError(
@@ -1464,6 +1474,10 @@ class Observability:
             rec["program"] = program
         if moe is not None:
             rec["moe"] = dict(zip(_MOE_STATS, map(int, moe)))
+        if prefill_ctx is not None:
+            rec["prefill_ctx"] = {
+                "attended": int(prefill_ctx[0]), "view": int(prefill_ctx[1]),
+            }
         rec.update(gap)
         est_ms = None
         if flops is not None and bytes_accessed is not None:

@@ -42,7 +42,7 @@ trace hook as ordinary prefill.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -615,6 +615,55 @@ def flash_attention(
         q, k, v, q_pos, kv_pos, seed, block_q, block_k, interpret,
         dropout_rate,
     )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("block_q", "block_k", "interpret")
+)
+def flash_attention_lse(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    q_pos: jnp.ndarray,
+    kv_pos: jnp.ndarray,
+    block_q: int = 2048,
+    block_k: int = 2048,
+    interpret: Optional[bool] = None,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``flash_attention``'s forward over ONE part of a row's keys, with
+    what merging the parts needs: ([B, T, H, d] in q.dtype, the row
+    log-sum-exp [B, T, H] fp32 of the scaled, masked scores — the one the
+    backward kernels already get).  Inference-only (no VJP, no dropout) and
+    one KV head per query head; ``merge_attention`` joins two parts.  A row
+    that attends nothing in this part reads ~``MASK_VALUE`` (finite), so
+    it weighs nothing against a part it does attend.
+    """
+    _maybe_fault()
+    if q.shape[2] != k.shape[2]:
+        raise ValueError(
+            f"flash_attention_lse takes one KV head per query head, got "
+            f"{q.shape[2]} and {k.shape[2]}"
+        )
+    out, lse = _flash_forward(
+        q, k, v, q_pos, kv_pos, block_q, block_k, interpret, need_lse=True
+    )
+    return out, jnp.swapaxes(lse[:, :, : q.shape[1], 0], 1, 2)
+
+
+def merge_attention(out_a, lse_a, out_b, lse_b):
+    """Softmax attention over two disjoint key sets from each set's own
+    (out [..., d], lse [...]): exact in real arithmetic, computed in fp32.
+    Returns the merged (out fp32, lse).  The weights are normalized by
+    their own sum, so two parts a row attends nothing in (both lse
+    ~``MASK_VALUE``, where adding log 2 rounds away) average, not add."""
+    m = jnp.maximum(lse_a, lse_b)
+    w_a, w_b = jnp.exp(lse_a - m), jnp.exp(lse_b - m)
+    den = w_a + w_b
+    out = (
+        (w_a / den)[..., None] * out_a.astype(jnp.float32)
+        + (w_b / den)[..., None] * out_b.astype(jnp.float32)
+    )
+    return out, m + jnp.log(den)
 
 
 @functools.partial(

@@ -213,6 +213,7 @@ from .models.llama import (
     paged_pool_write,
     paged_write_indices,
 )
+from .models.mla_moe import ctx_tiles
 from .ops import kernels as _kernels_mod
 from .ops.moe import STATS as _MOE_STATS
 from .ops.attention import NEG_INF
@@ -2276,6 +2277,8 @@ class ContinuousBatcher:
         # summed over the chunk fetches that brought them.  Zero on a
         # configuration without.
         self.moe_totals = dict.fromkeys(_MOE_STATS, 0)
+        self.prefill_ctx_slots_attended_total = 0
+        self.prefill_ctx_slots_view_total = 0
         self.fused_admissions_total = 0
         self.decode_stall_ms_total = 0.0
 
@@ -2682,6 +2685,10 @@ class ContinuousBatcher:
                 pf.remaining_tokens if pf is not None else 0
             ),
             "prefill_chunks_total": self.prefill_chunks_total,
+            "prefill_ctx_slots_attended_total": (
+                self.prefill_ctx_slots_attended_total
+            ),
+            "prefill_ctx_slots_view_total": self.prefill_ctx_slots_view_total,
             **{f"moe_{k}_total": v for k, v in self.moe_totals.items()},
             "fused_admissions_total": self.fused_admissions_total,
             "decode_stall_ms_total": round(self.decode_stall_ms_total, 3),
@@ -3008,6 +3015,7 @@ class ContinuousBatcher:
             s.request_id for s in self.slots.values() if s is not None
         ]
         pf_adv = 0 if pf is None else min(pf.chunk, pf.remaining_tokens)
+        pf_ctx = None if pf is None else self._pf_ctx_slots(pf, pf_flash)
         pf_done_rid: Optional[int] = None
         all_greedy = bool(np.all(self.temp_arr[self.active] == 0.0))
         if pf is not None:
@@ -3138,6 +3146,9 @@ class ContinuousBatcher:
             ]
             for name, v in zip(_MOE_STATS, moe_counts):
                 self.moe_totals[name] += v
+        if pf_ctx is not None:
+            self.prefill_ctx_slots_attended_total += pf_ctx[0]
+            self.prefill_ctx_slots_view_total += pf_ctx[1]
         self.obs.record_dispatch(
             kind=kind,
             k=K, occupancy=len(obs_rids), prefill_tokens=pf_adv,
@@ -3145,7 +3156,7 @@ class ContinuousBatcher:
             fetch_ms=(now_obs - tf_obs) * 1000.0,
             swap_inflight=len(self._restoring), rids=obs_rids,
             program=prog, flops=cost_fl, bytes_accessed=cost_by,
-            then="emit", moe=moe_counts,
+            then="emit", moe=moe_counts, prefill_ctx=pf_ctx,
         )
         if pf_done_rid is not None:
             # The prefill's last chunk linked into the prefilling span
@@ -4605,6 +4616,20 @@ class ContinuousBatcher:
                 self._admit_shared_group(
                     [(req, chain, hits)], [free[0]]
                 )
+
+    def _pf_ctx_slots(self, pf: _Prefill, flash: bool) -> Tuple[int, int]:
+        """(attended, view) slots of the row's gathered view for the fused
+        dispatch about to advance ``pf``: what prefill attention does work
+        for besides the chunk itself, and the view's width.  Host mirror
+        of ``forward``'s resolution, from numbers the scheduler holds: the
+        latent block's flash form walks the context below the write index
+        ``base + off`` by ``mla_moe.ctx_tiles`` (the device's own rule);
+        every other form takes the whole view."""
+        view = self.blocks_per_slot * self.block_size
+        if not (flash and self.config.latent_attention):
+            return view, view
+        tile, trips = ctx_tiles(pf.base + pf.off, view)
+        return min(trips * tile, view), view
 
     def _pf_chunk(self, suffix_len: int, n_share: int) -> int:
         """Prompt tokens per fused dispatch: ``prefill_budget`` rounded

@@ -18,6 +18,7 @@ fixture would then skip), and compile in the test's own process.
 """
 
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -203,3 +204,39 @@ def test_grouped_expert_matmul_compiles_for_v5e(sds, m, k, n):
         sds((m, k), jnp.bfloat16), sds((7 * 128, k, n), jnp.bfloat16),
         sds((7 * 128,), jnp.int32),
     ))
+
+
+def test_latent_tiled_prefill_compiles_for_v5e(sds, monkeypatch):
+    """`mla_moe.attend_tiled` at kanana-2-30b-a3b's widths and the benchmark
+    cell's view (16,384 slots, a 2048-token chunk, 8 layers): the flash
+    kernel with a row log-sum-exp inside a loop whose trip count is a value,
+    and no operand of the view's width times the heads in the program."""
+    from jax_llama_tpu import config as config_mod
+    from jax_llama_tpu.models import mla_moe
+    from jax_llama_tpu.models.llama import KVCache
+
+    # `interpret=None` asks the default backend, the CPU here: steer it.
+    # (`ops.flash_attention` the attribute is the function, not the module.)
+    monkeypatch.setattr(
+        sys.modules["jax_llama_tpu.ops.flash_attention"], "_resolve_interpret",
+        lambda _: False)
+    cfg = config_mod.LLaMAConfig(
+        n_heads=32, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, dtype="bfloat16")
+    layers, view, chunk, width = 8, 16384, 2048, cfg.cache_width
+    bf16, i32 = jnp.bfloat16, jnp.int32
+
+    def attend(q_nope, q_rope, latent, kv_b, q_pos, new_pos, k, pos, index, layer):
+        cache = KVCache(k=k, v=None, pos=pos, index=index)
+        return mla_moe.attend_tiled(
+            q_nope, q_rope, latent, kv_b, q_pos, new_pos, cache, layer, cfg)
+
+    lowered = jax.jit(attend).lower(
+        sds((1, chunk, 32, 128), bf16), sds((1, chunk, 32, 64), bf16),
+        sds((1, chunk, width), bf16), sds((32, 512, 256), bf16),
+        sds((1, chunk), i32), sds((1, chunk), i32),
+        sds((layers, 1, view, 1, width), bf16), sds((1, view), i32),
+        sds((), i32), sds((), i32),
+    )
+    _assert_mosaic(lowered)
+    assert f"{view + chunk},32" not in lowered.as_text()

@@ -10,6 +10,7 @@ and drops no token; a request served by `ContinuousBatcher` finds its prefix
 in latent blocks; what the block does not get yet is refused by name.
 """
 
+import dataclasses
 import importlib.util
 import json
 from pathlib import Path
@@ -126,6 +127,160 @@ def test_absorbed_equals_decompressed_attention():
     o_lat = mla_moe.attend_absorbed(q_abs, latent, bias, r, (dn + dr) ** -0.5)
     absorbed = jnp.einsum("bthc,hck->bthk", o_lat, kv_b[..., dn:])
     assert np.abs(np.asarray(dec - absorbed)).max() < 2e-5
+
+
+# --- prefill attention over the live context only (PR 29) -------------------
+
+TILE, VIEW, CHUNK = 16, 60, 10   # a view that is no multiple of the tile
+
+
+def _walk_avals(jaxpr):
+    """Every array shape in a jaxpr, sub-programs (scan, while, jit, kernel)
+    included."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield tuple(getattr(v.aval, "shape", ()))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk_avals(sub)
+
+
+def _context(cfg, params, index, seed):
+    """A `VIEW`-slot cache whose first `index` slots hold a real context
+    (written by the plain XLA form) and whose dead slots hold large finite
+    garbage at position -1, and the tokens / positions of the next chunk."""
+    toks, pos = _tokens(1, index + CHUNK, seed=seed)
+    cache = jlt.init_cache(cfg, 1, VIEW)
+    if index:
+        _, cache = jlt.forward(
+            params, toks[:, :index], pos[:, :index], cfg.replace(attn_impl="xla"), cache=cache)
+    cache = dataclasses.replace(cache, k=cache.k.at[:, :, index:].set(1e4))
+    return cache, toks[:, index:], pos[:, index:]
+
+
+@pytest.mark.parametrize("index,pad", [
+    (0, 0), (TILE - 1, 0), (TILE, 0), (TILE + TILE // 2, 3), (VIEW - CHUNK, 0), (VIEW - CHUNK, 3),
+], ids=["empty", "tile-1", "one-tile", "mid-second-tile-padded-tail", "whole-view", "whole-view-padded-tail"])
+def test_tiled_prefill_equals_the_one_piece_form(tiny, monkeypatch, index, pad):
+    """A chunk behind a scalar-index cache: the flash form, which walks the
+    live context by tiles, against the plain XLA form over the whole view —
+    logits of the real rows and the rows written.  A read of a dead slot
+    (1e4) would show; so would a slot attended twice where the last tile is
+    moved back inside the view (`whole-view`: 50 live slots, tiles of 16)."""
+    _, cfg, params = tiny
+    monkeypatch.setattr(mla_moe, "CTX_TILE", TILE)
+    cache, toks, pos = _context(cfg, params, index, seed=index)
+    real = jnp.arange(CHUNK)[None] < CHUNK - pad
+    pos = jnp.where(real, pos, -1)
+    got, got_cache = jlt.forward(params, toks, pos, cfg, cache=cache, attn_mask=real)
+    ref, ref_cache = jlt.forward(
+        params, toks, pos, cfg.replace(attn_impl="xla"), cache=cache, attn_mask=real)
+    n = CHUNK - pad
+    got, ref = np.asarray(got)[:, :n], np.asarray(ref)[:, :n]
+    assert np.abs(got - ref).max() < 1e-4 * np.abs(ref).max()
+    wrote = lambda c: np.asarray(c.k[:, :, index:index + n])  # noqa: E731
+    assert np.abs(wrote(got_cache) - wrote(ref_cache)).max() < 1e-4 * np.abs(wrote(ref_cache)).max()
+    assert int(got_cache.index) == index + CHUNK
+    np.testing.assert_array_equal(np.asarray(got_cache.pos), np.asarray(ref_cache.pos))
+
+
+def test_tile_rule_is_one_for_the_loop_and_the_counters(monkeypatch):
+    monkeypatch.setattr(mla_moe, "CTX_TILE", TILE)
+    assert [mla_moe.ctx_tiles(i, VIEW) for i in (0, 1, 16, 17, 50)] == [
+        (16, 0), (16, 1), (16, 1), (16, 2), (16, 4)]
+    assert mla_moe.ctx_tiles(7, 12) == (12, 1)       # a view under one tile
+    tile, trips = mla_moe.ctx_tiles(jnp.int32(33), VIEW)   # the traced form
+    assert (tile, int(trips)) == (16, 3)
+
+
+def test_nothing_of_the_views_width_is_decompressed(tiny, monkeypatch):
+    """Shapes of the traced program: the flash form behind a scalar-index
+    cache holds no array of the view's slots (with or without the chunk's)
+    times the heads; the one-piece XLA form, walked the same way, does."""
+    _, cfg, params = tiny
+    monkeypatch.setattr(mla_moe, "CTX_TILE", TILE)
+    view, H = 64, cfg.n_heads
+    toks, pos = _tokens(1, CHUNK)
+
+    def wide(c):
+        jaxpr = jax.make_jaxpr(
+            lambda p, t, q, kv: jlt.forward(p, t, q, c, cache=kv)[0])(
+                params, toks, pos + 20, jlt.init_cache(c, 1, view))
+        return {s for s in _walk_avals(jaxpr.jaxpr)
+                if H in s and any(d >= view for d in s)}
+
+    assert wide(cfg) == set()
+    assert any(view + CHUNK in s for s in wide(cfg.replace(attn_impl="xla")))
+
+
+def test_dense_prefill_program_does_not_see_the_new_flash_entry():
+    """The dense block's prefill lowers `flash_attention` as it did: the new
+    entry (`flash_attention_lse`) is beside it, not under it, and the
+    kernel it lowers to has no log-sum-exp output."""
+    from jax_llama_tpu.ops.flash_attention import flash_attention, flash_attention_lse
+
+    dense = jlt.get_config("tiny", attn_impl="auto")
+    dp = jlt.init_params(jax.random.PRNGKey(0), dense)
+    toks, pos = _tokens(1, 16)
+    text = jax.jit(lambda p, t, q: jlt.forward(p, t, q, dense)[0]).lower(
+        dp, toks % dense.vocab_size, pos).as_text()
+    assert "@flash_attention(" in text and "flash_attention_lse" not in text
+    q = jnp.zeros((1, 16, 4, 16), jnp.float32)
+    p = jnp.tile(jnp.arange(16)[None], (1, 1))
+    n_out = lambda f: len(jax.tree.leaves(jax.eval_shape(f, q, q, q, p, p)))  # noqa: E731
+    assert (n_out(flash_attention), n_out(flash_attention_lse)) == (1, 2)
+    out, lse = flash_attention_lse(q, q, q, p, p)
+    assert lse.shape == (1, 16, 4) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(flash_attention(q, q, q, p, p)), atol=1e-6)
+    # q = k = 0: every score is 0, so a row's log-sum-exp is log(slots it attends)
+    np.testing.assert_allclose(
+        np.asarray(lse[0, :, 0]), np.log(np.arange(1, 17)), rtol=1e-5)
+
+
+@pytest.mark.parametrize("block", ["latent", "dense"])
+def test_prefill_context_counters_follow_the_tile_rule(tiny, monkeypatch, block):
+    """One long prompt through the fused lane: `attended` is the sum over
+    its chunks of ceil(index / tile) x tile for the latent block (the rule
+    the device's loop runs: same function), the whole view for the dense
+    block; both on `stats()`, `/metrics`' registry and the dispatch record —
+    and the served tokens are an unbatched `engine.generate`'s."""
+    from jax_llama_tpu.engine import GenerationConfig, generate
+    from jax_llama_tpu.obs import metric_meta
+
+    monkeypatch.setattr(mla_moe, "CTX_TILE", TILE)
+    if block == "latent":
+        # its own max_seq_len: a jit key no other test of the file traced
+        # with another tile
+        cfg, params = tiny[1].replace(max_seq_len=96), tiny[2]
+    else:
+        cfg = jlt.get_config("tiny", max_seq_len=96, dtype="float32", attn_impl="auto")
+        params = jlt.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.RandomState(7)
+    prompt = [int(t) for t in rng.randint(0, cfg.vocab_size, size=75)]
+    cb = jlt.ContinuousBatcher(
+        params, cfg, n_slots=2, block_size=8, decode_chunk=4, prefill_budget=16)
+    # a row in steady decode first: an idle server admits whole prompts
+    cb.submit([int(t) for t in rng.randint(0, cfg.vocab_size, size=9)], max_new_tokens=60)
+    for _ in range(4):
+        cb.step()
+    rid = cb.submit(prompt, max_new_tokens=4)
+    out = cb.run_to_completion()
+    stats = cb.stats()
+    view, chunks = 96, [16 * i for i in range(5)]   # the write index of each chunk
+    assert stats["prefill_chunks_total"] == len(chunks)
+    assert stats["prefill_ctx_slots_view_total"] == view * len(chunks)
+    want = sum(-(-i // TILE) * TILE for i in chunks) if block == "latent" else view * len(chunks)
+    assert stats["prefill_ctx_slots_attended_total"] == want <= view * len(chunks)
+    recs = [d["prefill_ctx"] for d in cb.obs.dispatches if "prefill_ctx" in d]
+    assert [r["view"] for r in recs] == [view] * len(chunks)
+    assert sum(r["attended"] for r in recs) == want
+    for name in ("prefill_ctx_slots_attended_total", "prefill_ctx_slots_view_total"):
+        assert metric_meta(name)[0] == "counter"
+    alone = generate(
+        params, jnp.asarray([prompt]), jnp.ones((1, len(prompt)), bool),
+        jax.random.PRNGKey(0), config=cfg,
+        gen_config=GenerationConfig(max_new_tokens=4, temperature=0.0))
+    assert out[rid] == [int(t) for t in np.asarray(alone)[0, len(prompt):]]
 
 
 def test_the_pool_is_one_latent_plane(tiny):
