@@ -4,7 +4,7 @@
 
 PYTEST := env JAX_PLATFORMS=cpu python -m pytest
 
-.PHONY: tier1 tier1-budget faults chaos tpu chip-smoke perf-smoke kvcache obs overload lint lint-invariants mesh-serve fleet elastic bench-compare check kernels
+.PHONY: tier1 tier1-budget faults chaos tpu chip-smoke perf-smoke kvcache obs overload lint lint-invariants elastic check
 
 # The gating suite: everything not marked slow (the driver runs it with
 # six xdist workers: add `-p xdist -n 6 --dist loadfile`).
@@ -78,34 +78,6 @@ obs:
 overload:
 	$(PYTEST) tests/test_overload.py -q
 
-# Scale-out serving (parallel/serve_mesh.py + router.py): the full
-# mesh_serving suite including the slow matrices (tensor-only mesh,
-# sharded speculative chunk, host-tier restore under sharded
-# placement), the router fault drills, and the multichip_serving
-# dryrun round (sharded-chunk parity + mesh lowering contracts +
-# routed-replica token identity on the forced 8-host-device mesh —
-# what MULTICHIP_r06.json records; add `--record MULTICHIP_rNN.json`
-# to roll a new round).
-mesh-serve:
-	$(PYTEST) tests/test_serve_mesh.py tests/test_router.py -q
-	$(PYTEST) tests/test_faults.py -q -k router
-	$(PYTEST) tests/test_run_cli.py -q -k serve_mesh
-	env JAX_PLATFORMS=cpu python bench.py --multichip-serving
-
-# Globally cache-aware routing (router.py RouterRadixIndex + handoff
-# scheduler + prefill/decode disaggregation): the full cache-routing
-# suite (index/journal units, export/import bounds + demote-after-
-# export, the routed deep-hit / spill-migration / stale-digest /
-# mid-handoff-fault acceptance drills), the slow-marked CLI
-# disaggregation smoke (--route cache-aware --replica-roles), and the
-# fleet-TTFT A/B round (cache-aware vs least-loaded hit ratio +
-# dedup-by-migration — what MULTICHIP_r08.json records; add
-# `--record MULTICHIP_rNN.json` to roll a new round).
-fleet:
-	$(PYTEST) tests/test_cache_routing.py -q
-	$(PYTEST) tests/test_run_cli.py -q -k 'cache_aware or replica'
-	env JAX_PLATFORMS=cpu python bench.py --multichip-serving
-
 # Elastic fleet (FleetController): autoscaler hysteresis, drain-by-
 # migration (zero dropped sessions, token-identical), zero-downtime
 # rollouts with the per-rung canary gate, and the scale_event /
@@ -146,19 +118,6 @@ check: lint-invariants
 	$(PYTEST) tests/test_analysis.py -q -m 'not slow'
 	$(MAKE) perf-smoke
 
-# Machine-check the bench trajectory: diff headline keys between two
-# BENCH_*/MULTICHIP_* records and exit non-zero past tolerance
-# (bench.py --compare; override OLD/NEW/TOL, e.g.
-# `make bench-compare OLD=BENCH_r05.json NEW=BENCH_r07.json`).
-# Heterogeneous rounds that share no headline keys warn instead of
-# failing — the gate bites on same-shaped rounds (the next TPU round
-# vs r05's chip numbers).
-OLD ?= BENCH_r05.json
-NEW ?= BENCH_r06.json
-TOL ?= 5
-bench-compare:
-	env JAX_PLATFORMS=cpu python bench.py --compare $(OLD) $(NEW) --tolerance $(TOL)
-
 # The full lint gate (alias kept separate so CI can grow style/type
 # layers here without slowing the invariant auditor).
 lint: lint-invariants
@@ -168,22 +127,7 @@ lint: lint-invariants
 # no child that needs the chip.  chip-smoke's parent stays off jax and
 # runs its children strictly one after another.
 tpu:
-	env JAX_PLATFORMS=tpu python -m pytest tests/test_tpu_compiled.py tests/test_kernels.py -q -m tpu -p no:xdist
+	env JAX_PLATFORMS=tpu python -m pytest tests/test_tpu_compiled.py -q -m tpu -p no:xdist
 
 chip-smoke:
 	python chip_smoke.py
-
-# Kernel-selection layer (ops/kernels.py): the CPU-runnable parity
-# suite (splash-mha prefill + stock paged-attention decode in Pallas
-# interpret mode, op-level AND through the serving paths), the
-# serving A/B drills (kernel vs fallback token behavior) and the
-# quarantine drills proving splash->flash and stock-paged->paged
-# fallbacks keep serving token-identically.  Runs the file UNFILTERED
-# so the slow-marked serving matrices (r17 budget rebalance) are
-# included; TPU cells self-skip off-TPU and run under `make tpu`.
-# The throughput side of the A/B — prefill_kernel_sweep (flash vs
-# splash TFLOPs at 8k/16k/32k) and decode_kernel_ab (custom vs stock
-# vs gathered tok/s) — lands in the BENCH_* record via
-# `python bench.py` on a TPU host.
-kernels:
-	$(PYTEST) tests/test_kernels.py -q -m 'not tpu'

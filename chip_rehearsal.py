@@ -37,7 +37,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import chip_smoke
 from jax_llama_tpu import get_config, init_params
 from jax_llama_tpu import serving
-from jax_llama_tpu.ops import kernels as kernels_mod
 from jax_llama_tpu.parallel import serve_mesh as smesh
 from jax_llama_tpu.parallel.mesh import make_mesh
 from jax_llama_tpu.parallel.partition import shard_abstract
@@ -52,7 +51,7 @@ def _interpret_off() -> None:
 
     # By module NAME: ``jax_llama_tpu.ops`` re-exports functions called
     # flash_attention / paged_attention that shadow the submodules.
-    for name in ("flash_attention", "paged_attention", "kernels"):
+    for name in ("flash_attention", "paged_attention"):
         mod = importlib.import_module(f"jax_llama_tpu.ops.{name}")
         mod._resolve_interpret = lambda interpret=None: False
 
@@ -71,17 +70,6 @@ def main() -> int:
     spec = chip_smoke.FULL
     config = get_config(spec.preset, **dict(spec.overrides)).replace(
         attn_impl="auto"
-    )
-    # Resolved exactly as ContinuousBatcher's ctor does at run.py's
-    # defaults: no --prefill-kernel/--decode-kernel, so the config's own
-    # fields ("flash" / "paged"), not "auto".
-    config = config.replace(
-        prefill_kernel=kernels_mod.resolve_prefill_kernel(
-            config.prefill_kernel, config
-        ),
-        decode_kernel=kernels_mod.resolve_decode_kernel(
-            config.decode_kernel, config
-        ),
     )
     if args.chips == 4:
         mesh = smesh.build_serve_mesh(
@@ -189,8 +177,8 @@ def main() -> int:
     )
     print(json.dumps({
         "rehearsal": "compiled, nothing executed", "chips": args.chips,
-        "topology": "v5e:2x2", "prefill_kernel": config.prefill_kernel,
-        "decode_kernel": config.decode_kernel, "placed": placed,
+        "topology": "v5e:2x2", "attn_impl": config.attn_impl,
+        "placed": placed,
         "programs": report,
     }))
     return 0

@@ -60,7 +60,7 @@ LOG_DIR = ROOT / "chiprun_out" / "chip_smoke_logs"   # children's output
 
 # |delta logprob| allowed between two servings of the same greedy prompt.
 # bf16 activations carry 8 mantissa bits (2^-8 ~ 0.4% per rounding); two
-# differently shaped programs (flash/splash prefill vs the gathered suffix
+# differently shaped programs (flash prefill vs the gathered suffix
 # insert; one chip vs four-way tensor-parallel reductions) round in a
 # different order, which at logits of a few units is a few 1e-2 in
 # log-softmax.  A wrong mask, block table or shard moves logprobs by >= 1.
@@ -361,24 +361,20 @@ def check_health(base: str, name: str) -> dict:
 
 
 def check_kernels(base: str, name: str) -> dict:
-    """The kernels the batcher resolved (``ContinuousBatcher.describe``
+    """The attention path the batcher runs (``ContinuousBatcher.describe``
     under /debug/bundle): a run that never could touch a Pallas kernel
     is printed and fails."""
     status, b, _ = http_json(base + "/debug/bundle?trace=0")
     if status != 200:
         raise SmokeFailure(f"{name}: /debug/bundle -> {status}")
     d = b["config"]["batcher"]
-    keys = ("attn_impl", "prefill_kernel", "decode_kernel",
-            "use_pallas_kernel", "paged_kernel_eligible", "n_slots",
-            "max_len", "block_size", "n_blocks", "block_bytes",
-            "decode_chunk", "prefill_budget", "prefix_index",
-            "cost_models", "serve_mesh")
+    keys = ("attn_impl", "use_pallas_kernel", "paged_kernel_eligible",
+            "n_slots", "max_len", "block_size", "n_blocks", "block_bytes",
+            "decode_chunk", "prefill_budget", "prefix_index", "serve_mesh")
     got = {k: d.get(k) for k in keys}
     say("kernels", server=name, **got)
     if not (
         got["attn_impl"] == "auto"
-        and got["prefill_kernel"] in ("flash", "splash")
-        and got["decode_kernel"] in ("paged", "stock-paged")
         and got["use_pallas_kernel"] is True
         and got["paged_kernel_eligible"] is True
     ):

@@ -16,8 +16,7 @@ levels and checks the contract's :class:`~.contracts.CommsBudget`:
 
   * the traced **jaxpr** (recursing into scan/while/cond bodies) for
     explicit collective primitives — ``psum``/``all_gather``-class ops
-    that shard_map kernels (the splash/paged kernels of ROADMAP item
-    1) emit directly; and
+    that shard_map kernels emit directly; and
   * the **compiled module** text — GSPMD inserts the partition-time
     collectives nowhere earlier, so the compiled HLO is the only
     ground truth for propagation-chosen reshards.

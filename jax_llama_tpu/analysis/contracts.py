@@ -26,8 +26,7 @@ pins: EVERY jitted program the ``ContinuousBatcher`` dispatches declares
 
 New programs MUST join this registry before the batcher dispatches
 them — the auditor's coverage check fails on any jit-decorated
-module-level function in serving.py / kvcache.py / ops/kernels.py
-without a contract (allowlist: :data:`NON_DISPATCHED`).
+module-level function in serving.py / kvcache.py without a contract (allowlist: :data:`NON_DISPATCHED`).
 """
 
 from __future__ import annotations
@@ -482,55 +481,6 @@ def _build_release_blocks():
     )
 
 
-def _build_splash_prefill():
-    import jax.numpy as jnp
-    import numpy as np
-
-    # Splash's own lane geometry, not the tiny model's: the kernel
-    # requires head_dim / q_len / kv_len % 128 == 0 (splash_eligible
-    # gates real dispatches the same way), so the example is the
-    # smallest legal splash shape.  interpret=True pins the CPU-
-    # lowerable variant — the kernel body is identical on TPU.
-    rng = np.random.RandomState(5)
-    B, T, S, H, KVH, D = 1, 128, 128, 2, 1, 128
-    names = ("q", "k", "v")
-    args = (
-        jnp.asarray(rng.randn(B, T, H, D).astype(np.float32)),
-        jnp.asarray(rng.randn(B, S, KVH, D).astype(np.float32)),
-        jnp.asarray(rng.randn(B, S, KVH, D).astype(np.float32)),
-    )
-    kwargs = dict(chunk_offset=0, interpret=True)
-    return names, args, kwargs
-
-
-def _build_stock_paged_decode():
-    import jax.numpy as jnp
-    import numpy as np
-
-    # Tiny-pool geometry (mirrors the registry's example scale); the
-    # stock kernel body has no lane-alignment requirement in interpret
-    # mode, so the pool example matches the serving tests' shapes.
-    rng = np.random.RandomState(6)
-    B, H, KVH, D = 2, 4, 2, 16
-    L, NB, BLK, MB = _LAYERS, 8, _BLOCK, 4
-    names = ("q", "k_new", "v_new", "k_pool", "v_pool", "table",
-             "q_pos", "layer")
-    args = (
-        jnp.asarray(rng.randn(B, 1, H, D).astype(np.float32)),
-        jnp.asarray(rng.randn(B, 1, KVH, D).astype(np.float32)),
-        jnp.asarray(rng.randn(B, 1, KVH, D).astype(np.float32)),
-        jnp.asarray(rng.randn(L, KVH, NB, BLK, D).astype(np.float32)),
-        jnp.asarray(rng.randn(L, KVH, NB, BLK, D).astype(np.float32)),
-        jnp.asarray(
-            np.array([[0, 1, NB, NB], [2, NB, NB, NB]], np.int32)
-        ),
-        jnp.asarray(np.array([17, 9], np.int32)),
-        jnp.asarray(np.int32(1)),
-    )
-    kwargs = dict(interpret=True)
-    return names, args, kwargs
-
-
 def _build_adopt_jit():
     import numpy as np
 
@@ -685,59 +635,6 @@ REGISTRY: Dict[str, ProgramContract] = {
             max_cache_keys=2,
         ),
         ProgramContract(
-            name="splash_prefill", module="jax_llama_tpu.ops.kernels",
-            donated=(), max_live_outputs=1,
-            # NOT a host-fetch surface: this program is an attention
-            # primitive called INSIDE the serving programs' traces (its
-            # jit only caches per static chunk_offset under the outer
-            # trace); the one "live" output is the chunk's activation,
-            # handed to the surrounding jitted program, never the host.
-            # Budget = the example output [1, 128, 2, 128] fp32 exactly,
-            # so any second escaping output still trips the check.
-            max_fetch_bytes_per_row=131072,
-            build=_build_splash_prefill,
-            # No pool rides this program — it sees gathered activation
-            # views only ([B, T/S, heads, d]); the no-full-pool-copy
-            # invariant is the CALLING insert program's contract.
-            forbid_pool_shapes=False,
-            # chunk_offset: multiples of the fixed prefill chunk inside
-            # the pow2-bucketed group width (<= blocks_per_slot values)
-            # x q_len in {chunk, P-pow2} x kv_len pow2 — all O(log) or
-            # flag-bounded; interpret is platform-derived (1 value).
-            max_cache_keys=64,
-            # In-op shard_map places heads over "tensor" and rows over
-            # the batch axes with ZERO collectives (every (row, head)
-            # is independent; the o-projection all-reduce belongs to
-            # the calling program's budget) — declared as an explicit
-            # all-zero budget rather than omitted.
-            comms=CommsBudget(max_count={}, max_bytes=0),
-        ),
-        ProgramContract(
-            name="stock_paged_decode", module="jax_llama_tpu.ops.kernels",
-            donated=(), max_live_outputs=1,
-            # Same internal-primitive story as splash_prefill: the one
-            # output is the step's [B, 1, H, d] activation (512 B at
-            # the example geometry), consumed by the calling decode
-            # program's trace, not the host.
-            max_fetch_bytes_per_row=512,
-            build=_build_stock_paged_decode,
-            # The pool arrives as bare [L, KVH, NB, BLK, d] arrays (the
-            # flat-page reshape is a free row-major view, not a copy) —
-            # derive the forbidden full-pool/one-plane shapes from them.
-            forbidden_shapes=lambda args: [
-                tuple(args[3].shape), tuple(args[3].shape[1:]),
-            ],
-            # Every array shape is ctor-stable per batcher (full-width
-            # state rows, fixed pool geometry); layer is traced, and
-            # interpret is platform-derived — target + draft pool
-            # geometries are the only multiplier.
-            max_cache_keys=8,
-            # Zero-collective for the same reason as splash_prefill:
-            # KV heads shard over "tensor", rows over the batch axes,
-            # and the softmax merge is per-(row, head).
-            comms=CommsBudget(max_count={}, max_bytes=0),
-        ),
-        ProgramContract(
             name="_adopt_jit", module="jax_llama_tpu.kvcache",
             donated=("pool_arrays",), max_live_outputs=0,
             max_fetch_bytes_per_row=0,
@@ -760,7 +657,7 @@ REGISTRY: Dict[str, ProgramContract] = {
 NON_DISPATCHED: frozenset = frozenset()
 
 # Modules whose jitted programs must be registered.
-CONTRACT_MODULES = ("serving", "kvcache", "kernels")
+CONTRACT_MODULES = ("serving", "kvcache")
 
 
 def pool_shapes(pool) -> List[Tuple[int, ...]]:

@@ -106,14 +106,6 @@ LOCK_GUARDS: Tuple[LockGuard, ...] = (
         module="obs", cls="StructuredLogger", lock="_lock",
         fields=frozenset({"_ring"}),
     ),
-    # Static cost-model cache (obs.py): serving-loop threads of
-    # DIFFERENT batchers share the one module-level instance
-    # (serving._COST_MODELS) — lookups and inserts go under its lock;
-    # the cost analysis itself deliberately runs outside it.
-    LockGuard(
-        module="obs", cls="CostModelCache", lock="_lock",
-        fields=frozenset({"_cache"}),
-    ),
     LockGuard(
         module="degrade", cls="DegradeManager", lock="_lock",
         fields=frozenset({"_features"}),

@@ -93,24 +93,6 @@ class LLaMAConfig:
                                           #   scales; halves cache HBM
                                           #   traffic/memory; xla path)
 
-    # --- attention kernel selection (ops/kernels.py registry).  These
-    # name WHICH Pallas kernel serves each role when the role's path is
-    # active at all (attn_impl / use_pallas_kernel still gate the
-    # paths themselves).  "auto" is resolved ONCE at serving-batcher
-    # construction (ctor-stable — no per-dispatch cache-key churn); a
-    # config that still says "auto" at forward() time runs the custom
-    # defaults.  Fallback ladders: splash -> flash -> xla;
-    # stock-paged -> paged -> gathered.
-    prefill_kernel: str = "flash"         # "flash" (custom Pallas) |
-                                          #   "splash" (upstream splash-mha
-                                          #   on the insert path; per-chunk
-                                          #   shape eligibility falls back
-                                          #   to flash) | "auto"
-    decode_kernel: str = "paged"          # "paged" (custom block-table
-                                          #   kernel) | "stock-paged"
-                                          #   (upstream Pallas kernel, T=1
-                                          #   non-int8 dispatches) | "auto"
-
     # --- latent attention (MLA) and routed experts.  All zero: the dense
     # GQA + SwiGLU block.  kv_lora_rank > 0 selects the block of
     # models/mla_moe.py (deepseek_v3-style: one cached latent row of
@@ -214,19 +196,6 @@ class LLaMAConfig:
             raise ValueError(
                 f"unknown kv_cache_dtype {self.kv_cache_dtype!r}; "
                 "expected 'auto' or 'int8'"
-            )
-        # Same silent-fallback hazard as kv_cache_dtype: a typo'd kernel
-        # name would never match the dispatch predicates and quietly run
-        # the default kernel forever.
-        if self.prefill_kernel not in ("flash", "splash", "auto"):
-            raise ValueError(
-                f"unknown prefill_kernel {self.prefill_kernel!r}; "
-                "expected 'flash', 'splash', or 'auto'"
-            )
-        if self.decode_kernel not in ("paged", "stock-paged", "auto"):
-            raise ValueError(
-                f"unknown decode_kernel {self.decode_kernel!r}; "
-                "expected 'paged', 'stock-paged', or 'auto'"
             )
 
     def _validate_latent(self) -> None:

@@ -205,6 +205,10 @@ def _load_meta(path: str) -> Tuple[LLaMAConfig, bool, bool]:
         meta = json.load(f)
     quantized = meta.pop("_quantized", False)
     is_train = meta.pop("_train_state", False)
+    # Retired config fields that every checkpoint saved before their
+    # removal still carries; any other unknown key still fails below.
+    for key in ("prefill_kernel", "decode_kernel"):
+        meta.pop(key, None)
     return LLaMAConfig(**meta), quantized, is_train
 
 

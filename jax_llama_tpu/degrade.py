@@ -56,19 +56,7 @@ from typing import Callable, Deque, Dict, List, Optional, Tuple
 # here must have a fallback branch in ``LLMServer._build_batcher`` — a
 # feature without one would "quarantine" while the rebuild keeps
 # running it.
-#
-# The two kernel-selection features (ops/kernels.py registry) quarantine
-# to the EXISTING custom kernel, not straight to XLA — one rung of the
-# ladder at a time:
-#
-#   splash_prefill -> flash_attention -> xla       (prefill ladder)
-#   stock_paged    -> paged_kernel    -> gathered  (decode ladder)
-#
-# so a splash-specific Mosaic failure costs the splash upside only, and
-# the base features below still guard the custom kernels themselves.
 FEATURES = (
-    "splash_prefill",
-    "stock_paged",
     "flash_attention",
     "paged_kernel",
     "spec_decode",

@@ -78,9 +78,6 @@ Examples::
     step~0.01:error              1% of steps, deterministic per seed
     step@2:delay=1.5             stall one step by 1.5 s
     paged_kernel@0:error         kill the first kernel-path decode step
-    stock_paged_kernel@0:error   kill the first stock-kernel decode step
-                                 (quarantine falls back to the custom
-                                 paged kernel, not to XLA)
     step@3:nan                   poison one row's logits on step 3
 """
 
@@ -94,19 +91,11 @@ from typing import Dict, List, Optional, Sequence, Union
 SITES = (
     "step", "insert", "suffix_insert", "prefill_chunk", "alloc",
     # Kernel sites fire once per dispatch that runs the named kernel
-    # family.  ``flash_kernel`` covers the CUSTOM flash kernel
+    # family.  ``flash_kernel`` covers the flash kernel
     # (ops/flash_attention.py) on insert/chunked-prefill dispatches;
-    # ``paged_kernel`` covers the CUSTOM block-table decode kernel
-    # (ops/paged_attention.py).  The two new ops/kernels.py entries get
-    # their own sites below so a fault (or a real Mosaic error)
-    # attributes to the kernel actually selected: ``splash_kernel``
-    # (upstream splash-mha serving splash-eligible insert chunks;
-    # flash_kernel still fires on those dispatches for the non-eligible
-    # remainder) and ``stock_paged_kernel`` (upstream Pallas
-    # paged-attention serving T=1 non-int8 decode steps; paged_kernel
-    # still fires for the fused/verify halves it keeps).
-    "kv_swap", "flash_kernel", "paged_kernel", "splash_kernel",
-    "stock_paged_kernel", "spec_decode",
+    # ``paged_kernel`` covers the block-table decode kernel
+    # (ops/paged_attention.py).
+    "kv_swap", "flash_kernel", "paged_kernel", "spec_decode",
     # Router-side site (router.ReplicaRouter.forward): an injected
     # fault here simulates the chosen replica dying at dispatch time —
     # the router marks it unhealthy and re-routes the request to a
@@ -218,9 +207,8 @@ class FaultSpec:
 # ---------------------------------------------------------------------------
 # Trace-time hook registry
 #
-# The kernel/spec modules (ops.flash_attention, ops.paged_attention,
-# ops.kernels — splash_kernel / stock_paged_kernel — and spec_decode)
-# call ``fire_trace(<site>)`` at their entry points' TRACE
+# The kernel/spec modules (ops.flash_attention, ops.paged_attention
+# and spec_decode) call ``fire_trace(<site>)`` at their entry points' TRACE
 # time — the moment a Mosaic compile failure would surface on real
 # hardware.  One registry arms or clears every site at once
 # (run.py --inject-faults installs ``injector.fire`` here and clears it

@@ -1105,14 +1105,3 @@ def adopt_into_pool(pool, staged: Dict[str, jax.Array], prefix: str = ""):
     )
     return dataclasses.replace(pool, **dict(zip(names, new)))
 
-
-def adopt_lower(pool, staged: Dict[str, jax.Array], prefix: str = ""):
-    """AOT lowering of the adopt scatter with the exact args
-    :func:`adopt_into_pool` dispatches — the device-time attribution
-    hook (obs.CostModelCache) reads FLOPs/bytes off its cost_analysis.
-    Trace-time host work only: lowering never touches buffers."""
-    names = _pool_names(pool)
-    arrays = tuple(getattr(pool, name) for name in names)
-    return _adopt_jit.lower(
-        arrays, staged["ids"], tuple(staged[prefix + n] for n in names)
-    )

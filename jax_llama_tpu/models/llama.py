@@ -95,7 +95,7 @@ def qeinsum(
     QuantizedTensor weights route through ``ops.quant.matmul`` (the
     int8 dequant-fused contraction); plain arrays run the einsum HERE
     so the xplane source attribution lands on this file.  Before this
-    split, bench.py's ``step_breakdown_us`` charged every bf16/fp32
+    split, a per-source breakdown of device op time charged every bf16/fp32
     projection matmul to ``quant.py`` (the thin wrapper's frame), which
     made the breakdown's largest bucket unreadable — "quant.py
     2,572 µs/step" was the plain weight stream, not quantization work.
@@ -701,7 +701,6 @@ def _block(
     paged_pools: Optional[Tuple[jnp.ndarray, ...]] = None,
     paged_layer: Optional[jnp.ndarray] = None,
     ring_new_pos: Optional[jnp.ndarray] = None,
-    chunk_offset: Optional[int] = None,
     output_attentions: bool = False,
 ) -> Tuple[jnp.ndarray, ...]:
     """One pre-norm transformer block. x: [B, T, D].  ``impl`` is the
@@ -810,33 +809,13 @@ def _block(
             # at full precision (matching sdpa_cached's treatment of
             # same-step tokens).
             pool_k, pool_v, pool_ks, pool_vs = paged_pools
-            if (
-                config.decode_kernel == "stock-paged"
-                and T == 1
-                and pool_ks is None
-            ):
-                # Selected stock Pallas kernel (ops/kernels.py): T == 1
-                # non-int8 dispatches only — the decode halves of
-                # _chunk_scan/_fused_chunk and speculative DRAFT steps.
-                # T > 1 (speculative verify) and int8 pools keep the custom
-                # kernel (its native multi-token sweep / in-kernel scale
-                # folding); the static predicate here makes that split a
-                # trace-time decision, mirrored by serving's host-side
-                # feature accounting.
-                from ..ops.kernels import stock_paged_decode_attention
+            from ..ops.paged_attention import paged_decode_attention
 
-                attn = stock_paged_decode_attention(
-                    q, k, v, pool_k, pool_v, paged_table, paged_qpos,
-                    layer=paged_layer,
-                )
-            else:
-                from ..ops.paged_attention import paged_decode_attention
-
-                attn = paged_decode_attention(
-                    q, k, v, pool_k, pool_v, paged_pos, paged_table,
-                    paged_qpos, k_scale=pool_ks, v_scale=pool_vs,
-                    layer=paged_layer,
-                )
+            attn = paged_decode_attention(
+                q, k, v, pool_k, pool_v, paged_pos, paged_table,
+                paged_qpos, k_scale=pool_ks, v_scale=pool_vs,
+                layer=paged_layer,
+            )
             if pool_ks is not None:
                 k, cache_k_scale = quantize_kv(k)
                 v, cache_v_scale = quantize_kv(v)
@@ -894,27 +873,7 @@ def _block(
                     dropout_rate=config.attn_pdrop,
                 )
             elif impl in ("flash", "ring"):
-                from ..ops.kernels import splash_eligible
-
-                if cache_k is not None and splash_eligible(
-                    config, batch=B, q_len=T, kv_len=kk.shape[1],
-                    chunk_offset=chunk_offset,
-                ):
-                    # Selected splash prefill (ops/kernels.py): the insert
-                    # path's chunk offset is a static Python int (the chunk
-                    # loop variable), so the chunk's causal window is a pure
-                    # static CausalMask — splash's whole mask surface.
-                    # Per-chunk shape eligibility (128-multiples) falls back
-                    # to flash HERE, statically, chunk by chunk; the fused
-                    # prefill window's TRACED base can never reach this
-                    # branch (chunk_offset stays None there).  Dropout
-                    # cannot co-occur (cached forwards reject dropout_rng).
-                    from ..ops.kernels import splash_prefill_attention
-
-                    attn = splash_prefill_attention(
-                        q, kk, vv, chunk_offset=chunk_offset
-                    )
-                elif dropout_rng is not None and config.attn_pdrop > 0.0:
+                if dropout_rng is not None and config.attn_pdrop > 0.0:
                     # In-kernel probability dropout: the mask is generated
                     # blockwise inside the flash forward AND rebuilt
                     # bit-identically in the backward kernels — O(S·d) memory
@@ -1014,7 +973,6 @@ def forward(
     output_hidden_states: bool = False,
     output_attentions: bool = False,
     output_last_hidden: bool = False,
-    chunk_offset: Optional[int] = None,
 ):
     """Run the transformer.
 
@@ -1058,14 +1016,6 @@ def forward(
         ``compute_logits=False`` to take the head matmul chunkwise
         (``ops.loss``) instead of materializing [B, T, V] logits.
         Subsumed by the collect flags when both are set.
-      chunk_offset: STATIC (Python int) absolute position of this
-        call's first token, when the caller knows it at trace time —
-        the serving insert path passes its chunk-loop variable.  Only
-        consulted by the splash prefill kernel (ops/kernels.py), whose
-        causal mask is built at trace time from this offset; None (the
-        default, and every traced-position caller) keeps the custom
-        flash kernel.  The cache's own ``index`` cannot serve here: it
-        is a traced scalar.
     Returns:
       (logits [B, T, V] in config.logits_dtype, updated cache or None);
       logits is None when compute_logits=False.  When any output
@@ -1231,7 +1181,6 @@ def forward(
         bias_new=bias_new,
         impl=impl,
         ring_new_pos=new_slot_pos if ring_cached else None,
-        chunk_offset=chunk_offset,
     )
     if config.remat:
         block = _remat(block, config)

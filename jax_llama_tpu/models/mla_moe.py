@@ -265,7 +265,6 @@ def forward(
     output_hidden_states: bool = False,
     output_attentions: bool = False,
     output_last_hidden: bool = False,
-    chunk_offset: Optional[int] = None,
 ):
     """`llama.forward`'s contract for the latent-attention block: cache-free,
     over a `KVCache` (scalar or per-row index) or over a `PagedKVCache`."""
@@ -274,7 +273,6 @@ def forward(
         lm_head_logits, paged_pool_write, paged_write_indices, qeinsum,
     )
 
-    del chunk_offset  # the splash kernel's; this block has no such path
     if dropout_rng is not None:
         raise NotImplementedError(
             "the latent-attention block is served, not trained: dropout_rng "

@@ -39,20 +39,6 @@ questions (and ROADMAP item 5's online chunk controller) need:
     "adopt"}``), so a spec-round regression no longer hides inside a
     lumped all-kinds distribution.  Rendered straight into the
     ``/metrics`` text exposition (``_bucket``/``_sum``/``_count``).
-  * **Device-time attribution** (:class:`CostModelCache` + the
-    ``mxu_utilization`` / ``hbm_utilization`` / ``host_overhead_ratio``
-    gauges).  Each jitted serving program's static cost (FLOPs + bytes
-    accessed, from ``jit(...).lower(...).cost_analysis()`` at the LIVE
-    geometry, cached per jit-cache key — trace-time work only, never a
-    steady-state dispatch) rides its dispatch record; per-kind sliding
-    windows turn measured dispatch wall time into live roofline
-    utilization and a wall-vs-device-estimate host-overhead ratio —
-    the ~20-26x device-vs-wall gap BENCH_r05 measured offline, now a
-    scrapeable gauge.  Peaks come from :data:`DEVICE_PEAKS`, looked up
-    by run.py from the attached device's ``device_kind`` (or named
-    with ``--peak-tflops`` / ``--peak-hbm-gbps``); with no peak — the
-    ctor default, and any device the table does not list — the
-    utilization gauges are off.
   * **Jit-cache observability**.  A ``jax.monitoring`` listener turns
     every backend compile into a ``compile_ms`` observation, a span in
     the trace (its own ``jit compiles`` track), and a per-program
@@ -131,18 +117,11 @@ from .faults import SITES
 from .ops.moe import STATS as _MOE_STATS
 
 # Dispatch kinds serving.py records — each owns a labeled dispatch_ms
-# histogram series and a device-time attribution window.
-# record_dispatch VALIDATES against this set: a typo'd kind would
-# otherwise mint a phantom metrics series nobody scrapes.
-# The ":"-suffixed variants are per-kernel attribution splits
-# (ops/kernels.py): same dispatch site as the base kind, but served by
-# an alternative kernel — so ``llm_mxu_utilization{kind}`` turns the
-# kernel A/B into a live gauge.  Fused chunks and spec rounds keep ONE
-# kind each (mixed prefill/decode resp. draft/verify FLOPs — a kernel
-# split would attribute the mix to one kernel and lie).
+# histogram series.  record_dispatch VALIDATES against this set: a
+# typo'd kind would otherwise mint a phantom metrics series nobody
+# scrapes.
 DISPATCH_KINDS = frozenset({
     "decode", "fused", "spec", "insert", "suffix_insert", "adopt",
-    "decode:stock-paged", "insert:splash",
 })
 
 # Serving-loop phases (Observability.loop_phase): what the loop thread —
@@ -159,23 +138,12 @@ DISPATCH_KINDS = frozenset({
 # a device wait), ``admit`` (``_admit`` less its own dispatch records:
 # chain hashing, prefix match, block allocation, prefill set-up and
 # uploads, restore polling), ``prep`` (chunk pick, dirty-row sync,
-# fault sites, cost hook), ``emit`` (replay of the packed block,
+# fault sites), ``emit`` (replay of the packed block,
 # ``request_end``, slot frees).
 LOOP_PHASES = frozenset({
     "control", "intake", "idle", "deliver",
     "barrier", "admit", "prep", "emit",
 })
-
-# Hardware peaks for the utilization gauges, keyed by
-# ``jax.devices()[0].device_kind``: (bf16 FLOP/s, HBM bytes/s) of ONE
-# chip.  Source: Google Cloud documentation, "TPU v5e" system
-# architecture page (197 TFLOP/s bf16, 819 GB/s HBM per chip; JAX calls
-# the device "TPU v5 lite").  A kind that is not listed gets NO
-# utilization gauges — never another device's numbers; run.py
-# --peak-tflops / --peak-hbm-gbps name a peak explicitly.
-DEVICE_PEAKS: Dict[str, Tuple[float, float]] = {
-    "TPU v5 lite": (197e12, 819e9),
-}
 
 # ---------------------------------------------------------------------------
 # Histograms (Prometheus cumulative buckets)
@@ -526,23 +494,11 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "slo_attainment": _reg(
         "gauge", "Fraction of recent requests meeting every configured "
                  "SLO (window 256)"),
-    # -- device-time attribution / jit-cache observability -------------------
+    # -- jit-cache observability ----------------------------------------------
     "compiles_total": _reg(
         "counter", "Backend jit compiles observed (cache misses; see "
                    "the compile_ms histogram and "
                    "program_compiles_total)"),
-    "mxu_utilization": _reg(
-        "gauge", "Modeled-FLOPs / wall-time fraction of the MXU peak "
-                 "over the recent dispatch window (per dispatch kind)"),
-    "hbm_utilization": _reg(
-        "gauge", "Modeled bytes-accessed / wall-time fraction of the "
-                 "HBM peak over the recent dispatch window (per "
-                 "dispatch kind)"),
-    "host_overhead_ratio": _reg(
-        "gauge", "Dispatch wall time over the static-cost device-time "
-                 "estimate (per dispatch kind; ~1 = device-bound, "
-                 ">>1 = host overhead — the BENCH_r05 device-vs-wall "
-                 "gap, live)"),
     "program_compiles_total": _reg(
         "counter", "Backend jit compiles attributed to each serving "
                    "program (per program)"),
@@ -631,67 +587,8 @@ def metric_meta(name: str) -> Optional[Tuple[str, str]]:
 
 
 # ---------------------------------------------------------------------------
-# Static cost models + compile attribution
+# Compile attribution
 # ---------------------------------------------------------------------------
-
-class CostModelCache:
-    """Process-wide cache of static per-program cost models.
-
-    ``get(program, key, lower)`` returns ``(flops, bytes_accessed)``
-    from ``lower().cost_analysis()`` — ``lower`` is a thunk closing
-    over the EXACT live dispatch args, so the model is computed at the
-    live geometry.  The analysis runs once per ``(program, key)``
-    (``key`` mirrors the jit-cache key: geometry + the static args
-    that force a retrace) and is pure trace-time host work — it never
-    dispatches to the device, so attribution adds zero steady-state
-    device work.  A failed analysis (e.g. an exotic sharded lowering)
-    caches ``None`` so it is never retried per dispatch.
-
-    Thread-safe (``_lock``): batchers on different serving-loop
-    threads share the one module-level instance; the analysis itself
-    runs OUTSIDE the lock (two racing first-dispatches both lower —
-    idempotent — rather than one blocking on the other's trace)."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._cache: Dict[Tuple, Optional[Tuple[float, float]]] = {}
-
-    def get(self, program: str, key: Tuple,
-            lower) -> Optional[Tuple[float, float]]:
-        k = (program,) + tuple(key)
-        with self._lock:
-            if k in self._cache:
-                return self._cache[k]
-        cost: Optional[Tuple[float, float]] = None
-        try:
-            ca = lower().cost_analysis()
-            if isinstance(ca, (list, tuple)):  # per-device variant
-                ca = ca[0] if ca else None
-            if isinstance(ca, dict):
-                cost = (
-                    float(ca.get("flops", 0.0) or 0.0),
-                    float(ca.get("bytes accessed", 0.0) or 0.0),
-                )
-        except Exception:
-            cost = None
-        with self._lock:
-            self._cache[k] = cost
-        return cost
-
-    def snapshot(self) -> Dict[str, Dict[str, Any]]:
-        """{program: {keys, flops/bytes of the most recent key}} for
-        the /debug surface and tests."""
-        with self._lock:
-            items = list(self._cache.items())
-        out: Dict[str, Dict[str, Any]] = {}
-        for k, cost in items:
-            ent = out.setdefault(k[0], {"keys": 0, "modeled": 0})
-            ent["keys"] += 1
-            if cost is not None:
-                ent["modeled"] += 1
-                ent["flops"], ent["bytes_accessed"] = cost
-        return out
-
 
 # Compile attribution: serving.py names the program it is about to
 # dispatch (thread-local — each serving loop owns one batcher), and
@@ -978,9 +875,6 @@ class Observability:
         max_timelines: int = 1024,
         max_events: int = 256,
         slo_window: int = 256,
-        peak_flops: float = 0.0,
-        peak_bytes_per_s: float = 0.0,
-        util_window: int = 64,
         decision_ring: int = 512,
         max_snapshots: int = 128,
         clock=time.monotonic,
@@ -1012,15 +906,6 @@ class Observability:
         self.metric_snapshots: "deque[Dict[str, Any]]" = deque(
             maxlen=max_snapshots
         )
-        # Device-time attribution: hardware peaks (0 disables the
-        # corresponding gauge) and a per-kind sliding window of
-        # (flops, bytes, wall_ms, device_est_ms) from dispatches that
-        # carried a cost model.
-        self.peak_flops = float(peak_flops or 0.0)
-        self.peak_bytes_per_s = float(peak_bytes_per_s or 0.0)
-        self._util_window = int(util_window)
-        self._util: Dict[str, "deque[Tuple[float, float, float, float]]"]
-        self._util = {}
         # Jit-cache observability: compile spans (bounded ring, a
         # trace track of their own) + per-program counters, fed by the
         # process-wide jax.monitoring listener via record_compile.
@@ -1396,7 +1281,7 @@ class Observability:
         self,
     ) -> List[Tuple[str, Dict[str, str], float]]:
         """``loop_phase_ms_total{phase=...}`` samples for /metrics
-        (``(family, labels, value)`` like ``utilization_metrics``)."""
+        (``(family, labels, value)`` like ``compile_metrics``)."""
         with self._lock:
             totals = sorted(self.loop_phase_ms_total.items())
         return [
@@ -1417,8 +1302,6 @@ class Observability:
         swap_inflight: int = 0,
         rids: Sequence[int] = (),
         program: Optional[str] = None,
-        flops: Optional[float] = None,
-        bytes_accessed: Optional[float] = None,
         then: Optional[str] = None,
         moe: Optional[Sequence[int]] = None,
         prefill_ctx: Optional[Tuple[int, int]] = None,
@@ -1428,10 +1311,7 @@ class Observability:
         dispatch's ring-global seq number.  ``wall_ms`` covers dispatch
         submit through the packed fetch (what the host actually waited);
         ``fetch_ms`` isolates the ``np.asarray`` device sync.
-        ``program`` names the jitted program; ``flops`` /
-        ``bytes_accessed`` are its static cost model (CostModelCache) —
-        when present the record carries a roofline device-time estimate
-        and feeds the per-kind utilization window.
+        ``program`` names the jitted program.
 
         The record also carries the gap that led to it, from the loop
         phases: ``gap_ms`` (end of the previous record -> this one's
@@ -1479,22 +1359,6 @@ class Observability:
                 "attended": int(prefill_ctx[0]), "view": int(prefill_ctx[1]),
             }
         rec.update(gap)
-        est_ms = None
-        if flops is not None and bytes_accessed is not None:
-            est = 0.0
-            if self.peak_flops > 0:
-                est = max(est, float(flops) / self.peak_flops * 1000.0)
-            if self.peak_bytes_per_s > 0:
-                est = max(
-                    est,
-                    float(bytes_accessed) / self.peak_bytes_per_s
-                    * 1000.0,
-                )
-            if est > 0:
-                est_ms = est
-                rec["flops"] = float(flops)
-                rec["bytes_accessed"] = float(bytes_accessed)
-                rec["device_est_ms"] = round(est, 6)
         with self._lock:
             seq = self._seq
             self._seq += 1
@@ -1516,16 +1380,6 @@ class Observability:
                     labels={"kind": kind},
                 )
             h.observe(wall_ms)
-            if est_ms is not None:
-                dq = self._util.get(kind)
-                if dq is None:
-                    dq = self._util[kind] = deque(
-                        maxlen=self._util_window
-                    )
-                dq.append(
-                    (float(flops), float(bytes_accessed), wall_ms,
-                     est_ms)
-                )
             if prefill_tokens > 0 or kind in ("insert", "suffix_insert"):
                 self.hist["prefill_chunk_ms"].observe(wall_ms)
             for rid in rids:
@@ -1731,49 +1585,18 @@ class Observability:
                 )
             return lines
 
-    def utilization_metrics(
+    def compile_metrics(
         self,
     ) -> List[Tuple[str, Dict[str, str], float]]:
-        """Labeled device-time attribution samples for /metrics:
-        ``(family, labels, value)`` triples — per-kind
-        mxu_utilization / hbm_utilization / host_overhead_ratio over
-        the recent dispatch window, plus per-program compile counters.
-        Families are registered in METRICS; the server renders one
-        HELP/TYPE header per family."""
-        out: List[Tuple[str, Dict[str, str], float]] = []
+        """Per-program compile counters for /metrics: ``(family,
+        labels, value)`` triples.  The family is registered in METRICS;
+        the server renders one HELP/TYPE header per family."""
         with self._lock:
-            windows = {
-                kind: list(dq) for kind, dq in self._util.items() if dq
-            }
             compiles = sorted(self.compiles_by_program.items())
-        for kind in sorted(windows):
-            dq = windows[kind]
-            wall_ms = sum(w for _, _, w, _ in dq)
-            if wall_ms <= 0:
-                continue
-            wall_s = wall_ms / 1000.0
-            lab = {"kind": kind}
-            if self.peak_flops > 0:
-                fl = sum(f for f, _, _, _ in dq)
-                out.append((
-                    "mxu_utilization", lab,
-                    round(fl / wall_s / self.peak_flops, 6),
-                ))
-            if self.peak_bytes_per_s > 0:
-                by = sum(b for _, b, _, _ in dq)
-                out.append((
-                    "hbm_utilization", lab,
-                    round(by / wall_s / self.peak_bytes_per_s, 6),
-                ))
-            est_ms = sum(e for _, _, _, e in dq)
-            if est_ms > 0:
-                out.append((
-                    "host_overhead_ratio", lab,
-                    round(wall_ms / est_ms, 3),
-                ))
-        for prog, n in compiles:
-            out.append(("program_compiles_total", {"program": prog}, n))
-        return out
+        return [
+            ("program_compiles_total", {"program": prog}, n)
+            for prog, n in compiles
+        ]
 
     # -- debug JSON ------------------------------------------------------------
 
@@ -1905,7 +1728,7 @@ class Observability:
                     k: d[k] for k in (
                         "seq", "occupancy", "prefill_tokens",
                         "fetch_ms", "swap_inflight", "rids",
-                        "program", "device_est_ms", "gap_ms",
+                        "program", "gap_ms",
                         "host_ms", "gap_cpu_ms", "compiles",
                     ) if k in d
                 },

@@ -747,7 +747,7 @@ def flash_attention_sharded(
     an active mesh the kernel runs per-shard inside ``shard_map``: heads
     over "tensor" (contiguous H chunks == contiguous KVH chunks under
     the h = kvh*G + g layout), rows over the batch axes when they divide
-    — the same placement as the paged and splash kernels.  No
+    — the same placement as the paged kernel.  No
     collectives: every (row, head) is independent and the caller's
     o-projection all-reduce recombines heads.  Meshes the placement does
     not cover (seq/stage axes, heads not divisible) call the kernel

@@ -105,8 +105,8 @@ def matmul(
     Profile-attribution note: the model's hot path
     (``models.llama.qeinsum``) calls this only for QuantizedTensor
     weights and runs the plain-array einsum in its own frame — so a
-    ``quant.py`` bucket in an xplane source breakdown (bench.py
-    ``step_breakdown_us``) now measures real int8 dequant work, not the
+    ``quant.py`` bucket in an xplane source breakdown (a per-source
+    breakdown of device op time) now measures real int8 dequant work, not the
     bf16 weight stream it used to swallow.
     """
     dtype = dtype or x.dtype

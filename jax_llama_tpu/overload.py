@@ -67,9 +67,7 @@ surviving batcher rebuilds the way ``DegradeManager`` does):
     REGARDLESS of completions (open-loop — the arrival process does
     not slow down when the server does, which is exactly what makes
     overload visible; a closed-loop client self-throttles and hides
-    it).  ``bench.py`` sweeps it over request rate for the
-    ``serving_goodput_vs_rate`` record; ``tests/test_overload.py``
-    uses it for the flood drill (every refused/shed request gets a
+    it).  ``tests/test_overload.py`` uses it for the flood drill (every refused/shed request gets a
     well-formed 503 + Retry-After, zero hung clients).
 
 Thread-safety: handler threads call ``admit()`` while the serving loop

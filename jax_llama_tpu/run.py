@@ -25,7 +25,7 @@ DEFAULT_PROMPTS = [
 ]
 
 
-def main() -> None:
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--ckpt-dir", required=True, help="Orbax checkpoint dir")
     ap.add_argument("--tokenizer", default=None)
@@ -47,18 +47,6 @@ def main() -> None:
                     help="override attn_impl from the checkpoint config "
                          "(auto = flash prefill + append-free xla decode; "
                          "recommended for long prompts)")
-    ap.add_argument("--prefill-kernel", default=None,
-                    choices=["flash", "splash", "auto"],
-                    help="attention kernel for prefill/insert dispatches "
-                         "(ops/kernels.py registry; auto = splash when the "
-                         "geometry qualifies, else flash; fallback ladder "
-                         "splash -> flash -> xla)")
-    ap.add_argument("--decode-kernel", default=None,
-                    choices=["paged", "stock-paged", "gathered", "auto"],
-                    help="attention kernel for paged decode steps (auto = "
-                         "the custom paged kernel; gathered = disable the "
-                         "Pallas kernel, gathered-view XLA attention; "
-                         "fallback ladder stock-paged -> paged -> gathered)")
     ap.add_argument("--quantize", action="store_true",
                     help="int8-quantize weights after load (weight-only, "
                          "per-channel; ~2x decode throughput)")
@@ -325,34 +313,17 @@ def main() -> None:
                     help="idle KV blocks proactively demoted to the "
                          "host tier on entering brownout-1 and deeper "
                          "(no-op without --host-kv-blocks)")
-    ap.add_argument("--peak-tflops", type=float, default=None,
-                    help="accelerator MXU peak in TFLOP/s for the "
-                         "/metrics llm_mxu_utilization and "
-                         "llm_host_overhead_ratio gauges (default: "
-                         "looked up from obs.DEVICE_PEAKS by the "
-                         "attached device's kind; a device the table "
-                         "does not list gets no utilization gauges); "
-                         "0 disables the FLOPs-side gauges")
-    ap.add_argument("--peak-hbm-gbps", type=float, default=None,
-                    help="accelerator HBM bandwidth in GB/s for the "
-                         "/metrics llm_hbm_utilization gauge "
-                         "(default: looked up like --peak-tflops); "
-                         "0 disables it")
-    ap.add_argument("--no-cost-models", action="store_true",
-                    help="skip the per-program static cost models "
-                         "(jit lowering cost_analysis at the live "
-                         "geometry): the utilization / host-overhead "
-                         "gauges go dark but first-dispatch trace "
-                         "time drops — for compile-bound drills; "
-                         "live serving keeps them ON (the analysis "
-                         "is trace-time only, never per-dispatch)")
     ap.add_argument("--log-json", action="store_true",
                     help="structured JSON logging: one JSON object per "
                          "operational log line (event / request_id / "
                          "feature fields) instead of 'event k=v' text, "
                          "so a log pipeline joins server lines to the "
                          "/debug request timelines without regexes")
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    args = _parser().parse_args()
     # One formatter for every operational log line this process emits
     # (obs.StructuredLogger; --log-json flips it to JSON objects).
     # Generation OUTPUT (the completions themselves) stays on plain
@@ -436,7 +407,6 @@ def main() -> None:
         "devices", platform=dev0.platform, device_kind=dev0.device_kind,
         count=n, compile_cache=cache_dir,
     )
-    _resolve_peaks(args, dev0.device_kind, log)
     tensor = args.tensor or n // (args.data * args.fsdp)
     # Use exactly the devices the mesh needs — a smaller-than-host mesh
     # (e.g. --tensor 2 on an 8-device host) is valid for smoke runs.
@@ -590,30 +560,6 @@ def main() -> None:
     print(f"\n[{stats.summary()}] (incl. compile)")
 
 
-def _resolve_peaks(args, device_kind: str, log) -> None:
-    """Fill unset --peak-tflops / --peak-hbm-gbps from the peaks table
-    (obs.DEVICE_PEAKS) for the attached device.  A device the table
-    does not know gets 0 — utilization gauges off, with a log line —
-    never another device's peaks."""
-    from .obs import DEVICE_PEAKS
-
-    if args.peak_tflops is not None and args.peak_hbm_gbps is not None:
-        return
-    peaks = DEVICE_PEAKS.get(device_kind)
-    if peaks is None:
-        log.log(
-            "utilization_gauges_off",
-            "no peaks known for this device; name them with "
-            "--peak-tflops / --peak-hbm-gbps",
-            device_kind=device_kind,
-        )
-        peaks = (0.0, 0.0)
-    if args.peak_tflops is None:
-        args.peak_tflops = peaks[0] / 1e12
-    if args.peak_hbm_gbps is None:
-        args.peak_hbm_gbps = peaks[1] / 1e9
-
-
 def _param_bytes_by_device(params) -> dict:
     """{device id: weight bytes whose shards live there}."""
     import jax
@@ -746,8 +692,6 @@ def _serve_http(params, config, tokenizer, mesh, args, _test_hook=None,
     obs = Observability(
         slo_ttft_ms=getattr(args, "slo_ttft_ms", 0.0) or None,
         slo_itl_ms=getattr(args, "slo_itl_ms", 0.0) or None,
-        peak_flops=(getattr(args, "peak_tflops", None) or 0.0) * 1e12,
-        peak_bytes_per_s=(getattr(args, "peak_hbm_gbps", None) or 0.0) * 1e9,
     )
     cb = ContinuousBatcher(
         params, config, n_slots=args.slots,
@@ -765,9 +709,6 @@ def _serve_http(params, config, tokenizer, mesh, args, _test_hook=None,
         prefix_index=getattr(args, "prefix_index", "radix"),
         host_kv_blocks=getattr(args, "host_kv_blocks", 0),
         obs=obs,
-        cost_models=not getattr(args, "no_cost_models", False),
-        prefill_kernel=getattr(args, "prefill_kernel", None),
-        decode_kernel=getattr(args, "decode_kernel", None),
     )
     # Llama-3 tokenizers get the dialog endpoint for free (ChatFormat is
     # the reference's own framing; other tokenizers have no chat contract).
@@ -1002,10 +943,6 @@ def _serve_router(params, config, tokenizer, mesh, args,
         obs = Observability(
             slo_ttft_ms=getattr(args, "slo_ttft_ms", 0.0) or None,
             slo_itl_ms=getattr(args, "slo_itl_ms", 0.0) or None,
-            peak_flops=(getattr(args, "peak_tflops", None) or 0.0) * 1e12,
-            peak_bytes_per_s=(
-                (getattr(args, "peak_hbm_gbps", None) or 0.0) * 1e9
-            ),
         )
         cb = ContinuousBatcher(
             p, config, n_slots=args.slots,
@@ -1023,9 +960,6 @@ def _serve_router(params, config, tokenizer, mesh, args,
             prefix_index=getattr(args, "prefix_index", "radix"),
             host_kv_blocks=getattr(args, "host_kv_blocks", 0),
             obs=obs,
-            cost_models=not getattr(args, "no_cost_models", False),
-            prefill_kernel=getattr(args, "prefill_kernel", None),
-            decode_kernel=getattr(args, "decode_kernel", None),
         )
         srv = LLMServer(
             cb, tokenizer=tokenizer, host=args.host, port=0,

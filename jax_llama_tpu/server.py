@@ -175,14 +175,7 @@ heuristic is gone)::
     (every sample line carries the kind label; sum the series for the
     old lumped view).
 
-Device-time attribution (obs.py cost models; batcher
-``cost_models=True``, run.py default ON, ``--no-cost-models`` off):
-each dispatch kind's recent window exposes
-``llm_mxu_utilization{kind=...}`` / ``llm_hbm_utilization{kind=...}``
-(modeled FLOPs / bytes over wall time, against ``--peak-tflops`` /
-``--peak-hbm-gbps``) and ``llm_host_overhead_ratio{kind=...}`` (wall
-over the roofline device-time estimate — ~1 device-bound, >>1 host
-overhead).  Jit-cache observability:
+Jit-cache observability:
 ``llm_jit_cache_entries{program=...}`` (live executable-cache entries
 per registered serving program), ``llm_compiles_total`` +
 ``llm_program_compiles_total{program=...}`` and the ``compile_ms``
@@ -190,8 +183,7 @@ histogram (every backend compile, attributed to the program whose
 dispatch triggered it via the jax.monitoring listener).
 
 Serving-loop phases (obs.LOOP_PHASES — always on): the MEASURED host
-share of a step, where ``llm_host_overhead_ratio`` estimates one from a
-cost model.  ``llm_loop_phase_ms_total{phase="control"|"intake"|"idle"|
+share of a step.  ``llm_loop_phase_ms_total{phase="control"|"intake"|"idle"|
 "deliver"|"barrier"|"admit"|"prep"|"emit"}`` (loop-thread time between
 dispatch records, by what the thread was doing), ``llm_loop_gap_ms_total``
 (the sum of those gaps, idle included) and ``llm_loop_gap_cpu_ms_total``
@@ -269,10 +261,7 @@ the device's idle time by what the loop thread was doing::
      "idle_by_phase_ms": {"<phase>"|"in dispatch"|"unnamed": F, ...}}
 
 (404 with no completed session, 409 while one is active).  Dispatch
-records (/debug/dispatches) gain
-``program`` and — with cost models on — ``flops`` /
-``bytes_accessed`` / ``device_est_ms`` (the roofline estimate the
-host_overhead_ratio gauge divides by).
+records (/debug/dispatches) gain ``program``.
 
 ``GET /debug/kv[?depth=D&n=N]`` (KV chain digest, r13 — reads only the
 lock-guarded ``kvcache.KvDigest``, never the thread-confined store)::
@@ -445,18 +434,14 @@ from .serving import ContinuousBatcher, _round_up
 _SITE_FEATURES = {
     "flash_kernel": "flash_attention",
     "paged_kernel": "paged_kernel",
-    "splash_kernel": "splash_prefill",
-    "stock_paged_kernel": "stock_paged",
     "spec_decode": "spec_decode",
     "suffix_insert": "prefix_cache",
 }
 # Substrings that mark a real (non-injected) dispatch error as coming
 # out of a Pallas kernel (Mosaic compile/runtime failures name their
 # origin); matched case-insensitively against the exception text.
-# "splash" covers the upstream splash-attention module's own error
-# text (mask/BlockSizes validation raises name the kernel, not Mosaic).
 _KERNEL_ERROR_MARKERS = (
-    "mosaic", "pallas", "custom-call", "custom_call", "splash",
+    "mosaic", "pallas", "custom-call", "custom_call",
 )
 
 _DONE = object()  # stream sentinel
@@ -1690,19 +1675,7 @@ class LLMServer:
         text = f"{type(exc).__name__}: {exc}".lower()
         if any(m in text for m in _KERNEL_ERROR_MARKERS):
             feats = getattr(self.batcher, "last_dispatch_features", ())
-            # Opt-in kernels first: when a dispatch ran the splash or
-            # stock kernel it ALSO exercised the custom-kernel path
-            # (both feature names are in feats), and quarantining the
-            # opt-in rung first keeps the fallback ladder one step at
-            # a time (splash -> flash, stock-paged -> paged) instead
-            # of knocking the dispatch all the way to XLA/gathered.
-            # A splash-named error on a stock-kernel decode dispatch
-            # still lands on stock_paged via this order — acceptable:
-            # the two never share a dispatch kind.
-            for f in (
-                "splash_prefill", "stock_paged",
-                "paged_kernel", "flash_attention",
-            ):
+            for f in ("paged_kernel", "flash_attention"):
                 if f in feats:
                     return f
         return None
@@ -1713,13 +1686,6 @@ class LLMServer:
         features count as enabled — that is what a probe rebuild is."""
         params, config, kwargs = self._base_ctor
         kw = dict(kwargs)
-        # Kernel-selection rungs first: each falls back to the EXISTING
-        # custom kernel (ctor kwargs override the config fields, so this
-        # wins over a baked-in "splash"/"stock-paged"/"auto").
-        if not self.degrade.enabled("splash_prefill"):
-            kw["prefill_kernel"] = "flash"
-        if not self.degrade.enabled("stock_paged"):
-            kw["decode_kernel"] = "paged"
         if not self.degrade.enabled("paged_kernel"):
             kw["use_pallas_kernel"] = False
         if not self.degrade.enabled("spec_decode"):
@@ -2518,20 +2484,19 @@ class LLMServer:
         # Histogram families (ttft/itl/queue-wait/prefill/swap/dispatch)
         # render their own HELP/TYPE + _bucket/_sum/_count series.
         lines.extend(self.obs.expose_histograms("llm_"))
-        # Labeled families: per-kind device-time attribution gauges and
-        # per-program compile counters (obs.utilization_metrics), plus
-        # the live jit-cache entry count per registered serving program
+        # Labeled families: per-program compile counters
+        # (obs.compile_metrics), the loop phases, plus the live
+        # jit-cache entry count per registered serving program
         # (scrape-time reads of jax's own per-function caches — no
         # shared mutable state).  One HELP/TYPE header per family, even
         # while a family has no samples yet, so dashboards can discover
         # them before traffic.
-        labeled = list(self.obs.utilization_metrics())
+        labeled = list(self.obs.compile_metrics())
         labeled.extend(self.obs.loop_phase_metrics())
         for prog, n in sorted(serving_mod.jit_cache_entries().items()):
             labeled.append(("jit_cache_entries", {"program": prog}, n))
-        for family in ("mxu_utilization", "hbm_utilization",
-                       "host_overhead_ratio", "program_compiles_total",
-                       "jit_cache_entries", "loop_phase_ms_total"):
+        for family in ("program_compiles_total", "jit_cache_entries",
+                       "loop_phase_ms_total"):
             kind, help_text = metric_meta(family)
             lines.append(f"# HELP llm_{family} {help_text}")
             lines.append(f"# TYPE llm_{family} {kind}")

@@ -175,7 +175,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import itertools
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -193,7 +192,6 @@ from .faults import FaultInjector, InjectedFault
 from .kvcache import (
     MatchResult,
     adopt_into_pool,
-    adopt_lower,
     fetch_slab,
     make_prefix_store,
     pool_block_bytes,
@@ -201,7 +199,7 @@ from .kvcache import (
     stage_restore,
 )
 from . import obs as _obs_mod
-from .obs import CostModelCache, Observability
+from .obs import Observability
 from .models.llama import (
     FLASH_MIN_SEQ,
     KVCache,
@@ -214,7 +212,6 @@ from .models.llama import (
     paged_write_indices,
 )
 from .models.mla_moe import ctx_tiles
-from .ops import kernels as _kernels_mod
 from .ops.moe import STATS as _MOE_STATS
 from .ops.attention import NEG_INF
 from .ops.sampling import stop_token_hits
@@ -978,11 +975,6 @@ def _paged_insert(
                 positions[:, start:end], config, cache=sub,
                 attn_mask=prompt_mask[:, start:end],
                 compute_logits=False, output_last_hidden=True,
-                # start is a PYTHON int (this loop is unrolled at trace
-                # time), so the splash prefill kernel — whose causal
-                # mask needs a static offset — can engage per chunk
-                # when config.prefill_kernel selects it.
-                chunk_offset=start,
             )
             idx = plen - 1 - start  # [k] last-token offset in this chunk
             in_chunk = (idx >= 0) & (idx < end - start)
@@ -1675,16 +1667,6 @@ def jit_cache_entries() -> Dict[str, int]:
     return out
 
 
-# Process-wide static cost models (obs.CostModelCache): one entry per
-# (program, geometry, static args) — written at trace time by the
-# dispatch hooks below, read per dispatch as a dict hit.
-_COST_MODELS = CostModelCache()
-
-# Batcher-incarnation counter for the cost-model geometry key (see
-# ContinuousBatcher.__init__).
-_COST_GEOM_SEQ = itertools.count()
-
-
 # ---------------------------------------------------------------------------
 # Host-side batcher
 # ---------------------------------------------------------------------------
@@ -1880,9 +1862,6 @@ class ContinuousBatcher:
         prefix_index: str = "radix",
         host_kv_blocks: int = 0,
         obs: Optional[Observability] = None,
-        cost_models: bool = False,
-        prefill_kernel: Optional[str] = None,
-        decode_kernel: Optional[str] = None,
     ):
         # Raw construction arguments, captured before any derivation so
         # ``rebuild()`` (crash recovery) reproduces this batcher exactly
@@ -1900,19 +1879,9 @@ class ContinuousBatcher:
             decode_chunk=decode_chunk, spec_rounds=spec_rounds,
             prefill_budget=prefill_budget, prefix_index=prefix_index,
             host_kv_blocks=host_kv_blocks, obs=obs,
-            cost_models=cost_models, prefill_kernel=prefill_kernel,
-            decode_kernel=decode_kernel,
         )
-        # Device-time attribution (obs.py): static per-program cost
-        # models from jit lowering's cost_analysis at the live
-        # geometry.  OFF by default — computing a model costs one
-        # extra trace per (program, jit-cache key), which live serving
-        # amortizes over hours but a compile-bound test matrix cannot
-        # (tier-1 sits at its time ceiling); run.py turns it on for
-        # real serving.  Compile ATTRIBUTION (the jax.monitoring
-        # listener) is always on: it is two thread-local writes per
-        # dispatch.
-        self.cost_models = bool(cost_models)
+        # Compile attribution (obs.py, the jax.monitoring listener) is
+        # always on: it is two thread-local writes per dispatch.
         _obs_mod.install_compile_listener()
         # Observability sink (obs.py): request span timelines, dispatch
         # spans, latency histograms, SLO accounting.  Always on — pure
@@ -1936,36 +1905,6 @@ class ContinuousBatcher:
                 "continuous batching requires attn_impl 'xla' or 'auto' "
                 "(per-row cache offsets run on the xla path)"
             )
-        # Kernel selection (ops/kernels.py): ctor kwargs override the
-        # config's fields; "auto" (and None-with-"auto"-config) resolves
-        # HERE, once — the resolved names bake into the config (a static
-        # jit argument), so every dispatch of this batcher's lifetime
-        # traces against one concrete kernel choice and the jit-cache
-        # key set stays ctor-stable.  "gathered" is not a kernel: it
-        # maps to the paged path's existing use_pallas_kernel=False
-        # escape (identical pool geometry, gathered-view attention).
-        if decode_kernel == "gathered":
-            use_pallas_kernel = False
-            decode_kernel = "paged"
-        config = config.replace(
-            prefill_kernel=_kernels_mod.resolve_prefill_kernel(
-                prefill_kernel or config.prefill_kernel, config
-            ),
-            decode_kernel=_kernels_mod.resolve_decode_kernel(
-                decode_kernel or config.decode_kernel, config
-            ),
-        )
-        if draft_config is not None:
-            draft_config = draft_config.replace(
-                prefill_kernel=_kernels_mod.resolve_prefill_kernel(
-                    prefill_kernel or draft_config.prefill_kernel,
-                    draft_config,
-                ),
-                decode_kernel=_kernels_mod.resolve_decode_kernel(
-                    decode_kernel or draft_config.decode_kernel,
-                    draft_config,
-                ),
-            )
         self.spec = draft_params is not None
         self.logprobs = logprobs
         if config.latent_attention:
@@ -1984,8 +1923,8 @@ class ContinuousBatcher:
         self.config = config
         self.mesh = mesh
         # False forces the gathered-view attention everywhere the kernel
-        # would run — an A/B and debugging knob (bench.py uses it to
-        # compare the two paths at identical block size / pool geometry).
+        # would run: the paged_kernel quarantine's lever
+        # (server.LLMServer._build_batcher).
         self.use_pallas_kernel = use_pallas_kernel
         self.n_slots = n_slots
         self.max_len = max_len or config.max_seq_len
@@ -2014,18 +1953,6 @@ class ContinuousBatcher:
         self.top_k = 0 if top_k is None else int(top_k)
         self.prefill_chunk = prefill_chunk
         self.seed = seed
-        # Cost-model cache key prefix: the geometry half of the
-        # jit-cache key (per-dispatch statics like K append to it).
-        # A process-unique incarnation token keys per-batcher without
-        # requiring config to hash — id(config) would be unsound (a
-        # GC'd config's address can be reused by a new model with the
-        # same geometry, silently serving stale FLOPs/bytes).  Each
-        # rebuild re-lowers once per program — trace-time only.
-        self._cost_geom = (
-            next(_COST_GEOM_SEQ), self.n_slots, self.n_blocks,
-            self.block_size, bool(logprobs), mesh is not None,
-        )
-
         self.pool = init_pool(self.config, self.n_blocks, self.block_size)
         self.draft_pool = (
             init_pool(self.draft_config, self.n_blocks, self.block_size)
@@ -2324,26 +2251,6 @@ class ContinuousBatcher:
         self.last_dispatch_features = tuple(features)
         self.last_step_features.update(features)
 
-    def _dispatch_cost(
-        self, program: str, key: Tuple, lower,
-    ) -> Tuple[Optional[float], Optional[float]]:
-        """Per-dispatch attribution hook, called right before a jitted
-        program runs: (1) names ``program`` as this thread's compile
-        attribution (so a jit-cache miss during the call books its
-        backend-compile duration onto our obs sink), and (2) when cost
-        models are enabled, returns the program's static
-        (flops, bytes_accessed) at the live geometry — computed ONCE
-        per (program, geometry, key) via ``lower().cost_analysis()``
-        (``lower`` closes over the exact dispatch args), a dict hit on
-        every later dispatch.  Never a device dispatch or host sync
-        either way."""
-        _obs_mod.attribute_compiles(self.obs, program)
-        if not self.cost_models:
-            return None, None
-        cost = _COST_MODELS.get(program, self._cost_geom + tuple(key),
-                                lower)
-        return (None, None) if cost is None else cost
-
     def _take_nan(self) -> bool:
         """Consume an armed ``nan`` fault (the non-finite guard's test
         lever); no-op without an injector."""
@@ -2543,19 +2450,15 @@ class ContinuousBatcher:
             "host_kv_blocks": self.host_kv_blocks,
             "logprobs": self.logprobs,
             "use_pallas_kernel": bool(self.use_pallas_kernel),
-            # What the ctor RESOLVED (not what was asked for): the
-            # attention kernels every dispatch of this batcher traces
+            # The attention path every dispatch of this batcher traces
             # against, and whether the paged decode kernel can run at
             # this geometry at all (else the gathered XLA view serves).
             "attn_impl": self.config.attn_impl,
-            "prefill_kernel": self.config.prefill_kernel,
-            "decode_kernel": self.config.decode_kernel,
             "paged_kernel_eligible": _kernel_eligible(
                 self.block_size, self.mesh, self.config.kv_heads,
                 self.n_slots,
                 draft_config=self.draft_config if self.spec else None,
             ),
-            "cost_models": self.cost_models,
             "serve_mesh": smesh.mesh_shape(
                 self.mesh if self._mesh_placed else None
             ),
@@ -2897,24 +2800,11 @@ class ContinuousBatcher:
             self.d_active, self.d_temps, self.d_top_ps, self.d_top_ks,
             self.d_remaining, self.d_stops,
         )
-        self._dispatch_cost(
-            "_scatter_rows", (Rb, self.d_stops.shape),
-            lambda: _scatter_rows.lower(
-                state,
-                jax.ShapeDtypeStruct(idx.shape, idx.dtype),
-                tuple(
-                    jax.ShapeDtypeStruct((Rb,) + a.shape[1:], a.dtype)
-                    for a in state
-                ),
-            ),
-        )
+        _obs_mod.attribute_compiles(self.obs, "_scatter_rows")
         (self.d_table, self.d_n_alloc, self.d_fill, self.d_pos,
          self.d_active, self.d_temps, self.d_top_ps, self.d_top_ks,
          self.d_remaining, self.d_stops) = _scatter_rows(
-            (self.d_table, self.d_n_alloc, self.d_fill, self.d_pos,
-             self.d_active, self.d_temps, self.d_top_ps, self.d_top_ks,
-             self.d_remaining, self.d_stops),
-            jnp.asarray(idx),
+            state, jnp.asarray(idx),
             (take(self.table), take(self.n_alloc), take(self.fill),
              take(self.pos), take(self.active), take(self.temp_arr),
              take(self.top_p_arr), take(self.top_k_arr),
@@ -2972,17 +2862,6 @@ class ContinuousBatcher:
             self.n_slots,
         ):
             feats.append("paged_kernel")
-            # Host mirror of the _block static predicate: the stock
-            # kernel serves the chunk's T=1 decode steps whenever the
-            # paged path is live, the config selects it, and the pool
-            # is full-precision (int8 stays on the custom kernel).  A
-            # stock_paged quarantine rebuilds onto decode_kernel=
-            # "paged" — the CUSTOM kernel, not the gathered view.
-            if (
-                self.config.decode_kernel == "stock-paged"
-                and not self.pool.quantized
-            ):
-                feats.append("stock_paged")
         pf_flash = (
             pf is not None and pf.flash
             and self.config.attn_impl in ("auto", "flash")
@@ -3001,8 +2880,6 @@ class ContinuousBatcher:
             self._fault("flash_kernel")
         if "paged_kernel" in feats:
             self._fault("paged_kernel")
-        if "stock_paged" in feats:
-            self._fault("stock_paged_kernel")
         self.steps_total += K
         self.decode_dispatches_total += 1
         self.decode_chunk_last = K
@@ -3022,56 +2899,11 @@ class ContinuousBatcher:
             # The prefilling request samples inside the program, so the
             # greedy specialization must account for its policy too.
             all_greedy = all_greedy and pf.req.temperature <= 0.0
-        # Compile attribution + static cost model (obs.py): named
-        # BEFORE the dispatch so a jit-cache miss books onto the right
-        # program; the lower thunk closes over the exact live args
-        # (trace-time only — a dict hit once cached).
-        if pf is None:
-            prog = "_paged_decode_chunk"
-            cost_fl, cost_by = self._dispatch_cost(
-                prog, (K, all_greedy),
-                lambda: _paged_decode_chunk.lower(
-                    self.params, self.pool, self.d_table,
-                    self.d_n_alloc, self.d_fill, self.tau,
-                    self.d_tau_lp, self.d_pos, self.d_active,
-                    self.d_remaining, self.d_stops, self.keys,
-                    self.d_temps, self.d_top_ps, self.d_top_ks,
-                    config=self.config, n_iter=K,
-                    all_greedy=all_greedy, mesh=self.mesh,
-                    allow_kernel=self.use_pallas_kernel,
-                    with_logprobs=self.logprobs,
-                    placed=self._mesh_placed,
-                ),
-            )
-        else:
-            prog = "_fused_chunk"
-            cost_fl, cost_by = self._dispatch_cost(
-                prog, (K, pf.chunk, all_greedy),
-                lambda: _fused_chunk.lower(
-                    self.params, self.pool, self.d_table,
-                    self.d_n_alloc, self.d_fill, self.tau,
-                    self.d_tau_lp, self.d_pos, self.d_active,
-                    self.d_remaining, self.d_stops, self.keys,
-                    self.d_temps, self.d_top_ps, self.d_top_ks,
-                    pf.d_row, pf.d_toks, pf.d_len, pf.d_base, pf.d_off,
-                    pf.d_key,
-                    config=self.config, n_iter=K, pf_chunk=pf.chunk,
-                    all_greedy=all_greedy, mesh=self.mesh,
-                    allow_kernel=self.use_pallas_kernel,
-                    with_logprobs=self.logprobs,
-                    placed=self._mesh_placed,
-                ),
-            )
-        # Per-kernel MXU attribution: a stock-paged pure-decode chunk
-        # books under its own kind, so llm_mxu_utilization
-        # {kind="decode:stock-paged"} vs {kind="decode"} IS the live A/B
-        # gauge.  Fused chunks keep one kind — their FLOPs mix prefill
-        # and decode, so splitting them per-kernel would attribute
-        # flash work to the decode kernel.
-        kind = (
-            ("decode:stock-paged" if "stock_paged" in feats else "decode")
-            if pf_adv == 0 else "fused"
-        )
+        # Compile attribution (obs.py): named BEFORE the dispatch so a
+        # jit-cache miss books onto the right program.
+        prog = "_paged_decode_chunk" if pf is None else "_fused_chunk"
+        _obs_mod.attribute_compiles(self.obs, prog)
+        kind = "decode" if pf_adv == 0 else "fused"
         self.obs.dispatch_begin(kind, prog, K)
         t0_obs = time.monotonic()
         if pf is None:
@@ -3155,8 +2987,8 @@ class ContinuousBatcher:
             wall_ms=(now_obs - t0_obs) * 1000.0,
             fetch_ms=(now_obs - tf_obs) * 1000.0,
             swap_inflight=len(self._restoring), rids=obs_rids,
-            program=prog, flops=cost_fl, bytes_accessed=cost_by,
-            then="emit", moe=moe_counts, prefill_ctx=pf_ctx,
+            program=prog, then="emit", moe=moe_counts,
+            prefill_ctx=pf_ctx,
         )
         if pf_done_rid is not None:
             # The prefill's last chunk linked into the prefilling span
@@ -3289,22 +3121,11 @@ class ContinuousBatcher:
             feats: List[str] = ["spec_decode"]
             if self._spec_kernel_ok():
                 feats.append("paged_kernel")
-                # Stock kernel serves the DRAFT model's T=1 steps (the
-                # target's T=G+1 verify keeps the custom kernel's
-                # multi-token sweep — the _block predicate is static on
-                # T), so the feature keys on the draft config/pool.
-                if (
-                    self.draft_config.decode_kernel == "stock-paged"
-                    and not self.draft_pool.quantized
-                ):
-                    feats.append("stock_paged")
             self._record_dispatch(feats)
             self._fault("step")
             self._fault("spec_decode")
             if "paged_kernel" in feats:
                 self._fault("paged_kernel")
-            if "stock_paged" in feats:
-                self._fault("stock_paged_kernel")
             self.steps_total += 1
             self.spec_dispatches_total += 1
             self.spec_rounds_last = 1
@@ -3347,20 +3168,11 @@ class ContinuousBatcher:
         feats: List[str] = ["spec_decode"]
         if self._spec_kernel_ok():
             feats.append("paged_kernel")
-            # Draft T=1 steps ride the stock kernel when selected (see
-            # _step_spec for the target-verify split).
-            if (
-                self.draft_config.decode_kernel == "stock-paged"
-                and not self.draft_pool.quantized
-            ):
-                feats.append("stock_paged")
         self._record_dispatch(feats)
         self._fault("step")
         self._fault("spec_decode")
         if "paged_kernel" in feats:
             self._fault("paged_kernel")
-        if "stock_paged" in feats:
-            self._fault("stock_paged_kernel")
         self.steps_total += R
         self.decode_dispatches_total += 1
         self.spec_dispatches_total += 1
@@ -3370,21 +3182,7 @@ class ContinuousBatcher:
             s.request_id for s in self.slots.values() if s is not None
         ]
         all_greedy = bool(np.all(self.temp_arr[self.active] == 0.0))
-        cost_fl, cost_by = self._dispatch_cost(
-            "_spec_rounds_chunk", (R, all_greedy),
-            lambda: _spec_rounds_chunk.lower(
-                self.params, self.draft_params, self.pool,
-                self.draft_pool, self.d_table, self.d_n_alloc,
-                self.d_fill, self.tau, self.d_tau_lp, self.d_pos,
-                self.d_active, self.d_remaining, self.d_stops,
-                self.keys, self.d_temps, self.d_top_ps, self.d_top_ks,
-                t_config=self.config, d_config=self.draft_config,
-                n_draft=self.n_draft, n_rounds=R,
-                all_greedy=all_greedy,
-                use_kernel=self._spec_kernel_ok(), mesh=self.mesh,
-                with_logprobs=self.logprobs, placed=self._mesh_placed,
-            ),
-        )
+        _obs_mod.attribute_compiles(self.obs, "_spec_rounds_chunk")
         self.obs.dispatch_begin("spec", "_spec_rounds_chunk", R)
         t0_obs = time.monotonic()
         (packed, self.tau, self.d_tau_lp, self.d_fill, self.d_pos,
@@ -3414,8 +3212,7 @@ class ContinuousBatcher:
             wall_ms=(now_obs - t0_obs) * 1000.0,
             fetch_ms=(now_obs - tf_obs) * 1000.0,
             swap_inflight=len(self._restoring), rids=obs_rids,
-            program="_spec_rounds_chunk", flops=cost_fl,
-            bytes_accessed=cost_by, then="emit",
+            program="_spec_rounds_chunk", then="emit",
         )
         G = self.n_draft
         toks = arr[:, :, : G + 1]
@@ -3553,27 +3350,7 @@ class ContinuousBatcher:
             s.request_id for s in self.slots.values() if s is not None
         ]
         all_greedy = bool(np.all(self.temp_arr[self.active] == 0.0))
-
-        def _sds(a):
-            # Aval-only stand-ins for the mirrors the classic path
-            # uploads per round: lowering needs shapes/dtypes, never
-            # the bytes — the cost hook must not add uploads.
-            return jax.ShapeDtypeStruct(a.shape, a.dtype)
-
-        cost_fl, cost_by = self._dispatch_cost(
-            "_spec_round", (all_greedy,),
-            lambda: _spec_round.lower(
-                self.params, self.draft_params, self.pool,
-                self.draft_pool, _sds(self.table), _sds(self.n_alloc),
-                _sds(self.fill), self.tau, _sds(self.pos),
-                _sds(self.active), self.keys, _sds(self.temp_arr),
-                _sds(self.top_p_arr), _sds(self.top_k_arr),
-                t_config=self.config, d_config=self.draft_config,
-                n_draft=self.n_draft, all_greedy=all_greedy,
-                use_kernel=self._spec_kernel_ok(), mesh=self.mesh,
-                with_logprobs=self.logprobs, placed=self._mesh_placed,
-            ),
-        )
+        _obs_mod.attribute_compiles(self.obs, "_spec_round")
         self.obs.dispatch_begin("spec", "_spec_round")
         t0_obs = time.monotonic()
         outs, acc, lps, self.keys, self.pool, self.draft_pool = _spec_round(
@@ -3608,8 +3385,7 @@ class ContinuousBatcher:
             wall_ms=(now_obs - t0_obs) * 1000.0,
             fetch_ms=(now_obs - tf_obs) * 1000.0,
             swap_inflight=len(self._restoring), rids=obs_rids,
-            program="_spec_round", flops=cost_fl,
-            bytes_accessed=cost_by, then="emit",
+            program="_spec_round", then="emit",
         )
         round_proposed = round_accepted = 0
         # NOTE: the per-row fill/pos advances below touch the numpy
@@ -3737,13 +3513,7 @@ class ContinuousBatcher:
             )
             chunk = evicted[start:start + self.blocks_per_slot]
             ids[: len(chunk)] = chunk
-            self._dispatch_cost(
-                "_release_blocks", (ids.shape[0],),
-                lambda: _release_blocks.lower(
-                    self.pool.pos,
-                    jax.ShapeDtypeStruct(ids.shape, ids.dtype),
-                ),
-            )
+            _obs_mod.attribute_compiles(self.obs, "_release_blocks")
             self.pool = dataclasses.replace(
                 self.pool,
                 # audit: host-upload(eviction-batch id upload on the
@@ -4267,23 +4037,7 @@ class ContinuousBatcher:
         # never executed.
         for req, _, _ in grp:
             self.obs.begin_span(req.rid, "prefilling")
-
-        def _sds(a):
-            # Aval stand-ins (shape/dtype only) for the host arrays the
-            # dispatch below uploads — the cost hook must not add one.
-            return jax.ShapeDtypeStruct(a.shape, a.dtype)
-
-        cost_fl, cost_by = self._dispatch_cost(
-            "_paged_suffix_insert", (kb, T),
-            lambda: _paged_suffix_insert.lower(
-                self.params, self.pool, _sds(table_rows),
-                _sds(n_alloc_arr), _sds(fill0s), _sds(st), _sds(sm),
-                _sds(keysA), _sds(temps), _sds(top_ps), _sds(top_ks),
-                config=self.config, prefill_chunk=self.prefill_chunk,
-                mesh=self.mesh, with_logprobs=self.logprobs,
-                placed=self._mesh_placed,
-            ),
-        )
+        _obs_mod.attribute_compiles(self.obs, "_paged_suffix_insert")
         self.obs.dispatch_begin("suffix_insert", "_paged_suffix_insert", k)
         t0_obs = time.monotonic()
         self._record_dispatch(["prefix_cache"])
@@ -4327,8 +4081,7 @@ class ContinuousBatcher:
             wall_ms=(time.monotonic() - t0_obs) * 1000.0,
             swap_inflight=len(self._restoring),
             rids=[r.rid for r, _, _ in grp],
-            program="_paged_suffix_insert", flops=cost_fl,
-            bytes_accessed=cost_by,
+            program="_paged_suffix_insert",
         )
         idx = jnp.asarray(np.asarray(slots, np.int32))
         self.tau = self.tau.at[idx].set(tau[:k])
@@ -4543,10 +4296,7 @@ class ContinuousBatcher:
                 ready = True
             if not ready or r.polls <= self.swap_poll_min:
                 continue
-            cost_fl, cost_by = self._dispatch_cost(
-                "_adopt_jit", (len(r.staged["ids"]),),
-                lambda: adopt_lower(self.pool, r.staged),
-            )
+            _obs_mod.attribute_compiles(self.obs, "_adopt_jit")
             self.obs.dispatch_begin("adopt", "_adopt_jit", len(r.fresh))
             t_adopt = time.monotonic()
             self.pool = adopt_into_pool(self.pool, r.staged)
@@ -4586,8 +4336,7 @@ class ContinuousBatcher:
                 wall_ms=adopt_ms,
                 swap_inflight=len(self._restoring),
                 rids=(r.req.rid,),
-                program="_adopt_jit", flops=cost_fl,
-                bytes_accessed=cost_by,
+                program="_adopt_jit",
             )
             self.obs.begin_span(r.req.rid, "queued", note="restored")
 
@@ -4933,50 +4682,15 @@ class ContinuousBatcher:
                 self.config.attn_impl in ("auto", "flash")
                 and chunk > FLASH_MIN_SEQ
             )
-            # Host mirror of the splash dispatch inside _block: reuse
-            # the real eligibility predicate with the chunk geometry
-            # (q_len=chunk, kv_len=P covers every chunk of the loop —
-            # per-chunk kv_len is a multiple of chunk, so if chunk and
-            # P pass the %128 checks every chunk does too).
-            splash_used = flash and _kernels_mod.splash_eligible(
-                self.config, batch=kb, q_len=chunk, kv_len=P,
-                chunk_offset=0, quantized=self.pool.quantized,
-                mesh=self.mesh,
-            )
             for req in batch:
                 self.obs.begin_span(req.rid, "prefilling")
-
-            def _sds(a):
-                # Aval stand-ins for the admission upload arrays — the
-                # cost hook lowers without adding a host->device copy.
-                return jax.ShapeDtypeStruct(a.shape, a.dtype)
-
-            cost_fl, cost_by = self._dispatch_cost(
-                "_paged_insert", (kb, P),
-                lambda: _paged_insert.lower(
-                    self.params, self.pool, _sds(bid), _sds(pt),
-                    _sds(pm), _sds(keys), _sds(temps), _sds(top_ps),
-                    _sds(top_ks),
-                    config=self.config,
-                    prefill_chunk=self.prefill_chunk,
-                    mesh=self.mesh, with_logprobs=self.logprobs,
-                    placed=self._mesh_placed,
-                ),
-            )
-            self.obs.dispatch_begin(
-                "insert:splash" if splash_used else "insert",
-                "_paged_insert", k,
-            )
+            _obs_mod.attribute_compiles(self.obs, "_paged_insert")
+            self.obs.dispatch_begin("insert", "_paged_insert", k)
             t0_obs = time.monotonic()
-            feats_ins: List[str] = ["flash_attention"] if flash else []
-            if splash_used:
-                feats_ins.append("splash_prefill")
-            self._record_dispatch(feats_ins)
+            self._record_dispatch(["flash_attention"] if flash else [])
             self._fault("insert")
             if flash:
                 self._fault("flash_kernel")
-            if splash_used:
-                self._fault("splash_kernel")
             self._admit_dispatches += 1
             taus, tau_lps, plens, keys_out, self.pool = _paged_insert(
                 # audit: host-upload(admission-time prompt/state upload
@@ -5036,10 +4750,7 @@ class ContinuousBatcher:
             # (what decode_stall_ms_total clocks); linked into each
             # request's prefilling span.
             self.obs.record_dispatch(
-                # Per-kernel MXU attribution: splash-served inserts get
-                # their own utilization series so the A/B is a live
-                # gauge, not just a bench key.
-                kind="insert:splash" if splash_used else "insert", k=k,
+                kind="insert", k=k,
                 occupancy=sum(
                     s is not None for s in self.slots.values()
                 ),
@@ -5048,8 +4759,7 @@ class ContinuousBatcher:
                 fetch_ms=(now_obs - tf_obs) * 1000.0,
                 swap_inflight=len(self._restoring),
                 rids=[r.rid for r in batch],
-                program="_paged_insert", flops=cost_fl,
-                bytes_accessed=cost_by,
+                program="_paged_insert",
             )
             for i, req in enumerate(batch):
                 b = slot_ids[i]

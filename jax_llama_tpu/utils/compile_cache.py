@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives — placed from outside.
 
 Called by the entry points (``run.py`` ``main``, ``chip_smoke.py``'s
-children, ``bench.py``'s mains), never at package import and never by
+children, ``rehearsal.py``), never at package import and never by
 the tests.  If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it
 and this module sets nothing in code; otherwise the cache goes to
 ``<checkout>/.jax_cache``.  The path is part of the cache key, so it is

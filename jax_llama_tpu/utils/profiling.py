@@ -73,7 +73,7 @@ def device_op_times(
     file XLA attributes the op to (``by="source"``).
 
     This is the measurement primitive behind the round-3..5 perf
-    numbers in bench.py/ROADMAP.md: wall-clock timing of a single
+    numbers in ROADMAP.md: wall-clock timing of a single
     dispatch includes the host's dispatch overhead, not just the op,
     while device-op durations from the xplane are the device's own
     clock.  Caller contract: warm

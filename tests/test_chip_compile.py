@@ -143,26 +143,41 @@ def test_paged_decode_compiles_for_v5e(sds, shape, quantized, t_tokens):
     _assert_mosaic(lowered)
 
 
-def test_splash_prefill_compiles_for_v5e(sds):
-    from jax_llama_tpu.ops.kernels import splash_prefill
+@pytest.mark.parametrize("view", [2048, 4096], ids=["cell1-view2048", "cell2-view4096"])
+def test_flash_fused_chunk_compiles_for_v5e(sds, view):
+    """The flash kernel as `_fused_chunk`'s prompt lane calls it in the two
+    Mistral-7B cells: a `prefill_budget` of 512 queries over the row's
+    whole view (`max_seq_len` 2048 and 4096), 32 query / 8 KV heads."""
+    from jax_llama_tpu.ops.flash_attention import flash_attention
 
-    q, k, v, _, _ = _flash_operands(sds)
-    _assert_mosaic(
-        splash_prefill.lower(q, k, v, chunk_offset=0, interpret=False)
-    )
+    chunk = 512
+    kv = sds((1, view, KVH, D), jnp.bfloat16)
+    _assert_mosaic(flash_attention.lower(
+        sds((1, chunk, H, D), jnp.bfloat16), kv, kv,
+        sds((1, chunk), jnp.int32), sds((1, view), jnp.int32), interpret=False,
+    ))
 
 
-def test_stock_paged_decode_compiles_for_v5e(sds):
-    from jax_llama_tpu.ops.kernels import stock_paged_decode
+@pytest.mark.parametrize(
+    "queries,keys", [(2048, 2048), (16384, 16384)],
+    ids=["chunk-over-tile", "whole-prompt-insert"],
+)
+def test_flash_lse_latent_width_compiles_for_v5e(sds, queries, keys):
+    """`flash_attention_lse` at kanana-2-30b-a3b's decompressed width (128
+    nope + 64 rope = 192, values padded to it, one KV head per query head)
+    in the two forms `mla_moe.attend_tiled` gives it in `kanana2-docqa-long`:
+    a 2,048-token chunk over one 2,048-slot tile (`_fused_chunk`), and a
+    whole prompt of the 16,384 bucket over itself (`_paged_insert`, the
+    window's first request)."""
+    from jax_llama_tpu.ops.flash_attention import flash_attention_lse
 
-    pool = sds((L, KVH, NB, BLK, D), jnp.bfloat16)
-    new = sds((B, 1, KVH, D), jnp.bfloat16)
-    lowered = stock_paged_decode.lower(
-        sds((B, 1, H, D), jnp.bfloat16), new, new, pool, pool,
-        sds((B, MB), jnp.int32), sds((B,), jnp.int32),
-        sds((), jnp.int32), interpret=False,
-    )
-    _assert_mosaic(lowered)
+    fn = jax.jit(lambda q, k, v, qp, kp: flash_attention_lse(
+        q, k, v, qp, kp, interpret=False))
+    kv = sds((1, keys, 32, 192), jnp.bfloat16)
+    _assert_mosaic(fn.lower(
+        sds((1, queries, 32, 192), jnp.bfloat16), kv, kv,
+        sds((1, queries), jnp.int32), sds((1, keys), jnp.int32),
+    ))
 
 
 def test_latent_paged_decode_compiles_for_v5e(sds):

@@ -107,7 +107,7 @@ def test_tiny_run_on_cpu_exercises_every_phase_and_still_fails(
     by_event = {ln.get("smoke"): ln for ln in lines if "smoke" in ln}
     assert by_event["devices"]["compile_cache"] == str(cache)
     assert by_event["kernels"]["paged_kernel_eligible"] is True
-    assert by_event["kernels"]["decode_kernel"] == "paged"
+    assert by_event["kernels"]["use_pallas_kernel"] is True
     assert by_event["compare"]["name"] == "prefix_hit_vs_cold"
     assert by_event["drained"]["exit_code"] == 0
     assert by_event["metrics"]["llm_fused_admissions_total"] >= 1

@@ -445,3 +445,34 @@ def test_checkpoint_atomic_overwrite_keeps_manifest_consistent(tmp_path):
     leftovers = [n for n in os.listdir(tmp_path)
                  if ".tmp-" in n or ".trash-" in n]
     assert leftovers == []
+
+
+def test_checkpoint_with_retired_config_keys_loads(tmp_path):
+    """Every checkpoint saved before PR 30 carries `prefill_kernel` /
+    `decode_kernel` in its config.json (fields of the config then): the
+    loader drops exactly those two, and any other unknown key still
+    fails."""
+    from jax_llama_tpu.convert.checkpoint import _write_manifest
+
+    cfg = cfg_lib.tiny()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    ckpt = tmp_path / "ckpt"
+    save_checkpoint(ckpt, params, cfg)
+
+    def rewrite(**extra):
+        meta = json.loads((ckpt / "config.json").read_text())
+        meta.update(extra)
+        (ckpt / "config.json").write_text(json.dumps(meta))
+        _write_manifest(ckpt)
+
+    assert "prefill_kernel" not in json.loads((ckpt / "config.json").read_text())
+    rewrite(prefill_kernel="flash", decode_kernel="paged")
+    restored, rcfg = load_checkpoint(ckpt)
+    assert rcfg == cfg
+    jax.tree.map(
+        lambda a, b: np.testing.assert_array_equal(np.asarray(a), np.asarray(b)),
+        params, restored,
+    )
+    rewrite(flash_kernel="on")
+    with pytest.raises(TypeError, match="flash_kernel"):
+        load_checkpoint(ckpt)

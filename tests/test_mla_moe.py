@@ -531,17 +531,6 @@ def test_unsupported_combination_is_refused_at_start(tiny, attempt, named):
         attempt(cfg, params)
 
 
-@pytest.mark.parametrize("kind,name", [("prefill", "splash"), ("decode", "stock-paged")])
-def test_kernels_the_latent_block_cannot_run_are_refused(tiny, kind, name):
-    from jax_llama_tpu.ops import kernels
-
-    _, cfg, _ = tiny
-    resolve = getattr(kernels, f"resolve_{kind}_kernel")
-    assert resolve("auto", cfg) in ("flash", "paged")
-    with pytest.raises(ValueError, match="latent"):
-        resolve(name, cfg)
-
-
 def test_host_kv_blocks_help_takes_the_block_size_from_the_pool():
     """`--host-kv-blocks`' help no longer states a formula of the dense block."""
     import subprocess

@@ -24,7 +24,6 @@ from jax_llama_tpu.obs import (
     LABELED_HISTOGRAMS,
     LOOP_PHASES,
     METRICS,
-    CostModelCache,
     Histogram,
     Observability,
     StructuredLogger,
@@ -120,10 +119,13 @@ def test_metric_registry_shape():
     # dispatch_ms renders as one labeled series per dispatch kind.
     assert LABELED_HISTOGRAMS == {"dispatch_ms"}
     # The labeled attribution families are registered too.
-    for fam in ("mxu_utilization", "hbm_utilization",
-                "host_overhead_ratio", "jit_cache_entries",
-                "program_compiles_total", "compiles_total"):
+    for fam in ("jit_cache_entries", "program_compiles_total",
+                "compiles_total"):
         assert metric_meta(fam) is not None, fam
+    # The cost-model gauges are gone from the registry (PR 30).
+    for fam in ("mxu_utilization", "hbm_utilization",
+                "host_overhead_ratio"):
+        assert metric_meta(fam) is None, fam
 
 
 # ---------------------------------------------------------------------------
@@ -769,24 +771,19 @@ def test_trace_json_window_filters_old_events():
 
 
 # ---------------------------------------------------------------------------
-# Device-time attribution: per-kind histograms, cost models, compiles
+# Per-kind dispatch histograms and compile attribution
 # ---------------------------------------------------------------------------
 
 def test_per_kind_dispatch_histograms_and_utilization():
-    """Dispatches split into per-kind labeled dispatch_ms series; a
-    dispatch carrying a cost model feeds the per-kind utilization
-    window (flops/bytes over wall vs the configured peaks) and its
-    record gains a roofline device-time estimate."""
-    obs = Observability(peak_flops=1e12, peak_bytes_per_s=1e12)
-    # 1 GFLOP + 1 MB over 10 ms wall -> 10% MXU, ~0.01% HBM, and a
-    # device estimate of 1 ms -> host_overhead_ratio 10.
+    """Dispatches split into per-kind labeled dispatch_ms series; the
+    record names its program and carries no cost-model field."""
+    obs = Observability()
     obs.record_dispatch(kind="decode", k=4, wall_ms=10.0,
-                        program="_paged_decode_chunk",
-                        flops=1e8, bytes_accessed=1e6)
-    obs.record_dispatch(kind="spec", k=2, wall_ms=5.0)  # no model
+                        program="_paged_decode_chunk")
+    obs.record_dispatch(kind="spec", k=2, wall_ms=5.0)
     rec = list(obs.dispatches)[0]
     assert rec["program"] == "_paged_decode_chunk"
-    assert rec["device_est_ms"] == pytest.approx(0.1)
+    assert not {"flops", "bytes_accessed", "device_est_ms"} & set(rec)
     assert obs.hist_dispatch["decode"].count == 1
     assert obs.hist_dispatch["spec"].count == 1
     lines = obs.expose_histograms()
@@ -797,44 +794,9 @@ def test_per_kind_dispatch_histograms_and_utilization():
         for ln in lines
     )
     assert 'llm_dispatch_ms_count{kind="spec"} 1' in lines
-    util = {
-        (fam, lab.get("kind")): v
-        for fam, lab, v in obs.utilization_metrics()
-    }
-    assert util[("mxu_utilization", "decode")] == pytest.approx(0.01)
-    assert util[("host_overhead_ratio", "decode")] == pytest.approx(
-        100.0
-    )
-    # The model-less spec dispatch feeds no utilization window.
-    assert ("mxu_utilization", "spec") not in util
-
-
-def test_cost_model_cache_computes_once_and_caches_failure():
-    calls = {"n": 0}
-
-    class _Lowered:
-        def cost_analysis(self):
-            return {"flops": 8.0, "bytes accessed": 16.0}
-
-    def lower():
-        calls["n"] += 1
-        return _Lowered()
-
-    cache = CostModelCache()
-    assert cache.get("p", (4, True), lower) == (8.0, 16.0)
-    assert cache.get("p", (4, True), lower) == (8.0, 16.0)
-    assert calls["n"] == 1  # trace-time only: the second get is a hit
-    assert cache.get("p", (8, True), lower) == (8.0, 16.0)
-    assert calls["n"] == 2  # a new jit-cache key lowers once more
-
-    def broken():
-        raise RuntimeError("exotic sharded lowering")
-
-    assert cache.get("q", (), broken) is None
-    assert cache.get("q", (), broken) is None  # failure cached too
-    snap = cache.snapshot()
-    assert snap["p"]["keys"] == 2 and snap["p"]["modeled"] == 2
-    assert snap["q"]["modeled"] == 0
+    # A kind serving.py does not record is refused, not minted.
+    with pytest.raises(ValueError, match="unknown dispatch kind"):
+        obs.record_dispatch(kind="decode:stock-paged", wall_ms=1.0)
 
 
 def test_compile_recording_spans_and_counters():
@@ -855,7 +817,7 @@ def test_compile_recording_spans_and_counters():
     }
     assert (
         "program_compiles_total", {"program": "_fused_chunk"}, 2,
-    ) in obs.utilization_metrics()
+    ) in obs.compile_metrics()
     doc = obs.trace_json()
     assert doc["t0_unix_s"] > 0
     compiles = [

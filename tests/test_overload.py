@@ -555,7 +555,8 @@ def _flood_server(params, config, **ctl_kw):
     ctl = OverloadController(
         enabled=True, max_queue=ctl_kw.pop("max_queue", 8),
         slo_ttft_ms=slo, dwell_s=0.05, cooldown_s=0.2,
-        signal_window_s=2.0, min_signal_samples=2, **ctl_kw,
+        signal_window_s=ctl_kw.pop("signal_window_s", 2.0),
+        min_signal_samples=2, **ctl_kw,
     )
     return LLMServer(cb, overload=ctl)
 
@@ -624,29 +625,37 @@ def test_flood_escalates_ladder_then_recovers_to_normal(model):
     end, not just in the clock-injected unit."""
     params, config = model
     # An unmeetable TTFT SLO (0.01 ms) makes every served request a
-    # miss — deterministic pressure without timing sensitivity.
-    with _flood_server(params, config, slo_ttft_ms=0.01) as srv:
+    # miss — deterministic pressure without timing sensitivity.  The
+    # signal window is 8 s, not the other drills' 2 s: the ladder needs
+    # two misses inside one window, and beside five busy workers two
+    # interactive requests can finish more than 2 s apart (it then never
+    # moved at all; ROADMAP C12).
+    with _flood_server(
+        params, config, slo_ttft_ms=0.01, signal_window_s=8.0,
+    ) as srv:
         _post(srv.address, {"prompt": [1, 2], "max_new_tokens": 3})
         _run_flood(srv, n=16, rate_hz=20.0)
+        # The escalation is read from what the run leaves behind, not
+        # from the rung at this instant: with a 0.2 s cool-down the
+        # ladder may have risen and come back down before the flood has
+        # been joined.
         deadline = time.monotonic() + 60.0
-        seen_elevated = False
-        while time.monotonic() < deadline:
-            rung = srv.overload.rung
-            if rung != "normal":
-                seen_elevated = True
-                break
+        while (srv.overload.transitions_total < 1
+               and time.monotonic() < deadline):
             time.sleep(0.05)
-        assert seen_elevated, "ladder never escalated under the flood"
+        assert srv.overload.transitions_total >= 1, (
+            "ladder never escalated under the flood: "
+            f"{srv.overload.health()}"
+        )
         with urllib.request.urlopen(srv.address + "/healthz") as r:
             h = json.loads(r.read())
-        assert h["overload"]["rung"] != "normal"
         assert h["overload"]["enabled"] is True
         # Escalations are annotated into the obs event ring.
         assert any(
             e["name"] == "overload_transition"
             for e in list(srv.obs.events)
         )
-        # Flood over: the signal window drains (2 s) and the ladder
+        # Flood over: the signal window drains (8 s) and the ladder
         # walks back down one cooldown (0.2 s) per rung.
         deadline = time.monotonic() + 60.0
         while time.monotonic() < deadline:

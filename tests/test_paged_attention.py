@@ -318,7 +318,6 @@ def test_blocks_per_step_follows_the_shapes(blk, mb, kvh, d, itemsize, want):
     assert _blocks_per_step(blk, mb, kvh, d, itemsize) == want
 
 
-@pytest.mark.slow  # interpret-mode Pallas / long decode on CPU; out of the tier-1 budget (plain `pytest tests/` still runs it)
 def test_paged_forward_multi_token_matches_gathered_view():
     """paged_forward at T=3 (the verify shape) vs the gathered-view
     forward: same logits for active rows, same pool afterwards."""
@@ -660,7 +659,6 @@ def test_batcher_on_tensor_data_mesh_matches_unsharded():
     assert got == want
 
 
-@pytest.mark.slow  # interpret-mode Pallas / long decode on CPU; out of the tier-1 budget (plain `pytest tests/` still runs it)
 def test_use_pallas_kernel_toggle_token_identical():
     """The explicit gathered-view toggle (bench's A/B knob) must not
     change tokens: kernel and gathered paths at IDENTICAL block size and

@@ -472,58 +472,43 @@ def test_tracing_adds_zero_device_dispatches_and_host_syncs(model):
 
 @pytest.mark.obs
 def test_attribution_adds_zero_device_dispatches_and_host_syncs(model):
-    """The device-time attribution layer (static cost models + compile
-    attribution) rides the existing one-fetch-per-chunk boundary: with
-    ``cost_models=True`` steady-state chunks STILL pay exactly one
-    device->host sync and zero state uploads each, every dispatch span
-    carries its program name and roofline estimate, and the cost
-    analysis ran at TRACE time only — the cache holds one entry per
-    (program, key), not one per dispatch."""
-    from jax_llama_tpu.serving import _COST_MODELS
-
+    """Compile attribution rides the existing one-fetch-per-chunk
+    boundary: steady-state chunks pay exactly one device->host sync and
+    zero state uploads each, every dispatch span carries its program
+    name, the compiles of the ramp are booked onto that program, and a
+    steady chunk compiles nothing."""
     params, config = model
-    # Peaks are named: off a listed device the ctor default is "no
-    # peaks, no utilization gauges" (obs.DEVICE_PEAKS).
     cb = ContinuousBatcher(
         params, config, n_slots=2, max_len=128, decode_chunk=4,
-        cost_models=True,
-        obs=Observability(peak_flops=1e12, peak_bytes_per_s=1e12),
     )
     cb.submit(list(np.random.RandomState(3).randint(1, 128, 9)),
               max_new_tokens=40)
     cb.step()   # admission + its one owed state sync
-    cb.step()   # chunk-size ramp (K=1,2 cost models land here)
+    cb.step()   # chunk-size ramp (K=1,2 compile here)
     cb.step()
     s0, u0, d0 = (
         cb.host_syncs_total, cb.state_uploads_total,
         cb.decode_dispatches_total,
     )
-    keys0 = sum(
-        e["keys"] for e in _COST_MODELS.snapshot().values()
-    )
+    compiles0 = cb.obs.compiles_total
     for _ in range(4):
         cb.step()
     dispatches = cb.decode_dispatches_total - d0
     assert dispatches == 4
-    # The 1-fetch/0-upload steady state is bit-identical with the
-    # attribution layer on.
     assert cb.host_syncs_total - s0 == dispatches
     assert cb.state_uploads_total == u0
-    # Steady-state dispatches hit the cost cache — zero new lowerings.
-    assert sum(
-        e["keys"] for e in _COST_MODELS.snapshot().values()
-    ) == keys0
     spans = list(cb.obs.dispatches)[-dispatches:]
     assert all(
-        sp["program"] == "_paged_decode_chunk" and "flops" in sp
-        and sp["device_est_ms"] > 0
+        sp["program"] == "_paged_decode_chunk" and sp["compiles"] == 0
         for sp in spans
     )
-    # The utilization window saw them: per-kind gauges are live.
-    fams = {f for f, lab, _ in cb.obs.utilization_metrics()
-            if lab.get("kind") == "decode"}
-    assert {"mxu_utilization", "hbm_utilization",
-            "host_overhead_ratio"} <= fams
+    assert cb.obs.compiles_total == compiles0
+    booked = dict(
+        (lab["program"], n) for _, lab, n in cb.obs.compile_metrics()
+    )
+    assert set(booked) <= {
+        "_paged_insert", "_paged_decode_chunk", "_scatter_rows",
+    }
 
 
 @pytest.mark.obs

@@ -3,6 +3,7 @@
 run against a tiny Orbax checkpoint with the byte tokenizer."""
 
 import sys
+from pathlib import Path
 
 import jax
 import pytest
@@ -353,28 +354,107 @@ def test_run_cli_cache_aware_disaggregation(
     assert h["handoff"]["completed_total"] >= 1
 
 
-def test_peaks_come_from_the_device_table_or_are_off():
-    """--peak-tflops / --peak-hbm-gbps default to a lookup by device_kind
-    (obs.DEVICE_PEAKS).  A device the table does not list — the CPU these
-    tests run on — gets 0 (no utilization gauges) and a log line, never
-    the v5e's numbers; explicit flags are kept."""
-    import io
+WORKLOADS = sorted(
+    (Path(__file__).resolve().parent.parent / "benchmark" / "workloads").glob("*.json")
+)
+
+
+@pytest.mark.parametrize("workload", WORKLOADS, ids=lambda p: p.stem)
+def test_every_server_key_of_a_cell_is_an_option_of_run(workload):
+    """`benchmark/system.serve` hands a cell's `server` block to
+    `_serve_http` in a bare namespace that refuses nothing: a key that is
+    no `dest` of run.py's parser (an option removed or misspelt) would be
+    silently ignored and the cell would run run.py's default instead."""
+    import json
+
+    dests = {a.dest for a in run_cli._parser()._actions}
+    # What `system.serve` itself consumes: the row count, and the two
+    # that go into the configuration, not the namespace.
+    known = dests | {"slots", "max_seq_len", "attn"}
+    for block in (json.loads(workload.read_text()),
+                  json.loads(workload.read_text())["rehearse"]):
+        assert set(block["server"]) <= known, set(block["server"]) - known
+
+
+LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+
+@pytest.fixture(scope="module")
+def lowerings_of_a_served_window():
+    """Serve two requests through `_serve_http` with a bare namespace (as
+    the benchmark does: every setting run.py's default), the second
+    admitted through the fused lane; returns per program the lowering
+    events jax fired and the jit-cache entries the run added."""
+    import json
+    import threading
+    import time
+    import urllib.request
     from types import SimpleNamespace
 
-    from jax_llama_tpu.obs import DEVICE_PEAKS, StructuredLogger
+    from jax import monitoring
 
-    assert DEVICE_PEAKS["TPU v5 lite"] == (197e12, 819e9)
-    assert "cpu" not in DEVICE_PEAKS
+    from jax_llama_tpu import serving
+    from jax_llama_tpu.tokenizers.bytes import ByteTokenizer
 
-    def resolve(kind, tflops=None, gbps=None):
-        buf = io.StringIO()
-        args = SimpleNamespace(peak_tflops=tflops, peak_hbm_gbps=gbps)
-        run_cli._resolve_peaks(args, kind, StructuredLogger(stream=buf))
-        return args.peak_tflops, args.peak_hbm_gbps, buf.getvalue()
+    # A vocabulary no other test of this file uses: every variant is new
+    # to the process-wide jit cache.
+    config = get_config(
+        "tiny", vocab_size=384, dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        multiple_of=32, max_seq_len=256,
+    )
+    params = init_params(jax.random.PRNGKey(0), config)
+    events, live = {}, [True]
 
-    assert resolve("TPU v5 lite")[:2] == (197.0, 819.0)
-    tf, bw, log = resolve("cpu")
-    assert (tf, bw) == (0.0, 0.0) and "utilization_gauges_off" in log
-    tf, bw, log = resolve("cpu", tflops=10.0, gbps=20.0)
-    assert (tf, bw) == (10.0, 20.0) and log == ""
-    assert resolve("TPU v5 lite", tflops=100.0)[:2] == (100.0, 819.0)
+    def listener(event, duration_secs, fun_name=None, **kw):
+        if live[0] and event == LOWERING_EVENT:
+            events[fun_name] = events.get(fun_name, 0) + 1
+
+    monitoring.register_event_duration_secs_listener(listener)
+    before = serving.jit_cache_entries()
+    stats = {}
+
+    def post(srv, prompt, n):
+        req = urllib.request.Request(
+            srv.address + "/generate",
+            data=json.dumps({"prompt": prompt, "max_new_tokens": n,
+                             "temperature": 0.0}).encode(),
+            headers={"Content-Type": "application/json"},
+        )
+        with urllib.request.urlopen(req, timeout=300) as r:
+            return json.loads(r.read())
+
+    def hook(srv):
+        first = threading.Thread(
+            target=post, args=(srv, list(range(3, 23)), 100))
+        first.start()
+        deadline = time.monotonic() + 120
+        while (srv.batcher.decode_dispatches_total < 1
+               and time.monotonic() < deadline):
+            time.sleep(0.005)
+        post(srv, list(range(40, 70)), 8)
+        first.join()
+        stats.update(srv.batcher.stats())
+
+    args = SimpleNamespace(
+        slots=4, temperature=0.0, top_p=0.95, seed=0, host="127.0.0.1",
+        http=0, replicas=1,
+    )
+    try:
+        run_cli._serve_http(
+            params, config, ByteTokenizer(), None, args, _test_hook=hook)
+    finally:
+        live[0] = False
+    after = serving.jit_cache_entries()
+    assert stats["fused_admissions_total"] >= 1, stats
+    return events, {k: after[k] - before.get(k, 0) for k in after}
+
+
+@pytest.mark.parametrize("program", ["_paged_decode_chunk", "_fused_chunk"])
+def test_setup_lowers_a_program_variant_once(
+    lowerings_of_a_served_window, program
+):
+    """A variant's first dispatch is its only lowering: no second pass
+    over the program for a cost model (run.py's default until PR 30)."""
+    events, added = lowerings_of_a_served_window
+    assert added[program] >= 1
+    assert events.get(f"jit({program})", 0) == added[program], (events, added)

@@ -198,7 +198,12 @@ def test_http_mid_generation_timeout_reaps_active_request(model):
     # A generation budget far larger than 2s of CPU steps can finish.
     cb = ContinuousBatcher(params, config, n_slots=1, max_len=4096)
     total_blocks = cb.n_blocks
-    with LLMServer(cb) as srv:
+    # priority_classes=False: no deadline proof at admission.  Its rate
+    # comes from the warm-up's insert, whose wall time is the compile: on
+    # a loaded machine 3 tokens in over 2 s reads as a deadline that
+    # cannot be met, and the request is refused 503 before the reaper
+    # under test ever sees it (ROADMAP C12).
+    with LLMServer(cb, priority_classes=False) as srv:
         # Warm the compile caches so the timed request spends its budget
         # generating, not compiling.
         status, _ = _post(
@@ -763,14 +768,7 @@ def test_metrics_exposition_valid_prometheus(model):
     consistent with semantics, and the histogram families obey the
     cumulative-bucket invariants."""
     params, config = model
-    from jax_llama_tpu.obs import Observability
-
-    # Peaks are named: without them (the ctor default, and any device
-    # obs.DEVICE_PEAKS does not list) the utilization gauges are off.
-    cb = ContinuousBatcher(
-        params, config, n_slots=2, max_len=64, cost_models=True,
-        obs=Observability(peak_flops=1e12, peak_bytes_per_s=1e12),
-    )
+    cb = ContinuousBatcher(params, config, n_slots=2, max_len=64)
     with LLMServer(cb, tokenizer=ByteTokenizer()) as srv:
         status, _ = _post(
             srv.address, {"prompt": [3, 4, 5], "max_new_tokens": 6}
@@ -858,16 +856,15 @@ def test_metrics_exposition_valid_prometheus(model):
     # The request actually fed TTFT and the per-kind dispatch series.
     assert samples["llm_ttft_ms_count"] >= 1
     assert samples['llm_dispatch_ms_count{kind="decode"}'] >= 1
-    # Device-time attribution: per-kind utilization gauges (the
-    # batcher above has cost models ON) and the jit-cache entry gauge
-    # (one labeled sample per registered program).
-    for fam in ("llm_mxu_utilization", "llm_hbm_utilization",
-                "llm_host_overhead_ratio", "llm_jit_cache_entries",
-                "llm_program_compiles_total"):
+    # Jit-cache observability: the entry gauge (one labeled sample per
+    # registered program) and the per-program compile counter; the
+    # cost-model gauges are gone (PR 30).
+    for fam in ("llm_jit_cache_entries", "llm_program_compiles_total"):
         assert fam in types, fam
-    assert types["llm_mxu_utilization"] == "gauge"
-    assert samples['llm_mxu_utilization{kind="decode"}'] >= 0.0
-    assert samples['llm_host_overhead_ratio{kind="decode"}'] > 0.0
+    for fam in ("llm_mxu_utilization", "llm_hbm_utilization",
+                "llm_host_overhead_ratio"):
+        assert fam not in types, fam
+    assert types["llm_program_compiles_total"] == "counter"
     cache_progs = {
         n for n in samples if n.startswith("llm_jit_cache_entries{")
     }

@@ -357,3 +357,24 @@ def test_block_size_tiered_default():
     cb = ContinuousBatcher(params, cfg, n_slots=1, max_len=16384,
                            block_size=64)
     assert cb.block_size == 64
+
+
+def test_describe_names_the_resolved_kernels(model):
+    """/debug/bundle's batcher section says which attention path the
+    batcher runs and whether the paged kernel can run at this geometry —
+    what chip_smoke.py reads to refuse a run that never touched Pallas."""
+    params, config = model
+    d = ContinuousBatcher(params, config, n_slots=2, max_len=64).describe()
+    assert d["attn_impl"] == config.attn_impl
+    assert d["use_pallas_kernel"] is True
+    assert d["paged_kernel_eligible"] is True
+    for gone in ("prefill_kernel", "decode_kernel", "cost_models"):
+        assert gone not in d
+    g = ContinuousBatcher(
+        params, config, n_slots=2, max_len=64, use_pallas_kernel=False,
+    ).describe()
+    assert g["use_pallas_kernel"] is False
+    odd = ContinuousBatcher(
+        params, config, n_slots=2, max_len=64, block_size=12,
+    ).describe()
+    assert odd["paged_kernel_eligible"] is False   # 12 % 8 != 0

@@ -73,12 +73,8 @@ def _run_matrix(params, config, K, *, logprobs=False, stop=(), **cb_kw):
     )
 
 
-# K=8 cells ride slow (r17 budget rebalance, ~5 s each): the K=4 cells
-# pin chunked identity against the K=1 loop, and K-range adaptivity
-# (ramp to the configured chunk) is tier-1-pinned by
-# test_perf_smoke.py::test_chunk_size_adapts_around_admissions; the
-# K=8 re-proof runs in the unfiltered suite.
-@pytest.mark.parametrize("K", [4, pytest.param(8, marks=pytest.mark.slow)])
+# K=8 is every benchmark cell's `decode_chunk`: tier-1 since PR 30.
+@pytest.mark.parametrize("K", [4, 8])
 def test_chunk_token_identity_greedy_and_sampled(model, K):
     """K ∈ {4, 8} × {greedy, sampled} × max_new mid-chunk: identical to
     the K=1 loop (which test_serving.py pins against engine.generate)."""
@@ -88,8 +84,7 @@ def test_chunk_token_identity_greedy_and_sampled(model, K):
     assert got == base
 
 
-# K=8 rides slow with the same r17 justification as above.
-@pytest.mark.parametrize("K", [4, pytest.param(8, marks=pytest.mark.slow)])
+@pytest.mark.parametrize("K", [4, 8])
 def test_chunk_token_identity_stop_token_mid_chunk(model, K):
     """A stop token landing mid-chunk ends the request at exactly that
     token: the on-device stop set must agree with the host's."""
@@ -150,14 +145,9 @@ def test_chunk_token_identity_int8_kv(model):
     assert got == base
 
 
-@pytest.mark.slow
 def test_chunk_token_identity_gathered_fallback(model):
-    """slow (r14 budget rebalance, ~7 s): the quarantine drill
-    test_chunked_paged_kernel_quarantine_falls_back keeps the
-    gathered-fallback-under-chunking contract in tier-1 (it lands on
-    exactly this configuration and checks token identity through it).
-
-    The gathered-view fallback (use_pallas_kernel=False) chunks
+    """What a ``paged_kernel`` quarantine lands on (tier-1 since PR 30,
+    ~4 s).  The gathered-view fallback (use_pallas_kernel=False) chunks
     identically — the scan body's gather/scatter path is per-iteration
     the same program as one K=1 dispatch."""
     params, config = model

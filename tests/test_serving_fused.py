@@ -191,16 +191,15 @@ def test_fused_token_identity_int8_kv(model):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.slow
 def test_fused_token_identity_flash_prefill(model):
     """attn_impl='auto' with a >8-token budget runs the PREFILL half of
     the fused program through the flash kernel (the view's scalar write
     index keeps it off the must-xla path) — still token-identical to
     the classic xla admit-then-decode path.  block_size=8 keeps the
     cold classic admissions on xla, so flash only ever runs inside
-    ``_fused_chunk`` here.  slow: the interpret-mode flash compile is
-    ~26 s of pure trace time (tier-1 budget); the fused flash PATH
-    still runs in tier-1 via test_degrade's quarantine drill."""
+    ``_fused_chunk`` here.  Every benchmark cell's prefill path:
+    tier-1 since PR 30 (~18 s, most of it the interpret-mode flash
+    trace)."""
     params, config = model
     auto_cfg = config.replace(attn_impl="auto")
     base_t, _, _ = _scenario(
@@ -215,15 +214,11 @@ def test_fused_token_identity_flash_prefill(model):
     assert got_t == base_t
 
 
-@pytest.mark.slow
 def test_fused_token_identity_gathered_fallback(model):
     """use_pallas_kernel=False: the decode half of the fused program
     runs the gathered-view scan and the prefill half is unchanged —
-    still identical to the classic path on the same fallback.  slow:
-    the gathered decode scan is covered per-iteration by
-    tests/test_serving_chunked.py and the quarantine drills; this cell
-    pins the fused-prefill × gathered-decode CROSS in the unfiltered
-    suite."""
+    still identical to the classic path on the same fallback: what a
+    ``paged_kernel`` quarantine lands on (tier-1 since PR 30, ~9 s)."""
     params, config = model
     base_t, _, _ = _scenario(
         params, config, 0, use_pallas_kernel=False, logprobs=False,
