@@ -372,6 +372,14 @@ def paged_pool_write(
     threshold sits below it because per-plane trace size (5 planes when
     int8) is the binding cost before device time is.
 
+    Who takes which form: this PAIR form serves the writers that are per
+    token or per row — the decode iteration (T=1: always the chain), the
+    speculative verify, ``paged_forward`` and
+    ``serving._paged_suffix_insert`` (off the steady window; a wide one
+    falls to the scatter).  A writer whose entries are whole blocks of
+    one row — ``serving._fused_chunk``'s prompt chunk — takes
+    :func:`paged_pool_write_blocks` and never the scatter.
+
     plane: [L, KVH, NB, BLK, d] payload, [L, KVH, NB, BLK] scale, or
       [NB, BLK] position plane — the (NB, BLK) axes sit at (-3, -2),
       (-2, -1) and (0, 1) respectively, derived from ndim.
@@ -417,6 +425,55 @@ def paged_pool_write(
             plane = _constrain_pool_plane(
                 lax.dynamic_update_slice(plane, u, start)
             )
+    return _pin_pool_layout(plane)
+
+
+def paged_pool_write_blocks(
+    plane: jnp.ndarray,
+    upd: jnp.ndarray,
+    blk: jnp.ndarray,
+) -> jnp.ndarray:
+    """Land ``n`` WHOLE blocks: one ``dynamic_update_slice`` of a
+    ``[L, KVH, 1, BLK, d]`` slab a block, a chain of ``n`` (static).
+
+    The block form of :func:`paged_pool_write` for a writer whose new
+    entries are whole, block-aligned blocks of one row — the prompt chunk
+    of ``serving._fused_chunk`` (``_pf_chunk`` hands out whole blocks and
+    a chunk walk starts on a block boundary).  ``C`` tokens are ``C //
+    BLK`` slabs, not ``C`` (block, offset) pairs: no batched scatter at
+    any chunk size, so the pool keeps its row-major layout and none of
+    the scatter's four pool-sized relayout copies appear (compiled for a
+    v5e at [L, 8, 256, 128, 128] bf16, ``pf_chunk`` 512: four with the
+    pair form's scatter, none here — tests/test_chip_compile.py).
+
+    Drop semantics are the pair chain's: a dead block carries the
+    sentinel id NB (past the row's reservation, past the table's last
+    column), ``dynamic_update_slice`` would CLAMP it onto block NB - 1,
+    so each write re-reads the (identically clamped) target slab and
+    selects it back — an exact in-place no-op on whatever row owns that
+    block.
+
+    plane: [L, KVH, NB, BLK, d] payload, [L, KVH, NB, BLK] scale, or
+      [NB, BLK] position plane.
+    upd: matching [L, KVH, n, BLK, d] / [L, KVH, n, BLK] / [n, BLK].
+    blk: [n] int32 physical block ids (sentinel NB = drop).
+    """
+    (n,) = blk.shape
+    plane = _constrain_pool_plane(plane)
+    upd = _constrain_pool_plane(upd)  # see paged_pool_write
+    nb_ax = 0 if plane.ndim == 2 else 2
+    live = blk < plane.shape[nb_ax]
+    zero = jnp.int32(0)
+    for j in range(n):
+        start = (
+            (zero,) * nb_ax + (blk[j],) + (zero,) * (plane.ndim - nb_ax - 1)
+        )
+        new = lax.slice_in_dim(upd, j, j + 1, axis=nb_ax)
+        cur = lax.dynamic_slice(plane, start, new.shape)
+        u = jnp.where(live[j], new.astype(plane.dtype), cur)
+        plane = _constrain_pool_plane(
+            lax.dynamic_update_slice(plane, u, start)
+        )
     return _pin_pool_layout(plane)
 
 

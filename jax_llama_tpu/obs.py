@@ -416,6 +416,12 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "prefill_ctx_slots_view_total": _reg(
         "counter", "Slots of the prefilling row's view, summed over "
                    "dispatches that carried a prefill lane"),
+    "prefill_blocks_written_total": _reg(
+        "counter", "Whole blocks the fused prefill lane landed in the pool "
+                   "(block form: one slab a block)"),
+    "prefill_pairs_written_total": _reg(
+        "counter", "(block, offset) token slots the whole-prompt and "
+                   "suffix inserts landed in the pool (pair form)"),
     # -- routed experts (ops/moe.py; zero on a configuration without) -------
     "moe_assignments_total": _reg(
         "counter", "(token, expert) pairs the router assigned"),
@@ -1305,6 +1311,7 @@ class Observability:
         then: Optional[str] = None,
         moe: Optional[Sequence[int]] = None,
         prefill_ctx: Optional[Tuple[int, int]] = None,
+        prefill_write: Optional[Dict[str, int]] = None,
     ) -> int:
         """Record one jitted serving dispatch and link it into the
         CURRENT span of every request that rode it.  Returns the
@@ -1326,6 +1333,9 @@ class Observability:
         expert-layer calls.  ``prefill_ctx`` (dispatches with a prefill
         lane) is the (attended, view) slots of the prefilling row's view:
         what prefill attention did work for, and the view's width.
+        ``prefill_write`` (dispatches that land prompt KV) is what they
+        landed in the pool: ``{"blocks": n}`` whole blocks from the fused
+        lane, ``{"pairs": n}`` token slots from an insert.
         ``then`` names the phase the loop thread is in once
         the dispatch ends (default: the one it interrupted)."""
         if kind not in DISPATCH_KINDS:
@@ -1357,6 +1367,10 @@ class Observability:
         if prefill_ctx is not None:
             rec["prefill_ctx"] = {
                 "attended": int(prefill_ctx[0]), "view": int(prefill_ctx[1]),
+            }
+        if prefill_write is not None:
+            rec["prefill_write"] = {
+                key: int(v) for key, v in prefill_write.items()
             }
         rec.update(gap)
         with self._lock:

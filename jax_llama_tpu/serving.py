@@ -209,6 +209,7 @@ from .models.llama import (
     lm_head_logits,
     moe_stats_zero,
     paged_pool_write,
+    paged_pool_write_blocks,
     paged_write_indices,
 )
 from .models.mla_moe import ctx_tiles
@@ -363,7 +364,14 @@ def _scatter_back(
 ) -> BlockPool:
     """Write the T new entries per row from the gathered view back into
     their physical blocks.  Inactive rows and out-of-reservation columns
-    resolve to the sentinel block id and are dropped."""
+    resolve to the sentinel block id and are dropped.
+
+    The PAIR form — B*T (block, offset) pairs through
+    ``paged_pool_write`` — for the writers that are per token or per
+    row: ``_chunk_scan`` (T=1), the speculative verify,
+    ``_paged_suffix_insert``.  ``_fused_chunk``'s prompt chunk is whole
+    blocks of one row and takes the block form, ``_land_chunk``, whose
+    oracle this is (tests/test_serving_fused.py)."""
     NB, BLK = pool.pos.shape
     B, MB = table.shape
     rows = jnp.arange(B, dtype=jnp.int32)[:, None]
@@ -387,6 +395,40 @@ def _scatter_back(
             pool, view,
         ),
         pos=paged_pool_write(pool.pos, npos, blk, off),
+        stats=view.stats,
+    )
+
+
+def _land_chunk(
+    pool: BlockPool,
+    view: KVCache,
+    table_r: jnp.ndarray,
+    write_at: jnp.ndarray,
+    C: int,
+) -> BlockPool:
+    """Write the C new columns of ONE row's gathered view, from the
+    block-aligned column ``write_at``, back into the pool as ``C // BLK``
+    whole blocks (``paged_pool_write_blocks``): the block form of
+    ``_scatter_back(pool, view, table_r, write_at[None], ones, T=C)``
+    and bit-equal to it, plane by plane.  ``_pf_chunk`` makes C whole
+    blocks and keeps ``write_at + C`` inside the view; table entries
+    past the row's reservation hold the sentinel and drop."""
+    NB, BLK = pool.pos.shape
+    n = C // BLK
+    col = write_at // BLK + jnp.arange(n, dtype=jnp.int32)
+    blk = jnp.take(table_r[0], col, mode="fill", fill_value=NB)
+
+    def land(plane, seen):
+        # [L, 1, MB*BLK, KVH, ...] -> the chunk, [L, KVH, n, BLK, ...]
+        new = lax.dynamic_slice_in_dim(seen[:, 0], write_at, C, axis=1)
+        new = new.reshape(new.shape[:1] + (n, BLK) + new.shape[2:])
+        return paged_pool_write_blocks(plane, jnp.moveaxis(new, 3, 1), blk)
+
+    npos = lax.dynamic_slice_in_dim(view.pos[0], write_at, C).reshape(n, BLK)
+    return dataclasses.replace(
+        pool,
+        **_map_planes(land, pool, view),
+        pos=paged_pool_write_blocks(pool.pos, npos, blk),
         stats=view.stats,
     )
 
@@ -792,11 +834,12 @@ def _fused_chunk(
     (pf_chunk > 8) with the gathered XLA path as the quarantine/debug
     fallback; prefix-cache-hit rows start their chunk walk at
     fill0 = ``pf_base`` and attend the reused KV through the same view.
-    The chunk's KV lands in the row's reserved blocks via the shared
-    ``_scatter_back`` write contract.  The last prompt token's hidden
-    state is gathered every chunk (O(D); the [1, V] head matmul is
-    noise), but only the dispatch where ``pf_off + pf_chunk >= pf_len``
-    CONSUMES it: the row's key chain splits exactly once (the
+    The chunk's KV lands in the row's reserved blocks by whole blocks
+    (``_land_chunk``; the bytes ``_scatter_back``'s pairs would write).
+    The last prompt token's hidden state is gathered every chunk (O(D);
+    the [1, V] head matmul is noise), but only the dispatch where
+    ``pf_off + pf_chunk >= pf_len`` CONSUMES it: the row's key chain
+    splits exactly once (the
     ``_paged_insert`` split the classic path performs), the first token
     is sampled with the row's own policy (non-finite guard folds the -1
     sentinel exactly as admission does), and the row folds INTO the
@@ -853,10 +896,7 @@ def _fused_chunk(
         logits_last = lm_head_logits(
             params, h_last[:, None], config, normed=True
         )[:, 0]
-        pool = _scatter_back(
-            pool, view, table_r, write_at[None], jnp.ones((1,), bool),
-            T=C,
-        )
+        pool = _land_chunk(pool, view, table_r, write_at, C)
         # The admission sample — only persisted below when the prompt
         # completes this dispatch (the split/sample topology is exactly
         # _paged_insert's, so the row's stream is bit-identical to the
@@ -1635,8 +1675,8 @@ def _spec_rounds_chunk(
 
 # Every jitted program the serving stack dispatches (the same ten the
 # analysis lowering contracts audit), by name — the source for the
-# per-program ``jit_cache_entries`` gauge (/metrics) and the cost-model
-# hooks below.  ``_cache_size()`` is jax's own per-function executable
+# per-program ``jit_cache_entries`` gauge (/metrics).
+# ``_cache_size()`` is jax's own per-function executable
 # cache; a runaway entry count here is a bucketing bug re-specializing
 # a program per request (the stall that used to be invisible).
 def _programs() -> Dict[str, Any]:
@@ -2206,6 +2246,8 @@ class ContinuousBatcher:
         self.moe_totals = dict.fromkeys(_MOE_STATS, 0)
         self.prefill_ctx_slots_attended_total = 0
         self.prefill_ctx_slots_view_total = 0
+        self.prefill_blocks_written_total = 0
+        self.prefill_pairs_written_total = 0
         self.fused_admissions_total = 0
         self.decode_stall_ms_total = 0.0
 
@@ -2592,6 +2634,8 @@ class ContinuousBatcher:
                 self.prefill_ctx_slots_attended_total
             ),
             "prefill_ctx_slots_view_total": self.prefill_ctx_slots_view_total,
+            "prefill_blocks_written_total": self.prefill_blocks_written_total,
+            "prefill_pairs_written_total": self.prefill_pairs_written_total,
             **{f"moe_{k}_total": v for k, v in self.moe_totals.items()},
             "fused_admissions_total": self.fused_admissions_total,
             "decode_stall_ms_total": round(self.decode_stall_ms_total, 3),
@@ -2893,6 +2937,9 @@ class ContinuousBatcher:
         ]
         pf_adv = 0 if pf is None else min(pf.chunk, pf.remaining_tokens)
         pf_ctx = None if pf is None else self._pf_ctx_slots(pf, pf_flash)
+        pf_write = (
+            None if pf is None else {"blocks": self._pf_live_blocks(pf)}
+        )
         pf_done_rid: Optional[int] = None
         all_greedy = bool(np.all(self.temp_arr[self.active] == 0.0))
         if pf is not None:
@@ -2981,6 +3028,7 @@ class ContinuousBatcher:
         if pf_ctx is not None:
             self.prefill_ctx_slots_attended_total += pf_ctx[0]
             self.prefill_ctx_slots_view_total += pf_ctx[1]
+            self.prefill_blocks_written_total += pf_write["blocks"]
         self.obs.record_dispatch(
             kind=kind,
             k=K, occupancy=len(obs_rids), prefill_tokens=pf_adv,
@@ -2989,6 +3037,7 @@ class ContinuousBatcher:
             swap_inflight=len(self._restoring), rids=obs_rids,
             program=prog, then="emit", moe=moe_counts,
             prefill_ctx=pf_ctx,
+            prefill_write=pf_write,
         )
         if pf_done_rid is not None:
             # The prefill's last chunk linked into the prefilling span
@@ -4069,6 +4118,10 @@ class ContinuousBatcher:
                 prefill_chunk=self.prefill_chunk, mesh=self.mesh,
                 placed=self._mesh_placed,
             )
+        # Live (block, offset) pairs ``_scatter_back`` lands: each row's T
+        # columns from fill0, less those past its reservation.
+        pairs = int(np.minimum(T, n_alloc_arr * bs - fill0s)[:k].sum())
+        self.prefill_pairs_written_total += pairs
         # Dispatch span (async submit — wall covers dispatch time only,
         # the suffix path's known undercount); linked into each
         # request's prefilling span, which then closes into decoding.
@@ -4082,6 +4135,7 @@ class ContinuousBatcher:
             swap_inflight=len(self._restoring),
             rids=[r.rid for r, _, _ in grp],
             program="_paged_suffix_insert",
+            prefill_write={"pairs": pairs},
         )
         idx = jnp.asarray(np.asarray(slots, np.int32))
         self.tau = self.tau.at[idx].set(tau[:k])
@@ -4379,6 +4433,17 @@ class ContinuousBatcher:
             return view, view
         tile, trips = ctx_tiles(pf.base + pf.off, view)
         return min(trips * tile, view), view
+
+    def _pf_live_blocks(self, pf: _Prefill) -> int:
+        """Blocks the fused dispatch about to advance ``pf`` lands in the
+        pool (``_land_chunk``): the chunk's ``chunk // block_size`` table
+        columns from ``(base + off) // block_size``, less those past the
+        row's reservation, whose sentinel entries drop.  Host mirror, from
+        numbers the scheduler holds."""
+        bs = self.block_size
+        first = (pf.base + pf.off) // bs
+        held = len(self.slots[pf.slot].blocks)
+        return max(0, min(pf.chunk // bs, held - first))
 
     def _pf_chunk(self, suffix_len: int, n_share: int) -> int:
         """Prompt tokens per fused dispatch: ``prefill_budget`` rounded
@@ -4745,6 +4810,10 @@ class ContinuousBatcher:
             plens_np = np.asarray(plens)
             self.host_syncs_total += 1
             now_obs = time.monotonic()
+            # Token slots of the live entries of ``bid``: what the insert
+            # lands, a (block, offset) pair each.
+            pairs = int((bid < self.n_blocks).sum()) * self.block_size
+            self.prefill_pairs_written_total += pairs
             # Whole-prompt insert dispatch span: the plens fetch blocks
             # on the prefill, so wall here is the real admission cost
             # (what decode_stall_ms_total clocks); linked into each
@@ -4760,6 +4829,7 @@ class ContinuousBatcher:
                 swap_inflight=len(self._restoring),
                 rids=[r.rid for r in batch],
                 program="_paged_insert",
+                prefill_write={"pairs": pairs},
             )
             for i, req in enumerate(batch):
                 b = slot_ids[i]

@@ -17,6 +17,7 @@ live in THIS file (a second file could land on another worker, whose
 fixture would then skip), and compile in the test's own process.
 """
 
+import importlib
 import os
 import sys
 
@@ -255,3 +256,51 @@ def test_latent_tiled_prefill_compiles_for_v5e(sds, monkeypatch):
     )
     _assert_mosaic(lowered)
     assert f"{view + chunk},32" not in lowered.as_text()
+
+
+@pytest.mark.parametrize(
+    "rows,view", [(16, 2048), (8, 4096)],
+    ids=["cell1-16rows", "cell2-8rows"],
+)
+def test_fused_chunk_no_pool_copies_for_v5e(sds, monkeypatch, rows, view):
+    """`_fused_chunk` whole, at the two Mistral-7B cells' widths: a
+    [L, 8, 256, 128, 128] bf16 pool of 32,768 tokens under 16 rows x 2048 and
+    8 rows x 4096, `pf_chunk` 512 (four 128-token blocks), 8 decode
+    iterations.  Depth is cut to 2 so the case stays in seconds; a relayout
+    of the pool does not depend on depth.  The prompt chunk lands by whole
+    blocks (`paged_pool_write_blocks`), so no pool-sized `copy` may stand in
+    the compiled program: the pair form's scatter left four (ledger, PRs
+    25-30: `%copy.18x bf16[24,8,256,128,128]`, 15 % of cell 2's busy time)."""
+    from test_serving_fused import fused_chunk_operand_shapes
+    from test_tpu_compiled import _pool_copy_offenders
+
+    from jax_llama_tpu import get_config, init_params, serving
+
+    # By module NAME: `jax_llama_tpu.ops` re-exports functions of these names.
+    for name in ("flash_attention", "paged_attention"):
+        monkeypatch.setattr(
+            importlib.import_module(f"jax_llama_tpu.ops.{name}"),
+            "_resolve_interpret", lambda _=None: False)
+    layers, blk, chunk = 2, 128, 512
+    cfg = get_config(
+        "tiny", dim=4096, n_layers=layers, n_heads=H, n_kv_heads=KVH,
+        intermediate_size=14336, vocab_size=32768, max_seq_len=view,
+        dtype="bfloat16", param_dtype="bfloat16", attn_impl="auto",
+    )
+    mb = view // blk
+    nb = rows * mb
+    place = lambda tree: jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pool = place(jax.eval_shape(lambda: serving.init_pool(cfg, nb, blk)))
+    assert pool.k.shape == (layers, KVH, 256, blk, D)
+    lowered = serving._fused_chunk.lower(
+        params, pool, *fused_chunk_operand_shapes(sds, rows, mb, chunk),
+        config=cfg, n_iter=8, pf_chunk=chunk, all_greedy=True, mesh=None,
+        allow_kernel=True, with_logprobs=False,
+    )
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") >= 2  # flash and the paged kernel
+    offenders = _pool_copy_offenders(text, pool.k.shape)
+    assert not offenders, (len(offenders), offenders)

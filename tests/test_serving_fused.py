@@ -14,10 +14,11 @@ through the classic batched insert; there is nobody to stall)."""
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jax_llama_tpu import get_config, init_params
+from jax_llama_tpu import get_config, init_params, serving
 from jax_llama_tpu.serving import ContinuousBatcher
 
 CFG = dict(
@@ -338,3 +339,260 @@ def test_rebuild_drops_inflight_prefill(model):
     assert cb2._pf is None and cb2.prefill_budget == cb.prefill_budget
     r = cb2.submit(list(prompt), max_new_tokens=6)
     assert cb2.run_to_completion()[r] == want
+
+
+# ---------------------------------------------------------------------------
+# The prompt chunk's write: whole blocks (``_land_chunk``) against the pair
+# form (``_scatter_back``), the form every other writer keeps
+# ---------------------------------------------------------------------------
+
+_FUSED_STATIC = (
+    "config", "n_iter", "pf_chunk", "all_greedy", "mesh", "allow_kernel",
+    "with_logprobs", "placed",
+)
+W_BLK, W_MB, W_ROWS, W_CHUNK = 16, 4, 2, 32   # 2 rows x 4 blocks = the pool
+W_NB = W_ROWS * W_MB
+# (reserved blocks of the prefilling row, pf_base, pf_off, pf_len)
+_WRITE_GEOMETRIES = {
+    # columns 0-1 of a whole reservation
+    "full-aligned-chunk": (4, 0, 0, 64),
+    # last chunk of a 40-token suffix: column 2 is the row's last block,
+    # column 3's table entry is the sentinel (a clamped write hits NB - 1)
+    "tail-past-reservation": (3, 0, 32, 40),
+    # behind a 2-block prefix hit, ending on the view's last column
+    "ends-at-column-MB": (4, 32, 0, 32),
+}
+
+
+_PAIR_FORM_TRACED = []
+
+
+def _pair_form(pool, view, table_r, write_at, C):
+    """The parent's write of the prompt chunk: C (block, offset) pairs."""
+    _PAIR_FORM_TRACED.append(pool.k.shape)
+    return serving._scatter_back(
+        pool, view, table_r, write_at[None], jnp.ones((1,), bool), T=C
+    )
+
+
+def _fused_with_pairs(*args, **kwargs):
+    # Patched while it is TRACED: a function of its own, so jit's cache
+    # can never hand it the block form's trace.
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(serving, "_land_chunk", _pair_form)
+        return serving._fused_chunk.__wrapped__(*args, **kwargs)
+
+
+# Undonated, so the pool handed in can be compared with what comes back.
+_BLOCK_FORM = jax.jit(
+    serving._fused_chunk.__wrapped__, static_argnames=_FUSED_STATIC
+)
+_PAIR_FORM = jax.jit(_fused_with_pairs, static_argnames=_FUSED_STATIC)
+
+
+@pytest.fixture(scope="module")
+def latent_model():
+    """The latent-attention block at test_mla_moe's tiny widths."""
+    import json
+
+    from test_mla_moe import BOOKKEEPING, CONFIG_FILE, TINY
+
+    from jax_llama_tpu import config as config_mod
+
+    raw = dict(json.loads(CONFIG_FILE.read_text()), **TINY)
+    cfg = config_mod.from_published(
+        {k: v for k, v in raw.items() if k not in BOOKKEEPING},
+        max_seq_len=W_MB * W_BLK, attn_impl="auto")
+    return init_params(jax.random.PRNGKey(3), cfg), cfg
+
+
+def fused_chunk_operand_shapes(sds, rows, mb, chunk):
+    """``_fused_chunk``'s 19 operands after ``params`` and ``pool``, as
+    ``sds(shape, dtype)`` makes them (tests/test_chip_compile.py places
+    them on a described chip)."""
+    i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
+    return (
+        sds((rows, mb), i32), sds((rows,), i32), sds((rows,), i32),
+        sds((rows,), i32), sds((rows,), f32), sds((rows,), i32),
+        sds((rows,), jnp.bool_), sds((rows,), i32), sds((rows, 1), i32),
+        sds((rows, 2), u32), sds((rows,), f32), sds((rows,), f32),
+        sds((rows,), i32),
+        sds((), i32), sds((chunk,), i32), sds((), i32), sds((), i32),
+        sds((), i32), sds((2,), u32),
+    )
+
+
+def _write_case(params, config, geometry, seed=0):
+    """(args, kwargs) of one ``_fused_chunk`` dispatch over a FULL pool
+    whose every slot holds a seeded pattern: row 0 prefills (blocks 0-3),
+    row 1 decodes at fill 20 and owns blocks 4-7 — block NB - 1 is its."""
+    held, base, off, plen = _WRITE_GEOMETRIES[geometry]
+    rng = np.random.RandomState(seed)
+    empty = serving.init_pool(config, W_NB, W_BLK)
+
+    def pattern(a):
+        if a.dtype == jnp.int8:
+            return jnp.asarray(rng.randint(-127, 128, a.shape), jnp.int8)
+        return jnp.asarray(rng.uniform(0.1, 1.0, a.shape), a.dtype)
+
+    pos = np.full((W_NB, W_BLK), -1, np.int32)
+    pos.reshape(-1)[:base] = np.arange(base)                  # row 0's prefix
+    pos[4:6].reshape(-1)[:20] = np.arange(20)                 # row 1's context
+    pos[W_NB - 1] = 1000 + np.arange(W_BLK)                   # the canary's
+    pool = dataclasses.replace(
+        empty, pos=jnp.asarray(pos), **serving._map_planes(pattern, empty)
+    )
+    table = np.full((W_ROWS, W_MB), W_NB, np.int32)
+    table[0, :held] = np.arange(held)
+    table[1] = 4 + np.arange(W_MB)
+    i32, f32 = jnp.int32, jnp.float32
+    toks = rng.randint(1, config.vocab_size, size=64).astype(np.int32)
+    args = (
+        params, pool, jnp.asarray(table), jnp.asarray([held, W_MB], i32),
+        jnp.asarray([0, 20], i32), jnp.asarray([0, 5], i32),
+        jnp.zeros((W_ROWS,), f32), jnp.asarray([0, 20], i32),
+        jnp.asarray([False, True]), jnp.asarray([6, 6], i32),
+        jnp.full((W_ROWS, 1), -1, i32), jnp.zeros((W_ROWS, 2), jnp.uint32),
+        jnp.zeros((W_ROWS,), f32), jnp.ones((W_ROWS,), f32),
+        jnp.zeros((W_ROWS,), i32),
+        jnp.asarray(0, i32), jnp.asarray(toks), jnp.asarray(plen, i32),
+        jnp.asarray(base, i32), jnp.asarray(off, i32),
+        jnp.zeros((2,), jnp.uint32),
+    )
+    kwargs = dict(
+        config=config, n_iter=2, pf_chunk=W_CHUNK, all_greedy=True,
+        allow_kernel=False,
+    )
+    first = (base + off) // W_BLK
+    live = [b for b in range(first, first + W_CHUNK // W_BLK) if b < held]
+    return args, kwargs, live
+
+
+@pytest.mark.parametrize("geometry", list(_WRITE_GEOMETRIES))
+@pytest.mark.parametrize("kind", ["dense", "int8", "latent"])
+def test_chunk_lands_by_blocks_bit_equal_to_the_pair_form(
+    model, latent_model, kind, geometry,
+):
+    """``_fused_chunk`` as it is (``_land_chunk``: C // BLK whole-block
+    slabs) against the same program with the pair form in its place
+    (``_scatter_back``, T = C): every plane the pool has and ``pos`` are
+    bit-equal, only the chunk's live blocks and the decoding row's one
+    changed, and block NB - 1 — another row's, where a clamped dead write
+    would land — is untouched."""
+    params, config = latent_model if kind == "latent" else model
+    if kind == "int8":
+        config = config.replace(kv_cache_dtype="int8")
+    args, kwargs, live = _write_case(params, config, geometry)
+    pool0 = args[1]
+
+    blocks = _BLOCK_FORM(*args, **kwargs)[8]
+    pairs = _PAIR_FORM(*args, **kwargs)[8]
+    assert pool0.k.shape in _PAIR_FORM_TRACED  # the oracle IS the pair form
+    planes = [n for n in serving._PLANES if getattr(pool0, n) is not None]
+    assert planes == {
+        "dense": ["k", "v"], "int8": ["k", "v", "k_scale", "v_scale"],
+        "latent": ["k"],
+    }[kind]
+    untouched = [b for b in range(W_NB) if b not in live + [5]]
+    assert W_NB - 1 in untouched and live
+    for name in planes + ["pos"]:
+        got, want, was = (
+            np.asarray(getattr(p, name)) for p in (blocks, pairs, pool0)
+        )
+        assert np.array_equal(got, want), name
+        ax = 0 if name == "pos" else 2
+        assert np.array_equal(
+            np.take(got, untouched, axis=ax), np.take(was, untouched, axis=ax)
+        ), name
+        for b in live:  # and the write did land
+            assert not np.array_equal(
+                np.take(got, b, axis=ax), np.take(was, b, axis=ax)
+            ), (name, b)
+
+
+def test_fused_chunk_traces_no_scatter_on_a_pool_plane(model):
+    """A 512-token chunk — twice ``_POOL_WRITE_UNROLL_MAX`` pairs, where
+    the pair form takes the batched scatter and XLA:TPU relayouts the
+    whole pool for it — traces no ``scatter`` whose operand is a pool
+    plane: the chunk is eight 64-token slabs."""
+    params, config = model
+    config = config.replace(max_seq_len=1024)
+    rows, blk, chunk = 2, 64, 512
+    mb = config.max_seq_len // blk
+    pool = jax.eval_shape(lambda: serving.init_pool(config, rows * mb, blk))
+    traced = serving._fused_chunk.trace(
+        params, pool,
+        *fused_chunk_operand_shapes(jax.ShapeDtypeStruct, rows, mb, chunk),
+        config=config, n_iter=2, pf_chunk=chunk, all_greedy=True,
+        allow_kernel=False,
+    )
+    plane_shapes = {pool.k.shape, pool.pos.shape}
+
+    def scatters(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name.startswith("scatter"):
+                yield eqn.invars[0].aval.shape
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scatters(sub)
+
+    found = [s for s in scatters(traced.jaxpr.jaxpr) if s in plane_shapes]
+    assert not found, found
+
+
+@pytest.mark.parametrize("kind", ["dense", "latent"])
+def test_served_fused_admission_is_engine_generate_and_counts_its_writes(
+    model, latent_model, kind,
+):
+    """A prompt admitted through the fused lane while a row decodes, then
+    re-asked with a question appended (a 4-block prefix hit, again through
+    the fused lane), serves an unbatched ``engine.generate``'s tokens — and
+    the two write counters say what landed: whole blocks from the fused
+    lane, a last chunk's two blocks past the reservation not counted; token
+    slots from the idle server's whole-prompt insert."""
+    from jax_llama_tpu.engine import GenerationConfig, generate
+    from jax_llama_tpu.obs import metric_meta
+
+    params, config = latent_model if kind == "latent" else model
+    config = config.replace(attn_impl="auto", max_seq_len=64)
+    rng = np.random.RandomState(11)
+    draw = lambda n: [int(t) for t in rng.randint(1, config.vocab_size, size=n)]
+    cb = ContinuousBatcher(
+        params, config, n_slots=3, block_size=8, decode_chunk=4,
+        prefill_budget=32)
+    toks = {}
+
+    def pump(until):
+        for _ in range(200):
+            if until():
+                return
+            for ev in cb.step():
+                toks.setdefault(ev[0], []).append(ev[1])
+        raise AssertionError("did not get there")
+
+    holder = cb.submit(draw(8), max_new_tokens=54)   # idle server: an insert
+    pump(lambda: len(toks.get(holder, ())) >= 8)
+    doc = draw(33)      # 40 padded + 4: 6 blocks held, two 4-block chunks
+    first = cb.submit(doc, max_new_tokens=4)
+    pump(lambda: len(toks.get(first, ())) == 4)
+    asked = doc + draw(8)            # hits 4 blocks; 9 tokens in one chunk
+    again = cb.submit(asked, max_new_tokens=4)
+    pump(lambda: len(toks.get(again, ())) == 4)
+    assert len(toks[holder]) < 54    # a row decoded throughout
+    stats = cb.stats()
+    assert stats["fused_admissions_total"] == 2
+    assert cb.prefix_requests_hit == 1
+    assert stats["prefill_chunks_total"] == 3
+    writes = [d["prefill_write"] for d in cb.obs.dispatches if "prefill_write" in d]
+    assert writes == [
+        {"pairs": 8}, {"blocks": 4}, {"blocks": 2}, {"blocks": 2},
+    ]
+    assert stats["prefill_blocks_written_total"] == 8
+    assert stats["prefill_pairs_written_total"] == 8
+    for name in ("prefill_blocks_written_total", "prefill_pairs_written_total"):
+        assert metric_meta(name)[0] == "counter"
+    for rid, prompt in ((first, doc), (again, asked)):
+        alone = generate(
+            params, jnp.asarray([prompt]), jnp.ones((1, len(prompt)), bool),
+            jax.random.PRNGKey(0), config=config,
+            gen_config=GenerationConfig(max_new_tokens=4, temperature=0.0))
+        assert toks[rid] == [int(t) for t in np.asarray(alone)[0, len(prompt):]]

@@ -384,27 +384,32 @@ def test_fused_chunk_no_full_pool_copies_compiled():
     ONE row's view, never the pool, and the decode scan's carry must
     not materialize a pool copy at the scan boundary.  Same HLO-text
     assertion as its siblings, against the live mid-prefill args the
-    batcher actually dispatches."""
+    batcher actually dispatches.  The chunk is 512 tokens over eight
+    64-token blocks: over ``_POOL_WRITE_UNROLL_MAX`` pairs, where the pair
+    form took the batched scatter and its pool-sized relayout copies (the
+    64-pair chunk this test had until PR 31 took the chain and never saw
+    them); it lands by whole blocks (``_land_chunk``)."""
     from jax_llama_tpu import get_config, init_params
     from jax_llama_tpu.serving import ContinuousBatcher
 
     cfg = get_config(
         "tiny", dim=256, n_layers=4, n_heads=4, n_kv_heads=2,
-        vocab_size=512, max_seq_len=256, param_dtype="bfloat16",
+        vocab_size=512, max_seq_len=1024, param_dtype="bfloat16",
     )
     params = init_params(jax.random.PRNGKey(0), cfg)
-    cb = ContinuousBatcher(params, cfg, n_slots=4, max_len=256,
-                           block_size=32, decode_chunk=4,
-                           prefill_budget=64)
+    cb = ContinuousBatcher(params, cfg, n_slots=4, max_len=1024,
+                           block_size=64, decode_chunk=4,
+                           prefill_budget=512)
     rng = np.random.RandomState(5)
     cb.submit(list(rng.randint(1, cfg.vocab_size, 100)),
               max_new_tokens=16)
     cb.step()  # cold classic admission
     cb.step()
-    cb.submit(list(rng.randint(1, cfg.vocab_size, 100)),
+    cb.submit(list(rng.randint(1, cfg.vocab_size, 600)),
               max_new_tokens=16)
-    cb.step()  # fused prefill starts (128-token suffix > one 64 chunk)
+    cb.step()  # fused prefill starts (600-token suffix > one 512 chunk)
     assert cb._pf is not None  # the fused program has concrete args
+    assert cb._pf.chunk == 512
 
     from jax_llama_tpu import serving as srv
 
