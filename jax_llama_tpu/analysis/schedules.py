@@ -455,6 +455,7 @@ def _make_batcher_stub():
     s._pf = None
     s.prefill_chunks_total = 0
     s.moe_totals = {}
+    s.attn_step_totals = {}
     s.prefill_ctx_slots_attended_total = 0
     s.prefill_ctx_slots_view_total = 0
     s.prefill_blocks_written_total = 0

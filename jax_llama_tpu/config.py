@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -48,6 +48,10 @@ class LLaMAConfig:
     n_layers: int = 32
     n_heads: int = 32
     n_kv_heads: Optional[int] = None      # None -> n_heads (no GQA)
+    head_size: Optional[int] = None       # None -> dim // n_heads; a head
+                                          #   size of its own beside the
+                                          #   hidden size (q/k/v project
+                                          #   D -> H*head_size, o back)
     intermediate_size: Optional[int] = None  # None -> swiglu_hidden_size(...)
     multiple_of: int = 256
     ffn_dim_multiplier: Optional[float] = None
@@ -109,14 +113,31 @@ class LLaMAConfig:
     routed_scaling_factor: float = 1.0
     first_k_dense: int = 0                # leading layers with a dense FFN
 
+    # --- window and full attention layers in one stack.  None: every layer
+    # attends its whole causal context.  A tuple of n_layers flags selects
+    # the block of models/afmoe.py (afmoe-style): a True layer sees key j
+    # from query i iff 0 <= i - j < sliding_window and carries rope; a
+    # False one is a full layer, which carries no position at all.  Gated
+    # attention output, per-head q/k norms, a norm on both sides of each
+    # sub-block, the embedding scaled by sqrt(dim), and the routed experts
+    # of the fields above behind `first_k_dense` layers.
+    window_layers: Optional[Tuple[bool, ...]] = None
+    sliding_window: int = 0
+
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
 
     @property
     def head_dim(self) -> int:
+        if self.head_size is not None:
+            return self.head_size
         assert self.dim % self.n_heads == 0
         return self.dim // self.n_heads
+
+    @property
+    def windowed_attention(self) -> bool:
+        return self.window_layers is not None
 
     @property
     def latent_attention(self) -> bool:
@@ -173,8 +194,12 @@ class LLaMAConfig:
         return dataclasses.replace(self, **kw)
 
     def validate(self) -> None:
-        assert self.dim % self.n_heads == 0, "n_heads must divide dim"
-        if self.latent_attention:
+        assert self.head_size is not None or self.dim % self.n_heads == 0, (
+            "n_heads must divide dim (or head_size be given)"
+        )
+        if self.windowed_attention:
+            self._validate_windowed()
+        elif self.latent_attention:
             self._validate_latent()
         assert self.n_heads % self.kv_heads == 0, (
             "n_heads must be a multiple of n_kv_heads (GQA group size)"
@@ -198,17 +223,26 @@ class LLaMAConfig:
                 "expected 'auto' or 'int8'"
             )
 
-    def _validate_latent(self) -> None:
-        """The latent-attention block: what it needs, and what it does not
-        get yet — refused by name here, never served wrongly."""
-        for name in ("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim",
-                     "n_routed_experts", "n_experts_per_tok",
+    @property
+    def expert_block(self) -> Optional[str]:
+        """The block beside the dense one a configuration selects, as error
+        messages name it; None for the dense block.  Both have routed
+        experts behind a leading run of dense layers."""
+        if self.latent_attention:
+            return "latent attention"
+        return "window attention layers" if self.windowed_attention else None
+
+    def _validate_experts(self, needs=()) -> None:
+        """What both expert blocks need, and what neither gets yet — refused
+        by name here, never served wrongly."""
+        block = self.expert_block
+        for name in (*needs, "n_routed_experts", "n_experts_per_tok",
                      "moe_intermediate_size"):
             if getattr(self, name) <= 0:
-                raise ValueError(f"latent attention needs {name} > 0")
+                raise ValueError(f"{block} needs {name} > 0")
         if not 0 < self.first_k_dense < self.n_layers:
             raise ValueError(
-                "the latent-attention block has a leading run of dense "
+                f"the block with {block} has a leading run of dense "
                 f"layers then expert layers; first_k_dense={self.first_k_dense} "
                 f"of n_layers={self.n_layers} leaves one of them empty"
             )
@@ -216,18 +250,37 @@ class LLaMAConfig:
             raise ValueError("n_experts_per_tok exceeds n_routed_experts")
         if self.kv_cache_dtype == "int8":
             raise ValueError(
-                "kv_cache_dtype='int8' is not supported with latent "
-                "attention: the latent row has no per-head scale"
+                f"kv_cache_dtype='int8' is not supported with {block}: the "
+                "latent row has no per-head scale, the int8 kernels no window"
             )
         if self.attn_impl == "ring":
             raise ValueError(
-                "attn_impl='ring' is not supported with latent attention"
+                f"attn_impl='ring' is not supported with {block}"
             )
         if self.tie_word_embeddings or self.use_scaled_rope:
             raise ValueError(
                 "tie_word_embeddings / use_scaled_rope are not supported "
-                "with latent attention"
+                f"with {block}"
             )
+
+    def _validate_windowed(self) -> None:
+        """The window-and-full-attention block (see `_validate_experts`)."""
+        if self.latent_attention:
+            raise ValueError("window_layers and latent attention are two blocks")
+        if len(self.window_layers) != self.n_layers:
+            raise ValueError(
+                f"window_layers needs n_layers={self.n_layers} flags, got "
+                f"{self.window_layers!r}")
+        if any(self.window_layers) and self.sliding_window <= 0:
+            raise ValueError(
+                "the window layers need a sliding_window > 0, got "
+                f"{self.sliding_window!r}")
+        self._validate_experts()
+
+    def _validate_latent(self) -> None:
+        """The latent-attention block (see `_validate_experts`)."""
+        self._validate_experts(
+            needs=("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
 
 
 # ---------------------------------------------------------------------------
@@ -271,17 +324,73 @@ _PUBLISHED_LATENT_FIXED = {
 }
 
 
+# the afmoe block (window and full attention layers, routed + shared experts)
+_PUBLISHED_WINDOWED = {
+    "num_experts": "n_routed_experts",
+    "num_experts_per_tok": "n_experts_per_tok",
+    "num_shared_experts": "n_shared_experts",
+    "moe_intermediate_size": "moe_intermediate_size",
+    "route_scale": "routed_scaling_factor",
+    "num_dense_layers": "first_k_dense",
+}
+# its keys with ONE accepted value: the block as the program computes it
+_PUBLISHED_WINDOWED_FIXED = {
+    "model_type": "afmoe", "hidden_act": "silu", "score_func": "sigmoid",
+    "route_norm": True, "mup_enabled": True, "rope_scaling": None,
+    "n_group": 1, "topk_group": 1, "num_expert_groups": 1,
+    "num_limited_groups": 1,
+}
+# training and implementation switches: accepted at any value, unused
+_PUBLISHED_WINDOWED_UNUSED = ("load_balance_coeff", "use_grouped_mm")
+_LAYER_TYPES = ("sliding_attention", "full_attention")
+
+
+def _window_layers(raw) -> dict:
+    """`layer_types` + `sliding_window` -> which layers are window layers,
+    and the window's length."""
+    types, window = raw["layer_types"], raw.get("sliding_window")
+    if not isinstance(window, int) or isinstance(window, bool) or window <= 0:
+        raise ValueError(
+            f"sliding_window: {window!r} beside layer_types; the window "
+            "layers need a length > 0")
+    if not isinstance(types, (list, tuple)) or len(types) != raw["num_hidden_layers"]:
+        raise ValueError(
+            f"layer_types: needs num_hidden_layers={raw['num_hidden_layers']} "
+            f"entries, got {len(types) if isinstance(types, (list, tuple)) else types!r}")
+    other = [t for t in types if t not in _LAYER_TYPES]
+    if other:
+        raise ValueError(
+            f"layer_types: {other[0]!r} is not in the program; a layer is "
+            f"one of {_LAYER_TYPES}")
+    every = raw.get("global_attn_every_n_layers")
+    if every is not None and any(
+            (t == "full_attention") != ((i + 1) % every == 0)
+            for i, t in enumerate(types)):
+        raise ValueError(
+            f"global_attn_every_n_layers: {every!r} is not what layer_types says")
+    return {"window_layers": tuple(t == "sliding_attention" for t in types),
+            "sliding_window": window}
+
+
 def from_published(raw, *, max_seq_len: int, attn_impl: str) -> LLaMAConfig:
     """The `LLaMAConfig` of a model's published `config.json` keys `raw`, or
     `ValueError` naming the key that stands in the way.
     `max_position_embeddings` is accepted and unused: a server serves at its
-    own `max_seq_len`.  A file with `kv_lora_rank` is the deepseek_v3 block;
-    every other file is the dense block."""
+    own `max_seq_len`.  A file with `kv_lora_rank` is the deepseek_v3 block,
+    one with `layer_types` the afmoe block; every other file is the dense
+    block."""
     latent = "kv_lora_rank" in raw
-    fields = dict(_PUBLISHED, **(_PUBLISHED_LATENT if latent else {}))
+    windowed = "layer_types" in raw
+    if latent and windowed:
+        raise ValueError("layer_types beside kv_lora_rank: two blocks in one file")
+    fields = dict(_PUBLISHED, **(_PUBLISHED_LATENT if latent else {}),
+                  **(_PUBLISHED_WINDOWED if windowed else {}))
     known = set(fields) | set(_PUBLISHED_OTHER)
     if latent:
         known |= set(_PUBLISHED_LATENT_FIXED) | {"qk_head_dim"}
+    if windowed:
+        known |= (set(_PUBLISHED_WINDOWED_FIXED) | set(_PUBLISHED_WINDOWED_UNUSED)
+                  | {"layer_types", "global_attn_every_n_layers"})
     unknown = sorted(set(raw) - known)
     if unknown:
         raise ValueError(f"the program understands no published key {', '.join(map(repr, unknown))}")
@@ -290,12 +399,29 @@ def from_published(raw, *, max_seq_len: int, attn_impl: str) -> LLaMAConfig:
         raise ValueError(
             f"published key {missing[0]!r} is missing" + (
                 " (a file with 'kv_lora_rank' is the latent-attention "
-                "block, which needs it)" if latent else ""))
-    if raw.get("sliding_window") is not None:
-        raise ValueError("sliding_window: sliding-window attention is not in the program")
+                "block, which needs it)" if latent else
+                " (a file with 'layer_types' is the window-attention "
+                "block, which needs it)" if windowed else ""))
+    if raw.get("sliding_window") is not None and not windowed:
+        raise ValueError(
+            "sliding_window: a window without layer_types (which layers it "
+            "holds for) is not in the program")
     heads, hidden = raw["num_attention_heads"], raw["hidden_size"]
-    if raw.get("head_dim", hidden // heads) * heads != hidden:
-        raise ValueError("head_dim * heads != hidden_size; the program has no separate head size")
+    if "head_dim" not in raw and hidden % heads:
+        raise ValueError(
+            "head_dim * heads != hidden_size: the file gives no head_dim and "
+            "num_attention_heads does not divide hidden_size")
+    if not windowed and raw.get("head_dim", hidden // heads) * heads != hidden:
+        # `LLaMAConfig.head_size` would run it; a published file of the
+        # dense or the latent block that says so is more likely a slip than
+        # a model, and the benchmark's own tests hold this map to refusing it.
+        raise ValueError(
+            "head_dim * heads != hidden_size; only a file with layer_types "
+            "may give a head size of its own")
+    head_dim = raw.get("head_dim")
+    if isinstance(head_dim, bool) or not isinstance(head_dim, (int, type(None))) or (
+            head_dim is not None and (head_dim <= 0 or head_dim % 2)):
+        raise ValueError(f"head_dim: {head_dim!r} is not an even size > 0")
     if raw["torch_dtype"] not in _PUBLISHED_DTYPES:
         raise ValueError(f"torch_dtype {raw['torch_dtype']!r} is not one the program serves in")
     if latent:
@@ -310,8 +436,19 @@ def from_published(raw, *, max_seq_len: int, attn_impl: str) -> LLaMAConfig:
             raise ValueError("qk_head_dim != qk_nope_head_dim + qk_rope_head_dim")
         if raw["num_key_value_heads"] != heads:
             raise ValueError("num_key_value_heads != num_attention_heads under latent attention")
+    extra = {}
+    if windowed:
+        for key, only in _PUBLISHED_WINDOWED_FIXED.items():
+            if key in raw and raw[key] != only:
+                raise ValueError(
+                    f"{key}: {raw[key]!r} is not in the program; its "
+                    f"window-attention block computes {only!r} only"
+                )
+        extra.update(_window_layers(raw))
+    if head_dim is not None and head_dim * heads != hidden:
+        extra["head_size"] = head_dim
     return LLaMAConfig(
-        **{ours: raw[theirs] for theirs, ours in fields.items()},
+        **{ours: raw[theirs] for theirs, ours in fields.items()}, **extra,
         dtype=raw["torch_dtype"], param_dtype=raw["torch_dtype"],
         max_seq_len=max_seq_len, attn_impl=attn_impl,
     )

@@ -521,9 +521,18 @@ def quantize_kv(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return q, scale
 
 
-def moe_stats_zero() -> jnp.ndarray:
-    """Empty routing counts (``ops.moe.STATS``) of a cache or a pool."""
-    return jnp.zeros((_MOE_N_STATS,), jnp.int32)
+def cache_stats_zero(config: LLaMAConfig) -> Optional[jnp.ndarray]:
+    """Empty counters of a cache or a pool: the routing counts
+    (``ops.moe.STATS``) of a block with routed experts, then the window
+    block's attention step counts (``afmoe.ATTN_STATS``); None for the
+    dense block, which counts nothing on the device."""
+    if config.windowed_attention:
+        from .afmoe import N_STATS
+
+        return jnp.zeros((N_STATS,), jnp.int32)
+    if config.latent_attention:
+        return jnp.zeros((_MOE_N_STATS,), jnp.int32)
+    return None
 
 
 def init_cache(
@@ -546,7 +555,7 @@ def init_cache(
     return KVCache(
         k=jnp.zeros(shape, dtype=dtype),
         v=None if latent else jnp.zeros(shape, dtype=dtype),
-        stats=moe_stats_zero() if latent else None,
+        stats=cache_stats_zero(config),
         pos=jnp.full((batch, max_len), -1, dtype=jnp.int32),
         index=jnp.zeros((), dtype=jnp.int32),
         k_scale=jnp.zeros(shape[:-1], jnp.float32) if int8_kv else None,
@@ -567,6 +576,10 @@ def init_params(rng: jax.Array, config: LLaMAConfig) -> Params:
         from . import mla_moe
 
         return mla_moe.init_params(rng, config)
+    if config.windowed_attention:
+        from . import afmoe
+
+        return afmoe.init_params(rng, config)
     D, H, KVH, hd, F, V, L = (
         config.dim, config.n_heads, config.kv_heads, config.head_dim,
         config.ffn_dim, config.vocab_size, config.n_layers,
@@ -1079,12 +1092,14 @@ def forward(
       flag is set, a third ``AuxOutput`` element is appended:
       (logits, cache, aux).
     """
-    if config.latent_attention:
+    if config.latent_attention or config.windowed_attention:
         # The block follows from the configuration: latent attention over
-        # a latent cache, routed experts behind leading dense layers.
-        from . import mla_moe
+        # a latent cache, or window and full attention layers over the
+        # K/V cache; routed experts behind leading dense layers.
+        from . import afmoe, mla_moe
 
-        return mla_moe.forward(
+        block = mla_moe if config.latent_attention else afmoe
+        return block.forward(
             params, tokens, positions, config, cache=cache,
             attn_mask=attn_mask, compute_logits=compute_logits,
             dropout_rng=dropout_rng,

@@ -433,6 +433,15 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "moe_max_load_total": _reg(
         "counter", "Largest per-expert token count, summed over "
                    "expert-layer calls"),
+    # -- window and full attention layers (models/afmoe.py; zero without) ----
+    "attn_window_kv_steps_total": _reg(
+        "counter", "Live grid steps of the paged decode kernel in window "
+                   "attention layers, summed over rows, layers and "
+                   "iterations"),
+    "attn_full_kv_steps_total": _reg(
+        "counter", "Live grid steps of the paged decode kernel in full "
+                   "attention layers, summed over rows, layers and "
+                   "iterations"),
     "decode_stall_ms_total": _reg(
         "counter", "Wall time classic whole-prompt admissions stalled "
                    "decoding rows (ms)"),

@@ -47,6 +47,7 @@ def attention_bias(
     q_positions: jnp.ndarray,
     kv_positions: jnp.ndarray,
     kv_valid: Optional[jnp.ndarray] = None,
+    window=None,
 ) -> jnp.ndarray:
     """Additive fp32 attention bias combining causality and padding.
 
@@ -55,10 +56,18 @@ def attention_bias(
       kv_positions: [B, S] absolute positions of the key/value slots.
       kv_valid: optional [B, S] bool — False for padding / unwritten cache
         slots.
+      window: optional int32 scalar (a value, e.g. a layer's own in a layer
+        scan): a query at position i sees the keys at i - window + 1 .. i,
+        itself and the window - 1 before it.  None: the whole causal past.
     Returns:
       [B, 1, T, S] bias, 0 where attendable, finfo.min where masked.
     """
     allowed = kv_positions[:, None, :] <= q_positions[:, :, None]  # [B, T, S]
+    if window is not None:
+        allowed = jnp.logical_and(
+            allowed,
+            q_positions[:, :, None] - kv_positions[:, None, :] < window,
+        )
     if kv_valid is not None:
         allowed = jnp.logical_and(allowed, kv_valid[:, None, :])
     bias = jnp.where(allowed, 0.0, NEG_INF).astype(jnp.float32)

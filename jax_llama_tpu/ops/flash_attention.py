@@ -179,7 +179,7 @@ def _tri_gate(qp, kp, bq_s, bk_s, quantized=False):
 def _flash_tri_tile_update(
     q_ref, k_ref, v_ref, seed_ref,
     m_ref, l_ref, acc_ref, qp, kp, bi, hi, qi, ki,
-    *, scale, dropout_rate,
+    *, scale, dropout_rate, window=None,
 ):
     """Diagonal-crossing tile update with RAGGED sub-tile dots: k sub-tile
     ``i`` computes only query rows ``[i·rq:]`` — ``_KSUB`` shrinking dots
@@ -201,6 +201,10 @@ def _flash_tri_tile_update(
     ksub = bk // nsub
     rq = bq // nsub
     allowed = kp <= qp  # [bq, bk]
+    if window is not None:
+        # The window masks rows the ragged body still computes; what it
+        # skips is skipped on causal grounds alone.
+        allowed = allowed & (kp > qp - window)
     m_prev = m_ref[:, :1]  # [bq, 1]
 
     s_parts = []  # s_i: [bq - i*rq, ksub]
@@ -275,7 +279,13 @@ def _flash_kernel(
     with_lse: bool,
     quantized: bool = False,
     dropout_rate: float = 0.0,
+    windowed: bool = False,
 ):
+    if windowed:
+        # [B * nq] int32: the first kv block of this q block's sweep, and
+        # [1] int32: the window (a query sees its own position and the
+        # window - 1 before it).  Grid step ki visits block start + ki.
+        kv_start_ref, window_ref, *args = args
     if dropout_rate > 0.0:
         seed_ref, *args = args  # [2] uint32 scalar-prefetch (64-bit seed)
     else:
@@ -313,9 +323,13 @@ def _flash_kernel(
     # Grid-level dead-block skip: past this q block's kv bound the index
     # maps clamp to the boundary block (already-fetched — no new DMA) and
     # the tile must not be processed again.
-    in_bound = ki < kv_bound_ref[
-        pl.program_id(0) * pl.num_programs(2) + pl.program_id(2)
-    ]
+    row_block = pl.program_id(0) * pl.num_programs(2) + pl.program_id(2)
+    if windowed:
+        window = window_ref[0]
+        in_bound = kv_start_ref[row_block] + ki < kv_bound_ref[row_block]
+    else:
+        window = None
+        in_bound = ki < kv_bound_ref[row_block]
     # Block-level causal skip: if the smallest kv position in this block
     # exceeds every query position, no (q, kv) pair is attendable and
     # both dots + the softmax update can be skipped — for standard causal
@@ -359,7 +373,7 @@ def _flash_kernel(
             _flash_tri_tile_update(
                 q_ref, k_ref, v_ref, seed_ref,
                 m_ref, l_ref, acc_ref, qp, kp, bi, hi, qi, ki,
-                scale=scale, dropout_rate=dropout_rate,
+                scale=scale, dropout_rate=dropout_rate, window=window,
             )
     else:
         full_live = block_live
@@ -414,6 +428,8 @@ def _flash_kernel(
         # 1-row position plane hit unsupported Mosaic layouts), sliced
         # per sub-tile below.
         allowed = kp <= qp  # [bq, bk]
+        if windowed:
+            allowed = allowed & (kp > qp - window)
         s_parts = []
         for i in range(nsub):
             cols = slice(i * ksub, (i + 1) * ksub)
@@ -541,6 +557,7 @@ def flash_attention(
     interpret: Optional[bool] = None,
     dropout_rate: float = 0.0,
     dropout_seed: Optional[jnp.ndarray] = None,
+    window: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Blockwise attention; drop-in for ``ops.attention.sdpa`` + bias.
 
@@ -572,6 +589,14 @@ def flash_attention(
         are widened with a zero high word); required when
         dropout_rate > 0.  Derive per call site, e.g. via
         ``jax.random.bits(key, (2,), "uint32")``.
+      window: optional int32 scalar, a VALUE (a layer's own inside a layer
+        scan): a query at position i sees the keys at i - window + 1 .. i.
+        A window layer does a window's work: per q block the k sweep
+        starts at the first kv block holding a slot inside some query's
+        window (``kv_start``, the lower twin of ``kv_bound``), so blocks
+        wholly before it are neither fetched nor computed.  Inference
+        only (no VJP, no dropout).  None: the program without a window,
+        unchanged.
     Returns:
       [B, T, H, d] in q.dtype.
     """
@@ -579,6 +604,8 @@ def flash_attention(
     H, KVH = q.shape[2], k.shape[2]
     assert H % KVH == 0, (H, KVH)
     group = H // KVH
+    if window is not None and dropout_rate > 0.0:
+        raise ValueError("the window form of flash_attention is inference-only")
     if not 0.0 <= dropout_rate < 1.0:
         # Validate BEFORE the >0 branch: a negative rate must raise, not
         # silently train without dropout.
@@ -603,14 +630,25 @@ def flash_attention(
             q.reshape(B, T, KVH, group, -1), 3, 1
         ).reshape(B, group * T, KVH, -1)
         pos_p = jnp.tile(q_pos, (1, group))
-        out = _flash(
-            qp, k, v, pos_p, kv_pos, seed, block_q, block_k, interpret,
-            dropout_rate,
-        )
+        if window is not None:
+            out = _flash_forward(
+                qp, k, v, pos_p, kv_pos, block_q, block_k, interpret,
+                window=window,
+            )
+        else:
+            out = _flash(
+                qp, k, v, pos_p, kv_pos, seed, block_q, block_k, interpret,
+                dropout_rate,
+            )
         out = jnp.moveaxis(
             out.reshape(B, group, T, KVH, -1), 1, 3
         ).reshape(B, T, H, -1)
         return out
+    if window is not None:
+        return _flash_forward(
+            q, k, v, q_pos, kv_pos, block_q, block_k, interpret,
+            window=window,
+        )
     return _flash(
         q, k, v, q_pos, kv_pos, seed, block_q, block_k, interpret,
         dropout_rate,
@@ -851,9 +889,44 @@ def _clamp_blocks(T, S, block_q, block_k, interpret):
     return block_q, block_k
 
 
+def _window_bounds(q_pos_p, kv_pos_p, T, block_q, block_k, window):
+    """(kv_start, kv_bound) [B, nq] int32 of the window form: per q block
+    the kv blocks [start, bound) are those holding a live slot that some
+    query of the block may attend — not after its last query (the causal
+    side, ``kv_bound`` alone without a window) and not wholly before its
+    first query's window.  ``q_pos_p`` [B, Tp] / ``kv_pos_p`` [B, Sp] are
+    the padded planes (dead kv slots at +INT_MAX; query rows past ``T``
+    are padding and bound nothing).  A q block with no such block gets
+    ``start == bound`` and sweeps nothing."""
+    B, Tp = q_pos_p.shape
+    nq, nk = Tp // block_q, kv_pos_p.shape[1] // block_k
+    imax = jnp.iinfo(jnp.int32).max
+    real = jnp.arange(Tp, dtype=jnp.int32)[None, :] < T
+    qmax = jnp.max(
+        jnp.where(real, q_pos_p, -1).reshape(B, nq, block_q), axis=2)
+    qmin = jnp.min(
+        jnp.where(real, q_pos_p, imax).reshape(B, nq, block_q), axis=2)
+    kmin = jnp.min(kv_pos_p.reshape(B, nk, block_k), axis=2)
+    kmax = jnp.max(
+        jnp.where(kv_pos_p == imax, -1, kv_pos_p).reshape(B, nk, block_k),
+        axis=2,
+    )
+    # qmin may be imax (an all-padding q block): subtract in a way that
+    # cannot wrap, window >= 1.
+    lo = jnp.maximum(qmin, window - 1) - (window - 1)  # first seen position
+    live = (kmin[:, None, :] <= qmax[:, :, None]) & (
+        kmax[:, None, :] >= lo[:, :, None]
+    )  # [B, nq, nk]
+    idx = jnp.arange(nk, dtype=jnp.int32)[None, None, :]
+    bound = 1 + jnp.max(jnp.where(live, idx, -1), axis=2)
+    start = jnp.min(jnp.where(live, idx, nk), axis=2)
+    return jnp.minimum(start, bound).astype(jnp.int32), bound.astype(jnp.int32)
+
+
 def _flash_forward(
     q, k, v, q_pos, kv_pos, block_q, block_k, interpret, need_lse=False,
     k_scale=None, v_scale=None, dropout_rate=0.0, dropout_seed=None,
+    window=None,
 ):
     B, T, H, d = q.shape
     S, KVH = k.shape[1], k.shape[2]
@@ -863,6 +936,10 @@ def _flash_forward(
     with_dropout = dropout_rate > 0.0
     assert not (with_dropout and quantized), (
         "dropout is training-only; the int8-KV path is inference-only"
+    )
+    windowed = window is not None
+    assert not (windowed and (quantized or with_dropout)), (
+        "the window form is bf16/f32 inference only"
     )
     # log2(e) folded into the score scale: the kernel's online softmax
     # runs in base 2 (bare VPU exp2 per element, no hidden wide multiply).
@@ -899,20 +976,29 @@ def _flash_forward(
     # and the kernel skips their compute via the prefetched bound.  For
     # causal prefill this removes the dead upper-triangle K/V traffic that
     # the in-kernel block_live check alone still paid bandwidth for.
-    qmax = jnp.max(q_pos_p.reshape(B, nq, block_q), axis=2)
-    kmin = jnp.min(kv_pos_p.reshape(B, nk, block_k), axis=2)
-    attendable = kmin[:, None, :] <= qmax[:, :, None]  # [B, nq, nk]
-    kv_bound = 1 + jnp.max(
-        jnp.where(
-            attendable, jnp.arange(nk, dtype=jnp.int32)[None, None, :], -1
-        ),
-        axis=2,
-    )  # [B, nq], values in [0, nk]
+    if windowed:
+        kv_start, kv_bound = _window_bounds(
+            q_pos_p, kv_pos_p, T, block_q, block_k, window
+        )
+    else:
+        qmax = jnp.max(q_pos_p.reshape(B, nq, block_q), axis=2)
+        kmin = jnp.min(kv_pos_p.reshape(B, nk, block_k), axis=2)
+        attendable = kmin[:, None, :] <= qmax[:, :, None]  # [B, nq, nk]
+        kv_bound = 1 + jnp.max(
+            jnp.where(
+                attendable,
+                jnp.arange(nk, dtype=jnp.int32)[None, None, :], -1,
+            ),
+            axis=2,
+        )  # [B, nq], values in [0, nk]
     kv_bound_flat = kv_bound.reshape(B * nq)
 
     # Index maps take trailing *_ so the same lambdas serve both prefetch
     # layouts (kv_bound alone, or kv_bound + dropout seed).
-    def _clamp_ki(b, qi, ki, bound):
+    def _clamp_ki(b, qi, ki, bound, *pre):
+        if windowed:
+            # grid step ki visits block start + ki of the q block's sweep
+            ki = ki + pre[0][b * nq + qi]
         return jnp.minimum(ki, jnp.maximum(bound[b * nq + qi] - 1, 0))
 
     def q_row(b, h, qi, ki, bound, *_):
@@ -937,20 +1023,20 @@ def _flash_forward(
         pl.BlockSpec(
             (1, 1, block_k),
             lambda b, h, qi, ki, bound, *_: (
-                b, 0, _clamp_ki(b, qi, ki, bound)
+                b, 0, _clamp_ki(b, qi, ki, bound, *_)
             ),
         ),
         pl.BlockSpec((1, 1, block_q, d), q_row),
         pl.BlockSpec(
             (1, 1, block_k, d),
             lambda b, h, qi, ki, bound, *_: (
-                b, h // group, _clamp_ki(b, qi, ki, bound), 0
+                b, h // group, _clamp_ki(b, qi, ki, bound, *_), 0
             ),
         ),
         pl.BlockSpec(
             (1, 1, block_k, d),
             lambda b, h, qi, ki, bound, *_: (
-                b, h // group, _clamp_ki(b, qi, ki, bound), 0
+                b, h // group, _clamp_ki(b, qi, ki, bound, *_), 0
             ),
         ),
     ]
@@ -965,18 +1051,24 @@ def _flash_forward(
         scale_spec = pl.BlockSpec(
             (1, 1, 1, block_k),
             lambda b, h, qi, ki, bound, *_: (
-                b, h // group, 0, _clamp_ki(b, qi, ki, bound)
+                b, h // group, 0, _clamp_ki(b, qi, ki, bound, *_)
             ),
         )
         in_specs += [scale_spec, scale_spec]
         operands += [_scale_plane(k_scale), _scale_plane(v_scale)]
     prefetch = [kv_bound_flat]
+    if windowed:
+        prefetch += [
+            kv_start.reshape(B * nq),
+            jnp.asarray(window, jnp.int32).reshape(1),
+        ]
     if with_dropout:
         prefetch.append(_normalize_seed(dropout_seed))
     out = pl.pallas_call(
         functools.partial(
             _flash_kernel, scale=scale, with_lse=need_lse,
             quantized=quantized, dropout_rate=dropout_rate,
+            windowed=windowed,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),

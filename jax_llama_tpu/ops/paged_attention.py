@@ -135,8 +135,9 @@ def _paged_kernel(
     qpos_ref,   # [B] int32 scalar-prefetch: FIRST token's query position
     #             (-1 = inactive row; token t sits at qpos + t)
     layer_ref,  # [1] int32 scalar-prefetch: pool layer this call reads
-    q_ref,      # [1, KVH, TG8, d] — sublane row r = t*group + g
-    *rest,      # P k refs, P v refs [1, KVH, 1, BLK, d] (int8 when
+    *rest,      # [window_ref [1] int32 scalar-prefetch when windowed;]
+    #             q_ref [1, KVH, TG8, d] — sublane row r = t*group + g;
+    #             P k refs, P v refs [1, KVH, 1, BLK, d] (int8 when
     #             quantized); pos ref [1, P, BLK] int32 (-1 = masked slot);
     #             when quantized P k-scale and P v-scale refs
     #             [1, KVH, 1, 1, BLK] fp32; o_ref; lse_ref; scratch m, l, acc
@@ -149,9 +150,15 @@ def _paged_kernel(
     group: int,
     quantized: bool = False,
     v_width: int = 0,
+    windowed: bool = False,
 ):
     """Online-softmax sweep of one row's pool blocks, ``n_entries`` table
     entries a grid step.
+
+    ``windowed``: a query at position p sees the slots at p - window + 1 ..
+    p only (``window`` a scalar-prefetched value); the grid then holds the
+    steps ``_fetch_plan`` found a slot inside the window in, and masked
+    probabilities are zeroed explicitly as for T > 1.
 
     ``v_width`` > 0 is the latent-attention row: there are no v refs, and a
     slot's value is the first ``v_width`` columns of its key row (the normed
@@ -175,6 +182,9 @@ def _paged_kernel(
     late token but fully masked for an early one.
     """
     P = n_entries
+    if windowed:
+        window_ref, *rest = rest
+    q_ref, *rest = rest
     if v_width:
         k_refs, v_refs, pos_ref, rest = rest[:P], None, rest[P], rest[P + 1:]
     else:
@@ -211,7 +221,10 @@ def _paged_kernel(
         for j in range(P):
             kp = pos_ref[0, j:j + 1, :]  # [1, BLK]; a dead entry's is all -1
             entry_ok.append(fetch_ref[step * P + j] >= 0)
-            allowed.append((kp >= 0) & (kp <= qp))  # [1 | TG8, BLK]
+            ok = (kp >= 0) & (kp <= qp)  # [1 | TG8, BLK]
+            if windowed:
+                ok = ok & (kp > qp - window_ref[0])
+            allowed.append(ok)
         # One grid step covers ALL KV heads of its blocks (the loops
         # unroll statically): measured ~1 µs of per-cell overhead made a
         # (B, KVH, MB) grid SLOWER than the gathered-view fallback.
@@ -248,7 +261,7 @@ def _paged_kernel(
             probs, pv = [], None
             for j in range(P):
                 p = jnp.exp(scores[j] - m_new)
-                if t_tokens > 1:
+                if t_tokens > 1 or windowed:
                     p = jnp.where(allowed[j], p, 0.0)
                 probs.append(p)
                 if quantized:
@@ -293,11 +306,18 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _fetch_plan(pool_pos, table, q_pos, t_tokens: int, n_entries: int):
+def _fetch_plan(pool_pos, table, q_pos, t_tokens: int, n_entries: int,
+                window=None):
     """The grid of one call: which (row, step) pairs run, in order, and
     what each fetches.  Depends on ``(pool_pos, table, q_pos)`` only, not
     on the layer, so XLA computes it once per decode iteration, outside
     the model's layer scan.
+
+    ``window`` (an int32 value; None: no window) is a second bound on the
+    same liveness: an entry is live only if its block also holds a slot at
+    or after the FIRST query's ``q_pos - window + 1``, so a window layer's
+    grid is the steps that overlap the window and no others — at 512-token
+    blocks and a 2048 window, 4-5 a row whatever the context.
 
     A table entry is live when its block holds a slot the row's LAST
     query may attend (so sentinel entries, the reserved-but-unwritten
@@ -338,6 +358,11 @@ def _fetch_plan(pool_pos, table, q_pos, t_tokens: int, n_entries: int):
     ).reshape(S, P)
     qp = jnp.repeat(q_pos, NS)[:, None]
     ok = (blk_min[table] <= qp + (t_tokens - 1)) & (qp >= 0)
+    if window is not None:
+        blk_max = jnp.concatenate(
+            [jnp.max(pool_pos, axis=1), jnp.full((1,), -1, jnp.int32)]
+        )  # [NB + 1] max position per block (-1: none live)
+        ok = ok & (blk_max[table] > qp - window)
     kpos = jnp.where(
         ok[:, :, None], pool_pos[jnp.minimum(table, NB - 1)], -1
     )
@@ -374,6 +399,29 @@ def _fetch_plan(pool_pos, table, q_pos, t_tokens: int, n_entries: int):
     )
 
 
+def fetch_plan(k_pool, pool_pos, table, q_pos, t_tokens: int = 1,
+               window=None):
+    """The step list ``paged_decode_attention`` would derive for these
+    operands (``_fetch_plan`` at the pool's own entries-per-step), as a
+    tuple it takes back through ``plan=``: a caller whose layers differ in
+    ``window`` derives one plan per kind OUTSIDE its layer scan and hands
+    each layer its kind's, so the derivation still happens once an
+    iteration and not once a layer.  ``plan[0]`` is the grid length: the
+    steps the call will run."""
+    KVH, _, BLK, d = k_pool.shape[-4:]
+    P = _blocks_per_step(BLK, table.shape[1], KVH, d, k_pool.dtype.itemsize)
+    return _fetch_plan(
+        pool_pos, table, q_pos.astype(jnp.int32), t_tokens, P, window)
+
+
+def plan_live_steps(plan) -> jnp.ndarray:
+    """Grid steps of ``plan`` that hold a slot some query attends (a row
+    with none still runs one, dead, to write its empty output)."""
+    n_steps, _, flags = plan[:3]
+    ran = jnp.arange(flags.shape[0], dtype=jnp.int32) < n_steps
+    return jnp.sum(ran & (flags & _LIVE != 0), dtype=jnp.int32)
+
+
 @functools.partial(
     jax.jit, static_argnames=("t_tokens", "interpret", "v_width", "scale")
 )
@@ -391,8 +439,16 @@ def paged_pool_attention(
     interpret: Optional[bool] = None,
     v_width: int = 0,
     scale: Optional[float] = None,
+    window: Optional[jnp.ndarray] = None,   # int32 value; None: no window
+    plan=None,                              # ``fetch_plan``'s, for `window`
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Attend each row's table-mapped pool blocks; no gather, pool read once.
+
+    ``window``: a query at position p sees the slots at p - window + 1 .. p,
+    and the grid holds only the steps with such a slot (``_fetch_plan``).
+    ``plan`` hands in the step list derived elsewhere for the same operands
+    and window (``fetch_plan``).  With neither, the program is the one
+    without a window, unchanged.
 
     ``v_width`` > 0 (latent attention): ``v_pool`` is None, a slot's value
     is the first ``v_width`` columns of its ``k_pool`` row, the output is
@@ -464,17 +520,19 @@ def paged_pool_attention(
     P = _blocks_per_step(BLK, MB, KVH, d, k_pool.dtype.itemsize)
     q_pos = q_pos.astype(jnp.int32)
     NS = -(-MB // P)
-    n_steps, fetch, flags, src, kpos = _fetch_plan(
-        pool_pos, table, q_pos, t_tokens, P
-    )
+    windowed = window is not None
+    assert not (windowed and quantized), "the int8 pool takes no window"
+    if plan is None:
+        plan = _fetch_plan(pool_pos, table, q_pos, t_tokens, P, window)
+    n_steps, fetch, flags, src, kpos = plan
 
     # Index maps; the scalar-prefetch refs follow the grid index in the
-    # kernel's order: fetch, flags, src, qpos, layer.
+    # kernel's order: fetch, flags, src, qpos, layer[, window].
     def row_map(t, fetch, flags, src, *_):
         return (src[t] // NS, 0, 0, 0)
 
     def kv_map(j):
-        def index(t, fetch, flags, src, qpos, layer):
+        def index(t, fetch, flags, src, qpos, layer, *_):
             f = fetch[t * P + j]
             return (layer[0], 0, jnp.where(f < 0, -1 - f, f), 0, 0)
         return index
@@ -511,10 +569,10 @@ def paged_pool_attention(
             _paged_kernel, scale=scale, n_entries=P, row_steps=NS, kvh=KVH,
             tg8=TG8,
             t_tokens=t_tokens, group=group, quantized=quantized,
-            v_width=v_width,
+            v_width=v_width, windowed=windowed,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=5 + windowed,
             grid=(n_steps,),
             in_specs=in_specs,
             out_specs=(
@@ -542,7 +600,9 @@ def paged_pool_attention(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
-    )(fetch, flags, src, q_pos, layer_arr, *operands)
+    )(fetch, flags, src, q_pos, layer_arr,
+      *((jnp.asarray(window, jnp.int32).reshape(1),) if windowed else ()),
+      *operands)
     return out[:, :, :TG, :], lse[:, :, :TG, 0]
 
 
@@ -561,9 +621,15 @@ def paged_decode_attention(
     interpret: Optional[bool] = None,
     v_width: int = 0,
     scale: Optional[float] = None,
+    window: Optional[jnp.ndarray] = None,
+    plan=None,
 ) -> jnp.ndarray:
     """One decode step of attention over (pool blocks ∪ the step's T new
     slots).
+
+    ``window`` (an int32 value) / ``plan``: see ``paged_pool_attention``;
+    the step's own slots are masked by the same window.  One program, not
+    the mesh's, as the latent form.
 
     Latent attention (``v_width`` > 0): ``v_new`` and ``v_pool`` are None,
     every slot's value is the first ``v_width`` columns of its key row, and
@@ -599,7 +665,7 @@ def paged_decode_attention(
     from ..parallel.mesh import current_mesh
 
     mesh = current_mesh()
-    if mesh is not None and not v_width:
+    if mesh is not None and not v_width and window is None and plan is None:
         from jax.sharding import PartitionSpec as P
 
         tp = mesh.shape.get("tensor", 1)
@@ -657,13 +723,14 @@ def paged_decode_attention(
 
     return _paged_decode_local(
         q, k_new, v_new, k_pool, v_pool, pool_pos, table, q_pos,
-        k_scale, v_scale, layer, interpret, v_width, scale,
+        k_scale, v_scale, layer, interpret, v_width, scale, window, plan,
     )
 
 
 def _paged_decode_local(
     q, k_new, v_new, k_pool, v_pool, pool_pos, table, q_pos,
     k_scale, v_scale, layer, interpret, v_width=0, scale=None,
+    window=None, plan=None,
 ):
     """Single-shard body of ``paged_decode_attention`` (also the whole op
     when no mesh is active)."""
@@ -684,6 +751,8 @@ def _paged_decode_local(
         qg, k_pool, v_pool, pool_pos, table, q_pos,
         k_scale=k_scale, v_scale=v_scale, t_tokens=T, layer=layer,
         interpret=interpret, v_width=v_width, scale=scale,
+        **({} if window is None and plan is None
+           else {"window": window, "plan": plan}),
     )
     out_pool = out_pool.reshape(B, KVH, T, G, dv)
     lse = lse.reshape(B, KVH, T, G)
@@ -697,6 +766,8 @@ def _paged_decode_local(
     ) * scale
     t_idx = jnp.arange(T, dtype=jnp.int32)
     causal = t_idx[:, None] >= t_idx[None, :]  # [T(t), T(j)]
+    if window is not None:
+        causal = causal & (t_idx[:, None] - t_idx[None, :] < window)
     s_new = jnp.where(causal[None, None, :, None, :], s_new, MASK_VALUE)
 
     m_tot = jnp.maximum(lse, jnp.max(s_new, axis=-1))  # [B, KVH, T, G]

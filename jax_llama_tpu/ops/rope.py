@@ -83,6 +83,27 @@ def rope_table(
     )
 
 
+def rope_rows(
+    positions: jnp.ndarray, head_dim: int, theta: float = 10000.0
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(cos, sin), each [batch, seq, head_dim // 2] fp32, of `positions`
+    [batch, seq], computed on the device: position x inverse frequency in
+    float32, then cos / sin, as the published implementations compute them.
+    No table: a table is a constant of the program (numpy, see
+    `rope_table`), copied into every loop body and branch that rotates — at
+    65,536 positions x 64 pairs, 33 MB a copy of generated code held on
+    the device by every compiled variant (compiled for a v5e, PR 32).
+    `apply_rope_rows` takes them."""
+    assert head_dim % 2 == 0
+    inv_freq = 1.0 / (
+        theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+    )
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(
+        inv_freq, jnp.float32
+    )
+    return jnp.cos(ang), jnp.sin(ang)
+
+
 def apply_rope(
     x: jnp.ndarray,
     cos: jnp.ndarray,
@@ -99,6 +120,18 @@ def apply_rope(
     Returns:
       Rotated tensor, same shape/dtype as x.
     """
+    return _rotate(x, cos, sin, lambda table: jnp.take(table, positions, axis=0))
+
+
+def apply_rope_rows(
+    x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray
+) -> jnp.ndarray:
+    """`apply_rope` given the tokens' own rows [batch, seq, head_dim // 2]
+    (`rope_rows`) in place of the tables and the positions."""
+    return _rotate(x, cos, sin, lambda rows: rows)
+
+
+def _rotate(x, cos, sin, rows) -> jnp.ndarray:
     orig_dtype = x.dtype
     d2 = x.shape[-1] // 2
     # Slice the halves BEFORE the fp32 cast (elementwise-identical to
@@ -109,7 +142,7 @@ def apply_rope(
     # multiplies and the seam relayout happens on bf16 (or not at all).
     x1 = x[..., :d2].astype(jnp.float32)  # [B, S, H, D/2] — lane halves
     x2 = x[..., d2:].astype(jnp.float32)
-    c = jnp.take(cos, positions, axis=0)[:, :, None, :]  # [B, S, 1, D/2]
-    s = jnp.take(sin, positions, axis=0)[:, :, None, :]
+    c = rows(cos)[:, :, None, :]  # [B, S, 1, D/2]
+    s = rows(sin)[:, :, None, :]
     out = jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], axis=-1)
     return out.astype(orig_dtype)

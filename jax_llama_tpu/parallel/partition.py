@@ -44,8 +44,8 @@ def param_partition_specs(
     """
     f = "fsdp" if fsdp else None
     s = "stage" if pp else None
-    if config.latent_attention:
-        return _latent_specs(config)
+    if config.expert_block:
+        return _expert_block_specs(config)
     specs: Dict[str, Any] = {
         # Vocab-sharded over BOTH model axes, hidden dim unsharded: a
         # vocab-sharded table lowers the token gather to masked-gather +
@@ -78,18 +78,28 @@ def param_partition_specs(
 EXPERT_AXIS = "tensor"
 
 
-def _latent_specs(config: LLaMAConfig) -> Dict[str, Any]:
-    """Specs mirroring `models.mla_moe.init_params`: heads and the dense
-    FFN's F axis over ``tensor`` as in the dense block, the latent
-    projections replicated (the latent is shared by every head), experts
-    over ``EXPERT_AXIS``."""
+def _expert_block_specs(config: LLaMAConfig) -> Dict[str, Any]:
+    """Specs mirroring `models.mla_moe.init_params` and
+    `models.afmoe.init_params`, which differ in their attention weights
+    only: heads and the dense FFN's F axis over ``tensor`` as in the dense
+    block (the latent projections replicated: the latent is shared by every
+    head), experts over ``EXPERT_AXIS``."""
     t = "tensor"
-    attention = {
-        "attn_norm": P(None, None), "q": P(None, t, None, None),
-        "kv_a": P(None, None, None), "kv_norm": P(None, None),
-        "kv_b": P(None, t, None, None), "o": P(None, t, None, None),
-        "mlp_norm": P(None, None),
-    }
+    norm = P(None, None)
+    if config.latent_attention:
+        attention = {
+            "attn_norm": norm, "q": P(None, t, None, None),
+            "kv_a": P(None, None, None), "kv_norm": norm,
+            "kv_b": P(None, t, None, None), "o": P(None, t, None, None),
+            "mlp_norm": norm,
+        }
+    else:
+        attention = {
+            "attn_norm": norm, "post_attn_norm": norm, "mlp_norm": norm,
+            "post_mlp_norm": norm, "q_norm": norm, "k_norm": norm,
+            "qkv": P(None, t, None, None, None), "gate": P(None, t, None, None),
+            "o": P(None, t, None, None),
+        }
     moe = dict(
         attention,
         router=P(None, None, None), router_bias=P(None, None),
@@ -116,11 +126,11 @@ def validate_tp(config: LLaMAConfig, mesh: Mesh, *, fsdp: bool = False) -> None:
     own: its sharding propagates from the constrained k/v projections that
     write it.)
     """
-    if config.latent_attention and (
+    if config.expert_block and (
         fsdp or any(n > 1 for n in mesh.shape.values())
     ):
         raise ValueError(
-            "the latent-attention block runs on one chip: tensor / serve-mesh "
+            f"the block with {config.expert_block} runs on one chip: tensor / serve-mesh "
             f"sharding (mesh {dict(mesh.shape)}, fsdp={fsdp}) is not supported"
         )
     st = mesh.shape.get("stage", 1)

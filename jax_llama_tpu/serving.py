@@ -207,13 +207,14 @@ from .models.llama import (
     forward,
     init_cache,
     lm_head_logits,
-    moe_stats_zero,
+    cache_stats_zero,
     paged_pool_write,
     paged_pool_write_blocks,
     paged_write_indices,
 )
 from .models.mla_moe import ctx_tiles
 from .ops.moe import STATS as _MOE_STATS
+from .models.afmoe import ATTN_STATS as _ATTN_STATS
 from .ops.attention import NEG_INF
 from .ops.sampling import stop_token_hits
 from .parallel.mesh import use_mesh
@@ -294,7 +295,7 @@ def init_pool(
         pos=jnp.full((n_blocks, block_size), -1, jnp.int32),
         k_scale=jnp.zeros(shape[:-1], jnp.float32) if int8_kv else None,
         v_scale=jnp.zeros(shape[:-1], jnp.float32) if int8_kv else None,
-        stats=moe_stats_zero() if latent else None,
+        stats=cache_stats_zero(config),
     )
 
 
@@ -1172,28 +1173,28 @@ def _cache_into_pool(pool: BlockPool, pcache: PagedKVCache) -> BlockPool:
     )
 
 
-def _refuse_latent_extras(params, draft_params, mesh) -> None:
-    """What the latent-attention block does not get yet is refused at
-    server start, by name, never served wrongly."""
+def _refuse_block_extras(params, draft_params, mesh, block: str) -> None:
+    """What a block beside the dense one (``block``: latent attention,
+    window attention layers) does not get yet is refused at server start,
+    by name, never served wrongly."""
     from .ops.quant import QuantizedTensor
 
     if draft_params is not None:
         raise ValueError(
-            "speculative decoding (--draft-*) is not supported with latent "
-            "attention"
+            f"speculative decoding (--draft-*) is not supported with {block}"
         )
     if any(
         isinstance(x, QuantizedTensor) for x in jax.tree_util.tree_leaves(
             params, is_leaf=lambda x: isinstance(x, QuantizedTensor))
     ):
         raise ValueError(
-            "--quantize (int8 weights) is not supported with latent "
-            "attention and routed experts"
+            f"--quantize (int8 weights) is not supported with {block} and "
+            "routed experts"
         )
     if mesh is not None and any(n > 1 for n in mesh.shape.values()):
         raise ValueError(
-            "--serve-mesh / tensor sharding is not supported with latent "
-            f"attention: it runs on one chip (mesh {dict(mesh.shape)})"
+            f"--serve-mesh / tensor sharding is not supported with {block}: "
+            f"it runs on one chip (mesh {dict(mesh.shape)})"
         )
 
 
@@ -1947,8 +1948,10 @@ class ContinuousBatcher:
             )
         self.spec = draft_params is not None
         self.logprobs = logprobs
-        if config.latent_attention:
-            _refuse_latent_extras(params, draft_params, mesh)
+        if config.expert_block:
+            _refuse_block_extras(
+                params, draft_params, mesh, config.expert_block
+            )
         if self.spec:
             if draft_config is None:
                 raise ValueError("draft_params requires draft_config")
@@ -2244,6 +2247,10 @@ class ContinuousBatcher:
         # summed over the chunk fetches that brought them.  Zero on a
         # configuration without.
         self.moe_totals = dict.fromkeys(_MOE_STATS, 0)
+        # Window and full attention layers: the paged decode kernel's live
+        # grid steps by layer kind (``afmoe.ATTN_STATS``), behind the
+        # routing counts in the same fetch.  Zero on a configuration without.
+        self.attn_step_totals = dict.fromkeys(_ATTN_STATS, 0)
         self.prefill_ctx_slots_attended_total = 0
         self.prefill_ctx_slots_view_total = 0
         self.prefill_blocks_written_total = 0
@@ -2637,6 +2644,7 @@ class ContinuousBatcher:
             "prefill_blocks_written_total": self.prefill_blocks_written_total,
             "prefill_pairs_written_total": self.prefill_pairs_written_total,
             **{f"moe_{k}_total": v for k, v in self.moe_totals.items()},
+            **{f"attn_{k}_total": v for k, v in self.attn_step_totals.items()},
             "fused_admissions_total": self.fused_admissions_total,
             "decode_stall_ms_total": round(self.decode_stall_ms_total, 3),
         })
@@ -2882,12 +2890,14 @@ class ContinuousBatcher:
         self.obs.loop_phase("prep")
         self._admits_at_last_chunk = self._admit_dispatches
         pf = self._pf
-        if pf is not None and not bool(np.any(self.active)):
-            # Nothing is decoding: the scan half would be all-masked
-            # forwards, so keep it minimal while the prefill advances.
-            K = 1
-        else:
-            K = self._pick_chunk(admitted)
+        # While a prefill is in flight and nothing decodes (a row's last
+        # token fell inside another's prefill) the scan half is K
+        # all-masked iterations.  K stays what the fused lane runs at all
+        # the same: a K=1 variant of every chunk shape, reached by that
+        # state alone, is a program no warm-up is sure to meet, and it
+        # compiled inside a served window (4.5 s, v5e; PERF.md section 6,
+        # PR 32) to save iterations of some milliseconds each.
+        K = self._pick_chunk(admitted)
         self._sync_device_rows()
         # Injection site "step": fires BEFORE the chunk dispatch; an
         # exception out of the dispatch (or its packed fetch below)
@@ -3019,12 +3029,15 @@ class ContinuousBatcher:
         moe_counts = None
         if self.pool.stats is not None:
             # Trailing planes of the same fetch (``_pack_stats``).
-            moe_counts = [
+            counts = [
                 int(v) for v in arr[2 if self.logprobs else 1:]
-                .reshape(-1)[:len(_MOE_STATS)]
+                .reshape(-1)[:self.pool.stats.shape[0]]
             ]
+            moe_counts = counts[:len(_MOE_STATS)]
             for name, v in zip(_MOE_STATS, moe_counts):
                 self.moe_totals[name] += v
+            for name, v in zip(_ATTN_STATS, counts[len(_MOE_STATS):]):
+                self.attn_step_totals[name] += v
         if pf_ctx is not None:
             self.prefill_ctx_slots_attended_total += pf_ctx[0]
             self.prefill_ctx_slots_view_total += pf_ctx[1]

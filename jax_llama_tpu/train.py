@@ -162,9 +162,9 @@ def train_step(
     """
     from .parallel.mesh import current_mesh, use_mesh
 
-    if config.latent_attention:
+    if config.expert_block:
         raise NotImplementedError(
-            "the training step is not supported with latent attention and "
+            f"the training step is not supported with {config.expert_block} and "
             "routed experts: the block is served, not trained (no router "
             "balance loss, no grouped-matmul gradient)"
         )

@@ -304,3 +304,49 @@ def test_fused_chunk_no_pool_copies_for_v5e(sds, monkeypatch, rows, view):
     assert text.count("tpu_custom_call") >= 2  # flash and the paged kernel
     offenders = _pool_copy_offenders(text, pool.k.shape)
     assert not offenders, (len(offenders), offenders)
+
+
+# --- the window forms of the two kernels (Trinity-Mini's geometry) -----------
+
+@pytest.mark.parametrize(
+    "queries,keys", [(2048, 32768), (512, 32768), (8192, 8192)],
+    ids=["chunk-over-view", "short-chunk-over-view", "whole-prompt-insert"],
+)
+def test_flash_window_compiles_for_v5e(sds, queries, keys):
+    """The flash kernel with its window operand (a second scalar-prefetched
+    bound a q block and the window itself) as `trinitymini-docqa-mixed` calls
+    it: 32 query / 4 KV heads of 128, a `prefill_budget` chunk over the row's
+    32,768-slot view, and a whole prompt over itself."""
+    from jax_llama_tpu.ops.flash_attention import flash_attention
+
+    kv = sds((1, keys, 4, D), jnp.bfloat16)
+    _assert_mosaic(flash_attention.lower(
+        sds((1, queries, 32, D), jnp.bfloat16), kv, kv,
+        sds((1, queries), jnp.int32), sds((1, keys), jnp.int32), interpret=False,
+        window=sds((), jnp.int32),
+    ))
+
+
+@pytest.mark.parametrize("t_tokens", [1, 5], ids=["decode", "verify-t5"])
+def test_paged_window_decode_compiles_for_v5e(sds, t_tokens):
+    """The paged kernel with its window operand over the cell's pool: 8 rows
+    x 64 blocks of 512 tokens, 4 KV heads of 128 under 32 query heads, 5
+    layers, the step list handed in (`fetch_plan`) as the block derives it
+    outside its layer scan."""
+    from jax_llama_tpu.ops.paged_attention import fetch_plan, paged_pool_attention
+
+    rows, mb, kvh, blk, layers = 8, 64, 4, 512, 5
+    nb = rows * mb
+    pool = sds((layers, kvh, nb, blk, D), jnp.bfloat16)
+    pos, table, q_pos = sds((nb, blk), jnp.int32), sds((rows, mb), jnp.int32), sds((rows,), jnp.int32)
+    window = sds((), jnp.int32)
+
+    def attend(q, pool_k, pool_v, pos, table, q_pos, layer, window):
+        plan = fetch_plan(pool_k, pos, table, q_pos, t_tokens, window)
+        return paged_pool_attention(
+            q, pool_k, pool_v, pos, table, q_pos, t_tokens=t_tokens, layer=layer,
+            interpret=False, window=window, plan=plan)
+
+    _assert_mosaic(jax.jit(attend).lower(
+        sds((rows, kvh, t_tokens * 8, D), jnp.bfloat16), pool, pool, pos, table,
+        q_pos, sds((), jnp.int32), window))

@@ -275,6 +275,39 @@ def test_first_token_emitted_by_prefill_completion_dispatch(model):
     assert any(ev[0] == r1 for ev in completion_events)
 
 
+def test_a_row_that_ends_inside_a_prefill_leaves_k_alone(model):
+    """With a prefill in flight and nothing decoding (the riding row's
+    last token fell inside it) the fused dispatch keeps the K of every
+    other fused dispatch: no K=1 variant of a chunk shape that this state
+    alone would reach (such a variant compiled inside a served window,
+    PERF.md section 6, PR 32).  The tokens are the classic path's."""
+    params, config = model
+    prompt = np.random.RandomState(3).randint(1, 128, size=56).tolist()
+
+    def serve(budget):
+        cb = ContinuousBatcher(
+            params, config, n_slots=2, max_len=80, decode_chunk=4,
+            block_size=BLOCK, prefill_budget=budget,
+        )
+        r0 = cb.submit([5, 17, 99, 3], max_new_tokens=6)
+        cb.step()
+        r1 = cb.submit(prompt, max_new_tokens=6)
+        toks, alone = {}, []
+        while cb.pending():
+            lonely = cb._pf is not None and not bool(np.any(cb.active))
+            for ev in cb.step():
+                toks.setdefault(ev[0], []).append(ev[1])
+            if lonely:
+                alone.append(cb.decode_chunk_last)
+        return toks[r0], toks[r1], alone
+
+    fused0, fused1, alone = serve(BLOCK)
+    classic0, classic1, _ = serve(0)
+    assert (fused0, fused1) == (classic0, classic1)
+    # 56 tokens at a 16-token budget outlast r0's 6 tokens
+    assert alone and all(k == 4 for k in alone), alone
+
+
 def test_cancel_mid_prefill_frees_admission(model):
     """Cancelling the in-flight admission mid-prefill drops it cleanly:
     its blocks free, no fused dispatches reference it afterwards, and
