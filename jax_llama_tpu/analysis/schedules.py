@@ -460,6 +460,7 @@ def _make_batcher_stub():
     s.prefill_ctx_slots_view_total = 0
     s.prefill_blocks_written_total = 0
     s.prefill_pairs_written_total = 0
+    s.fused_dispatches_queued_total = 0
     s.fused_admissions_total = 0
     s.decode_stall_ms_total = 0.0
     s.prefix_index = "radix"

@@ -422,6 +422,13 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "prefill_pairs_written_total": _reg(
         "counter", "(block, offset) token slots the whole-prompt and "
                    "suffix inserts landed in the pool (pair form)"),
+    "fused_dispatches_total": _reg(
+        "counter", "Chunk dispatches that carried a prompt chunk (the "
+                   "denominator of the queued share; equals "
+                   "prefill_chunks_total)"),
+    "fused_dispatches_queued_total": _reg(
+        "counter", "Of those, dispatches submitted while requests queued "
+                   "for the prefill lane (they run the lane's K clamp)"),
     # -- routed experts (ops/moe.py; zero on a configuration without) -------
     "moe_assignments_total": _reg(
         "counter", "(token, expert) pairs the router assigned"),
@@ -1321,6 +1328,7 @@ class Observability:
         moe: Optional[Sequence[int]] = None,
         prefill_ctx: Optional[Tuple[int, int]] = None,
         prefill_write: Optional[Dict[str, int]] = None,
+        queued: Optional[int] = None,
     ) -> int:
         """Record one jitted serving dispatch and link it into the
         CURRENT span of every request that rode it.  Returns the
@@ -1345,6 +1353,8 @@ class Observability:
         ``prefill_write`` (dispatches that land prompt KV) is what they
         landed in the pool: ``{"blocks": n}`` whole blocks from the fused
         lane, ``{"pairs": n}`` token slots from an insert.
+        ``queued`` (chunk dispatches) is the batcher's queue length at the
+        submit: what the dispatch's K was clamped for.
         ``then`` names the phase the loop thread is in once
         the dispatch ends (default: the one it interrupted)."""
         if kind not in DISPATCH_KINDS:
@@ -1381,6 +1391,8 @@ class Observability:
             rec["prefill_write"] = {
                 key: int(v) for key, v in prefill_write.items()
             }
+        if queued is not None:
+            rec["queued"] = int(queued)
         rec.update(gap)
         with self._lock:
             seq = self._seq

@@ -47,6 +47,10 @@ parity.  Design constraints, in order:
     admission's prompt), ``llm_prefill_tokens_inflight`` (gauge —
     prompt tokens of the current admission still to prefill; 0 when
     none), ``llm_fused_admissions_total`` (counter),
+    ``llm_fused_dispatches_queued_total`` over
+    ``llm_fused_dispatches_total`` (counters — the share of
+    prompt-carrying dispatches submitted while requests queued for the
+    prefill lane, which run the lane's small K),
     ``llm_decode_stall_ms_total`` (counter — wall time classic
     whole-prompt admission dispatches spent while rows were
     mid-decode; ≈0 once fused scheduling is on), and

@@ -58,9 +58,11 @@ TPU-native mechanics:
     before the next chunk — steady-state decode performs zero
     host->device state uploads and one device->host fetch per K tokens
     per slot, instead of the five uploads + one fetch PER TOKEN the
-    K=1 loop pays.  K adapts (1 right after an admission, clamped small
-    while the queue holds capacity-blocked requests, pow2 up to
-    ``decode_chunk`` once slots are steady) so admission latency and
+    K=1 loop pays.  K adapts (1 right after a classic admission; while
+    requests queue, clamped to 4 on a plain decode dispatch, where they
+    wait for a slot, and to 2 on one that carries a prompt chunk, where
+    they wait for the prefill lane; pow2 up to ``decode_chunk`` once the
+    queue is empty) so admission latency and
     time-to-first-token match the K=1 loop while saturated load keeps
     amortizing dispatches.  Chunked output is
     token-identical to K=1 under greedy and seeded sampling — per-row
@@ -2255,6 +2257,10 @@ class ContinuousBatcher:
         self.prefill_ctx_slots_view_total = 0
         self.prefill_blocks_written_total = 0
         self.prefill_pairs_written_total = 0
+        # Fused dispatches submitted while requests queued for the lane
+        # (the state _QUEUED_LANE_CAP answers); over
+        # ``prefill_chunks_total``, which counts every fused dispatch.
+        self.fused_dispatches_queued_total = 0
         self.fused_admissions_total = 0
         self.decode_stall_ms_total = 0.0
 
@@ -2643,6 +2649,10 @@ class ContinuousBatcher:
             "prefill_ctx_slots_view_total": self.prefill_ctx_slots_view_total,
             "prefill_blocks_written_total": self.prefill_blocks_written_total,
             "prefill_pairs_written_total": self.prefill_pairs_written_total,
+            "fused_dispatches_total": self.prefill_chunks_total,
+            "fused_dispatches_queued_total": (
+                self.fused_dispatches_queued_total
+            ),
             **{f"moe_{k}_total": v for k, v in self.moe_totals.items()},
             **{f"attn_{k}_total": v for k, v in self.attn_step_totals.items()},
             "fused_admissions_total": self.fused_admissions_total,
@@ -2736,10 +2746,13 @@ class ContinuousBatcher:
         handling ON DEVICE, and pays exactly one device->host fetch (the
         packed token block).  Batcher state lives device-resident; the
         host mirrors advance by replaying the block.  K adapts: 1 when
-        an admission just landed, <= _QUEUED_CHUNK_CAP while the queue
-        holds capacity-blocked requests (slot turnaround / admission
-        latency), up to ``decode_chunk`` (pow2, clamped to the largest
-        remaining budget) once slots are steady.
+        a classic admission just landed; while requests queue,
+        <= _QUEUED_CHUNK_CAP on a plain decode dispatch (they wait for a
+        SLOT: slot turnaround) and <= _QUEUED_LANE_CAP on a dispatch that
+        carries a prompt chunk (a slot is free and they wait for the one
+        prefill LANE, which every iteration past the first holds up);
+        up to ``decode_chunk`` (pow2, clamped to the largest remaining
+        budget) once the queue is empty.
         """
         self.last_step_features = set()
         # Fused scheduling routes warm admissions through the chunk
@@ -2785,33 +2798,58 @@ class ContinuousBatcher:
         "this request; it was aborted (server healthy)"
     )
 
-    # Chunk clamp while the queue is capacity-blocked: small enough that
-    # a finishing slot is detected within a few iterations (bounded
-    # admission latency for the queue head), large enough that a
-    # SATURATED server — the normal high-throughput regime, where the
-    # queue is never empty — still amortizes the per-dispatch host
-    # overhead instead of reverting to one dispatch per token.
+    # Chunk clamp of a PLAIN decode dispatch while requests queue: every
+    # slot is taken, the queue waits for one to finish, and the host only
+    # learns that at a chunk boundary.  Small enough that a finishing slot
+    # is detected within a few iterations (bounded admission latency for
+    # the queue head), large enough that a SATURATED server — the normal
+    # high-throughput regime, where the queue is never empty — still
+    # amortizes the per-dispatch host overhead instead of reverting to
+    # one dispatch per token.
     _QUEUED_CHUNK_CAP = 4
+    # Chunk clamp of a dispatch that CARRIES A PROMPT CHUNK while requests
+    # queue: a slot is free (the server hands the batcher only as many
+    # requests as it has free slots), so the queue waits for the one
+    # prefill lane, and the lane advances one chunk a dispatch however
+    # many decode iterations ride behind it.  Every iteration past the
+    # first is a whole pass over the weights for the few rows the slots
+    # hold so far, and keeps the next prompt out of an empty one; once
+    # the slots are full the batcher's queue is empty and K is
+    # ``decode_chunk`` again, which slows the lane — so a clamp that
+    # fills the slots too eagerly loses where a prompt chunk costs many
+    # iterations.  One constant for every block and cell (v5e, closed
+    # loops of 16 clients on 8 slots; PERF.md section 6, PR 33): the 7B
+    # dense cell reads 130 tokens/s at 4, 159 at 2, 168-171 at 1; the
+    # latent-attention cell 240 at 4 and 217-232 at 1.
+    _QUEUED_LANE_CAP = 2
 
     def _pick_chunk(self, admitted: bool, cap: Optional[int] = None) -> int:
-        """Effective K for the next chunk dispatch.  K=1 right after an
-        admission (the fresh request's first token should not wait out a
-        full chunk); K <= _QUEUED_CHUNK_CAP while the queue holds
-        capacity-blocked requests (their admission waits on a slot
-        finishing, which the host only learns at a chunk boundary);
-        otherwise the largest power of two <= min(cap, max remaining
-        budget) — pow2 throughout, so the jit cache holds O(log cap)
-        chunk programs.  ``cap`` defaults to ``decode_chunk``; the
-        speculative path passes ``spec_rounds`` (each round emits at
-        least one token, so clamping R by the token budget bounds the
-        dead masked tail the same way it does for K).
+        """Effective K for the next chunk dispatch.  K=1 right after a
+        classic admission (the fresh request's first token should not
+        wait out a full chunk).  While the queue holds requests the
+        clamp follows what they wait for: K <= _QUEUED_CHUNK_CAP on a
+        plain decode dispatch (no slot is free; their admission waits on
+        one finishing, which the host only learns at a chunk boundary),
+        K <= _QUEUED_LANE_CAP on a dispatch that carries a prompt chunk
+        (``self._pf``: a slot is free and they wait for the prefill
+        lane, which moves once a dispatch).  Otherwise the largest power
+        of two <= min(cap, max remaining budget) — pow2 throughout, so
+        the jit cache holds O(log cap) chunk programs.  A prefilling row
+        has emitted nothing, so while a chunk rides the budget clamp is
+        its ``max_new`` at least and a fused dispatch's K is two-valued:
+        the lane cap under a queue, ``decode_chunk`` without.  ``cap``
+        defaults to ``decode_chunk``; the speculative path passes
+        ``spec_rounds`` (each round emits at least one token, so
+        clamping R by the token budget bounds the dead masked tail the
+        same way it does for K; it has no prefill lane, so R only ever
+        meets _QUEUED_CHUNK_CAP).
 
         ``admitted`` only counts CLASSIC whole-prompt admissions: a
         fused admission's first token is sampled inside the chunk
         dispatch chain itself, so K no longer collapses to 1 while a
         prefill rides along — exactly when a burst is hammering the
-        server (the queued clamp below still bounds the queue head's
-        wait on a finishing slot)."""
+        server (the queued clamps still bound the queue head's wait, on
+        a finishing slot or on the lane)."""
         cap = self.decode_chunk if cap is None else cap
         if cap <= 1 or admitted:
             return 1
@@ -2821,7 +2859,11 @@ class ContinuousBatcher:
         )
         k = max(1, min(cap, rem))
         if self.queue:
-            k = min(k, self._QUEUED_CHUNK_CAP)
+            k = min(
+                k,
+                self._QUEUED_CHUNK_CAP if self._pf is None
+                else self._QUEUED_LANE_CAP,
+            )
         return 1 << (k.bit_length() - 1)
 
     def _sync_device_rows(self) -> None:
@@ -2873,7 +2915,10 @@ class ContinuousBatcher:
         fetch — so decoding rows keep emitting while the prompt lands,
         and K does NOT collapse to 1 (fused admissions never set the
         ``admitted`` reset; the first token rides this dispatch chain
-        regardless of K)."""
+        regardless of K).  While requests queue behind the lane such a
+        dispatch runs K <= _QUEUED_LANE_CAP (``_pick_chunk``); the record
+        carries ``queued`` and ``fused_dispatches_queued_total`` counts
+        how often that held."""
         # CLASSIC admissions since the last chunk dispatch — including
         # one this step() performed at the PREVIOUS call's trailing
         # _admit().  Fused admissions perform no insert dispatch, so
@@ -2892,11 +2937,10 @@ class ContinuousBatcher:
         pf = self._pf
         # While a prefill is in flight and nothing decodes (a row's last
         # token fell inside another's prefill) the scan half is K
-        # all-masked iterations.  K stays what the fused lane runs at all
-        # the same: a K=1 variant of every chunk shape, reached by that
-        # state alone, is a program no warm-up is sure to meet, and it
-        # compiled inside a served window (4.5 s, v5e; PERF.md section 6,
-        # PR 32) to save iterations of some milliseconds each.
+        # all-masked iterations.  That state gets no K of its own: a
+        # program variant that it alone reaches is one no warm-up is sure
+        # to meet, and such a one compiled inside a served window (4.5 s,
+        # v5e; PERF.md section 6, PR 32).
         K = self._pick_chunk(admitted)
         self._sync_device_rows()
         # Injection site "step": fires BEFORE the chunk dispatch; an
@@ -2950,6 +2994,7 @@ class ContinuousBatcher:
         pf_write = (
             None if pf is None else {"blocks": self._pf_live_blocks(pf)}
         )
+        queued = len(self.queue)
         pf_done_rid: Optional[int] = None
         all_greedy = bool(np.all(self.temp_arr[self.active] == 0.0))
         if pf is not None:
@@ -2991,6 +3036,7 @@ class ContinuousBatcher:
                 with_logprobs=self.logprobs, placed=self._mesh_placed,
             )
             self.prefill_chunks_total += 1
+            self.fused_dispatches_queued_total += queued > 0
             pf.off += pf.chunk
             if pf.off >= pf.suffix_len:
                 # Prefill complete: the device already folded the row
@@ -3051,6 +3097,7 @@ class ContinuousBatcher:
             program=prog, then="emit", moe=moe_counts,
             prefill_ctx=pf_ctx,
             prefill_write=pf_write,
+            queued=queued,
         )
         if pf_done_rid is not None:
             # The prefill's last chunk linked into the prefilling span

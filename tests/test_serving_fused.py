@@ -308,6 +308,110 @@ def test_a_row_that_ends_inside_a_prefill_leaves_k_alone(model):
     assert alone and all(k == 4 for k in alone), alone
 
 
+def _serve_a_queue(params, config, budget):
+    """A holder decodes throughout; one prompt rides the lane ALONE, then
+    four arrive at once on the two free slots of three, so the lane has a
+    queue behind it, then every slot is taken with requests still queued,
+    then the last prompt rides the lane with nobody behind it.  Returns
+    (tokens by submit order, batcher)."""
+    cb = ContinuousBatcher(
+        params, config, n_slots=3, max_len=128, decode_chunk=8,
+        block_size=BLOCK, prefill_budget=budget,
+    )
+    rng = np.random.RandomState(5)
+    draw = lambda n: [int(t) for t in rng.randint(1, 128, size=n)]
+    toks = {}
+
+    def pump(until):
+        for _ in range(300):
+            if until():
+                return
+            for ev in cb.step():
+                toks.setdefault(ev[0], []).append(ev[1])
+        raise AssertionError("did not get there")
+
+    rids = [cb.submit(draw(4), max_new_tokens=110)]    # idle server: an insert
+    pump(lambda: len(toks.get(rids[0], ())) >= 2)
+    rids.append(cb.submit(draw(20), max_new_tokens=8))  # two chunks, alone
+    pump(lambda: len(toks.get(rids[1], ())) == 8)
+    # three chunks each; the first two decode long enough to fill the slots
+    rids += [cb.submit(draw(36), max_new_tokens=n) for n in (30, 30, 8, 8)]
+    pump(lambda: not cb.pending())
+    return [toks[r] for r in rids], cb
+
+
+@pytest.fixture(scope="module")
+def queued_run(model):
+    params, config = model
+    toks, cb = _serve_a_queue(params, config, BLOCK)
+    chunks = [d for d in cb.obs.dispatches if d["kind"] in ("fused", "decode")]
+    return toks, cb, chunks
+
+
+@pytest.mark.parametrize(
+    "kind,queued,cap",
+    [
+        ("fused", True, "_QUEUED_LANE_CAP"),
+        ("fused", False, "decode_chunk"),
+        ("decode", True, "_QUEUED_CHUNK_CAP"),
+    ],
+    ids=["lane-with-a-queue", "lane-alone", "decode-with-a-queue"],
+)
+def test_k_under_a_queue_follows_what_the_queue_waits_for(
+    queued_run, kind, queued, cap,
+):
+    """While requests queue, a dispatch that carries a prompt chunk runs
+    the lane's clamp (the queue waits for the lane, which moves once a
+    dispatch) and a plain decode dispatch the slot clamp; with nobody
+    queued a fused dispatch runs ``decode_chunk``.  The holder's budget is
+    never the limit here."""
+    _, cb, chunks = queued_run
+    assert (cb._QUEUED_LANE_CAP, cb._QUEUED_CHUNK_CAP, cb.decode_chunk) == (
+        2, 4, 8)
+    ks = [
+        d["k"] for d in chunks
+        if d["kind"] == kind and (d["queued"] > 0) == queued
+    ]
+    assert len(ks) >= 3, chunks
+    assert set(ks) == {getattr(cb, cap)}, ks
+
+
+def test_tokens_behind_a_queued_lane_are_the_classic_path_s(model, queued_run):
+    """Three requests queued behind the lane, then behind full slots: every
+    request's tokens are what classic admission (``prefill_budget=0``)
+    serves.  K decides when a token arrives, never which."""
+    params, config = model
+    toks, cb, chunks = queued_run
+    assert max(d["queued"] for d in chunks if d["kind"] == "fused") >= 3
+    classic, cb0 = _serve_a_queue(params, config, 0)
+    assert cb0.fused_admissions_total == 0
+    assert cb0.stats()["fused_dispatches_queued_total"] == 0
+    assert cb.fused_admissions_total == 5
+    assert [len(t) for t in toks] == [110, 8, 30, 30, 8, 8]
+    assert toks == classic
+
+
+def test_the_record_says_queued_and_the_counters_count_it(queued_run):
+    """Every chunk dispatch's record carries the queue length at its submit
+    (an insert's does not), and ``fused_dispatches_queued_total`` over
+    ``fused_dispatches_total`` is the share of prompt-carrying dispatches
+    that met a queue."""
+    from jax_llama_tpu.obs import metric_meta
+
+    _, cb, chunks = queued_run
+    assert all("queued" in d for d in chunks)
+    assert all(
+        "queued" not in d for d in cb.obs.dispatches if d["kind"] == "insert")
+    fused = [d["queued"] for d in chunks if d["kind"] == "fused"]
+    stats = cb.stats()
+    # two chunks alone, then four prompts of three: the last has no queue
+    assert fused == [0, 0, 3, 3, 3, 2, 2, 2, 1, 1, 1, 0, 0, 0]
+    assert stats["fused_dispatches_total"] == 14 == stats["prefill_chunks_total"]
+    assert stats["fused_dispatches_queued_total"] == 9
+    for name in ("fused_dispatches_total", "fused_dispatches_queued_total"):
+        assert metric_meta(name)[0] == "counter"
+
+
 def test_cancel_mid_prefill_frees_admission(model):
     """Cancelling the in-flight admission mid-prefill drops it cleanly:
     its blocks free, no fused dispatches reference it afterwards, and
