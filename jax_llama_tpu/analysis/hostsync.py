@@ -99,6 +99,11 @@ DEVICE_RETURNING = frozenset({
     "_paged_suffix_insert", "_scatter_rows", "_release_blocks",
     "_adopt_jit", "adopt_into_pool", "stage_restore", "init_pool",
     "_gather_cache", "_scatter_back", "_pool_as_cache",
+    # recurrent state layers: the per-slot state and the snapshot pool are
+    # fields of ``pool`` (covered above); these cut rows out of them and
+    # put them back, on the device: a snapshot copy or a state reset that
+    # fetched either would be a finding
+    "_snapshot_rows", "_state_into_rows", "init_state",
 })
 
 # Metadata attributes of device arrays — host-resident, never a sync.

@@ -104,6 +104,10 @@ SHAPE_SOURCES: Dict[str, List[Tuple[str, str]]] = {
     "_paged_decode_chunk": [("_ensure_stop_width", "tab")],
     "_spec_rounds_chunk": [("_ensure_stop_width", "tab")],
     "_scatter_rows": [("_ensure_stop_width", "tab")],
+    # recurrent state layers: the whole-prompt insert's slot ids, one a row
+    # of the admission's pow2 row bucket; the fused lane's two snapshot
+    # operands are int32 scalars (no shape)
+    "_paged_insert": [("_state_operands", "rows")],
 }
 
 

@@ -456,6 +456,10 @@ def _make_batcher_stub():
     s.prefill_chunks_total = 0
     s.moe_totals = {}
     s.attn_step_totals = {}
+    s.ssm_snapshots_taken_total = 0
+    s.ssm_snapshots_restored_total = 0
+    s.ssm_match_tokens_cut_total = 0
+    s.n_snapshots = 0
     s.prefill_ctx_slots_attended_total = 0
     s.prefill_ctx_slots_view_total = 0
     s.prefill_blocks_written_total = 0

@@ -124,6 +124,55 @@ class LLaMAConfig:
     window_layers: Optional[Tuple[bool, ...]] = None
     sliding_window: int = 0
 
+    # --- recurrent (state-space) layers beside attention.  mb_per_layer 0:
+    # none.  2 selects the block of models/sambay.py (phi4flash-style): even
+    # layers of the first half are Mamba-1 mixers with a per-row convolution
+    # and state-space state, odd ones window attention (`sliding_window`);
+    # layer L/2 is a mixer that publishes its scan output, layer L/2 + 1 a
+    # full-attention layer whose K/V is the only cache the second half reads:
+    # there even layers are gated memory units over the published output
+    # (no state, no cache), odd ones cross attention over that K/V.
+    # Differential attention on adjacent head pairs, LayerNorm with bias,
+    # no position encoding, tied head (`layer_kinds`).
+    mb_per_layer: int = 0
+    mamba_d_state: int = 16               # N: state values a channel
+    mamba_d_conv: int = 4                 # causal depthwise conv width
+    mamba_expand: int = 2                 # Di = expand * dim
+    mamba_dt_rank: int = 0                # 0 -> ceil(dim / 16)
+    layer_norm_eps: float = 1e-5
+
+    @property
+    def recurrent_state(self) -> bool:
+        return self.mb_per_layer > 0
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_expand * self.dim
+
+    @property
+    def dt_rank(self) -> int:
+        return self.mamba_dt_rank or -(-self.dim // 16)
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The recurrent block's layer kinds by index (see above)."""
+        half = self.n_layers // 2
+        first = ("mamba", "window") * (half // 2)
+        second = ("gmu", "cross") * ((half - 2) // 2)
+        return first + ("mamba_pub", "full_pub") + second
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that carry a recurrent state a row: the mixers."""
+        return self.n_layers // 4 + 1 if self.recurrent_state else 0
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers that OWN K/V planes (or the latent plane) in a cache: all
+        of them, but in the recurrent block the window layers and the one
+        full-attention layer, whose plane the cross layers read."""
+        return self.n_layers // 4 + 1 if self.recurrent_state else self.n_layers
+
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads if self.n_kv_heads is not None else self.n_heads
@@ -149,7 +198,10 @@ class LLaMAConfig:
 
     @property
     def cache_heads(self) -> int:
-        """Heads of one cached row: the KV heads, or 1 for the latent."""
+        """Heads of one cached row: the KV heads, 1 for the latent, or the
+        KV head PAIRS of differential attention (`[k1 | k2]` a row)."""
+        if self.recurrent_state:
+            return self.kv_heads // 2
         return 1 if self.latent_attention else self.kv_heads
 
     @property
@@ -165,6 +217,8 @@ class LLaMAConfig:
             # operand then costs a copy of the whole pool in and out of
             # every dispatch (compiled for a v5e, PR 27).
             return -(-self.latent_dim // 128) * 128
+        if self.recurrent_state:
+            return 2 * self.head_dim
         return self.head_dim
 
     @property
@@ -197,7 +251,9 @@ class LLaMAConfig:
         assert self.head_size is not None or self.dim % self.n_heads == 0, (
             "n_heads must divide dim (or head_size be given)"
         )
-        if self.windowed_attention:
+        if self.recurrent_state:
+            self._validate_recurrent()
+        elif self.windowed_attention:
             self._validate_windowed()
         elif self.latent_attention:
             self._validate_latent()
@@ -226,8 +282,10 @@ class LLaMAConfig:
     @property
     def expert_block(self) -> Optional[str]:
         """The block beside the dense one a configuration selects, as error
-        messages name it; None for the dense block.  Both have routed
-        experts behind a leading run of dense layers."""
+        messages name it; None for the dense block.  None of them gets a
+        mesh, int8, speculation or the train step yet."""
+        if self.recurrent_state:
+            return "recurrent state layers"
         if self.latent_attention:
             return "latent attention"
         return "window attention layers" if self.windowed_attention else None
@@ -262,6 +320,52 @@ class LLaMAConfig:
                 "tie_word_embeddings / use_scaled_rope are not supported "
                 f"with {block}"
             )
+
+    def _validate_recurrent(self) -> None:
+        """The block with recurrent state layers: what it needs, and what
+        it does not get yet — refused by name here, never served wrongly."""
+        block = self.expert_block
+        if self.latent_attention or self.windowed_attention or self.n_routed_experts:
+            raise ValueError(
+                "mb_per_layer beside kv_lora_rank / window_layers / routed "
+                "experts: two blocks in one configuration")
+        if self.mb_per_layer != 2:
+            raise ValueError(
+                f"mb_per_layer: {self.mb_per_layer!r} is not in the program; "
+                "its block alternates one mixer and one attention layer (2)")
+        if self.n_layers < 8 or self.n_layers % 4:
+            raise ValueError(
+                f"{block} need n_layers a multiple of 4 and >= 8 (two halves "
+                f"of mixer / attention pairs), got {self.n_layers}")
+        if self.sliding_window <= 0:
+            raise ValueError(
+                f"{block}: the window layers need a sliding_window > 0, got "
+                f"{self.sliding_window!r}")
+        if self.n_heads % 2 or self.kv_heads % 2 or self.n_heads % self.kv_heads:
+            raise ValueError(
+                "differential attention pairs adjacent heads: n_heads and "
+                f"n_kv_heads must be even ({self.n_heads}, {self.kv_heads})")
+        if self.head_size is not None:
+            raise ValueError(f"head_size is not supported with {block}")
+        if self.mamba_d_conv != 4:
+            raise ValueError(
+                f"mamba_d_conv: {self.mamba_d_conv!r} is not in the program; "
+                "the convolution state holds 3 inputs (width 4)")
+        for name in ("mamba_d_state", "mamba_expand"):
+            if getattr(self, name) <= 0:
+                raise ValueError(f"{block} need {name} > 0")
+        if not self.tie_word_embeddings:
+            raise ValueError(f"{block}: the head is tied (tie_word_embeddings)")
+        if self.kv_cache_dtype == "int8":
+            raise ValueError(
+                f"kv_cache_dtype='int8' is not supported with {block}: the "
+                "head-pair row has no per-head scale")
+        if self.attn_impl == "ring":
+            raise ValueError(f"attn_impl='ring' is not supported with {block}")
+        if self.use_scaled_rope:
+            raise ValueError(
+                f"use_scaled_rope is not supported with {block}: the block "
+                "carries no position encoding")
 
     def _validate_windowed(self) -> None:
         """The window-and-full-attention block (see `_validate_experts`)."""
@@ -342,6 +446,73 @@ _PUBLISHED_WINDOWED_FIXED = {
 }
 # training and implementation switches: accepted at any value, unused
 _PUBLISHED_WINDOWED_UNUSED = ("load_balance_coeff", "use_grouped_mm")
+
+# the phi4flash block (recurrent state layers beside window / full / cross
+# attention): published key -> field.  It has no rope, no RMSNorm eps and no
+# routed experts, so it takes none of `_PUBLISHED`'s keys it does not name.
+_PUBLISHED_RECURRENT = {
+    "hidden_size": "dim", "num_hidden_layers": "n_layers",
+    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+    "intermediate_size": "intermediate_size", "vocab_size": "vocab_size",
+    "tie_word_embeddings": "tie_word_embeddings",
+    "layer_norm_eps": "layer_norm_eps", "mb_per_layer": "mb_per_layer",
+    "sliding_window": "sliding_window",
+}
+# sizes the published file may leave to the modelling code's defaults
+_PUBLISHED_RECURRENT_OPTIONAL = ("mamba_d_state", "mamba_d_conv", "mamba_expand")
+# its keys with ONE accepted value: the block as the program computes it
+_PUBLISHED_RECURRENT_FIXED = {
+    "model_type": "phi4flash", "hidden_act": "silu", "mb_per_layer": 2,
+    "tie_word_embeddings": True, "mlp_bias": False, "lm_head_bias": False,
+    "embd_pdrop": 0, "resid_pdrop": 0,
+}
+
+
+def _from_published_recurrent(raw, *, max_seq_len: int, attn_impl: str) -> "LLaMAConfig":
+    """`from_published` for a file with `mb_per_layer` (the phi4flash
+    block), as strict as the others."""
+    fields = _PUBLISHED_RECURRENT
+    known = (set(fields) | set(_PUBLISHED_RECURRENT_OPTIONAL)
+             | set(_PUBLISHED_RECURRENT_FIXED)
+             | {"torch_dtype", "max_position_embeddings", "mamba_dt_rank"})
+    unknown = sorted(set(raw) - known)
+    if unknown:
+        raise ValueError(f"the program understands no published key {', '.join(map(repr, unknown))}")
+    missing = sorted(k for k in (*fields, "torch_dtype") if k not in raw)
+    if missing:
+        raise ValueError(
+            f"published key {missing[0]!r} is missing (a file with "
+            "'mb_per_layer' is the block with recurrent state layers, "
+            "which needs it)")
+    for key, only in _PUBLISHED_RECURRENT_FIXED.items():
+        if key in raw and (raw[key] != only or isinstance(raw[key], bool) != isinstance(only, bool)):
+            raise ValueError(
+                f"{key}: {raw[key]!r} is not in the program; its block with "
+                f"recurrent state layers computes {only!r} only")
+    window = raw["sliding_window"]
+    if not isinstance(window, int) or isinstance(window, bool) or window <= 0:
+        raise ValueError(
+            f"sliding_window: {window!r}; the window layers need an int > 0")
+    layers = raw["num_hidden_layers"]
+    if not isinstance(layers, int) or layers < 8 or layers % 4:
+        raise ValueError(
+            f"num_hidden_layers: {layers!r} is not a multiple of 4 (>= 8): "
+            "two halves of mixer / attention pairs")
+    if raw["hidden_size"] % raw["num_attention_heads"]:
+        raise ValueError("num_attention_heads does not divide hidden_size")
+    if raw["torch_dtype"] not in _PUBLISHED_DTYPES:
+        raise ValueError(f"torch_dtype {raw['torch_dtype']!r} is not one the program serves in")
+    extra = {k: raw[k] for k in _PUBLISHED_RECURRENT_OPTIONAL if k in raw}
+    rank = raw.get("mamba_dt_rank", "auto")
+    if rank != "auto":
+        if not isinstance(rank, int) or isinstance(rank, bool) or rank <= 0:
+            raise ValueError(f"mamba_dt_rank: {rank!r} is neither 'auto' nor an int > 0")
+        extra["mamba_dt_rank"] = rank
+    return LLaMAConfig(
+        **{ours: raw[theirs] for theirs, ours in fields.items()}, **extra,
+        dtype=raw["torch_dtype"], param_dtype=raw["torch_dtype"],
+        max_seq_len=max_seq_len, attn_impl=attn_impl,
+    )
 _LAYER_TYPES = ("sliding_attention", "full_attention")
 
 
@@ -377,10 +548,14 @@ def from_published(raw, *, max_seq_len: int, attn_impl: str) -> LLaMAConfig:
     `ValueError` naming the key that stands in the way.
     `max_position_embeddings` is accepted and unused: a server serves at its
     own `max_seq_len`.  A file with `kv_lora_rank` is the deepseek_v3 block,
-    one with `layer_types` the afmoe block; every other file is the dense
-    block."""
+    one with `layer_types` the afmoe block, one with `mb_per_layer` the
+    phi4flash block; every other file is the dense block."""
     latent = "kv_lora_rank" in raw
     windowed = "layer_types" in raw
+    if "mb_per_layer" in raw or raw.get("model_type") == "phi4flash":
+        if latent or windowed:
+            raise ValueError("mb_per_layer beside kv_lora_rank / layer_types: two blocks in one file")
+        return _from_published_recurrent(raw, max_seq_len=max_seq_len, attn_impl=attn_impl)
     if latent and windowed:
         raise ValueError("layer_types beside kv_lora_rank: two blocks in one file")
     fields = dict(_PUBLISHED, **(_PUBLISHED_LATENT if latent else {}),

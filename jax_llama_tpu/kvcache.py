@@ -366,11 +366,17 @@ class MatchResult:
     path:    the full reachable node path (radix only; includes
              host-resident nodes past ``blocks``' depth).
     restore: the host-resident nodes on ``path`` needing swap-in before
-             the whole path is claimable (empty = plain hit)."""
+             the whole path is claimable (empty = plain hit).
+    snap:    a store that keeps STATE SNAPSHOTS (a configuration with
+             recurrent state layers) ends ``blocks`` at the deepest
+             resident node that carries one — its id, or None — and
+    cut:     counts the resident blocks behind it that the match gave up."""
 
     blocks: List[int]
     path: List["RadixNode"]
     restore: List["RadixNode"]
+    snap: Optional[int] = None
+    cut: int = 0
 
 
 # ---------------------------------------------------------------------------
@@ -440,7 +446,7 @@ class RadixNode:
 
     __slots__ = (
         "key", "parent", "children", "block", "host", "depth",
-        "restoring",
+        "restoring", "snap",
     )
 
     def __init__(self, key: bytes, parent: Optional["RadixNode"],
@@ -452,6 +458,10 @@ class RadixNode:
         self.host: Optional[Dict[str, np.ndarray]] = None
         self.depth = depth
         self.restoring = False
+        # Id of the recurrent-state snapshot taken at this block's END
+        # (``enable_snapshots`` stores only): a prefix hit on a model with
+        # recurrent state layers can resume here and nowhere between.
+        self.snap: Optional[int] = None
 
     @property
     def reachable(self) -> bool:
@@ -505,10 +515,62 @@ class RadixPrefixStore:
         # archaeology.  Pure host bookkeeping, never on the decode hot
         # path.
         self._on_event = on_event
+        # Recurrent-state snapshots (``enable_snapshots``): ids into a pool
+        # of its own beside the blocks, hung on nodes, with an LRU of their
+        # own (front = evict first) — a node that loses its snapshot still
+        # serves as K/V on the path of a deeper one.
+        self.snapshots = False
+        self._snap_free: List[int] = []
+        self._snap_lru: "OrderedDict[bytes, RadixNode]" = OrderedDict()
+        self.snapshots_evicted_total = 0
 
     def _event(self, name: str, **fields) -> None:
         if self._on_event is not None:
             self._on_event(name, **fields)
+
+    # -- recurrent-state snapshots -------------------------------------------
+
+    def enable_snapshots(self, n: int) -> None:
+        """Match only up to nodes that carry a state snapshot, out of a
+        pool of ``n`` ids this store hands out and takes back."""
+        self.snapshots = True
+        self._snap_free = list(range(n))
+
+    def snapshots_in_use(self) -> int:
+        return len(self._snap_lru)
+
+    def alloc_snapshot(self) -> Optional[int]:
+        """An id to write a snapshot into: a free one, else the least
+        recently used one's (its node keeps its block); None with no pool."""
+        if self._snap_free:
+            return self._snap_free.pop()
+        if not self._snap_lru:
+            return None
+        _, node = self._snap_lru.popitem(last=False)
+        sid, node.snap = node.snap, None
+        self.snapshots_evicted_total += 1
+        self._event("snapshot_evict", depth=node.depth)
+        return sid
+
+    def release_snapshot(self, sid: int) -> None:
+        """Hand back an id that hangs on no node."""
+        self._snap_free.append(sid)
+
+    def attach_snapshot(self, key: bytes, sid: int) -> bool:
+        """Hang snapshot ``sid`` on the resident node ``key``; False (the
+        caller keeps the id) if there is none or it has one already."""
+        node = self._by_key.get(key)
+        if node is None or node.block is None or node.snap is not None:
+            return False
+        node.snap = sid
+        self._snap_lru[key] = node
+        return True
+
+    def _drop_snapshot(self, node: RadixNode) -> None:
+        if node.snap is not None:
+            self._snap_lru.pop(node.key, None)
+            self._snap_free.append(node.snap)
+            node.snap = None
 
     # -- matching / publication --------------------------------------------
 
@@ -527,7 +589,21 @@ class RadixPrefixStore:
                 break
             blocks.append(n.block)
         restore = [n for n in path if n.block is None]
-        return MatchResult(blocks=blocks, path=path, restore=restore)
+        if not self.snapshots:
+            return MatchResult(blocks=blocks, path=path, restore=restore)
+        # A hit resumes a recurrent state, so it ends where one was kept.
+        keep = max(
+            (i + 1 for i in range(len(blocks)) if path[i].snap is not None),
+            default=0,
+        )
+        snap = None
+        if keep:
+            snap = path[keep - 1].snap
+            self._snap_lru.move_to_end(path[keep - 1].key)
+        return MatchResult(
+            blocks=blocks[:keep], path=path[:keep], restore=[], snap=snap,
+            cut=len(blocks) - keep,
+        )
 
     def publish(self, keys: Sequence[bytes],
                 blocks: Sequence[int]) -> List[int]:
@@ -578,6 +654,7 @@ class RadixPrefixStore:
             stack.extend(n.children.values())
             self._by_key.pop(n.key, None)
             self.digest.on_remove(n.key)
+            self._drop_snapshot(n)
             if n.block is not None:
                 if self._by_block.get(n.block) is n:
                     del self._by_block[n.block]
@@ -670,6 +747,7 @@ class RadixPrefixStore:
         del self._by_block[blk]
         node.block = None
         node.host = slab
+        self._drop_snapshot(node)  # the state does not demote with its node
         self.digest.on_demote(key)
         self._event("kv_demote", block=blk, depth=node.depth)
         return blk

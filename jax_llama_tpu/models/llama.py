@@ -117,7 +117,8 @@ def qeinsum(
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["k", "v", "pos", "index", "k_scale", "v_scale", "stats"],
+    data_fields=["k", "v", "pos", "index", "k_scale", "v_scale", "stats",
+                 "conv", "ssm"],
     meta_fields=[],
 )
 @dataclasses.dataclass
@@ -142,6 +143,13 @@ class KVCache:
     beside the rotated shared key, and ``v`` is None — nothing per head.
     stats: [ops.moe.N_STATS] int32 routing counts a forward adds to (routed
            experts only; None otherwise).
+
+    ... and by the per-ROW state it has.  Recurrent state layers
+    (``config.recurrent_state``, models/sambay.py) keep K/V planes for the
+    ``config.cache_layers`` layers that own keys (``L`` above), and beside
+    them ``conv`` [Ls, B, 3 * Di] (the mixers' last conv inputs) and ``ssm``
+    [Ls, B, N, Di] float32 (their state-space state): fixed-size, advanced by
+    a row's live tokens only, never paged.  None for every other block.
     """
 
     k: jnp.ndarray
@@ -151,6 +159,8 @@ class KVCache:
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
     stats: Optional[jnp.ndarray] = None
+    conv: Optional[jnp.ndarray] = None
+    ssm: Optional[jnp.ndarray] = None
 
     @property
     def max_len(self) -> int:
@@ -172,6 +182,7 @@ class KVCache:
     jax.tree_util.register_dataclass,
     data_fields=[
         "k", "v", "pos", "table", "fill", "k_scale", "v_scale", "stats",
+        "conv", "ssm",
     ],
     meta_fields=[],
 )
@@ -195,7 +206,8 @@ class PagedKVCache:
     k_scale, v_scale: [L, KVH, NB, BLK] fp32 per-slot-per-head dequant
            scales (int8 pool only; None otherwise) — folded in-kernel.
     Latent attention: ``k`` is the one latent plane [L, 1, NB, BLK, w] and
-    ``v`` is None; ``stats`` as in ``KVCache``.
+    ``v`` is None; ``stats`` as in ``KVCache``.  Recurrent state layers:
+    ``conv`` / ``ssm`` as in ``KVCache``, a row of them a table row.
     """
 
     k: jnp.ndarray
@@ -206,6 +218,8 @@ class PagedKVCache:
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
     stats: Optional[jnp.ndarray] = None
+    conv: Optional[jnp.ndarray] = None
+    ssm: Optional[jnp.ndarray] = None
 
     @property
     def n_blocks(self) -> int:
@@ -526,7 +540,9 @@ def cache_stats_zero(config: LLaMAConfig) -> Optional[jnp.ndarray]:
     (``ops.moe.STATS``) of a block with routed experts, then the window
     block's attention step counts (``afmoe.ATTN_STATS``); None for the
     dense block, which counts nothing on the device."""
-    if config.windowed_attention:
+    if config.windowed_attention or config.recurrent_state:
+        # The recurrent block counts its attention steps in the window
+        # block's layout; its routing counts stay zero.
         from .afmoe import N_STATS
 
         return jnp.zeros((N_STATS,), jnp.int32)
@@ -548,11 +564,17 @@ def init_cache(
     int8_kv = config.kv_cache_dtype == "int8" and dtype is None
     dtype = jnp.int8 if int8_kv else (dtype or config.activation_dtype)
     shape = (
-        config.n_layers, batch, max_len, config.cache_heads,
+        config.cache_layers, batch, max_len, config.cache_heads,
         config.cache_width,
     )
     latent = config.latent_attention
+    state = {}
+    if config.recurrent_state:
+        from .sambay import init_state
+
+        state["conv"], state["ssm"] = init_state(config, batch)
     return KVCache(
+        **state,
         k=jnp.zeros(shape, dtype=dtype),
         v=None if latent else jnp.zeros(shape, dtype=dtype),
         stats=cache_stats_zero(config),
@@ -580,6 +602,10 @@ def init_params(rng: jax.Array, config: LLaMAConfig) -> Params:
         from . import afmoe
 
         return afmoe.init_params(rng, config)
+    if config.recurrent_state:
+        from . import sambay
+
+        return sambay.init_params(rng, config)
     D, H, KVH, hd, F, V, L = (
         config.dim, config.n_heads, config.kv_heads, config.head_dim,
         config.ffn_dim, config.vocab_size, config.n_layers,
@@ -1092,13 +1118,16 @@ def forward(
       flag is set, a third ``AuxOutput`` element is appended:
       (logits, cache, aux).
     """
-    if config.latent_attention or config.windowed_attention:
+    if (config.latent_attention or config.windowed_attention
+            or config.recurrent_state):
         # The block follows from the configuration: latent attention over
-        # a latent cache, or window and full attention layers over the
-        # K/V cache; routed experts behind leading dense layers.
-        from . import afmoe, mla_moe
+        # a latent cache, window and full attention layers over the K/V
+        # cache (routed experts behind leading dense layers), or recurrent
+        # state layers beside window / full / cross attention.
+        from . import afmoe, mla_moe, sambay
 
-        block = mla_moe if config.latent_attention else afmoe
+        block = (sambay if config.recurrent_state
+                 else mla_moe if config.latent_attention else afmoe)
         return block.forward(
             params, tokens, positions, config, cache=cache,
             attn_mask=attn_mask, compute_logits=compute_logits,

@@ -449,6 +449,20 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "Live grid steps of the paged decode kernel in full "
                    "attention layers, summed over rows, layers and "
                    "iterations"),
+    # -- recurrent state layers (models/sambay.py; zero without) ------------
+    "ssm_snapshots_taken_total": _reg(
+        "counter", "Recurrent-state snapshots copied out at a block "
+                   "boundary of a prompt and hung on its radix node"),
+    "ssm_snapshots_restored_total": _reg(
+        "counter", "Prefix hits that resumed from a state snapshot"),
+    "ssm_snapshots_evicted_total": _reg(
+        "counter", "State snapshots taken from their radix node for the "
+                   "snapshot pool's room (the node keeps its block)"),
+    "ssm_match_tokens_cut_total": _reg(
+        "counter", "Cached prompt tokens a prefix match gave up because "
+                   "no state snapshot stood behind them"),
+    "ssm_snapshots_in_use": _reg(
+        "gauge", "State snapshots hung on radix nodes"),
     "decode_stall_ms_total": _reg(
         "counter", "Wall time classic whole-prompt admissions stalled "
                    "decoding rows (ms)"),
@@ -1329,6 +1343,7 @@ class Observability:
         prefill_ctx: Optional[Tuple[int, int]] = None,
         prefill_write: Optional[Dict[str, int]] = None,
         queued: Optional[int] = None,
+        ssm: Optional[Dict[str, int]] = None,
     ) -> int:
         """Record one jitted serving dispatch and link it into the
         CURRENT span of every request that rode it.  Returns the
@@ -1355,6 +1370,8 @@ class Observability:
         lane, ``{"pairs": n}`` token slots from an insert.
         ``queued`` (chunk dispatches) is the batcher's queue length at the
         submit: what the dispatch's K was clamped for.
+        ``ssm`` (recurrent state layers, dispatches with a prefill lane) is
+        the state snapshots it moved: ``{"taken": n, "restored": n}``.
         ``then`` names the phase the loop thread is in once
         the dispatch ends (default: the one it interrupted)."""
         if kind not in DISPATCH_KINDS:
@@ -1393,6 +1410,8 @@ class Observability:
             }
         if queued is not None:
             rec["queued"] = int(queued)
+        if ssm is not None:
+            rec["ssm"] = {key: int(v) for key, v in ssm.items()}
         rec.update(gap)
         with self._lock:
             seq = self._seq

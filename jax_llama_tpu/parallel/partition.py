@@ -44,6 +44,8 @@ def param_partition_specs(
     """
     f = "fsdp" if fsdp else None
     s = "stage" if pp else None
+    if config.recurrent_state:
+        return _recurrent_block_specs(config)
     if config.expert_block:
         return _expert_block_specs(config)
     specs: Dict[str, Any] = {
@@ -76,6 +78,19 @@ def param_partition_specs(
 # latent-attention block to one chip, so it is named here and unused: experts
 # spread over chips need the token exchange this program does not have yet.
 EXPERT_AXIS = "tensor"
+
+
+def _recurrent_block_specs(config: LLaMAConfig) -> Dict[str, Any]:
+    """Specs mirroring `models.sambay.init_params`: every leaf whole on its
+    chip.  `validate_tp` holds the block to one chip, so nothing is split
+    yet: the mixers' channels and the head pairs over ``tensor`` need the
+    per-slot state and the snapshot pool split with them."""
+    import jax
+
+    from ..models.sambay import init_params
+
+    shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), config))
+    return jax.tree_util.tree_map(lambda a: P(*(None,) * a.ndim), shapes)
 
 
 def _expert_block_specs(config: LLaMAConfig) -> Dict[str, Any]:

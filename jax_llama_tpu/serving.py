@@ -236,7 +236,8 @@ from .spec_decode import (
 
 @functools.partial(
     jax.tree_util.register_dataclass,
-    data_fields=["k", "v", "pos", "k_scale", "v_scale", "stats"],
+    data_fields=["k", "v", "pos", "k_scale", "v_scale", "stats",
+                 "conv", "ssm", "snap_conv", "snap_ssm"],
     meta_fields=[],
 )
 @dataclasses.dataclass
@@ -258,6 +259,16 @@ class BlockPool:
     see blocks, not planes, and are the same for both.
     stats: [ops.moe.N_STATS] int32 routing counts since the last packed
           fetch took them (routed-expert configurations only).
+
+    ... and by the per-SLOT state beside them.  Recurrent state layers
+    (models/sambay.py) keep planes for the ``config.cache_layers`` layers
+    that own keys, and ``conv`` [Ls, n_slots, 3 * Di] / ``ssm`` [Ls, n_slots,
+    N, Di] float32: a slot's recurrent state, fixed-size, never paged,
+    advanced by its row's live tokens only.  ``snap_conv`` / ``snap_ssm``
+    [Ls, n_snap, ...] are the snapshot pool: a row's state copied out at a
+    block boundary of its prompt under an id the prefix store hangs on that
+    block's radix node, copied back in by a prefix hit that ends there.
+    None for every other block.
     """
 
     k: jnp.ndarray
@@ -266,6 +277,10 @@ class BlockPool:
     k_scale: Optional[jnp.ndarray] = None
     v_scale: Optional[jnp.ndarray] = None
     stats: Optional[jnp.ndarray] = None
+    conv: Optional[jnp.ndarray] = None
+    ssm: Optional[jnp.ndarray] = None
+    snap_conv: Optional[jnp.ndarray] = None
+    snap_ssm: Optional[jnp.ndarray] = None
 
     @property
     def n_blocks(self) -> int:
@@ -281,17 +296,32 @@ class BlockPool:
 
 
 def init_pool(
-    config: LLaMAConfig, n_blocks: int, block_size: int
+    config: LLaMAConfig, n_blocks: int, block_size: int,
+    n_slots: int = 0, n_snapshots: int = 0,
 ) -> BlockPool:
+    """``n_slots`` / ``n_snapshots`` size the per-slot state and the
+    snapshot pool of a configuration with recurrent state layers (which
+    needs the first); every other pool has neither."""
     config.validate()
     int8_kv = config.kv_cache_dtype == "int8"
     dtype = jnp.int8 if int8_kv else config.activation_dtype
     shape = (
-        config.n_layers, config.cache_heads, n_blocks, block_size,
+        config.cache_layers, config.cache_heads, n_blocks, block_size,
         config.cache_width,
     )
     latent = config.latent_attention
+    state = {}
+    if config.recurrent_state:
+        from .models.sambay import init_state
+
+        if n_slots <= 0:
+            raise ValueError(
+                "a pool for recurrent state layers needs n_slots > 0")
+        state["conv"], state["ssm"] = init_state(config, n_slots)
+        state["snap_conv"], state["snap_ssm"] = init_state(
+            config, max(1, n_snapshots))
     return BlockPool(
+        **state,
         k=jnp.zeros(shape, dtype=dtype),
         v=None if latent else jnp.zeros(shape, dtype=dtype),
         pos=jnp.full((n_blocks, block_size), -1, jnp.int32),
@@ -303,6 +333,9 @@ def init_pool(
 
 # The per-(layer, head) planes a pool may have; ``pos`` is per block only.
 _PLANES = ("k", "v", "k_scale", "v_scale")
+# State snapshots a slot (recurrent state layers): the snapshot pool holds
+# this many times n_slots.
+_SNAPSHOTS_PER_SLOT = 8
 
 
 def _map_planes(fn, pool_like, *others):
@@ -314,12 +347,41 @@ def _map_planes(fn, pool_like, *others):
     }
 
 
+_STATE = ("conv", "ssm")
+
+
+def _snapshot_rows(pool: BlockPool, ids: jnp.ndarray):
+    """(conv, ssm) [Ls, k, ...] of the snapshots ``ids`` [k]; an id < 0 is
+    no snapshot: the empty state a fresh prompt starts from."""
+    def take(a):
+        got = jnp.take(a, jnp.maximum(ids, 0), axis=1, mode="clip")
+        live = (ids >= 0).reshape((1, -1) + (1,) * (a.ndim - 2))
+        return jnp.where(live, got, jnp.zeros_like(got))
+
+    return take(pool.snap_conv), take(pool.snap_ssm)
+
+
+def _state_into_rows(pool: BlockPool, view, rows: Optional[jnp.ndarray]):
+    """{conv, ssm} of ``pool`` with the view's rows written back: every
+    slot's (``rows`` None: the view IS the slots) or the slots ``rows``
+    [k] (an index past the last slot drops: a pad row)."""
+    if pool.conv is None:
+        return {}
+    if rows is None:
+        return {"conv": view.conv, "ssm": view.ssm}
+    return {
+        n: getattr(pool, n).at[:, rows].set(getattr(view, n), mode="drop")
+        for n in _STATE
+    }
+
+
 def _gather_cache(
     pool: BlockPool,
     table: jnp.ndarray,     # [B, MB] int32 physical block ids (NB = invalid)
     n_alloc: jnp.ndarray,   # [B] int32 allocated blocks per row
     fill: jnp.ndarray,      # [B] int32 per-row write offset (tokens)
     placed: bool = False,   # pin the view's KVH axis (serving mesh)
+    state=None,             # (conv, ssm) of the B rows (recurrent layers)
 ) -> KVCache:
     """Materialize the per-row virtually-contiguous cache view.
 
@@ -345,7 +407,7 @@ def _gather_cache(
     posg = jnp.where(jnp.repeat(valid, BLK, axis=1), posg, -1)
     view = KVCache(
         **{"v": None, **_map_planes(g, pool)}, pos=posg, index=fill,
-        stats=pool.stats,
+        stats=pool.stats, **dict(zip(_STATE, state or ())),
     )
     if placed:
         # Pin the gathered view to the pool's own KV-head sharding:
@@ -367,7 +429,8 @@ def _scatter_back(
 ) -> BlockPool:
     """Write the T new entries per row from the gathered view back into
     their physical blocks.  Inactive rows and out-of-reservation columns
-    resolve to the sentinel block id and are dropped.
+    resolve to the sentinel block id and are dropped.  A view's recurrent
+    state goes back whole: its rows are the slots.
 
     The PAIR form — B*T (block, offset) pairs through
     ``paged_pool_write`` — for the writers that are per token or per
@@ -399,6 +462,7 @@ def _scatter_back(
         ),
         pos=paged_pool_write(pool.pos, npos, blk, off),
         stats=view.stats,
+        **_state_into_rows(pool, view, None),
     )
 
 
@@ -574,7 +638,10 @@ def _decode_step_core(
         )
         pool = _cache_into_pool(pool, pcache)
     else:
-        view = _gather_cache(pool, table, n_alloc, fill, placed=placed)
+        view = _gather_cache(
+            pool, table, n_alloc, fill, placed=placed,
+            state=None if pool.conv is None else (pool.conv, pool.ssm),
+        )
         logits, view = forward(
             params, tau[:, None], positions, config, cache=view,
             attn_mask=active[:, None],
@@ -820,7 +887,8 @@ def _chunk_scan(
 def _fused_chunk(
     params, pool, table, n_alloc, fill, tau, tau_lp, pos, active,
     remaining, stops, keys, temperature, top_p, top_k,
-    pf_row, pf_toks, pf_len, pf_base, pf_off, pf_key, *,
+    pf_row, pf_toks, pf_len, pf_base, pf_off, pf_key,
+    pf_snap_in=None, pf_snap_out=None, *,
     config, n_iter, pf_chunk, all_greedy=False, mesh=None,
     allow_kernel=True, with_logprobs=False, placed=False,
 ):
@@ -865,6 +933,14 @@ def _fused_chunk(
     prefill costs zero per-chunk host->device transfers beyond the
     dispatch itself.
 
+    Recurrent state layers (``pool.conv`` / ``pool.ssm``): the prefilling
+    row's state enters the chunk and leaves it in its slot.  The walk's
+    FIRST chunk (``pf_off`` 0) starts from snapshot ``pf_snap_in`` — the
+    prefix hit's, or the empty state with id -1 — and a chunk's end state
+    is copied into snapshot ``pf_snap_out`` (-1: none; the host asks for
+    one when the chunk ends on a block boundary of the prompt).  Both are
+    None for every other block.
+
     Returns ``_chunk_scan``'s tuple + the advanced ``pf_off``.
     """
     with use_mesh(mesh):
@@ -875,8 +951,19 @@ def _fused_chunk(
         table_r = lax.dynamic_slice_in_dim(table, pf_row, 1, axis=0)
         n_alloc_r = lax.dynamic_slice_in_dim(n_alloc, pf_row, 1, axis=0)
         write_at = (pf_base + pf_off).astype(jnp.int32)
+        state = None
+        if pool.conv is not None:
+            state = tuple(
+                jnp.where(
+                    pf_off == 0, start,
+                    lax.dynamic_slice_in_dim(held, pf_row, 1, axis=1))
+                for start, held in zip(
+                    _snapshot_rows(pool, pf_snap_in[None]),
+                    (pool.conv, pool.ssm))
+            )
         view = _gather_cache(
-            pool, table_r, n_alloc_r, write_at[None], placed=placed
+            pool, table_r, n_alloc_r, write_at[None], placed=placed,
+            state=state,
         )
         # Scalar index (ONE prefilling row): keeps the view off the
         # per-row-index must-xla path, so "auto" runs flash over the
@@ -900,6 +987,24 @@ def _fused_chunk(
             params, h_last[:, None], config, normed=True
         )[:, 0]
         pool = _land_chunk(pool, view, table_r, write_at, C)
+        if pool.conv is not None:
+            # The chunk's end state into the row's slot, and into snapshot
+            # ``pf_snap_out``; with no snapshot asked for, the slab at the
+            # (clamped) id is written back as read.
+            keep, at = pf_snap_out >= 0, jnp.maximum(pf_snap_out, 0)
+            put = lax.dynamic_update_slice_in_dim
+            pool = dataclasses.replace(
+                pool,
+                **{n: put(getattr(pool, n), getattr(view, n), pf_row, axis=1)
+                   for n in _STATE},
+                **{"snap_" + n: put(
+                    snaps,
+                    jnp.where(keep, getattr(view, n),
+                              lax.dynamic_slice_in_dim(snaps, at, 1, axis=1)),
+                    at, axis=1)
+                   for n, snaps in (("conv", pool.snap_conv),
+                                    ("ssm", pool.snap_ssm))},
+            )
         # The admission sample — only persisted below when the prompt
         # completes this dispatch (the split/sample topology is exactly
         # _paged_insert's, so the row's stream is bit-identical to the
@@ -973,7 +1078,7 @@ def _token_logprob(logits: jnp.ndarray, tok: jnp.ndarray) -> jnp.ndarray:
 )
 def _paged_insert(
     params, pool, block_ids, prompt_tokens, prompt_mask, keys,
-    temperature, top_p, top_k, *,
+    temperature, top_p, top_k, state_rows=None, *,
     config, prefill_chunk=None, mesh=None, with_logprobs=False,
     placed=False,
 ):
@@ -994,6 +1099,9 @@ def _paged_insert(
     every P_b are block multiples, so the alignment is exact).
     Inactive (padding) rows, if any, carry all-sentinel block_ids and an
     all-False mask.
+    state_rows: [k] the rows' slots (recurrent state layers only; a pad
+    row carries n_slots, which drops): each row's state after its last
+    real token lands in its slot.  This path takes no snapshots.
     Returns (sampled tokens [k], their model logprobs [k], prompt
     lengths [k], carried keys [k, 2], updated pool).
     """
@@ -1062,6 +1170,7 @@ def _paged_insert(
                 sub.pos.reshape(k_rows, nb, BLK), mode="drop"
             ),
             stats=_add_stats(pool.stats, sub.stats),
+            **_state_into_rows(pool, sub, state_rows),
         )
         # Serving-mesh placement: the donated pool leaves the insert
         # with the same canonical sharding it arrived with (``placed``
@@ -1101,6 +1210,8 @@ def _paged_suffix_insert(
     table_row: [k, MB]; n_alloc_row, fill0: [k] int32 (fill0 = shared
     prefix length in tokens, a block multiple); suffix_tokens/mask:
     [k, T] right-padded to a block multiple.
+    Never dispatched for recurrent state layers: their prefix hits end at
+    state snapshots, which only the fused lane takes and restores.
     Returns (tau [k], tau logprobs, carried keys, updated pool).
     """
     with use_mesh(mesh):
@@ -1165,6 +1276,7 @@ def _pool_as_cache(pool: BlockPool, table, fill) -> PagedKVCache:
     return PagedKVCache(
         k=pool.k, v=pool.v, pos=pool.pos, table=table, fill=fill,
         k_scale=pool.k_scale, v_scale=pool.v_scale, stats=pool.stats,
+        conv=pool.conv, ssm=pool.ssm,
     )
 
 
@@ -1172,13 +1284,14 @@ def _cache_into_pool(pool: BlockPool, pcache: PagedKVCache) -> BlockPool:
     return dataclasses.replace(
         pool, k=pcache.k, v=pcache.v, pos=pcache.pos,
         k_scale=pcache.k_scale, v_scale=pcache.v_scale, stats=pcache.stats,
+        conv=pcache.conv, ssm=pcache.ssm,
     )
 
 
 def _refuse_block_extras(params, draft_params, mesh, block: str) -> None:
     """What a block beside the dense one (``block``: latent attention,
-    window attention layers) does not get yet is refused at server start,
-    by name, never served wrongly."""
+    window attention layers, recurrent state layers) does not get yet is
+    refused at server start, by name, never served wrongly."""
     from .ops.quant import QuantizedTensor
 
     if draft_params is not None:
@@ -1190,8 +1303,7 @@ def _refuse_block_extras(params, draft_params, mesh, block: str) -> None:
             params, is_leaf=lambda x: isinstance(x, QuantizedTensor))
     ):
         raise ValueError(
-            f"--quantize (int8 weights) is not supported with {block} and "
-            "routed experts"
+            f"--quantize (int8 weights) is not supported with {block}"
         )
     if mesh is not None and any(n > 1 for n in mesh.shape.values()):
         raise ValueError(
@@ -1755,6 +1867,11 @@ class _Prefill:
     d_base: Any = None    # int32 scalar
     d_len: Any = None     # int32 scalar
     d_key: Any = None     # [2] uint32 request key (chain start)
+    # Recurrent state layers: the snapshot the walk starts from (-1: the
+    # empty state) and the (depth in blocks, id) snapshots its chunks have
+    # taken, hung on the chain's nodes once it is published.
+    snap_in: int = -1
+    snaps: List[Tuple[int, int]] = dataclasses.field(default_factory=list)
 
     @property
     def remaining_tokens(self) -> int:
@@ -1998,7 +2115,28 @@ class ContinuousBatcher:
         self.top_k = 0 if top_k is None else int(top_k)
         self.prefill_chunk = prefill_chunk
         self.seed = seed
-        self.pool = init_pool(self.config, self.n_blocks, self.block_size)
+        # Recurrent state layers: a per-slot state beside the pool and a
+        # pool of state snapshots under the radix store, eight a slot (a
+        # 4,096-token row at a 512-token chunk), none without the store.
+        self.recurrent = config.recurrent_state
+        if self.recurrent and host_kv_blocks > 0:
+            raise ValueError(
+                "--host-kv-blocks (the host tier) is not supported with "
+                f"{config.expert_block}: a demoted node's state snapshot "
+                "does not demote with it")
+        if self.recurrent and prefix_cache and prefix_index == "exact":
+            raise ValueError(
+                "--prefix-index exact is not supported with "
+                f"{config.expert_block}: state snapshots hang on radix nodes")
+        self.n_snapshots = (
+            _SNAPSHOTS_PER_SLOT * n_slots
+            if self.recurrent and prefix_cache and prefix_index == "radix"
+            else 0
+        )
+        self.pool = init_pool(
+            self.config, self.n_blocks, self.block_size,
+            n_slots=n_slots, n_snapshots=self.n_snapshots,
+        )
         self.draft_pool = (
             init_pool(self.draft_config, self.n_blocks, self.block_size)
             if self.spec else None
@@ -2070,6 +2208,14 @@ class ContinuousBatcher:
         # registered), making it the one piece of KV state that is
         # legitimately cross-thread.
         self.kv_digest = self._store.digest
+        if self.n_snapshots:
+            self._store.enable_snapshots(self.n_snapshots)
+        # Recurrent-state snapshots: taken (a chunk's end state copied
+        # out), restored (a prefix hit resumed from one), and the prompt
+        # tokens a match gave up because no snapshot stood behind them.
+        self.ssm_snapshots_taken_total = 0
+        self.ssm_snapshots_restored_total = 0
+        self.ssm_match_tokens_cut_total = 0
         # Bytes one pool block occupies (k+v+pos+scales, draft twins
         # included) — the duplicate-chain accounting unit the router's
         # fleet cache view multiplies by.  Ctor-stable.
@@ -2655,6 +2801,13 @@ class ContinuousBatcher:
             ),
             **{f"moe_{k}_total": v for k, v in self.moe_totals.items()},
             **{f"attn_{k}_total": v for k, v in self.attn_step_totals.items()},
+            "ssm_snapshots_taken_total": self.ssm_snapshots_taken_total,
+            "ssm_snapshots_restored_total": self.ssm_snapshots_restored_total,
+            "ssm_snapshots_evicted_total": getattr(
+                self._store, "snapshots_evicted_total", 0),
+            "ssm_match_tokens_cut_total": self.ssm_match_tokens_cut_total,
+            "ssm_snapshots_in_use": (
+                self._store.snapshots_in_use() if self.n_snapshots else 0),
             "fused_admissions_total": self.fused_admissions_total,
             "decode_stall_ms_total": round(self.decode_stall_ms_total, 3),
         })
@@ -2996,6 +3149,8 @@ class ContinuousBatcher:
         )
         queued = len(self.queue)
         pf_done_rid: Optional[int] = None
+        pf_ssm = None if pf is None or not self.recurrent else (
+            self._pf_snapshots(pf))
         all_greedy = bool(np.all(self.temp_arr[self.active] == 0.0))
         if pf is not None:
             # The prefilling request samples inside the program, so the
@@ -3029,7 +3184,7 @@ class ContinuousBatcher:
                 self.d_active, self.d_remaining, self.d_stops, self.keys,
                 self.d_temps, self.d_top_ps, self.d_top_ks,
                 pf.d_row, pf.d_toks, pf.d_len, pf.d_base, pf.d_off,
-                pf.d_key,
+                pf.d_key, *(pf_ssm or ((), None))[0],
                 config=self.config, n_iter=K, pf_chunk=pf.chunk,
                 all_greedy=all_greedy, mesh=self.mesh,
                 allow_kernel=self.use_pallas_kernel,
@@ -3063,6 +3218,7 @@ class ContinuousBatcher:
                 self._register_chain(
                     slot.blocks[: len(pf.chain)], pf.chain,
                 )
+                self._hang_snapshots(pf)
                 pf_done_rid = pf.req.rid
                 self._pf = None
         # THE one device->host sync of the chunk: tokens (+ bitcast
@@ -3098,6 +3254,7 @@ class ContinuousBatcher:
             prefill_ctx=pf_ctx,
             prefill_write=pf_write,
             queued=queued,
+            ssm=None if pf_ssm is None else pf_ssm[1],
         )
         if pf_done_rid is not None:
             # The prefill's last chunk linked into the prefilling span
@@ -3983,7 +4140,9 @@ class ContinuousBatcher:
             # writes land before any re-allocation of its blocks.  The
             # chain was never published (publication happens at
             # completion), so nothing to unpublish beyond _fail_slot's
-            # usual scan.
+            # usual scan.  Its chunks' state snapshots hang on no node yet.
+            for _, sid in self._pf.snaps:
+                self._store.release_snapshot(sid)
             self._pf = None
         # Keyed blocks with no remaining users are RETAINED (prefix
         # cache) — their positions must stay valid for future reusers —
@@ -4256,6 +4415,17 @@ class ContinuousBatcher:
             )
             self.obs.observe_kv(hit_depth_tokens=n_share * bs)
 
+    def _state_operands(self, slots: List[int]) -> Tuple:
+        """Recurrent state layers: the whole-prompt insert's extra operand —
+        the rows' slots, padded to the admission's row bucket
+        (``_row_bucket``'s) with n_slots (which drops).  Nothing for every
+        other block."""
+        if not self.recurrent:
+            return ()
+        rows = np.full((pow2_bucket(len(slots)),), self.n_slots, np.int32)
+        rows[: len(slots)] = slots
+        return (jnp.asarray(rows),)
+
     def _fused_scheduling(self) -> bool:
         """Fused prefill-decode scheduling is in force for this batcher
         (spec batchers keep classic admission — the round program has no
@@ -4292,7 +4462,8 @@ class ContinuousBatcher:
                 if self.queue:
                     self._begin_fused_prefill()
                 return
-            # Cold pool: nobody to stall — classic batched admission.
+            # Cold pool: nobody to stall — classic batched admission (but a
+            # prefix hit on recurrent state layers: ``_admit_classic_impl``).
         self._admit_classic()
 
     # -- host-tier swap-ins (the ``restoring`` admission state) -------------
@@ -4505,14 +4676,49 @@ class ContinuousBatcher:
         held = len(self.slots[pf.slot].blocks)
         return max(0, min(pf.chunk // bs, held - first))
 
+    def _pf_snapshots(self, pf: _Prefill):
+        """Recurrent state layers: the snapshot operands of the fused
+        dispatch about to advance ``pf`` and its record's ``ssm`` field.
+        The walk's first chunk starts from ``pf.snap_in``; a chunk whose
+        tokens are all the prompt's (so it ends on a block boundary the
+        chain holds) has its end state copied out under a fresh id, hung on
+        that block's node once the chain is published."""
+        out = -1
+        end = pf.off + pf.chunk
+        # Blocks the chain keys: those strictly before the last token.
+        depth = (pf.base + end) // self.block_size
+        if self.n_snapshots and end <= pf.suffix_len and depth <= len(pf.chain):
+            sid = self._store.alloc_snapshot()
+            if sid is not None:
+                out = sid
+                pf.snaps.append((depth, sid))
+        restored = pf.off == 0 and pf.snap_in >= 0
+        self.ssm_snapshots_restored_total += restored
+        # audit: host-upload(two int32 scalars beside the dispatch: which
+        # snapshot the chunk starts from and which it leaves; no state
+        # array crosses the host)
+        ops = (jnp.asarray(np.int32(pf.snap_in)), jnp.asarray(np.int32(out)))
+        return ops, {"taken": int(out >= 0), "restored": int(restored)}
+
+    def _hang_snapshots(self, pf: _Prefill) -> None:
+        """The finished walk's snapshots onto its (now published) chain's
+        nodes; one whose node has a snapshot already, or lost its block,
+        goes back to the pool."""
+        for depth, sid in pf.snaps:
+            if self._store.attach_snapshot(pf.chain[depth - 1], sid):
+                self.ssm_snapshots_taken_total += 1
+            else:
+                self._store.release_snapshot(sid)
+        pf.snaps = []
+
     def _pf_chunk(self, suffix_len: int, n_share: int) -> int:
         """Prompt tokens per fused dispatch: ``prefill_budget`` rounded
         DOWN to a pow2 block count (jit-cache discipline that still
         honors the flag as an upper bound — rounding up would let a
         640-token budget ride 1024 tokens of prefill per dispatch,
         inflating exactly the per-dispatch ITL the flag caps; the floor
-        is one block), clamped to the suffix's own pow2 bucket, then
-        halved until the LAST chunk's write window fits the row's
+        is one block), clamped to the suffix's own pow2 bucket (not for
+        recurrent state layers: below), then halved until the LAST chunk's write window fits the row's
         remaining gathered-view columns — the ``_suffix_pad`` clamp
         hazard: the in-forward cache write is a scalar-start
         dynamic-update that would silently clamp and scribble over the
@@ -4522,7 +4728,12 @@ class ContinuousBatcher:
         nbb = max(1, self.prefill_budget // bs)
         nbb = 1 << (nbb.bit_length() - 1)
         nbs = pow2_bucket(max(1, -(-suffix_len // bs)))
-        c_blocks = min(nbb, nbs)
+        # Recurrent state layers keep the whole budget for a short suffix
+        # too: a walk's chunk ends are where its state snapshots stand, so
+        # they stay on one grid (the hit + multiples of the budget), and a
+        # length class that one request in a hundred falls in gets no
+        # program variant of its own for a warm-up to miss.
+        c_blocks = nbb if self.recurrent else min(nbb, nbs)
         view_blocks = self.blocks_per_slot - n_share
         while c_blocks > 1 and (
             -(-suffix_len // (c_blocks * bs)) * c_blocks > view_blocks
@@ -4563,12 +4774,16 @@ class ContinuousBatcher:
                 self._begin_restore(req, chain, m)
                 continue
             del self.queue[0]
-            self._setup_fused_prefill(req, chain, m.blocks, claimed=False)
+            self.ssm_match_tokens_cut_total += m.cut * self.block_size
+            self._setup_fused_prefill(
+                req, chain, m.blocks, claimed=False,
+                snap_in=-1 if m.snap is None else m.snap,
+            )
             return
 
     def _setup_fused_prefill(
         self, req: "_Request", chain: List[bytes], hits: List[int],
-        claimed: bool = False,
+        claimed: bool = False, snap_in: int = -1,
     ) -> None:
         """The ``prefilling``-state setup shared by fresh admissions and
         completed swap-ins (``claimed=True``: the hit blocks were
@@ -4619,6 +4834,7 @@ class ContinuousBatcher:
             d_base=jnp.asarray(np.int32(base)),
             d_len=jnp.asarray(np.int32(len(suffix))),
             d_key=jnp.asarray(self._request_key(req)),
+            snap_in=snap_in,
         )
         self.fused_admissions_total += 1
         self.prompt_tokens_total += len(req.tokens)
@@ -4703,6 +4919,7 @@ class ContinuousBatcher:
                     del self.queue[0]
                     self._begin_restore(req, chain0, m0)
             picked: List[Tuple[_Request, List[bytes], List[int]]] = []
+            lane = False
             budget = self._capacity()
             for req in self.queue:
                 if len(picked) >= len(free_slots):
@@ -4720,12 +4937,27 @@ class ContinuousBatcher:
                         self._chain_keys(req.tokens, self.block_size)
                         if self.prefix_cache_enabled else []
                     )
-                    hits = self._match_prefix(chain).blocks
+                    m = self._match_prefix(chain)
+                    hits = m.blocks
+                    if hits and self.recurrent:
+                        # A hit on recurrent state layers resumes from a
+                        # state snapshot, which only the fused lane takes
+                        # and restores (``_paged_suffix_insert`` is never
+                        # dispatched for them: its wide scatter would need
+                        # pool-sized temporaries beside a pool their cells
+                        # fill the chip with).  It waits for the lane: now,
+                        # if it heads the queue, else behind the rows this
+                        # round admits.
+                        lane = True
+                        break
+                    self.ssm_match_tokens_cut_total += m.cut * self.block_size
                 # Claim hits at SELECTION time: a later allocation in
                 # this same admission round must not evict them.
                 self._claim_blocks(hits)
                 picked.append((req, chain, hits))
             if not picked:
+                if lane:
+                    self._begin_fused_prefill()
                 return
             del self.queue[:len(picked)]
             slot_iter = iter(free_slots)
@@ -4817,6 +5049,7 @@ class ContinuousBatcher:
             if flash:
                 self._fault("flash_kernel")
             self._admit_dispatches += 1
+            slot_ids = [next(slot_iter) for _ in range(k)]
             taus, tau_lps, plens, keys_out, self.pool = _paged_insert(
                 # audit: host-upload(admission-time prompt/state upload
                 # for the whole batch — once per admission round, never
@@ -4824,7 +5057,7 @@ class ContinuousBatcher:
                 self.params, self.pool, jnp.asarray(bid),
                 jnp.asarray(pt), jnp.asarray(pm), jnp.asarray(keys),
                 jnp.asarray(temps), jnp.asarray(top_ps),
-                jnp.asarray(top_ks),
+                jnp.asarray(top_ks), *self._state_operands(slot_ids),
                 config=self.config, prefill_chunk=self.prefill_chunk,
                 mesh=self.mesh, with_logprobs=self.logprobs,
                 placed=self._mesh_placed,
@@ -4846,7 +5079,6 @@ class ContinuousBatcher:
                     prefill_chunk=self.prefill_chunk, mesh=self.mesh,
                     placed=self._mesh_placed,
                 )
-            slot_ids = [next(slot_iter) for _ in range(k)]
             # audit: host-upload(slot-index upload, once per admission)
             idx = jnp.asarray(np.asarray(slot_ids, np.int32))
             self.tau = self.tau.at[idx].set(taus[:k])

@@ -164,9 +164,9 @@ def train_step(
 
     if config.expert_block:
         raise NotImplementedError(
-            f"the training step is not supported with {config.expert_block} and "
-            "routed experts: the block is served, not trained (no router "
-            "balance loss, no grouped-matmul gradient)"
+            f"the training step is not supported with {config.expert_block}: "
+            "the block is served, not trained (no router balance loss, no "
+            "grouped-matmul gradient, no gradient of the scan kernel)"
         )
     if mesh is None and current_mesh() is not None:
         # Entering use_mesh(None) here would silently disable every

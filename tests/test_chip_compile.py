@@ -350,3 +350,90 @@ def test_paged_window_decode_compiles_for_v5e(sds, t_tokens):
     _assert_mosaic(jax.jit(attend).lower(
         sds((rows, kvh, t_tokens * 8, D), jnp.bfloat16), pool, pool, pos, table,
         q_pos, sds((), jnp.int32), window))
+
+
+# --- the recurrent block at its cell's shapes (phi4flash-reason-sessions) ----
+
+def _recurrent_cell(sds, monkeypatch, layers):
+    """(config, params, pool) shapes of the cell on a described chip: every
+    published width, 24 slots x 4096 over 128-token blocks (768 blocks),
+    192 snapshots; depth `layers`.  The kernels compile, not interpret."""
+    import json
+    from pathlib import Path
+
+    from jax_llama_tpu import config as config_mod, init_params, serving
+
+    for name in ("flash_attention", "paged_attention", "ssm"):
+        monkeypatch.setattr(
+            importlib.import_module(f"jax_llama_tpu.ops.{name}"),
+            "_resolve_interpret", lambda _=None: False)
+    raw = json.loads((Path(__file__).resolve().parent.parent / "benchmark" / "configs"
+                      / "Phi-4-mini-flash-reasoning.json").read_text())
+    keys = {k: v for k, v in raw.items() if k not in (
+        "source", "architecture", "reference", "reduced", "assumed", "deployment")}
+    cfg = config_mod.from_published(
+        dict(keys, num_hidden_layers=layers), max_seq_len=4096, attn_impl="auto")
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: sds(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pool = place(jax.eval_shape(
+        lambda: serving.init_pool(cfg, 768, 128, n_slots=24, n_snapshots=192)))
+    assert pool.k.shape == (layers // 4 + 1, 10, 768, 128, 128)
+    assert pool.ssm.shape == (layers // 4 + 1, 24, 16, 5120)
+    return cfg, params, pool
+
+
+def _state_temporaries(text, tokens):
+    """Lines of a compiled program that hold the recurrence a token: a
+    [T, N, Di] / [T, Di, N] (or channel-folded) array of the chunk's
+    tokens, which the scan kernel exists never to materialise."""
+    import re
+
+    shapes = (rf"{tokens},16,5120", rf"{tokens},5120,16", rf"{tokens},16,40,128")
+    return [line.strip()[:140] for line in text.splitlines()
+            if re.search(rf"\[(1,)?({'|'.join(shapes)})\]", line)]
+
+
+def test_recurrent_fused_chunk_for_v5e(sds, monkeypatch):
+    """`_fused_chunk` at the cell's widths (depth 8: two mixer + window pairs,
+    the publishing pair, one memory unit + cross pair), `pf_chunk` 512, 8
+    decode iterations: the flash, paged and scan kernels are in the program,
+    no pool-sized copy and no [T, N, Di] temporary stand in it."""
+    from test_serving_fused import fused_chunk_operand_shapes
+    from test_tpu_compiled import _pool_copy_offenders
+
+    from jax_llama_tpu import serving
+
+    cfg, params, pool = _recurrent_cell(sds, monkeypatch, 8)
+    lowered = serving._fused_chunk.lower(
+        params, pool, *fused_chunk_operand_shapes(sds, 24, 32, 512),
+        sds((), jnp.int32), sds((), jnp.int32),
+        config=cfg, n_iter=8, pf_chunk=512, all_greedy=True, mesh=None,
+        allow_kernel=True, with_logprobs=False,
+    )
+    assert "ssm_scan" in lowered.as_text()
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") >= 3   # flash, the paged kernel, the scan
+    offenders = _pool_copy_offenders(text, pool.k.shape)
+    assert not offenders, (len(offenders), offenders)
+    assert not _state_temporaries(text, 512)
+
+
+def test_recurrent_decode_chunk_for_v5e(sds, monkeypatch):
+    """`_paged_decode_chunk` at the cell's widths (depth 8), 8 iterations over
+    24 rows: the paged kernel is in the program and no pool-sized copy."""
+    from test_serving_fused import fused_chunk_operand_shapes
+    from test_tpu_compiled import _pool_copy_offenders
+
+    from jax_llama_tpu import serving
+
+    cfg, params, pool = _recurrent_cell(sds, monkeypatch, 8)
+    lowered = serving._paged_decode_chunk.lower(
+        params, pool, *fused_chunk_operand_shapes(sds, 24, 32, 512)[:13],
+        config=cfg, n_iter=8, all_greedy=True, mesh=None,
+        allow_kernel=True, with_logprobs=False,
+    )
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    offenders = _pool_copy_offenders(text, pool.k.shape)
+    assert not offenders, (len(offenders), offenders)
