@@ -465,6 +465,8 @@ def _make_batcher_stub():
     s.prefill_blocks_written_total = 0
     s.prefill_pairs_written_total = 0
     s.fused_dispatches_queued_total = 0
+    s.fused_dispatches_merged_total = 0
+    s.fused_merged_rows_total = 0
     s.fused_admissions_total = 0
     s.decode_stall_ms_total = 0.0
     s.prefix_index = "radix"

@@ -355,6 +355,7 @@ def paged_pool_write(
     upd: jnp.ndarray,
     blk: jnp.ndarray,
     off: jnp.ndarray,
+    rolled: bool = False,
 ) -> jnp.ndarray:
     """Land per-(row, token) pool updates via an unrolled chain of
     ``dynamic_update_slice`` ops instead of one batched scatter.
@@ -394,6 +395,15 @@ def paged_pool_write(
     one row — ``serving._fused_chunk``'s prompt chunk — takes
     :func:`paged_pool_write_blocks` and never the scatter.
 
+    ``rolled``: the same chain as a ``lax.fori_loop`` over the pairs —
+    one traced step, not B*T.  For a SECOND chain in a program that
+    already unrolls one (``mixed_forward`` beside the decode scan's body:
+    16 rows x 3 planes unrolled again cost every ``_fused_chunk`` variant
+    ~0.5 s more to trace and lower, 8 s of a cell's warm set-up; PERF.md
+    section 6, PR 37).  Compiled for a v5e it leaves no pool-sized copy
+    either (tests/test_chip_compile.py); on the device it is B*T loop
+    trips of a few microseconds, once a dispatch.
+
     plane: [L, KVH, NB, BLK, d] payload, [L, KVH, NB, BLK] scale, or
       [NB, BLK] position plane — the (NB, BLK) axes sit at (-3, -2),
       (-2, -1) and (0, 1) respectively, derived from ndim.
@@ -428,6 +438,21 @@ def paged_pool_write(
         pick = lambda b, t: upd[b, t][None, None]
     live = blk < NB  # off is always in range (contract above)
     zero = jnp.int32(0)
+    if rolled:
+        def one(i, plane):
+            b, t = i // T, i % T
+            at = lambda *bt: (
+                (zero,) * nb_ax + bt + (zero,) * (plane.ndim - nb_ax - 2)
+            )
+            start = at(blk[b, t], off[b, t])
+            cur = lax.dynamic_slice(plane, start, slab)
+            new = lax.dynamic_slice(upd, at(b, t), slab)
+            u = jnp.where(live[b, t], new.astype(plane.dtype), cur)
+            return _constrain_pool_plane(
+                lax.dynamic_update_slice(plane, u, start)
+            )
+
+        return _pin_pool_layout(lax.fori_loop(0, B * T, one, plane))
     for b in range(B):
         for t in range(T):
             start = (
@@ -798,10 +823,19 @@ def _block(
     paged_layer: Optional[jnp.ndarray] = None,
     ring_new_pos: Optional[jnp.ndarray] = None,
     output_attentions: bool = False,
+    n_riders: int = 0,
 ) -> Tuple[jnp.ndarray, ...]:
     """One pre-norm transformer block. x: [B, T, D].  ``impl`` is the
     RESOLVED attention implementation (forward maps "auto" to "flash" or
     "xla" per call based on T).
+
+    ``n_riders`` > 0 (``mixed_forward``): x is [1, T, D] and its last
+    ``n_riders`` rows are decode rows riding a prompt chunk's pass over
+    the weights.  Norms, the QKV product, rope, the output product and
+    the FFN see one activation; only attention splits — the chunk's rows
+    take ``impl`` over ``cache_k`` / ``cache_v`` as below, the riders take
+    the paged kernel over ``paged_pools`` — and the riders' projections
+    [n_riders, 1, KVH, hd] are appended to the result.
 
     Returns (x, cache_k, cache_v, cache_k_scale, cache_v_scale), plus a
     trailing [B, H, T, S] post-softmax probability array when
@@ -838,6 +872,21 @@ def _block(
 
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
+
+        if n_riders:
+            from ..ops.paged_attention import paged_decode_attention
+
+            def rows(a):  # [1, T, h, hd] -> the riders', [n_riders, 1, h, hd]
+                return jnp.swapaxes(a[:, T - n_riders:], 0, 1)
+
+            rider_k, rider_v = rows(k), rows(v)
+            rider_attn = paged_decode_attention(
+                rows(q), rider_k, rider_v, *paged_pools[:2], paged_pos,
+                paged_table, paged_qpos, layer=paged_layer,
+            )
+            q, k, v, positions = (
+                a[:, :T - n_riders] for a in (q, k, v, positions)
+            )
 
         softmax_dtype = jnp.dtype(config.attn_softmax_dtype)
         if cache_k is not None and impl == "ring_decode":
@@ -1000,6 +1049,10 @@ def _block(
                 if output_attentions:
                     attn, attn_weights = attn
 
+        if n_riders:
+            attn = jnp.concatenate(
+                [attn, jnp.swapaxes(rider_attn, 0, 1)], axis=1
+            )
         attn_out = qeinsum(attn, lp["o"], "bthk,hkd->btd", adt)
         attn_out = constrain(attn_out, "data", "seq", None)
     if dropout_rng is not None and config.resid_pdrop > 0.0:
@@ -1021,6 +1074,9 @@ def _block(
     x = x + down
     if output_attentions:
         return x, cache_k, cache_v, cache_k_scale, cache_v_scale, attn_weights
+    if n_riders:
+        return (x, cache_k, cache_v, cache_k_scale, cache_v_scale,
+                rider_k, rider_v)
     return x, cache_k, cache_v, cache_k_scale, cache_v_scale
 
 
@@ -1666,10 +1722,23 @@ def paged_forward(
 
     logits = lm_head_logits(params, x, config) if compute_logits else None
 
-    # Land the step's projections via the shared write-back contract
-    # (paged_write_indices — same function serving's gathered-view
-    # scatter uses, so the two paths cannot drift).
-    active = row_active
+    return logits, _paged_land(
+        cache, new_k, new_v, nks, nvs, row_active, positions
+    )
+
+
+def _paged_land(
+    cache: PagedKVCache, new_k, new_v, nks, nvs, active, positions,
+    rolled: bool = False,
+) -> PagedKVCache:
+    """Land a step's projections ``new_k`` / ``new_v`` [L, B, T, KVH, hd]
+    (int8 pools: payload, with scales ``nks`` / ``nvs`` [L, B, T, KVH]) and
+    its ``positions`` [B, T] in the pool via the shared write-back
+    contract (paged_write_indices — same function serving's gathered-view
+    scatter uses, so the two paths cannot drift).  Rows not ``active``
+    resolve to the sentinel block id and drop."""
+    NB, BLK = cache.pos.shape
+    T = positions.shape[1]
     blk_idx, off, _ = paged_write_indices(
         cache.table, cache.fill, active, T, NB, BLK
     )  # [B, T] each
@@ -1677,11 +1746,11 @@ def paged_forward(
     upd_v = jnp.moveaxis(new_v, 3, 1)
     new_cache = dataclasses.replace(
         cache,
-        k=paged_pool_write(cache.k, upd_k, blk_idx, off),
-        v=paged_pool_write(cache.v, upd_v, blk_idx, off),
+        k=paged_pool_write(cache.k, upd_k, blk_idx, off, rolled),
+        v=paged_pool_write(cache.v, upd_v, blk_idx, off, rolled),
         pos=paged_pool_write(
             cache.pos, jnp.where(active[:, None], positions, -1),
-            blk_idx, off,
+            blk_idx, off, rolled,
         ),
     )
     if cache.quantized:
@@ -1695,4 +1764,126 @@ def paged_forward(
                 cache.v_scale, jnp.moveaxis(nvs, 3, 1), blk_idx, off
             ),
         )
-    return logits, new_cache
+    return new_cache
+
+
+def mixed_forward(
+    params: Params,
+    tokens: jnp.ndarray,
+    positions: jnp.ndarray,
+    config: LLaMAConfig,
+    cache: KVCache,
+    attn_mask: jnp.ndarray,
+    rider_tokens: jnp.ndarray,
+    rider_positions: jnp.ndarray,
+    pool: PagedKVCache,
+) -> Tuple[jnp.ndarray, KVCache, PagedKVCache]:
+    """ONE pass over the weights for a prompt chunk and a decode step: the
+    chunk's [1, C] ``tokens`` (``positions`` / ``attn_mask`` as ``forward``
+    takes them, appended to the one row's ``cache`` at its scalar index)
+    and the B ``rider_tokens`` — one token a decode row, at
+    ``rider_positions`` [B], -1 for a row that rides masked — go through
+    the embedding and every layer as one [1, C + B, D] activation.  Only
+    attention splits (``_block``, ``n_riders``): the chunk's rows do what
+    ``forward`` does with them (flash over the cache, or the append-free
+    xla form; "auto" resolves by C), the riders what ``paged_forward``
+    does (the paged kernel over ``pool``, bound outside the layer scan,
+    and one ``paged_pool_write`` a plane after it).  The riders' blocks
+    and the chunk's row are different rows' blocks, so neither half reads
+    what the other writes.
+
+    Returns (post-final-norm hidden states [1, C + B, D] — the chunk's
+    rows, then the riders' —, the updated ``cache``, the updated ``pool``).
+    For a float pool and no sharded mesh; the head is the caller's.
+    """
+    if cache.quantized or cache.per_row_index or pool.quantized:
+        raise NotImplementedError(
+            "mixed_forward: a float cache with a scalar index and a float "
+            "pool"
+        )
+    C = tokens.shape[1]
+    B = rider_tokens.shape[0]
+    q_positions = jnp.maximum(positions, 0)
+
+    cos, sin = _rope_tables(
+        config.head_dim, max(2 * config.max_seq_len, cache.max_len),
+        config.rope_theta, config.use_scaled_rope,
+    )
+    x = jnp.take(
+        params["embed"]["embedding"],
+        jnp.concatenate([tokens, rider_tokens[None]], axis=1), axis=0,
+    ).astype(config.activation_dtype)
+
+    impl = config.attn_impl
+    if impl == "auto":
+        impl = "flash" if C > FLASH_MIN_SEQ else "xla"
+    xla_cached = impl == "xla"
+    # The chunk's masking state, as ``forward`` builds it for a cache with
+    # a scalar index.
+    new_slot_pos = jnp.where(attn_mask, q_positions, -1).astype(jnp.int32)
+    bias = bias_new = None
+    if xla_cached:
+        bias = attention_bias(q_positions, cache.pos, cache.pos >= 0)
+        bias_new = attention_bias(q_positions, new_slot_pos, attn_mask)
+    slot_pos = lax.dynamic_update_slice(
+        cache.pos, new_slot_pos, (0, cache.index)
+    )
+    block = functools.partial(
+        _block,
+        config=config,
+        positions=jnp.concatenate(
+            [q_positions, jnp.maximum(rider_positions, 0)[None]], axis=1
+        ),
+        bias=bias,
+        slot_pos=slot_pos,
+        cache_index=cache.index,
+        cos=cos,
+        sin=sin,
+        bias_new=bias_new,
+        impl=impl,
+        paged_pos=pool.pos,
+        paged_table=pool.table,
+        paged_qpos=rider_positions.astype(jnp.int32),
+        paged_pools=(pool.k, pool.v, None, None),
+        n_riders=B,
+    )
+
+    def layer(x, xs):
+        layer_params, ck, cv, li = xs
+        y, ck, cv, _, _, rk, rv = block(
+            x, layer_params, ck, cv, paged_layer=li
+        )
+        return y, (ck, cv, rk, rv)
+
+    xs = (
+        params["layers"], cache.k, cache.v,
+        jnp.arange(config.n_layers, dtype=jnp.int32),
+    )
+    if config.scan_layers:
+        x, ys = lax.scan(layer, x, xs, unroll=config.scan_unroll)
+    else:
+        per_layer = []
+        for i in range(config.n_layers):
+            x, y = layer(x, jax.tree.map(lambda a: a[i], xs))
+            per_layer.append(y)
+        ys = jax.tree.map(lambda *a: jnp.stack(a), *per_layer)
+    new_k, new_v, rider_k, rider_v = ys
+    if xla_cached:
+        # The ys are the chunk's projections [L, 1, C, KVH, hd]: one
+        # in-place write a plane, after the scan (see ``forward``).
+        new_k = lax.dynamic_update_slice(
+            cache.k, new_k.astype(cache.k.dtype), (0, 0, cache.index, 0, 0)
+        )
+        new_v = lax.dynamic_update_slice(
+            cache.v, new_v.astype(cache.v.dtype), (0, 0, cache.index, 0, 0)
+        )
+    return (
+        rms_norm(x, params["final_norm"], config.rms_norm_eps),
+        dataclasses.replace(
+            cache, k=new_k, v=new_v, pos=slot_pos, index=cache.index + C
+        ),
+        _paged_land(
+            pool, rider_k, rider_v, None, None, rider_positions >= 0,
+            rider_positions[:, None], rolled=True,
+        ),
+    )

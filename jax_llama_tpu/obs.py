@@ -429,6 +429,12 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "fused_dispatches_queued_total": _reg(
         "counter", "Of those, dispatches submitted while requests queued "
                    "for the prefill lane (they run the lane's K clamp)"),
+    "fused_dispatches_merged_total": _reg(
+        "counter", "Of those, dispatches whose first decode iteration rode "
+                   "the prompt chunk's pass over the weights"),
+    "fused_merged_rows_total": _reg(
+        "counter", "Decoding rows that rode such a pass, summed over "
+                   "those dispatches"),
     # -- routed experts (ops/moe.py; zero on a configuration without) -------
     "moe_assignments_total": _reg(
         "counter", "(token, expert) pairs the router assigned"),
@@ -1344,6 +1350,7 @@ class Observability:
         prefill_write: Optional[Dict[str, int]] = None,
         queued: Optional[int] = None,
         ssm: Optional[Dict[str, int]] = None,
+        merged_rows: Optional[int] = None,
     ) -> int:
         """Record one jitted serving dispatch and link it into the
         CURRENT span of every request that rode it.  Returns the
@@ -1372,6 +1379,9 @@ class Observability:
         submit: what the dispatch's K was clamped for.
         ``ssm`` (recurrent state layers, dispatches with a prefill lane) is
         the state snapshots it moved: ``{"taken": n, "restored": n}``.
+        ``merged_rows`` (fused dispatches that took the mixed pass, and only
+        they) is the decoding rows whose first iteration rode the prompt
+        chunk's pass over the weights.
         ``then`` names the phase the loop thread is in once
         the dispatch ends (default: the one it interrupted)."""
         if kind not in DISPATCH_KINDS:
@@ -1412,6 +1422,8 @@ class Observability:
             rec["queued"] = int(queued)
         if ssm is not None:
             rec["ssm"] = {key: int(v) for key, v in ssm.items()}
+        if merged_rows is not None:
+            rec["merged_rows"] = int(merged_rows)
         rec.update(gap)
         with self._lock:
             seq = self._seq

@@ -51,6 +51,10 @@ parity.  Design constraints, in order:
     ``llm_fused_dispatches_total`` (counters — the share of
     prompt-carrying dispatches submitted while requests queued for the
     prefill lane, which run the lane's small K),
+    ``llm_fused_dispatches_merged_total`` and
+    ``llm_fused_merged_rows_total`` (counters — prompt-carrying
+    dispatches whose first decode iteration rode the chunk's pass over
+    the weights, and the decoding rows that rode it),
     ``llm_decode_stall_ms_total`` (counter — wall time classic
     whole-prompt admission dispatches spent while rows were
     mid-decode; ≈0 once fused scheduling is on), and

@@ -88,8 +88,8 @@ TPU-native mechanics:
     ``spec_rounds``), and chunked output is token-identical to the
     classic per-round path — including the acceptance pattern and
     per-token logprobs (pinned by tests/test_serving_spec.py).
-  * **Fused prefill-decode scheduling (Sarathi-style stall-free
-    admission).**  With ``prefill_budget`` > 0 (run.py
+  * **Fused prefill-decode scheduling (stall-free admission).**  With
+    ``prefill_budget`` > 0 (run.py
     ``--prefill-budget``, on by default there) the batched-prefill
     bullet above only describes the COLD pool: once any row is
     mid-decode, an admission no longer runs as a separate whole-prompt
@@ -103,7 +103,14 @@ TPU-native mechanics:
     flight; its row rides the scan masked until the dispatch its last
     prompt chunk lands, where it samples its first token (one key
     split, exactly the classic insert's) and folds INTO the decode
-    mask mid-dispatch — first token out of the same dispatch.  Host
+    mask mid-dispatch — first token out of the same dispatch.  For
+    the dense block over the paged kernel the dispatch is a hybrid
+    batch in Sarathi's sense: the chunk's tokens and the decode rows'
+    first iteration go through ONE pass over the weights
+    (``_mixed_pass``, ``models.llama.mixed_forward``; K passes a
+    dispatch, not K + 1), and a row that folds in emits from the second
+    iteration on.  The other blocks run the chunk's pass and then the
+    K iterations' (ROADMAP A1 ports the pass to them).  Host
     boundary: the whole prefill pays ONE admission-time upload (the
     dirty-row sync + the one-off suffix/walk-scalar buffers) and the
     usual one packed fetch per chunk — no per-prefill-chunk host
@@ -209,6 +216,7 @@ from .models.llama import (
     forward,
     init_cache,
     lm_head_logits,
+    mixed_forward,
     cache_stats_zero,
     paged_pool_write,
     paged_pool_write_blocks,
@@ -617,6 +625,27 @@ def _kernel_eligible(block_size, mesh, kv_heads, n_rows, draft_config=None):
     return bool(ok)
 
 
+def _mixed_pass(config, quantized_pool, mesh, use_kernel, n_iter) -> bool:
+    """Whether a fused dispatch's first decode iteration rides its prompt
+    chunk's pass over the weights (``_fused_chunk``) — shared by the
+    program and the host's counter so the two cannot drift.  By the
+    block: the dense one (``models.llama.mixed_forward``); the others
+    keep two passes until the mechanism is ported to them (ROADMAP A1).
+    By the decode half: the paged kernel's (``use_kernel``: allowed and
+    ``_kernel_eligible``).  And by what the trace sees of the operands:
+    a float pool (an int8 pool quantizes a chunk where it lands, and the
+    two halves would do so in two places), one device (the mixed
+    activation is [1, C + B, D]: the riders have no batch axis to shard
+    over "data"), and a second iteration for a row that folds in to emit
+    its first token from this dispatch (K = 1 keeps chunk-then-emit)."""
+    return bool(
+        use_kernel and n_iter >= 2 and not quantized_pool
+        and (mesh is None or mesh.size == 1)
+        and not (config.latent_attention or config.windowed_attention
+                 or config.recurrent_state)
+    )
+
+
 def _decode_step_core(
     params, pool, table, n_alloc, fill, tau, pos, active, keys,
     temperature, top_p, top_k, *, config, all_greedy, use_kernel,
@@ -647,6 +676,21 @@ def _decode_step_core(
             attn_mask=active[:, None],
         )
         pool = _scatter_back(pool, view, table, fill, active, T=1)
+    nxt, lp, keys = _sample_step(
+        logits, keys, temperature, top_p, top_k,
+        all_greedy=all_greedy, with_logprobs=with_logprobs,
+    )
+    return nxt, lp, keys, pool
+
+
+def _sample_step(
+    logits, keys, temperature, top_p, top_k, *, all_greedy, with_logprobs,
+):
+    """A decode iteration's draw from the last of its rows' ``logits``
+    [B, T, V]: (next token [B] with the -1 non-finite sentinel folded in,
+    its model logprob or None, carried keys).  Key chains split once an
+    iteration for all B rows whatever their liveness (never under
+    ``all_greedy``)."""
     if all_greedy:
         nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
     else:
@@ -662,7 +706,7 @@ def _decode_step_core(
     # so the sentinel cannot collide).  Folding the flag into tau
     # keeps the guard free of extra device->host fetches.
     nxt = jnp.where(finite_rows(logits[:, -1]), nxt, -1)
-    return nxt, lp, keys, pool
+    return nxt, lp, keys
 
 
 @functools.partial(
@@ -791,33 +835,54 @@ def _paged_decode_chunk(
         )
 
 
+def _emit(tau, tau_lp, active, remaining, stops):
+    """The host emit scan, on device — steps 1 and 2 of an iteration
+    (``_paged_decode_chunk``): (this column's tokens [B], their logprobs,
+    ``active`` with the rows that just ended folded out, ``remaining``)."""
+    nonfinite = tau < 0
+    hit_stop = stop_token_hits(tau, stops)
+    out_tok = jnp.where(
+        active,
+        jnp.where(nonfinite, -1, tau),
+        _CHUNK_PAD,
+    ).astype(jnp.int32)
+    out_lp = tau_lp
+    done = active & (nonfinite | hit_stop | (remaining <= 1))
+    remaining = remaining - active.astype(jnp.int32)
+    active = active & ~done
+    return out_tok, out_lp, active, remaining
+
+
+def _advance(tau, tau_lp, fill, pos, active, nxt, lp):
+    """Step 3's tail: the surviving rows take their draw (``lp`` None:
+    no logprobs) and move one slot on."""
+    tau = jnp.where(active, nxt, tau)
+    if lp is not None:
+        tau_lp = jnp.where(active, lp, tau_lp)
+    return tau, tau_lp, fill + active, pos + active
+
+
 def _chunk_scan(
     params, pool, table, n_alloc, fill, tau, tau_lp, pos, active,
     remaining, stops, keys, temperature, top_p, top_k, *,
     config, n_iter, all_greedy, use_kernel, with_logprobs,
-    placed=False,
+    placed=False, emitted=None,
 ):
     """The shared K-iteration fused decode scan — the body of
     ``_paged_decode_chunk`` AND the decode half of ``_fused_chunk`` (the
     fused prefill-decode program), factored out so the two cannot drift
     (the same discipline ``_decode_step_core`` enforces one level down).
     See ``_paged_decode_chunk``'s docstring for the full contract;
-    callers resolve ``use_kernel`` and enter the mesh."""
+    callers resolve ``use_kernel`` and enter the mesh.  ``emitted``: the
+    (tokens [B], logprobs [B]) column of an iteration the caller ran
+    itself (``_fused_chunk``'s mixed pass); it goes first in the packed
+    block, ahead of the scan's ``n_iter`` columns."""
 
     def body(carry, _):
         pool, tau, tau_lp, fill, pos, active, remaining, keys = carry
-        # --- the host emit scan, on device ---
-        nonfinite = tau < 0
-        hit_stop = stop_token_hits(tau, stops)
-        out_tok = jnp.where(
-            active,
-            jnp.where(nonfinite, -1, tau),
-            _CHUNK_PAD,
-        ).astype(jnp.int32)
-        out_lp = tau_lp
-        done = active & (nonfinite | hit_stop | (remaining <= 1))
-        remaining = remaining - active.astype(jnp.int32)
-        active = active & ~done
+        out_tok, out_lp, active, remaining = _emit(
+            tau, tau_lp, active, remaining, stops
+        )
         # --- one decode iteration for the surviving rows ---
         nxt, lp, keys, pool = _decode_step_core(
             params, pool, table, n_alloc, fill, tau, pos, active,
@@ -825,11 +890,9 @@ def _chunk_scan(
             all_greedy=all_greedy, use_kernel=use_kernel,
             with_logprobs=with_logprobs, placed=placed,
         )
-        tau = jnp.where(active, nxt, tau)
-        if with_logprobs:
-            tau_lp = jnp.where(active, lp, tau_lp)
-        fill = fill + active
-        pos = pos + active
+        tau, tau_lp, fill, pos = _advance(
+            tau, tau_lp, fill, pos, active, nxt, lp
+        )
         return (
             (pool, tau, tau_lp, fill, pos, active, remaining, keys),
             (out_tok, out_lp),
@@ -842,6 +905,11 @@ def _chunk_scan(
         length=n_iter,
     )
     pool, tau, tau_lp, fill, pos, active, remaining, keys = carry
+    if emitted is not None:
+        toks, lps = (
+            jnp.concatenate([first[None], rest])
+            for first, rest in zip(emitted, (toks, lps))
+        )
     # Serving-mesh placement (parallel/serve_mesh.py): pin the carried
     # state and pool outputs to their canonical shardings so the
     # donated inputs (placed the same way at construction) alias
@@ -894,9 +962,26 @@ def _fused_chunk(
 ):
     """The fused prefill-decode program: ONE jitted dispatch that
     advances up to ``pf_chunk`` prompt tokens of the single in-flight
-    admission AND runs the standard ``n_iter``-iteration decode scan —
-    so admissions never stall decode (Sarathi-style stall-free chunked
-    prefill, piggybacked on the device-resident decode chunk).
+    admission AND runs ``n_iter`` decode iterations — so admissions
+    never stall decode (stall-free chunked prefill, piggybacked on the
+    device-resident decode chunk).
+
+    Two forms, chosen by ``_mixed_pass`` from what the trace sees.  The
+    mixed pass (the dense block over the paged kernel, K >= 2): the
+    first iteration's emit and stop-detect run ahead of the chunk, then
+    its forward rides the chunk's pass over the weights — C prompt
+    tokens and B decode tokens as one [1, C + B, D] activation, split
+    for attention only (``models.llama.mixed_forward``) — one head
+    product serves the chunk's last hidden state and the B rows, the
+    rows draw their next tokens, and the scan below runs the other
+    ``n_iter - 1`` iterations: ``n_iter`` passes a dispatch.  A row
+    whose prompt completes folds in behind the mixed pass and emits its
+    first token at the second iteration (column 1 of the packed block,
+    a pad in column 0): still from THIS dispatch.  Everywhere else the
+    chunk runs as a forward of its own ahead of the whole scan
+    (``n_iter + 1`` passes; a row that folds in emits from column 0),
+    as the paragraphs below describe; K = 1 keeps that order so the
+    completing dispatch still hands the first token over.
 
     Prefill half: the admitted row's gathered view is cut from the pool
     (``_gather_cache`` over its table row) with a SCALAR write index
@@ -923,8 +1008,9 @@ def _fused_chunk(
 
     Decode half: the unchanged ``_chunk_scan`` (shared with
     ``_paged_decode_chunk``, so the fused program cannot drift from the
-    plain one).  The prefilling row rides the scan masked (position -1,
-    writes dropped) until its activation dispatch.
+    plain one; the mixed pass's iteration is built from the same
+    ``_emit`` and ``_sample_step``).  The prefilling row rides the scan
+    masked (position -1, writes dropped) until its activation dispatch.
 
     Host boundary: identical to ``_paged_decode_chunk`` — ONE packed
     [1 or 2, B, K] fetch, zero steady-state uploads.  All prefill state
@@ -974,18 +1060,56 @@ def _fused_chunk(
         view = dataclasses.replace(view, index=write_at)
         toks_c = lax.dynamic_slice_in_dim(pf_toks, pf_off, C)[None]
         positions, real = window_positions(pf_base, pf_off, C, pf_len)
-        _, view, aux = forward(
-            params, toks_c, positions, config, cache=view,
-            attn_mask=real, compute_logits=False, output_last_hidden=True,
+        use_kernel = allow_kernel and _kernel_eligible(
+            pool.block_size, mesh, config.kv_heads, B
         )
-        idx = pf_len - 1 - pf_off  # in [0, C) iff this is the last chunk
-        h_last = jnp.take_along_axis(
-            aux.last_hidden_state,
-            jnp.clip(idx, 0, C - 1)[None, None, None], axis=1,
-        )[:, 0]
-        logits_last = lm_head_logits(
-            params, h_last[:, None], config, normed=True
-        )[:, 0]
+        mixed = _mixed_pass(config, pool.quantized, mesh, use_kernel, n_iter)
+        emitted = None
+        if mixed:
+            # Iteration 1 of the decode scan, its forward merged into the
+            # chunk's: emit and stop-detect first (``_emit`` does not
+            # depend on the chunk), then ONE pass over the weights for the
+            # chunk's C tokens and the B decode tokens, one head product
+            # over the chunk's last hidden state and the decode rows'.
+            *emitted, active, remaining = _emit(
+                tau, tau_lp, active, remaining, stops
+            )
+            hidden, view, pcache = mixed_forward(
+                params, toks_c, positions, config, view, real,
+                tau, jnp.where(active, pos, -1),
+                _pool_as_cache(pool, table, fill),
+            )
+            pool = _cache_into_pool(pool, pcache)
+            idx = pf_len - 1 - pf_off  # as below
+            h_last = jnp.take_along_axis(
+                hidden, jnp.clip(idx, 0, C - 1)[None, None, None], axis=1
+            )
+            logits = lm_head_logits(
+                params, jnp.concatenate([h_last, hidden[:, C:]], axis=1),
+                config, normed=True,
+            )[0]
+            logits_last = logits[:1]
+            nxt, lp, keys = _sample_step(
+                logits[1:, None], keys, temperature, top_p, top_k,
+                all_greedy=all_greedy, with_logprobs=with_logprobs,
+            )
+            tau, tau_lp, fill, pos = _advance(
+                tau, tau_lp, fill, pos, active, nxt, lp
+            )
+        else:
+            _, view, aux = forward(
+                params, toks_c, positions, config, cache=view,
+                attn_mask=real, compute_logits=False,
+                output_last_hidden=True,
+            )
+            idx = pf_len - 1 - pf_off  # in [0, C) iff this is the last chunk
+            h_last = jnp.take_along_axis(
+                aux.last_hidden_state,
+                jnp.clip(idx, 0, C - 1)[None, None, None], axis=1,
+            )[:, 0]
+            logits_last = lm_head_logits(
+                params, h_last[:, None], config, normed=True
+            )[:, 0]
         pool = _land_chunk(pool, view, table_r, write_at, C)
         if pool.conv is not None:
             # The chunk's end state into the row's slot, and into snapshot
@@ -1031,16 +1155,15 @@ def _fused_chunk(
         pos = jnp.where(fold, pf_base + pf_len, pos)
         keys = jnp.where(fold[:, None], kc, keys)
         pf_off = pf_off + C
-        # ---- the standard K-iteration decode scan ----
-        use_kernel = allow_kernel and _kernel_eligible(
-            pool.block_size, mesh, config.kv_heads, B
-        )
+        # ---- the standard decode scan: K iterations, or the K - 1 after
+        # the mixed pass's ----
         out = _chunk_scan(
             params, pool, table, n_alloc, fill, tau, tau_lp, pos,
             active, remaining, stops, keys, temperature, top_p, top_k,
-            config=config, n_iter=n_iter, all_greedy=all_greedy,
+            config=config, n_iter=n_iter - 1 if mixed else n_iter,
+            all_greedy=all_greedy,
             use_kernel=use_kernel, with_logprobs=with_logprobs,
-            placed=placed,
+            placed=placed, emitted=emitted,
         )
         return out + (pf_off,)
 
@@ -2407,6 +2530,8 @@ class ContinuousBatcher:
         # (the state _QUEUED_LANE_CAP answers); over
         # ``prefill_chunks_total``, which counts every fused dispatch.
         self.fused_dispatches_queued_total = 0
+        self.fused_dispatches_merged_total = 0
+        self.fused_merged_rows_total = 0
         self.fused_admissions_total = 0
         self.decode_stall_ms_total = 0.0
 
@@ -2809,6 +2934,13 @@ class ContinuousBatcher:
             "ssm_snapshots_in_use": (
                 self._store.snapshots_in_use() if self.n_snapshots else 0),
             "fused_admissions_total": self.fused_admissions_total,
+            # Fused dispatches whose first decode iteration rode the
+            # prompt chunk's pass over the weights (``_mixed_pass``), and
+            # the decoding rows that rode it.
+            "fused_dispatches_merged_total": (
+                self.fused_dispatches_merged_total
+            ),
+            "fused_merged_rows_total": self.fused_merged_rows_total,
             "decode_stall_ms_total": round(self.decode_stall_ms_total, 3),
         })
         return out
@@ -3148,6 +3280,15 @@ class ContinuousBatcher:
             None if pf is None else {"blocks": self._pf_live_blocks(pf)}
         )
         queued = len(self.queue)
+        # Rows whose first iteration rides the chunk's weight pass: the
+        # rows decoding at the submit, when the program takes the mixed
+        # pass (the predicate it traces by); None when it does not.
+        merged_rows = None
+        if pf is not None and _mixed_pass(
+            self.config, self.pool.quantized, self.mesh,
+            "paged_kernel" in feats, K,
+        ):
+            merged_rows = int(np.sum(self.active))
         pf_done_rid: Optional[int] = None
         pf_ssm = None if pf is None or not self.recurrent else (
             self._pf_snapshots(pf))
@@ -3192,6 +3333,9 @@ class ContinuousBatcher:
             )
             self.prefill_chunks_total += 1
             self.fused_dispatches_queued_total += queued > 0
+            if merged_rows is not None:
+                self.fused_dispatches_merged_total += 1
+                self.fused_merged_rows_total += merged_rows
             pf.off += pf.chunk
             if pf.off >= pf.suffix_len:
                 # Prefill complete: the device already folded the row
@@ -3255,6 +3399,7 @@ class ContinuousBatcher:
             prefill_write=pf_write,
             queued=queued,
             ssm=None if pf_ssm is None else pf_ssm[1],
+            merged_rows=merged_rows,
         )
         if pf_done_rid is not None:
             # The prefill's last chunk linked into the prefilling span
@@ -3281,7 +3426,10 @@ class ContinuousBatcher:
             for i in range(toks.shape[1]):
                 tok = int(toks[b, i])
                 if tok == _CHUNK_PAD:
-                    break
+                    # Not decoding at this column.  A row that ended
+                    # left the loop at its last token; one that folded
+                    # in behind a mixed pass emits from column 1 on.
+                    continue
                 if tok < 0:
                     # On-device non-finite sentinel: the device already
                     # folded the row out of the chunk; fail just this
