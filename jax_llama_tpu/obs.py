@@ -30,6 +30,12 @@ questions (and ROADMAP item 5's online chunk controller) need:
     (``llm.loop.<phase>`` / ``llm.dispatch`` with the record's
     ``seq``), so a profiler session holds them on the device trace's
     clock and a device idle gap is named by overlap.
+  * **Loop spans** (:meth:`Observability.loop_span`,
+    :data:`LOOP_SPANS`).  The parts of a phase, or of a dispatch's own
+    host time: child spans with their cause, the request they work for
+    and the ring number of the record their gap leads to.  They ride
+    the record as ``span_ms`` / ``span_n`` (``host_ms`` keeps its keys
+    and its tiling) and the profiler's clock as ``llm.span.<name>``.
   * **Latency histograms** (:class:`Histogram`).  Prometheus cumulative-
     bucket histograms for TTFT, inter-token latency, queue wait,
     prefill-chunk latency, swap-in latency, jit compile time, and
@@ -144,6 +150,36 @@ LOOP_PHASES = frozenset({
     "control", "intake", "idle", "deliver",
     "barrier", "admit", "prep", "emit",
 })
+
+# Child spans (Observability.loop_span): the parts of a phase, or of the
+# host time inside a dispatch record (``dispatch``: dispatch_begin to the
+# record).  name -> the parent it is recorded under, a phase, ``dispatch``
+# or another span; a closed set, validated like the phases.  Opened
+# anywhere else a span records nothing and its time stays with whatever is
+# open there, so a span's time is part of its parent's by construction
+# (``_free_slot`` from a cancel is no ``emit.free``).  What a phase spends
+# outside its spans is its self time.  serving.py holds the sites.
+LOOP_SPANS: Dict[str, str] = {
+    "admit.restore": "admit",      # swap-in polling + restored admissions
+    "admit.hash": "admit",         # chain keys of the prompt
+    "admit.match": "admit",        # the prefix walk
+    "admit.alloc": "admit",        # claims and block allocation
+    "admit.evict": "admit.alloc",  # ... when the free list is dry
+    "admit.upload": "admit",       # the prefill lane's device operands
+    "admit.insert": "admit",       # an insert program's operands
+    "prep.sync_rows": "prep",      # dirty rows to the device twins
+    "prep.snapshots": "prep",      # state-snapshot operands
+    "dispatch.submit": "dispatch",   # the jitted call, until it returns
+    "dispatch.publish": "dispatch",  # chain publish under the device
+    "emit.replay": "emit",         # replay of the packed block
+    "emit.free": "emit.replay",    # a finished row's slot free
+}
+
+# Why the queue's head stayed queued (record field ``blocked``, counter
+# admit_blocked_total{reason}): the prefill lane is taken, the pool lacks
+# blocks for its reservation, no slot is free, or a completed swap-in
+# (FIFO priority over the queue) took the lane in the same ``_admit``.
+ADMIT_BLOCKED = ("lane", "capacity", "slot", "restoring")
 
 # ---------------------------------------------------------------------------
 # Histograms (Prometheus cumulative buckets)
@@ -561,6 +597,20 @@ METRICS: Dict[str, Tuple[str, str]] = {
                    "same gaps (ms; gap - idle - cpu = runnable or "
                    "blocked but not running: the GIL, a descheduled "
                    "core, a profiler)"),
+    "loop_span_ms_total": _reg(
+        "counter", "Loop-thread time inside the child spans of the loop "
+                   "phases and of the dispatches, per span (ms; "
+                   "obs.LOOP_SPANS; a nested span's time is also in its "
+                   "parent's; folded in at each dispatch record)"),
+    "loop_span_total": _reg(
+        "counter", "Child spans closed, per span"),
+    "dispatch_submit_ms_total": _reg(
+        "counter", "Summed submit_ms of the dispatch records: "
+                   "dispatch_begin to the return of the jitted call, "
+                   "the host's enqueue (ms; inside wall_ms)"),
+    "admit_blocked_total": _reg(
+        "counter", "Admission passes that left the queue's head "
+                   "queued, per reason (lane|capacity|slot|restoring)"),
     "jit_cache_entries": _reg(
         "gauge", "Live jit-cache entries per registered serving "
                  "program (a runaway series here is a bucketing bug "
@@ -897,6 +947,48 @@ class _Timeline:
         self.kv: Dict[str, Any] = {}
 
 
+class _LoopSpan:
+    """One ``Observability.loop_span``: a context manager the loop thread
+    enters once.  ``t0`` is None while it records nothing (not under its
+    parent) and once it is closed."""
+
+    __slots__ = ("obs", "name", "rid", "t0", "parent", "seq", "ann")
+
+    def __init__(self, obs: "Observability", name: str,
+                 rid: Optional[int]):
+        self.obs = obs
+        self.name = name
+        self.rid = rid
+        self.t0: Optional[float] = None
+
+    def __enter__(self) -> "_LoopSpan":
+        obs = self.obs
+        stack = obs._sp_open
+        if stack:
+            cause = stack[-1].name
+            if self.rid is None:
+                self.rid = stack[-1].rid
+        elif obs._ph_resume is not None:
+            cause = "dispatch"
+        else:
+            cause = obs._ph_name
+        if cause != LOOP_SPANS[self.name]:
+            return self
+        self.parent = cause
+        self.seq = obs._ph_seq
+        args = {"seq": self.seq}
+        if self.rid is not None:
+            args["rid"] = int(self.rid)
+        self.ann = _annotate("llm.span." + self.name, **args)
+        stack.append(self)
+        self.t0 = obs._clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self.t0 is not None:
+            self.obs._sp_close(self, self.obs._clock())
+
+
 class Observability:
     """The serving stack's shared observability sink (module docstring).
 
@@ -1002,6 +1094,18 @@ class Observability:
         self.loop_phase_ms_total: Dict[str, float] = {}
         self.loop_gap_ms_total = 0.0
         self.loop_gap_cpu_ms_total = 0.0
+        # Child spans (loop_span): the same single writer.  Ring entries
+        # are (name, start, end, parent, rid, seq), seconds on ``clock``.
+        self.loop_spans: "deque[Tuple[Any, ...]]" = deque(
+            maxlen=_PHASE_RING
+        )
+        self._sp_open: List[_LoopSpan] = []   # innermost last
+        self._sp_acc: Dict[str, float] = {}   # span -> s since last record
+        self._sp_n: Dict[str, int] = {}       # ... and how many closed
+        self.loop_span_ms_total: Dict[str, float] = {}
+        self.loop_span_total: Dict[str, int] = {}
+        self.dispatch_submit_ms_total = 0.0
+        self.admit_blocked_total: Dict[str, int] = {}
 
     # -- internal helpers ---------------------------------------------------
 
@@ -1212,7 +1316,10 @@ class Observability:
 
     def _ph_abandon_dispatch(self) -> None:
         """A dispatch began and never recorded (it raised): its time
-        goes back to the phase it interrupted."""
+        goes back to the phase it interrupted (its spans' with it)."""
+        self._sp_close(None, self._ph_t)
+        for name in [n for n in self._sp_acc if n.startswith("dispatch.")]:
+            del self._sp_acc[name], self._sp_n[name]
         self._ph_close(self._ph_t)  # the llm.dispatch annotation
         resume, self._ph_resume = self._ph_resume, None
         self._ph_open(resume, self._ph_t)
@@ -1239,6 +1346,58 @@ class Observability:
         self._ph_close(t)
         self._ph_open(name, t)
 
+    def loop_span(self, name: str, rid: Optional[int] = None) -> _LoopSpan:
+        """A child span of the open loop phase, of the open dispatch
+        (``dispatch_begin`` to the record) or of the open span: ``with
+        obs.loop_span("admit.match", rid=req.rid): ...``.  ``name`` is
+        one of :data:`LOOP_SPANS`, which also names the parent it is
+        recorded under; opened elsewhere it records nothing and its time
+        stays with what is open.  A span ends at its exit or at the next
+        ``dispatch_begin`` / record, whichever comes first, so it never
+        reaches across a dispatch.  It goes into the ``loop_spans`` ring
+        with its cause, ``rid`` (a nested span takes its parent's) and
+        the ring number of the record its gap leads to; into that
+        record's ``span_ms`` / ``span_n`` (a nested span's time is also
+        in its parent's; ``host_ms`` is untouched); and onto the
+        profiler's clock as ``llm.span.<name>`` with ``seq`` and
+        ``rid``.  Loop thread only, no lock, always on: two clock reads,
+        two dict adds, a ring entry and an inactive annotation."""
+        if name not in LOOP_SPANS:
+            raise ValueError(
+                f"unknown loop span {name!r}; have {sorted(LOOP_SPANS)}"
+            )
+        return _LoopSpan(self, name, rid)
+
+    def _sp_close(self, sp: Optional[_LoopSpan], t: float) -> None:
+        """End ``sp`` and every span opened inside it at ``t`` (None:
+        every open span)."""
+        stack = self._sp_open
+        while stack:
+            cur = stack.pop()
+            dur = max(0.0, t - cur.t0)
+            self._sp_acc[cur.name] = self._sp_acc.get(cur.name, 0.0) + dur
+            self._sp_n[cur.name] = self._sp_n.get(cur.name, 0) + 1
+            self.loop_spans.append(
+                (cur.name, cur.t0, cur.t0 + dur, cur.parent, cur.rid,
+                 cur.seq)
+            )
+            cur.t0 = None
+            if cur.ann is not None:
+                cur.ann.__exit__(None, None, None)
+            if cur is sp:
+                break
+
+    def admit_blocked(self, reason: str) -> None:
+        """The admission pass left the queue's head queued for
+        ``reason`` (:data:`ADMIT_BLOCKED`).  Loop thread only."""
+        if reason not in ADMIT_BLOCKED:
+            raise ValueError(
+                f"unknown blocked reason {reason!r}; have {ADMIT_BLOCKED}"
+            )
+        self.admit_blocked_total[reason] = (
+            self.admit_blocked_total.get(reason, 0) + 1
+        )
+
     def dispatch_begin(self, kind: str, program: Optional[str] = None,
                        k: int = 1) -> None:
         """The loop thread is about to submit a jitted dispatch that
@@ -1251,6 +1410,7 @@ class Observability:
             self._ph_abandon_dispatch()
         t = self._clock()
         resume = self._ph_name
+        self._sp_close(None, t)
         self._ph_close(t)
         self._ph_cpu1 = time.thread_time()
         # ``_ph_t`` keeps the begin instant until the record lands: an
@@ -1276,13 +1436,23 @@ class Observability:
             # up to the record's start, and the CPU reading below
             # includes the dispatch's own.
             resume = self._ph_name
+            self._sp_close(None, max(start, self._ph_t))
             self._ph_close(max(start, self._ph_t))
             cpu1 = time.thread_time()
         else:
             self._ph_resume = None
+            self._sp_close(None, now)  # a dispatch span left open
             self._ph_close(now)  # the llm.dispatch annotation
             cpu1 = self._ph_cpu1
-        out: Dict[str, Any] = {}
+        out: Dict[str, Any] = {
+            "span_ms": {
+                n: round(v * 1000.0, 3) for n, v in self._sp_acc.items()
+            },
+            "span_n": self._sp_n,
+        }
+        if "dispatch.submit" in self._sp_acc:
+            out["submit_ms"] = out["span_ms"]["dispatch.submit"]
+        self._sp_acc, self._sp_n = {}, {}
         tid = threading.get_ident()
         if self._ph_prev_end is not None and resume is not None:
             gap = start - self._ph_prev_end
@@ -1331,6 +1501,40 @@ class Observability:
             for p, v in totals
         ]
 
+    def loop_span_metrics(
+        self,
+    ) -> List[Tuple[str, Dict[str, str], float]]:
+        """``loop_span_ms_total{span=...}``, ``loop_span_total{span=...}``
+        and ``admit_blocked_total{reason=...}`` samples for /metrics."""
+        with self._lock:
+            ms = sorted(self.loop_span_ms_total.items())
+            n = sorted(self.loop_span_total.items())
+        # One writer and int values: the copy is atomic under the GIL.
+        blocked = sorted(dict(self.admit_blocked_total).items())
+        return (
+            [("loop_span_ms_total", {"span": p}, round(v, 3)) for p, v in ms]
+            + [("loop_span_total", {"span": p}, v) for p, v in n]
+            + [("admit_blocked_total", {"reason": r}, v) for r, v in blocked]
+        )
+
+    def loop_spans_json(
+        self, rids: Optional[Sequence[int]] = None,
+    ) -> List[Dict[str, Any]]:
+        """The recent child spans (all, or those that worked for one of
+        ``rids``), times in ms on the origin of every ``start_ms`` here;
+        ``seq`` is the ring number of the dispatch record each led to."""
+        t0 = self.t0
+        keep = None if rids is None else set(rids)
+        return [
+            {"name": name, "start_ms": round((a - t0) * 1000.0, 3),
+             "end_ms": round((b - t0) * 1000.0, 3),
+             "duration_ms": round((b - a) * 1000.0, 3),
+             "parent": parent, "rid": rid, "seq": seq}
+            # As loop_phases_json: one writer, an atomic copy.
+            for name, a, b, parent, rid, seq in list(self.loop_spans)
+            if keep is None or rid in keep
+        ]
+
     # -- dispatch spans ------------------------------------------------------
 
     def record_dispatch(
@@ -1351,6 +1555,7 @@ class Observability:
         queued: Optional[int] = None,
         ssm: Optional[Dict[str, int]] = None,
         merged_rows: Optional[int] = None,
+        blocked: Optional[str] = None,
     ) -> int:
         """Record one jitted serving dispatch and link it into the
         CURRENT span of every request that rode it.  Returns the
@@ -1375,8 +1580,15 @@ class Observability:
         ``prefill_write`` (dispatches that land prompt KV) is what they
         landed in the pool: ``{"blocks": n}`` whole blocks from the fused
         lane, ``{"pairs": n}`` token slots from an insert.
+        ``span_ms`` / ``span_n`` (every record) are the child spans closed
+        since the previous record, ``{name: ms}`` and ``{name: count}``
+        (``loop_span``: the parts of this gap's phases and of this
+        dispatch's own host time; a nested span's time is in its parent's
+        too), and ``submit_ms`` is ``span_ms["dispatch.submit"]``.
         ``queued`` (chunk dispatches) is the batcher's queue length at the
-        submit: what the dispatch's K was clamped for.
+        submit: what the dispatch's K was clamped for; ``blocked`` beside
+        it is why the queue's head stayed queued in the admission pass
+        before the submit (:data:`ADMIT_BLOCKED`; None: nothing waits).
         ``ssm`` (recurrent state layers, dispatches with a prefill lane) is
         the state snapshots it moved: ``{"taken": n, "restored": n}``.
         ``merged_rows`` (fused dispatches that took the mixed pass, and only
@@ -1392,6 +1604,10 @@ class Observability:
         if then is not None and then not in LOOP_PHASES:
             raise ValueError(
                 f"unknown loop phase {then!r}; have {sorted(LOOP_PHASES)}"
+            )
+        if blocked is not None and blocked not in ADMIT_BLOCKED:
+            raise ValueError(
+                f"unknown blocked reason {blocked!r}; have {ADMIT_BLOCKED}"
             )
         now = self._clock()
         t = (now - self.t0) * 1000.0
@@ -1420,6 +1636,7 @@ class Observability:
             }
         if queued is not None:
             rec["queued"] = int(queued)
+            rec["blocked"] = blocked
         if ssm is not None:
             rec["ssm"] = {key: int(v) for key, v in ssm.items()}
         if merged_rows is not None:
@@ -1439,6 +1656,14 @@ class Observability:
                 totals = self.loop_phase_ms_total
                 for p, v in gap["host_ms"].items():
                     totals[p] = totals.get(p, 0.0) + v
+            for p, v in gap["span_ms"].items():
+                self.loop_span_ms_total[p] = (
+                    self.loop_span_ms_total.get(p, 0.0) + v
+                )
+                self.loop_span_total[p] = (
+                    self.loop_span_total.get(p, 0) + gap["span_n"][p]
+                )
+            self.dispatch_submit_ms_total += gap.get("submit_ms", 0.0)
             h = self.hist_dispatch.get(kind)
             if h is None:
                 h = self.hist_dispatch[kind] = Histogram(
@@ -1625,6 +1850,9 @@ class Observability:
                 "loop_gap_cpu_ms_total": round(
                     self.loop_gap_cpu_ms_total, 3
                 ),
+                "dispatch_submit_ms_total": round(
+                    self.dispatch_submit_ms_total, 3
+                ),
                 "slo_ttft_ms": self.slo_ttft_ms or 0.0,
                 "slo_itl_ms": self.slo_itl_ms or 0.0,
                 "requests_slo_ok_total": self.requests_slo_ok_total,
@@ -1712,6 +1940,8 @@ class Observability:
                 "dispatch_spans": [
                     dict(d) for d in self.dispatches if d["seq"] in seqs
                 ],
+                # What the loop thread did for this request's admission.
+                "loop_spans": self.loop_spans_json(tl.rids),
             }
 
     def requests_json(self, n: int = 64) -> Dict[str, Any]:
@@ -1753,6 +1983,7 @@ class Observability:
         # the list copies are reference-shallow; only the mutable
         # _Span fields are copied out.
         phases = self.loop_phases_json()
+        loop_spans = self.loop_spans_json()
         with self._lock:
             dispatches = list(self.dispatches)
             events = list(self.events)
@@ -1782,6 +2013,17 @@ class Observability:
                 "tid": 2, "ts": round(a * 1000.0, 1),
                 "dur": max(1, round((b - a) * 1000.0)),
             })
+        # ... and their parts, which nest under them on the same track.
+        for sp in loop_spans:
+            if horizon is not None and sp["end_ms"] < horizon:
+                continue
+            ev.append({
+                "name": sp["name"], "cat": "loop_span", "ph": "X",
+                "pid": 1, "tid": 2,
+                "ts": round(sp["start_ms"] * 1000.0, 1),
+                "dur": max(1, round(sp["duration_ms"] * 1000.0)),
+                "args": {k: sp[k] for k in ("parent", "rid", "seq")},
+            })
         for d in dispatches:
             if horizon is not None and d["start_ms"] < horizon:
                 continue
@@ -1796,6 +2038,7 @@ class Observability:
                         "fetch_ms", "swap_inflight", "rids",
                         "program", "gap_ms",
                         "host_ms", "gap_cpu_ms", "compiles",
+                        "span_ms", "submit_ms", "blocked",
                     ) if k in d
                 },
             })

@@ -196,7 +196,14 @@ share of a step.  ``llm_loop_phase_ms_total{phase="control"|"intake"|"idle"|
 dispatch records, by what the thread was doing), ``llm_loop_gap_ms_total``
 (the sum of those gaps, idle included) and ``llm_loop_gap_cpu_ms_total``
 (the thread's CPU time over them: gap - idle - cpu is time it was
-runnable or blocked but not running).
+runnable or blocked but not running).  Their parts (obs.LOOP_SPANS —
+always on): ``llm_loop_span_ms_total{span=...}`` /
+``llm_loop_span_total{span=...}`` (time inside and count of the child
+spans of ``admit``, ``prep``, ``emit`` and of the dispatches' own host
+time), ``llm_dispatch_submit_ms_total`` (dispatch_begin to the return of
+the jitted call, summed) and ``llm_admit_blocked_total{reason="lane"|
+"capacity"|"slot"|"restoring"}`` (admission passes that left the queue's
+head queued, by why).
 
 SLO accounting (run.py ``--slo-ttft-ms`` / ``--slo-itl-ms``; a 0/unset
 dimension always passes): ``llm_slo_ttft_attainment`` /
@@ -221,8 +228,12 @@ evicted)::
                  "duration_ms": float|null,
                  "dispatches": [seq, ...],     # causal links
                  "note": str}, ...],
-      "dispatch_spans": [<dispatch records the spans link to>]
-    }
+      "dispatch_spans": [<dispatch records the spans link to>],
+      "loop_spans": [{"name": "admit.hash"|..., "start_ms": float,
+                      "end_ms": float, "duration_ms": float,
+                      "parent": str,   # phase, "dispatch" or span
+                      "rid": int, "seq": int}, ...]  # seq: the record
+    }                                  # the span's gap led to
 
 ``GET /debug/requests?n=64`` lists recent timelines (id, rids, states,
 outcome).  ``GET /debug/dispatches?n=128`` returns the dispatch ring::
@@ -242,12 +253,22 @@ outcome).  ``GET /debug/dispatches?n=128`` returns the dispatch ring::
                      "gap_ms": float,          # prev record's end -> start
                      "host_ms": {phase: ms},   # sums to gap_ms (idle too)
                      "gap_cpu_ms": float,      # loop-thread CPU in the gap
-                     "compiles": int}, ...]}   # since the prev record
+                     "compiles": int,          # since the prev record
+                     # the parts of that gap and of this dispatch's
+                     # host time (obs.LOOP_SPANS; not in host_ms):
+                     "span_ms": {span: ms}, "span_n": {span: count},
+                     "submit_ms": float,       # span_ms["dispatch.submit"]
+                     # chunk dispatches: queue length at the submit and
+                     # why its head stayed queued (null: nothing waits)
+                     "queued": int,
+                     "blocked": "lane"|"capacity"|"slot"|"restoring"|null},
+                    ...]}
 
 ``GET /debug/trace[?window_s=S]`` emits Chrome ``trace_event`` JSON
 (``{"traceEvents": [...]}``) — load in chrome://tracing or
 https://ui.perfetto.dev: dispatches on one track, the loop thread's
-phases between them on the ``serving loop`` track, request lifecycles
+phases between them on the ``serving loop`` track with their child spans
+nested under them, request lifecycles
 on per-request tracks, fault/quarantine/kv-tier annotations as instant
 events, jit compiles on their own track, and the document carries a
 ``t0_unix_s`` wall-clock anchor — the router's fleet-merged
@@ -266,7 +287,10 @@ the device's idle time by what the loop thread was doing::
      "programs": {"<program>": {"device_ms": F, "host_ms": F}, ...},
      "total_device_ms": F, "total_host_ms": F,
      "busy_ms": F, "idle_ms": F,          # union of XLA Ops, device 0
-     "idle_by_phase_ms": {"<phase>"|"in dispatch"|"unnamed": F, ...}}
+     "idle_by_phase_ms": {"<phase>"|"in dispatch"|"unnamed": F, ...},
+     # the same gaps, each to the innermost llm.span.* event over it,
+     # else its phase, else "in dispatch", else "unnamed":
+     "idle_by_span_ms": {"<span>"|"<phase>"|"in dispatch"|"unnamed": F}}
 
 (404 with no completed session, 409 while one is active).  Dispatch
 records (/debug/dispatches) gain ``program``.
@@ -2501,10 +2525,12 @@ class LLMServer:
         # them before traffic.
         labeled = list(self.obs.compile_metrics())
         labeled.extend(self.obs.loop_phase_metrics())
+        labeled.extend(self.obs.loop_span_metrics())
         for prog, n in sorted(serving_mod.jit_cache_entries().items()):
             labeled.append(("jit_cache_entries", {"program": prog}, n))
         for family in ("program_compiles_total", "jit_cache_entries",
-                       "loop_phase_ms_total"):
+                       "loop_phase_ms_total", "loop_span_ms_total",
+                       "loop_span_total", "admit_blocked_total"):
             kind, help_text = metric_meta(family)
             lines.append(f"# HELP llm_{family} {help_text}")
             lines.append(f"# TYPE llm_{family} {kind}")

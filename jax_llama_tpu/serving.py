@@ -2468,6 +2468,10 @@ class ContinuousBatcher:
         # (the spec round program has no prefill lane).
         self.prefill_budget = max(0, int(prefill_budget))
         self._pf: Optional[_Prefill] = None
+        # Why the last ``_admit`` left the queue's head queued (one of
+        # obs.ADMIT_BLOCKED; None: it did not).  The next chunk record's
+        # ``blocked``.
+        self._blocked: Optional[str] = None
         # Device-resident twins (chunked path only); row-sharded on a
         # placed serving mesh (see _mesh_placed above).
         self.d_table = self._rows(self.table)
@@ -3157,39 +3161,40 @@ class ContinuousBatcher:
         dispatch.  No dirty rows (the steady state) -> no upload."""
         if not self._dirty_rows:
             return
-        if self.d_stops.shape != self.stop_tab.shape:
-            # Stop-table width grew (pow2-bucketed): rebuild the device
-            # twin wholesale before the row scatter — admission-time
-            # only, and the array is [B, S] ints.
-            self.d_stops = self._rows(self.stop_tab)
-        rows = sorted(self._dirty_rows)
-        self._dirty_rows.clear()
-        R = len(rows)
-        Rb = pow2_bucket(R)  # pow2 jit-cache bucket
-        idx = np.full((Rb,), self.n_slots, np.int32)  # pads drop
-        idx[:R] = rows
+        with self.obs.loop_span("prep.sync_rows"):
+            if self.d_stops.shape != self.stop_tab.shape:
+                # Stop-table width grew (pow2-bucketed): rebuild the device
+                # twin wholesale before the row scatter — admission-time
+                # only, and the array is [B, S] ints.
+                self.d_stops = self._rows(self.stop_tab)
+            rows = sorted(self._dirty_rows)
+            self._dirty_rows.clear()
+            R = len(rows)
+            Rb = pow2_bucket(R)  # pow2 jit-cache bucket
+            idx = np.full((Rb,), self.n_slots, np.int32)  # pads drop
+            idx[:R] = rows
 
-        def take(a: np.ndarray) -> jnp.ndarray:
-            out = np.zeros((Rb,) + a.shape[1:], a.dtype)
-            out[:R] = a[rows]
-            return jnp.asarray(out)
+            def take(a: np.ndarray) -> jnp.ndarray:
+                out = np.zeros((Rb,) + a.shape[1:], a.dtype)
+                out[:R] = a[rows]
+                return jnp.asarray(out)
 
-        state = (
-            self.d_table, self.d_n_alloc, self.d_fill, self.d_pos,
-            self.d_active, self.d_temps, self.d_top_ps, self.d_top_ks,
-            self.d_remaining, self.d_stops,
-        )
-        _obs_mod.attribute_compiles(self.obs, "_scatter_rows")
-        (self.d_table, self.d_n_alloc, self.d_fill, self.d_pos,
-         self.d_active, self.d_temps, self.d_top_ps, self.d_top_ks,
-         self.d_remaining, self.d_stops) = _scatter_rows(
-            state, jnp.asarray(idx),
-            (take(self.table), take(self.n_alloc), take(self.fill),
-             take(self.pos), take(self.active), take(self.temp_arr),
-             take(self.top_p_arr), take(self.top_k_arr),
-             take(self.remaining), take(self.stop_tab)),
-        )
-        self.state_uploads_total += 1
+            state = (
+                self.d_table, self.d_n_alloc, self.d_fill, self.d_pos,
+                self.d_active, self.d_temps, self.d_top_ps, self.d_top_ks,
+                self.d_remaining, self.d_stops,
+            )
+            _obs_mod.attribute_compiles(self.obs, "_scatter_rows")
+            (self.d_table, self.d_n_alloc, self.d_fill, self.d_pos,
+             self.d_active, self.d_temps, self.d_top_ps, self.d_top_ks,
+             self.d_remaining, self.d_stops) = _scatter_rows(
+                state, jnp.asarray(idx),
+                (take(self.table), take(self.n_alloc), take(self.fill),
+                 take(self.pos), take(self.active), take(self.temp_arr),
+                 take(self.top_p_arr), take(self.top_k_arr),
+                 take(self.remaining), take(self.stop_tab)),
+            )
+            self.state_uploads_total += 1
 
     def _step_chunked(self) -> List[Tuple]:
         """Non-speculative step: one fused K-iteration chunk dispatch,
@@ -3290,8 +3295,10 @@ class ContinuousBatcher:
         ):
             merged_rows = int(np.sum(self.active))
         pf_done_rid: Optional[int] = None
-        pf_ssm = None if pf is None or not self.recurrent else (
-            self._pf_snapshots(pf))
+        pf_ssm = None
+        if pf is not None and self.recurrent:
+            with self.obs.loop_span("prep.snapshots", rid=pf.req.rid):
+                pf_ssm = self._pf_snapshots(pf)
         all_greedy = bool(np.all(self.temp_arr[self.active] == 0.0))
         if pf is not None:
             # The prefilling request samples inside the program, so the
@@ -3304,33 +3311,35 @@ class ContinuousBatcher:
         kind = "decode" if pf_adv == 0 else "fused"
         self.obs.dispatch_begin(kind, prog, K)
         t0_obs = time.monotonic()
-        if pf is None:
-            (packed, self.tau, self.d_tau_lp, self.d_fill, self.d_pos,
-             self.d_active, self.d_remaining, self.keys,
-             self.pool) = _paged_decode_chunk(
-                self.params, self.pool, self.d_table, self.d_n_alloc,
-                self.d_fill, self.tau, self.d_tau_lp, self.d_pos,
-                self.d_active, self.d_remaining, self.d_stops, self.keys,
-                self.d_temps, self.d_top_ps, self.d_top_ks,
-                config=self.config, n_iter=K, all_greedy=all_greedy,
-                mesh=self.mesh, allow_kernel=self.use_pallas_kernel,
-                with_logprobs=self.logprobs, placed=self._mesh_placed,
-            )
-        else:
-            (packed, self.tau, self.d_tau_lp, self.d_fill, self.d_pos,
-             self.d_active, self.d_remaining, self.keys, self.pool,
-             pf.d_off) = _fused_chunk(
-                self.params, self.pool, self.d_table, self.d_n_alloc,
-                self.d_fill, self.tau, self.d_tau_lp, self.d_pos,
-                self.d_active, self.d_remaining, self.d_stops, self.keys,
-                self.d_temps, self.d_top_ps, self.d_top_ks,
-                pf.d_row, pf.d_toks, pf.d_len, pf.d_base, pf.d_off,
-                pf.d_key, *(pf_ssm or ((), None))[0],
-                config=self.config, n_iter=K, pf_chunk=pf.chunk,
-                all_greedy=all_greedy, mesh=self.mesh,
-                allow_kernel=self.use_pallas_kernel,
-                with_logprobs=self.logprobs, placed=self._mesh_placed,
-            )
+        with self.obs.loop_span("dispatch.submit"):
+            if pf is None:
+                (packed, self.tau, self.d_tau_lp, self.d_fill, self.d_pos,
+                 self.d_active, self.d_remaining, self.keys,
+                 self.pool) = _paged_decode_chunk(
+                    self.params, self.pool, self.d_table, self.d_n_alloc,
+                    self.d_fill, self.tau, self.d_tau_lp, self.d_pos,
+                    self.d_active, self.d_remaining, self.d_stops, self.keys,
+                    self.d_temps, self.d_top_ps, self.d_top_ks,
+                    config=self.config, n_iter=K, all_greedy=all_greedy,
+                    mesh=self.mesh, allow_kernel=self.use_pallas_kernel,
+                    with_logprobs=self.logprobs, placed=self._mesh_placed,
+                )
+            else:
+                (packed, self.tau, self.d_tau_lp, self.d_fill, self.d_pos,
+                 self.d_active, self.d_remaining, self.keys, self.pool,
+                 pf.d_off) = _fused_chunk(
+                    self.params, self.pool, self.d_table, self.d_n_alloc,
+                    self.d_fill, self.tau, self.d_tau_lp, self.d_pos,
+                    self.d_active, self.d_remaining, self.d_stops, self.keys,
+                    self.d_temps, self.d_top_ps, self.d_top_ks,
+                    pf.d_row, pf.d_toks, pf.d_len, pf.d_base, pf.d_off,
+                    pf.d_key, *(pf_ssm or ((), None))[0],
+                    config=self.config, n_iter=K, pf_chunk=pf.chunk,
+                    all_greedy=all_greedy, mesh=self.mesh,
+                    allow_kernel=self.use_pallas_kernel,
+                    with_logprobs=self.logprobs, placed=self._mesh_placed,
+                )
+        if pf is not None:
             self.prefill_chunks_total += 1
             self.fused_dispatches_queued_total += queued > 0
             if merged_rows is not None:
@@ -3359,10 +3368,11 @@ class ContinuousBatcher:
                 # the digest.  The hit prefix re-publishes as a no-op
                 # (existing resident nodes keep their block) and
                 # supplies the correct parent chain.
-                self._register_chain(
-                    slot.blocks[: len(pf.chain)], pf.chain,
-                )
-                self._hang_snapshots(pf)
+                with self.obs.loop_span("dispatch.publish", rid=pf.req.rid):
+                    self._register_chain(
+                        slot.blocks[: len(pf.chain)], pf.chain,
+                    )
+                    self._hang_snapshots(pf)
                 pf_done_rid = pf.req.rid
                 self._pf = None
         # THE one device->host sync of the chunk: tokens (+ bitcast
@@ -3398,6 +3408,7 @@ class ContinuousBatcher:
             prefill_ctx=pf_ctx,
             prefill_write=pf_write,
             queued=queued,
+            blocked=self._blocked if queued else None,
             ssm=None if pf_ssm is None else pf_ssm[1],
             merged_rows=merged_rows,
         )
@@ -3410,62 +3421,64 @@ class ContinuousBatcher:
 
         out: List[Tuple] = []
         forced_nan = self._take_nan()
-        for b, slot in self.slots.items():
-            if slot is None:
-                continue
-            if forced_nan:
-                # An armed ``nan`` fault (chaos drills) poisons the
-                # first active row, exactly like the K=1 emit scan; the
-                # row's chunk tokens are discarded (the request fails
-                # with a clean error either way).
-                forced_nan = False
-                self._fail_slot(b, self._NONFINITE_MSG)
-                continue
-            advanced = 0
-            ended = False
-            for i in range(toks.shape[1]):
-                tok = int(toks[b, i])
-                if tok == _CHUNK_PAD:
-                    # Not decoding at this column.  A row that ended
-                    # left the loop at its last token; one that folded
-                    # in behind a mixed pass emits from column 1 on.
+        with self.obs.loop_span("emit.replay"):
+            for b, slot in self.slots.items():
+                if slot is None:
                     continue
-                if tok < 0:
-                    # On-device non-finite sentinel: the device already
-                    # folded the row out of the chunk; fail just this
-                    # request (tokens before the sentinel were emitted).
-                    self._fail_slot(
-                        b, self._NONFINITE_MSG, device_done=True
+                if forced_nan:
+                    # An armed ``nan`` fault (chaos drills) poisons the
+                    # first active row, exactly like the K=1 emit scan; the
+                    # row's chunk tokens are discarded (the request fails
+                    # with a clean error either way).
+                    forced_nan = False
+                    self._fail_slot(b, self._NONFINITE_MSG)
+                    continue
+                advanced = 0
+                ended = False
+                for i in range(toks.shape[1]):
+                    tok = int(toks[b, i])
+                    if tok == _CHUNK_PAD:
+                        # Not decoding at this column.  A row that ended
+                        # left the loop at its last token; one that folded
+                        # in behind a mixed pass emits from column 1 on.
+                        continue
+                    if tok < 0:
+                        # On-device non-finite sentinel: the device already
+                        # folded the row out of the chunk; fail just this
+                        # request (tokens before the sentinel were emitted).
+                        self._fail_slot(
+                            b, self._NONFINITE_MSG, device_done=True
+                        )
+                        ended = True
+                        break
+                    slot.emitted.append(tok)
+                    self.emitted_total += 1
+                    done = (
+                        tok in slot.stop_tokens
+                        or len(slot.emitted) >= slot.max_new
                     )
-                    ended = True
-                    break
-                slot.emitted.append(tok)
-                self.emitted_total += 1
-                done = (
-                    tok in slot.stop_tokens
-                    or len(slot.emitted) >= slot.max_new
-                )
-                if self.logprobs:
-                    out.append((
-                        slot.request_id, tok, done, float(lps[b, i])
-                    ))
-                else:
-                    out.append((slot.request_id, tok, done))
-                if done:
-                    # The device made the same call mid-chunk (stop set
-                    # and budget live on device), so the row is already
-                    # inactive there — no deactivation upload needed.
-                    self.obs.request_end(slot.request_id, "finished")
-                    self._free_slot(b, device_done=True)
-                    ended = True
-                    break
-                advanced += 1
-            if not ended:
-                # Mirror advance by replay: the device ran one forward
-                # per emitted-and-continued token.
-                self.fill[b] += advanced
-                self.pos[b] += advanced
-                self.remaining[b] = slot.max_new - len(slot.emitted)
+                    if self.logprobs:
+                        out.append((
+                            slot.request_id, tok, done, float(lps[b, i])
+                        ))
+                    else:
+                        out.append((slot.request_id, tok, done))
+                    if done:
+                        # The device made the same call mid-chunk (stop set
+                        # and budget live on device), so the row is already
+                        # inactive there — no deactivation upload needed.
+                        self.obs.request_end(slot.request_id, "finished")
+                        with self.obs.loop_span("emit.free"):
+                            self._free_slot(b, device_done=True)
+                        ended = True
+                        break
+                    advanced += 1
+                if not ended:
+                    # Mirror advance by replay: the device ran one forward
+                    # per emitted-and-continued token.
+                    self.fill[b] += advanced
+                    self.pos[b] += advanced
+                    self.remaining[b] = slot.max_new - len(slot.emitted)
         self._admit()
         return out
 
@@ -3599,19 +3612,20 @@ class ContinuousBatcher:
         _obs_mod.attribute_compiles(self.obs, "_spec_rounds_chunk")
         self.obs.dispatch_begin("spec", "_spec_rounds_chunk", R)
         t0_obs = time.monotonic()
-        (packed, self.tau, self.d_tau_lp, self.d_fill, self.d_pos,
-         self.d_active, self.d_remaining, self.keys, self.pool,
-         self.draft_pool) = _spec_rounds_chunk(
-            self.params, self.draft_params, self.pool, self.draft_pool,
-            self.d_table, self.d_n_alloc, self.d_fill, self.tau,
-            self.d_tau_lp, self.d_pos, self.d_active, self.d_remaining,
-            self.d_stops, self.keys, self.d_temps, self.d_top_ps,
-            self.d_top_ks,
-            t_config=self.config, d_config=self.draft_config,
-            n_draft=self.n_draft, n_rounds=R, all_greedy=all_greedy,
-            use_kernel=self._spec_kernel_ok(), mesh=self.mesh,
-            with_logprobs=self.logprobs, placed=self._mesh_placed,
-        )
+        with self.obs.loop_span("dispatch.submit"):
+            (packed, self.tau, self.d_tau_lp, self.d_fill, self.d_pos,
+             self.d_active, self.d_remaining, self.keys, self.pool,
+             self.draft_pool) = _spec_rounds_chunk(
+                self.params, self.draft_params, self.pool, self.draft_pool,
+                self.d_table, self.d_n_alloc, self.d_fill, self.tau,
+                self.d_tau_lp, self.d_pos, self.d_active, self.d_remaining,
+                self.d_stops, self.keys, self.d_temps, self.d_top_ps,
+                self.d_top_ks,
+                t_config=self.config, d_config=self.draft_config,
+                n_draft=self.n_draft, n_rounds=R, all_greedy=all_greedy,
+                use_kernel=self._spec_kernel_ok(), mesh=self.mesh,
+                with_logprobs=self.logprobs, placed=self._mesh_placed,
+            )
         # THE one device->host sync of the chunk: tokens, acceptance
         # counts and (bitcast) logprobs in a single packed array.
         tf_obs = time.monotonic()
@@ -3767,18 +3781,20 @@ class ContinuousBatcher:
         _obs_mod.attribute_compiles(self.obs, "_spec_round")
         self.obs.dispatch_begin("spec", "_spec_round")
         t0_obs = time.monotonic()
-        outs, acc, lps, self.keys, self.pool, self.draft_pool = _spec_round(
-            self.params, self.draft_params, self.pool, self.draft_pool,
-            jnp.array(self.table), jnp.array(self.n_alloc),
-            jnp.array(self.fill), self.tau, jnp.array(self.pos),
-            jnp.array(self.active), self.keys,
-            jnp.array(self.temp_arr), jnp.array(self.top_p_arr),
-            jnp.array(self.top_k_arr),
-            t_config=self.config, d_config=self.draft_config,
-            n_draft=self.n_draft, all_greedy=all_greedy,
-            use_kernel=self._spec_kernel_ok(), mesh=self.mesh,
-            with_logprobs=self.logprobs, placed=self._mesh_placed,
-        )
+        with self.obs.loop_span("dispatch.submit"):
+            (outs, acc, lps, self.keys, self.pool,
+             self.draft_pool) = _spec_round(
+                self.params, self.draft_params, self.pool, self.draft_pool,
+                jnp.array(self.table), jnp.array(self.n_alloc),
+                jnp.array(self.fill), self.tau, jnp.array(self.pos),
+                jnp.array(self.active), self.keys,
+                jnp.array(self.temp_arr), jnp.array(self.top_p_arr),
+                jnp.array(self.top_k_arr),
+                t_config=self.config, d_config=self.draft_config,
+                n_draft=self.n_draft, all_greedy=all_greedy,
+                use_kernel=self._spec_kernel_ok(), mesh=self.mesh,
+                with_logprobs=self.logprobs, placed=self._mesh_placed,
+            )
         tf_obs = time.monotonic()
         # audit: host-fetch(classic spec path: per-round outs fetch; counted)
         outs = np.asarray(outs)
@@ -3898,8 +3914,19 @@ class ContinuousBatcher:
         live slot."""
         self._fault("alloc")
         out: List[int] = []
+        while len(out) < n and self.free_blocks:
+            out.append(self.free_blocks.pop(0))
+        if len(out) < n:
+            with self.obs.loop_span("admit.evict"):
+                self._evict_into(out, n)
+        return out
+
+    def _evict_into(self, out: List[int], n: int) -> None:
+        """``_alloc_blocks`` once the free list is dry: fill ``out`` up to
+        ``n`` blocks from the idle LRU (and from what a forced subtree drop
+        hands back), then invalidate the evicted blocks' positions."""
         evicted: List[int] = []
-        for _ in range(n):
+        while len(out) < n:
             if self.free_blocks:
                 out.append(self.free_blocks.pop(0))
             else:
@@ -3916,7 +3943,6 @@ class ContinuousBatcher:
             # More evictions than one slot's span is impossible in one
             # call (n <= blocks_per_slot), but stay defensive.
             self._invalidate_evicted(evicted)
-        return out
 
     def _invalidate_evicted(self, evicted: List[int]) -> None:
         """Invalidate repurposed blocks' pool positions (batched; pads
@@ -4232,6 +4258,18 @@ class ContinuousBatcher:
         names demoted nodes a host-tier swap-in could bring back."""
         return self._store.match(keys)
 
+    def _hash_and_match(
+        self, req: "_Request",
+    ) -> Tuple[List[bytes], MatchResult]:
+        """``req``'s chain keys (none for a user who opted out of the
+        prefix cache: do not hash their prompt) and their match."""
+        chain: List[bytes] = []
+        if self.prefix_cache_enabled:
+            with self.obs.loop_span("admit.hash", rid=req.rid):
+                chain = self._chain_keys(req.tokens, self.block_size)
+        with self.obs.loop_span("admit.match", rid=req.rid):
+            return chain, self._match_prefix(chain)
+
     def _claim_blocks(self, blocks: List[int]) -> None:
         self._store.on_claim(blocks)
         for blk in blocks:
@@ -4418,33 +4456,37 @@ class ContinuousBatcher:
         hits deeper."""
         bs = self.block_size
         k = len(grp)
-        kb, keysA, temps, top_ps, top_ks = self._row_bucket(
-            [r for r, _, _ in grp]
-        )
-        T = self._suffix_pad(
-            len(grp[0][0].tokens) - len(grp[0][2]) * bs, len(grp[0][2])
-        )
-        st = np.zeros((kb, T), np.int32)
-        sm = np.zeros((kb, T), bool)
-        table_rows = np.full((kb, self.blocks_per_slot), self.n_blocks,
-                             np.int32)
-        n_alloc_arr = np.zeros((kb,), np.int32)
-        fill0s = np.zeros((kb,), np.int32)
-        row_blocks: List[List[int]] = []
-        row_fresh: List[List[int]] = []
-        for i, (req, chain, hits) in enumerate(grp):
-            n_share = len(hits)
-            L0 = n_share * bs
-            fresh = self._alloc_blocks(req.blocks_needed(bs) - n_share)
-            blocks = hits + fresh
-            row_blocks.append(blocks)
-            row_fresh.append(fresh)
-            suffix = req.tokens[L0:]
-            st[i, : len(suffix)] = suffix
-            sm[i, : len(suffix)] = True
-            table_rows[i, : len(blocks)] = blocks
-            n_alloc_arr[i] = len(blocks)
-            fill0s[i] = L0
+        rid = grp[0][0].rid
+        with self.obs.loop_span("admit.alloc", rid=rid):
+            row_fresh = [
+                self._alloc_blocks(req.blocks_needed(bs) - len(hits))
+                for req, _, hits in grp
+            ]
+        with self.obs.loop_span("admit.insert", rid=rid):
+            kb, keysA, temps, top_ps, top_ks = self._row_bucket(
+                [r for r, _, _ in grp]
+            )
+            T = self._suffix_pad(
+                len(grp[0][0].tokens) - len(grp[0][2]) * bs, len(grp[0][2])
+            )
+            st = np.zeros((kb, T), np.int32)
+            sm = np.zeros((kb, T), bool)
+            table_rows = np.full((kb, self.blocks_per_slot), self.n_blocks,
+                                 np.int32)
+            n_alloc_arr = np.zeros((kb,), np.int32)
+            fill0s = np.zeros((kb,), np.int32)
+            row_blocks: List[List[int]] = []
+            for i, (req, chain, hits) in enumerate(grp):
+                n_share = len(hits)
+                L0 = n_share * bs
+                blocks = hits + row_fresh[i]
+                row_blocks.append(blocks)
+                suffix = req.tokens[L0:]
+                st[i, : len(suffix)] = suffix
+                sm[i, : len(suffix)] = True
+                table_rows[i, : len(blocks)] = blocks
+                n_alloc_arr[i] = len(blocks)
+                fill0s[i] = L0
         # No flash here regardless of T: the gathered view carries
         # PER-ROW cache offsets (fill0 is a vector), which forces
         # forward()'s must_xla path — "auto" resolves to XLA for every
@@ -4459,32 +4501,33 @@ class ContinuousBatcher:
         self._record_dispatch(["prefix_cache"])
         self._fault("suffix_insert")
         self._admit_dispatches += 1
-        tau, tau_lp, keys_out, self.pool = _paged_suffix_insert(
-            self.params, self.pool, jnp.asarray(table_rows),
-            jnp.asarray(n_alloc_arr), jnp.asarray(fill0s),
-            jnp.asarray(st), jnp.asarray(sm), jnp.asarray(keysA),
-            jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks),
-            config=self.config, prefill_chunk=self.prefill_chunk,
-            mesh=self.mesh, with_logprobs=self.logprobs,
-            placed=self._mesh_placed,
-        )
-        if self.spec:
-            # Draft pool: the shared blocks hold the DRAFT model's KV
-            # for the same tokens (written when the chain was first
-            # admitted under this batcher), so only the suffixes run
-            # here too; sampled tokens are discarded.
-            _, _, _, self.draft_pool = _paged_suffix_insert(
-                self.draft_params, self.draft_pool,
-                jnp.asarray(table_rows), jnp.asarray(n_alloc_arr),
-                jnp.asarray(fill0s), jnp.asarray(st), jnp.asarray(sm),
-                jnp.asarray(keysA),
-                jnp.zeros((kb,), jnp.float32),
-                jnp.ones((kb,), jnp.float32),
-                jnp.zeros((kb,), jnp.int32),
-                config=self.draft_config,
-                prefill_chunk=self.prefill_chunk, mesh=self.mesh,
+        with self.obs.loop_span("dispatch.submit"):
+            tau, tau_lp, keys_out, self.pool = _paged_suffix_insert(
+                self.params, self.pool, jnp.asarray(table_rows),
+                jnp.asarray(n_alloc_arr), jnp.asarray(fill0s),
+                jnp.asarray(st), jnp.asarray(sm), jnp.asarray(keysA),
+                jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(top_ks),
+                config=self.config, prefill_chunk=self.prefill_chunk,
+                mesh=self.mesh, with_logprobs=self.logprobs,
                 placed=self._mesh_placed,
             )
+            if self.spec:
+                # Draft pool: the shared blocks hold the DRAFT model's KV
+                # for the same tokens (written when the chain was first
+                # admitted under this batcher), so only the suffixes run
+                # here too; sampled tokens are discarded.
+                _, _, _, self.draft_pool = _paged_suffix_insert(
+                    self.draft_params, self.draft_pool,
+                    jnp.asarray(table_rows), jnp.asarray(n_alloc_arr),
+                    jnp.asarray(fill0s), jnp.asarray(st), jnp.asarray(sm),
+                    jnp.asarray(keysA),
+                    jnp.zeros((kb,), jnp.float32),
+                    jnp.ones((kb,), jnp.float32),
+                    jnp.zeros((kb,), jnp.int32),
+                    config=self.draft_config,
+                    prefill_chunk=self.prefill_chunk, mesh=self.mesh,
+                    placed=self._mesh_placed,
+                )
         # Live (block, offset) pairs ``_scatter_back`` lands: each row's T
         # columns from fill0, less those past its reservation.
         pairs = int(np.minimum(T, n_alloc_arr * bs - fill0s)[:k].sum())
@@ -4574,6 +4617,14 @@ class ContinuousBatcher:
         rows[: len(slots)] = slots
         return (jnp.asarray(rows),)
 
+    def _block(self, reason: str) -> None:
+        """``_admit`` says why it leaves the queue's head queued: the
+        first reason of a pass stands (it is the one that decided), and
+        is counted."""
+        if self.queue and self._blocked is None:
+            self._blocked = reason
+            self.obs.admit_blocked(reason)
+
     def _fused_scheduling(self) -> bool:
         """Fused prefill-decode scheduling is in force for this batcher
         (spec batchers keep classic admission — the round program has no
@@ -4601,10 +4652,14 @@ class ContinuousBatcher:
         instead (either path) — later queue entries keep admitting
         while its swap-in flies."""
         self.obs.loop_phase("admit")
-        self._poll_restores()
-        self._admit_restored_ready()
+        self._blocked = None
+        if self._restoring or self._restored_ready:
+            with self.obs.loop_span("admit.restore"):
+                self._poll_restores()
+                self._admit_restored_ready()
         if self._fused_scheduling():
             if self._pf is not None:
+                self._block("lane")
                 return  # one in-flight admission at a time
             if bool(np.any(self.active)):
                 if self.queue:
@@ -4793,6 +4848,7 @@ class ContinuousBatcher:
                     return
                 self._restored_ready.pop(0)
                 self._setup_fused_prefill(req, chain, hits, claimed=True)
+                self._block("restoring")
             else:
                 self._restored_ready.pop(0)
                 self._admit_shared_group(
@@ -4903,17 +4959,15 @@ class ContinuousBatcher:
         head gets the prefill lane — swap-ins never block admission."""
         free = [b for b, s in self.slots.items() if s is None]
         if not free:
+            self._block("slot")
             return
         while self.queue:
             req = self.queue[0]
             need = req.blocks_needed(self.block_size)
             if need > self._capacity():
+                self._block("capacity")
                 return  # head-of-line blocking (FIFO fairness): wait
-            chain = (
-                self._chain_keys(req.tokens, self.block_size)
-                if self.prefix_cache_enabled else []
-            )
-            m = self._match_prefix(chain)
+            chain, m = self._hash_and_match(req)
             if m.restore:
                 del self.queue[0]
                 # Restoring (or cleanly failed on an injected swap
@@ -4927,6 +4981,7 @@ class ContinuousBatcher:
                 req, chain, m.blocks, claimed=False,
                 snap_in=-1 if m.snap is None else m.snap,
             )
+            self._block("lane")  # what the next head waits for
             return
 
     def _setup_fused_prefill(
@@ -4936,15 +4991,16 @@ class ContinuousBatcher:
         """The ``prefilling``-state setup shared by fresh admissions and
         completed swap-ins (``claimed=True``: the hit blocks were
         claimed at restore begin)."""
-        if not claimed:
-            self._claim_blocks(hits)
         b = next(b for b, s in self.slots.items() if s is None)
         n_share = len(hits)
         base = n_share * self.block_size
-        fresh = self._alloc_blocks(
-            req.blocks_needed(self.block_size) - n_share
-        )
-        self._claim_blocks(fresh)
+        with self.obs.loop_span("admit.alloc", rid=req.rid):
+            if not claimed:
+                self._claim_blocks(hits)
+            fresh = self._alloc_blocks(
+                req.blocks_needed(self.block_size) - n_share
+            )
+            self._claim_blocks(fresh)
         blocks = hits + fresh
         suffix = req.tokens[base:]
         C = self._pf_chunk(len(suffix), n_share)
@@ -4973,17 +5029,18 @@ class ContinuousBatcher:
             request_id=req.rid, emitted=[], max_new=req.max_new,
             stop_tokens=req.stops, blocks=blocks, shared=n_share,
         )
-        self._pf = _Prefill(
-            slot=b, req=req, chain=chain, n_share=n_share, base=base,
-            suffix_len=len(suffix), chunk=C,
-            d_toks=jnp.asarray(toks),
-            d_off=jnp.zeros((), jnp.int32),
-            d_row=jnp.asarray(np.int32(b)),
-            d_base=jnp.asarray(np.int32(base)),
-            d_len=jnp.asarray(np.int32(len(suffix))),
-            d_key=jnp.asarray(self._request_key(req)),
-            snap_in=snap_in,
-        )
+        with self.obs.loop_span("admit.upload", rid=req.rid):
+            self._pf = _Prefill(
+                slot=b, req=req, chain=chain, n_share=n_share, base=base,
+                suffix_len=len(suffix), chunk=C,
+                d_toks=jnp.asarray(toks),
+                d_off=jnp.zeros((), jnp.int32),
+                d_row=jnp.asarray(np.int32(b)),
+                d_base=jnp.asarray(np.int32(base)),
+                d_len=jnp.asarray(np.int32(len(suffix))),
+                d_key=jnp.asarray(self._request_key(req)),
+                snap_in=snap_in,
+            )
         self.fused_admissions_total += 1
         self.prompt_tokens_total += len(req.tokens)
         self.obs.begin_span(req.rid, "prefilling")
@@ -5039,6 +5096,7 @@ class ContinuousBatcher:
         while True:
             free_slots = [b for b, s in self.slots.items() if s is None]
             if not free_slots or not self.queue:
+                self._block("slot")
                 return
             # Head-of-line swap-ins: a queue HEAD whose matched prefix
             # includes host-tier blocks parks in ``restoring`` (async
@@ -5054,15 +5112,12 @@ class ContinuousBatcher:
             if self.host_kv_blocks > 0 and self._store.kind == "radix":
                 while self.queue:
                     req = self.queue[0]
-                    chain0 = (
-                        self._chain_keys(req.tokens, self.block_size)
-                        if self.prefix_cache_enabled else []
-                    )
-                    m0 = self._match_prefix(chain0)
+                    chain0, m0 = self._hash_and_match(req)
                     if not m0.restore:
                         head_match = (req.rid, chain0, m0.blocks)
                         break
                     if req.blocks_needed(self.block_size) > self._capacity():
+                        self._block("capacity")
                         return  # FIFO: wait for capacity
                     del self.queue[0]
                     self._begin_restore(req, chain0, m0)
@@ -5075,17 +5130,14 @@ class ContinuousBatcher:
                 need = req.blocks_needed(self.block_size)
                 if need > budget:
                     # Head-of-line blocking (FIFO fairness): wait.
+                    if not picked:
+                        self._block("capacity")
                     break
                 budget -= need
                 if head_match is not None and head_match[0] == req.rid:
                     chain, hits = head_match[1], head_match[2]
                 else:
-                    # Don't hash prompts for users who opted out.
-                    chain = (
-                        self._chain_keys(req.tokens, self.block_size)
-                        if self.prefix_cache_enabled else []
-                    )
-                    m = self._match_prefix(chain)
+                    chain, m = self._hash_and_match(req)
                     hits = m.blocks
                     if hits and self.recurrent:
                         # A hit on recurrent state layers resumes from a
@@ -5101,7 +5153,8 @@ class ContinuousBatcher:
                     self.ssm_match_tokens_cut_total += m.cut * self.block_size
                 # Claim hits at SELECTION time: a later allocation in
                 # this same admission round must not evict them.
-                self._claim_blocks(hits)
+                with self.obs.loop_span("admit.alloc", rid=req.rid):
+                    self._claim_blocks(hits)
                 picked.append((req, chain, hits))
             if not picked:
                 if lane:
@@ -5129,64 +5182,68 @@ class ContinuousBatcher:
             if not batch:
                 continue
             k = len(batch)
-            kb, keys, temps, top_ps, top_ks = self._row_bucket(batch)
-            # Group width: the max block-padded prompt length, its
-            # BLOCK COUNT pow2-bucketed (clamped to the reservation
-            # cap, which admissibility guarantees covers every row) —
-            # the same jit-cache-key discipline the suffix path
-            # (_suffix_pad) and admission row counts already follow.
-            # Un-bucketed, diverse prompt lengths compiled one
-            # _paged_insert executable per distinct block count
-            # (O(max_len / block_size) cache keys — the over-wide
-            # trace-key domain analysis/retrace.py flags); the extra
-            # padding is masked compute and sentinel block ids drop.
-            nb = min(
-                pow2_bucket(max(
-                    _round_up(len(r.tokens), self.block_size)
+            with self.obs.loop_span("admit.alloc", rid=batch[0].rid):
+                row_blocks = [
+                    self._alloc_blocks(r.blocks_needed(self.block_size))
                     for r in batch
-                ) // self.block_size),
-                self.blocks_per_slot,
-            )
-            P = nb * self.block_size
-            pt = np.zeros((kb, P), np.int32)
-            pm = np.zeros((kb, P), bool)
-            bid = np.full((kb, nb), self.n_blocks, np.int32)
-            row_blocks: List[List[int]] = []
-            for i, req in enumerate(batch):
-                Pb = _round_up(len(req.tokens), self.block_size)
-                need = req.blocks_needed(self.block_size)
-                blocks = self._alloc_blocks(need)
-                row_blocks.append(blocks)
-                self.prompt_tokens_total += len(req.tokens)
-                # Per-session KV accounting (cold batched prefill):
-                # full reservation, zero hit depth.
-                self.obs.request_kv(
-                    req.rid, blocks_held=need, prefix_hit_tokens=0,
-                )
-                self.obs.observe_kv(hit_depth_tokens=0)
-                # RIGHT padding (r5): token j at view column j, so block
-                # content is a pure function of the tokens (the prefix
-                # cache's keying invariant).  Trailing sentinels cover
-                # the group padding past this row's block-padded length.
-                pt[i, :len(req.tokens)] = req.tokens
-                pm[i, :len(req.tokens)] = True
-                bid[i, : Pb // self.block_size] = blocks[
-                    : Pb // self.block_size
                 ]
-            # Host mirror of forward()'s "auto" resolution for the
-            # batched prefill: flash runs iff a chunk exceeds 8 tokens
-            # (the chunked loop forwards ``chunk`` tokens at a time, so
-            # prefill_chunk <= 8 keeps every chunk on XLA; the batch
-            # cache is a fresh scalar-index init_cache, so must_xla
-            # never triggers here).
-            chunk = (
-                self.prefill_chunk
-                if self.prefill_chunk and self.prefill_chunk < P else P
-            )
-            flash = (
-                self.config.attn_impl in ("auto", "flash")
-                and chunk > FLASH_MIN_SEQ
-            )
+            with self.obs.loop_span("admit.insert", rid=batch[0].rid):
+                kb, keys, temps, top_ps, top_ks = self._row_bucket(batch)
+                # Group width: the max block-padded prompt length, its
+                # BLOCK COUNT pow2-bucketed (clamped to the reservation
+                # cap, which admissibility guarantees covers every row) —
+                # the same jit-cache-key discipline the suffix path
+                # (_suffix_pad) and admission row counts already follow.
+                # Un-bucketed, diverse prompt lengths compiled one
+                # _paged_insert executable per distinct block count
+                # (O(max_len / block_size) cache keys — the over-wide
+                # trace-key domain analysis/retrace.py flags); the extra
+                # padding is masked compute and sentinel block ids drop.
+                nb = min(
+                    pow2_bucket(max(
+                        _round_up(len(r.tokens), self.block_size)
+                        for r in batch
+                    ) // self.block_size),
+                    self.blocks_per_slot,
+                )
+                P = nb * self.block_size
+                pt = np.zeros((kb, P), np.int32)
+                pm = np.zeros((kb, P), bool)
+                bid = np.full((kb, nb), self.n_blocks, np.int32)
+                for i, req in enumerate(batch):
+                    Pb = _round_up(len(req.tokens), self.block_size)
+                    need = req.blocks_needed(self.block_size)
+                    blocks = row_blocks[i]
+                    self.prompt_tokens_total += len(req.tokens)
+                    # Per-session KV accounting (cold batched prefill):
+                    # full reservation, zero hit depth.
+                    self.obs.request_kv(
+                        req.rid, blocks_held=need, prefix_hit_tokens=0,
+                    )
+                    self.obs.observe_kv(hit_depth_tokens=0)
+                    # RIGHT padding (r5): token j at view column j, so block
+                    # content is a pure function of the tokens (the prefix
+                    # cache's keying invariant).  Trailing sentinels cover
+                    # the group padding past this row's block-padded length.
+                    pt[i, :len(req.tokens)] = req.tokens
+                    pm[i, :len(req.tokens)] = True
+                    bid[i, : Pb // self.block_size] = blocks[
+                        : Pb // self.block_size
+                    ]
+                # Host mirror of forward()'s "auto" resolution for the
+                # batched prefill: flash runs iff a chunk exceeds 8 tokens
+                # (the chunked loop forwards ``chunk`` tokens at a time, so
+                # prefill_chunk <= 8 keeps every chunk on XLA; the batch
+                # cache is a fresh scalar-index init_cache, so must_xla
+                # never triggers here).
+                chunk = (
+                    self.prefill_chunk
+                    if self.prefill_chunk and self.prefill_chunk < P else P
+                )
+                flash = (
+                    self.config.attn_impl in ("auto", "flash")
+                    and chunk > FLASH_MIN_SEQ
+                )
             for req in batch:
                 self.obs.begin_span(req.rid, "prefilling")
             _obs_mod.attribute_compiles(self.obs, "_paged_insert")
@@ -5198,35 +5255,36 @@ class ContinuousBatcher:
                 self._fault("flash_kernel")
             self._admit_dispatches += 1
             slot_ids = [next(slot_iter) for _ in range(k)]
-            taus, tau_lps, plens, keys_out, self.pool = _paged_insert(
-                # audit: host-upload(admission-time prompt/state upload
-                # for the whole batch — once per admission round, never
-                # per-token)
-                self.params, self.pool, jnp.asarray(bid),
-                jnp.asarray(pt), jnp.asarray(pm), jnp.asarray(keys),
-                jnp.asarray(temps), jnp.asarray(top_ps),
-                jnp.asarray(top_ks), *self._state_operands(slot_ids),
-                config=self.config, prefill_chunk=self.prefill_chunk,
-                mesh=self.mesh, with_logprobs=self.logprobs,
-                placed=self._mesh_placed,
-            )
-            if self.spec:
-                # Prefill the draft pool over the same reserved blocks
-                # (its sampled tokens are discarded — the target picks
-                # tau, and each row's key chain carries from the TARGET
-                # insert only).
-                _, _, _, _, self.draft_pool = _paged_insert(
-                    # audit: host-upload(draft-pool twin of the
-                    # admission-time upload above)
-                    self.draft_params, self.draft_pool, jnp.asarray(bid),
+            with self.obs.loop_span("dispatch.submit"):
+                taus, tau_lps, plens, keys_out, self.pool = _paged_insert(
+                    # audit: host-upload(admission-time prompt/state upload
+                    # for the whole batch — once per admission round, never
+                    # per-token)
+                    self.params, self.pool, jnp.asarray(bid),
                     jnp.asarray(pt), jnp.asarray(pm), jnp.asarray(keys),
-                    jnp.zeros((kb,), jnp.float32),
-                    jnp.ones((kb,), jnp.float32),
-                    jnp.zeros((kb,), jnp.int32),
-                    config=self.draft_config,
-                    prefill_chunk=self.prefill_chunk, mesh=self.mesh,
+                    jnp.asarray(temps), jnp.asarray(top_ps),
+                    jnp.asarray(top_ks), *self._state_operands(slot_ids),
+                    config=self.config, prefill_chunk=self.prefill_chunk,
+                    mesh=self.mesh, with_logprobs=self.logprobs,
                     placed=self._mesh_placed,
                 )
+                if self.spec:
+                    # Prefill the draft pool over the same reserved blocks
+                    # (its sampled tokens are discarded — the target picks
+                    # tau, and each row's key chain carries from the TARGET
+                    # insert only).
+                    _, _, _, _, self.draft_pool = _paged_insert(
+                        # audit: host-upload(draft-pool twin of the
+                        # admission-time upload above)
+                        self.draft_params, self.draft_pool, jnp.asarray(bid),
+                        jnp.asarray(pt), jnp.asarray(pm), jnp.asarray(keys),
+                        jnp.zeros((kb,), jnp.float32),
+                        jnp.ones((kb,), jnp.float32),
+                        jnp.zeros((kb,), jnp.int32),
+                        config=self.draft_config,
+                        prefill_chunk=self.prefill_chunk, mesh=self.mesh,
+                        placed=self._mesh_placed,
+                    )
             # audit: host-upload(slot-index upload, once per admission)
             idx = jnp.asarray(np.asarray(slot_ids, np.int32))
             self.tau = self.tau.at[idx].set(taus[:k])

@@ -1,3 +1,3 @@
-from .profiling import DecodeStats, Timer, device_op_times, trace
+from .profiling import DecodeStats, Timer, trace
 
-__all__ = ["DecodeStats", "Timer", "device_op_times", "trace"]
+__all__ = ["DecodeStats", "Timer", "trace"]

@@ -17,10 +17,8 @@ import dataclasses
 import glob
 import os
 import re
-import shutil
-import tempfile
 import time
-from typing import Callable, Dict, Iterable, Iterator, List, Sequence, Tuple
+from typing import Dict, Iterable, Iterator, List, Sequence, Tuple
 
 import jax
 
@@ -62,69 +60,6 @@ def _block_on_pending() -> None:
     jax.effects_barrier()
 
 
-def device_op_times(
-    thunk: Callable[[], None],
-    *,
-    by: str = "op",
-    device_substr: str = "TPU",
-) -> Dict[str, int]:
-    """Run ``thunk`` under a profiler trace and return device-op time in
-    PICOSECONDS aggregated by HLO op name (``by="op"``) or by the source
-    file XLA attributes the op to (``by="source"``).
-
-    This is the measurement primitive behind the round-3..5 perf
-    numbers in ROADMAP.md: wall-clock timing of a single
-    dispatch includes the host's dispatch overhead, not just the op,
-    while device-op durations from the xplane are the device's own
-    clock.  Caller contract: warm
-    the thunk (compile) BEFORE calling, or the trace will be dominated
-    by compilation; outer ``%while`` ops are dropped so loop bodies are
-    not double-counted.
-
-    Requires the TensorFlow profiler protos (`tensorflow.tsl`); raises
-    ImportError where unavailable.
-    """
-    assert by in ("op", "source"), by
-    tmpdir = tempfile.mkdtemp(prefix="jlt_xplane_")
-    try:
-        # trace() stops the profiler even when thunk raises — a leaked
-        # active profiler would fail every later start_trace in the
-        # process, cascading one failure into many.
-        with trace(tmpdir):
-            thunk()
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-
-        path = glob.glob(f"{tmpdir}/**/*.xplane.pb", recursive=True)[0]
-        space = xplane_pb2.XSpace()
-        with open(path, "rb") as f:
-            space.ParseFromString(f.read())
-    finally:
-        shutil.rmtree(tmpdir, ignore_errors=True)
-    plane = next(p for p in space.planes if device_substr in p.name)
-    stat_names = {k: v.name for k, v in plane.stat_metadata.items()}
-    op_name, op_src = {}, {}
-    for k, v in plane.event_metadata.items():
-        op_name[k] = v.name
-        src = next(
-            (
-                st.str_value
-                for st in v.stats
-                if stat_names.get(st.metadata_id) == "source"
-            ),
-            "",
-        )
-        m = re.search(r"/(\w+\.py):", src)
-        op_src[k] = m.group(1) if m else "other"
-    line = next(ln for ln in plane.lines if ln.name == "XLA Ops")
-    agg: Dict[str, int] = collections.Counter()
-    key = op_name if by == "op" else op_src
-    for e in line.events:
-        if op_name[e.metadata_id].startswith("%while"):
-            continue  # outer loops double-count their bodies
-        agg[key[e.metadata_id]] += e.duration_ps
-    return agg
-
-
 # Event-name spellings that carry a jitted-program identity in an
 # xplane capture: the host plane's python line traces dispatch frames
 # as ``PjitFunction(<name>)``, and device planes' "XLA Modules" line
@@ -138,6 +73,9 @@ _JIT_MODULE_RE = re.compile(r"^jit_(.+?)(?:\(\d+\))?(?:\.\d+)?$")
 # loop thread, on the clock the device events are on.
 LOOP_PREFIX = "llm.loop."
 DISPATCH_EVENT = "llm.dispatch"
+# ... and the child spans of those (obs.Observability.loop_span), which
+# nest inside a phase event, a dispatch event or one another.
+SPAN_PREFIX = "llm.span."
 
 Interval = Tuple[float, float]
 
@@ -198,6 +136,36 @@ def split_by_overlap(
     return dict(out)
 
 
+def innermost(
+    events: Sequence[Tuple[str, float, float]],
+) -> List[Tuple[str, float, float]]:
+    """One thread's nested events ``(label, start, end)`` cut into pieces
+    that do not overlap, each labelled by the innermost event over it."""
+    out: List[Tuple[str, float, float]] = []
+    stack: List[Tuple[str, float]] = []  # (label, end), outermost first
+    cur = 0.0
+
+    def close(upto: float) -> float:
+        t = cur
+        while stack and stack[-1][1] <= upto:
+            label, end = stack.pop()
+            if end > t:
+                out.append((label, t, end))
+                t = end
+        return t
+
+    for label, s, e in sorted(events, key=lambda ev: (ev[1], -ev[2])):
+        cur = close(s)
+        if stack:
+            if s > cur:
+                out.append((stack[-1][0], cur, s))
+            e = min(e, stack[-1][1])  # a child never outlives its parent
+        cur = s
+        stack.append((label, e))
+    close(float("inf"))
+    return out
+
+
 def summarize_xplane(log_dir: str) -> Dict[str, object]:
     """Aggregate the newest xplane capture under ``log_dir``, read with
     ``jax.profiler.ProfileData`` alone.
@@ -213,7 +181,9 @@ def summarize_xplane(log_dir: str) -> Dict[str, object]:
     (``llm.loop.<phase>`` by phase, ``llm.dispatch`` as ``in
     dispatch``, the rest ``unnamed``), so the attribution of device
     idle time to host work comes from the running server with no clock
-    join.  Raises FileNotFoundError when ``log_dir`` holds no capture —
+    join — and ``idle_by_span_ms``, the same gaps with each part given
+    to the innermost ``llm.span.<name>`` event over it (by span name),
+    else to its phase, else ``in dispatch``, else ``unnamed``.  Raises FileNotFoundError when ``log_dir`` holds no capture —
     the /debug/profile/summary endpoint maps it to a clean 404.
     """
     from jax.profiler import ProfileData
@@ -230,6 +200,7 @@ def summarize_xplane(log_dir: str) -> Dict[str, object]:
     device_ms: Dict[str, float] = collections.defaultdict(float)
     host_ms: Dict[str, float] = collections.defaultdict(float)
     loop: List[Tuple[str, float, float]] = []
+    spans: List[Tuple[str, float, float]] = []
     ops: List[Interval] = []  # of the first device plane that has any
     for plane in ProfileData.from_file(path).planes:
         is_device = any(t in plane.name for t in ("TPU", "GPU"))
@@ -254,6 +225,12 @@ def summarize_xplane(log_dir: str) -> Dict[str, object]:
                         (label, e.start_ns, e.start_ns + e.duration_ns)
                     )
                     continue
+                if name.startswith(SPAN_PREFIX) and not is_device:
+                    spans.append((
+                        name[len(SPAN_PREFIX):], e.start_ns,
+                        e.start_ns + e.duration_ns,
+                    ))
+                    continue
                 prog = normalize_program_name(name)
                 if prog is not None:
                     sink[prog] += e.duration_ns / 1e6
@@ -275,6 +252,12 @@ def summarize_xplane(log_dir: str) -> Dict[str, object]:
         "idle_by_phase_ms": {
             k: round(v / 1e6, 3)
             for k, v in sorted(split_by_overlap(gaps, loop).items())
+        },
+        "idle_by_span_ms": {
+            k: round(v / 1e6, 3)
+            for k, v in sorted(
+                split_by_overlap(gaps, innermost(loop + spans)).items()
+            )
         },
     }
 

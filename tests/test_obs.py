@@ -20,9 +20,11 @@ import pytest
 
 from jax_llama_tpu import get_config, init_params
 from jax_llama_tpu.obs import (
+    ADMIT_BLOCKED,
     HISTOGRAMS,
     LABELED_HISTOGRAMS,
     LOOP_PHASES,
+    LOOP_SPANS,
     METRICS,
     Histogram,
     Observability,
@@ -306,6 +308,228 @@ def test_trace_json_serving_loop_track():
     recent = [e for e in obs.trace_json(window_ms=5000.0)["traceEvents"]
               if e.get("cat") == "loop"]
     assert 0 < len(recent) < len(loop)
+
+
+# ---------------------------------------------------------------------------
+# Loop spans: the parts of a phase, and of a dispatch's own host time
+# ---------------------------------------------------------------------------
+
+def _spanned_iteration(obs, clk, kind="fused", rid=7, blocked=None):
+    """One iteration with an admission in it: admit = 1 self + hash 2 +
+    match 1 + alloc 4 (evict 3 inside) + upload 2; prep = 1 self +
+    sync_rows 2; the dispatch = submit 3 + publish 1 of 100 ms; emit =
+    replay 2 (free 1 inside) + 1 self."""
+    obs.loop_phase("admit")
+    clk.advance(0.001)
+    with obs.loop_span("admit.hash", rid=rid):
+        clk.advance(0.002)
+    with obs.loop_span("admit.match", rid=rid):
+        clk.advance(0.001)
+    with obs.loop_span("admit.alloc", rid=rid):
+        clk.advance(0.001)
+        with obs.loop_span("admit.evict"):
+            clk.advance(0.003)
+    with obs.loop_span("admit.upload", rid=rid):
+        clk.advance(0.002)
+    obs.loop_phase("prep")
+    clk.advance(0.001)
+    with obs.loop_span("prep.sync_rows"):
+        clk.advance(0.002)
+    obs.dispatch_begin(kind, "_fused_chunk", 2)
+    with obs.loop_span("dispatch.submit"):
+        clk.advance(0.003)
+    with obs.loop_span("dispatch.publish", rid=rid):
+        clk.advance(0.001)
+    clk.advance(0.096)
+    seq = obs.record_dispatch(kind, k=2, wall_ms=100.0, then="emit",
+                              queued=1, blocked=blocked)
+    with obs.loop_span("emit.replay"):
+        clk.advance(0.001)
+        with obs.loop_span("emit.free"):
+            clk.advance(0.001)
+    clk.advance(0.001)
+    return seq
+
+
+def _parent_ms(rec, name):
+    """What a span's time is part of: its parent span's, its phase's, or
+    the dispatch's wall time."""
+    parent = LOOP_SPANS[name]
+    if parent == "dispatch":
+        return rec["wall_ms"]
+    if parent in LOOP_SPANS:
+        return rec["span_ms"][parent]
+    return rec["host_ms"][parent]
+
+
+def test_spans_ride_the_record_and_leave_the_tiling_alone():
+    clk = FakeClock()
+    obs = Observability(clock=clk)
+    _one_iteration(obs, clk)
+    first = _spanned_iteration(obs, clk, blocked="lane")
+    second = _spanned_iteration(obs, clk)
+    recs = obs.dispatches_json()["dispatches"]
+    _assert_tiles(recs)  # host_ms: the phases' keys, summing to gap_ms
+    assert set(LOOP_SPANS.values()) - set(LOOP_SPANS) <= (
+        LOOP_PHASES | {"dispatch"}
+    )
+    rec = recs[first]
+    assert rec["host_ms"] == pytest.approx(
+        {"emit": 2.0, "deliver": 1.0, "admit": 10.0, "prep": 3.0})
+    assert rec["span_ms"] == pytest.approx({
+        "admit.hash": 2.0, "admit.match": 1.0, "admit.alloc": 4.0,
+        "admit.evict": 3.0, "admit.upload": 2.0, "prep.sync_rows": 2.0,
+        "dispatch.submit": 3.0, "dispatch.publish": 1.0,
+    })
+    assert rec["submit_ms"] == pytest.approx(3.0)
+    assert set(rec["span_n"].values()) == {1}
+    assert (rec["queued"], rec["blocked"]) == (1, "lane")
+    # The emit spans closed after `first` landed: they led to `second`.
+    rec = recs[second]
+    assert rec["span_ms"]["emit.replay"] == pytest.approx(2.0)
+    assert rec["span_ms"]["emit.free"] == pytest.approx(1.0)
+    assert rec["host_ms"]["emit"] == pytest.approx(3.0)
+    assert rec["blocked"] is None
+    for r in recs[1:]:
+        for name, ms in r["span_ms"].items():
+            assert ms <= _parent_ms(r, name) + 1e-6, (name, r)
+    # The first record of an Observability has no gap and still its submit.
+    assert recs[0]["span_ms"] == {} and "host_ms" not in recs[0]
+    # The ring: cause, request and the record each span led to.
+    ring = obs.loop_spans_json()
+    got = {(s["name"], s["seq"]): s for s in ring}
+    assert got["admit.hash", first]["parent"] == "admit"
+    assert got["admit.evict", first]["parent"] == "admit.alloc"
+    assert got["admit.evict", first]["rid"] == 7  # its parent's
+    assert got["dispatch.submit", first]["parent"] == "dispatch"
+    assert got["emit.free", second]["parent"] == "emit.replay"
+    assert got["admit.match", second]["duration_ms"] == pytest.approx(1.0)
+    assert {s["rid"] for s in obs.loop_spans_json(rids=[7])} == {7}
+
+
+@pytest.mark.parametrize("call", [
+    lambda o: o.loop_span("admit.mach"),
+    lambda o: o.loop_span("admit"),
+    lambda o: o.admit_blocked("lanes"),
+    lambda o: o.record_dispatch("decode", queued=1, blocked="pool"),
+], ids=["span-typo", "a-phase-is-no-span", "reason-typo", "record-reason"])
+def test_unknown_span_or_reason_raises(call):
+    with pytest.raises(ValueError, match="unknown (loop span|blocked)"):
+        call(Observability(clock=FakeClock()))
+
+
+def test_a_span_outside_its_parent_records_nothing():
+    """``_free_slot`` from a cancel (phase ``intake``) is no
+    ``emit.free``; ``_alloc_blocks`` from a handoff import (phase
+    ``control``) no ``admit.evict``: the time stays with the phase."""
+    clk = FakeClock()
+    obs = Observability(clock=clk)
+    _one_iteration(obs, clk)
+    obs.loop_phase("intake")
+    with obs.loop_span("emit.free"):
+        clk.advance(0.004)
+    obs.loop_phase("admit")
+    with obs.loop_span("admit.evict"):      # not under admit.alloc
+        clk.advance(0.002)
+    with obs.loop_span("dispatch.submit"):  # no dispatch is open
+        clk.advance(0.001)
+    _one_iteration(obs, clk)
+    rec = obs.dispatches[-1]
+    assert set(rec["span_ms"]) == set() and not obs._sp_open
+    assert rec["host_ms"]["intake"] == pytest.approx(4.0 + 1.0)
+    assert rec["host_ms"]["admit"] == pytest.approx(3.0 + 2.0)
+
+
+def test_a_span_ends_at_its_exit_or_at_the_next_dispatch():
+    """A body that raises closes its span on the way out; a span left
+    open (entered by hand, never exited) is closed at dispatch_begin,
+    one inside the dispatch at the record; an abandoned dispatch takes
+    its spans' time back with its own."""
+    clk = FakeClock()
+    obs = Observability(clock=clk)
+    _one_iteration(obs, clk)
+    obs.loop_phase("admit")
+    with pytest.raises(RuntimeError):
+        with obs.loop_span("admit.alloc"):
+            clk.advance(0.002)
+            with obs.loop_span("admit.evict"):
+                clk.advance(0.001)
+                raise RuntimeError("injected")
+    assert not obs._sp_open
+    left_open = obs.loop_span("admit.insert", rid=3)
+    left_open.__enter__()
+    clk.advance(0.004)
+    obs.dispatch_begin("insert", "_paged_insert", 1)
+    assert not obs._sp_open
+    obs.loop_span("dispatch.submit").__enter__()
+    clk.advance(0.005)
+    obs.record_dispatch("insert", wall_ms=5.0)
+    assert not obs._sp_open
+    left_open.__exit__(None, None, None)  # closed already: nothing twice
+    rec = obs.dispatches[-1]
+    assert rec["span_ms"] == pytest.approx({
+        "admit.alloc": 3.0, "admit.evict": 1.0, "admit.insert": 4.0,
+        "dispatch.submit": 5.0,
+    })
+    assert rec["host_ms"]["admit"] == pytest.approx(7.0)
+    # A dispatch that raised after its submit began.
+    obs.loop_phase("prep")
+    clk.advance(0.001)
+    obs.dispatch_begin("decode", "_paged_decode_chunk", 8)
+    with pytest.raises(RuntimeError):
+        with obs.loop_span("dispatch.submit"):
+            clk.advance(0.030)
+            raise RuntimeError("injected")
+    _one_iteration(obs, clk)
+    rec = obs.dispatches[-1]
+    assert "dispatch.submit" not in rec["span_ms"] and "submit_ms" not in rec
+    assert rec["host_ms"]["prep"] == pytest.approx(1.0 + 30.0 + 2.0)
+    _assert_tiles(obs.dispatches_json()["dispatches"])
+
+
+def test_span_metrics_registered_and_folded_at_records():
+    for fam in ("loop_span_ms_total", "loop_span_total",
+                "dispatch_submit_ms_total", "admit_blocked_total"):
+        assert metric_meta(fam)[0] == "counter", fam
+    clk = FakeClock()
+    obs = Observability(clock=clk)
+    _spanned_iteration(obs, clk)
+    _spanned_iteration(obs, clk)
+    obs.admit_blocked("lane")
+    obs.admit_blocked("lane")
+    obs.admit_blocked("capacity")
+    assert obs.metrics()["dispatch_submit_ms_total"] == pytest.approx(6.0)
+    fams = {}
+    for fam, lab, v in obs.loop_span_metrics():
+        fams.setdefault(fam, {})[next(iter(lab.values()))] = v
+    assert fams["loop_span_ms_total"]["admit.alloc"] == pytest.approx(8.0)
+    assert fams["loop_span_total"]["admit.evict"] == 2
+    # emit.replay of the second iteration waits for a third record.
+    assert fams["loop_span_total"]["emit.replay"] == 1
+    assert fams["admit_blocked_total"] == {"lane": 2, "capacity": 1}
+    assert set(fams["admit_blocked_total"]) <= set(ADMIT_BLOCKED)
+
+
+def test_trace_json_nests_the_spans_on_the_serving_loop_track():
+    clk = FakeClock()
+    obs = Observability(clock=clk)
+    _one_iteration(obs, clk)
+    _spanned_iteration(obs, clk)
+    evs = obs.trace_json()["traceEvents"]
+    spans = [e for e in evs if e.get("cat") == "loop_span"]
+    assert {e["name"] for e in spans} >= {
+        "admit.hash", "admit.evict", "dispatch.submit", "emit.free"}
+    phases = [e for e in evs if e.get("cat") == "loop"]
+    assert {e["tid"] for e in spans} == {phases[0]["tid"]}
+    hash_ev = next(e for e in spans if e["name"] == "admit.hash")
+    assert hash_ev["args"] == {"parent": "admit", "rid": 7, "seq": 1}
+    assert any(  # under its phase on the track
+        p["name"] == "admit" and p["ts"] <= hash_ev["ts"]
+        and hash_ev["ts"] + hash_ev["dur"] <= p["ts"] + p["dur"] + 1
+        for p in phases
+    )
+    disp = [e for e in evs if e.get("cat") == "dispatch"]
+    assert "span_ms" in disp[-1]["args"] and "submit_ms" in disp[-1]["args"]
 
 
 def test_received_span_opens_the_timeline():
@@ -1054,6 +1278,116 @@ def test_profiler_capture_holds_the_loop_on_the_trace_clock(model, tmp_path):
         assert not any(
             d.start_ns < mid < d.start_ns + d.duration_ns for d in disp
         )
+    # The child spans: ``llm.span.<name>``, never ``llm.loop.``, each
+    # inside its phase's or its dispatch's event, with the ring number of
+    # the record it led to.
+    spans = [e for e in events if e.name.startswith("llm.span.")]
+    assert {e.name for e in spans} == {
+        "llm.span.dispatch.submit", "llm.span.emit.replay",
+    }
+    assert {e.name for e in phases} <= {
+        "llm.loop." + p for p in LOOP_PHASES}
+    for e in spans:
+        outer = disp if "dispatch" in e.name else [
+            p for p in phases if p.name == "llm.loop.emit"]
+        assert any(
+            o.start_ns <= e.start_ns and e.start_ns + e.duration_ns
+            <= o.start_ns + o.duration_ns for o in outer
+        ), e.name
+        assert dict(e.stats)["seq"] in ring or (
+            dict(e.stats)["seq"] == first + 4)  # the last emit's
+
+
+def test_a_served_workload_yields_every_span_with_its_request_and_record():
+    """Recurrent state layers over a pool of 18 blocks: an insert on the
+    idle server, a 105-token document through the fused lane (snapshots,
+    publish), a re-ask that hits its prefix, and a last prompt that finds
+    the free list dry and evicts.  Every span but ``admit.restore`` (the
+    host tier, which this block refuses: the restoring drill below holds
+    it) closes at least once; an admission's spans carry its rid and the
+    ring number of the record they led to, and every span's time is part
+    of its parent's."""
+    import json as _json
+    from pathlib import Path
+
+    import jax_llama_tpu as jlt
+    from jax_llama_tpu import config as config_mod
+
+    raw = _json.loads((
+        Path(__file__).resolve().parent.parent / "benchmark" / "configs"
+        / "Phi-4-mini-flash-reasoning.json"
+    ).read_text())
+    raw.update(
+        hidden_size=64, intermediate_size=128, num_attention_heads=8,
+        num_key_value_heads=4, num_hidden_layers=8, vocab_size=512,
+        sliding_window=24, torch_dtype="float32",
+    )
+    bookkeeping = ("source", "architecture", "reference", "reduced",
+                   "assumed", "deployment")
+    cfg = config_mod.from_published(
+        {k: v for k, v in raw.items() if k not in bookkeeping},
+        max_seq_len=256, attn_impl="auto")
+    params = jlt.init_params(jax.random.PRNGKey(3), cfg)
+    cb = ContinuousBatcher(
+        params, cfg, n_slots=3, block_size=BS, n_blocks=18,
+        decode_chunk=4, prefill_budget=32)
+    rng = np.random.RandomState(4)
+    draw = lambda n: [int(t) for t in rng.randint(0, 512, size=n)]  # noqa: E731
+    doc = draw(100)
+
+    def steps(n):
+        for _ in range(n):
+            cb.step()
+
+    cb.submit(draw(20), max_new_tokens=120)     # the holder: an insert
+    steps(3)
+    first = cb.submit(doc + draw(5), max_new_tokens=4)   # 7 blocks, fused
+    while any(s is not None and s.request_id == first
+              for s in cb.slots.values()) or cb.queue:
+        steps(1)
+    hit = cb.submit(doc + draw(9), max_new_tokens=4)     # hits 96 tokens
+    steps(8)
+    assert cb.obs.timeline_json(hit)["kv"]["prefix_hit_tokens"] == 96
+    cold = cb.submit(draw(100), max_new_tokens=4)        # evicts the doc
+    cb.run_to_completion()
+
+    recs = {d["seq"]: d for d in cb.obs.dispatches}
+    _assert_tiles(list(recs.values()))
+    spans = cb.obs.loop_spans_json()
+    names = {s["name"] for s in spans}
+    assert names == set(LOOP_SPANS) - {"admit.restore"}, (
+        set(LOOP_SPANS) - names)
+    assert {n for d in recs.values() for n in d["span_ms"]} == names
+    for sp in spans:
+        assert sp["parent"] == LOOP_SPANS[sp["name"]]
+        if sp["seq"] > max(recs):   # the last emit leads to no record
+            assert sp["name"] in ("emit.replay", "emit.free")
+            continue
+        rec = recs[sp["seq"]]       # the record its gap led to
+        assert sp["name"] in rec["span_ms"]
+        assert sp["end_ms"] <= rec["start_ms"] + rec["wall_ms"] + 0.01
+    for d in recs.values():
+        for name, ms in d["span_ms"].items():
+            if "host_ms" in d or name.startswith("dispatch."):
+                assert ms <= _parent_ms(d, name) + 0.01, (name, d)
+        if d["kind"] in ("decode", "fused", "insert"):
+            assert 0.0 < d["submit_ms"] <= d["wall_ms"]
+    # The admissions: each with its rid, on the record it led to.
+    for rid, kind in ((first, "fused"), (hit, "fused"), (cold, "fused")):
+        own = cb.obs.timeline_json(rid)["loop_spans"]
+        assert {s["rid"] for s in own} == {rid}
+        by_name = {s["name"]: s for s in own}
+        assert {"admit.hash", "admit.match", "admit.alloc", "admit.upload",
+                "prep.snapshots", "dispatch.publish"} <= set(by_name), rid
+        led_to = recs[by_name["admit.upload"]["seq"]]
+        assert led_to["kind"] == kind and rid in led_to["rids"]
+        assert by_name["admit.hash"]["seq"] == led_to["seq"]
+    assert "admit.evict" in {
+        s["name"] for s in cb.obs.timeline_json(cold)["loop_spans"]}
+    assert not [s for s in cb.obs.timeline_json(hit)["loop_spans"]
+                if s["name"] == "admit.evict"]
+    assert "admit.insert" in recs[min(recs)]["span_ms"]   # the holder's
+    assert cb.stats()["host_syncs_per_token"] < 1
 
 
 def test_failed_request_timeline_records_error(model):
@@ -1125,6 +1459,11 @@ def test_restoring_fused_admission_full_timeline(model):
     assert saw_restoring
     st = cb.stats()
     assert st["swap_ins_total"] == 1
+    # The swap-in's polling and its admission are the admit.restore span
+    # (the one span the recurrent workload above cannot reach).
+    restores = [s for s in cb.obs.loop_spans_json()
+                if s["name"] == "admit.restore"]
+    assert restores and {s["parent"] for s in restores} == {"admit"}
 
     tl = _timeline(cb, rid)
     assert tl["outcome"] == "finished"

@@ -75,6 +75,35 @@ def test_busy_is_a_union_and_idle_splits_by_overlap():
     assert split_by_overlap(gaps, []) == {"unnamed": 7.0}
 
 
+def test_an_idle_gap_goes_to_the_innermost_event_over_it():
+    """``idle_by_span_ms``: phases, dispatches and their child spans cut
+    into pieces that do not overlap, each the innermost event's, so a gap
+    under both ``admit`` and ``admit.match`` counts once, for the leaf."""
+    from jax_llama_tpu.utils.profiling import innermost, split_by_overlap
+
+    events = [
+        ("admit", 0, 10), ("admit.hash", 1, 3), ("admit.alloc", 4, 9),
+        ("admit.evict", 5, 7), ("in dispatch", 10, 30),
+        ("dispatch.submit", 10, 12), ("emit", 30, 34),
+        ("emit.replay", 30, 33), ("emit.free", 32, 35),  # outlives: clipped
+    ]
+    pieces = innermost(events)
+    assert pieces == [
+        ("admit", 0, 1), ("admit.hash", 1, 3), ("admit", 3, 4),
+        ("admit.alloc", 4, 5), ("admit.evict", 5, 7), ("admit.alloc", 7, 9),
+        ("admit", 9, 10), ("dispatch.submit", 10, 12),
+        ("in dispatch", 12, 30), ("emit.replay", 30, 32),
+        ("emit.free", 32, 33), ("emit", 33, 34),
+    ]
+    gaps = [(2, 6), (11, 13), (33, 40)]
+    assert split_by_overlap(gaps, pieces) == {
+        "admit.hash": 1.0, "admit": 1.0, "admit.alloc": 1.0,
+        "admit.evict": 1.0, "dispatch.submit": 1.0, "in dispatch": 1.0,
+        "emit": 1.0, "unnamed": 6.0,
+    }
+    assert innermost([]) == []
+
+
 def test_summarize_xplane_reads_a_capture_with_jax_alone(tmp_path):
     """summarize_xplane parses with jax.profiler.ProfileData (no
     TensorFlow protos): host-side time lands on the jitted program, and
@@ -104,3 +133,4 @@ def test_summarize_xplane_reads_a_capture_with_jax_alone(tmp_path):
     assert out["total_device_ms"] == 0.0
     assert out["busy_ms"] == 0.0 and out["idle_ms"] == 0.0
     assert out["idle_by_phase_ms"] == {}
+    assert out["idle_by_span_ms"] == {}

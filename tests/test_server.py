@@ -886,6 +886,14 @@ def test_metrics_exposition_valid_prometheus(model):
     assert sum(phase_ms.values()) == pytest.approx(
         samples["llm_loop_gap_ms_total"], abs=0.5
     )
+    # ... and their parts: the child spans, the submit, the blocked head.
+    for fam in ("llm_loop_span_ms_total", "llm_loop_span_total",
+                "llm_dispatch_submit_ms_total", "llm_admit_blocked_total"):
+        assert types[fam] == "counter", fam
+    assert samples['llm_loop_span_total{span="dispatch.submit"}'] >= 1
+    assert 0.0 < samples["llm_dispatch_submit_ms_total"] == pytest.approx(
+        samples['llm_loop_span_ms_total{span="dispatch.submit"}'], abs=0.5
+    )
     # SLO gauges present (unset deadlines -> 0 / attainment 1.0).
     assert samples["llm_slo_ttft_ms"] == 0.0
     assert samples["llm_slo_attainment"] == 1.0
@@ -1093,6 +1101,15 @@ def test_fused_run_names_every_phase_of_the_gap(model):
             f'llm_loop_phase_ms_total{{phase="{phase}"}}'
         ] > 0.0, phase
     assert samples["llm_loop_gap_ms_total"] > 0.0
+    # The parts ride the same records, beside host_ms and never in it.
+    fused = next(d for d in recs if d["kind"] == "fused")
+    assert {"admit.hash", "admit.match", "admit.alloc", "admit.upload",
+            "prep.sync_rows", "dispatch.submit"} <= set(fused["span_ms"])
+    assert fused["submit_ms"] <= fused["wall_ms"]
+    assert not set(fused["host_ms"]) & set(fused["span_ms"])
+    for span in ("admit.upload", "emit.replay", "dispatch.submit"):
+        assert samples[f'llm_loop_span_ms_total{{span="{span}"}}'] > 0.0
+    assert any(e.get("cat") == "loop_span" for e in doc["traceEvents"])
     names = {
         e["args"]["name"] for e in doc["traceEvents"]
         if e.get("ph") == "M" and e.get("name") == "thread_name"
@@ -1289,6 +1306,7 @@ def test_debug_profile_summary_attributes_programs(model, tmp_path):
     assert summary["total_host_ms"] + summary["total_device_ms"] > 0
     assert summary["busy_ms"] == 0.0 and summary["idle_ms"] == 0.0
     assert summary["idle_by_phase_ms"] == {}
+    assert summary["idle_by_span_ms"] == {}
 
 
 def test_http_overload_refusal_503_carries_retry_after(model):

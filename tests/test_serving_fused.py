@@ -412,6 +412,57 @@ def test_the_record_says_queued_and_the_counters_count_it(queued_run):
         assert metric_meta(name)[0] == "counter"
 
 
+@pytest.fixture(scope="module")
+def starved_run(model):
+    """A pool of 8 blocks, 7 of them the holder's reservation: a 20-token
+    prompt (2 blocks) waits with two slots free until the holder is done."""
+    params, config = model
+    cb = ContinuousBatcher(
+        params, config, n_slots=3, max_len=128, decode_chunk=8,
+        block_size=BLOCK, prefill_budget=BLOCK, n_blocks=8,
+    )
+    rng = np.random.RandomState(6)
+    cb.submit([int(t) for t in rng.randint(1, 128, size=4)],
+              max_new_tokens=100)
+    for _ in range(2):
+        cb.step()
+    waiting = cb.submit([int(t) for t in rng.randint(1, 128, size=20)],
+                        max_new_tokens=4)
+    out = cb.run_to_completion()
+    assert len(out[waiting]) == 4
+    return cb, [d for d in cb.obs.dispatches if "queued" in d]
+
+
+@pytest.mark.parametrize(
+    "run,kind,queued,blocked",
+    [
+        ("queued_run", "fused", True, "lane"),
+        ("queued_run", "decode", True, "slot"),
+        ("starved_run", "decode", True, "capacity"),
+        ("queued_run", "fused", False, None),
+    ],
+    ids=["lane", "slot", "capacity", "nothing-waits"],
+)
+def test_the_record_says_why_the_head_stayed_queued(
+    request, run, kind, queued, blocked,
+):
+    """``blocked`` beside ``queued``: the reason the admission pass before
+    the submit left the queue's head where it was, decided where ``_admit``
+    decides and counted there; None when nothing waits."""
+    cb, chunks = request.getfixturevalue(run)[-2:]
+    got = [
+        d["blocked"] for d in chunks
+        if d["kind"] == kind and (d["queued"] > 0) == queued
+    ]
+    assert len(got) >= 3 and set(got) == {blocked}, got
+    counted = cb.obs.admit_blocked_total
+    if blocked is not None:
+        # Two admission passes a step; the record carries the later one.
+        assert counted[blocked] >= len(got)
+    assert all(d["blocked"] is None for d in chunks if not d["queued"])
+    assert set(counted) <= {"lane", "slot", "capacity"}
+
+
 def test_cancel_mid_prefill_frees_admission(model):
     """Cancelling the in-flight admission mid-prefill drops it cleanly:
     its blocks free, no fused dispatches reference it afterwards, and

@@ -435,28 +435,6 @@ def test_fused_chunk_no_full_pool_copies_compiled():
     assert not offenders, offenders
 
 
-def test_device_op_times_compiled():
-    """utils.profiling.device_op_times — the measurement primitive behind
-    every bench/ROADMAP perf number — attributes device time to a known
-    dominant op, in both aggregation modes, on a real trace."""
-    from jax_llama_tpu.utils.profiling import device_op_times
-
-    a = jnp.ones((1024, 1024), jnp.bfloat16)
-
-    @jax.jit
-    def f(x):
-        return (x @ x).sum()
-
-    float(f(a))  # compile outside the trace
-    by_op = device_op_times(lambda: float(f(a)), by="op")
-    assert by_op and all(v >= 0 for v in by_op.values())
-    # The matmul fusion dominates a trace whose only work is a matmul.
-    top = max(by_op, key=by_op.get)
-    assert "fusion" in top or "convolution" in top or "dot" in top, top
-    by_src = device_op_times(lambda: float(f(a)), by="source")
-    assert sum(by_src.values()) > 0
-
-
 def test_suffix_admission_parity_on_chip():
     """Prefix-cache hit admission vs cold full prefill, ON CHIP in the
     serving dtype (bf16): token identity.
