@@ -164,8 +164,7 @@ def main() -> int:
     )
     compile_one(
         "_fused_chunk", serving._fused_chunk, params, pool, *decode_args,
-        sds((), i32), sds((512,), i32), sds((), i32), sds((), i32),
-        sds((), i32), sds((2,), u32),
+        sds((serving._PF_HEADER + 512,), i32),
         n_iter=8, pf_chunk=512, all_greedy=True, allow_kernel=True, **common,
     )
     T = BLK

@@ -323,12 +323,8 @@ def _build_paged_decode_chunk():
 def _build_fused_chunk():
     cb = _fused_batcher()
     pf = cb._pf
-    names = ("params", "pool") + _STATE_NAMES + (
-        "pf_row", "pf_toks", "pf_len", "pf_base", "pf_off", "pf_key",
-    )
-    args = (cb.params, cb.pool) + _chunk_state(cb) + (
-        pf.d_row, pf.d_toks, pf.d_len, pf.d_base, pf.d_off, pf.d_key,
-    )
+    names = ("params", "pool") + _STATE_NAMES + ("pf_vec",)
+    args = (cb.params, cb.pool) + _chunk_state(cb) + (pf.d_vec,)
     kwargs = dict(config=cb.config, n_iter=2, pf_chunk=pf.chunk,
                   all_greedy=True, mesh=None, allow_kernel=True,
                   with_logprobs=False)
@@ -348,12 +344,8 @@ def _build_paged_decode_chunk_mesh():
 def _build_fused_chunk_mesh():
     cb = _fused_batcher_mesh()
     pf = cb._pf
-    names = ("params", "pool") + _STATE_NAMES + (
-        "pf_row", "pf_toks", "pf_len", "pf_base", "pf_off", "pf_key",
-    )
-    args = (cb.params, cb.pool) + _chunk_state(cb) + (
-        pf.d_row, pf.d_toks, pf.d_len, pf.d_base, pf.d_off, pf.d_key,
-    )
+    names = ("params", "pool") + _STATE_NAMES + ("pf_vec",)
+    args = (cb.params, cb.pool) + _chunk_state(cb) + (pf.d_vec,)
     kwargs = dict(config=cb.config, n_iter=2, pf_chunk=pf.chunk,
                   all_greedy=True, mesh=cb.mesh, allow_kernel=True,
                   with_logprobs=False, placed=True)
@@ -362,7 +354,7 @@ def _build_fused_chunk_mesh():
 
 # Donated argname -> position in the chunk programs' return tuple
 # (packed, tau, tau_lp, fill, pos, active, remaining, keys, pool[,
-# pf_off]) — the mesh pass's sharding-stability map.
+# pf_vec]) — the mesh pass's sharding-stability map.
 _CHUNK_ALIASES = {
     "tau": 1, "tau_lp": 2, "fill": 3, "pos": 4, "active": 5,
     "remaining": 6, "keys": 7, "pool": 8,
@@ -454,19 +446,19 @@ def _build_paged_suffix_insert():
 
 def _build_scatter_rows():
     import jax.numpy as jnp
-    import numpy as np
+
+    from ..serving import pack_rows
 
     cb = _plain_batcher()
     state = (cb.d_table, cb.d_n_alloc, cb.d_fill, cb.d_pos,
              cb.d_active, cb.d_temps, cb.d_top_ps, cb.d_top_ks,
              cb.d_remaining, cb.d_stops)
-    rows = tuple(
-        jnp.asarray(np.zeros((1,) + tuple(a.shape[1:]),
-                             np.asarray(a).dtype))
-        for a in state
+    packed = pack_rows(
+        [0], 1, cb.n_slots, cb.table, cb.n_alloc, cb.fill, cb.pos,
+        cb.active, cb.temp_arr, cb.top_p_arr, cb.top_k_arr, cb.remaining,
+        cb.stop_tab,
     )
-    idx = jnp.asarray(np.zeros((1,), np.int32))
-    return ("state", "idx", "rows"), (state, idx, rows), {}
+    return ("state", "packed"), (state, jnp.asarray(packed)), {}
 
 
 def _build_release_blocks():
@@ -561,13 +553,13 @@ REGISTRY: Dict[str, ProgramContract] = {
         ),
         ProgramContract(
             name="_fused_chunk", module="jax_llama_tpu.serving",
-            donated=_CHUNK_DONATED + ("pf_off",), max_live_outputs=1,
+            donated=_CHUNK_DONATED + ("pf_vec",), max_live_outputs=1,
             max_fetch_bytes_per_row=16,
             build=_build_fused_chunk,
             mesh_build=_build_fused_chunk_mesh,
-            mesh_aliases=dict(_CHUNK_ALIASES, pf_off=9),
+            mesh_aliases=dict(_CHUNK_ALIASES, pf_vec=9),
             # n_iter pow2 (<= 6) x pf_chunk pow2-down from the budget
-            # flag (<= 5) x pf_toks buffer in pow2 chunk counts
+            # flag (<= 5) x pf_vec's token buffer in pow2 chunk counts
             # (<= 5) x all_greedy (2) — the admission sweep touches a
             # sparse corner of that product, and every axis is O(log).
             max_cache_keys=48,

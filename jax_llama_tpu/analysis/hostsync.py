@@ -36,6 +36,18 @@ pragma (common.py) — the allowlist IS the documentation: grep for
 ``audit: host-fetch`` and you have every device→host sync the serving
 stack performs, with its justification.
 
+``audit: host-upload`` likewise names every host→device crossing of the
+serving loop, in its two forms: a COPY outside a jitted call
+(``ContinuousBatcher._upload``: a fused admission's packed vector, once
+an admission; the classic inserts' operands — counted by
+``host_uploads_total`` and a dispatch record's ``uploads``) and a HOST
+OPERAND of a dispatch's own call (numpy handed to the jitted program: a
+row sync's packed matrix, the recurrent block's snapshot ids every chunk,
+an eviction batch's block ids — no call of their own, so the rule above,
+which looks for ``jnp.*`` constructions, does not see them; their pragmas
+are the record).  A fused or decode dispatch without an admission makes
+no copy; ``tests/test_perf_smoke.py`` pins the counts.
+
 Functions that only execute at trace time (the jitted programs
 themselves, and module-level helpers reachable ONLY from them) skip
 the ``host-upload`` rule: a ``jnp.*`` call in a Python loop there is

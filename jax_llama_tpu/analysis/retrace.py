@@ -91,22 +91,23 @@ _SHAPE_CTORS = frozenset({
 _PASSTHROUGH = frozenset({"asarray", "array"})
 
 # Host buffers whose SHAPES reach a dispatch indirectly (through
-# ``pf.d_toks``-style attributes or device twins): per program, the
+# ``pf.d_vec``-style attributes or device twins): per program, the
 # (defining function, local variable) pairs whose constructor dims the
 # static layer must prove bounded.  This is the contract for "shape
 # dims flowing in from admission": the buffer is built once on the
 # admission path, and its width is a jit cache key of the program.
 SHAPE_SOURCES: Dict[str, List[Tuple[str, str]]] = {
-    # the fused-prefill token buffer: n_chunks (pow2) * C (_pf_chunk)
-    "_fused_chunk": [("_setup_fused_prefill", "toks")],
+    # the fused-prefill token buffer behind ``pack_prefill``'s fixed
+    # header: n_chunks (pow2) * C (_pf_chunk)
+    "_fused_chunk": [("_setup_fused_prefill", "buf_len")],
     # the per-slot stop table: width pow2-bucketed on regrowth; its
     # shape keys every chunk/spec-chunk/scatter program
     "_paged_decode_chunk": [("_ensure_stop_width", "tab")],
     "_spec_rounds_chunk": [("_ensure_stop_width", "tab")],
     "_scatter_rows": [("_ensure_stop_width", "tab")],
     # recurrent state layers: the whole-prompt insert's slot ids, one a row
-    # of the admission's pow2 row bucket; the fused lane's two snapshot
-    # operands are int32 scalars (no shape)
+    # of the admission's pow2 row bucket; the fused lane's snapshot
+    # operand is int32 [2] (a fixed shape)
     "_paged_insert": [("_state_operands", "rows")],
 }
 

@@ -424,6 +424,12 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "Device-to-host fetches the serving loop performed"),
     "state_uploads_total": _reg(
         "counter", "Host-to-device state-sync dispatches"),
+    "host_uploads_total": _reg(
+        "counter", "Host-to-device copies the serving loop made outside "
+                   "a jitted call (a fused admission's packed vector, a "
+                   "classic insert's operands); a host operand handed to "
+                   "a dispatch's own call (a row sync's matrix) is not "
+                   "one"),
     "host_syncs_per_token": _reg(
         "gauge", "Fetches per emitted token (trends to 1/K steady-state)"),
     # -- speculative serving ------------------------------------------------
@@ -1090,6 +1096,8 @@ class Observability:
         self._ph_cpu1 = 0.0                   # ... at dispatch_begin
         self._ph_tid = 0                      # thread that wrote that end
         self._ph_compiles0 = 0                # compiles_total at that end
+        self.host_uploads_total = 0           # count_upload; same writer
+        self._ph_uploads0 = 0                 # ... at that end
         self._ph_seq = 0                      # the next record's ring number
         self.loop_phase_ms_total: Dict[str, float] = {}
         self.loop_gap_ms_total = 0.0
@@ -1572,9 +1580,10 @@ class Observability:
         ``gap_cpu_ms`` (``time.thread_time()`` over the same interval;
         absent when the previous record came from another thread) and
         ``compiles`` (backend compiles booked since the previous record
-        ended).  ``moe`` (routed-expert configurations) is the fetch's
-        routing counts, in ``ops.moe.STATS``' order, summed over the
-        expert-layer calls.  ``prefill_ctx`` (dispatches with a prefill
+        ended) beside ``uploads`` (host->device copies the loop thread
+        made outside a jitted call since then, ``count_upload``).  ``moe``
+        (routed-expert configurations) is the fetch's routing counts, in
+        ``ops.moe.STATS``' order, summed over the expert-layer calls.  ``prefill_ctx`` (dispatches with a prefill
         lane) is the (attended, view) slots of the prefilling row's view:
         what prefill attention did work for, and the view's width.
         ``prefill_write`` (dispatches that land prompt KV) is what they
@@ -1649,6 +1658,8 @@ class Observability:
             rec["seq"] = seq
             rec["compiles"] = self.compiles_total - self._ph_compiles0
             self._ph_compiles0 = self.compiles_total
+            rec["uploads"] = self.host_uploads_total - self._ph_uploads0
+            self._ph_uploads0 = self.host_uploads_total
             self.dispatches.append(rec)
             if "gap_ms" in gap:
                 self.loop_gap_ms_total += gap["gap_ms"]
@@ -1690,6 +1701,13 @@ class Observability:
         if self.on_dispatch is not None:
             self.on_dispatch(rec)
         return seq
+
+    def count_upload(self, n: int = 1) -> None:
+        """The loop thread made ``n`` host->device copies outside a
+        jitted call (counted at the site; a host operand handed to a
+        dispatch's own call is not one).  ``host_uploads_total``, and the
+        next record's ``uploads``."""
+        self.host_uploads_total += n
 
     def record_compile(self, program: str, dur_ms: float) -> None:
         """One backend jit compile landed (fed by the jax.monitoring
@@ -1846,6 +1864,7 @@ class Observability:
                 "requests_cancelled_total": self.requests_cancelled_total,
                 "decision_events_total": decisions_total,
                 "compiles_total": self.compiles_total,
+                "host_uploads_total": self.host_uploads_total,
                 "loop_gap_ms_total": round(self.loop_gap_ms_total, 3),
                 "loop_gap_cpu_ms_total": round(
                     self.loop_gap_cpu_ms_total, 3

@@ -25,7 +25,9 @@ parity.  Design constraints, in order:
     (counter — jitted decode dispatches; tokens/dispatch trends toward
     K), ``llm_host_syncs_total`` / ``llm_state_uploads_total``
     (counters — device->host fetches and host->device state-sync
-    dispatches the serving loop performed), and
+    dispatches the serving loop performed), ``llm_host_uploads_total``
+    (counter — host->device copies it made outside a jitted call: one a
+    fused admission, none a steady chunk), and
     ``llm_host_syncs_per_token`` (gauge — trends toward 1/K in steady
     state; ~1.0 means the loop is paying one round-trip per token).
     Speculative serving (a batcher with a draft model) adds:

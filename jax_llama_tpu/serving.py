@@ -54,7 +54,8 @@ TPU-native mechanics:
     [B, K] logprobs when enabled) back in ONE ``np.asarray``.  Batcher
     state (block table, fills, positions, active mask, sampling
     policies, budgets, stop sets) is device-resident: admission / free /
-    cancel mark rows dirty and one ``_scatter_rows`` dispatch syncs them
+    cancel mark rows dirty and one ``_scatter_rows`` dispatch over one
+    packed host matrix (``pack_rows``) syncs them
     before the next chunk — steady-state decode performs zero
     host->device state uploads and one device->host fetch per K tokens
     per slot, instead of the five uploads + one fetch PER TOKEN the
@@ -166,8 +167,8 @@ TPU-native mechanics:
     pass).  Host boundary under sharding: the packed per-chunk fetch
     is replicated-out (one [1-2, B, K] block regardless of mesh
     size — ``np.asarray`` gathers the addressable shards), dirty-row
-    ``_scatter_rows`` uploads are small host arrays GSPMD scatters to
-    the row shards, and host-tier swap slabs stage PRE-SHARDED with
+    ``_scatter_rows`` uploads are one small host matrix GSPMD scatters
+    to the row shards, and host-tier swap slabs stage PRE-SHARDED with
     the pool's layout (``kvcache.stage_restore`` placements) so the
     adoption scatter is shard-local.  The radix prefix index stays
     host-global: block ids are global, only the KV-head slice
@@ -949,14 +950,13 @@ def _chunk_scan(
     ),
     donate_argnames=(
         "pool", "fill", "tau", "tau_lp", "pos", "active", "remaining",
-        "keys", "pf_off",
+        "keys", "pf_vec",
     ),
 )
 def _fused_chunk(
     params, pool, table, n_alloc, fill, tau, tau_lp, pos, active,
     remaining, stops, keys, temperature, top_p, top_k,
-    pf_row, pf_toks, pf_len, pf_base, pf_off, pf_key,
-    pf_snap_in=None, pf_snap_out=None, *,
+    pf_vec, pf_snap=None, *,
     config, n_iter, pf_chunk, all_greedy=False, mesh=None,
     allow_kernel=True, with_logprobs=False, placed=False,
 ):
@@ -1003,7 +1003,7 @@ def _fused_chunk(
     flip on device — so the decode scan below emits its first sampled
     token from THIS dispatch, not a later one.  Non-final chunks
     discard the sample and leave the key chain untouched (``pf_key`` is
-    the same device array every dispatch, so the chain starts exactly
+    the same two header words every dispatch, so the chain starts exactly
     where a classic ``_paged_insert`` of the request would).
 
     Decode half: the unchanged ``_chunk_scan`` (shared with
@@ -1014,25 +1014,34 @@ def _fused_chunk(
 
     Host boundary: identical to ``_paged_decode_chunk`` — ONE packed
     [1 or 2, B, K] fetch, zero steady-state uploads.  All prefill state
-    (``pf_toks`` uploaded once at admission; ``pf_off`` a donated
-    device carry advanced in-program) stays resident: a 32-chunk 16k
-    prefill costs zero per-chunk host->device transfers beyond the
-    dispatch itself.
+    stays resident in ``pf_vec``, the admission's ONE upload
+    (``pack_prefill``: row, base, suffix length, the request key's two
+    words and the walk's offset — zero at admission — in a
+    ``_PF_HEADER``-long int32 header, the padded suffix tokens behind it;
+    unpacked here at static offsets).  It is a donated carry: the program
+    advances the offset word in place and hands the vector back, so a
+    32-chunk 16k prefill costs zero per-chunk host->device transfers
+    beyond the dispatch itself.
 
     Recurrent state layers (``pool.conv`` / ``pool.ssm``): the prefilling
-    row's state enters the chunk and leaves it in its slot.  The walk's
-    FIRST chunk (``pf_off`` 0) starts from snapshot ``pf_snap_in`` — the
+    row's state enters the chunk and leaves it in its slot.  ``pf_snap``
+    is int32 [2], a host operand of the call itself: the walk's FIRST
+    chunk (``pf_off`` 0) starts from snapshot ``pf_snap[0]`` — the
     prefix hit's, or the empty state with id -1 — and a chunk's end state
-    is copied into snapshot ``pf_snap_out`` (-1: none; the host asks for
-    one when the chunk ends on a block boundary of the prompt).  Both are
-    None for every other block.
+    is copied into snapshot ``pf_snap[1]`` (-1: none; the host asks for
+    one when the chunk ends on a block boundary of the prompt).  None for
+    every other block.
 
-    Returns ``_chunk_scan``'s tuple + the advanced ``pf_off``.
+    Returns ``_chunk_scan``'s tuple + ``pf_vec`` with its offset advanced.
     """
     with use_mesh(mesh):
         B = tau.shape[0]
         C = pf_chunk
         NB, BLK = pool.pos.shape
+        (pf_row, pf_base, pf_len, pf_key, pf_off,
+         pf_toks) = _unpack_prefill(pf_vec)
+        if pf_snap is not None:
+            pf_snap_in, pf_snap_out = pf_snap[0], pf_snap[1]
         # ---- one bounded prefill chunk for the in-flight admission ----
         table_r = lax.dynamic_slice_in_dim(table, pf_row, 1, axis=0)
         n_alloc_r = lax.dynamic_slice_in_dim(n_alloc, pf_row, 1, axis=0)
@@ -1154,7 +1163,9 @@ def _fused_chunk(
         fill = jnp.where(fold, fill_done, fill)
         pos = jnp.where(fold, pf_base + pf_len, pos)
         keys = jnp.where(fold[:, None], kc, keys)
-        pf_off = pf_off + C
+        pf_vec = lax.dynamic_update_slice_in_dim(
+            pf_vec, (pf_off + C)[None], _PF_OFF, axis=0
+        )
         # ---- the standard decode scan: K iterations, or the K - 1 after
         # the mixed pass's ----
         out = _chunk_scan(
@@ -1165,18 +1176,96 @@ def _fused_chunk(
             use_kernel=use_kernel, with_logprobs=with_logprobs,
             placed=placed, emitted=emitted,
         )
-        return out + (pf_off,)
+        return out + (pf_vec,)
+
+
+# The admission's one upload (``_fused_chunk``'s ``pf_vec``): an int32
+# header — row, base, suffix length, the request key's two uint32 words
+# viewed as int32, the walk's offset (the program's carry; zero at
+# admission) — then the padded suffix tokens.  The header is one row of
+# 128 lanes, so a chunk's token slice starts lane-aligned as it did when
+# the tokens were an array of their own.
+_PF_HEADER = 128
+_PF_OFF = 5
+
+
+def pack_prefill(
+    row: int, base: int, suffix_len: int, key: np.ndarray,
+    suffix: Sequence[int], buf_len: int,
+) -> np.ndarray:
+    """The host buffer of one fused admission: header + ``buf_len`` token
+    slots (whole chunks; trailing zeros are masked and never dispatched)."""
+    vec = np.zeros((_PF_HEADER + buf_len,), np.int32)
+    vec[:3] = row, base, suffix_len
+    vec[3:5] = np.asarray(key, np.uint32).view(np.int32)
+    vec[_PF_HEADER:_PF_HEADER + len(suffix)] = suffix
+    return vec
+
+
+def _unpack_prefill(pf_vec):
+    """(row, base, suffix length, key [2] uint32, offset, tokens) of
+    ``pack_prefill``'s buffer, on the device."""
+    return (
+        pf_vec[0], pf_vec[1], pf_vec[2],
+        lax.bitcast_convert_type(pf_vec[3:5], jnp.uint32),
+        pf_vec[_PF_OFF], pf_vec[_PF_HEADER:],
+    )
+
+
+# Per-row scalars of a row sync, in the column order of ``pack_rows``'s
+# matrix; the row's table and stop set follow as column ranges.
+_ROW_FIELDS = (
+    "idx", "n_alloc", "fill", "pos", "active", "temps", "top_ps",
+    "top_ks", "remaining",
+)
+
+
+def pack_rows(
+    rows: Sequence[int], n_padded: int, sentinel: int,
+    table, n_alloc, fill, pos, active, temps, top_ps, top_ks, remaining,
+    stops,
+) -> np.ndarray:
+    """The host buffer of one row sync: int32 [n_padded, 9 + MB + S], row
+    r of it the dirty slot ``rows[r]`` of the host mirrors — its index,
+    the eight scalars (float32 ``temps`` / ``top_ps`` by their bits,
+    ``active`` as 0 / 1), its table row and its stop row.  Pad rows carry
+    the out-of-range index ``sentinel`` and zeros."""
+    rows = list(rows)
+    n, mb = len(_ROW_FIELDS), table.shape[1]
+    mat = np.zeros((n_padded, n + mb + stops.shape[1]), np.int32)
+    mat[:, 0] = sentinel
+    live = mat[:len(rows)]
+    live[:, 0] = rows
+    for c, a in enumerate((
+        n_alloc, fill, pos, active, temps.view(np.int32),
+        top_ps.view(np.int32), top_ks, remaining,
+    ), 1):
+        live[:, c] = a[rows]
+    live[:, n:n + mb] = table[rows]
+    live[:, n + mb:] = stops[rows]
+    return mat
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_rows(state, idx, rows):
+def _scatter_rows(state, packed):
     """Update per-slot device-resident decode state for the (padded,
-    pow2-bucketed) row indices ``idx`` in ONE dispatch — the admission/
-    free/cancel sync primitive of the chunked path.  Pad entries carry
-    the out-of-range index n_slots and drop."""
+    pow2-bucketed) dirty rows of ``packed`` (``pack_rows``) in ONE
+    dispatch — the admission/free/cancel sync primitive of the chunked
+    path.  ``state``: (table, n_alloc, fill, pos, active, temps, top_ps,
+    top_ks, remaining, stops).  Pad rows carry the out-of-range index
+    n_slots and drop.  The float columns come back by their bits."""
+    n = len(_ROW_FIELDS)
+    mb = state[0].shape[1]
+    col = {name: packed[:, c] for c, name in enumerate(_ROW_FIELDS)}
+    rows = (
+        packed[:, n:n + mb], col["n_alloc"], col["fill"], col["pos"],
+        col["active"] != 0,
+        lax.bitcast_convert_type(col["temps"], jnp.float32),
+        lax.bitcast_convert_type(col["top_ps"], jnp.float32),
+        col["top_ks"], col["remaining"], packed[:, n + mb:],
+    )
     return tuple(
-        a.at[idx].set(v.astype(a.dtype), mode="drop")
-        for a, v in zip(state, rows)
+        a.at[col["idx"]].set(v, mode="drop") for a, v in zip(state, rows)
     )
 
 
@@ -1970,11 +2059,13 @@ def _round_up(n: int, m: int) -> int:
 @dataclasses.dataclass
 class _Prefill:
     """Host view of the single in-flight fused admission (queued ->
-    prefilling(off) -> decoding).  The device twins (``d_*``) are
-    uploaded ONCE when the prefill starts; ``d_off`` is a donated carry
-    the fused program advances on device, and ``off`` is the host's
-    deterministic replay of it (off advances by exactly ``chunk`` per
-    dispatch, so completion is host-computable without a fetch)."""
+    prefilling(off) -> decoding).  ``d_vec`` is the admission's ONE
+    upload, made when the prefill starts (``pack_prefill``: the walk's
+    scalars, key words and offset in front of the suffix tokens), and a
+    donated carry from then on: the fused program advances its offset
+    word and hands it back.  ``off`` is the host's deterministic replay
+    of that word (off advances by exactly ``chunk`` per dispatch, so
+    completion is host-computable without a fetch)."""
 
     slot: int
     req: "_Request"
@@ -1984,12 +2075,7 @@ class _Prefill:
     suffix_len: int       # real suffix tokens still to prefill at start
     chunk: int            # C: prompt tokens advanced per dispatch
     off: int = 0          # suffix tokens already dispatched
-    d_toks: Any = None    # [buf] int32, uploaded once
-    d_off: Any = None     # int32 scalar, donated carry
-    d_row: Any = None     # int32 scalar
-    d_base: Any = None    # int32 scalar
-    d_len: Any = None     # int32 scalar
-    d_key: Any = None     # [2] uint32 request key (chain start)
+    d_vec: Any = None     # [_PF_HEADER + buf] int32, donated carry
     # Recurrent state layers: the snapshot the walk starts from (-1: the
     # empty state) and the (depth in blocks, id) snapshots its chunks have
     # taken, hung on the chain's nodes once it is published.
@@ -3155,10 +3241,29 @@ class ContinuousBatcher:
             )
         return 1 << (k.bit_length() - 1)
 
+    def _upload(self, host: np.ndarray) -> jnp.ndarray:
+        """One host->device copy by the loop thread outside a jitted
+        call, counted (``host_uploads_total``, the next record's
+        ``uploads``).  Replicated under a mesh, like every unplaced
+        operand."""
+        self.obs.count_upload()
+        # audit: host-upload(the counted copy outside a jitted call: a
+        # fused admission's packed vector, once an admission; the classic
+        # speculative round's tau)
+        return jnp.asarray(host)
+
     def _sync_device_rows(self) -> None:
         """Flush host-side per-row state changes (admission / free /
         cancel) to the device-resident twins in ONE ``_scatter_rows``
-        dispatch.  No dirty rows (the steady state) -> no upload."""
+        dispatch over ONE host buffer (``pack_rows``: the dirty rows'
+        index and ten fields as an int32 matrix, unpacked on the device),
+        handed to the call as it is: the dispatch is the sync's one
+        crossing, no copy beside it (on a v5e a ``jnp.asarray`` costs the
+        loop thread 0.3 ms alone and 1.1-1.8 ms beside 16-32 handler
+        threads, whatever its size; a host operand of a call it makes
+        anyway adds ~0.05: PERF.md section 6, PR 39).  No dirty rows (the
+        steady state) -> no dispatch.  A stop table that grew re-uploads
+        the whole twin first (a copy of its own, rare)."""
         if not self._dirty_rows:
             return
         with self.obs.loop_span("prep.sync_rows"):
@@ -3167,33 +3272,28 @@ class ContinuousBatcher:
                 # twin wholesale before the row scatter — admission-time
                 # only, and the array is [B, S] ints.
                 self.d_stops = self._rows(self.stop_tab)
+                self.obs.count_upload()
             rows = sorted(self._dirty_rows)
             self._dirty_rows.clear()
-            R = len(rows)
-            Rb = pow2_bucket(R)  # pow2 jit-cache bucket
-            idx = np.full((Rb,), self.n_slots, np.int32)  # pads drop
-            idx[:R] = rows
-
-            def take(a: np.ndarray) -> jnp.ndarray:
-                out = np.zeros((Rb,) + a.shape[1:], a.dtype)
-                out[:R] = a[rows]
-                return jnp.asarray(out)
-
+            Rb = pow2_bucket(len(rows))  # pow2 jit-cache bucket
+            packed = pack_rows(
+                rows, Rb, self.n_slots,  # pad rows drop
+                self.table, self.n_alloc, self.fill, self.pos, self.active,
+                self.temp_arr, self.top_p_arr, self.top_k_arr,
+                self.remaining, self.stop_tab,
+            )
             state = (
                 self.d_table, self.d_n_alloc, self.d_fill, self.d_pos,
                 self.d_active, self.d_temps, self.d_top_ps, self.d_top_ks,
                 self.d_remaining, self.d_stops,
             )
             _obs_mod.attribute_compiles(self.obs, "_scatter_rows")
+            # audit: host-upload(the packed dirty rows as a HOST operand
+            # of the sync's own dispatch, once an admission / free /
+            # cancel batch; no copy beside it)
             (self.d_table, self.d_n_alloc, self.d_fill, self.d_pos,
              self.d_active, self.d_temps, self.d_top_ps, self.d_top_ks,
-             self.d_remaining, self.d_stops) = _scatter_rows(
-                state, jnp.asarray(idx),
-                (take(self.table), take(self.n_alloc), take(self.fill),
-                 take(self.pos), take(self.active), take(self.temp_arr),
-                 take(self.top_p_arr), take(self.top_k_arr),
-                 take(self.remaining), take(self.stop_tab)),
-            )
+             self.d_remaining, self.d_stops) = _scatter_rows(state, packed)
             self.state_uploads_total += 1
 
     def _step_chunked(self) -> List[Tuple]:
@@ -3327,13 +3427,12 @@ class ContinuousBatcher:
             else:
                 (packed, self.tau, self.d_tau_lp, self.d_fill, self.d_pos,
                  self.d_active, self.d_remaining, self.keys, self.pool,
-                 pf.d_off) = _fused_chunk(
+                 pf.d_vec) = _fused_chunk(
                     self.params, self.pool, self.d_table, self.d_n_alloc,
                     self.d_fill, self.tau, self.d_tau_lp, self.d_pos,
                     self.d_active, self.d_remaining, self.d_stops, self.keys,
                     self.d_temps, self.d_top_ps, self.d_top_ks,
-                    pf.d_row, pf.d_toks, pf.d_len, pf.d_base, pf.d_off,
-                    pf.d_key, *(pf_ssm or ((), None))[0],
+                    pf.d_vec, *(pf_ssm or ((), None))[0],
                     config=self.config, n_iter=K, pf_chunk=pf.chunk,
                     all_greedy=all_greedy, mesh=self.mesh,
                     allow_kernel=self.use_pallas_kernel,
@@ -3873,7 +3972,7 @@ class ContinuousBatcher:
                 self.pos[b] += a + 1
         if round_proposed:
             self._accept_window.append((round_proposed, round_accepted))
-        self.tau = jnp.asarray(new_tau)
+        self.tau = self._upload(new_tau)
 
     def run_to_completion(self) -> Dict[int, List[int]]:
         """Drain everything; returns {request_id: emitted tokens}."""
@@ -3954,19 +4053,16 @@ class ContinuousBatcher:
             chunk = evicted[start:start + self.blocks_per_slot]
             ids[: len(chunk)] = chunk
             _obs_mod.attribute_compiles(self.obs, "_release_blocks")
+            # audit: host-upload(the eviction batch's ids as a HOST
+            # operand of the release dispatch's own call — and of the
+            # draft pool's twin; admission/capacity path, never per-token)
             self.pool = dataclasses.replace(
-                self.pool,
-                # audit: host-upload(eviction-batch id upload on the
-                # admission/capacity path, never per-token)
-                pos=_release_blocks(self.pool.pos, jnp.asarray(ids)),
+                self.pool, pos=_release_blocks(self.pool.pos, ids),
             )
             if self.spec:
                 self.draft_pool = dataclasses.replace(
                     self.draft_pool,
-                    # audit: host-upload(draft-pool twin of the above)
-                    pos=_release_blocks(
-                        self.draft_pool.pos, jnp.asarray(ids)
-                    ),
+                    pos=_release_blocks(self.draft_pool.pos, ids),
                 )
 
     def demote_idle(self, n: int) -> int:
@@ -4501,6 +4597,8 @@ class ContinuousBatcher:
         self._record_dispatch(["prefix_cache"])
         self._fault("suffix_insert")
         self._admit_dispatches += 1
+        # nine operands and the slot index; the draft twin's again
+        self.obs.count_upload(10 + 9 * self.spec)
         with self.obs.loop_span("dispatch.submit"):
             tau, tau_lp, keys_out, self.pool = _paged_suffix_insert(
                 self.params, self.pool, jnp.asarray(table_rows),
@@ -4898,10 +4996,11 @@ class ContinuousBatcher:
                 pf.snaps.append((depth, sid))
         restored = pf.off == 0 and pf.snap_in >= 0
         self.ssm_snapshots_restored_total += restored
-        # audit: host-upload(two int32 scalars beside the dispatch: which
-        # snapshot the chunk starts from and which it leaves; no state
-        # array crosses the host)
-        ops = (jnp.asarray(np.int32(pf.snap_in)), jnp.asarray(np.int32(out)))
+        # audit: host-upload(which snapshot the chunk starts from and
+        # which it leaves: int32 [2], a HOST operand of the fused
+        # dispatch's own call, every chunk of the recurrent block; no copy
+        # beside it, no state array crosses the host)
+        ops = (np.array([pf.snap_in, out], np.int32),)
         return ops, {"taken": int(out >= 0), "restored": int(restored)}
 
     def _hang_snapshots(self, pf: _Prefill) -> None:
@@ -4951,8 +5050,8 @@ class ContinuousBatcher:
         walk at fill0 = the matched depth), set up the host mirrors
         with the row VISIBLE BUT INACTIVE (the fused program activates
         it on device the dispatch its last chunk lands), and upload the
-        suffix tokens + walk scalars ONCE — later chunks are pure
-        dispatches, zero per-chunk host->device state traffic.  No
+        suffix tokens + walk scalars ONCE, as one vector — later chunks
+        are pure dispatches, zero per-chunk host->device state traffic.  No
         model dispatch happens here; the prefill itself rides
         ``_fused_chunk``.  A head whose matched prefix includes
         host-tier blocks moves to ``restoring`` instead, and the NEXT
@@ -5008,8 +5107,7 @@ class ContinuousBatcher:
         # buffer length is a jit cache key of _fused_chunk); trailing
         # zeros are masked and never dispatched.
         n_chunks = pow2_bucket(max(1, -(-len(suffix) // C)))
-        toks = np.zeros((n_chunks * C,), np.int32)
-        toks[: len(suffix)] = suffix
+        buf_len = n_chunks * C
         # Host mirrors: full reservation visible, row inactive; the
         # admission-time dirty sync is the ONE state upload the whole
         # prefill pays.
@@ -5030,15 +5128,16 @@ class ContinuousBatcher:
             stop_tokens=req.stops, blocks=blocks, shared=n_share,
         )
         with self.obs.loop_span("admit.upload", rid=req.rid):
+            # audit: host-upload(the admission's ONE copy: the walk's
+            # scalars, key words and zero offset in front of the suffix
+            # tokens, once an admission; later chunks cross nothing)
             self._pf = _Prefill(
                 slot=b, req=req, chain=chain, n_share=n_share, base=base,
                 suffix_len=len(suffix), chunk=C,
-                d_toks=jnp.asarray(toks),
-                d_off=jnp.zeros((), jnp.int32),
-                d_row=jnp.asarray(np.int32(b)),
-                d_base=jnp.asarray(np.int32(base)),
-                d_len=jnp.asarray(np.int32(len(suffix))),
-                d_key=jnp.asarray(self._request_key(req)),
+                d_vec=self._upload(pack_prefill(
+                    b, base, len(suffix), self._request_key(req), suffix,
+                    buf_len,
+                )),
                 snap_in=snap_in,
             )
         self.fused_admissions_total += 1
@@ -5255,6 +5354,9 @@ class ContinuousBatcher:
                 self._fault("flash_kernel")
             self._admit_dispatches += 1
             slot_ids = [next(slot_iter) for _ in range(k)]
+            # seven operands (the state rows an eighth), the slot index;
+            # the draft twin's seven again
+            self.obs.count_upload(8 + self.recurrent + 7 * self.spec)
             with self.obs.loop_span("dispatch.submit"):
                 taus, tau_lps, plens, keys_out, self.pool = _paged_insert(
                     # audit: host-upload(admission-time prompt/state upload

@@ -407,7 +407,7 @@ def test_recurrent_fused_chunk_for_v5e(sds, monkeypatch):
     cfg, params, pool = _recurrent_cell(sds, monkeypatch, 8)
     lowered = serving._fused_chunk.lower(
         params, pool, *fused_chunk_operand_shapes(sds, 24, 32, 512),
-        sds((), jnp.int32), sds((), jnp.int32),
+        sds((2,), jnp.int32),
         config=cfg, n_iter=8, pf_chunk=512, all_greedy=True, mesh=None,
         allow_kernel=True, with_logprobs=False,
     )

@@ -10,14 +10,37 @@ that contract through the batcher's instrumented counters
 (``host_syncs_total`` / ``state_uploads_total`` count every np.asarray
 fetch and every ``_scatter_rows`` state-sync dispatch the serving loop
 performs; the ``spec_*`` twins attribute the speculative path's share),
-plus the adaptive-K/R policy around admissions."""
+plus the adaptive-K/R policy around admissions.
+
+The upload contract (PR 39; ``obs.host_uploads_total``, a dispatch
+record's ``uploads``: host->device copies the loop thread makes OUTSIDE a
+jitted call, counted at the site).  What crosses, and when:
+
+  * a fused admission — ONE copy, when the prefill starts: the packed
+    int32 vector (``serving.pack_prefill``: row, base, suffix length, the
+    request key's two words, the zero offset, then the padded suffix
+    tokens).  ``_fused_chunk`` unpacks it, advances its offset word and
+    hands it back (a donated carry), so later chunks cross nothing;
+  * a row sync (admission / free / cancel) — no copy of its own: the
+    dirty rows' index and ten fields travel as ONE int32 matrix
+    (``serving.pack_rows``), a host operand of the ``_scatter_rows``
+    dispatch (``state_uploads_total`` counts those dispatches, as ever).
+    A stop table that grew re-uploads the whole twin first: one copy;
+  * a chunk of the recurrent block — its two snapshot ids as ONE int32 [2]
+    host operand of the ``_fused_chunk`` call itself; an eviction batch —
+    its block ids as a host operand of ``_release_blocks``;
+  * a fused or decode dispatch without an admission — nothing: 0 copies
+    (19 an admission and 2 a recurrent chunk before PR 39);
+  * the classic whole-prompt / suffix inserts still copy their operands
+    one by one (8-10 a dispatch, counted): once a window in the cells."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from jax_llama_tpu import get_config, init_params
-from jax_llama_tpu.obs import Observability
+from jax_llama_tpu import get_config, init_params, serving
+from jax_llama_tpu.obs import Observability, metric_meta
 from jax_llama_tpu.serving import ContinuousBatcher
 
 CFG = dict(
@@ -256,6 +279,138 @@ def test_fused_admission_host_sync_discipline(model):
     assert cb.state_uploads_total - u0 == 1
     while cb.pending():
         cb.step()
+    # The upload contract: the admission's record carries its one copy
+    # (the packed vector; <= 2), every other chunk record none — and the
+    # classic insert before them its operands, one by one.
+    recs = list(cb.obs.dispatches)
+    assert [r["uploads"] for r in recs if r["kind"] == "insert"] == [8]
+    fused = [r for r in recs if r["kind"] == "fused"]
+    assert [r["uploads"] for r in fused] == [1, 0, 0, 0]
+    assert {r["uploads"] for r in recs if r["kind"] == "decode"} == {0}
+    assert cb.obs.host_uploads_total == 9 == cb.obs.metrics()[
+        "host_uploads_total"]
+    assert metric_meta("host_uploads_total")[0] == "counter"
+
+
+def _mirrors(rng, rows, mb, width):
+    """Host mirrors of ``rows`` slots, the float fields holding the values
+    a conversion would lose (-0.0, a denormal-sized 1e-7, top_p 1.0)."""
+    edge = np.array([-0.0, 1e-7, 1.0, 0.7, 1e-38, 0.95], np.float32)
+    return dict(
+        table=rng.randint(0, 64, (rows, mb)).astype(np.int32),
+        n_alloc=rng.randint(0, mb, rows).astype(np.int32),
+        fill=rng.randint(0, 99, rows).astype(np.int32),
+        pos=rng.randint(0, 99, rows).astype(np.int32),
+        active=rng.rand(rows) < 0.5,
+        temps=rng.permutation(edge)[:rows],
+        top_ps=rng.permutation(edge)[:rows],
+        top_ks=rng.randint(0, 50, rows).astype(np.int32),
+        remaining=rng.randint(0, 300, rows).astype(np.int32),
+        stops=rng.randint(-1, 128, (rows, width)).astype(np.int32),
+    )
+
+
+@pytest.mark.parametrize(
+    "dirty", [[2], [0, 3, 5], [1, 2, 3, 4]],
+    ids=["one-row", "three-rows-and-a-pad", "four-rows"],
+)
+def test_scatter_rows_over_the_packed_matrix_is_the_field_by_field_form(dirty):
+    """``_scatter_rows`` over ``pack_rows``' ONE int32 matrix against the
+    form it replaces — each field's rows uploaded and scattered on their
+    own: every twin bit for bit (the float columns travel by their bits),
+    the pad row dropped, no other row touched."""
+    rows, mb, width = 6, 4, 2
+    order = ("table", "n_alloc", "fill", "pos", "active", "temps", "top_ps",
+             "top_ks", "remaining", "stops")
+    was = _mirrors(np.random.RandomState(0), rows, mb, width)
+    host = _mirrors(np.random.RandomState(1), rows, mb, width)
+    rb = serving.pow2_bucket(len(dirty))
+    packed = serving.pack_rows(
+        dirty, rb, rows, *(host[n] for n in order))
+    assert packed.dtype == np.int32 and packed.shape == (rb, 9 + mb + width)
+    assert (packed[len(dirty):, 0] == rows).all()       # pads: out of range
+    got = serving._scatter_rows(
+        tuple(jnp.asarray(was[n]) for n in order), packed)
+    idx = np.full((rb,), rows, np.int32)
+    idx[:len(dirty)] = dirty
+    for name, twin in zip(order, got):
+        take = np.zeros((rb,) + host[name].shape[1:], host[name].dtype)
+        take[:len(dirty)] = host[name][dirty]
+        want = jnp.asarray(was[name]).at[jnp.asarray(idx)].set(
+            jnp.asarray(take), mode="drop")
+        assert twin.dtype == want.dtype and twin.shape == want.shape, name
+        bits = lambda a: np.asarray(a).view(  # noqa: E731
+            np.int32 if a.dtype == jnp.float32 else np.asarray(a).dtype)
+        assert np.array_equal(bits(twin), bits(want)), name
+        clean = [r for r in range(rows) if r not in dirty]
+        assert np.array_equal(np.asarray(twin)[clean], was[name][clean]), name
+        assert np.array_equal(np.asarray(twin)[dirty], host[name][dirty]), name
+
+
+def test_the_packed_admission_vector_round_trips():
+    """``pack_prefill`` -> ``_unpack_prefill``: row, base, suffix length,
+    the key's two words (a seed past 2**31, so the uint32 view matters),
+    the zero offset and the tokens behind the header, types as the program
+    used to get them one by one."""
+    toks = np.random.RandomState(2).randint(0, 2**31 - 1, 40).tolist()
+    key = np.array([0xFFFFFFFE, 2**31 + 12345], np.uint32)
+    vec = serving.pack_prefill(5, 48, len(toks), key, toks, 64)
+    assert vec.dtype == np.int32 and vec.shape == (serving._PF_HEADER + 64,)
+    row, base, plen, pf_key, off, pf_toks = jax.jit(serving._unpack_prefill)(
+        jnp.asarray(vec))
+    assert (int(row), int(base), int(plen), int(off)) == (5, 48, 40, 0)
+    assert pf_key.dtype == jnp.uint32 and np.array_equal(np.asarray(pf_key), key)
+    assert pf_toks.dtype == jnp.int32 and pf_toks.shape == (64,)
+    assert np.asarray(pf_toks).tolist() == toks + [0] * 24
+
+
+def test_a_seeded_run_through_the_lane_is_the_classic_run_and_stops_regrow(model):
+    """Served: sampled requests whose seeds lie past 2**31 (the key words
+    cross as int32 and come back uint32) emit through the fused lane what
+    the whole-prompt insert emits, and a request with a wider stop set
+    than any before regrows the stop table: the device twin is rebuilt
+    (one more copy on that admission's record: 2) and holds the host's."""
+    params, config = model
+    rng = np.random.RandomState(6)
+    prompts = [rng.randint(1, 128, n).tolist() for n in (9, 50, 37)]
+    stops = (3, 5, 7, 11, 13)
+
+    def run(budget):
+        cb = ContinuousBatcher(
+            params, config, n_slots=3, max_len=128, decode_chunk=4,
+            block_size=16, prefill_budget=budget,
+        )
+        out = {}
+
+        def steps(n):
+            for _ in range(n):
+                for rid, tok, *_ in cb.step():
+                    out.setdefault(rid, []).append(tok)
+
+        rids = [cb.submit(prompts[0], max_new_tokens=40, temperature=0.9,
+                          seed=2**31 + 7)]
+        steps(2)
+        rids.append(cb.submit(prompts[1], max_new_tokens=12, temperature=0.8,
+                              top_p=0.9, seed=2**32 - 5))
+        steps(4)
+        width = cb.d_stops.shape
+        rids.append(cb.submit(prompts[2], max_new_tokens=12, temperature=0.7,
+                              seed=2**31, stop_tokens=stops))
+        steps(1)
+        twin = (np.asarray(cb.d_stops), cb.stop_tab.copy())
+        while cb.pending():
+            steps(1)
+        return cb, width, twin, [out[r] for r in rids]
+
+    cb, width, (twin, mirror), fused = run(16)
+    assert cb.fused_admissions_total == 2
+    assert width == (3, 1) and twin.shape == (3, 8)
+    assert np.array_equal(twin, mirror) and set(stops) < set(twin[2].tolist())
+    admits = [r["uploads"] for r in cb.obs.dispatches
+              if r["kind"] == "fused" and r["uploads"]]
+    assert admits == [1, 2]
+    *_, classic = run(0)
+    assert fused == classic
 
 
 def test_fused_prefill_does_not_collapse_chunk_size(model):

@@ -512,12 +512,15 @@ def test_the_cache_is_planes_for_the_owning_layers_and_a_state_a_row(tiny):
     assert serving.init_pool(dense, 8, BLK).conv is None and jlt.init_cache(dense, 1).ssm is None
 
 
-def test_a_snapshot_copy_or_a_state_reset_adds_no_fetch_and_no_retrace(tiny):
+def test_a_snapshot_copy_or_a_state_reset_adds_no_fetch_and_no_retrace(tiny, monkeypatch):
     """The perf-smoke pin on this block: every dispatch of a fused admission
     (first chunk from the empty state, middle chunks that leave a snapshot,
     the last that leaves none) and of a re-ask that restores one pays ONE
-    packed fetch, the admission one state upload, and all of them run one
-    compiled `_fused_chunk` a (K, chunk) pair: the snapshot ids are values."""
+    packed fetch, the admission one state upload and ONE host->device copy
+    (its packed vector, on its first record; no chunk after it copies
+    anything), and all of them run one compiled `_fused_chunk` a (K, chunk)
+    pair: the snapshot ids are values — one int32 [2] HOST operand of the
+    call itself, every chunk, where two device scalars were copied."""
     _, cfg, params = tiny
     rng = np.random.RandomState(9)
     draw = lambda n: [int(t) for t in rng.randint(0, 512, size=n)]  # noqa: E731
@@ -527,21 +530,38 @@ def test_a_snapshot_copy_or_a_state_reset_adds_no_fetch_and_no_retrace(tiny):
     cb.submit(draw(40), max_new_tokens=60)
     for _ in range(3):
         cb.step()
+    snaps, fused = [], serving._fused_chunk
+
+    def spy(*args, **kwargs):
+        snaps.append(args[-1])
+        return fused(*args, **kwargs)
+
+    monkeypatch.setattr(serving, "_fused_chunk", spy)
     for ask in (doc + draw(5), doc + draw(6)):
         cb.submit(ask, max_new_tokens=4)
-        uploads, programs = cb.state_uploads_total, None
+        uploads, copies, programs = cb.state_uploads_total, cb.obs.host_uploads_total, None
+        first = len(cb.obs.dispatches)
         while cb.queue or cb._pf is not None:
             syncs = cb.host_syncs_total
             cb.step()
             assert cb.host_syncs_total - syncs == 1
             if cb._pf is not None:      # the walk's chunks share one program
-                programs = programs or serving._fused_chunk._cache_size()
-                assert serving._fused_chunk._cache_size() == programs
+                programs = programs or fused._cache_size()
+                assert fused._cache_size() == programs
         assert cb.state_uploads_total - uploads == 1
+        assert cb.obs.host_uploads_total - copies == 1
+        walk = list(cb.obs.dispatches)[first:]
+        assert {r["kind"] for r in walk} == {"fused"}
+        assert [r["uploads"] for r in walk] == [1] + [0] * (len(walk) - 1)
         while any(s is not None and s.max_new == 4 for s in cb.slots.values()):
             cb.step()
     assert cb.stats()["ssm_snapshots_restored_total"] == 1
     assert cb.stats()["ssm_snapshots_taken_total"] == 3
+    assert all(type(s) is np.ndarray and s.dtype == np.int32 and s.shape == (2,) for s in snaps)
+    # (from, to): the fresh walk leaves three, the re-ask starts from the deepest
+    *left, last, reask = (s.tolist() for s in snaps)
+    assert [s[0] for s in left] == [-1] * 3 and len({s[1] for s in left}) == 3
+    assert last == [-1, -1] and reask == [left[-1][1], -1]
 
 
 def test_a_checkpoint_of_the_block_loads_as_run_py_loads_it(tiny, tmp_path):

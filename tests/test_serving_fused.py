@@ -595,7 +595,8 @@ def latent_model():
 
 
 def fused_chunk_operand_shapes(sds, rows, mb, chunk):
-    """``_fused_chunk``'s 19 operands after ``params`` and ``pool``, as
+    """``_fused_chunk``'s 14 operands after ``params`` and ``pool`` — the
+    thirteen of the decode state and the admission's packed vector — as
     ``sds(shape, dtype)`` makes them (tests/test_chip_compile.py places
     them on a described chip)."""
     i32, f32, u32 = jnp.int32, jnp.float32, jnp.uint32
@@ -605,8 +606,7 @@ def fused_chunk_operand_shapes(sds, rows, mb, chunk):
         sds((rows,), jnp.bool_), sds((rows,), i32), sds((rows, 1), i32),
         sds((rows, 2), u32), sds((rows,), f32), sds((rows,), f32),
         sds((rows,), i32),
-        sds((), i32), sds((chunk,), i32), sds((), i32), sds((), i32),
-        sds((), i32), sds((2,), u32),
+        sds((serving._PF_HEADER + chunk,), i32),
     )
 
 
@@ -635,6 +635,8 @@ def _write_case(params, config, geometry, seed=0):
     table[1] = 4 + np.arange(W_MB)
     i32, f32 = jnp.int32, jnp.float32
     toks = rng.randint(1, config.vocab_size, size=64).astype(np.int32)
+    vec = serving.pack_prefill(0, base, plen, np.zeros(2, np.uint32), toks, 64)
+    vec[serving._PF_OFF] = off
     args = (
         params, pool, jnp.asarray(table), jnp.asarray([held, W_MB], i32),
         jnp.asarray([0, 20], i32), jnp.asarray([0, 5], i32),
@@ -643,9 +645,7 @@ def _write_case(params, config, geometry, seed=0):
         jnp.full((W_ROWS, 1), -1, i32), jnp.zeros((W_ROWS, 2), jnp.uint32),
         jnp.zeros((W_ROWS,), f32), jnp.ones((W_ROWS,), f32),
         jnp.zeros((W_ROWS,), i32),
-        jnp.asarray(0, i32), jnp.asarray(toks), jnp.asarray(plen, i32),
-        jnp.asarray(base, i32), jnp.asarray(off, i32),
-        jnp.zeros((2,), jnp.uint32),
+        jnp.asarray(vec),
     )
     kwargs = dict(
         config=config, n_iter=2, pf_chunk=W_CHUNK, all_greedy=True,

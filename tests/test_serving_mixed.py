@@ -62,6 +62,9 @@ def _case(params, config, last, sampled=False, seed=0):
     table = np.arange(NB, dtype=np.int32).reshape(ROWS, MB)
     i32, f32 = jnp.int32, jnp.float32
     toks = rng.randint(1, config.vocab_size, size=64).astype(np.int32)
+    vec = serving.pack_prefill(
+        0, 0, plen, np.asarray(jax.random.PRNGKey(3), np.uint32), toks, 64)
+    vec[serving._PF_OFF] = off
     keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(ROWS) + 7)
     temp = [0.8, 0.7, 0.9, 0.0] if sampled else [0.0] * ROWS
     args = (
@@ -75,9 +78,7 @@ def _case(params, config, last, sampled=False, seed=0):
         jnp.full((ROWS, 1), -1, i32), keys.astype(jnp.uint32),
         jnp.asarray(temp, f32), jnp.ones((ROWS,), f32),
         jnp.full((ROWS,), config.vocab_size, i32),
-        jnp.asarray(0, i32), jnp.asarray(toks), jnp.asarray(plen, i32),
-        jnp.asarray(0, i32), jnp.asarray(off, i32),
-        jax.random.PRNGKey(3).astype(jnp.uint32),
+        jnp.asarray(vec),
     )
     kwargs = dict(
         config=config, n_iter=2, pf_chunk=CHUNK, all_greedy=not sampled,
@@ -105,7 +106,7 @@ def _fused_in_two_passes(*args, **kwargs):
 _MIXED = jax.jit(serving._fused_chunk.__wrapped__, static_argnames=_FUSED_STATIC)
 _TWO_PASS = jax.jit(_fused_in_two_passes, static_argnames=_FUSED_STATIC)
 _OUT = ("packed", "tau", "tau_lp", "fill", "pos", "active", "remaining",
-        "keys", "pool", "pf_off")
+        "keys", "pool", "pf_vec")
 
 
 @pytest.mark.parametrize("impl", ["xla", "auto"], ids=["chunk-xla", "chunk-flash"])
@@ -131,7 +132,7 @@ def test_a_chunk_that_is_not_the_last_leaves_what_two_passes_leave(
     np.testing.assert_allclose(
         np.asarray(got["packed"][1]).view(np.float32)[toks != pad],
         np.asarray(want["packed"][1]).view(np.float32)[toks != pad], **TOL)
-    for name in ("tau", "fill", "pos", "active", "remaining", "keys", "pf_off"):
+    for name in ("tau", "fill", "pos", "active", "remaining", "keys", "pf_vec"):
         assert np.array_equal(np.asarray(got[name]), np.asarray(want[name])), name
     assert np.asarray(got["fill"]).tolist() == [0, 22, 9, 0]
     was = args[1]
@@ -196,7 +197,7 @@ def test_mixed_forward_is_the_chunk_forward_and_the_paged_decode_forward(
     tau, active = args[5], args[8]
     view = serving._gather_cache(pool, table[:1], jnp.asarray([MB]), fill[:1])
     view = dataclasses.replace(view, index=jnp.asarray(0, jnp.int32))
-    toks_c = args[16][None, :CHUNK]
+    toks_c = args[15][None, serving._PF_HEADER:][:, :CHUNK]
     positions, real = window_positions(0, 0, CHUNK, 64)
     rider_pos = jnp.where(active, args[7], -1)
     pcache = serving._pool_as_cache(pool, table, fill)
@@ -502,7 +503,7 @@ def test_only_the_dense_block_s_fused_chunk_holds_a_mixed_pass(model, kind):
     mb = config.max_seq_len // BLK
     pool = jax.eval_shape(lambda: serving.init_pool(
         config, rows * mb, BLK, n_slots=rows, n_snapshots=rows))
-    extra = (jax.ShapeDtypeStruct((), jnp.int32),) * 2 if kind == "recurrent" else ()
+    extra = (jax.ShapeDtypeStruct((2,), jnp.int32),) if kind == "recurrent" else ()
     traced = serving._fused_chunk.trace(
         params, pool,
         *fused_chunk_operand_shapes(jax.ShapeDtypeStruct, rows, mb, chunk),

@@ -421,7 +421,7 @@ def test_fused_chunk_no_full_pool_copies_compiled():
         cb.params, cb.pool, cb.d_table, cb.d_n_alloc, cb.d_fill,
         cb.tau, cb.d_tau_lp, cb.d_pos, cb.d_active, cb.d_remaining,
         cb.d_stops, cb.keys, cb.d_temps, cb.d_top_ps, cb.d_top_ks,
-        pf.d_row, pf.d_toks, pf.d_len, pf.d_base, pf.d_off, pf.d_key,
+        pf.d_vec,
         config=cb.config, n_iter=4, pf_chunk=pf.chunk,
         all_greedy=True, mesh=None, allow_kernel=True,
         with_logprobs=False,
