@@ -460,6 +460,8 @@ def _make_batcher_stub():
     s.ssm_snapshots_restored_total = 0
     s.ssm_match_tokens_cut_total = 0
     s.n_snapshots = 0
+    s.ssm_state_bytes_per_slot = 0
+    s.ssm_snapshot_bytes = 0
     s.prefill_ctx_slots_attended_total = 0
     s.prefill_ctx_slots_view_total = 0
     s.prefill_blocks_written_total = 0

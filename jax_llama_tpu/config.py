@@ -141,9 +141,83 @@ class LLaMAConfig:
     mamba_dt_rank: int = 0                # 0 -> ceil(dim / 16)
     layer_norm_eps: float = 1e-5
 
+    # --- a state-space mixer BESIDE attention in every layer.  mamba_d_ssm
+    # 0: none.  > 0 selects the block of models/falcon_h1.py (falcon_h1-
+    # style): every layer runs a Mamba-2 mixer (one scalar decay a head a
+    # token, a state [mamba_n_heads, mamba_d_head, mamba_d_state] float32 a
+    # row, `B` / `C` shared by `mamba_n_groups` groups of heads, a chunked
+    # matmul scan over `mamba_chunk_size` tokens) AND rotary GQA attention on
+    # the same normed input and adds both to the residual, so every layer
+    # owns K/V planes and a recurrent state at once.  RMSNorm, SwiGLU, an
+    # untied head, and the muP multipliers below (scalars; `ssm_multipliers`
+    # over the mixer projection's zones z, x, B, C, dt; `mlp_multipliers` on
+    # the FFN's gate and its down projection).
+    mamba_d_ssm: int = 0
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 128
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: Tuple[float, ...] = (1.0, 1.0)
+
+    def __post_init__(self):
+        # JSON (a checkpoint's config.json) hands a tuple back as a list,
+        # which neither compares equal nor hashes as a static argument.
+        for name in ("window_layers", "ssm_multipliers", "mlp_multipliers"):
+            value = getattr(self, name)
+            if isinstance(value, list):
+                object.__setattr__(self, name, tuple(value))
+
+    @property
+    def parallel_mixer(self) -> bool:
+        """The block whose every layer is a mixer beside attention."""
+        return self.mamba_d_ssm > 0
+
     @property
     def recurrent_state(self) -> bool:
-        return self.mb_per_layer > 0
+        """A per-row recurrent state beside the K/V planes: either block."""
+        return self.mb_per_layer > 0 or self.parallel_mixer
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the parallel mixer's convolution: x beside B and C."""
+        return self.mamba_d_ssm + 2 * self.mamba_n_groups * self.mamba_d_state
+
+    @property
+    def state_shapes(self) -> Tuple[Tuple[str, Tuple[int, ...], str], ...]:
+        """(name, shape a layer a row, dtype) of a row's recurrent state, in
+        the order caches and pools carry it (`conv`, `ssm`): the mixers' last
+        conv inputs in the activation type and their state-space state in
+        float32, minor axis lane-friendly (see `ops/ssm.py`).  Empty without
+        recurrent layers."""
+        if not self.recurrent_state:
+            return ()
+        keep = self.mamba_d_conv - 1
+        if self.parallel_mixer:
+            return (
+                ("conv", (keep * self.mamba_conv_dim,), self.dtype),
+                ("ssm", (self.mamba_n_heads, self.mamba_d_head,
+                         self.mamba_d_state), "float32"),
+            )
+        return (
+            ("conv", (keep * self.mamba_d_inner,), self.dtype),
+            ("ssm", (self.mamba_d_state, self.mamba_d_inner), "float32"),
+        )
+
+    @property
+    def state_bytes_per_row(self) -> int:
+        """Bytes of one row's recurrent state over all its layers: what a
+        slot holds beside its blocks, and what one snapshot costs."""
+        return self.state_layers * sum(
+            math.prod(shape) * jnp.dtype(dtype).itemsize
+            for _, shape, dtype in self.state_shapes)
 
     @property
     def mamba_d_inner(self) -> int:
@@ -155,7 +229,9 @@ class LLaMAConfig:
 
     @property
     def layer_kinds(self) -> Tuple[str, ...]:
-        """The recurrent block's layer kinds by index (see above)."""
+        """A recurrent block's layer kinds by index (see above)."""
+        if self.parallel_mixer:
+            return ("mixer+full",) * self.n_layers
         half = self.n_layers // 2
         first = ("mamba", "window") * (half // 2)
         second = ("gmu", "cross") * ((half - 2) // 2)
@@ -164,14 +240,16 @@ class LLaMAConfig:
     @property
     def state_layers(self) -> int:
         """Layers that carry a recurrent state a row: the mixers."""
+        if self.parallel_mixer:
+            return self.n_layers
         return self.n_layers // 4 + 1 if self.recurrent_state else 0
 
     @property
     def cache_layers(self) -> int:
         """Layers that OWN K/V planes (or the latent plane) in a cache: all
-        of them, but in the recurrent block the window layers and the one
-        full-attention layer, whose plane the cross layers read."""
-        return self.n_layers // 4 + 1 if self.recurrent_state else self.n_layers
+        of them, but in the alternating recurrent block the window layers
+        and the one full-attention layer, whose plane the cross layers read."""
+        return self.n_layers // 4 + 1 if self.mb_per_layer > 0 else self.n_layers
 
     @property
     def kv_heads(self) -> int:
@@ -200,7 +278,7 @@ class LLaMAConfig:
     def cache_heads(self) -> int:
         """Heads of one cached row: the KV heads, 1 for the latent, or the
         KV head PAIRS of differential attention (`[k1 | k2]` a row)."""
-        if self.recurrent_state:
+        if self.mb_per_layer > 0:
             return self.kv_heads // 2
         return 1 if self.latent_attention else self.kv_heads
 
@@ -217,7 +295,7 @@ class LLaMAConfig:
             # operand then costs a copy of the whole pool in and out of
             # every dispatch (compiled for a v5e, PR 27).
             return -(-self.latent_dim // 128) * 128
-        if self.recurrent_state:
+        if self.mb_per_layer > 0:
             return 2 * self.head_dim
         return self.head_dim
 
@@ -251,7 +329,9 @@ class LLaMAConfig:
         assert self.head_size is not None or self.dim % self.n_heads == 0, (
             "n_heads must divide dim (or head_size be given)"
         )
-        if self.recurrent_state:
+        if self.parallel_mixer:
+            self._validate_parallel_mixer()
+        elif self.recurrent_state:
             self._validate_recurrent()
         elif self.windowed_attention:
             self._validate_windowed()
@@ -284,6 +364,8 @@ class LLaMAConfig:
         """The block beside the dense one a configuration selects, as error
         messages name it; None for the dense block.  None of them gets a
         mesh, int8, speculation or the train step yet."""
+        if self.parallel_mixer:
+            return "parallel mixer and attention layers"
         if self.recurrent_state:
             return "recurrent state layers"
         if self.latent_attention:
@@ -366,6 +448,52 @@ class LLaMAConfig:
             raise ValueError(
                 f"use_scaled_rope is not supported with {block}: the block "
                 "carries no position encoding")
+
+    def _validate_parallel_mixer(self) -> None:
+        """The block with a mixer beside attention in every layer: what it
+        needs, and what it does not get yet — refused by name here, never
+        served wrongly."""
+        block = self.expert_block
+        if (self.mb_per_layer or self.latent_attention
+                or self.windowed_attention or self.n_routed_experts):
+            raise ValueError(
+                "mamba_d_ssm beside mb_per_layer / kv_lora_rank / "
+                "window_layers / routed experts: two blocks in one "
+                "configuration")
+        for name in ("mamba_n_heads", "mamba_d_head", "mamba_d_state",
+                     "mamba_n_groups", "mamba_chunk_size"):
+            if getattr(self, name) <= 0:
+                raise ValueError(f"{block} need {name} > 0")
+        if self.mamba_n_heads * self.mamba_d_head != self.mamba_d_ssm:
+            raise ValueError(
+                f"mamba_n_heads * mamba_d_head = {self.mamba_n_heads} * "
+                f"{self.mamba_d_head} is not mamba_d_ssm = {self.mamba_d_ssm}")
+        if (self.mamba_n_heads % self.mamba_n_groups
+                or self.mamba_d_ssm % self.mamba_n_groups):
+            raise ValueError(
+                f"mamba_n_groups = {self.mamba_n_groups} must divide "
+                f"mamba_n_heads = {self.mamba_n_heads} (heads share a "
+                "group's B and C, the gated norm runs a group)")
+        if self.mamba_d_conv != 4:
+            raise ValueError(
+                f"mamba_d_conv: {self.mamba_d_conv!r} is not in the program; "
+                "the convolution state holds 3 inputs (width 4)")
+        if len(self.ssm_multipliers) != 5 or len(self.mlp_multipliers) != 2:
+            raise ValueError(
+                "ssm_multipliers has one value a zone of the mixer's "
+                "projection (z, x, B, C, dt: 5) and mlp_multipliers one for "
+                "the gate and one for the down projection (2), got "
+                f"{len(self.ssm_multipliers)} and {len(self.mlp_multipliers)}")
+        if self.tie_word_embeddings:
+            raise ValueError(f"{block}: the head is untied (tie_word_embeddings)")
+        if self.kv_cache_dtype == "int8":
+            raise ValueError(
+                f"kv_cache_dtype='int8' is not supported with {block}: the "
+                "recurrent state beside the planes has no int8 form")
+        if self.attn_impl == "ring":
+            raise ValueError(f"attn_impl='ring' is not supported with {block}")
+        if self.use_scaled_rope:
+            raise ValueError(f"use_scaled_rope is not supported with {block}")
 
     def _validate_windowed(self) -> None:
         """The window-and-full-attention block (see `_validate_experts`)."""
@@ -513,6 +641,100 @@ def _from_published_recurrent(raw, *, max_seq_len: int, attn_impl: str) -> "LLaM
         dtype=raw["torch_dtype"], param_dtype=raw["torch_dtype"],
         max_seq_len=max_seq_len, attn_impl=attn_impl,
     )
+
+
+# the falcon_h1 block (a Mamba-2 mixer beside rotary GQA attention in every
+# layer, muP multipliers): published key -> field.  Every key is needed.
+_PUBLISHED_PARALLEL = {
+    "hidden_size": "dim", "num_hidden_layers": "n_layers",
+    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+    "head_dim": "head_size",
+    "intermediate_size": "intermediate_size", "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta", "rms_norm_eps": "rms_norm_eps",
+    "tie_word_embeddings": "tie_word_embeddings",
+    "mamba_d_ssm": "mamba_d_ssm", "mamba_n_heads": "mamba_n_heads",
+    "mamba_d_head": "mamba_d_head", "mamba_d_state": "mamba_d_state",
+    "mamba_n_groups": "mamba_n_groups", "mamba_d_conv": "mamba_d_conv",
+    "mamba_chunk_size": "mamba_chunk_size",
+    "embedding_multiplier": "embedding_multiplier",
+    "lm_head_multiplier": "lm_head_multiplier",
+    "key_multiplier": "key_multiplier",
+    "attention_in_multiplier": "attention_in_multiplier",
+    "attention_out_multiplier": "attention_out_multiplier",
+    "ssm_in_multiplier": "ssm_in_multiplier",
+    "ssm_out_multiplier": "ssm_out_multiplier",
+    "ssm_multipliers": "ssm_multipliers", "mlp_multipliers": "mlp_multipliers",
+}
+# its keys with ONE accepted value: the block as the program computes it
+# (`mamba_expand` and `mlp_expansion_factor` are unused where `mamba_d_ssm`
+# and `intermediate_size` are given, and accepted as published only)
+_PUBLISHED_PARALLEL_FIXED = {
+    "model_type": "falcon_h1", "hidden_act": "silu", "attention_bias": False,
+    "attn_layer_indices": None, "rope_scaling": None, "num_logits_to_keep": 1,
+    "mamba_conv_bias": True, "mamba_proj_bias": False, "mamba_rms_norm": True,
+    "mamba_norm_before_gate": False, "mamba_use_mlp": True,
+    "projectors_bias": False, "mlp_bias": False, "tie_word_embeddings": False,
+    "mamba_expand": 2, "mlp_expansion_factor": 8, "mamba_d_conv": 4,
+}
+_PUBLISHED_PARALLEL_LISTS = {"ssm_multipliers": 5, "mlp_multipliers": 2}
+
+
+def _is_number(v) -> bool:
+    return isinstance(v, (int, float)) and not isinstance(v, bool)
+
+
+def _from_published_parallel(raw, *, max_seq_len: int, attn_impl: str) -> "LLaMAConfig":
+    """`from_published` for a file with `mamba_d_ssm` (the falcon_h1
+    block), as strict as the others.  How the mixer's sizes must fit one
+    another is `LLaMAConfig._validate_parallel_mixer`'s to say: its fields
+    carry the published names."""
+    fields = _PUBLISHED_PARALLEL
+    known = (set(fields) | set(_PUBLISHED_PARALLEL_FIXED)
+             | {"torch_dtype", "max_position_embeddings"})
+    unknown = sorted(set(raw) - known)
+    if unknown:
+        raise ValueError(f"the program understands no published key {', '.join(map(repr, unknown))}")
+    missing = sorted(k for k in (*fields, *_PUBLISHED_PARALLEL_FIXED, "torch_dtype")
+                     if k not in raw)
+    if missing:
+        raise ValueError(
+            f"published key {missing[0]!r} is missing (a file with "
+            "'mamba_d_ssm' is the block with parallel mixer and attention "
+            "layers, which needs it)")
+    for key, only in _PUBLISHED_PARALLEL_FIXED.items():
+        if raw[key] != only or isinstance(raw[key], bool) != isinstance(only, bool):
+            raise ValueError(
+                f"{key}: {raw[key]!r} is not in the program; its block with "
+                f"parallel mixer and attention layers computes {only!r} only")
+    for key, n in _PUBLISHED_PARALLEL_LISTS.items():
+        v = raw[key]
+        if not isinstance(v, (list, tuple)) or len(v) != n or not all(map(_is_number, v)):
+            raise ValueError(f"{key}: {v!r} is not a list of {n} numbers")
+    for key in fields:
+        if key.endswith("_multiplier") and not _is_number(raw[key]):
+            raise ValueError(f"{key}: {raw[key]!r} is not a number")
+    for key in ("hidden_size", "num_hidden_layers", "num_attention_heads",
+                "num_key_value_heads", "head_dim", "intermediate_size",
+                "vocab_size", "mamba_d_ssm", "mamba_n_heads", "mamba_d_head",
+                "mamba_d_state", "mamba_n_groups", "mamba_chunk_size"):
+        v = raw[key]
+        if not isinstance(v, int) or isinstance(v, bool) or v <= 0:
+            raise ValueError(f"{key}: {v!r} is not an int > 0")
+    if raw["head_dim"] % 2:
+        raise ValueError(f"head_dim: {raw['head_dim']!r} is not an even size > 0")
+    if raw["num_attention_heads"] % raw["num_key_value_heads"]:
+        raise ValueError("num_key_value_heads does not divide num_attention_heads")
+    if raw["torch_dtype"] not in _PUBLISHED_DTYPES:
+        raise ValueError(f"torch_dtype {raw['torch_dtype']!r} is not one the program serves in")
+    values = {ours: raw[theirs] for theirs, ours in fields.items()}
+    for key in _PUBLISHED_PARALLEL_LISTS:
+        values[key] = tuple(float(v) for v in raw[key])
+    return LLaMAConfig(
+        **values, dtype=raw["torch_dtype"], param_dtype=raw["torch_dtype"],
+        max_seq_len=max_seq_len, attn_impl=attn_impl,
+    )
+
+
 _LAYER_TYPES = ("sliding_attention", "full_attention")
 
 
@@ -549,9 +771,16 @@ def from_published(raw, *, max_seq_len: int, attn_impl: str) -> LLaMAConfig:
     `max_position_embeddings` is accepted and unused: a server serves at its
     own `max_seq_len`.  A file with `kv_lora_rank` is the deepseek_v3 block,
     one with `layer_types` the afmoe block, one with `mb_per_layer` the
-    phi4flash block; every other file is the dense block."""
+    phi4flash block, one with `mamba_d_ssm` the falcon_h1 block; every
+    other file is the dense block."""
     latent = "kv_lora_rank" in raw
     windowed = "layer_types" in raw
+    if "mamba_d_ssm" in raw or raw.get("model_type") == "falcon_h1":
+        if latent or windowed or "mb_per_layer" in raw:
+            raise ValueError(
+                "mamba_d_ssm beside kv_lora_rank / layer_types / mb_per_layer: "
+                "two blocks in one file")
+        return _from_published_parallel(raw, max_seq_len=max_seq_len, attn_impl=attn_impl)
     if "mb_per_layer" in raw or raw.get("model_type") == "phi4flash":
         if latent or windowed:
             raise ValueError("mb_per_layer beside kv_lora_rank / layer_types: two blocks in one file")

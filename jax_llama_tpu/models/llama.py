@@ -524,16 +524,19 @@ def lm_head_logits(
     config.logits_dtype (fp32 island, reference model.py:732-736).
     ``normed=True`` means x is already the post-final-norm hidden state
     (callers that also emit it as an aux output norm exactly once)."""
-    if not normed:
-        x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
-    if config.tie_word_embeddings:
-        kernel = params["embed"]["embedding"].T
-    else:
-        kernel = params["lm_head"]
-    logits = qeinsum(
-        x, kernel, "btd,dv->btv", config.activation_dtype,
-        preferred_element_type=jnp.dtype(config.logits_dtype),
-    ).astype(config.logits_dtype)
+    with jax.named_scope("head"):
+        if not normed:
+            x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
+        if config.tie_word_embeddings:
+            kernel = params["embed"]["embedding"].T
+        else:
+            kernel = params["lm_head"]
+        logits = qeinsum(
+            x, kernel, "btd,dv->btv", config.activation_dtype,
+            preferred_element_type=jnp.dtype(config.logits_dtype),
+        ).astype(config.logits_dtype)
+        if config.lm_head_multiplier != 1.0:
+            logits = logits * config.lm_head_multiplier
     return constrain(logits, "data", "seq", "tensor")
 
 
@@ -576,6 +579,15 @@ def cache_stats_zero(config: LLaMAConfig) -> Optional[jnp.ndarray]:
     return None
 
 
+def init_state(config: LLaMAConfig, rows: int) -> Tuple[jnp.ndarray, ...]:
+    """Empty per-row recurrent state of `rows` rows, by
+    `config.state_shapes`: (`conv` [Ls, rows, ...], `ssm` [Ls, rows, ...]
+    float32) for a block with recurrent layers, () for every other."""
+    return tuple(
+        jnp.zeros((config.state_layers, rows) + shape, jnp.dtype(dtype))
+        for _, shape, dtype in config.state_shapes)
+
+
 def init_cache(
     config: LLaMAConfig,
     batch: int,
@@ -593,13 +605,8 @@ def init_cache(
         config.cache_width,
     )
     latent = config.latent_attention
-    state = {}
-    if config.recurrent_state:
-        from .sambay import init_state
-
-        state["conv"], state["ssm"] = init_state(config, batch)
     return KVCache(
-        **state,
+        **dict(zip(("conv", "ssm"), init_state(config, batch))),
         k=jnp.zeros(shape, dtype=dtype),
         v=None if latent else jnp.zeros(shape, dtype=dtype),
         stats=cache_stats_zero(config),
@@ -627,6 +634,10 @@ def init_params(rng: jax.Array, config: LLaMAConfig) -> Params:
         from . import afmoe
 
         return afmoe.init_params(rng, config)
+    if config.parallel_mixer:
+        from . import falcon_h1
+
+        return falcon_h1.init_params(rng, config)
     if config.recurrent_state:
         from . import sambay
 
@@ -1179,10 +1190,12 @@ def forward(
         # The block follows from the configuration: latent attention over
         # a latent cache, window and full attention layers over the K/V
         # cache (routed experts behind leading dense layers), or recurrent
-        # state layers beside window / full / cross attention.
-        from . import afmoe, mla_moe, sambay
+        # state layers beside window / full / cross attention, or a mixer
+        # beside attention in every layer.
+        from . import afmoe, falcon_h1, mla_moe, sambay
 
-        block = (sambay if config.recurrent_state
+        block = (falcon_h1 if config.parallel_mixer
+                 else sambay if config.recurrent_state
                  else mla_moe if config.latent_attention else afmoe)
         return block.forward(
             params, tokens, positions, config, cache=cache,

@@ -84,16 +84,6 @@ LAMBDA_STD = 0.1
 DT_MIN, DT_MAX = 1e-3, 1e-1
 
 
-def init_state(config: LLaMAConfig, rows: int):
-    """Empty per-row recurrent state: (`conv` [Ls, rows, 3 Di], `ssm`
-    [Ls, rows, N, Di] float32)."""
-    Ls, Di, N = config.state_layers, config.mamba_d_inner, config.mamba_d_state
-    return (
-        jnp.zeros((Ls, rows, (config.mamba_d_conv - 1) * Di), config.activation_dtype),
-        jnp.zeros((Ls, rows, N, Di), jnp.float32),
-    )
-
-
 def init_params(rng: jax.Array, config: LLaMAConfig) -> Params:
     """Seeded weights by the family's initialisers: N(0, INIT_STD^2) for the
     projections and the embedding; the conv and `dt_proj` uniform in
@@ -219,7 +209,7 @@ def forward(
     cache-free (the state starts at zero), over a `KVCache` (scalar or per-row
     index) or over a `PagedKVCache`, each with its `conv` / `ssm` state."""
     from .llama import (
-        FLASH_MIN_SEQ, AuxOutput, KVCache, PagedKVCache, _swiglu,
+        FLASH_MIN_SEQ, AuxOutput, KVCache, PagedKVCache, _swiglu, init_state,
         lm_head_logits, paged_pool_write, paged_write_indices, qeinsum,
     )
 

@@ -497,7 +497,8 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "Live grid steps of the paged decode kernel in full "
                    "attention layers, summed over rows, layers and "
                    "iterations"),
-    # -- recurrent state layers (models/sambay.py; zero without) ------------
+    # -- recurrent state layers (models/sambay.py, models/falcon_h1.py; zero
+    # without) ---------------------------------------------------------------
     "ssm_snapshots_taken_total": _reg(
         "counter", "Recurrent-state snapshots copied out at a block "
                    "boundary of a prompt and hung on its radix node"),
@@ -511,6 +512,11 @@ METRICS: Dict[str, Tuple[str, str]] = {
                    "no state snapshot stood behind them"),
     "ssm_snapshots_in_use": _reg(
         "gauge", "State snapshots hung on radix nodes"),
+    "ssm_state_bytes_per_slot": _reg(
+        "gauge", "Bytes of one slot's recurrent state over all its layers "
+                 "(what one state snapshot holds too)"),
+    "ssm_snapshot_bytes": _reg(
+        "gauge", "Bytes of the state snapshot pool under the radix store"),
     "decode_stall_ms_total": _reg(
         "counter", "Wall time classic whole-prompt admissions stalled "
                    "decoding rows (ms)"),

@@ -1,5 +1,7 @@
-"""The selective state-space recurrence (Mamba-1), in the two forms serving
-needs.
+"""The selective state-space recurrences, each in the two forms serving
+needs.  First the per-channel one (Mamba-1: `ssm_step`, `ssm_scan`), then
+the matrix-a-head one (Mamba-2 / SSD: `ssd_step`, `ssd_scan`; its own
+section below).
 
     h_t = exp(dt_t * A) * h_{t-1} + (dt_t * c_t) B_t^T        h [N, Di] float32
     y_t = sum_n h_t[n] * C_t[n]                               (the caller adds Dskip * c_t)
@@ -171,3 +173,113 @@ def _scan_pallas(h0, x, dt, Bm, Cm, A, interpret: bool):
         name="ssm_scan",
     )(Bm, Cm, fold(x), fold(dt), fold(A), fold(h0))
     return y.reshape(B, T, Di), hT.reshape(B, N, Di)
+
+
+# ---------------------------------------------------------------------------
+# The matrix-a-head recurrence (Mamba-2, "SSD"): one scalar decay a head a
+# token, a state [Hm, P, N] a row, `B` / `C` shared by groups of heads.
+#
+#     h_t = exp(dt_t A) h_{t-1} + dt_t x_t (x) B_t        h [Hm, P, N] float32
+#     y_t = h_t C_t                                       (the caller adds Dskip * x_t)
+#
+# `ssd_step` advances every row by ONE token; a row that is not `live` keeps
+# its state bit for bit.  `ssd_scan` takes a row's state through the `T`
+# tokens of a prompt chunk in chunks of `chunk` tokens: inside a chunk
+# `Y = (L o (C B^T)) X` with `L[t, s] = exp(sum_{s<r<=t} dt_r A)` for
+# `s <= t`, plus `C_t` against the chunk's entry state decayed to `t`; between
+# chunks the state carries through a `lax.scan`.  Everything a chunk needs is
+# a matmul ([Q, Q] scores a head, [Q, N] x [N, P] against the state), no
+# `[T, Hm, P, N]` temporary exists, and the same code is the CPU tests' form.
+#
+# Layout.  The state is `[B, Hm, P, N]`, `N` minor (256 here: two lane
+# tiles), `P` on the sublanes.  Everything is float32, and the matmuls run
+# at the highest precision: the state compounds over thousands of tokens,
+# and the scan is well under a hundredth of a prompt chunk's operations.
+# ---------------------------------------------------------------------------
+
+_HIGHEST = lax.Precision.HIGHEST
+
+
+def _by_head(g: jnp.ndarray, heads: int) -> jnp.ndarray:
+    """[..., G, N] of the groups -> [..., Hm, N] of the heads (head h reads
+    group h // (Hm / G))."""
+    return jnp.repeat(g, heads // g.shape[-2], axis=-2)
+
+
+def ssd_step(
+    h: jnp.ndarray,      # [B, Hm, P, N] float32
+    x: jnp.ndarray,      # [B, Hm, P]
+    dt: jnp.ndarray,     # [B, Hm]
+    Bm: jnp.ndarray,     # [B, G, N]
+    Cm: jnp.ndarray,     # [B, G, N]
+    A: jnp.ndarray,      # [Hm]
+    live: jnp.ndarray,   # [B] bool
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """One token a row: (y [B, Hm, P], the new state).  A row that is not
+    `live` returns its state unchanged, bit for bit."""
+    f32 = jnp.float32
+    x, dt, Bm, Cm = (a.astype(f32) for a in (x, dt, Bm, Cm))
+    Hm = h.shape[1]
+    decay = jnp.exp(dt * A)[:, :, None, None]
+    new = decay * h + (dt[:, :, None] * x)[..., None] * _by_head(Bm, Hm)[:, :, None, :]
+    kept = jnp.where(live[:, None, None, None], new, h)
+    # `y` reads what is written (a dead row's `y` is nobody's): one pass over
+    # the slab yields both.
+    return jnp.sum(kept * _by_head(Cm, Hm)[:, :, None, :], axis=-1), kept
+
+
+def ssd_scan(
+    h0: jnp.ndarray,       # [B, Hm, P, N] float32
+    x: jnp.ndarray,        # [B, T, Hm, P]
+    dt: jnp.ndarray,       # [B, T, Hm]
+    Bm: jnp.ndarray,       # [B, T, G, N]
+    Cm: jnp.ndarray,       # [B, T, G, N]
+    A: jnp.ndarray,        # [Hm]
+    lengths: jnp.ndarray,  # [B] int32: the row's live tokens, a prefix of T
+    chunk: int = 128,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """`T` tokens a row: (y [B, T, Hm, P], the state after the row's last
+    live token).  Tokens past `lengths[b]` update nothing (their `dt` is
+    taken as zero, under which the recurrence is the identity); their `y` is
+    not meaningful.  `T` need not be a multiple of `chunk`: the tail is
+    padded with such tokens."""
+    f32 = jnp.float32
+    B, T, Hm, P = x.shape
+    G, N = Bm.shape[2:]
+    Q = min(chunk, T)
+    n = -(-T // Q)
+    per = Hm // G
+    live = jnp.arange(T, dtype=jnp.int32)[None, :] < lengths[:, None]
+    dt = jnp.where(live[:, :, None], dt.astype(f32), 0.0)
+
+    def chunks(a):
+        """[B, T, H, ...] -> [n, B, H, Q, ...]: heads (or groups) major, so
+        that every product below is a matmul batched over them."""
+        a = jnp.pad(a.astype(f32), ((0, 0), (0, n * Q - T)) + ((0, 0),) * (a.ndim - 2))
+        return jnp.moveaxis(a.reshape((B, n, Q) + a.shape[2:]), (1, 3), (0, 2))
+
+    causal = jnp.tril(jnp.ones((Q, Q), jnp.bool_))
+
+    def step(h, xs):
+        x_c, dt_c, B_c, C_c = xs          # [B, Hm, Q, P], [B, Hm, Q], [B, G, Q, N] x 2
+        cum = jnp.cumsum(dt_c * A[None, :, None], axis=2)         # [B, Hm, Q], <= 0
+        # L[t, s] = exp(cum_t - cum_s) for s <= t: the decay from s to t.
+        seg = jnp.where(causal, cum[..., :, None] - cum[..., None, :], -jnp.inf)
+        CB = jnp.einsum("bgtn,bgsn->bgts", C_c, B_c, precision=_HIGHEST)
+        W = jnp.exp(seg) * jnp.repeat(CB, per, axis=1)            # [B, Hm, Q, Q]
+        xdt = x_c * dt_c[..., None]                               # [B, Hm, Q, P]
+        y = jnp.einsum("bhts,bhsp->bhtp", W, xdt, precision=_HIGHEST)
+        # The entry state, decayed to t, read by C_t.
+        hg = h.reshape(B, G, per, P, N)
+        y_in = jnp.einsum("bgtn,bgepn->bgetp", C_c, hg, precision=_HIGHEST)
+        y = y + jnp.exp(cum)[..., None] * y_in.reshape(B, Hm, Q, P)
+        # The state at the chunk's end.
+        tail = jnp.exp(cum[..., -1:] - cum)                       # [B, Hm, Q]
+        xg = (xdt * tail[..., None]).reshape(B, G, per, Q, P)
+        add = jnp.einsum("bgesp,bgsn->bgepn", xg, B_c, precision=_HIGHEST)
+        h = jnp.exp(cum[..., -1])[..., None, None] * h + add.reshape(h.shape)
+        return h, y
+
+    hT, y = lax.scan(step, h0, tuple(chunks(a) for a in (x, dt, Bm, Cm)))
+    # [n, B, Hm, Q, P] -> [B, T, Hm, P]
+    return jnp.moveaxis(y, (0, 2), (1, 3)).reshape(B, n * Q, Hm, P)[:, :T], hT

@@ -81,13 +81,15 @@ EXPERT_AXIS = "tensor"
 
 
 def _recurrent_block_specs(config: LLaMAConfig) -> Dict[str, Any]:
-    """Specs mirroring `models.sambay.init_params`: every leaf whole on its
-    chip.  `validate_tp` holds the block to one chip, so nothing is split
-    yet: the mixers' channels and the head pairs over ``tensor`` need the
-    per-slot state and the snapshot pool split with them."""
+    """Specs mirroring `models.sambay.init_params` or, for a mixer beside
+    attention in every layer, `models.falcon_h1.init_params`: every leaf
+    whole on its chip.  `validate_tp` holds both blocks to one chip, so
+    nothing is split yet: the mixers' channels (or heads, whose groups of
+    `B` / `C` must divide with them) and the attention heads over ``tensor``
+    need the per-slot state and the snapshot pool split with them."""
     import jax
 
-    from ..models.sambay import init_params
+    from ..models.llama import init_params
 
     shapes = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), config))
     return jax.tree_util.tree_map(lambda a: P(*(None,) * a.ndim), shapes)

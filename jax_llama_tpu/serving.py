@@ -216,6 +216,7 @@ from .models.llama import (
     PagedKVCache,
     forward,
     init_cache,
+    init_state,
     lm_head_logits,
     mixed_forward,
     cache_stats_zero,
@@ -321,8 +322,6 @@ def init_pool(
     latent = config.latent_attention
     state = {}
     if config.recurrent_state:
-        from .models.sambay import init_state
-
         if n_slots <= 0:
             raise ValueError(
                 "a pool for recurrent state layers needs n_slots > 0")
@@ -343,8 +342,27 @@ def init_pool(
 # The per-(layer, head) planes a pool may have; ``pos`` is per block only.
 _PLANES = ("k", "v", "k_scale", "v_scale")
 # State snapshots a slot (recurrent state layers): the snapshot pool holds
-# this many times n_slots.
+# this many times n_slots, but no more bytes than the K/V pool beside it
+# (``snapshot_pool_size``).
 _SNAPSHOTS_PER_SLOT = 8
+
+
+def snapshot_pool_size(
+    config: LLaMAConfig, n_slots: int, n_blocks: int, block_size: int,
+) -> int:
+    """How many state snapshots the radix store gets: eight a slot (a
+    4,096-token row at a 512-token chunk), but no more bytes than the K/V
+    pool holds.  A snapshot of a per-channel state is a few MB and eight a
+    slot stand (192 beside 24 slots and 4.5 GB of planes); one of a
+    matrix-a-head state is tens of MB, and eight a slot would be four times
+    the planes' bytes (256 x 25 MB beside 1.6 GB: 63 stand)."""
+    # A block with a recurrent state keeps K and V planes in the activation
+    # type (a latent or an int8 cache beside one is refused at `validate`).
+    kv_bytes = (
+        n_blocks * block_size * 2 * config.cache_layers * config.cache_heads
+        * config.cache_width * config.activation_dtype.itemsize)
+    return max(1, min(_SNAPSHOTS_PER_SLOT * n_slots,
+                      kv_bytes // config.state_bytes_per_row))
 
 
 def _map_planes(fn, pool_like, *others):
@@ -2276,6 +2294,23 @@ class ContinuousBatcher:
             )
         self.spec = draft_params is not None
         self.logprobs = logprobs
+        # Where ``_upload`` commits its copies: where the weights are, if
+        # they are committed to ONE device — replicated on their own
+        # one-device mesh if they carry one, which is how a program hands
+        # such an operand back (None: uncommitted weights, or several
+        # devices, where an unplaced copy is replicated as ever).
+        leaf = next((x for x in jax.tree_util.tree_leaves(params)
+                     if isinstance(x, jax.Array)), None)
+        self._upload_to = None
+        if leaf is not None and leaf.committed and len(leaf.devices()) == 1:
+            held = leaf.sharding
+            self._upload_to = (
+                jax.sharding.NamedSharding(
+                    held.mesh, jax.sharding.PartitionSpec())
+                if isinstance(held, jax.sharding.NamedSharding)
+                else jax.sharding.SingleDeviceSharding(
+                    next(iter(leaf.devices())))
+            )
         if config.expert_block:
             _refuse_block_extras(
                 params, draft_params, mesh, config.expert_block
@@ -2338,10 +2373,16 @@ class ContinuousBatcher:
                 "--prefix-index exact is not supported with "
                 f"{config.expert_block}: state snapshots hang on radix nodes")
         self.n_snapshots = (
-            _SNAPSHOTS_PER_SLOT * n_slots
+            snapshot_pool_size(
+                self.config, n_slots, self.n_blocks, self.block_size)
             if self.recurrent and prefix_cache and prefix_index == "radix"
             else 0
         )
+        # What a slot's state (and one snapshot of it) holds, and the
+        # snapshot pool: gauges that turn the ssm_* counts into bytes.
+        self.ssm_state_bytes_per_slot = self.config.state_bytes_per_row
+        self.ssm_snapshot_bytes = (
+            self.n_snapshots * self.ssm_state_bytes_per_slot)
         self.pool = init_pool(
             self.config, self.n_blocks, self.block_size,
             n_slots=n_slots, n_snapshots=self.n_snapshots,
@@ -2864,6 +2905,11 @@ class ContinuousBatcher:
             "prefill_budget": int(kw["prefill_budget"]),
             "prefix_index": self.prefix_index,
             "host_kv_blocks": self.host_kv_blocks,
+            # Recurrent state layers (0 without): what a slot's state and
+            # one snapshot of it hold, and the snapshot pool's size.
+            "n_snapshots": self.n_snapshots,
+            "ssm_state_bytes_per_slot": self.ssm_state_bytes_per_slot,
+            "ssm_snapshot_bytes": self.ssm_snapshot_bytes,
             "logprobs": self.logprobs,
             "use_pallas_kernel": bool(self.use_pallas_kernel),
             # The attention path every dispatch of this batcher traces
@@ -3023,6 +3069,8 @@ class ContinuousBatcher:
             "ssm_match_tokens_cut_total": self.ssm_match_tokens_cut_total,
             "ssm_snapshots_in_use": (
                 self._store.snapshots_in_use() if self.n_snapshots else 0),
+            "ssm_state_bytes_per_slot": self.ssm_state_bytes_per_slot,
+            "ssm_snapshot_bytes": self.ssm_snapshot_bytes,
             "fused_admissions_total": self.fused_admissions_total,
             # Fused dispatches whose first decode iteration rode the
             # prompt chunk's pass over the weights (``_mixed_pass``), and
@@ -3245,12 +3293,19 @@ class ContinuousBatcher:
         """One host->device copy by the loop thread outside a jitted
         call, counted (``host_uploads_total``, the next record's
         ``uploads``).  Replicated under a mesh, like every unplaced
-        operand."""
+        operand.  Where the weights are COMMITTED to one device the copy is
+        committed there too (``_upload_to``): beside committed operands an
+        uncommitted one selects another executable than the same operand
+        once a program has handed it back (the donated ``pf_vec``), and a
+        walk's first chunk must run the program of its later chunks — one
+        compile a (buffer length, K) pair, none left for a rare later
+        chunk to meet first."""
         self.obs.count_upload()
         # audit: host-upload(the counted copy outside a jitted call: a
         # fused admission's packed vector, once an admission; the classic
         # speculative round's tau)
-        return jnp.asarray(host)
+        return (jnp.asarray(host) if self._upload_to is None
+                else jax.device_put(host, self._upload_to))
 
     def _sync_device_rows(self) -> None:
         """Flush host-side per-row state changes (admission / free /

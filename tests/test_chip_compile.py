@@ -437,3 +437,83 @@ def test_recurrent_decode_chunk_for_v5e(sds, monkeypatch):
     assert "tpu_custom_call" in text
     offenders = _pool_copy_offenders(text, pool.k.shape)
     assert not offenders, (len(offenders), offenders)
+
+
+# --- the block with a mixer beside attention at its cell's shapes
+# (falconh1-assist-sessions): a GQA group of 5 at head size 128 ---------------
+
+def test_flash_and_paged_kernels_take_a_gqa_group_of_five_for_v5e(sds):
+    """20 query heads over 4 KV heads of 128, which no other cell puts
+    through the kernels: the flash kernel as the cell's prompt lane calls it
+    (512 queries over a 4096-slot view) and the paged decode kernel over the
+    cell's pool (32 rows x 32 blocks of 128, 6 layers)."""
+    from jax_llama_tpu.ops.flash_attention import flash_attention
+    from jax_llama_tpu.ops.paged_attention import paged_pool_attention
+
+    heads, kvh, rows, mb, layers, chunk, view = 20, 4, 32, 32, 6, 512, 4096
+    kv = sds((1, view, kvh, D), jnp.bfloat16)
+    _assert_mosaic(flash_attention.lower(
+        sds((1, chunk, heads, D), jnp.bfloat16), kv, kv,
+        sds((1, chunk), jnp.int32), sds((1, view), jnp.int32), interpret=False,
+    ))
+    nb = rows * mb
+    pool = sds((layers, kvh, nb, BLK, D), jnp.bfloat16)
+    _assert_mosaic(paged_pool_attention.lower(
+        sds((rows, kvh, heads // kvh, D), jnp.bfloat16), pool, pool,
+        sds((nb, BLK), jnp.int32), sds((rows, mb), jnp.int32),
+        sds((rows,), jnp.int32), k_scale=None, v_scale=None,
+        t_tokens=1, layer=sds((), jnp.int32), interpret=False,
+    ))
+
+
+def test_parallel_mixer_fused_chunk_for_v5e(sds, monkeypatch):
+    """`_fused_chunk` at the cell's widths (depth 2 of the 6 it runs), 32
+    slots x 4096 over 128-token blocks, 63 snapshots, `pf_chunk` 512, 8 decode
+    iterations: the flash and paged kernels are in the program, no pool-sized
+    copy stands in it, no [T, Hm, P, N] temporary of the chunk's tokens, and
+    the per-slot state is not copied whole beside itself (it rides the layer
+    scan's carry and is written back a layer's slab at a time)."""
+    import json
+    import re
+    from pathlib import Path
+
+    from test_serving_fused import fused_chunk_operand_shapes
+    from test_tpu_compiled import _pool_copy_offenders
+
+    from jax_llama_tpu import config as config_mod, init_params, serving
+
+    for name in ("flash_attention", "paged_attention"):
+        monkeypatch.setattr(
+            importlib.import_module(f"jax_llama_tpu.ops.{name}"),
+            "_resolve_interpret", lambda _=None: False)
+    raw = json.loads((Path(__file__).resolve().parent.parent / "benchmark" / "configs"
+                      / "Falcon-H1-34B-Instruct.json").read_text())
+    keys = {k: v for k, v in raw.items() if k not in (
+        "source", "architecture", "reference", "reduced", "assumed", "deployment")}
+    layers, rows, nb = 2, 32, 1024
+    cfg = config_mod.from_published(
+        dict(keys, num_hidden_layers=layers), max_seq_len=4096, attn_impl="auto")
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: sds(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pool = place(jax.eval_shape(
+        lambda: serving.init_pool(cfg, nb, 128, n_slots=rows, n_snapshots=63)))
+    assert pool.k.shape == (layers, 4, nb, 128, 128)
+    assert pool.ssm.shape == (layers, rows, 32, 128, 256)
+    lowered = serving._fused_chunk.lower(
+        params, pool, *fused_chunk_operand_shapes(sds, rows, 32, 512),
+        sds((2,), jnp.int32),
+        config=cfg, n_iter=8, pf_chunk=512, all_greedy=True, mesh=None,
+        allow_kernel=True, with_logprobs=False,
+    )
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") >= 2   # flash and the paged kernel
+    offenders = _pool_copy_offenders(text, pool.k.shape)
+    assert not offenders, (len(offenders), offenders)
+    # the recurrence a token of the chunk: [512, 32, 128, 256] in any order
+    assert not [l[:140] for l in text.splitlines()
+                if re.search(r"f32\[(1,)?(512,32,128,256|32,512,128,256|32,128,512,256)\]", l)]
+    whole = rf"f32\[{layers},{rows},32,128,256\]"
+    copies = [l.strip()[:140] for l in text.splitlines()
+              if re.search(rf" = {whole}\S* copy\(", l)]
+    assert not copies, copies

@@ -231,7 +231,9 @@ def test_a_reask_restores_a_snapshot_and_an_evicted_one_shortens_the_match(tiny,
     cb = jlt.ContinuousBatcher(
         params, cfg, n_slots=3, block_size=BLK, decode_chunk=4, prefill_budget=32,
         use_pallas_kernel=use_kernel)
-    assert cb.n_snapshots == 24 and cb.pool.snap_ssm.shape[1] == 24
+    # eight a slot would be 24; the tiny K/V pool's bytes hold 20 of these
+    # states (`serving.snapshot_pool_size`: the cell's own sizes give 192)
+    assert cb.n_snapshots == 20 and cb.pool.snap_ssm.shape[1] == 20
     out = {}
 
     def steps(n):
