@@ -469,6 +469,7 @@ def _make_batcher_stub():
     s.fused_dispatches_queued_total = 0
     s.fused_dispatches_merged_total = 0
     s.fused_merged_rows_total = 0
+    s.first_sample_totals = {}
     s.fused_admissions_total = 0
     s.decode_stall_ms_total = 0.0
     s.prefix_index = "radix"

@@ -477,6 +477,17 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "fused_merged_rows_total": _reg(
         "counter", "Decoding rows that rode such a pass, summed over "
                    "those dispatches"),
+    "first_sample_skipped_total": _reg(
+        "counter", "Fused dispatches whose prompt did not complete: the "
+                   "program read no head and drew nothing for the "
+                   "admission (with greedy and drawn, adds up to "
+                   "prefill_chunks_total)"),
+    "first_sample_greedy_total": _reg(
+        "counter", "Fused dispatches that completed a greedy request's "
+                   "prompt: its first token was an argmax"),
+    "first_sample_drawn_total": _reg(
+        "counter", "Fused dispatches that completed a sampling request's "
+                   "prompt: its first token took the warp and the draw"),
     # -- routed experts (ops/moe.py; zero on a configuration without) -------
     "moe_assignments_total": _reg(
         "counter", "(token, expert) pairs the router assigned"),
@@ -1570,6 +1581,7 @@ class Observability:
         ssm: Optional[Dict[str, int]] = None,
         merged_rows: Optional[int] = None,
         blocked: Optional[str] = None,
+        first_sample: Optional[str] = None,
     ) -> int:
         """Record one jitted serving dispatch and link it into the
         CURRENT span of every request that rode it.  Returns the
@@ -1609,6 +1621,9 @@ class Observability:
         ``merged_rows`` (fused dispatches that took the mixed pass, and only
         they) is the decoding rows whose first iteration rode the prompt
         chunk's pass over the weights.
+        ``first_sample`` (fused dispatches) is what the program's admission
+        sample cost: ``skipped`` (the prompt did not complete: no head, no
+        draw), ``greedy`` (an argmax) or ``drawn`` (the warp and the draw).
         ``then`` names the phase the loop thread is in once
         the dispatch ends (default: the one it interrupted)."""
         if kind not in DISPATCH_KINDS:
@@ -1656,6 +1671,8 @@ class Observability:
             rec["ssm"] = {key: int(v) for key, v in ssm.items()}
         if merged_rows is not None:
             rec["merged_rows"] = int(merged_rows)
+        if first_sample is not None:
+            rec["first_sample"] = first_sample
         rec.update(gap)
         with self._lock:
             seq = self._seq

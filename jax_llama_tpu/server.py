@@ -57,6 +57,11 @@ parity.  Design constraints, in order:
     ``llm_fused_merged_rows_total`` (counters — prompt-carrying
     dispatches whose first decode iteration rode the chunk's pass over
     the weights, and the decoding rows that rode it),
+    ``llm_first_sample_skipped_total`` / ``_greedy_total`` /
+    ``_drawn_total`` (counters — what each prompt-carrying dispatch's
+    admission sample cost: nothing while the prompt is incomplete, an
+    argmax for a greedy request, the warp and the draw otherwise; they
+    add up to ``llm_prefill_chunks_total``),
     ``llm_decode_stall_ms_total`` (counter — wall time classic
     whole-prompt admission dispatches spent while rows were
     mid-decode; ≈0 once fused scheduling is on), and

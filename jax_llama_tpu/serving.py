@@ -728,6 +728,52 @@ def _sample_step(
     return nxt, lp, keys
 
 
+def _admission_sample(
+    logits_of, done, sub, temperature, top_p, top_k, *, all_greedy,
+    with_logprobs,
+):
+    """The first token of ``_fused_chunk``'s admission, at the cost of what
+    its result needs: (token [1] with the -1 non-finite sentinel folded in,
+    its model logprob [1] or None).  ``logits_of()`` builds the [1, V]
+    logits of the prompt's last token and is called only under ``done``
+    (the prompt completes this dispatch), so a non-final chunk reads no
+    head, sorts nothing, and returns zeros nobody reads.  A greedy
+    admission is ``sample_rows``' own argmax and nothing else: the whole
+    branch under static ``all_greedy`` (as in ``_sample_step``), else by
+    the row's temperature as a value, so a greedy request admitted beside
+    sampling rows skips the warp and the draw too.  ``sub`` is the row's
+    subkey [1, 2]; the policies are the row's own, [1] each."""
+
+    def greedy(logits):
+        return jnp.argmax(
+            logits.astype(jnp.float32), axis=-1
+        ).astype(jnp.int32)
+
+    def consume():
+        logits = logits_of()
+        if all_greedy:
+            first = greedy(logits)
+        else:
+            first = lax.cond(
+                temperature[0] <= 0.0, greedy,
+                lambda lg: sample_rows(sub, lg, temperature, top_p, top_k),
+                logits,
+            )
+        lp = _token_logprob(logits, first) if with_logprobs else None
+        # Non-finite guard (see _paged_insert): the -1 sentinel rides
+        # tau into the scan's emit, which fails just this request.
+        return jnp.where(finite_rows(logits), first, -1), lp
+
+    def skip():
+        return (
+            jnp.zeros((1,), jnp.int32),
+            jnp.zeros((1,), jnp.float32) if with_logprobs else None,
+        )
+
+    with jax.named_scope("admit.sample"):
+        return lax.cond(done, consume, skip)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -785,6 +831,11 @@ def _paged_decode_step(
 # (the row was already inactive).  Distinct from the -1 non-finite
 # sentinel: real tokens are never negative, so both are unambiguous.
 _CHUNK_PAD = -2
+
+# What a fused dispatch's admission sample cost (``_admission_sample``):
+# nothing on a non-final chunk, an argmax for a greedy request, the warp
+# and the draw otherwise.  The dispatch record's ``first_sample``.
+_FIRST_SAMPLE = ("skipped", "greedy", "drawn")
 
 
 @functools.partial(
@@ -1010,19 +1061,26 @@ def _fused_chunk(
     fill0 = ``pf_base`` and attend the reused KV through the same view.
     The chunk's KV lands in the row's reserved blocks by whole blocks
     (``_land_chunk``; the bytes ``_scatter_back``'s pairs would write).
-    The last prompt token's hidden state is gathered every chunk (O(D);
-    the [1, V] head matmul is noise), but only the dispatch where
-    ``pf_off + pf_chunk >= pf_len`` CONSUMES it: the row's key chain
-    splits exactly once (the
+    The last prompt token's hidden state is gathered every chunk (O(D)),
+    but its [1, V] head product and the first token's sample run only in
+    the dispatch where ``pf_off + pf_chunk >= pf_len``, which CONSUMES
+    them (``_admission_sample``, a ``lax.cond`` on that value under the
+    scope ``admit.sample``; at 261,120 x 5,120 the head alone is 2.67 GB
+    of reads): the row's key chain splits exactly once (the
     ``_paged_insert`` split the classic path performs), the first token
-    is sampled with the row's own policy (non-finite guard folds the -1
-    sentinel exactly as admission does), and the row folds INTO the
+    is sampled with the row's own policy — an argmax and nothing else
+    for a greedy request, by the static ``all_greedy`` or by the row's
+    temperature as a value; the warp and the draw of ``sample_rows``
+    otherwise — (non-finite guard folds the -1 sentinel exactly as
+    admission does), and the row folds INTO the
     decode state mid-dispatch — active/fill/pos/tau/tau_lp/keys all
     flip on device — so the decode scan below emits its first sampled
-    token from THIS dispatch, not a later one.  Non-final chunks
-    discard the sample and leave the key chain untouched (``pf_key`` is
-    the same two header words every dispatch, so the chain starts exactly
-    where a classic ``_paged_insert`` of the request would).
+    token from THIS dispatch, not a later one.  Non-final chunks read no
+    head, draw nothing (the mixed pass's shared head product carries the
+    chunk's row either way; only its draw is skipped) and leave the key
+    chain untouched (``pf_key`` is the same two header words every
+    dispatch, so the chain starts exactly where a classic
+    ``_paged_insert`` of the request would).
 
     Decode half: the unchanged ``_chunk_scan`` (shared with
     ``_paged_decode_chunk``, so the fused program cannot drift from the
@@ -1115,7 +1173,10 @@ def _fused_chunk(
                 params, jnp.concatenate([h_last, hidden[:, C:]], axis=1),
                 config, normed=True,
             )[0]
-            logits_last = logits[:1]
+
+            def logits_last():  # a row of the shared head product
+                return logits[:1]
+
             nxt, lp, keys = _sample_step(
                 logits[1:, None], keys, temperature, top_p, top_k,
                 all_greedy=all_greedy, with_logprobs=with_logprobs,
@@ -1134,9 +1195,11 @@ def _fused_chunk(
                 aux.last_hidden_state,
                 jnp.clip(idx, 0, C - 1)[None, None, None], axis=1,
             )[:, 0]
-            logits_last = lm_head_logits(
-                params, h_last[:, None], config, normed=True
-            )[:, 0]
+
+            def logits_last():
+                return lm_head_logits(
+                    params, h_last[:, None], config, normed=True
+                )[:, 0]
         pool = _land_chunk(pool, view, table_r, write_at, C)
         if pool.conv is not None:
             # The chunk's end state into the row's slot, and into snapshot
@@ -1156,22 +1219,18 @@ def _fused_chunk(
                    for n, snaps in (("conv", pool.snap_conv),
                                     ("ssm", pool.snap_ssm))},
             )
-        # The admission sample — only persisted below when the prompt
-        # completes this dispatch (the split/sample topology is exactly
-        # _paged_insert's, so the row's stream is bit-identical to the
-        # classic admit-then-decode path).
-        kc, sub = _split_rows(pf_key[None])
-        t_r = lax.dynamic_slice_in_dim(temperature, pf_row, 1, axis=0)
-        p_r = lax.dynamic_slice_in_dim(top_p, pf_row, 1, axis=0)
-        k_r = lax.dynamic_slice_in_dim(top_k, pf_row, 1, axis=0)
-        first = sample_rows(sub, logits_last, t_r, p_r, k_r)
-        first_lp = (
-            _token_logprob(logits_last, first) if with_logprobs else None
-        )
-        # Non-finite guard (see _paged_insert): the -1 sentinel rides
-        # tau into the scan's emit, which fails just this request.
-        first = jnp.where(finite_rows(logits_last), first, -1)
+        # The admission sample — evaluated only in the dispatch where the
+        # prompt completes, and persisted below (the split/sample topology
+        # is exactly _paged_insert's, so the row's stream is bit-identical
+        # to the classic admit-then-decode path).
         done = pf_off + C >= pf_len
+        kc, sub = _split_rows(pf_key[None])
+        first, first_lp = _admission_sample(
+            logits_last, done, sub,
+            *(lax.dynamic_slice_in_dim(a, pf_row, 1, axis=0)
+              for a in (temperature, top_p, top_k)),
+            all_greedy=all_greedy, with_logprobs=with_logprobs,
+        )
         fold = (jnp.arange(B, dtype=jnp.int32) == pf_row) & done
         active = active | fold
         tau = jnp.where(fold, first[0], tau)
@@ -2663,6 +2722,9 @@ class ContinuousBatcher:
         self.fused_dispatches_queued_total = 0
         self.fused_dispatches_merged_total = 0
         self.fused_merged_rows_total = 0
+        # What each fused dispatch's admission sample cost
+        # (``_admission_sample``; they add up to ``prefill_chunks_total``).
+        self.first_sample_totals = dict.fromkeys(_FIRST_SAMPLE, 0)
         self.fused_admissions_total = 0
         self.decode_stall_ms_total = 0.0
 
@@ -3079,6 +3141,8 @@ class ContinuousBatcher:
                 self.fused_dispatches_merged_total
             ),
             "fused_merged_rows_total": self.fused_merged_rows_total,
+            **{f"first_sample_{k}_total": v
+               for k, v in self.first_sample_totals.items()},
             "decode_stall_ms_total": round(self.decode_stall_ms_total, 3),
         })
         return out
@@ -3455,10 +3519,17 @@ class ContinuousBatcher:
             with self.obs.loop_span("prep.snapshots", rid=pf.req.rid):
                 pf_ssm = self._pf_snapshots(pf)
         all_greedy = bool(np.all(self.temp_arr[self.active] == 0.0))
+        first_sample = None
         if pf is not None:
             # The prefilling request samples inside the program, so the
             # greedy specialization must account for its policy too.
             all_greedy = all_greedy and pf.req.temperature <= 0.0
+            # What the program's admission sample will cost, from the two
+            # inputs it branches on (``_admission_sample``).
+            first_sample = (
+                "skipped" if pf.off + pf.chunk < pf.suffix_len
+                else "greedy" if pf.req.temperature <= 0.0 else "drawn"
+            )
         # Compile attribution (obs.py): named BEFORE the dispatch so a
         # jit-cache miss books onto the right program.
         prog = "_paged_decode_chunk" if pf is None else "_fused_chunk"
@@ -3495,6 +3566,7 @@ class ContinuousBatcher:
                 )
         if pf is not None:
             self.prefill_chunks_total += 1
+            self.first_sample_totals[first_sample] += 1
             self.fused_dispatches_queued_total += queued > 0
             if merged_rows is not None:
                 self.fused_dispatches_merged_total += 1
@@ -3565,6 +3637,7 @@ class ContinuousBatcher:
             blocked=self._blocked if queued else None,
             ssm=None if pf_ssm is None else pf_ssm[1],
             merged_rows=merged_rows,
+            first_sample=first_sample,
         )
         if pf_done_rid is not None:
             # The prefill's last chunk linked into the prefilling span

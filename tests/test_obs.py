@@ -608,6 +608,21 @@ def test_span_lifecycle_and_dispatch_links():
     assert obs.timeline_json("7")["request_id"] == "ext-abc"
 
 
+def test_a_fused_record_says_what_its_admission_sample_cost():
+    """``first_sample`` rides a record as it was handed in (``skipped``,
+    ``greedy`` or ``drawn``: the program's two branch inputs, known to the
+    host before the submit) and only the records that were handed one."""
+    obs = Observability(clock=FakeClock())
+    for cost in ("skipped", "greedy", "drawn"):
+        obs.record_dispatch(kind="fused", k=2, prefill_tokens=16,
+                            first_sample=cost)
+    obs.record_dispatch(kind="decode", k=4)
+    assert [d.get("first_sample") for d in obs.dispatches] == [
+        "skipped", "greedy", "drawn", None]
+    assert "first_sample" not in obs.dispatches[-1]
+    assert obs.dispatches_json()["dispatches"][1]["first_sample"] == "greedy"
+
+
 def test_bind_before_spans_and_unknown_rid_is_noop():
     obs = Observability(clock=FakeClock())
     obs.bind(99, "never-queued")  # unknown rid: no crash, no timeline

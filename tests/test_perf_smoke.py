@@ -286,6 +286,10 @@ def test_fused_admission_host_sync_discipline(model):
     assert [r["uploads"] for r in recs if r["kind"] == "insert"] == [8]
     fused = [r for r in recs if r["kind"] == "fused"]
     assert [r["uploads"] for r in fused] == [1, 0, 0, 0]
+    # ... and what each dispatch's admission sample cost rides the record
+    # as host bookkeeping: nothing was fetched or uploaded to know it.
+    assert [r["first_sample"] for r in fused] == [
+        "skipped", "skipped", "skipped", "greedy"]
     assert {r["uploads"] for r in recs if r["kind"] == "decode"} == {0}
     assert cb.obs.host_uploads_total == 9 == cb.obs.metrics()[
         "host_uploads_total"]
@@ -468,6 +472,16 @@ def test_fused_metrics_surface(model):
     assert stats["fused_admissions_total"] == 1
     assert stats["prefill_chunks_total"] >= 2
     assert stats["prefill_tokens_inflight"] == 0  # drained
+    # What the admission sample cost, a fused dispatch: the three totals
+    # add up to the dispatches, and one of them completed the prompt.
+    firsts = {
+        k: stats[f"first_sample_{k}_total"]
+        for k in ("skipped", "greedy", "drawn")
+    }
+    assert sum(firsts.values()) == stats["prefill_chunks_total"]
+    assert firsts["greedy"] == 1 and firsts["drawn"] == 0
+    for k in firsts:
+        assert metric_meta(f"first_sample_{k}_total")[0] == "counter"
 
 
 # ---------------------------------------------------------------------------

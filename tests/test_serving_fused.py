@@ -716,14 +716,11 @@ def test_fused_chunk_traces_no_scatter_on_a_pool_plane(model):
     )
     plane_shapes = {pool.k.shape, pool.pos.shape}
 
-    def scatters(jaxpr):
-        for eqn in jaxpr.eqns:
-            if eqn.primitive.name.startswith("scatter"):
-                yield eqn.invars[0].aval.shape
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from scatters(sub)
-
-    found = [s for s in scatters(traced.jaxpr.jaxpr) if s in plane_shapes]
+    found = [
+        eqn.invars[0].aval.shape
+        for name, _, eqn in _eqns(traced.jaxpr.jaxpr)
+        if name.startswith("scatter") and eqn.invars[0].aval.shape in plane_shapes
+    ]
     assert not found, found
 
 
@@ -784,3 +781,241 @@ def test_served_fused_admission_is_engine_generate_and_counts_its_writes(
             jax.random.PRNGKey(0), config=config,
             gen_config=GenerationConfig(max_new_tokens=4, temperature=0.0))
         assert toks[rid] == [int(t) for t in np.asarray(alone)[0, len(prompt):]]
+
+
+# ---------------------------------------------------------------------------
+# The admission's first-token sample (``_admission_sample``): the head and the
+# draw only in the dispatch that consumes them, an argmax for a greedy request
+# ---------------------------------------------------------------------------
+
+def _eqns(jaxpr, inside=()):
+    """(primitive name, the control-flow primitives around it, eqn) of
+    every equation of ``jaxpr``, nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, inside, eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, inside + (eqn.primitive.name,))
+
+
+def _trace_fused(params, config, mixed, all_greedy):
+    """``_fused_chunk``'s jaxpr at this file's geometry: two rows, a
+    32-token chunk, K = 2; the mixed pass needs the paged kernel."""
+    rows, mb = W_ROWS, W_MB
+    pool = jax.eval_shape(lambda: serving.init_pool(config, rows * mb, W_BLK))
+    assert serving._mixed_pass(config, False, None, mixed, 2) == mixed
+    return serving._fused_chunk.trace(
+        params, pool,
+        *fused_chunk_operand_shapes(jax.ShapeDtypeStruct, rows, mb, W_CHUNK),
+        config=config, n_iter=2, pf_chunk=W_CHUNK, all_greedy=all_greedy,
+        allow_kernel=mixed,
+    ).jaxpr.jaxpr
+
+
+@pytest.mark.parametrize("mixed", [False, True], ids=["two-pass", "mixed"])
+def test_a_greedy_fused_chunk_holds_no_sort_and_heads_the_prompt_under_cond(
+    model, mixed,
+):
+    """Every row greedy: no ``sort`` anywhere in the program, in either
+    form.  The two-pass form's one-row head product — the only [1, 1, V]
+    product there is — sits inside the ``cond`` on "the prompt completes
+    in this dispatch"; the mixed form's head product is shared with the
+    decode rows ([1, 1 + B, V]) and stays outside."""
+    params, config = model
+    found = list(_eqns(_trace_fused(params, config, mixed, True)))
+    assert not [n for n, _, _ in found if n == "sort"]
+    V = config.vocab_size
+    heads = {
+        (eqn.outvars[0].aval.shape, "cond" in inside)
+        for name, inside, eqn in found
+        if name == "dot_general" and eqn.outvars[0].aval.shape[-1] == V
+    }
+    if mixed:
+        # the shared product, and the scan's one other iteration
+        assert heads == {((1, 1 + W_ROWS, V), False), ((W_ROWS, 1, V), False)}
+    else:
+        assert heads == {((1, 1, V), True), ((W_ROWS, 1, V), False)}
+
+
+def test_a_sampling_fused_chunk_sorts_for_the_admission_under_two_conds(model):
+    """Beside sampling rows (``all_greedy=False``) the admission's warp —
+    the only sorts over ONE row — lies under the ``cond`` on completion AND
+    the one on the row's own temperature, whose other branch has none."""
+    params, config = model
+    found = list(_eqns(_trace_fused(params, config, False, False)))
+    one_row = [
+        inside for name, inside, eqn in found
+        if name == "sort" and eqn.outvars[0].aval.shape[0] == 1
+    ]
+    assert len(one_row) == 2 and all(
+        inside.count("cond") == 2 for inside in one_row)
+    by_value = [
+        eqn for name, inside, eqn in found
+        if name == "cond" and inside.count("cond") == 1
+    ]
+    assert len(by_value) == 1
+    sorts = [
+        sum(n == "sort" for n, _, _ in _eqns(branch.jaxpr))
+        for branch in by_value[0].params["branches"]
+    ]
+    assert sorted(sorts) == [0, 2]
+
+
+_WALK_POLICIES = {
+    # (the decoding holder's policy, the admitted request's)
+    "greedy": ({}, {}),
+    "sampled": ({}, dict(temperature=0.8, top_p=0.9, top_k=40, seed=12)),
+    "greedy-beside-sampling": (dict(temperature=0.8, seed=7), {}),
+}
+
+
+def _walk(params, config, budget, policy, logprobs, on_step=None):
+    """A holder decodes, then a 36-token prompt is admitted beside it
+    (three 16-token chunks through the fused lane): (token streams,
+    logprob streams, batcher).  ``on_step(cb, seen)`` runs once at the
+    start (``seen`` None) and after every step, handed what it returned
+    the time before."""
+    cb = ContinuousBatcher(
+        params, config, n_slots=2, max_len=64, decode_chunk=4,
+        block_size=BLOCK, prefill_budget=budget, logprobs=logprobs,
+    )
+    toks, lps = {}, {}
+
+    seen = on_step and on_step(cb, None)
+
+    def pump(n=None):
+        nonlocal seen
+        for i in range(200):
+            if (n is not None and i >= n) or (n is None and not cb.pending()):
+                return
+            for ev in cb.step():
+                toks.setdefault(ev[0], []).append(ev[1])
+                if logprobs:
+                    lps.setdefault(ev[0], []).append(ev[3])
+            seen = on_step and on_step(cb, seen)
+        raise AssertionError("did not finish")
+
+    holder_policy, policy = _WALK_POLICIES[policy]
+    r0 = cb.submit([5, 17, 99, 3], max_new_tokens=24, **holder_policy)
+    pump(2)
+    prompt = np.random.RandomState(3).randint(1, 128, size=36).tolist()
+    r1 = cb.submit(prompt, max_new_tokens=6, **policy)
+    pump()
+    return [toks[r0], toks[r1]], [lps.get(r0), lps.get(r1)], cb
+
+
+@pytest.mark.parametrize("logprobs", [False, True], ids=["tokens", "logprobs"])
+@pytest.mark.parametrize("policy", list(_WALK_POLICIES))
+def test_a_three_chunk_walk_samples_once_and_serves_the_classic_stream(
+    model, policy, logprobs,
+):
+    """A dispatch that does not complete the prompt leaves the prefilling
+    row's ``tau``, ``active`` and (every row greedy: nothing splits)
+    ``keys`` as it found them; the one that does samples once.  The streams — a greedy request, a sampled one, a
+    greedy one admitted beside a sampling row (the value branch) — are the
+    classic admit-then-decode path's token for token (logprobs to float32
+    noise), and the records say what each dispatch's sample cost."""
+    params, config = model
+    want_t, want_l, cb0 = _walk(params, config, 0, policy, logprobs)
+    assert cb0.fused_admissions_total == 0
+    non_final = []
+
+    def row_state(cb, before):
+        state = [np.asarray(a) for a in (cb.tau, cb.keys, cb.d_active)]
+        pf = cb._pf
+        if before is not None and pf is not None and pf.off:
+            # a fused dispatch ran and its prompt is not complete
+            assert cb.obs.dispatches[-1]["kind"] == "fused"
+            for name, was, now in zip(("tau", "keys", "active"), before, state):
+                # beside a sampling row the scan splits every row's key once
+                # an iteration, masked rows' too; the fold overwrites it
+                if name != "keys" or policy == "greedy":
+                    assert np.array_equal(was[pf.slot], now[pf.slot]), name
+            assert not state[2][pf.slot]
+            non_final.append(pf.slot)
+        return state
+
+    got_t, got_l, cb = _walk(params, config, BLOCK, policy, logprobs, row_state)
+    assert len(non_final) == 2
+    assert got_t == want_t
+    if logprobs:
+        for a, b in zip(got_l, want_l):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    last = "drawn" if policy == "sampled" else "greedy"
+    fused = [d for d in cb.obs.dispatches if d["kind"] == "fused"]
+    assert [d["first_sample"] for d in fused] == ["skipped", "skipped", last]
+    assert not any(
+        "first_sample" in d for d in cb.obs.dispatches if d["kind"] != "fused")
+    stats = cb.stats()
+    assert stats["first_sample_skipped_total"] == 2
+    assert stats[f"first_sample_{last}_total"] == 1
+    assert sum(
+        stats[f"first_sample_{k}_total"] for k in serving._FIRST_SAMPLE
+    ) == stats["prefill_chunks_total"] == 3
+
+
+def test_a_greedy_admission_beside_a_sampling_row_emits_the_argmax(model):
+    """One dispatch, handed to the program: row 0's prompt completes, row
+    1 decodes at temperature 0.8, so the program is the sampling variant
+    (``all_greedy=False``) and row 0's temperature — zero, a value —
+    chooses the argmax: its first token is the all-greedy variant's, and a
+    key that would draw another token changes nothing."""
+    params, config = model
+    args, kwargs, _ = _write_case(params, config, "tail-past-reservation")
+    want = np.asarray(_BLOCK_FORM(*args, **kwargs)[0][0])
+    assert want[0, 0] >= 0                      # row 0 folded in and emitted
+    args = list(args)
+    args[12] = jnp.asarray([0.0, 0.8], jnp.float32)
+    kwargs = dict(kwargs, all_greedy=False)
+    firsts = set()
+    for key in (0, 1, 2):
+        vec = np.asarray(args[15]).copy()
+        vec[3:5] = np.asarray(jax.random.PRNGKey(key), np.uint32).view(np.int32)
+        args[15] = jnp.asarray(vec)
+        firsts.add(int(np.asarray(_BLOCK_FORM(*args, **kwargs)[0][0])[0, 0]))
+    assert firsts == {int(want[0, 0])}
+    # ... where a sampling row 0 does draw by its key
+    args[12] = jnp.asarray([1.5, 0.8], jnp.float32)
+    drawn = set()
+    for key in range(6):
+        vec = np.asarray(args[15]).copy()
+        vec[3:5] = np.asarray(jax.random.PRNGKey(key), np.uint32).view(np.int32)
+        args[15] = jnp.asarray(vec)
+        drawn.add(int(np.asarray(_BLOCK_FORM(*args, **kwargs)[0][0])[0, 0]))
+    assert len(drawn) > 1
+
+
+def test_nan_logits_on_the_final_chunk_fail_only_that_request(model):
+    """A prompt whose last chunk holds a token with a NaN embedding: the
+    dispatch that completes it folds the -1 sentinel into ``tau`` (the
+    guard lives inside the ``cond`` with the sample), the host fails that
+    request alone, and the holder beside it serves what it serves alone."""
+    params, config = model
+    holder = [5, 17, 99, 3]
+
+    def serve(params, prompt=None):
+        cb = ContinuousBatcher(
+            params, config, n_slots=2, max_len=64, decode_chunk=4,
+            block_size=BLOCK, prefill_budget=BLOCK,
+        )
+        r0 = cb.submit(holder, max_new_tokens=24)
+        cb.step()
+        cb.step()
+        r1 = None if prompt is None else cb.submit(prompt, max_new_tokens=6)
+        out = cb.run_to_completion()
+        return out, cb, r0, r1
+
+    alone, _, r0, _ = serve(params)
+    poison = next(
+        t for t in range(1, 128) if t not in holder and t not in alone[r0])
+    bad = dict(params, embed=dict(params["embed"]))
+    bad["embed"]["embedding"] = (
+        params["embed"]["embedding"].at[poison].set(jnp.nan))
+    prompt = [
+        t for t in np.random.RandomState(3).randint(1, 128, size=60).tolist()
+        if t != poison][:35] + [poison]
+    out, cb, r0, r1 = serve(bad, prompt)
+    failed = cb.pop_failed()
+    assert [rid for rid, _ in failed] == [r1] and "non-finite" in failed[0][1]
+    assert r1 not in out and out[r0] == alone[r0]
+    fused = [d for d in cb.obs.dispatches if d["kind"] == "fused"]
+    assert [d["first_sample"] for d in fused] == ["skipped", "skipped", "greedy"]
