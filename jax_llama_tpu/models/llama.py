@@ -1808,7 +1808,19 @@ def mixed_forward(
     Returns (post-final-norm hidden states [1, C + B, D] — the chunk's
     rows, then the riders' —, the updated ``cache``, the updated ``pool``).
     For a float pool and no sharded mesh; the head is the caller's.
+
+    The block with a mixer beside attention in every layer has its own
+    (``falcon_h1.mixed_forward``: the mixer's recurrence splits as
+    attention does); the blocks with neither keep two passes
+    (``serving._mixed_pass``).
     """
+    if config.parallel_mixer:
+        from . import falcon_h1
+
+        return falcon_h1.mixed_forward(
+            params, tokens, positions, config, cache, attn_mask,
+            rider_tokens, rider_positions, pool,
+        )
     if cache.quantized or cache.per_row_index or pool.quantized:
         raise NotImplementedError(
             "mixed_forward: a float cache with a scalar index and a float "

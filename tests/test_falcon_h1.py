@@ -550,6 +550,31 @@ def test_scopes_are_in_the_lowered_programs(tiny):
     assert named(text, "attn.full") and named(text, "head")
 
 
+def test_the_mixed_pass_keeps_the_scan_and_the_step_under_their_scopes(tiny):
+    """`mixed_forward` (a prompt chunk and one token a decode row in one pass
+    over the weights): the chunk's scan under `ssm.mix/ssm.scan` and the
+    riders' step under `ssm.mix/ssm.step`, as the benchmark's readers find
+    them, and one attention and one FFN scope for both halves."""
+    from jax_llama_tpu.models import llama
+
+    _, cfg, params = tiny
+    i32 = jnp.int32
+    pool = serving.init_pool(cfg, 8, BLK, n_slots=2)
+    table, fill = jnp.arange(8, dtype=i32).reshape(2, 4), jnp.zeros((2,), i32)
+    view = serving._gather_cache(
+        pool, table[:1], jnp.asarray([4]), fill[:1],
+        state=(pool.conv[:, :1], pool.ssm[:, :1]))
+    view = dataclasses.replace(view, index=jnp.asarray(0, i32))
+    toks, pos = _tokens(1, 32)
+    text = jax.jit(lambda: llama.mixed_forward(
+        params, toks, pos, cfg, view, pos >= 0, jnp.zeros((2,), i32),
+        jnp.asarray([-1, 3], i32), serving._pool_as_cache(pool, table, fill))[0]
+    ).lower().as_text(debug_info=True)
+    named = lambda scope: f'"{scope}/' in text or f"/{scope}/" in text  # noqa: E731
+    for scope in ("ssm.mix/ssm.scan", "ssm.mix/ssm.step", "attn.full", "dense.ffn"):
+        assert named(scope), scope
+
+
 def test_every_parameter_has_a_partition_rule(tiny):
     _, cfg, params = tiny
     from jax.sharding import PartitionSpec

@@ -1,6 +1,8 @@
 """The mixed pass: a fused dispatch's first decode iteration rides its
 prompt chunk's pass over the weights (``serving._fused_chunk`` →
-``models.llama.mixed_forward``; dense block, paged kernel, K >= 2).
+``models.llama.mixed_forward``; paged kernel, K >= 2; the dense block, and
+the block with a mixer beside attention in every layer through
+``models.falcon_h1.mixed_forward``).
 
 Three levels, each against the two-pass form it replaces: the model's
 (``mixed_forward`` against a chunk ``forward`` and a paged decode
@@ -30,12 +32,22 @@ CFG = dict(
 BLK, MB, ROWS, CHUNK = 16, 4, 4, 32
 NB = ROWS * MB
 TOL = dict(rtol=2e-5, atol=2e-6)
+SNAPS = 4
+_STATE_PLANES = serving._STATE + tuple("snap_" + n for n in serving._STATE)
 
 
 @pytest.fixture(scope="module")
 def model():
     config = get_config("tiny", **CFG)
     return init_params(jax.random.PRNGKey(0), config), config
+
+
+@pytest.fixture(scope="module")
+def mixer_model():
+    """The block with a mixer beside attention in every layer, at
+    tests/test_falcon_h1.py's tiny widths."""
+    config = _tiny_block("parallel-mixer")
+    return init_params(jax.random.PRNGKey(1), config), config
 
 
 # ---------------------------------------------------------------------------
@@ -48,9 +60,15 @@ def _case(params, config, last, sampled=False, seed=0):
     every slot holds a seeded pattern.  Row 0 prefills a 64-token prompt's
     first chunk, or (``last``) the second and last of a 40-token one; row 1
     decodes at position 20, row 2 at 9 with ONE token of budget left (it
-    finishes at emit 1 and rides the pass masked), row 3 holds nothing."""
+    finishes at emit 1 and rides the pass masked), row 3 holds nothing.
+    With recurrent state layers every slot and each of ``SNAPS`` snapshots
+    holds a pattern too, and the call's ``pf_snap`` follows the operands:
+    the walk's first chunk starts from snapshot 2, and either chunk's end
+    state is kept as snapshot 1."""
     rng = np.random.RandomState(seed)
-    empty = serving.init_pool(config, NB, BLK)
+    stateful = config.recurrent_state
+    empty = (serving.init_pool(config, NB, BLK, n_slots=ROWS, n_snapshots=SNAPS)
+             if stateful else serving.init_pool(config, NB, BLK))
     pattern = lambda a: jnp.asarray(rng.uniform(-1.0, 1.0, a.shape), a.dtype)
     off, plen = (32, 40) if last else (0, 64)
     pos = np.full((NB, BLK), -1, np.int32)
@@ -58,7 +76,8 @@ def _case(params, config, last, sampled=False, seed=0):
     pos[4:6].reshape(-1)[:20] = np.arange(20)        # row 1's context
     pos[8].reshape(-1)[:9] = np.arange(9)            # row 2's
     pool = dataclasses.replace(
-        empty, pos=jnp.asarray(pos), **serving._map_planes(pattern, empty))
+        empty, pos=jnp.asarray(pos), **serving._map_planes(pattern, empty),
+        **{n: pattern(getattr(empty, n)) for n in _STATE_PLANES if stateful})
     table = np.arange(NB, dtype=np.int32).reshape(ROWS, MB)
     i32, f32 = jnp.int32, jnp.float32
     toks = rng.randint(1, config.vocab_size, size=64).astype(np.int32)
@@ -79,7 +98,7 @@ def _case(params, config, last, sampled=False, seed=0):
         jnp.asarray(temp, f32), jnp.ones((ROWS,), f32),
         jnp.full((ROWS,), config.vocab_size, i32),
         jnp.asarray(vec),
-    )
+    ) + ((jnp.asarray([2, 1], i32),) if stateful else ())
     kwargs = dict(
         config=config, n_iter=2, pf_chunk=CHUNK, all_greedy=not sampled,
         allow_kernel=True, with_logprobs=True,
@@ -179,26 +198,91 @@ def test_the_last_chunk_folds_its_row_in_one_column_later(model, sampled):
         np.asarray(got["pool"].pos)[:3], np.asarray(want["pool"].pos)[:3])
 
 
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("last", [False, True], ids=["mid-prompt", "last-chunk"])
+def test_a_mixer_beside_attention_leaves_what_its_two_passes_leave(
+    mixer_model, last, sampled,
+):
+    """The block with a mixer beside attention in every layer, mid-prompt
+    and on the chunk that completes the prompt: what the two tests above
+    hold the dense block to, and the recurrent state.  Every decoding
+    slot's state is the two-pass form's and the idle slot's is untouched,
+    bit for bit; the chunk's end state — from snapshot 2 on the walk's
+    first chunk, from the row's slot on a later one — stands in snapshot 1
+    and (mid-prompt, where the row does not decode yet) in the row's slot;
+    no other snapshot moves."""
+    params, config = mixer_model
+    args, kwargs = _case(params, config, last, sampled)
+    got = dict(zip(_OUT, _MIXED(*args, **kwargs)))
+    want = dict(zip(_OUT, _TWO_PASS(*args, **kwargs)))
+    assert _TWO_PASS_TRACED and _TWO_PASS_TRACED[-1] == 2
+    pad = serving._CHUNK_PAD
+    gt, wt = np.asarray(got["packed"][0]), np.asarray(want["packed"][0])
+    glp, wlp = (np.asarray(p["packed"][1]).view(np.float32) for p in (got, want))
+    assert np.array_equal(gt[1:], wt[1:])
+    assert (gt[1:] != pad).tolist() == [[True, True], [True, False], [False, False]]
+    np.testing.assert_allclose(glp[1:][gt[1:] != pad], wlp[1:][gt[1:] != pad], **TOL)
+    if last:  # the folded row: its first token one column later
+        assert gt[0, 0] == pad and gt[0, 1] == wt[0, 0] >= 0
+        np.testing.assert_allclose(glp[0, 1], wlp[0, 0], **TOL)
+        assert int(got["tau"][0]) == wt[0, 1]
+    else:
+        assert np.array_equal(gt[0], wt[0]) and (gt[0] == pad).all()
+    rows = slice(1, None) if last else slice(None)
+    for name in ("tau", "fill", "pos", "active", "remaining", "keys"):
+        assert np.array_equal(
+            np.asarray(got[name])[rows], np.asarray(want[name])[rows]), name
+    was = args[1]
+    blocks = slice(0, 3) if last else slice(None)  # the prompt's, or all
+    for name in ("k", "v"):
+        g, w = (np.asarray(getattr(p["pool"], name))[:, :, blocks] for p in (got, want))
+        np.testing.assert_allclose(g, w, **TOL, err_msg=name)
+    assert np.array_equal(
+        np.asarray(got["pool"].pos)[blocks], np.asarray(want["pool"].pos)[blocks])
+    for name in ("conv", "ssm"):
+        g, w, o = (np.asarray(getattr(p, name)) for p in (got["pool"], want["pool"], was))
+        np.testing.assert_allclose(g[:, rows], w[:, rows], **TOL, err_msg=name)
+        assert np.array_equal(g[:, 3], o[:, 3]), name       # the idle slot
+        assert not np.array_equal(g[:, 1], o[:, 1]), name   # a decoding one
+        gs, ws, os_ = (np.asarray(getattr(p, "snap_" + name))
+                       for p in (got["pool"], want["pool"], was))
+        np.testing.assert_allclose(gs, ws, **TOL, err_msg="snap_" + name)
+        assert np.array_equal(gs[:, [0, 2, 3]], os_[:, [0, 2, 3]]), name
+        assert not np.array_equal(gs[:, 1], os_[:, 1]), name
+        if not last:
+            assert np.array_equal(g[:, 0], gs[:, 1]), name  # the chunk's end state
+    assert np.array_equal(
+        np.asarray(got["pool"].stats), np.asarray(want["pool"].stats))
+
+
+@pytest.mark.parametrize("block", ["dense", "parallel-mixer"])
 @pytest.mark.parametrize(
     "impl,scan", [("xla", True), ("auto", True), ("xla", False)],
     ids=["chunk-xla", "chunk-flash", "unrolled"],
 )
 def test_mixed_forward_is_the_chunk_forward_and_the_paged_decode_forward(
-    model, impl, scan,
+    request, block, impl, scan,
 ):
     """The model's half alone: hidden states of the chunk's rows, logits of
     the riding rows (a masked row's are nobody's), the row's view and the
     pool planes against ``forward`` over the view and ``forward`` over the
-    paged cache."""
-    params, config = model
+    paged cache.  The dense block on a prompt's first chunk; the block with
+    a mixer on a later one (8 live tokens of 32 behind 32 in the cache),
+    with the view's and the slots' recurrent state — a masked rider's, the
+    prefilling row's own slot among them, bit for bit."""
+    stateful = block == "parallel-mixer"
+    params, config = request.getfixturevalue("mixer_model" if stateful else "model")
     config = config.replace(attn_impl=impl, scan_layers=scan)
-    args, _ = _case(params, config, False)
+    args, _ = _case(params, config, stateful)
+    off, plen = (32, 40) if stateful else (0, 64)
     pool, table, fill = args[1], args[2], args[4]
     tau, active = args[5], args[8]
-    view = serving._gather_cache(pool, table[:1], jnp.asarray([MB]), fill[:1])
-    view = dataclasses.replace(view, index=jnp.asarray(0, jnp.int32))
-    toks_c = args[15][None, serving._PF_HEADER:][:, :CHUNK]
-    positions, real = window_positions(0, 0, CHUNK, 64)
+    view = serving._gather_cache(
+        pool, table[:1], jnp.asarray([MB]), fill[:1],
+        state=(pool.conv[:, :1], pool.ssm[:, :1]) if stateful else None)
+    view = dataclasses.replace(view, index=jnp.asarray(off, jnp.int32))
+    toks_c = args[15][None, serving._PF_HEADER:][:, off:off + CHUNK]
+    positions, real = window_positions(0, off, CHUNK, plen)
     rider_pos = jnp.where(active, args[7], -1)
     pcache = serving._pool_as_cache(pool, table, fill)
 
@@ -206,29 +290,45 @@ def test_mixed_forward_is_the_chunk_forward_and_the_paged_decode_forward(
         lambda: llama.mixed_forward(
             params, toks_c, positions, config, view, real, tau, rider_pos,
             pcache))()
-    _, want_view, aux = llama.forward(
-        params, toks_c, positions, config, cache=view, attn_mask=real,
-        compute_logits=False, output_last_hidden=True)
-    want_logits, want_pool = llama.forward(
-        params, tau[:, None], rider_pos[:, None], config, cache=pcache,
-        attn_mask=active[:, None])
+    (_, want_view, aux), (want_logits, want_pool) = jax.jit(lambda: (
+        llama.forward(
+            params, toks_c, positions, config, cache=view, attn_mask=real,
+            compute_logits=False, output_last_hidden=True),
+        llama.forward(
+            params, tau[:, None], rider_pos[:, None], config, cache=pcache,
+            attn_mask=active[:, None])))()
     assert hidden.shape == (1, CHUNK + ROWS, config.dim)
+    live = np.asarray(real[0])
+    assert live.sum() == min(CHUNK, plen - off)
     np.testing.assert_allclose(
-        np.asarray(hidden[:, :CHUNK]), np.asarray(aux.last_hidden_state), **TOL)
+        np.asarray(hidden[0, :CHUNK])[live],
+        np.asarray(aux.last_hidden_state[0])[live], **TOL)
     got_logits = llama.lm_head_logits(
         params, hidden[:, CHUNK:], config, normed=True)[0]
     riding = np.asarray(active)
     np.testing.assert_allclose(
         np.asarray(got_logits)[riding], np.asarray(want_logits)[riding, 0],
         **TOL)
-    for name in ("k", "v", "pos"):
+    for name in ("k", "v", "pos") + (("conv", "ssm") if stateful else ()):
         np.testing.assert_allclose(
             np.asarray(getattr(got_view, name)),
             np.asarray(getattr(want_view, name)), **TOL, err_msg=name)
         np.testing.assert_allclose(
             np.asarray(getattr(got_pool, name)),
             np.asarray(getattr(want_pool, name)), **TOL, err_msg=name)
-    assert int(got_view.index) == CHUNK == int(want_view.index)
+    assert int(got_view.index) == off + CHUNK == int(want_view.index)
+    if stateful:
+        for name in ("conv", "ssm"):
+            got, was = (np.asarray(getattr(p, name)) for p in (got_pool, pool))
+            assert np.array_equal(got[:, ~riding], was[:, ~riding]), name
+            assert not np.array_equal(got[:, 1], was[:, 1]), name
+            assert not np.array_equal(
+                np.asarray(getattr(got_view, name)), was[:, :1]), name
+        # the riders' kernel steps, counted once: the pool's count is the
+        # paged forward's
+        assert np.array_equal(
+            np.asarray(got_pool.stats), np.asarray(want_pool.stats))
+        assert int(got_pool.stats[-1]) > 0
 
 
 @pytest.mark.parametrize("rank", [5, 4, 2], ids=["payload", "scale", "pos"])
@@ -405,6 +505,70 @@ def test_cancel_and_rebuild_mid_prefill_behind_mixed_dispatches(model):
     assert cb2.run_to_completion()[r] == want[rb]
 
 
+def test_a_mixer_beside_attention_is_served_as_classic_admission_serves_it(
+    mixer_model,
+):
+    """The block with a mixer through ``ContinuousBatcher``, chunks of 32
+    over blocks of 16 beside a decoding holder: a 105-token request rides
+    the lane in four mixed dispatches (snapshots at 32, 64, 96), a re-ask
+    restores the snapshot at 96, and a third prompt is cancelled mid-prefill
+    behind a mixed dispatch.  Every stream is classic admit-then-decode's (``prefill_budget=0``: whole
+    prompts through ``_paged_insert``, no hit)."""
+    params, config = mixer_model
+    rng = np.random.RandomState(4)
+    draw = lambda n: [int(t) for t in rng.randint(0, config.vocab_size, n)]  # noqa: E731
+    doc = draw(100)
+    holder, cancelled = draw(6), draw(70)
+    asks = [doc + draw(n) for n in (5, 9)]
+
+    def batcher(budget):
+        # the geometry of the last test of this file: one set of programs
+        return ContinuousBatcher(
+            params, config, n_slots=3, max_len=128, block_size=BLK,
+            decode_chunk=4, prefill_budget=budget)
+
+    alone = batcher(0)
+    rids = [alone.submit(holder, max_new_tokens=40)] + [
+        alone.submit(p, max_new_tokens=6) for p in asks]
+    done = alone.run_to_completion()
+    want = [done[r] for r in rids]
+    assert alone.fused_admissions_total == 0
+
+    cb = batcher(32)
+    out = {}
+
+    def steps(n=None):
+        for i in range(400):
+            if (n is not None and i >= n) or (n is None and not cb.pending()):
+                return
+            for rid, tok, *_ in cb.step():
+                out.setdefault(rid, []).append(tok)
+        raise AssertionError("did not finish")
+
+    h = cb.submit(holder, max_new_tokens=40)
+    steps(2)
+    a = cb.submit(asks[0], max_new_tokens=6)
+    steps(5)
+    assert cb.stats()["ssm_snapshots_taken_total"] == 3
+    b = cb.submit(asks[1], max_new_tokens=6)        # restores the one at 96
+    steps(3)
+    assert cb.stats()["ssm_snapshots_restored_total"] == 1
+    assert cb.prefix_hit_tokens_total == 96
+    c = cb.submit(cancelled, max_new_tokens=4)
+    steps(1)
+    assert cb._pf is not None and cb._pf.req.rid == c
+    assert cb.obs.dispatches[-1]["merged_rows"] >= 1
+    assert cb.cancel(c) and cb._pf is None
+    steps()
+    assert [out[r] for r in (h, a, b)] == want and c not in out
+    stats = cb.stats()
+    fused = [r for r in cb.obs.dispatches if r["kind"] == "fused"]
+    assert len(fused) == stats["fused_dispatches_total"] == 6
+    assert all(("merged_rows" in r) == (r["k"] >= 2) for r in fused)
+    assert stats["fused_dispatches_merged_total"] >= 5
+    assert stats["fused_merged_rows_total"] >= 5
+
+
 # ---------------------------------------------------------------------------
 # The counter, and the blocks the pass was not ported to
 # ---------------------------------------------------------------------------
@@ -452,16 +616,17 @@ def test_a_one_device_mesh_takes_the_mixed_pass(model, classic):
 
 
 def _tiny_block(kind):
-    """A tiny configuration of one of the three other blocks, from the
+    """A tiny configuration of one of the four other blocks, from the
     published keys of its benchmark configuration."""
     import test_afmoe
+    import test_falcon_h1
     import test_mla_moe
     import test_sambay
 
     from jax_llama_tpu import config as config_mod
 
     mod = {"latent": test_mla_moe, "windowed": test_afmoe,
-           "recurrent": test_sambay}[kind]
+           "recurrent": test_sambay, "parallel-mixer": test_falcon_h1}[kind]
     raw = dict(json.loads(mod.CONFIG_FILE.read_text()), **mod.TINY)
     return config_mod.from_published(
         {k: v for k, v in raw.items() if k not in mod.BOOKKEEPING},
@@ -487,23 +652,28 @@ def _products(jaxpr, rows, head, times=1):
     return mixed, passes
 
 
-@pytest.mark.parametrize("kind", ["dense", "latent", "windowed", "recurrent"])
-def test_only_the_dense_block_s_fused_chunk_holds_a_mixed_pass(model, kind):
+@pytest.mark.parametrize(
+    "kind", ["dense", "parallel-mixer", "latent", "windowed", "recurrent"])
+def test_only_the_blocks_the_pass_was_ported_to_hold_it_in_their_fused_chunk(
+    model, kind,
+):
     """Traced at K = 4 over 4 rows and a 32-token chunk: the dense
-    program has products over C + B = 36 rows and K passes over the
+    program, and that of the block with a mixer beside attention in every
+    layer, have products over C + B = 36 rows and K passes over the
     weights; the three other blocks' have no such product and K + 1 passes
-    (the chunk's, and the decode scan's K) — and served, their counter
-    reads 0."""
+    (the chunk's, and the decode scan's K).  Served, the mixer block's
+    counters read what its records say, and the three others' read 0."""
     if kind == "dense":
         params, config = model
     else:
-        config = _tiny_block(kind)
+        config = _tiny_block(kind)  # the mixer's: ``mixer_model``'s own
         params = init_params(jax.random.PRNGKey(1), config)
     rows, chunk, n_iter = 4, 32, 4
     mb = config.max_seq_len // BLK
     pool = jax.eval_shape(lambda: serving.init_pool(
         config, rows * mb, BLK, n_slots=rows, n_snapshots=rows))
-    extra = (jax.ShapeDtypeStruct((2,), jnp.int32),) if kind == "recurrent" else ()
+    extra = ((jax.ShapeDtypeStruct((2,), jnp.int32),)
+             if config.recurrent_state else ())
     traced = serving._fused_chunk.trace(
         params, pool,
         *fused_chunk_operand_shapes(jax.ShapeDtypeStruct, rows, mb, chunk),
@@ -512,12 +682,15 @@ def test_only_the_dense_block_s_fused_chunk_holds_a_mixed_pass(model, kind):
     )
     head = {(config.dim, config.vocab_size), (config.vocab_size, config.dim)}
     mixed, passes = _products(traced.jaxpr.jaxpr, chunk + rows, head)
-    if kind == "dense":
+    ported = kind in ("dense", "parallel-mixer")
+    if ported:
         assert mixed > 0 and passes == n_iter
-        return
-    assert mixed == 0 and passes == n_iter + 1
+        if kind == "dense":  # served: the tests above
+            return
+    else:
+        assert mixed == 0 and passes == n_iter + 1
     cb = ContinuousBatcher(
-        params, config, n_slots=2, max_len=128, decode_chunk=4,
+        params, config, n_slots=3, max_len=128, decode_chunk=4,
         block_size=BLK, prefill_budget=2 * BLK)
     rng = np.random.RandomState(4)
     cb.submit([int(t) for t in rng.randint(1, config.vocab_size, 6)],
@@ -529,6 +702,11 @@ def test_only_the_dense_block_s_fused_chunk_holds_a_mixed_pass(model, kind):
     cb.run_to_completion()
     stats = cb.stats()
     assert stats["fused_dispatches_total"] >= 2
+    if ported:
+        rode = [d["merged_rows"] for d in cb.obs.dispatches if "merged_rows" in d]
+        assert len(rode) == stats["fused_dispatches_merged_total"] >= 2
+        assert sum(rode) == stats["fused_merged_rows_total"] > 0
+        return
     assert stats["fused_dispatches_merged_total"] == 0
     assert stats["fused_merged_rows_total"] == 0
     assert not any("merged_rows" in d for d in cb.obs.dispatches)
