@@ -1809,15 +1809,19 @@ def mixed_forward(
     rows, then the riders' —, the updated ``cache``, the updated ``pool``).
     For a float pool and no sharded mesh; the head is the caller's.
 
-    The block with a mixer beside attention in every layer has its own
-    (``falcon_h1.mixed_forward``: the mixer's recurrence splits as
-    attention does); the blocks with neither keep two passes
+    The two blocks with a recurrent state have their own, where the
+    mixers' recurrence splits as attention does: a mixer beside attention
+    in every layer (``falcon_h1.mixed_forward``), and mixer layers between
+    window, full and cross attention layers (``sambay.mixed_forward``).
+    The two blocks with routed experts (latent attention, ``mla_moe``;
+    window attention layers, ``afmoe``) have none and keep two passes
     (``serving._mixed_pass``).
     """
-    if config.parallel_mixer:
-        from . import falcon_h1
+    if config.recurrent_state:
+        from . import falcon_h1, sambay
 
-        return falcon_h1.mixed_forward(
+        block = falcon_h1 if config.parallel_mixer else sambay
+        return block.mixed_forward(
             params, tokens, positions, config, cache, attn_mask,
             rider_tokens, rider_positions, pool,
         )

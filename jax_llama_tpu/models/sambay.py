@@ -75,6 +75,8 @@ from ..ops import moe, ssm
 from ..ops.attention import attention_bias, sdpa, sdpa_cached
 from ..ops.flash_attention import flash_attention
 from .afmoe import ATTN_STATS
+from .falcon_h1 import _conv  # the causal depthwise conv behind a row's held inputs
+from .llama import _swiglu, qeinsum  # `llama` reaches this module inside its functions only
 from .mla_moe import INIT_STD
 
 Params = Dict[str, Any]
@@ -192,109 +194,94 @@ def combine_pairs(out, lam, lam0, subln):
     return (d * subln.astype(jnp.float32) * (1.0 - lam0)).astype(out.dtype)
 
 
-def forward(
-    params: Params,
-    tokens: jnp.ndarray,
-    positions: jnp.ndarray,
-    config: LLaMAConfig,
-    cache=None,
-    attn_mask: Optional[jnp.ndarray] = None,
-    compute_logits: bool = True,
-    dropout_rng: Optional[jax.Array] = None,
-    output_hidden_states: bool = False,
-    output_attentions: bool = False,
-    output_last_hidden: bool = False,
-):
-    """`llama.forward`'s contract for the block with recurrent state layers:
-    cache-free (the state starts at zero), over a `KVCache` (scalar or per-row
-    index) or over a `PagedKVCache`, each with its `conv` / `ssm` state."""
-    from .llama import (
-        FLASH_MIN_SEQ, AuxOutput, KVCache, PagedKVCache, _swiglu, init_state,
-        lm_head_logits, paged_pool_write, paged_write_indices, qeinsum,
-    )
+def _normed(x, lp, config):
+    return layer_norm(x, lp["in_norm"], lp["in_norm_bias"], config.layer_norm_eps)
 
-    if dropout_rng is not None:
-        raise NotImplementedError(
-            "the block with recurrent state layers is served, not trained: "
-            "dropout_rng (the training step) is not supported")
-    if output_hidden_states or output_attentions:
-        raise NotImplementedError(
-            "output_hidden_states / output_attentions are not supported by "
-            "the block with recurrent state layers")
-    B, T = tokens.shape
+
+def _ffn(x, lp, config):
+    with jax.named_scope("dense.ffn"):
+        m = layer_norm(x, lp["post_norm"], lp["post_norm_bias"], config.layer_norm_eps)
+        return x + _swiglu(m, lp["gate_up"], lp["down"])
+
+
+def _recur(h, c, dt, Bm, Cm, A, lengths, live, use_kernel: bool):
+    """The recurrence of `c` [R, T, Di] from each row's state `h` [R, N, Di]:
+    (y [R, T, Di] float32, the state after each row's `lengths` live tokens;
+    `live` is `lengths > 0`).  One token a row is a step, more are a scan
+    (the Pallas one with `use_kernel`)."""
+    if c.shape[1] == 1:
+        y, h = ssm.ssm_step(h, c[:, 0], dt[:, 0], Bm[:, 0], Cm[:, 0], A, live)
+        return y[:, None], h
+    return ssm.ssm_scan(h, c, dt, Bm, Cm, A, lengths,
+                        impl="pallas" if use_kernel else "xla")
+
+
+def _mixer(x, lp, config, convolve, recur):
+    """One Mamba layer over `x` [B, T, D] around the two things that are a
+    row's own, which the caller hands in: `convolve(u)` -> (the conv's
+    output [B, T, Di], the new conv state) and `recur(c, dt, Bm, Cm, A)` ->
+    (y [B, T, Di] float32, the new ssm state).  Returns (x, the scan's
+    output before the gate, the new conv state, the new ssm state)."""
     adt = config.activation_dtype
     f32 = jnp.float32
-    Di, N, R = config.mamba_d_inner, config.mamba_d_state, config.dt_rank
-    P1 = config.n_layers // 4
-    half = config.n_layers // 2
-    eps = config.layer_norm_eps
+    N, R = config.mamba_d_state, config.dt_rank
+    with jax.named_scope("ssm.mix"):
+        a = _normed(x, lp, config)
+        uz = qeinsum(a, lp["in_proj"], "btd,cde->btce", adt)
+        u, z = uz[..., 0, :], uz[..., 1, :]
+        c, new_conv = convolve(u)
+        xp = qeinsum(c, lp["x_proj"], "bte,er->btr", adt, preferred_element_type=f32)
+        r, Bm, Cm = xp[..., :R], xp[..., R:R + N], xp[..., R + N:]
+        dt = qeinsum(r.astype(adt), lp["dt_proj"], "btr,re->bte", adt,
+                     preferred_element_type=f32)
+        dt = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))
+        A = -jnp.exp(lp["A_log"].astype(f32)).T                    # [N, Di]
+        with jax.named_scope("ssm.scan"):
+            y, new_ssm = recur(c, dt, Bm, Cm, A)
+        y = y + lp["D"].astype(f32) * c.astype(f32)
+        gated = (y * jax.nn.silu(z.astype(f32))).astype(adt)
+        out = qeinsum(gated, lp["out_proj"], "bte,ed->btd", adt)
+    return _ffn(x + out, lp, config), y.astype(adt), new_conv, new_ssm
+
+
+def _own_keys(x, lp, config):
+    adt = config.activation_dtype
+    f32 = jnp.float32
+    kv = qeinsum(_normed(x, lp, config), lp["kv"], "btd,csdk->btcsk", adt,
+                 preferred_element_type=f32)
+    kv = (kv + lp["kv_bias"].astype(f32)).astype(adt)
+    return kv[..., 0, :], kv[..., 1, :]
+
+
+def _attention(x, lp, li, attend, config):
+    """An attention layer's mixer once its keys' `attend` stands (its FFN
+    follows outside the kind's scope, under its own)."""
+    adt = config.activation_dtype
+    f32 = jnp.float32
+    a = _normed(x, lp, config)
+    lq1, lk1, lq2, lk2 = lp["lambda"].astype(f32)
+    lam0 = 0.8 - 0.6 * jnp.exp(-0.3 * li.astype(f32))
+    lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + lam0
+    q = qeinsum(a, lp["q"], "btd,hdk->bthk", adt, preferred_element_type=f32)
+    q = (q + lp["q_bias"].astype(f32)) * math.sqrt(2.0)
+    o = combine_pairs(attend(pad_query_pairs(q.astype(adt))), lam, lam0, lp["subln"])
+    out = qeinsum(o, lp["o"], "btpk,pkd->btd", adt, preferred_element_type=f32)
+    return x + (out + lp["o_bias"].astype(f32)).astype(adt)
+
+
+def _attend_rows(config, cache, q_positions, new_pos, attn_mask, slot_pos,
+                 use_flash: bool):
+    """`attender(k, v, ck, cv, windowed)` of one call: how its queries
+    attend ONE owner's keys — the step's `k`, `v` [B, T, KVH/2, 2hd] and,
+    with `ck` / `cv` (the owner's slices of the `KVCache` `cache`), what
+    the cache holds — through the flash kernel's one sweep, or the
+    append-free xla form."""
+    adt = config.activation_dtype
     softmax_dtype = jnp.dtype(config.attn_softmax_dtype)
-    paged = isinstance(cache, PagedKVCache)
-    if attn_mask is None:
-        attn_mask = positions >= 0
-    q_positions = jnp.maximum(positions, 0)
-    new_pos = jnp.where(attn_mask, q_positions, -1).astype(jnp.int32)
-    # A row's live tokens are a prefix of T (right padding): what the mixers
-    # advance their state by.
-    lengths = jnp.sum(attn_mask.astype(jnp.int32), axis=1)
-
-    use_flash = (not paged and T > FLASH_MIN_SEQ
-                 and config.attn_impl in ("flash", "auto")
-                 and not (cache is not None and cache.per_row_index))
-    use_scan_kernel = (T > 1 and ssm.kernel_eligible(T, Di)
-                       and not ssm._resolve_interpret())
     window = jnp.int32(config.sliding_window)
-    attn_stats = jnp.zeros((len(ATTN_STATS),), jnp.int32)
-    if paged:
-        from ..ops.paged_attention import (
-            fetch_plan, paged_decode_attention, plan_live_steps,
-        )
 
-        NB, BLK = cache.pos.shape
-        row_active = attn_mask[:, 0]
-        if T > 1:  # the kernel's T > 1 contract (see `llama.paged_forward`)
-            row_active = (
-                row_active & jnp.all(attn_mask == attn_mask[:, :1], axis=1)
-                & jnp.all(positions == positions[:, :1]
-                          + jnp.arange(T, dtype=positions.dtype), axis=1))
-        q_pos_row = jnp.where(row_active, positions[:, 0], -1).astype(jnp.int32)
-        lengths = jnp.where(row_active, T, 0).astype(jnp.int32)
-        plans = {
-            windowed: fetch_plan(cache.k, cache.pos, cache.table, q_pos_row, T,
-                                 window if windowed else None)
-            for windowed in (True, False)
-        }
-        # The cross layers sweep the full layer's plane once each.
-        attn_stats = jnp.stack([
-            P1 * plan_live_steps(plans[True]), P1 * plan_live_steps(plans[False]),
-        ]).astype(jnp.int32)
-    elif cache is not None:
-        slot_pos = (
-            cache.pos.at[
-                jnp.arange(B, dtype=jnp.int32)[:, None],
-                cache.index[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :],
-            ].set(new_pos, mode="drop")
-            if cache.per_row_index
-            else lax.dynamic_update_slice(cache.pos, new_pos, (0, cache.index))
-        )
-    else:
-        slot_pos = new_pos
-    if cache is None:
-        conv0, ssm0 = init_state(config, B)
-    else:
-        conv0, ssm0 = cache.conv, cache.ssm
-    row_live = lengths > 0
-
-    def attender(k, v, ck, cv, plane, windowed: bool):
-        """How queries attend ONE owner's keys: its step's `k`, `v`
-        [B, T, KVH/2, 2hd] and, cached, its planes `ck`, `cv` (or the paged
-        pool's plane index).  Built once an owner: the cross layers share
-        the full layer's."""
+    def attender(k, v, ck, cv, windowed: bool):
         w = window if windowed else None
-        if paged:
-            return lambda q: paged_decode_attention(
-                q, k, v, cache.k, cache.v, cache.pos, cache.table, q_pos_row,
-                layer=plane, window=w, plan=plans[windowed])
         if use_flash:
             if ck is None:
                 return lambda q: flash_attention(q, k, v, q_positions, new_pos, window=w)
@@ -312,73 +299,51 @@ def forward(
             q, ck.astype(adt), cv.astype(adt), k, v, bias, bias_new,
             softmax_dtype=softmax_dtype)
 
-    def normed(x, lp):
-        return layer_norm(x, lp["in_norm"], lp["in_norm_bias"], eps)
+    return attender
 
-    def ffn(x, lp):
-        with jax.named_scope("dense.ffn"):
-            m = layer_norm(x, lp["post_norm"], lp["post_norm_bias"], eps)
-            return x + _swiglu(m, lp["gate_up"], lp["down"])
 
-    def mixer(x, lp, conv_s, ssm_s):
-        """One Mamba layer: (x, the scan's output before the gate, the new
-        conv state, the new ssm state)."""
-        with jax.named_scope("ssm.mix"):
-            a = normed(x, lp)
-            uz = qeinsum(a, lp["in_proj"], "btd,cde->btce", adt)
-            u, z = uz[..., 0, :], uz[..., 1, :]
-            seen = jnp.concatenate([conv_s.reshape(B, 3, Di).astype(adt), u], axis=1)
-            w = lp["conv_w"].astype(f32)
-            c = sum(w[k] * seen[:, k:k + T].astype(f32) for k in range(4))
-            c = jax.nn.silu(c + lp["conv_b"].astype(f32)).astype(adt)
-            # The last 3 inputs a row has seen: columns lengths .. lengths + 2
-            # of [state | chunk]; a row with nothing live keeps its own.
-            at = lengths[:, None] + jnp.arange(3, dtype=jnp.int32)[None, :]
-            new_conv = jnp.take_along_axis(seen, at[:, :, None], axis=1)
-            new_conv = new_conv.reshape(B, 3 * Di).astype(conv_s.dtype)
-            xp = qeinsum(c, lp["x_proj"], "bte,er->btr", adt, preferred_element_type=f32)
-            r, Bm, Cm = xp[..., :R], xp[..., R:R + N], xp[..., R + N:]
-            dt = qeinsum(r.astype(adt), lp["dt_proj"], "btr,re->bte", adt,
-                         preferred_element_type=f32)
-            dt = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))
-            A = -jnp.exp(lp["A_log"].astype(f32)).T                    # [N, Di]
-            with jax.named_scope("ssm.scan"):
-                if T == 1:
-                    y, new_ssm = ssm.ssm_step(
-                        ssm_s, c[:, 0], dt[:, 0], Bm[:, 0], Cm[:, 0], A, row_live)
-                    y = y[:, None]
-                else:
-                    y, new_ssm = ssm.ssm_scan(
-                        ssm_s, c, dt, Bm, Cm, A, lengths,
-                        impl="pallas" if use_scan_kernel else "xla")
-            y = y + lp["D"].astype(f32) * c.astype(f32)
-            gated = (y * jax.nn.silu(z.astype(f32))).astype(adt)
-            out = qeinsum(gated, lp["out_proj"], "bte,ed->btd", adt)
-        return ffn(x + out, lp), y.astype(adt), new_conv, new_ssm
+def _attend_paged(config, cache, q_pos_row, T: int):
+    """(`attender(k, v, plane, windowed)` of one call over the `PagedKVCache`
+    `cache` — the paged kernel over the pool's plane index `plane`, its two
+    fetch plans (window, full) bound here, outside the scans —, the call's
+    `ATTN_STATS`: the cross layers sweep the full layer's plane once each)."""
+    from ..ops.paged_attention import (
+        fetch_plan, paged_decode_attention, plan_live_steps,
+    )
 
-    def queries(a, lp):
-        q = qeinsum(a, lp["q"], "btd,hdk->bthk", adt, preferred_element_type=f32)
-        q = (q + lp["q_bias"].astype(f32)) * math.sqrt(2.0)
-        return pad_query_pairs(q.astype(adt))
+    P1 = config.n_layers // 4
+    window = jnp.int32(config.sliding_window)
+    plans = {
+        windowed: fetch_plan(cache.k, cache.pos, cache.table, q_pos_row, T,
+                             window if windowed else None)
+        for windowed in (True, False)
+    }
+    attn_stats = jnp.stack([
+        P1 * plan_live_steps(plans[True]), P1 * plan_live_steps(plans[False]),
+    ]).astype(jnp.int32)
 
-    def attention(x, lp, li, attend):
-        """An attention layer's mixer once its keys' `attend` stands (its
-        FFN follows outside the kind's scope, under its own)."""
-        a = normed(x, lp)
-        lq1, lk1, lq2, lk2 = lp["lambda"].astype(f32)
-        lam0 = 0.8 - 0.6 * jnp.exp(-0.3 * li.astype(f32))
-        lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + lam0
-        o = combine_pairs(attend(queries(a, lp)), lam, lam0, lp["subln"])
-        out = qeinsum(o, lp["o"], "btpk,pkd->btd", adt, preferred_element_type=f32)
-        return x + (out + lp["o_bias"].astype(f32)).astype(adt)
+    def attender(k, v, plane, windowed: bool):
+        return lambda q: paged_decode_attention(
+            q, k, v, cache.k, cache.v, cache.pos, cache.table, q_pos_row,
+            layer=plane, window=window if windowed else None,
+            plan=plans[windowed])
 
-    def own_keys(x, lp):
-        kv = qeinsum(normed(x, lp), lp["kv"], "btd,csdk->btcsk", adt,
-                     preferred_element_type=f32)
-        kv = (kv + lp["kv_bias"].astype(f32)).astype(adt)
-        return kv[..., 0, :], kv[..., 1, :]
+    return attender, attn_stats
 
-    cached = cache is not None and not paged
+
+def _layers(params, x, config, state, planes, mixer, attender):
+    """The stack over `x` [B, T, D] as three scans of PAIRS: the (mixer,
+    window attention) pairs, the publishing pair once, the (memory unit,
+    cross attention) pairs.  `state` is a tuple of arrays [Ls, ...] and
+    `planes` one of arrays [Lc, ...] (or empty), each handed on a layer at a
+    time: `mixer(x, lp, *state)` -> (x, the scan's output before the gate,
+    the new state, a tuple like `state`) and `attender(k, v, *planes (or
+    None, None), plane index, windowed)` -> (how queries attend that owner's
+    keys, what the caller keeps of `k`, `v`: a tuple).  Returns (x, what was
+    kept [Lc, ...], the new state [Ls, ...])."""
+    adt = config.activation_dtype
+    P1 = config.n_layers // 4
+    half = config.n_layers // 2
 
     def scan(body, x, xs):
         if config.scan_layers:
@@ -392,51 +357,147 @@ def forward(
 
     def pair(x, xs, windowed: bool, first: int):
         """A (mixer, attention over own keys) pair, layers `first + 2j` and
-        `first + 2j + 1`: (x, what the cache keeps of it, the mixer's scan
-        output, how its keys are attended)."""
-        lp, j, conv_s, ssm_s, *kv = xs
-        x, m, new_conv, new_ssm = mixer(x, lp["mixer"], conv_s, ssm_s)
+        `first + 2j + 1`: (x, (what is kept of its keys, its new state), the
+        mixer's scan output, how its keys are attended)."""
+        lp, j, state_l, planes_l = xs
+        x, m, new_state = mixer(x, lp["mixer"], *state_l)
         with jax.named_scope("attn.window" if windowed else "attn.full"):
-            k, v = own_keys(x, lp["attn"])
-            attend = attender(k, v, *(kv or (None, None)), j + first // 2, windowed)
-            x = attention(x, lp["attn"], first + 2 * j + 1, attend)
-        return ffn(x, lp["attn"]), (k, v, new_conv, new_ssm), m, attend
+            k, v = _own_keys(x, lp["attn"], config)
+            attend, kept = attender(
+                k, v, *(planes_l or (None, None)), j + first // 2, windowed)
+            x = _attention(x, lp["attn"], first + 2 * j + 1, attend, config)
+        return _ffn(x, lp["attn"], config), (kept, new_state), m, attend
 
     def stacked(lp, lo: int, n: int):
-        xs = (lp, jnp.arange(n, dtype=jnp.int32), conv0[lo:lo + n], ssm0[lo:lo + n])
-        if cached:
-            xs += (cache.k[lo:lo + n], cache.v[lo:lo + n])
-        return xs
+        return (lp, jnp.arange(n, dtype=jnp.int32),
+                tuple(a[lo:lo + n] for a in state),
+                tuple(a[lo:lo + n] for a in planes))
 
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(adt)
-
-    x, (k_s, v_s, conv_s, ssm_s) = scan(
+    x, (kept_s, state_s) = scan(
         lambda x, xs: pair(x, xs, True, 0)[:2], x,
         stacked(params["self_layers"], 0, P1))
     # The publishing pair, once: its scan output `m` and its `attend` (over
     # the one K/V the second half reads) are carried, not stored per layer.
     mid = jax.tree.map(lambda a: a[0], stacked(params["mid_layers"], P1, 1))
-    x, (k_f, v_f, conv_f, ssm_f), m, attend_full = pair(x, mid, False, half)
+    x, (kept_f, state_f), m, attend_full = pair(x, mid, False, half)
 
     def cross_pair(x, xs):
         lp, j = xs
         with jax.named_scope("gmu.mix"):
             g = lp["mixer"]
-            a = normed(x, g)
+            a = _normed(x, g, config)
             gate = jax.nn.silu(qeinsum(a, g["in_proj"], "btd,de->bte", adt))
             x = x + qeinsum(gate * m, g["out_proj"], "bte,ed->btd", adt)
-        x = ffn(x, g)
+        x = _ffn(x, g, config)
         with jax.named_scope("attn.cross"):
-            x = attention(x, lp["attn"], half + 2 * j + 3, attend_full)
-        return ffn(x, lp["attn"]), None
+            x = _attention(x, lp["attn"], half + 2 * j + 3, attend_full, config)
+        return _ffn(x, lp["attn"], config), None
 
     x, _ = scan(cross_pair, x,
                 (params["cross_layers"], jnp.arange(P1 - 1, dtype=jnp.int32)))
+    join = lambda s, f: jnp.concatenate([s, f[None]], axis=0)  # noqa: E731
+    return (x, jax.tree.map(join, kept_s, kept_f),
+            jax.tree.map(join, state_s, state_f))
 
-    new_k = jnp.concatenate([k_s, k_f[None]], axis=0)     # [Lc, B, T, KVH/2, 2hd]
-    new_v = jnp.concatenate([v_s, v_f[None]], axis=0)
-    new_conv = jnp.concatenate([conv_s, conv_f[None]], axis=0)
-    new_ssm = jnp.concatenate([ssm_s, ssm_f[None]], axis=0)
+
+def forward(
+    params: Params,
+    tokens: jnp.ndarray,
+    positions: jnp.ndarray,
+    config: LLaMAConfig,
+    cache=None,
+    attn_mask: Optional[jnp.ndarray] = None,
+    compute_logits: bool = True,
+    dropout_rng: Optional[jax.Array] = None,
+    output_hidden_states: bool = False,
+    output_attentions: bool = False,
+    output_last_hidden: bool = False,
+):
+    """`llama.forward`'s contract for the block with recurrent state layers:
+    cache-free (the state starts at zero), over a `KVCache` (scalar or per-row
+    index) or over a `PagedKVCache`, each with its `conv` / `ssm` state."""
+    from .llama import (
+        FLASH_MIN_SEQ, AuxOutput, KVCache, PagedKVCache, init_state,
+        lm_head_logits, paged_pool_write, paged_write_indices,
+    )
+
+    if dropout_rng is not None:
+        raise NotImplementedError(
+            "the block with recurrent state layers is served, not trained: "
+            "dropout_rng (the training step) is not supported")
+    if output_hidden_states or output_attentions:
+        raise NotImplementedError(
+            "output_hidden_states / output_attentions are not supported by "
+            "the block with recurrent state layers")
+    B, T = tokens.shape
+    adt = config.activation_dtype
+    eps = config.layer_norm_eps
+    paged = isinstance(cache, PagedKVCache)
+    if attn_mask is None:
+        attn_mask = positions >= 0
+    q_positions = jnp.maximum(positions, 0)
+    new_pos = jnp.where(attn_mask, q_positions, -1).astype(jnp.int32)
+    # A row's live tokens are a prefix of T (right padding): what the mixers
+    # advance their state by.
+    lengths = jnp.sum(attn_mask.astype(jnp.int32), axis=1)
+
+    use_flash = (not paged and T > FLASH_MIN_SEQ
+                 and config.attn_impl in ("flash", "auto")
+                 and not (cache is not None and cache.per_row_index))
+    use_scan_kernel = (T > 1 and ssm.kernel_eligible(T, config.mamba_d_inner)
+                       and not ssm._resolve_interpret())
+    attn_stats = jnp.zeros((len(ATTN_STATS),), jnp.int32)
+    if paged:
+        NB, BLK = cache.pos.shape
+        row_active = attn_mask[:, 0]
+        if T > 1:  # the kernel's T > 1 contract (see `llama.paged_forward`)
+            row_active = (
+                row_active & jnp.all(attn_mask == attn_mask[:, :1], axis=1)
+                & jnp.all(positions == positions[:, :1]
+                          + jnp.arange(T, dtype=positions.dtype), axis=1))
+        q_pos_row = jnp.where(row_active, positions[:, 0], -1).astype(jnp.int32)
+        lengths = jnp.where(row_active, T, 0).astype(jnp.int32)
+        attend_paged, attn_stats = _attend_paged(config, cache, q_pos_row, T)
+    elif cache is not None:
+        slot_pos = (
+            cache.pos.at[
+                jnp.arange(B, dtype=jnp.int32)[:, None],
+                cache.index[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :],
+            ].set(new_pos, mode="drop")
+            if cache.per_row_index
+            else lax.dynamic_update_slice(cache.pos, new_pos, (0, cache.index))
+        )
+    else:
+        slot_pos = new_pos
+    if cache is None:
+        conv0, ssm0 = init_state(config, B)
+    else:
+        conv0, ssm0 = cache.conv, cache.ssm
+    row_live = lengths > 0
+    if not paged:
+        attend_rows = _attend_rows(
+            config, cache, q_positions, new_pos, attn_mask, slot_pos, use_flash)
+
+    def mixer(x, lp, conv_s, ssm_s):
+        x, m, new_conv, new_ssm = _mixer(
+            x, lp, config,
+            lambda u: _conv(u, conv_s, lengths, lp),
+            lambda *operands: _recur(
+                ssm_s, *operands, lengths, row_live, use_scan_kernel))
+        return x, m, (new_conv, new_ssm)
+
+    def attender(k, v, ck, cv, plane, windowed: bool):
+        """Built once an owner: the cross layers share the full layer's."""
+        if paged:
+            return attend_paged(k, v, plane, windowed), (k, v)
+        return attend_rows(k, v, ck, cv, windowed), (k, v)
+
+    cached = cache is not None and not paged
+    x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(adt)
+    x, (new_k, new_v), (new_conv, new_ssm) = _layers(
+        params, x, config, (conv0, ssm0),
+        (cache.k, cache.v) if cached else (), mixer, attender)
+    # new_k, new_v [Lc, B, T, KVH/2, 2hd]
     stats = jnp.concatenate([jnp.zeros((moe.N_STATS,), jnp.int32), attn_stats])
 
     final_h = layer_norm(x, params["final_norm"], params["final_norm_bias"], eps)
@@ -473,3 +534,112 @@ def forward(
             k=new_k, v=new_v, pos=slot_pos, index=cache.index + T,
             conv=new_conv, ssm=new_ssm, stats=total)
     return (logits, new_cache, aux) if aux is not None else (logits, new_cache)
+
+
+def mixed_forward(
+    params: Params,
+    tokens: jnp.ndarray,
+    positions: jnp.ndarray,
+    config: LLaMAConfig,
+    cache,
+    attn_mask: jnp.ndarray,
+    rider_tokens: jnp.ndarray,
+    rider_positions: jnp.ndarray,
+    pool,
+):
+    """`llama.mixed_forward`'s contract for the block with recurrent state
+    layers: the chunk's [1, C] `tokens` over the one-row `cache` (a
+    `KVCache` with a scalar index and the row's `conv` / `ssm`) and one
+    token a decode row (`rider_tokens` [B] at `rider_positions`, -1 for a
+    row that rides masked) over `pool` (a `PagedKVCache` with every slot's
+    state) go through the embedding and all three scans of `_layers` as ONE
+    [1, C + B, D] activation.  It splits where a quantity is a row's own.
+    A mixer's recurrence: behind the shared `in_proj` the chunk's columns
+    take the conv and `ssm_scan` over the cache's state, the riders' the
+    conv and `ssm_step` over the pool's; `x_proj`, `dt_proj`, the gate and
+    `out_proj` see every column, and the publishing mixer's scan output
+    reaches the memory units as one [1, C + B, Di] tensor.  Attention, once
+    an owner: the chunk's queries attend over the cache's slices as
+    `forward` does (flash or xla by C, window or full), the riders' through
+    the paged kernel, its plans bound outside the scans; the cross layers
+    reuse the full layer's pair.  The riders' keys land once a plane after
+    the scans.  A masked rider leaves its slot's state bit for bit.
+
+    Returns (post-final-norm hidden states [1, C + B, D] — the chunk's rows,
+    then the riders' —, the updated `cache`, the updated `pool`); the call's
+    counts are added to the `stats` of both (a caller that folds one cache
+    into the other keeps one).  The head is the caller's."""
+    from .llama import FLASH_MIN_SEQ, _paged_land
+
+    if cache.per_row_index:
+        raise NotImplementedError("mixed_forward: a cache with a scalar index")
+    C = tokens.shape[1]
+    q_positions = jnp.maximum(positions, 0)
+    new_pos = jnp.where(attn_mask, q_positions, -1).astype(jnp.int32)
+    lengths = jnp.sum(attn_mask.astype(jnp.int32), axis=1)
+    chunk_live = lengths > 0
+    slot_pos = lax.dynamic_update_slice(cache.pos, new_pos, (0, cache.index))
+    use_flash = C > FLASH_MIN_SEQ and config.attn_impl in ("flash", "auto")
+    use_scan_kernel = (C > 1 and ssm.kernel_eligible(C, config.mamba_d_inner)
+                       and not ssm._resolve_interpret())
+    rider_qpos = rider_positions.astype(jnp.int32)
+    riding = rider_qpos >= 0
+    rider_lengths = riding.astype(jnp.int32)
+    attend_rows = _attend_rows(
+        config, cache, q_positions, new_pos, attn_mask, slot_pos, use_flash)
+    attend_paged, attn_stats = _attend_paged(config, pool, rider_qpos, 1)
+
+    def riders(a):  # [1, C + B, ...] -> the riders' columns as rows, [B, 1, ...]
+        return jnp.swapaxes(a[:, C:], 0, 1)
+
+    def rejoin(chunk, rode):  # [1, C, ...] and [B, 1, ...] -> [1, C + B, ...]
+        return jnp.concatenate([chunk, jnp.swapaxes(rode, 0, 1)], axis=1)
+
+    def mixer(x, lp, conv_c, ssm_c, conv_r, ssm_r):
+        def convolve(u):
+            c_c, held_c = _conv(u[:, :C], conv_c, lengths, lp)
+            c_r, held_r = _conv(riders(u), conv_r, rider_lengths, lp)
+            return rejoin(c_c, c_r), (held_c, held_r)
+
+        def recur(c, dt, Bm, Cm, A):
+            cols = (c, dt, Bm, Cm)
+            y_c, h_c = _recur(ssm_c, *(a[:, :C] for a in cols), A, lengths,
+                              chunk_live, use_scan_kernel)
+            y_r, h_r = _recur(ssm_r, *(riders(a) for a in cols), A,
+                              rider_lengths, riding, False)
+            return rejoin(y_c, y_r), (h_c, h_r)
+
+        x, m, (held_c, held_r), (h_c, h_r) = _mixer(x, lp, config, convolve, recur)
+        return x, m, (held_c, h_c, held_r, h_r)
+
+    def attender(k, v, ck, cv, plane, windowed: bool):
+        """Built once an owner, for both halves."""
+        kept = (k[:, :C], v[:, :C], riders(k), riders(v))
+        chunk = attend_rows(*kept[:2], ck, cv, windowed)
+        rode = attend_paged(*kept[2:], plane, windowed)
+        return lambda q: rejoin(chunk(q[:, :C]), rode(riders(q))), kept
+
+    x = jnp.take(
+        params["embed"]["embedding"],
+        jnp.concatenate([tokens, rider_tokens[None]], axis=1), axis=0,
+    ).astype(config.activation_dtype)
+    x, (new_k, new_v, rider_k, rider_v), (conv_c, ssm_c, conv_r, ssm_r) = _layers(
+        params, x, config, (cache.conv, cache.ssm, pool.conv, pool.ssm),
+        (cache.k, cache.v), mixer, attender)
+    stats = jnp.concatenate([jnp.zeros((moe.N_STATS,), jnp.int32), attn_stats])
+    counted = lambda c: stats if c.stats is None else c.stats + stats  # noqa: E731
+    at = (0, 0, cache.index, 0, 0)
+    return (
+        layer_norm(x, params["final_norm"], params["final_norm_bias"],
+                   config.layer_norm_eps),
+        dataclasses.replace(
+            cache,
+            k=lax.dynamic_update_slice(cache.k, new_k.astype(cache.k.dtype), at),
+            v=lax.dynamic_update_slice(cache.v, new_v.astype(cache.v.dtype), at),
+            pos=slot_pos, index=cache.index + C, conv=conv_c, ssm=ssm_c,
+            stats=counted(cache)),
+        dataclasses.replace(
+            _paged_land(pool, rider_k, rider_v, None, None, riding,
+                        rider_qpos[:, None], rolled=True),
+            conv=conv_r, ssm=ssm_r, stats=counted(pool)),
+    )

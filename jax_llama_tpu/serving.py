@@ -105,14 +105,14 @@ TPU-native mechanics:
     prompt chunk lands, where it samples its first token (one key
     split, exactly the classic insert's) and folds INTO the decode
     mask mid-dispatch — first token out of the same dispatch.  For
-    the dense block and the one with a mixer beside attention in every
-    layer, over the paged kernel, the dispatch is a hybrid batch in
+    the dense block and the two with a recurrent state, over the paged
+    kernel, the dispatch is a hybrid batch in
     Sarathi's sense: the chunk's tokens and the decode rows' first
     iteration go through ONE pass over the weights (``_mixed_pass``,
     ``models.llama.mixed_forward``; K passes a dispatch, not K + 1),
     and a row that folds in emits from the second iteration on.  The
-    other blocks run the chunk's pass and then the K iterations'
-    (ROADMAP A1 (e) ports the pass to them).  Host
+    two blocks with routed experts run the chunk's pass and then the K
+    iterations' (ROADMAP A1 (e) ports the pass to them).  Host
     boundary: the whole prefill pays ONE admission-time upload (the
     dirty-row sync + the one-off suffix/walk-scalar buffers) and the
     usual one packed fetch per chunk — no per-prefill-chunk host
@@ -649,10 +649,12 @@ def _mixed_pass(config, quantized_pool, mesh, use_kernel, n_iter) -> bool:
     """Whether a fused dispatch's first decode iteration rides its prompt
     chunk's pass over the weights (``_fused_chunk``) — shared by the
     program and the host's counter so the two cannot drift.  By the
-    block: the dense one and the one with a mixer beside attention in
-    every layer (``models.llama.mixed_forward``, which hands the second
-    to ``models.falcon_h1.mixed_forward``); latent attention, window
-    attention layers and the other recurrent block keep two passes until
+    block: the dense one and the two with a recurrent state — a mixer
+    beside attention in every layer, and mixer layers between attention
+    layers — take it (``models.llama.mixed_forward``, which hands the
+    two to ``models.falcon_h1.mixed_forward`` and
+    ``models.sambay.mixed_forward``); the two with routed experts
+    (latent attention, window attention layers) keep two passes until
     the mechanism is ported to them (ROADMAP A1 (e)).
     By the decode half: the paged kernel's (``use_kernel``: allowed and
     ``_kernel_eligible``).  And by what the trace sees of the operands:
@@ -664,9 +666,7 @@ def _mixed_pass(config, quantized_pool, mesh, use_kernel, n_iter) -> bool:
     return bool(
         use_kernel and n_iter >= 2 and not quantized_pool
         and (mesh is None or mesh.size == 1)
-        and (config.parallel_mixer or not (
-            config.latent_attention or config.windowed_attention
-            or config.recurrent_state))
+        and not (config.latent_attention or config.windowed_attention)
     )
 
 
@@ -1041,8 +1041,9 @@ def _fused_chunk(
     device-resident decode chunk).
 
     Two forms, chosen by ``_mixed_pass`` from what the trace sees.  The
-    mixed pass (the dense block, or the one with a mixer beside
-    attention in every layer, over the paged kernel, K >= 2): the
+    mixed pass (the dense block, or either block with a recurrent
+    state — a mixer beside attention in every layer, or mixer layers
+    between attention layers —, over the paged kernel, K >= 2): the
     first iteration's emit and stop-detect run ahead of the chunk, then
     its forward rides the chunk's pass over the weights — C prompt
     tokens and B decode tokens as one [1, C + B, D] activation, split
@@ -1054,9 +1055,9 @@ def _fused_chunk(
     whose prompt completes folds in behind the mixed pass and emits its
     first token at the second iteration (column 1 of the packed block,
     a pad in column 0): still from THIS dispatch.  Everywhere else
-    (latent attention, window attention layers, the other recurrent
-    block; an int8 pool, a sharded mesh, the gathered fallback) the
-    chunk runs as a forward of its own ahead of the whole scan
+    (the two blocks with routed experts: latent attention, and window
+    attention layers; an int8 pool, a sharded mesh, the gathered
+    fallback) the chunk runs as a forward of its own ahead of the scan
     (``n_iter + 1`` passes; a row that folds in emits from column 0),
     as the paragraphs below describe; K = 1 keeps that order so the
     completing dispatch still hands the first token over.

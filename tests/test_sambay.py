@@ -10,6 +10,7 @@ a wrong plane for the cross layers or a missing pair combine fails the float32
 tolerances — which a bfloat16 compute would fail too.
 """
 
+import dataclasses
 import importlib.util
 import json
 from pathlib import Path
@@ -485,6 +486,23 @@ def test_scopes_are_in_the_lowered_programs(tiny):
     text = jax.jit(lambda p, t, q, c: jlt.forward(p, t, q, cfg, cache=c)[0]).lower(
         params, toks[:, :1], pos[:, :1], cache).as_text(debug_info=True)
     assert "ssm.scan" in text and "attn.cross" in text
+    # the mixed pass (a prompt chunk and two decode rows, one of them masked)
+    # keeps every scope, so the `step.*_share_pct` readers keep reading
+    from jax_llama_tpu.models import llama
+
+    pool = serving.init_pool(cfg, 8, BLK, n_slots=2)
+    table, fill = jnp.arange(8, dtype=jnp.int32).reshape(2, 4), jnp.zeros((2,), jnp.int32)
+    view = serving._gather_cache(
+        pool, table[:1], jnp.asarray([4]), fill[:1],
+        state=(pool.conv[:, :1], pool.ssm[:, :1]))
+    view = dataclasses.replace(view, index=jnp.int32(0))
+    text = jax.jit(lambda p, t, q, c, pc: llama.mixed_forward(
+        p, t, q, cfg, c, q >= 0, jnp.asarray([5, 7]), jnp.asarray([3, -1]), pc)[0]
+    ).lower(params, toks, pos, view, serving._pool_as_cache(pool, table, fill)
+            ).as_text(debug_info=True)
+    for scope in ("ssm.mix", "ssm.scan", "gmu.mix", "attn.window", "attn.full",
+                  "attn.cross", "dense.ffn"):
+        assert scope in text, scope
 
 
 def test_every_parameter_has_a_partition_rule(tiny):

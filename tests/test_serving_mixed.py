@@ -1,15 +1,16 @@
 """The mixed pass: a fused dispatch's first decode iteration rides its
 prompt chunk's pass over the weights (``serving._fused_chunk`` →
 ``models.llama.mixed_forward``; paged kernel, K >= 2; the dense block, and
-the block with a mixer beside attention in every layer through
-``models.falcon_h1.mixed_forward``).
+the two blocks with a recurrent state: a mixer beside attention in every layer
+through ``models.falcon_h1.mixed_forward``, mixer layers between window, full
+and cross attention layers through ``models.sambay.mixed_forward``).
 
 Three levels, each against the two-pass form it replaces: the model's
 (``mixed_forward`` against a chunk ``forward`` and a paged decode
 ``forward``), the program's (``_fused_chunk`` as it is against itself
 traced with ``_mixed_pass`` answering no) and the scheduler's (token and
 logprob streams against classic admit-then-decode).  Then the counter, and
-that the three other blocks' ``_fused_chunk`` holds no such pass."""
+that the two other blocks' ``_fused_chunk`` holds no such pass."""
 
 import dataclasses
 import json
@@ -48,6 +49,18 @@ def mixer_model():
     tests/test_falcon_h1.py's tiny widths."""
     config = _tiny_block("parallel-mixer")
     return init_params(jax.random.PRNGKey(1), config), config
+
+
+@pytest.fixture(scope="module")
+def recurrent_model():
+    """The block with mixer layers between window, full and cross attention
+    layers, at tests/test_sambay.py's tiny widths (a window of 24 over
+    blocks of 16)."""
+    config = _tiny_block("recurrent")
+    return init_params(jax.random.PRNGKey(1), config), config
+
+
+_STATEFUL = {"parallel-mixer": "mixer_model", "recurrent": "recurrent_model"}
 
 
 # ---------------------------------------------------------------------------
@@ -198,12 +211,14 @@ def test_the_last_chunk_folds_its_row_in_one_column_later(model, sampled):
         np.asarray(got["pool"].pos)[:3], np.asarray(want["pool"].pos)[:3])
 
 
+@pytest.mark.parametrize("block", list(_STATEFUL))
 @pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
 @pytest.mark.parametrize("last", [False, True], ids=["mid-prompt", "last-chunk"])
 def test_a_mixer_beside_attention_leaves_what_its_two_passes_leave(
-    mixer_model, last, sampled,
+    request, block, last, sampled,
 ):
-    """The block with a mixer beside attention in every layer, mid-prompt
+    """Either block with a recurrent state (a mixer beside attention in
+    every layer; mixer layers between attention layers), mid-prompt
     and on the chunk that completes the prompt: what the two tests above
     hold the dense block to, and the recurrent state.  Every decoding
     slot's state is the two-pass form's and the idle slot's is untouched,
@@ -211,7 +226,7 @@ def test_a_mixer_beside_attention_leaves_what_its_two_passes_leave(
     first chunk, from the row's slot on a later one — stands in snapshot 1
     and (mid-prompt, where the row does not decode yet) in the row's slot;
     no other snapshot moves."""
-    params, config = mixer_model
+    params, config = request.getfixturevalue(_STATEFUL[block])
     args, kwargs = _case(params, config, last, sampled)
     got = dict(zip(_OUT, _MIXED(*args, **kwargs)))
     want = dict(zip(_OUT, _TWO_PASS(*args, **kwargs)))
@@ -255,7 +270,7 @@ def test_a_mixer_beside_attention_leaves_what_its_two_passes_leave(
         np.asarray(got["pool"].stats), np.asarray(want["pool"].stats))
 
 
-@pytest.mark.parametrize("block", ["dense", "parallel-mixer"])
+@pytest.mark.parametrize("block", ["dense", *_STATEFUL])
 @pytest.mark.parametrize(
     "impl,scan", [("xla", True), ("auto", True), ("xla", False)],
     ids=["chunk-xla", "chunk-flash", "unrolled"],
@@ -266,12 +281,12 @@ def test_mixed_forward_is_the_chunk_forward_and_the_paged_decode_forward(
     """The model's half alone: hidden states of the chunk's rows, logits of
     the riding rows (a masked row's are nobody's), the row's view and the
     pool planes against ``forward`` over the view and ``forward`` over the
-    paged cache.  The dense block on a prompt's first chunk; the block with
-    a mixer on a later one (8 live tokens of 32 behind 32 in the cache),
-    with the view's and the slots' recurrent state — a masked rider's, the
-    prefilling row's own slot among them, bit for bit."""
-    stateful = block == "parallel-mixer"
-    params, config = request.getfixturevalue("mixer_model" if stateful else "model")
+    paged cache.  The dense block on a prompt's first chunk; the two blocks
+    with a recurrent state on a later one (8 live tokens of 32 behind 32 in
+    the cache), with the view's and the slots' recurrent state — a masked
+    rider's, the prefilling row's own slot among them, bit for bit."""
+    stateful = block in _STATEFUL
+    params, config = request.getfixturevalue(_STATEFUL.get(block, "model"))
     config = config.replace(attn_impl=impl, scan_layers=scan)
     args, _ = _case(params, config, stateful)
     off, plen = (32, 40) if stateful else (0, 64)
@@ -569,6 +584,82 @@ def test_a_mixer_beside_attention_is_served_as_classic_admission_serves_it(
     assert stats["fused_merged_rows_total"] >= 5
 
 
+@pytest.fixture(scope="module")
+def recurrent_classic(recurrent_model):
+    """{sampled: the scenario's streams under classic admit-then-decode}."""
+    # conftest's per-module clearing, once more inside this long module: under
+    # every program of the tests above the XLA:CPU compiler segfaulted three
+    # block configurations later (reproduced twice; nothing below reuses them).
+    jax.clear_caches()
+    memo = {}
+
+    def get(sampled):
+        if sampled not in memo:
+            memo[sampled] = _serve_sessions(*recurrent_model, 0, 4, sampled)[0]
+        return memo[sampled]
+
+    return get
+
+
+def _serve_sessions(params, config, budget, k, sampled):
+    """A holder decodes; a 105-token request arrives (with ``budget`` 32: four
+    chunks through the lane, the last one 9 live tokens that end the prompt,
+    snapshots at 32, 64 and 96), then a re-ask of its first 100 tokens with
+    another tail.  Returns ([the three streams], batcher)."""
+    rng = np.random.RandomState(4)
+    draw = lambda n: [int(t) for t in rng.randint(0, config.vocab_size, n)]  # noqa: E731
+    doc, holder = draw(100), draw(6)
+    asks = [doc + draw(n) for n in (5, 9)]
+    pol = lambda t, seed: dict(temperature=t, seed=seed) if sampled else {}  # noqa: E731
+    cb = ContinuousBatcher(
+        params, config, n_slots=3, max_len=128, block_size=BLK,
+        decode_chunk=k, prefill_budget=budget)
+    out = {}
+
+    def steps(n=None):
+        for i in range(400):
+            if (n is not None and i >= n) or (n is None and not cb.pending()):
+                return
+            for rid, tok, *_ in cb.step():
+                out.setdefault(rid, []).append(tok)
+        raise AssertionError("did not finish")
+
+    rids = [cb.submit(holder, max_new_tokens=64, **pol(0.8, 7))]
+    steps(2)
+    rids.append(cb.submit(asks[0], max_new_tokens=8, **pol(0.7, 12)))
+    steps(5)
+    rids.append(cb.submit(asks[1], max_new_tokens=8, **pol(0.9, 13)))
+    steps()
+    return [out[r] for r in rids], cb
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("k", [2, 8])
+def test_mixer_layers_between_attention_layers_are_served_as_classic_admission_serves_them(
+    recurrent_model, recurrent_classic, k, sampled,
+):
+    """The block with mixer layers between window, full and cross attention
+    layers through ``ContinuousBatcher``, chunks of 32 over blocks of 16
+    beside a holder that decodes past the window of 24: every fused
+    dispatch runs K iterations and takes the mixed pass, the re-ask
+    restores the snapshot at 96, and every stream — greedy, or drawn from
+    the rows' own keys — is classic admit-then-decode's
+    (``prefill_budget=0``: whole prompts through ``_paged_insert``)."""
+    params, config = recurrent_model
+    got, cb = _serve_sessions(params, config, 32, k, sampled)
+    assert got == recurrent_classic(sampled)
+    stats = cb.stats()
+    assert stats["ssm_snapshots_taken_total"] == 3
+    assert stats["ssm_snapshots_restored_total"] == 1
+    assert cb.prefix_hit_tokens_total == 96
+    fused = [r for r in cb.obs.dispatches if r["kind"] == "fused"]
+    assert [r["k"] for r in fused] == [k] * 5
+    assert [r["prefill_tokens"] for r in fused] == [32, 32, 32, 9, 13]
+    assert all(r["merged_rows"] >= 1 for r in fused)
+    assert stats["fused_dispatches_merged_total"] == 5
+    assert stats["fused_merged_rows_total"] == sum(r["merged_rows"] for r in fused)
+
+
 # ---------------------------------------------------------------------------
 # The counter, and the blocks the pass was not ported to
 # ---------------------------------------------------------------------------
@@ -658,15 +749,15 @@ def test_only_the_blocks_the_pass_was_ported_to_hold_it_in_their_fused_chunk(
     model, kind,
 ):
     """Traced at K = 4 over 4 rows and a 32-token chunk: the dense
-    program, and that of the block with a mixer beside attention in every
-    layer, have products over C + B = 36 rows and K passes over the
-    weights; the three other blocks' have no such product and K + 1 passes
-    (the chunk's, and the decode scan's K).  Served, the mixer block's
-    counters read what its records say, and the three others' read 0."""
+    program, and those of the two blocks with a recurrent state, have
+    products over C + B = 36 rows and K passes over the weights; the two
+    blocks with routed experts have no such product and K + 1 passes (the
+    chunk's, and the decode scan's K).  Served, the ported blocks' counters
+    read what their records say, and the two others' read 0."""
     if kind == "dense":
         params, config = model
     else:
-        config = _tiny_block(kind)  # the mixer's: ``mixer_model``'s own
+        config = _tiny_block(kind)  # the stateful blocks': their fixtures' own
         params = init_params(jax.random.PRNGKey(1), config)
     rows, chunk, n_iter = 4, 32, 4
     mb = config.max_seq_len // BLK
@@ -682,7 +773,7 @@ def test_only_the_blocks_the_pass_was_ported_to_hold_it_in_their_fused_chunk(
     )
     head = {(config.dim, config.vocab_size), (config.vocab_size, config.dim)}
     mixed, passes = _products(traced.jaxpr.jaxpr, chunk + rows, head)
-    ported = kind in ("dense", "parallel-mixer")
+    ported = kind in ("dense", *_STATEFUL)
     if ported:
         assert mixed > 0 and passes == n_iter
         if kind == "dense":  # served: the tests above
