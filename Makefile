@@ -88,7 +88,7 @@ elastic:
 
 # Invariant auditor (jax_llama_tpu/analysis): host-boundary lint,
 # lowering-contract audit (donated args actually alias, host-fetch
-# surface within budget, no full-pool-copy equations — all ten
+# surface within budget, no full-pool-copy equations — all eight
 # registered jitted programs lowered at a tiny geometry), the
 # lock-discipline / thread-confinement check, the retrace auditor
 # (bounded jit-cache-key domains statically + the admission-sweep

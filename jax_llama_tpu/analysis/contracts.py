@@ -293,24 +293,6 @@ def _chunk_state(cb) -> tuple:
     )
 
 
-def _build_paged_decode_step():
-    import jax.numpy as jnp
-
-    cb = _plain_batcher()
-    names = ("params", "pool", "table", "n_alloc", "fill", "tau",
-             "pos", "active", "keys", "temperature", "top_p", "top_k")
-    args = (
-        cb.params, cb.pool, jnp.asarray(cb.table),
-        jnp.asarray(cb.n_alloc), jnp.asarray(cb.fill), cb.tau,
-        jnp.asarray(cb.pos), jnp.asarray(cb.active), cb.keys,
-        jnp.asarray(cb.temp_arr), jnp.asarray(cb.top_p_arr),
-        jnp.asarray(cb.top_k_arr),
-    )
-    kwargs = dict(config=cb.config, all_greedy=True, mesh=None,
-                  allow_kernel=True, with_logprobs=False)
-    return names, args, kwargs
-
-
 def _build_paged_decode_chunk():
     cb = _plain_batcher()
     names = ("params", "pool") + _STATE_NAMES
@@ -359,26 +341,6 @@ _CHUNK_ALIASES = {
     "tau": 1, "tau_lp": 2, "fill": 3, "pos": 4, "active": 5,
     "remaining": 6, "keys": 7, "pool": 8,
 }
-
-
-def _build_spec_round():
-    import jax.numpy as jnp
-
-    cb = _spec_batcher()
-    names = ("t_params", "d_params", "t_pool", "d_pool", "table",
-             "n_alloc", "fill", "tau", "pos", "active", "keys",
-             "temperature", "top_p", "top_k")
-    args = (
-        cb.params, cb.draft_params, cb.pool, cb.draft_pool,
-        jnp.asarray(cb.table), jnp.asarray(cb.n_alloc),
-        jnp.asarray(cb.fill), cb.tau, jnp.asarray(cb.pos),
-        jnp.asarray(cb.active), cb.keys, jnp.asarray(cb.temp_arr),
-        jnp.asarray(cb.top_p_arr), jnp.asarray(cb.top_k_arr),
-    )
-    kwargs = dict(t_config=cb.config, d_config=cb.draft_config,
-                  n_draft=cb.n_draft, all_greedy=True, use_kernel=True,
-                  mesh=None, with_logprobs=False)
-    return names, args, kwargs
 
 
 def _build_spec_rounds_chunk():
@@ -531,15 +493,6 @@ _FUSED_CHUNK_COMMS = CommsBudget(
 REGISTRY: Dict[str, ProgramContract] = {
     c.name: c for c in (
         ProgramContract(
-            name="_paged_decode_step", module="jax_llama_tpu.serving",
-            donated=("pool",), max_live_outputs=2,
-            max_fetch_bytes_per_row=16,
-            build=_build_paged_decode_step,
-            # all_greedy (bool); config/mesh/allow_kernel/with_logprobs
-            # are ctor-stable per batcher.
-            max_cache_keys=4,
-        ),
-        ProgramContract(
             name="_paged_decode_chunk", module="jax_llama_tpu.serving",
             donated=_CHUNK_DONATED, max_live_outputs=1,
             max_fetch_bytes_per_row=16,
@@ -564,14 +517,6 @@ REGISTRY: Dict[str, ProgramContract] = {
             # sparse corner of that product, and every axis is O(log).
             max_cache_keys=48,
             comms=_FUSED_CHUNK_COMMS,
-        ),
-        ProgramContract(
-            name="_spec_round", module="jax_llama_tpu.serving",
-            donated=("t_pool", "d_pool"), max_live_outputs=4,
-            max_fetch_bytes_per_row=64,
-            build=_build_spec_round,
-            # all_greedy (2) x use_kernel (2).
-            max_cache_keys=6,
         ),
         ProgramContract(
             name="_spec_rounds_chunk", module="jax_llama_tpu.serving",

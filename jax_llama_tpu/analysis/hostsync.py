@@ -77,8 +77,7 @@ AUDITED_MODULES = (
 # Per-module device-state registry: ``self.<attr>`` names that hold
 # jax arrays (device residency).  The generic ``d_`` prefix rule covers
 # the device twins on ANY object; these are the exceptions that don't
-# carry the prefix.  NOTE: ``tau_lp`` (no prefix) is the NUMPY mirror
-# and is deliberately absent.
+# carry the prefix.
 DEVICE_SELF_ATTRS: Dict[str, Set[str]] = {
     "serving": {
         "pool", "draft_pool", "tau", "keys", "params", "draft_params",
@@ -106,11 +105,10 @@ DEVICE_PARAM_NAMES = frozenset({
 # lowering auditor's contract registry is the authority for the jitted
 # subset; this adds the non-jit wrappers.
 DEVICE_RETURNING = frozenset({
-    "_paged_decode_step", "_paged_decode_chunk", "_fused_chunk",
-    "_spec_round", "_spec_rounds_chunk", "_paged_insert",
-    "_paged_suffix_insert", "_scatter_rows", "_release_blocks",
-    "_adopt_jit", "adopt_into_pool", "stage_restore", "init_pool",
-    "_gather_cache", "_scatter_back", "_pool_as_cache",
+    "_paged_decode_chunk", "_fused_chunk", "_spec_rounds_chunk",
+    "_paged_insert", "_paged_suffix_insert", "_scatter_rows",
+    "_release_blocks", "_adopt_jit", "adopt_into_pool", "stage_restore",
+    "init_pool", "_gather_cache", "_scatter_back", "_pool_as_cache",
     # recurrent state layers: the per-slot state and the snapshot pool are
     # fields of ``pool`` (covered above); these cut rows out of them and
     # put them back, on the device: a snapshot copy or a state reset that

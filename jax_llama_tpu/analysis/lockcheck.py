@@ -220,7 +220,7 @@ CONFINEMENTS: Tuple[ThreadConfinement, ...] = (
               "design — the dispatch path stays lock-free)",
         fields=frozenset({
             # block-table / per-slot decode state + their device twins
-            "table", "fill", "pos", "active", "tau", "tau_lp", "keys",
+            "table", "fill", "pos", "active", "tau", "keys",
             "remaining", "stop_tab", "pool", "draft_pool",
             "_dirty_rows",
             # admission machinery

@@ -17,8 +17,8 @@ Quarantine swaps ONLY the failing feature: a ``spec_decode`` fallback
 rebuild drops the draft model but keeps the original ``decode_chunk``
 and ``spec_rounds`` configuration (the rebuild reuses the base ctor
 kwargs), so a quarantined speculative server degrades onto plain
-CHUNKED decode, not the per-token loop — and a later probe re-enable
-restores fused speculative serving with the same R.  Failures are
+decode at the same K — and a later probe re-enable restores
+speculative serving with the same R.  Failures are
 attributed once per fused chunk dispatch (the R rounds inside one
 jitted program are one dispatch).
 

@@ -13,12 +13,12 @@ recovery, the retry budget, and the step watchdog deterministically.
 
 Sites (fired by ``ContinuousBatcher`` just before the real operation):
 
-  ``step``           a decode/speculative step dispatch.  Chunked
-                     dispatches (``decode_chunk`` / ``spec_rounds``
-                     > 1) fire ONCE per fused chunk — the K decode
-                     iterations or R speculative rounds inside one
-                     jitted program are a single dispatch, so ``@N``
-                     indices count chunks, not tokens or rounds
+  ``step``           a decode/speculative step dispatch: fires ONCE
+                     per fused chunk — the K decode iterations
+                     (``decode_chunk``) or R speculative rounds
+                     (``spec_rounds``) inside one jitted program are a
+                     single dispatch, so ``@N`` indices count chunks,
+                     not tokens or rounds
   ``insert``         a batched full-prompt prefill (``_paged_insert``)
   ``suffix_insert``  a prefix-cache-hit suffix prefill
   ``prefill_chunk``  a chunk dispatch CARRYING a fused prefill lane
@@ -47,9 +47,8 @@ Sites (fired by ``ContinuousBatcher`` just before the real operation):
                      batcher-side site)
   ``paged_kernel``   a decode step on the Pallas paged-attention kernel
                      path (same batcher-then-trace fire order)
-  ``spec_decode``    a speculative draft+verify dispatch — one round
-                     classically, one fused R-round chunk under
-                     ``spec_rounds`` > 1 (also fired by
+  ``spec_decode``    a speculative draft+verify dispatch — one fused
+                     chunk of R <= ``spec_rounds`` rounds (also fired by
                      ``spec_decode.generate_speculative`` at trace time
                      when a hook is installed)
 

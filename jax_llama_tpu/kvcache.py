@@ -38,10 +38,9 @@ adapted to this repo's paged pool and fused-chunk scheduler):
     a plain prefix hit — decode rows never stall (``make perf-smoke``
     asserts 0 stall dispatches while a swap-in is in flight).
 
-Three index modes (``run.py --prefix-index``): ``radix`` (the default
-— partial-prefix sharing + host tier), ``exact`` (the legacy flat
-chain map, kept as the behavioral oracle; no host tier), ``off`` (no
-prefix matching or retention — the old ``prefix_cache=False``).
+Two index modes: ``radix`` (the default — partial-prefix sharing + host
+tier) and ``off`` (no prefix matching or retention: ``prefix_cache=False``,
+``run.py --no-prefix-cache``).
 
 Every store also maintains a :class:`KvDigest` (r13 fleet cache
 telemetry): an incrementally-updated, lock-guarded, cross-thread-
@@ -75,7 +74,7 @@ import numpy as np
 
 from .engine import pow2_bucket
 
-PREFIX_INDEX_MODES = ("radix", "exact", "off")
+PREFIX_INDEX_MODES = ("radix", "off")
 
 
 # ---------------------------------------------------------------------------
@@ -876,118 +875,8 @@ class RadixPrefixStore:
 
 
 # ---------------------------------------------------------------------------
-# Exact (legacy) and off modes
+# The off mode
 # ---------------------------------------------------------------------------
-
-class ExactPrefixStore:
-    """The pre-radix flat chain map (mode ``exact``), kept as the
-    behavioral oracle: one ``Dict[bytes, block]`` keyed by cumulative
-    chain hash, duplicate publications SUPERSEDE (the old
-    ``_register_chain`` churn), eviction is pure insertion-order LRU,
-    and there is no host tier."""
-
-    kind = "exact"
-    enabled = True
-
-    def __init__(self):
-        self._prefix_index: Dict[bytes, int] = {}
-        self._block_chain: Dict[int, bytes] = {}
-        self._reusable: "OrderedDict[int, None]" = OrderedDict()
-        # Flat-map digest: depth = chain index + 1 (no tree, but the
-        # same versioned surface every store exposes).
-        self.digest = KvDigest()
-
-    def match(self, keys: Sequence[bytes]) -> MatchResult:
-        hits: List[int] = []
-        for key in keys:
-            blk = self._prefix_index.get(key)
-            if blk is None:
-                break
-            hits.append(blk)
-        return MatchResult(blocks=hits, path=[], restore=[])
-
-    def publish(self, keys: Sequence[bytes],
-                blocks: Sequence[int]) -> List[int]:
-        superseded: List[int] = []
-        for depth, (blk, key) in enumerate(zip(blocks, keys)):
-            old = self._prefix_index.get(key)
-            if old is not None and old != blk:
-                self._block_chain.pop(old, None)
-                if old in self._reusable:
-                    del self._reusable[old]
-                    superseded.append(old)
-                # The key now binds the freshly published (claimed)
-                # block: clear any idle flag inherited from the
-                # superseded one, or /debug/kv would report a live
-                # session's block as evictable for its whole life.
-                self.digest.on_idle(key, False)
-            self._block_chain[blk] = key
-            self._prefix_index[key] = blk
-            self.digest.on_publish(key, depth + 1)
-        return superseded
-
-    def unpublish(self, blk: int) -> List[int]:
-        key = self._block_chain.pop(blk, None)
-        if key is not None and self._prefix_index.get(key) == blk:
-            del self._prefix_index[key]
-            self.digest.on_remove(key)
-        return []
-
-    def is_keyed(self, blk: int) -> bool:
-        return blk in self._block_chain
-
-    def retain(self, blocks: Sequence[int]) -> None:
-        for blk in reversed(list(blocks)):
-            self._reusable[blk] = None
-            key = self._block_chain.get(blk)
-            if key is not None:
-                self.digest.on_idle(key, True)
-
-    def on_claim(self, blocks: Sequence[int]) -> None:
-        for blk in blocks:
-            self._reusable.pop(blk, None)
-            key = self._block_chain.get(blk)
-            if key is not None:
-                self.digest.on_idle(key, False)
-
-    def evictable(self) -> int:
-        return len(self._reusable)
-
-    def pop_evictable(self, demote=None) -> Tuple[Optional[int], List[int]]:
-        if not self._reusable:
-            return None, []
-        blk, _ = self._reusable.popitem(last=False)
-        self.unpublish(blk)
-        return blk, []
-
-    def demote_keys(self, keys, demote=None) -> List[int]:
-        """Demote-after-export is a radix/tier feature; the exact
-        oracle keeps its published chains in place."""
-        return []
-
-    def pin_restoring(self, nodes) -> None:  # pragma: no cover - no tier
-        raise AssertionError("exact store has no host tier")
-
-    unpin_restoring = complete_restore = pin_restoring
-
-    def cached_blocks(self) -> int:
-        return len(self._reusable)
-
-    def nodes_total(self) -> int:
-        return len(self._prefix_index)
-
-    def host_blocks(self) -> int:
-        return 0
-
-    def resident_chains(self) -> List[List[bytes]]:
-        """Flat map: no parent links, so chains cannot be reassembled —
-        each published key is emitted as its own depth-1 chain.  Because
-        ``match`` looks every cumulative key up independently, importing
-        these singletons on another replica reproduces the same hit
-        surface; only the radix store's shared-prefix structure is
-        lost (it never existed here)."""
-        return [[key] for key in self._prefix_index]
-
 
 class NullPrefixStore:
     """Mode ``off``: nothing matches, nothing is retained."""
@@ -1040,10 +929,10 @@ class NullPrefixStore:
 
 def make_prefix_store(mode: str, host_blocks: int = 0, on_event=None):
     """Store factory.  The host tier only attaches to the radix index
-    (``exact`` is the legacy oracle, ``off`` retains nothing — in both
-    a nonzero ``host_blocks`` is inert by design: the degradation
-    layer's prefix-cache quarantine rebuilds with the cache off and
-    must not trip a constructor error over the tier flag).
+    (``off`` retains nothing, and a nonzero ``host_blocks`` is inert
+    there by design: the degradation layer's prefix-cache quarantine
+    rebuilds with the cache off and must not trip a constructor error
+    over the tier flag).
     ``on_event`` (radix only) is an observability sink for tier
     transitions — the batcher wires ``obs.Observability.annotate`` so
     demote/host-evict/restore events land in the serving trace."""
@@ -1054,8 +943,6 @@ def make_prefix_store(mode: str, host_blocks: int = 0, on_event=None):
     if mode == "radix":
         return RadixPrefixStore(host_blocks=host_blocks,
                                 on_event=on_event)
-    if mode == "exact":
-        return ExactPrefixStore()
     return NullPrefixStore()
 
 

@@ -141,8 +141,8 @@ def _parser() -> argparse.ArgumentParser:
                          "batcher state live on device, the host syncs "
                          "once per chunk instead of once per token; "
                          "effective K adapts down to 1 around "
-                         "admissions; 1 restores the classic per-token "
-                         "loop.  Speculative serving chunks by ROUNDS "
+                         "admissions; 1 is one dispatch a token.  "
+                         "Speculative serving chunks by ROUNDS "
                          "through --spec-rounds instead)")
     ap.add_argument("--prefill-budget", type=int, default=512,
                     help="fused prefill-decode scheduling for --serve / "
@@ -171,8 +171,7 @@ def _parser() -> argparse.ArgumentParser:
                          "twin of --decode-chunk; token-identical to 1 "
                          "including the acceptance pattern; the "
                          "effective R adapts down to 1 around "
-                         "admissions; 1 restores the classic "
-                         "per-round loop)")
+                         "admissions; 1 is one dispatch a round)")
     ap.add_argument("--http", type=int, default=None, metavar="PORT",
                     help="serve over HTTP on this port (POST /generate "
                          "with blocking or NDJSON-streaming responses, "
@@ -183,21 +182,13 @@ def _parser() -> argparse.ArgumentParser:
                     help="disable prompt prefix caching in the serving "
                          "pool (on by default; hits are token-identical "
                          "in tested configurations — this is a "
-                         "memory/debug knob; equivalent to "
-                         "--prefix-index off)")
-    ap.add_argument("--prefix-index", default="radix",
-                    choices=["radix", "exact", "off"],
-                    help="prefix-cache index for --serve / --http: "
-                         "'radix' (default) shares partial prompt "
-                         "prefixes across ALL cached chains through a "
-                         "block-granular radix tree (leaves-first "
-                         "eviction, host-tier residency); 'exact' keeps "
-                         "the legacy flat exact-chain map (the "
-                         "behavioral oracle, no host tier); 'off' "
-                         "disables matching and retention")
+                         "memory/debug knob).  The cache shares "
+                         "partial prompt prefixes across ALL cached "
+                         "chains through a block-granular radix tree "
+                         "(leaves-first eviction, host-tier residency)")
     ap.add_argument("--host-kv-blocks", type=int, default=0,
                     help="host-DRAM KV block tier capacity for --serve "
-                         "/ --http (requires --prefix-index radix): "
+                         "/ --http (not with --no-prefix-cache): "
                          "cold prefix-cache blocks evict into pinned "
                          "host memory instead of being freed, and "
                          "sessions whose cached prefix was demoted "
@@ -331,17 +322,14 @@ def main() -> None:
     from .obs import StructuredLogger
 
     log = StructuredLogger(json_mode=args.log_json)
-    if args.host_kv_blocks > 0 and (
-        args.prefix_index != "radix" or args.no_prefix_cache
-    ):
+    if args.host_kv_blocks > 0 and args.no_prefix_cache:
         # The tier hangs off radix-node residency; refusing loudly here
         # beats a silently inert flag (the batcher ctor tolerates the
         # combination only because the degradation layer's prefix-cache
         # quarantine must be able to rebuild with the cache off).
         raise SystemExit(
-            "--host-kv-blocks requires --prefix-index radix with the "
-            "prefix cache enabled (the host tier hangs off radix-node "
-            "residency)"
+            "--host-kv-blocks requires the prefix cache enabled (the "
+            "host tier hangs off radix-node residency)"
         )
     if args.logprobs and args.http is None:
         raise SystemExit(
@@ -627,6 +615,44 @@ def _load_draft(args, mesh):
     return draft_params, draft_config
 
 
+def _stop_tokens(tokenizer) -> tuple:
+    return tuple(
+        int(s) for s in getattr(tokenizer, "stop_tokens", [tokenizer.eos_id])
+    )
+
+
+def _make_batcher(params, config, tokenizer, mesh, args, *, seed,
+                  draft_params, draft_config, fault_injector=None,
+                  obs=None):
+    """The one place the CLI builds a ``ContinuousBatcher``.  An option a
+    bare namespace leaves out (tests, ``benchmark/system.py``) takes the
+    parser's own default, so what is served without a flag is written
+    once."""
+    from .serving import ContinuousBatcher
+
+    parser = _parser()
+
+    def opt(name):
+        return getattr(args, name, parser.get_default(name))
+
+    return ContinuousBatcher(
+        params, config, n_slots=args.slots,
+        max_len=config.max_seq_len, stop_tokens=_stop_tokens(tokenizer),
+        temperature=args.temperature, top_p=args.top_p,
+        seed=seed, mesh=mesh,
+        logprobs=opt("logprobs"),
+        prefix_cache=not opt("no_prefix_cache"),
+        fault_injector=fault_injector,
+        decode_chunk=opt("decode_chunk"),
+        draft_params=draft_params, draft_config=draft_config,
+        n_draft=opt("n_draft"),
+        spec_rounds=opt("spec_rounds"),
+        prefill_budget=opt("prefill_budget"),
+        host_kv_blocks=opt("host_kv_blocks"),
+        obs=obs,
+    )
+
+
 def _serve_http(params, config, tokenizer, mesh, args, _test_hook=None,
                 logger=None):
     """HTTP front-end: LLMServer over the batcher until interrupted.
@@ -640,7 +666,6 @@ def _serve_http(params, config, tokenizer, mesh, args, _test_hook=None,
 
     from .obs import Observability, StructuredLogger
     from .server import LLMServer
-    from .serving import ContinuousBatcher
 
     if logger is None:
         logger = StructuredLogger(
@@ -653,9 +678,6 @@ def _serve_http(params, config, tokenizer, mesh, args, _test_hook=None,
         )
         return
 
-    stops = tuple(
-        int(s) for s in getattr(tokenizer, "stop_tokens", [tokenizer.eos_id])
-    )
     # Fault injection (chaos runs / tests): --inject-faults wins over the
     # JLT_FAULTS env var; absent both, no injector is constructed.
     fault_spec = (
@@ -693,22 +715,10 @@ def _serve_http(params, config, tokenizer, mesh, args, _test_hook=None,
         slo_ttft_ms=getattr(args, "slo_ttft_ms", 0.0) or None,
         slo_itl_ms=getattr(args, "slo_itl_ms", 0.0) or None,
     )
-    cb = ContinuousBatcher(
-        params, config, n_slots=args.slots,
-        max_len=config.max_seq_len, stop_tokens=stops,
-        temperature=args.temperature, top_p=args.top_p,
-        seed=args.seed, mesh=mesh,
-        logprobs=getattr(args, "logprobs", False),
-        prefix_cache=not getattr(args, "no_prefix_cache", False),
-        fault_injector=injector,
-        decode_chunk=getattr(args, "decode_chunk", 8),
+    cb = _make_batcher(
+        params, config, tokenizer, mesh, args, seed=args.seed,
         draft_params=draft_params, draft_config=draft_config,
-        n_draft=getattr(args, "n_draft", 4),
-        spec_rounds=getattr(args, "spec_rounds", 8),
-        prefill_budget=getattr(args, "prefill_budget", 512),
-        prefix_index=getattr(args, "prefix_index", "radix"),
-        host_kv_blocks=getattr(args, "host_kv_blocks", 0),
-        obs=obs,
+        fault_injector=injector, obs=obs,
     )
     # Llama-3 tokenizers get the dialog endpoint for free (ChatFormat is
     # the reference's own framing; other tokenizers have no chat contract).
@@ -843,15 +853,11 @@ def _serve_router(params, config, tokenizer, mesh, args,
     from .parallel.serve_mesh import build_serve_mesh, parse_serve_mesh
     from .router import ReplicaRouter
     from .server import LLMServer
-    from .serving import ContinuousBatcher
 
     if logger is None:
         logger = StructuredLogger(
             json_mode=getattr(args, "log_json", False)
         )
-    stops = tuple(
-        int(s) for s in getattr(tokenizer, "stop_tokens", [tokenizer.eos_id])
-    )
     fault_spec = (
         getattr(args, "inject_faults", None) or os.environ.get("JLT_FAULTS")
     )
@@ -944,22 +950,10 @@ def _serve_router(params, config, tokenizer, mesh, args,
             slo_ttft_ms=getattr(args, "slo_ttft_ms", 0.0) or None,
             slo_itl_ms=getattr(args, "slo_itl_ms", 0.0) or None,
         )
-        cb = ContinuousBatcher(
-            p, config, n_slots=args.slots,
-            max_len=config.max_seq_len, stop_tokens=stops,
-            temperature=args.temperature, top_p=args.top_p,
-            seed=args.seed + i, mesh=m,
-            logprobs=getattr(args, "logprobs", False),
-            prefix_cache=not getattr(args, "no_prefix_cache", False),
-            fault_injector=injector,
-            decode_chunk=getattr(args, "decode_chunk", 8),
+        cb = _make_batcher(
+            p, config, tokenizer, m, args, seed=args.seed + i,
             draft_params=d, draft_config=draft_config,
-            n_draft=getattr(args, "n_draft", 4),
-            spec_rounds=getattr(args, "spec_rounds", 8),
-            prefill_budget=getattr(args, "prefill_budget", 512),
-            prefix_index=getattr(args, "prefix_index", "radix"),
-            host_kv_blocks=getattr(args, "host_kv_blocks", 0),
-            obs=obs,
+            fault_injector=injector, obs=obs,
         )
         srv = LLMServer(
             cb, tokenizer=tokenizer, host=args.host, port=0,
@@ -1082,25 +1076,11 @@ def _serve(params, config, tokenizer, mesh, args) -> None:
     """Continuous-batching loop over stdin prompts (one per line)."""
     import sys
 
-    from .serving import ContinuousBatcher
-
-    stops = tuple(
-        int(s) for s in getattr(tokenizer, "stop_tokens", [tokenizer.eos_id])
-    )
+    stops = _stop_tokens(tokenizer)
     draft_params, draft_config = _load_draft(args, mesh)
-    cb = ContinuousBatcher(
-        params, config, n_slots=args.slots,
-        max_len=config.max_seq_len, stop_tokens=stops,
-        temperature=args.temperature, top_p=args.top_p,
-        seed=args.seed, mesh=mesh,
-        prefix_cache=not getattr(args, "no_prefix_cache", False),
-        decode_chunk=getattr(args, "decode_chunk", 8),
+    cb = _make_batcher(
+        params, config, tokenizer, mesh, args, seed=args.seed,
         draft_params=draft_params, draft_config=draft_config,
-        n_draft=getattr(args, "n_draft", 4),
-        spec_rounds=getattr(args, "spec_rounds", 8),
-        prefill_budget=getattr(args, "prefill_budget", 512),
-        prefix_index=getattr(args, "prefix_index", "radix"),
-        host_kv_blocks=getattr(args, "host_kv_blocks", 0),
     )
     rid_prompt: dict = {}
     emitted: dict = {}

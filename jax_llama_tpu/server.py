@@ -69,7 +69,7 @@ parity.  Design constraints, in order:
     time-to-first-token over delivered requests, alpha 0.2; the
     stall win surfaces here first).  The KV-capacity subsystem
     (``kvcache.py``: radix prefix index + host-DRAM block tier,
-    run.py ``--prefix-index`` / ``--host-kv-blocks``) adds:
+    run.py ``--no-prefix-cache`` / ``--host-kv-blocks``) adds:
     ``llm_radix_nodes_total`` (gauge — keyed blocks in the radix
     tree), ``llm_prefix_hit_tokens_ratio`` (gauge — fraction of
     admitted prompt tokens served from cached prefix blocks; the
@@ -124,7 +124,7 @@ parity.  Design constraints, in order:
       "degraded": bool,        # any feature quarantined or probing
       "quarantined": [feature, ...],
       "kv": {                  # KV-capacity subsystem (kvcache.py)
-        "prefix_index": "radix"|"exact"|"off",
+        "prefix_index": "radix"|"off",
         "host_kv_blocks": int,     # tier capacity (0 = tier off)
         "host_tier_blocks": int,   # blocks currently demoted
         "swap_queue_depth": int,   # swap-ins in flight (restoring)
@@ -314,9 +314,9 @@ lock-guarded ``kvcache.KvDigest``, never the thread-confined store)::
      "truncated": int,                   # nodes past the n= cap
      "depth_cap": int|null,
      "summary": {<the /healthz kv.digest dict> +
-                 prefix_index/block_size/block_bytes/total_blocks/
-                 host_kv_blocks/prefix_hit_tokens_total/
-                 prompt_tokens_total}}
+                 prefix_index ("radix"|"off")/block_size/block_bytes/
+                 total_blocks/host_kv_blocks/
+                 prefix_hit_tokens_total/prompt_tokens_total}}
 
 Nodes sort (depth, key) so equal content serializes identically; the
 walk is depth-capped by ``depth`` and truncated past ``n`` (default

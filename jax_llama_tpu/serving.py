@@ -44,8 +44,8 @@ TPU-native mechanics:
     are -1 (masked), their sampled token is ignored by the host, and
     their cache write-back is dropped (sentinel block id, scatter mode
     "drop").
-  * **Chunked decode (Orca-style iteration batching).**  With
-    ``decode_chunk`` > 1 the non-speculative step fuses K decode
+  * **Chunked decode (Orca-style iteration batching).**  The
+    non-speculative step fuses K <= ``decode_chunk`` decode
     iterations into ONE jitted ``lax.scan`` program
     (``_paged_decode_chunk``): stop-token sets, per-row max_new budgets
     and the non-finite -1 sentinel are evaluated ON DEVICE (finished
@@ -58,8 +58,7 @@ TPU-native mechanics:
     packed host matrix (``pack_rows``) syncs them
     before the next chunk — steady-state decode performs zero
     host->device state uploads and one device->host fetch per K tokens
-    per slot, instead of the five uploads + one fetch PER TOKEN the
-    K=1 loop pays.  K adapts (1 right after a classic admission; while
+    per slot.  K adapts (1 right after a classic admission; while
     requests queue, clamped to 4 on a plain decode dispatch, where they
     wait for a slot, and to 2 on one that carries a prompt chunk, where
     they wait for the prefill lane; pow2 up to ``decode_chunk`` once the
@@ -69,26 +68,25 @@ TPU-native mechanics:
     token-identical to K=1 under greedy and seeded sampling — per-row
     key chains split once per iteration exactly as one K=1 dispatch
     would (pinned by tests/test_serving_chunked.py).
-  * **Chunked speculative serving.**  With ``spec_rounds`` > 1 the
-    speculative path gets the same treatment: R draft+verify rounds
-    fuse into ONE jitted ``lax.scan`` program (``_spec_rounds_chunk``,
-    sharing ``_spec_round_core`` with the kept single-round program),
-    with the per-round host work moved on device — the pending-tau
-    emit, the accepted-prefix emit scan with stop-token / max_new /
-    non-finite folding (``spec_decode.accepted_emit_counts``), the
-    fill rewind to ``+acc+1`` after each verify, and mid-chunk
-    fold-out of finished rows.  Host-boundary accounting: the classic
-    loop paid 2-3 device->host fetches (tau, outs/acc, logprobs) plus
-    FIVE mirror uploads (table/n_alloc/fill/pos/active + policies)
-    PER ROUND; the fused path pays ONE packed [B, R, G+2(+G+1)] fetch
-    per R rounds and zero steady-state uploads — both the target and
-    draft pools and all per-slot decode state are device-resident via
-    the same ``d_*`` twins / dirty-row ``_scatter_rows`` sync the
+  * **Chunked speculative serving.**  The speculative path gets the
+    same treatment: up to ``spec_rounds`` draft+verify rounds fuse
+    into ONE jitted ``lax.scan`` program (``_spec_rounds_chunk``, each
+    iteration one ``_spec_round_core``), with the per-round host work
+    on device — the pending-tau emit, the accepted-prefix emit scan
+    with stop-token / max_new / non-finite folding
+    (``spec_decode.accepted_emit_counts``), the fill rewind to
+    ``+acc+1`` after each verify, and mid-chunk fold-out of finished
+    rows.  Host-boundary accounting: ONE packed [B, R, G+2(+G+1)]
+    fetch per R rounds and zero steady-state uploads — both the target
+    and draft pools and all per-slot decode state are device-resident
+    via the same ``d_*`` twins / dirty-row ``_scatter_rows`` sync the
     plain chunked path uses.  R adapts exactly like K (1 after an
     admission, clamped while capacity-blocked, pow2 up to
-    ``spec_rounds``), and chunked output is token-identical to the
-    classic per-round path — including the acceptance pattern and
-    per-token logprobs (pinned by tests/test_serving_spec.py).
+    ``spec_rounds``; ``spec_rounds=1`` is the same program at one
+    round a dispatch), and output is token-identical at every R and to
+    the standalone ``spec_decode.generate_speculative`` — including
+    the acceptance pattern and per-token logprobs (pinned by
+    tests/test_serving_spec.py).
   * **Fused prefill-decode scheduling (stall-free admission).**  With
     ``prefill_budget`` > 0 (run.py
     ``--prefill-budget``, on by default there) the batched-prefill
@@ -127,9 +125,8 @@ TPU-native mechanics:
     keep classic admission everywhere.
   * **KV capacity: radix prefix index + host-DRAM block tier**
     (``kvcache.py``).  The prefix cache's index is a block-granular
-    radix/trie over token chains (``prefix_index="radix"``, the
-    default; ``"exact"`` keeps the legacy flat chain map as the
-    behavioral oracle, ``"off"`` disables matching): an admission
+    radix/trie over token chains (``prefix_cache=False`` disables
+    matching and retention): an admission
     claims the longest shared block prefix across ALL cached chains,
     divergent chains share their common prefix nodes by construction,
     and eviction is leaves-first.  With ``host_kv_blocks`` > 0 cold
@@ -675,10 +672,28 @@ def _decode_step_core(
     temperature, top_p, top_k, *, config, all_greedy, use_kernel,
     with_logprobs, placed=False,
 ):
-    """One [n_slots, 1] decode iteration over the paged pool — the shared
-    body of the single-step program (``_paged_decode_step``) and each
-    ``lax.scan`` iteration of the fused chunk program
-    (``_paged_decode_chunk``), so the two cannot drift numerically.
+    """One [n_slots, 1] decode iteration over the paged pool — the body
+    of each ``lax.scan`` iteration of ``_chunk_scan`` (``_paged_decode_chunk``
+    and the decode half of ``_fused_chunk``).
+
+    tau: [B] current token per slot; pos: [B] its absolute position;
+    active: [B] bool.  Inactive rows run masked (position -1, write-back
+    dropped, sampled token ignored by the host).
+
+    ``all_greedy`` is static: when every active slot is greedy the step
+    compiles to a pure argmax — no sorts/softmax/key-splits on the hot
+    path (the host flips to the sampling variant the moment a sampled
+    request is admitted; greedy rows' key chains are never consumed, so
+    skipping the split here is unobservable).
+
+    Attention path (``use_kernel``, resolved by the caller): the Pallas
+    paged kernel walks the block table in-kernel (pool read once per
+    step; int8 pools fold their dequant scales in-kernel).  Under a mesh
+    the op itself shard_maps over the tensor (KV heads) and data (rows)
+    axes.  Fallbacks to the gathered contiguous view: block sizes that
+    break Mosaic's 8-sublane tiling, and meshes the kernel sharding
+    cannot cover (kv_heads % tensor != 0, n_slots % data != 0, or active
+    seq/stage axes).
 
     Returns (next token [B] with the -1 non-finite sentinel folded in,
     its model logprob or None, carried keys, updated pool)."""
@@ -779,59 +794,6 @@ def _admission_sample(
         return lax.cond(done, consume, skip)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "config", "mesh", "all_greedy", "allow_kernel", "with_logprobs",
-        "placed",
-    ),
-    donate_argnames=("pool",),
-)
-def _paged_decode_step(
-    params, pool, table, n_alloc, fill, tau, pos, active, keys,
-    temperature, top_p, top_k, *, config, all_greedy=False, mesh=None,
-    allow_kernel=True, with_logprobs=False, placed=False,
-):
-    """One [n_slots, 1] decode step over the paged pool.
-
-    tau: [B] current token per slot; pos: [B] its absolute position;
-    active: [B] bool.  Inactive rows run masked (position -1, write-back
-    dropped, sampled token ignored by the host).
-
-    ``all_greedy`` is static: when every active slot is greedy the step
-    compiles to a pure argmax — no sorts/softmax/key-splits on the hot
-    path (the host flips to the sampling variant the moment a sampled
-    request is admitted; greedy rows' key chains are never consumed, so
-    skipping the split here is unobservable).
-
-    Attention path: the Pallas paged kernel walks the block table
-    in-kernel (pool read once per step; int8 pools fold their dequant
-    scales in-kernel).  Under a mesh the op itself shard_maps over the
-    tensor (KV heads) and data (rows) axes.  Fallbacks to the gathered
-    contiguous view: block sizes that break Mosaic's 8-sublane tiling,
-    and meshes the kernel sharding cannot cover (kv_heads % tensor != 0,
-    n_slots % data != 0, or active seq/stage axes).
-    """
-    with use_mesh(mesh):
-        # Sub-128 (narrow-lane) block sizes are verified compiled on
-        # hardware — bf16 and int8 kernels match interpret mode exactly at
-        # BLK 8/16/32/64/128 on a v5e chip (regression-tested in
-        # tests/test_tpu_compiled.py).
-        use_kernel = allow_kernel and _kernel_eligible(
-            pool.block_size, mesh, config.kv_heads, tau.shape[0]
-        )
-        nxt, lp, keys, pool = _decode_step_core(
-            params, pool, table, n_alloc, fill, tau, pos, active, keys,
-            temperature, top_p, top_k, config=config,
-            all_greedy=all_greedy, use_kernel=use_kernel,
-            with_logprobs=with_logprobs, placed=placed,
-        )
-        if placed:
-            keys, = smesh.constrain_rows(keys)
-            pool = smesh.constrain_pool(pool)
-        return nxt, lp, keys, pool
-
-
 # "No token emitted this chunk column" marker in the [B, K] token block
 # (the row was already inactive).  Distinct from the -1 non-finite
 # sentinel: real tokens are never negative, so both are unambiguous.
@@ -898,6 +860,10 @@ def _paged_decode_chunk(
     fully-dead tail only arises from stop tokens landing early.
     """
     with use_mesh(mesh):
+        # Sub-128 (narrow-lane) block sizes are verified compiled on
+        # hardware — bf16 and int8 kernels match interpret mode exactly at
+        # BLK 8/16/32/64/128 on a v5e chip (regression-tested in
+        # tests/test_tpu_compiled.py).
         use_kernel = allow_kernel and _kernel_eligible(
             pool.block_size, mesh, config.kv_heads, tau.shape[0]
         )
@@ -1447,7 +1413,7 @@ def _paged_insert(
         tau_lp = (
             _token_logprob(logits_last, tau) if with_logprobs else None
         )
-        # Non-finite guard (see _paged_decode_step): -1 sentinel rows are
+        # Non-finite guard (see _sample_step): -1 sentinel rows are
         # failed by the host at the next emit boundary.
         tau = jnp.where(finite_rows(logits_last), tau, -1)
 
@@ -1557,7 +1523,7 @@ def _paged_suffix_insert(
         keys, sub = _split_rows(keys)
         tau = sample_rows(sub, logits_last, temperature, top_p, top_k)
         lp = _token_logprob(logits_last, tau) if with_logprobs else None
-        # Non-finite guard (see _paged_decode_step): -1 sentinel rows are
+        # Non-finite guard (see _sample_step): -1 sentinel rows are
         # failed by the host at the next emit boundary.
         tau = jnp.where(finite_rows(logits_last), tau, -1)
         # Serving-mesh placement: see _paged_insert's epilogue.
@@ -1642,11 +1608,10 @@ def _spec_round_core(
     with_logprobs=False, placed=False,
 ):
     """One speculative round for every active slot — greedy or sampled
-    verification, per-row policies.  The shared row-wise draft/verify
-    body of the single-round program (``_spec_round``) and each
-    ``lax.scan`` iteration of the fused R-round chunk program
-    (``_spec_rounds_chunk``), so the two cannot drift numerically (the
-    same discipline ``_decode_step_core`` enforces for plain decode).
+    verification, per-row policies.  The row-wise draft/verify body of
+    each ``lax.scan`` iteration of the R-round chunk program
+    (``_spec_rounds_chunk``) — to speculation what ``_decode_step_core``
+    is to plain decode.
 
     Draft proposes ``n_draft`` tokens autoregressively, the target
     verifies them in ONE [B, n_draft+1] forward (weights stream once per
@@ -1884,37 +1849,6 @@ def _spec_round_core(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "t_config", "d_config", "n_draft", "all_greedy", "use_kernel",
-        "mesh", "with_logprobs", "placed",
-    ),
-    donate_argnames=("t_pool", "d_pool"),
-)
-def _spec_round(
-    t_params, d_params, t_pool, d_pool, table, n_alloc, fill, tau, pos,
-    active, keys, temperature, top_p, top_k, *,
-    t_config, d_config, n_draft, all_greedy, use_kernel, mesh=None,
-    with_logprobs=False, placed=False,
-):
-    """One jitted speculative round — the classic one-dispatch-per-round
-    program (``spec_rounds=1``); a thin jit wrapper over
-    ``_spec_round_core`` (see its docstring for the full contract)."""
-    outs, acc, lps, keys, t_pool, d_pool = _spec_round_core(
-        t_params, d_params, t_pool, d_pool, table, n_alloc, fill, tau,
-        pos, active, keys, temperature, top_p, top_k,
-        t_config=t_config, d_config=d_config, n_draft=n_draft,
-        all_greedy=all_greedy, use_kernel=use_kernel, mesh=mesh,
-        with_logprobs=with_logprobs, placed=placed,
-    )
-    with use_mesh(mesh):
-        if placed:
-            t_pool = smesh.constrain_pool(t_pool)
-            d_pool = smesh.constrain_pool(d_pool)
-    return outs, acc, lps, keys, t_pool, d_pool
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
         "t_config", "d_config", "n_draft", "n_rounds", "all_greedy",
         "use_kernel", "mesh", "with_logprobs", "placed",
     ),
@@ -1930,22 +1864,20 @@ def _spec_rounds_chunk(
     use_kernel, mesh=None, with_logprobs=False, placed=False,
 ):
     """``n_rounds`` fused speculative rounds in ONE jitted program — the
-    speculative twin of ``_paged_decode_chunk``.  Each ``lax.scan``
-    iteration replays the host's classic per-round contract
-    (``_step_spec`` + ``_spec_tail``) exactly, ON DEVICE:
+    speculative twin of ``_paged_decode_chunk`` (``n_rounds=1`` is to it
+    what ``n_iter=1`` is to that program).  Each ``lax.scan`` iteration
+    is one whole round, ON DEVICE:
 
       1. *emit* the pending token ``tau`` into the round's output row
          (column 0), recording -1 for a non-finite-sentinel row and
          ``_CHUNK_PAD`` for rows already inactive; a row whose tau hits
          its stop set / exhausts its budget folds out of ``active``
-         before the round runs (the host freed the slot BEFORE the
-         round in the classic loop, so it never paid for a discarded
-         draft+verify);
+         before the round runs, so it never pays for a discarded
+         draft+verify;
       2. run one ``_spec_round_core`` draft+verify for the surviving
-         rows (identical per-round key-split topology, warp math, and
-         commit/rewind as the classic program — it IS the same traced
-         function);
-      3. fold the host's accepted-prefix emit scan on device
+         rows (per-round key-split topology, warp math and
+         commit/rewind as a standalone ``generate_speculative``);
+      3. the accepted-prefix emit scan
          (``spec_decode.accepted_emit_counts``): tokens ``outs[:acc]``
          emit into columns 1..acc until a stop token or the max_new
          budget lands mid-prefix, the fill/pos rewind to ``+acc+1``
@@ -1958,13 +1890,13 @@ def _spec_rounds_chunk(
     G+1 token columns, its acceptance count (-1 = the verify's
     non-finite sentinel, ``_CHUNK_PAD`` = row inactive that round) and,
     under ``with_logprobs``, the G+1 bitcast fp32 target logprobs —
-    ONE ``np.asarray`` fetch replaces the classic loop's 2-3 fetches +
-    five mirror uploads PER ROUND.  All speculative decode state
-    (tau/tau_lp/fill/pos/active/remaining/keys + BOTH pools) stays
-    device-resident between chunks.
+    ONE ``np.asarray`` fetch a dispatch and no upload.  All speculative
+    decode state (tau/tau_lp/fill/pos/active/remaining/keys + BOTH
+    pools) stays device-resident between chunks.
 
-    Token-identity with the classic per-round path — including the
-    acceptance pattern and per-token logprobs — is pinned by
+    Token-identity across R and with the standalone
+    ``spec_decode.generate_speculative`` — including the acceptance
+    pattern and per-token logprobs — is pinned by
     tests/test_serving_spec.py; rounds after every row has folded out
     run masked rather than cond-skipped (same trade as
     ``_paged_decode_chunk`` — the host clamps R to the largest
@@ -1975,7 +1907,7 @@ def _spec_rounds_chunk(
         def body(carry, _):
             (t_pool, d_pool, tau, tau_lp, fill, pos, active, remaining,
              keys) = carry
-            # --- the host's step-start emit of the pending tau ---
+            # --- emit the pending tau ---
             nonfinite = tau < 0
             hit_stop = stop_token_hits(tau, stops)
             out0 = jnp.where(
@@ -1993,7 +1925,7 @@ def _spec_rounds_chunk(
                 n_draft=G, all_greedy=all_greedy, use_kernel=use_kernel,
                 mesh=mesh, with_logprobs=with_logprobs, placed=placed,
             )
-            # --- the host's accepted-prefix emit scan, on device ---
+            # --- the accepted-prefix emit scan ---
             verify_nan = active & (acc < 0)
             acc_c = jnp.clip(acc, 0, G)
             stop_hits = stop_token_hits(outs[:, :G], stops)  # [B, G]
@@ -2011,8 +1943,8 @@ def _spec_rounds_chunk(
             acc_out = jnp.where(
                 active, jnp.where(verify_nan, -1, acc_c), _CHUNK_PAD
             ).astype(jnp.int32)
-            # --- advance / fold-out: the classic host loop's
-            # fill/pos += acc+1 rewind and slot frees, in-carry ---
+            # --- advance / fold-out: the fill/pos += acc+1 rewind and
+            # the finished rows' exit, in-carry ---
             cont = active & ~verify_nan & ~any_done
             adv = jnp.where(cont, acc_c + 1, 0)
             fill = fill + adv
@@ -2089,7 +2021,7 @@ def _spec_rounds_chunk(
 # Jit-cache observability: the registered serving programs
 # ---------------------------------------------------------------------------
 
-# Every jitted program the serving stack dispatches (the same ten the
+# Every jitted program the serving stack dispatches (the same eight the
 # analysis lowering contracts audit), by name — the source for the
 # per-program ``jit_cache_entries`` gauge (/metrics).
 # ``_cache_size()`` is jax's own per-function executable
@@ -2098,10 +2030,8 @@ def _spec_rounds_chunk(
 def _programs() -> Dict[str, Any]:
     from .kvcache import _adopt_jit
     return {
-        "_paged_decode_step": _paged_decode_step,
         "_paged_decode_chunk": _paged_decode_chunk,
         "_fused_chunk": _fused_chunk,
-        "_spec_round": _spec_round,
         "_spec_rounds_chunk": _spec_rounds_chunk,
         "_paged_insert": _paged_insert,
         "_paged_suffix_insert": _paged_suffix_insert,
@@ -2248,8 +2178,8 @@ class ContinuousBatcher:
     ``decode_chunk`` fuses up to that many decode iterations per jitted
     dispatch (module docstring, "Chunked decode"): each ``step()`` call
     may emit up to K tokens per slot, token-identically to the K=1 loop,
-    at one host round-trip per chunk.  1 (the default) preserves the
-    classic one-dispatch-per-token behavior; serving entry points
+    at one host round-trip per chunk.  1 (the default) is the same
+    program at one iteration a dispatch; serving entry points
     (run.py ``--decode-chunk``) default higher.
 
     Passing ``draft_params``/``draft_config`` turns on speculative
@@ -2263,12 +2193,11 @@ class ContinuousBatcher:
 
     ``spec_rounds`` is ``decode_chunk``'s speculative twin: up to that
     many draft+verify ROUNDS fuse into one jitted dispatch (module
-    docstring, "Chunked speculative serving"), token-identically to the
-    per-round loop — one ``step()`` may then emit up to
-    R * (n_draft + 1) tokens per slot at one host round-trip per chunk.
-    1 (the default) preserves the classic one-dispatch-per-round
-    behavior; serving entry points (run.py ``--spec-rounds``) default
-    higher.
+    docstring, "Chunked speculative serving"), token-identically at
+    every R — one ``step()`` may then emit up to R * (n_draft + 1)
+    tokens per slot at one host round-trip per chunk.  1 (the default)
+    is the same program at one round a dispatch; serving entry points
+    (run.py ``--spec-rounds``) default higher.
 
     ``prefill_budget`` turns on fused prefill-decode scheduling (module
     docstring, "Fused prefill-decode scheduling"): warm admissions
@@ -2280,13 +2209,13 @@ class ContinuousBatcher:
     points (run.py ``--prefill-budget``) default it on.  Ignored by
     speculative batchers.
 
-    ``prefix_index`` picks the prefix cache's index implementation
-    (module docstring, "KV capacity"): ``"radix"`` (default) shares
-    partial prefixes across ALL cached chains through a block-granular
-    trie; ``"exact"`` keeps the legacy flat chain map as the
-    behavioral oracle; ``"off"`` ≡ ``prefix_cache=False``.
-    ``host_kv_blocks`` > 0 (radix only) attaches the host-DRAM block
-    tier: cold blocks demote into it instead of being freed, and
+    ``prefix_cache`` (module docstring, "KV capacity") shares partial
+    prefixes across ALL cached chains through a block-granular radix
+    trie; False disables matching and retention (``prefix_index``, the
+    derived attribute the server reports, reads ``"radix"`` or
+    ``"off"``).  ``host_kv_blocks`` > 0 (with the cache on) attaches
+    the host-DRAM block tier: cold blocks demote into it instead of
+    being freed, and
     admissions whose matched prefix was demoted swap it back in
     asynchronously through the ``restoring`` state — decode rows never
     stall on a swap-in (run.py ``--host-kv-blocks``).
@@ -2317,7 +2246,6 @@ class ContinuousBatcher:
         decode_chunk: int = 1,
         spec_rounds: int = 1,
         prefill_budget: int = 0,
-        prefix_index: str = "radix",
         host_kv_blocks: int = 0,
         obs: Optional[Observability] = None,
     ):
@@ -2335,8 +2263,8 @@ class ContinuousBatcher:
             use_pallas_kernel=use_pallas_kernel, logprobs=logprobs,
             prefix_cache=prefix_cache, fault_injector=fault_injector,
             decode_chunk=decode_chunk, spec_rounds=spec_rounds,
-            prefill_budget=prefill_budget, prefix_index=prefix_index,
-            host_kv_blocks=host_kv_blocks, obs=obs,
+            prefill_budget=prefill_budget, host_kv_blocks=host_kv_blocks,
+            obs=obs,
         )
         # Compile attribution (obs.py, the jax.monitoring listener) is
         # always on: it is two thread-local writes per dispatch.
@@ -2439,15 +2367,10 @@ class ContinuousBatcher:
                 "--host-kv-blocks (the host tier) is not supported with "
                 f"{config.expert_block}: a demoted node's state snapshot "
                 "does not demote with it")
-        if self.recurrent and prefix_cache and prefix_index == "exact":
-            raise ValueError(
-                "--prefix-index exact is not supported with "
-                f"{config.expert_block}: state snapshots hang on radix nodes")
         self.n_snapshots = (
             snapshot_pool_size(
                 self.config, n_slots, self.n_blocks, self.block_size)
-            if self.recurrent and prefix_cache and prefix_index == "radix"
-            else 0
+            if self.recurrent and prefix_cache else 0
         )
         # What a slot's state (and one snapshot of it) holds, and the
         # snapshot pool: gauges that turn the ssm_* counts into bytes.
@@ -2498,28 +2421,20 @@ class ContinuousBatcher:
         # the same, it just never hits).
         #
         # The INDEX behind the cache lives in kvcache.py
-        # (``prefix_index``: "radix" — block-granular trie, partial-
-        # prefix hits shared across all chains, leaves-first eviction,
-        # host-tier residency; "exact" — the legacy flat chain map,
-        # kept as the behavioral oracle; "off").  ``host_kv_blocks``
-        # > 0 attaches the host-DRAM tier (radix only — inert
-        # elsewhere, see kvcache.make_prefix_store): cold blocks
+        # (``prefix_index``, what the server reports: "radix" —
+        # block-granular trie, partial-prefix hits shared across all
+        # chains, leaves-first eviction, host-tier residency; "off").
+        # ``host_kv_blocks`` > 0 attaches the host-DRAM tier (inert with
+        # the cache off, see kvcache.make_prefix_store): cold blocks
         # demote into it instead of being freed, and admissions whose
         # matched prefix includes demoted blocks swap them back in
         # asynchronously through the ``restoring`` admission state
         # (module docstring, "KV capacity").
-        if prefix_index not in ("radix", "exact", "off"):
-            raise ValueError(
-                f"unknown prefix_index {prefix_index!r}; "
-                "have ('radix', 'exact', 'off')"
-            )
-        if not prefix_cache:
-            prefix_index = "off"
-        self.prefix_index = prefix_index
+        self.prefix_index = "radix" if prefix_cache else "off"
         self.host_kv_blocks = max(0, int(host_kv_blocks))
-        self.prefix_cache_enabled = prefix_index != "off"
+        self.prefix_cache_enabled = bool(prefix_cache)
         self._store = make_prefix_store(
-            prefix_index, host_blocks=self.host_kv_blocks,
+            self.prefix_index, host_blocks=self.host_kv_blocks,
             on_event=self.obs.annotate,
         )
         # The store's chain digest, surfaced as a batcher attribute so
@@ -2609,8 +2524,7 @@ class ContinuousBatcher:
         # (one dispatch per batch of dirty rows) and advanced ON DEVICE
         # by ``_paged_decode_chunk`` / ``_spec_rounds_chunk`` —
         # steady-state decode uploads nothing and fetches one packed
-        # token block per chunk.  Only the CLASSIC speculative path
-        # (spec_rounds=1) still uploads the mirrors per round.
+        # token block per chunk.
         B, MB = n_slots, self.blocks_per_slot
         # Row placer: the mesh-sharded upload for [B, ...] per-slot
         # device arrays (plain jnp.asarray without placement).
@@ -2622,10 +2536,6 @@ class ContinuousBatcher:
         self.n_alloc = np.zeros((B,), np.int32)
         self.fill = np.zeros((B,), np.int32)
         self.tau = self._rows(jnp.zeros((B,), jnp.int32))
-        # Model logprob of each slot's pending tau (valid while active).
-        # The numpy mirror serves the speculative emit scan; the chunked
-        # path carries the device twin through the chunk program.
-        self.tau_lp = np.zeros((B,), np.float32)
         self.pos = np.zeros((B,), np.int32)
         self.active = np.zeros((B,), bool)
         self.keys = self._rows(jnp.zeros((B, 2), jnp.uint32))
@@ -2641,13 +2551,12 @@ class ContinuousBatcher:
         self.stop_tab = np.full((B, w0), -1, np.int32)
         # decode_chunk: max fused decode iterations per dispatch (the
         # effective K per dispatch adapts — see _pick_chunk — and is
-        # always a power of two <= this).  1 = the classic one-dispatch-
-        # per-token loop.
+        # always a power of two <= this).  1 = one dispatch a token.
         self.decode_chunk = max(1, int(decode_chunk))
         # spec_rounds: max fused speculative draft+verify ROUNDS per
         # dispatch (the speculative twin of decode_chunk; the effective
-        # R adapts through the same _pick_chunk policy).  1 = the
-        # classic one-dispatch-per-round loop.
+        # R adapts through the same _pick_chunk policy).  1 = one
+        # dispatch a round.
         self.spec_rounds = max(1, int(spec_rounds))
         # prefill_budget: fused prefill-decode scheduling.  > 0 admits
         # prompts that would stall mid-decode rows through _fused_chunk
@@ -2670,7 +2579,7 @@ class ContinuousBatcher:
         # obs.ADMIT_BLOCKED; None: it did not).  The next chunk record's
         # ``blocked``.
         self._blocked: Optional[str] = None
-        # Device-resident twins (chunked path only); row-sharded on a
+        # Device-resident twins; row-sharded on a
         # placed serving mesh (see _mesh_placed above).
         self.d_table = self._rows(self.table)
         self.d_n_alloc = self._rows(self.n_alloc)
@@ -2682,6 +2591,8 @@ class ContinuousBatcher:
         self.d_top_ks = self._rows(self.top_k_arr)
         self.d_remaining = self._rows(self.remaining)
         self.d_stops = self._rows(self.stop_tab)
+        # Model logprob of each slot's pending tau (valid while active),
+        # carried through the chunk programs.
         self.d_tau_lp = self._rows(jnp.zeros((B,), jnp.float32))
         # Rows whose mirrors changed since the last device sync
         # (admission / free / cancel); flushed in one _scatter_rows
@@ -3231,9 +3142,9 @@ class ContinuousBatcher:
         """One decode dispatch for every active slot.
 
         Returns [(request_id, token, done)] for tokens emitted this call
-        — up to the effective chunk size K per slot on the chunked path
-        (``decode_chunk`` > 1), up to ``n_draft + 1`` per slot in
-        speculative mode.  With ``logprobs=True`` each tuple carries a
+        — up to the effective chunk size K per slot, up to
+        R * (``n_draft`` + 1) per slot in speculative mode.  With
+        ``logprobs=True`` each tuple carries a
         4th element: the token's model logprob (fp32 log-softmax of the
         raw logits — what ``engine.score`` reports for the position).
         Finished slots free their blocks and queued requests are
@@ -3378,8 +3289,7 @@ class ContinuousBatcher:
         chunk to meet first."""
         self.obs.count_upload()
         # audit: host-upload(the counted copy outside a jitted call: a
-        # fused admission's packed vector, once an admission; the classic
-        # speculative round's tau)
+        # fused admission's packed vector, once an admission)
         return (jnp.asarray(host) if self._upload_to is None
                 else jax.device_put(host, self._upload_to))
 
@@ -3722,94 +3632,15 @@ class ContinuousBatcher:
         return out
 
     def _step_spec(self) -> List[Tuple]:
-        """Speculative step.  With ``spec_rounds`` > 1 the fused
-        R-round chunk path (``_step_spec_chunked``) runs: R draft+verify
-        rounds per jitted dispatch, state device-resident, one packed
-        fetch per chunk.  The default (``spec_rounds=1``) keeps the
-        classic one-round-per-dispatch loop below, with its per-round
-        mirror uploads — the parity oracle the chunked path is pinned
-        against (tests/test_serving_spec.py)."""
-        if self.spec_rounds > 1:
-            return self._step_spec_chunked()
-        # Emit each active slot's current tau; free finished slots BEFORE
-        # the round so a completing request doesn't pay for one more
-        # forward whose output would be discarded.
-        out: List[Tuple] = []
-        self.obs.loop_phase("barrier")
-        # audit: host-fetch(classic spec path: per-round pending-tau
-        # emit fetch; counted)
-        taus = np.asarray(self.tau)
-        self.host_syncs_total += 1
-        self.spec_host_syncs_total += 1
-        self.obs.loop_phase("emit")
-        # Non-finite guard: a -1 tau is the step programs' sentinel for
-        # "this row's logits contained NaN/Inf" — fail just that request
-        # with a clean error instead of streaming a garbage token.  An
-        # armed ``nan`` fault (chaos drills) poisons the first active
-        # row the same way.
-        forced_nan = self._take_nan()
-        for b, slot in self.slots.items():
-            if slot is None:
-                continue
-            tok = int(taus[b])
-            if tok < 0 or forced_nan:
-                forced_nan = False
-                self._fail_slot(b, self._NONFINITE_MSG)
-                continue
-            slot.emitted.append(tok)
-            self.emitted_total += 1
-            self.spec_emitted_total += 1
-            done = (
-                tok in slot.stop_tokens
-                or len(slot.emitted) >= slot.max_new
-            )
-            if self.logprobs:
-                out.append((
-                    slot.request_id, tok, done, float(self.tau_lp[b])
-                ))
-            else:
-                out.append((slot.request_id, tok, done))
-            if done:
-                self.obs.request_end(slot.request_id, "finished")
-                self._free_slot(b, device_done=True)
-
-        if any(s is not None for s in self.slots.values()):
-            # Injection site "step": fires AFTER the emit/free scan above
-            # — exactly where a real dispatch failure lands, with this
-            # step's events already appended to slot.emitted but never
-            # returned to the caller.  Recovery must therefore replay
-            # from the tokens it DELIVERED, not from slot.emitted (the
-            # server keeps its own per-request token record).
-            # The kernel/spec sites fire after "step" (same dispatch,
-            # finer attribution: their exceptions carry a site name the
-            # degradation layer maps to a quarantinable feature).
-            self.obs.loop_phase("prep")
-            feats: List[str] = ["spec_decode"]
-            if self._spec_kernel_ok():
-                feats.append("paged_kernel")
-            self._record_dispatch(feats)
-            self._fault("step")
-            self._fault("spec_decode")
-            if "paged_kernel" in feats:
-                self._fault("paged_kernel")
-            self.steps_total += 1
-            self.spec_dispatches_total += 1
-            self.spec_rounds_last = 1
-            self._spec_tail(out)
-        self._admit()
-        return out
-
-    def _step_spec_chunked(self) -> List[Tuple]:
-        """Speculative step, fused: ONE ``_spec_rounds_chunk`` dispatch
+        """Speculative step: ONE ``_spec_rounds_chunk`` dispatch
         runs R draft+verify rounds with the pending-tau emit, the
         accepted-prefix emit scan, stop/max_new/non-finite folding and
         the fill rewind all ON DEVICE; the host gets one packed
         [B, R, W] block (each round's G+1 token columns + its
         acceptance count + bitcast logprobs) in ONE fetch and replays
-        it to advance the mirrors and produce the caller's events —
-        token-identically (including the acceptance pattern) to the
-        classic per-round loop.  Both pools and all per-slot decode
-        state are device-resident via the ``d_*`` twins; admission /
+        it to advance the mirrors and produce the caller's events.
+        Both pools and all per-slot decode state are device-resident
+        via the ``d_*`` twins; admission /
         free / cancel sync dirty rows exactly as in ``_step_chunked``,
         so steady state = 1 fetch + 0 uploads per R rounds."""
         admitted = self._admit_dispatches > self._admits_at_last_chunk
@@ -3894,8 +3725,8 @@ class ContinuousBatcher:
                 continue
             if forced_nan:
                 # An armed ``nan`` fault poisons the first active row,
-                # exactly like the classic emit scan; the row's chunk
-                # tokens are discarded.
+                # exactly like ``_step_chunked``'s emit scan; the row's
+                # chunk tokens are discarded.
                 forced_nan = False
                 self._fail_slot(b, self._NONFINITE_MSG)
                 continue
@@ -4000,7 +3831,7 @@ class ContinuousBatcher:
         return out
 
     def _spec_kernel_ok(self) -> bool:
-        """Same kernel-eligibility gate as _paged_decode_step — literally:
+        """Same kernel-eligibility gate as _paged_decode_chunk — literally:
         both call ``_kernel_eligible`` (the T>1 verify adds no
         constraints, it shards identically; the draft model adds its own
         KV-head divisibility)."""
@@ -4008,111 +3839,6 @@ class ContinuousBatcher:
             self.block_size, self.mesh, self.config.kv_heads,
             self.n_slots, draft_config=self.draft_config,
         )
-
-    def _spec_tail(self, out: List[Tuple]) -> None:
-        """Speculative remainder of a step: draft + verify, emit the
-        accepted prefix (appended to ``out``, with per-token logprobs
-        when ``logprobs=True``), rewind fills past rejected slots."""
-        obs_rids = [
-            s.request_id for s in self.slots.values() if s is not None
-        ]
-        all_greedy = bool(np.all(self.temp_arr[self.active] == 0.0))
-        _obs_mod.attribute_compiles(self.obs, "_spec_round")
-        self.obs.dispatch_begin("spec", "_spec_round")
-        t0_obs = time.monotonic()
-        with self.obs.loop_span("dispatch.submit"):
-            (outs, acc, lps, self.keys, self.pool,
-             self.draft_pool) = _spec_round(
-                self.params, self.draft_params, self.pool, self.draft_pool,
-                jnp.array(self.table), jnp.array(self.n_alloc),
-                jnp.array(self.fill), self.tau, jnp.array(self.pos),
-                jnp.array(self.active), self.keys,
-                jnp.array(self.temp_arr), jnp.array(self.top_p_arr),
-                jnp.array(self.top_k_arr),
-                t_config=self.config, d_config=self.draft_config,
-                n_draft=self.n_draft, all_greedy=all_greedy,
-                use_kernel=self._spec_kernel_ok(), mesh=self.mesh,
-                with_logprobs=self.logprobs, placed=self._mesh_placed,
-            )
-        tf_obs = time.monotonic()
-        # audit: host-fetch(classic spec path: per-round outs fetch; counted)
-        outs = np.asarray(outs)
-        # audit: host-fetch(classic spec path: per-round acceptance fetch;
-        # counted)
-        acc = np.asarray(acc)
-        self.host_syncs_total += 2
-        self.spec_host_syncs_total += 2
-        if self.logprobs:
-            # audit: host-fetch(classic spec path: per-round logprobs
-            # fetch; counted)
-            lps = np.asarray(lps)
-            self.host_syncs_total += 1
-            self.spec_host_syncs_total += 1
-        now_obs = time.monotonic()
-        self.obs.record_dispatch(
-            kind="spec", k=1, occupancy=len(obs_rids),
-            wall_ms=(now_obs - t0_obs) * 1000.0,
-            fetch_ms=(now_obs - tf_obs) * 1000.0,
-            swap_inflight=len(self._restoring), rids=obs_rids,
-            program="_spec_round", then="emit",
-        )
-        round_proposed = round_accepted = 0
-        # NOTE: the per-row fill/pos advances below touch the numpy
-        # mirrors only — the CLASSIC (spec_rounds=1) path re-uploads
-        # them every round and never consumes the chunked paths'
-        # device-resident twins.
-        new_tau = np.zeros((self.n_slots,), np.int32)
-        for b, slot in self.slots.items():
-            if slot is None:
-                continue
-            a = int(acc[b])
-            if a < 0:
-                # _spec_round's non-finite sentinel: the row's verify
-                # logits held NaN/Inf; its round was never committed
-                # (all slots invalidated in-jit) — fail just this
-                # request.
-                self._fail_slot(b, self._NONFINITE_MSG)
-                continue
-            self.drafts_proposed += self.n_draft
-            self.drafts_accepted += a
-            round_proposed += self.n_draft
-            round_accepted += a
-            # Emit accepted drafts outs[0..a-1] (== the draft tokens);
-            # outs[a] becomes the next pending token, mirroring the plain
-            # batcher's sampled-but-unemitted tau.
-            done = False
-            for i in range(a):
-                tok = int(outs[b, i])
-                slot.emitted.append(tok)
-                self.emitted_total += 1
-                self.spec_emitted_total += 1
-                done = (
-                    tok in slot.stop_tokens
-                    or len(slot.emitted) >= slot.max_new
-                )
-                if self.logprobs:
-                    out.append((
-                        slot.request_id, tok, done, float(lps[b, i])
-                    ))
-                else:
-                    out.append((slot.request_id, tok, done))
-                if done:
-                    break
-            if done:
-                self.obs.request_end(slot.request_id, "finished")
-                self._free_slot(b)
-            else:
-                new_tau[b] = outs[b, a]
-                if self.logprobs:
-                    # The pending token's logprob travels with it: emitted
-                    # at the next step() from tau_lp, exactly like the
-                    # plain batcher's sampled-but-unemitted tau.
-                    self.tau_lp[b] = float(lps[b, a])
-                self.fill[b] += a + 1
-                self.pos[b] += a + 1
-        if round_proposed:
-            self._accept_window.append((round_proposed, round_accepted))
-        self.tau = self._upload(new_tau)
 
     def run_to_completion(self) -> Dict[int, List[int]]:
         """Drain everything; returns {request_id: emitted tokens}."""
@@ -4788,19 +4514,7 @@ class ContinuousBatcher:
         idx = jnp.asarray(np.asarray(slots, np.int32))
         self.tau = self.tau.at[idx].set(tau[:k])
         if self.logprobs:
-            # Device twin always; the numpy mirror only feeds the
-            # CLASSIC (spec_rounds=1) speculative emit scan — fetching
-            # it costs an admission-time device->host sync neither
-            # chunked path (plain or fused-spec) needs.
             self.d_tau_lp = self.d_tau_lp.at[idx].set(tau_lp[:k])
-            if self.spec and self.spec_rounds == 1:
-                # audit: host-fetch(classic-spec admission: the numpy
-                # tau_lp mirror feeds the per-round emit scan; counted
-                # — was an uncounted sync until the host-boundary lint
-                # flagged it)
-                self.tau_lp[np.asarray(slots)] = np.asarray(tau_lp)[:k]
-                self.host_syncs_total += 1
-                self.spec_host_syncs_total += 1
         self.keys = self.keys.at[idx].set(keys_out[:k])
         for i, (req, chain, hits) in enumerate(grp):
             b = slots[i]
@@ -5532,16 +5246,6 @@ class ContinuousBatcher:
             self.tau = self.tau.at[idx].set(taus[:k])
             if self.logprobs:
                 self.d_tau_lp = self.d_tau_lp.at[idx].set(tau_lps[:k])
-                if self.spec and self.spec_rounds == 1:
-                    # audit: host-fetch(classic-spec admission: numpy
-                    # tau_lp mirror for the per-round emit scan;
-                    # counted — was an uncounted sync until the
-                    # host-boundary lint flagged it)
-                    self.tau_lp[np.asarray(slot_ids)] = (
-                        np.asarray(tau_lps)[:k]
-                    )
-                    self.host_syncs_total += 1
-                    self.spec_host_syncs_total += 1
             self.keys = self.keys.at[idx].set(keys_out[:k])
             tf_obs = time.monotonic()
             # audit: host-fetch(admission-path prompt-length fetch —

@@ -137,8 +137,9 @@ def _greedy(logits: jnp.ndarray) -> jnp.ndarray:
 #
 # ONE implementation of the Leviathan draft-draw / accept / residual rules,
 # traced into both jit contexts that need it: the standalone engine below
-# (B-wide keys, static policies) and the serving batcher's ``_spec_round``
-# (per-row key chains, traced per-row policies, vmapped draws).  Sharing the
+# (B-wide keys, static policies) and the serving batcher's
+# ``_spec_round_core`` (per-row key chains, traced per-row policies,
+# vmapped draws).  Sharing the
 # math is what makes a sampled serving slot emit bit-identically to a
 # standalone B=1 seeded ``generate_speculative`` of the same request — the
 # equivalence is pinned by tests/test_serving_spec.py.
@@ -202,10 +203,10 @@ def place_extra(drafts, acc, extra):
 
 def accepted_emit_counts(acc, stop_hits, remaining):
     """How many of a round's accepted tokens the serving host's emit
-    scan would actually deliver — the ON-DEVICE mirror of the classic
-    per-round loop's token-by-token stop/budget walk over ``outs[:acc]``
-    (``serving.ContinuousBatcher._spec_tail``), so the fused R-round
-    chunk program can fold slot completion mid-chunk without a host
+    scan would actually deliver — the ON-DEVICE form of its
+    token-by-token stop/budget walk over ``outs[:acc]``
+    (``serving.ContinuousBatcher._step_spec``), so the R-round chunk
+    program can fold slot completion mid-chunk without a host
     round-trip.
 
     acc: [B] int32 accepted-prefix lengths (clipped to >= 0).

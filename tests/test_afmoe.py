@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from paged_steps import decode_row
 
 import jax_llama_tpu as jlt
 from jax_llama_tpu import config as config_mod
@@ -94,7 +95,7 @@ def test_forward_matches_the_plain_reference(tiny, attn):
 @pytest.mark.parametrize("use_kernel", [True, False], ids=["paged-kernel", "gathered-view"])
 def test_prefill_then_decode_through_the_paged_cache(tiny, use_kernel):
     """A 96-token prompt (four windows) through `_paged_insert`, eight tokens
-    through `_paged_decode_step`, each step's logits recomputed by the
+    through `_paged_decode_chunk`, each step's logits recomputed by the
     reference's full forward over prompt + served tokens."""
     raw, cfg, params = tiny
     NB, P, G = 16, 96, 8
@@ -108,17 +109,11 @@ def test_prefill_then_decode_through_the_paged_cache(tiny, use_kernel):
         params, pool, ids, toks, jnp.ones((1, P), bool), keys,
         one(0.0, f32), one(1.0, f32), one(0, i32), config=cfg)
     table = jnp.full((1, 8), NB, i32).at[0, :7].set(jnp.arange(7))
-    served = [int(tau[0])]
-    for i in range(G - 1):
-        nxt, _, keys, pool = serving._paged_decode_step(
-            params, pool, table, one(7, i32), one(P + i, i32),
-            jnp.asarray(served[-1:], i32), one(P + i, i32), jnp.ones((1,), bool),
-            keys, one(0.0, f32), one(1.0, f32), one(0, i32), config=cfg,
-            all_greedy=True, allow_kernel=use_kernel)
-        served.append(int(nxt[0]))
+    served, _, stats = decode_row(
+        params, cfg, pool, table, 7, P, int(tau[0]), G - 1, use_kernel=use_kernel)
     assert _deficit(params, raw, [int(t) for t in toks[0]], served).max() < 1e-4
-    # the kernel's step counts rode the pool's counters; the gathered view has none
-    steps = np.asarray(pool.stats)[-2:]
+    # the kernel's step counts rode the packed fetch; the gathered view has none
+    steps = stats[-2:]
     assert (steps > 0).all() if use_kernel else (steps == 0).all()
 
 

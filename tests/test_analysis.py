@@ -11,7 +11,7 @@ Two halves:
   * **Package-cleanliness gates** (tier-1): the REAL package must be
     clean under every static layer, and every jitted program the
     batcher dispatches must hold a registered lowering contract.  The
-    abstract-trace layer (lowers all ten programs at a tiny geometry)
+    abstract-trace layer (lowers all eight programs at a tiny geometry)
     is ``slow``-marked — ``make lint-invariants`` runs it on every
     lint invocation; tier-1 keeps the fast static gates.
 """
@@ -189,12 +189,12 @@ class TestHostBoundary:
         assert rules(fs) == ["host-fetch"] and len(fs) == 2
 
     def test_numpy_mirror_not_flagged(self):
-        # self.tau_lp is the numpy mirror: np.asarray on it is free.
+        # self.remaining is a numpy mirror: np.asarray on it is free.
         src = (
             "import numpy as np\n"
             "class B:\n"
             "    def f(self):\n"
-            "        return np.asarray(self.tau_lp)\n"
+            "        return np.asarray(self.remaining)\n"
         )
         assert self.check(src) == []
 
@@ -323,12 +323,33 @@ class TestLoweringStatic:
         # unregistered jit-decorated function in serving/kvcache; the
         # dispatch sites are a subset of those.
         for name in (
-            "_paged_decode_step", "_paged_decode_chunk", "_fused_chunk",
-            "_spec_round", "_spec_rounds_chunk", "_paged_insert",
-            "_paged_suffix_insert", "_scatter_rows", "_release_blocks",
-            "_adopt_jit",
+            "_paged_decode_chunk", "_fused_chunk", "_spec_rounds_chunk",
+            "_paged_insert", "_paged_suffix_insert", "_scatter_rows",
+            "_release_blocks", "_adopt_jit",
         ):
             assert name in REGISTRY, f"{name} lost its contract"
+
+    def test_every_registered_program_is_dispatched(self):
+        # The converse: a registered program (a contract, a retrace
+        # domain, a host-sync entry, a /metrics label) is one the
+        # serving path calls.  One that only tests reach is a program
+        # every operand change still has to thread through.
+        import ast
+        import inspect
+
+        from jax_llama_tpu import kvcache, serving
+
+        called = set()
+        for mod in (serving, kvcache):
+            for node in ast.walk(ast.parse(inspect.getsource(mod))):
+                if isinstance(node, ast.Call) and isinstance(
+                    node.func, ast.Name
+                ):
+                    called.add(node.func.id)
+        programs = set(serving._programs())
+        assert programs == set(REGISTRY)
+        assert len(programs) == 8
+        assert programs <= called, sorted(programs - called)
 
     def test_unregistered_program_caught(self):
         registry = {
@@ -542,7 +563,7 @@ class TestLoweringTraceFixtures:
 @pytest.mark.slow
 class TestLoweringTracePackage:
     def test_all_contracts_trace_clean(self):
-        # Lowers all ten registered programs at the tiny example
+        # Lowers all eight registered programs at the tiny example
         # geometry: donation resolves, fetch surface within budget,
         # no pool-shaped copy-class equations.  ~30 s cold.
         clear_examples()

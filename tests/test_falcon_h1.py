@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from paged_steps import decode_row
 
 import jax_llama_tpu as jlt
 from jax_llama_tpu import config as config_mod
@@ -145,14 +146,8 @@ def test_decode_through_the_gathered_view_reads_the_references_tokens(tiny):
         one(0.0, f32), one(1.0, f32), one(0, i32), one(0, i32), config=cfg,
         prefill_chunk=32)
     table = jnp.full((1, 8), NB, i32).at[0, :7].set(jnp.arange(7))
-    served = [int(tau[0])]
-    for i in range(G - 1):
-        nxt, _, keys, pool = serving._paged_decode_step(
-            params, pool, table, one(7, i32), one(P + i, i32),
-            jnp.asarray(served[-1:], i32), one(P + i, i32), jnp.ones((1,), bool),
-            keys, one(0.0, f32), one(1.0, f32), one(0, i32), config=cfg,
-            all_greedy=True, allow_kernel=False)
-        served.append(int(nxt[0]))
+    served, _, _ = decode_row(
+        params, cfg, pool, table, 7, P, int(tau[0]), G - 1, use_kernel=False)
     assert _deficit(params, raw, [int(t) for t in toks[0]], served).max() < TOL
 
 
@@ -513,7 +508,6 @@ def _refuse_train(cfg, params):
      "speculative"),
     (_refuse_serve_mesh, "serve-mesh"), (_refuse_train, "training step"),
     (lambda cfg, p: jlt.ContinuousBatcher(p, cfg, n_slots=1, host_kv_blocks=4), "host tier"),
-    (lambda cfg, p: jlt.ContinuousBatcher(p, cfg, n_slots=1, prefix_index="exact"), "exact"),
     (lambda cfg, p: cfg.replace(tie_word_embeddings=True).validate(), "untied"),
     (lambda cfg, p: cfg.replace(mb_per_layer=2).validate(), "two blocks"),
     (lambda cfg, p: cfg.replace(mamba_n_groups=3).validate(), "mamba_n_groups"),
@@ -523,7 +517,7 @@ def _refuse_train(cfg, params):
     (lambda cfg, p: jlt.forward(p, jnp.zeros((1, 4), jnp.int32), jnp.arange(4)[None], cfg,
                                 dropout_rng=jax.random.PRNGKey(0)), "served, not trained"),
 ], ids=["tensor", "int8-kv", "ring", "quantize", "speculation", "serve-mesh", "train",
-        "host-tier", "exact-index", "tied", "two-blocks", "groups", "zones", "scaled-rope",
+        "host-tier", "tied", "two-blocks", "groups", "zones", "scaled-rope",
         "pool-without-slots", "dropout"])
 def test_unsupported_combination_is_refused_by_name(tiny, attempt, named):
     _, cfg, params = tiny

@@ -9,7 +9,6 @@ import json
 import pytest
 
 from jax_llama_tpu.kvcache import (
-    ExactPrefixStore,
     KvDigest,
     NullPrefixStore,
     RadixPrefixStore,
@@ -155,34 +154,6 @@ def test_nodes_json_bounded_at_max_occupancy():
     assert len(shallow["nodes"]) == 8
     assert shallow["truncated"] == 0
     assert all(e["depth"] <= 8 for e in shallow["nodes"])
-
-
-def test_exact_store_digest_parity_surface():
-    """The legacy flat map exposes the same digest surface: versioned
-    publishes, supersede keeps the key, unpublish removes it."""
-    store = ExactPrefixStore()
-    store.publish(_chain(0, 3), [0, 1, 2])
-    s = store.digest.summary()
-    assert s["nodes"] == 3 and s["publishes_total"] == 3
-    # Supersede: same keys, new blocks — content keys unchanged.
-    h0 = s["hash"]
-    store.retain([0, 1, 2])
-    store.publish(_chain(0, 3), [4, 5, 6])
-    s = store.digest.summary()
-    assert s["nodes"] == 3 and s["hash"] == h0
-    assert s["version"] > 3
-    store.unpublish(4)
-    assert store.digest.summary()["nodes"] == 2
-    # Supersede of an IDLE old block by a freshly claimed one clears
-    # the digest's idle flag (review fix: the store's truth is
-    # claimed, and the gauge must not call a live block evictable).
-    s2 = ExactPrefixStore()
-    s2.publish([_key(9)], [0])
-    s2.retain([0])
-    assert s2.digest.summary()["idle_blocks"] == 1
-    s2.publish([_key(9)], [5])  # supersede with a claimed block
-    assert s2.evictable() == 0
-    assert s2.digest.summary()["idle_blocks"] == 0
 
 
 def test_null_store_digest_stays_empty():

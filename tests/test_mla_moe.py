@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from paged_steps import decode_row
 
 import jax_llama_tpu as jlt
 from jax_llama_tpu import config as config_mod
@@ -77,7 +78,7 @@ def test_forward_matches_the_plain_reference(tiny):
 
 @pytest.mark.parametrize("use_kernel", [True, False], ids=["paged-kernel", "gathered-view"])
 def test_prefill_then_decode_through_the_paged_latent_cache(tiny, use_kernel):
-    """Prompt through `_paged_insert`, six tokens through `_paged_decode_step`
+    """Prompt through `_paged_insert`, six tokens through `_paged_decode_chunk`
     (the absorbed form over the latent pool), each step's logits recomputed by
     the reference's full forward over prompt + served tokens."""
     raw, cfg, params = tiny
@@ -92,14 +93,8 @@ def test_prefill_then_decode_through_the_paged_latent_cache(tiny, use_kernel):
         params, pool, ids, toks, jnp.ones((1, P), bool), keys,
         one(0.0, f32), one(1.0, f32), one(0, i32), config=cfg)
     table = jnp.full((1, 8), NB, i32).at[0, :5].set(jnp.arange(5))
-    served = [int(tau[0])]
-    for i in range(G - 1):
-        nxt, _, keys, pool = serving._paged_decode_step(
-            params, pool, table, one(5, i32), one(P + i, i32),
-            jnp.asarray(served[-1:], i32), one(P + i, i32), jnp.ones((1,), bool),
-            keys, one(0.0, f32), one(1.0, f32), one(0, i32), config=cfg,
-            all_greedy=True, allow_kernel=use_kernel)
-        served.append(int(nxt[0]))
+    served, _, _ = decode_row(
+        params, cfg, pool, table, 5, P, int(tau[0]), G - 1, use_kernel=use_kernel)
     full = jnp.concatenate([toks, jnp.asarray([served], toks.dtype)], axis=1)
     ref = np.asarray(_reference().logits(params, full, raw, P - 1))[0, :G]
     deficit = ref.max(axis=1) - ref[np.arange(G), served]

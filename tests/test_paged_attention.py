@@ -615,7 +615,7 @@ def test_int8_batcher_kernel_path_runs_end_to_end():
             n_slots=2, max_len=128, block_size=16,
         )
         # block_size 16 (% 8 == 0) routes the decode dispatch (the
-        # fused chunk program; _paged_decode_step body at K=1) through
+        # fused chunk program; _decode_step_core at K=1) through
         # the Pallas kernel (kernel-vs-gathered equivalence is tested
         # above).
         rids = [cb.submit(p, max_new_tokens=10) for p in prompts]

@@ -361,37 +361,6 @@ def test_suffix_admission_buckets_jit_executables(model):
 # that would otherwise perturb test_suffix_admission_buckets' compile
 # count (the jit cache is cleared per MODULE, not per test).
 
-def test_exact_mode_supersede_frees_idle_duplicates(model):
-    """The legacy flat-map semantics survive behind
-    ``prefix_index="exact"`` (the behavioral oracle): a duplicate
-    publication SUPERSEDES, and re-keying a chain whose old block sits
-    refcount-0 in the idle LRU frees it outright — the pre-radix pin,
-    verbatim, one flag away."""
-    params, config = model
-    rng = np.random.RandomState(11)
-    prompt = rng.randint(1, 128, size=40).tolist()
-
-    cb = ContinuousBatcher(params, config, n_slots=2, max_len=128,
-                           block_size=16, prefix_index="exact")
-    r1 = cb.submit(list(prompt), max_new_tokens=4)
-    r2 = cb.submit(list(prompt), max_new_tokens=4)
-    res = cb.run_to_completion()
-    assert res[r1] == res[r2]
-    store = cb._store
-    assert set(store._reusable) <= set(store._prefix_index.values())
-    assert len(cb.free_blocks) + len(store._reusable) == cb.n_blocks
-    assert not cb._block_refs
-
-    key = next(iter(store._prefix_index))
-    old_blk = store._prefix_index[key]
-    assert old_blk in store._reusable
-    new_blk = cb.free_blocks[0]
-    cb._register_chain([new_blk], [key])
-    assert old_blk not in store._reusable
-    assert old_blk in cb.free_blocks
-    assert store._prefix_index[key] == new_blk
-
-
 def test_radix_partial_prefix_shared_across_divergent_chains(model):
     """The radix win the flat map could not express as sharing: three
     chains diverging AFTER a common 2-block prefix share those two

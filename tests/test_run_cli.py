@@ -376,6 +376,36 @@ def test_every_server_key_of_a_cell_is_an_option_of_run(workload):
         assert set(block["server"]) <= known, set(block["server"]) - known
 
 
+def test_the_prefix_cache_is_one_switch_and_a_bare_namespace_is_the_parsers_defaults():
+    """`--no-prefix-cache` is the one option of the prefix cache (the server
+    still reports `prefix_index`: `radix` or `off`), and the one place run.py
+    builds a batcher fills what a bare namespace leaves out from the parser,
+    not from literals of its own."""
+    from types import SimpleNamespace
+
+    from jax_llama_tpu.tokenizers.bytes import ByteTokenizer
+
+    parser = run_cli._parser()
+    assert "prefix_index" not in {a.dest for a in parser._actions}
+    assert not any("--prefix-index" in a.option_strings for a in parser._actions)
+    config = get_config(
+        "tiny", vocab_size=ByteTokenizer().n_words, dim=32, n_layers=1,
+        n_heads=2, n_kv_heads=1, multiple_of=32, max_seq_len=64)
+    params = init_params(jax.random.PRNGKey(0), config)
+
+    def build(**server):
+        args = SimpleNamespace(slots=2, temperature=0.0, top_p=0.95, seed=0, **server)
+        return run_cli._make_batcher(
+            params, config, ByteTokenizer(), None, args, seed=args.seed,
+            draft_params=None, draft_config=None).describe()
+
+    bare, off = build(), build(no_prefix_cache=True, decode_chunk=2)
+    assert bare["prefix_index"] == "radix" and off["prefix_index"] == "off"
+    for key in ("decode_chunk", "prefill_budget", "spec_rounds", "host_kv_blocks"):
+        assert bare[key] == parser.get_default(key), key
+    assert off["decode_chunk"] == 2
+
+
 LOWERING_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from paged_steps import decode_row
 
 import jax_llama_tpu as jlt
 from jax_llama_tpu import config as config_mod
@@ -94,7 +95,7 @@ def test_forward_matches_the_plain_reference(tiny, attn):
 def test_prefill_in_chunks_then_decode_through_the_paged_cache(tiny, use_kernel):
     """A 96-token prompt through `_paged_insert` in three 32-token chunks (the
     state handed from chunk to chunk), eight tokens through
-    `_paged_decode_step` over the pool and the per-slot state, each step's
+    `_paged_decode_chunk` over the pool and the per-slot state, each step's
     logits recomputed by the reference's full forward."""
     raw, cfg, params = tiny
     NB, P, G = 16, 96, 8
@@ -110,17 +111,11 @@ def test_prefill_in_chunks_then_decode_through_the_paged_cache(tiny, use_kernel)
         prefill_chunk=32)
     assert float(jnp.abs(pool.ssm).max()) > 0 and float(jnp.abs(pool.conv).max()) > 0
     table = jnp.full((1, 8), NB, i32).at[0, :7].set(jnp.arange(7))
-    served = [int(tau[0])]
-    for i in range(G - 1):
-        nxt, _, keys, pool = serving._paged_decode_step(
-            params, pool, table, one(7, i32), one(P + i, i32),
-            jnp.asarray(served[-1:], i32), one(P + i, i32), jnp.ones((1,), bool),
-            keys, one(0.0, f32), one(1.0, f32), one(0, i32), config=cfg,
-            all_greedy=True, allow_kernel=use_kernel)
-        served.append(int(nxt[0]))
+    served, _, stats = decode_row(
+        params, cfg, pool, table, 7, P, int(tau[0]), G - 1, use_kernel=use_kernel)
     assert _deficit(params, raw, [int(t) for t in toks[0]], served).max() < TOL
     # window and full steps, the cross layers with the full one: 2 and 2 here
-    steps = np.asarray(pool.stats)[-2:]
+    steps = stats[-2:]
     assert (steps > 0).all() and steps[0] == steps[1] if use_kernel else (steps == 0).all()
 
 
@@ -458,13 +453,12 @@ def _refuse_train(cfg, params):
      "speculative"),
     (_refuse_serve_mesh, "serve-mesh"), (_refuse_train, "training step"),
     (lambda cfg, p: jlt.ContinuousBatcher(p, cfg, n_slots=1, host_kv_blocks=4), "host tier"),
-    (lambda cfg, p: jlt.ContinuousBatcher(p, cfg, n_slots=1, prefix_index="exact"), "exact"),
     (lambda cfg, p: cfg.replace(sliding_window=0).validate(), "sliding_window > 0"),
     (lambda cfg, p: cfg.replace(tie_word_embeddings=False).validate(), "tied"),
     (lambda cfg, p: cfg.replace(n_layers=6).validate(), "multiple of 4"),
     (lambda cfg, p: serving.init_pool(cfg, 8, BLK), "n_slots"),
 ], ids=["tensor", "int8-kv", "ring", "quantize", "speculation", "serve-mesh", "train",
-        "host-tier", "exact-index", "no-window", "untied", "odd-depth", "pool-without-slots"])
+        "host-tier", "no-window", "untied", "odd-depth", "pool-without-slots"])
 def test_unsupported_combination_is_refused_by_name(tiny, attempt, named):
     _, cfg, params = tiny
     with pytest.raises((ValueError, NotImplementedError), match=named):

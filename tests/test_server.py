@@ -872,7 +872,7 @@ def test_metrics_exposition_valid_prometheus(model):
         'llm_jit_cache_entries{program="_paged_decode_chunk"}'
         in cache_progs
     )
-    assert len(cache_progs) == 10  # all registered serving programs
+    assert len(cache_progs) == 8  # all registered serving programs
     assert samples["llm_compiles_total"] >= 0
     # Loop phases: the measured host share of a step, by phase.
     assert types["llm_loop_phase_ms_total"] == "counter"

@@ -2,13 +2,14 @@
 token-identical to the plain greedy batcher — the draft model only changes
 speed (acceptance), never content.
 
-Fused R-round chunking (``spec_rounds`` > 1, ``_spec_rounds_chunk``)
-must additionally be token-identical to the classic per-round loop —
-including the ACCEPTANCE PATTERN (drafts proposed/accepted) and
-per-token logprobs — across greedy/seeded-sampled policies, stop tokens
-and max_new landing mid-chunk, non-finite logits mid-chunk, and the
-int8-KV pool; and the crash-recovery / non-finite-guard / quarantine
-semantics proven for the per-round loop must hold with round fusion
+One program (``_spec_rounds_chunk``) serves every ``spec_rounds``: R = 1
+is held to the standalone references (``engine.generate``,
+``engine.score``, ``spec_decode.generate_speculative``), and R > 1 must
+be token-identical to R = 1 — including the ACCEPTANCE PATTERN (drafts
+proposed/accepted) and per-token logprobs — across greedy/seeded-sampled
+policies, stop tokens and max_new landing mid-chunk, non-finite logits
+mid-chunk, and the int8-KV pool; and the crash-recovery /
+non-finite-guard / quarantine semantics must hold with round fusion
 (fault sites fire once per R-round chunk dispatch, replay works from
 delivered tokens, quarantine falls back to plain CHUNKED decode with
 the decode_chunk / spec_rounds configuration preserved)."""
@@ -110,6 +111,85 @@ def test_spec_batcher_stop_tokens(models):
     assert results[rid] == pres_stop[0]
     assert not cb.pending()
     assert sorted(cb.free_blocks) == list(range(cb.n_blocks))
+
+
+def test_one_round_a_dispatch_is_the_chunk_program_and_reads_engine_generate(models):
+    """``spec_rounds=1`` (the constructor's default) is
+    ``_spec_rounds_chunk`` at ``n_rounds=1``: every speculative dispatch
+    is that program at k = 1 and pays its one packed fetch — nothing a
+    round beside it — and the tokens are ``engine.generate``'s greedy
+    ones."""
+    import jax.numpy as jnp
+
+    from jax_llama_tpu.engine import GenerationConfig, generate
+
+    params, config, draft_params, draft_config = models
+    prompt, max_new = [5, 17, 99, 3, 42, 8, 61, 2], 10
+    cb = ContinuousBatcher(
+        params, config, n_slots=1, max_len=64,
+        draft_params=draft_params, draft_config=draft_config, n_draft=3,
+    )
+    assert cb.spec_rounds == 1
+    rid = cb.submit(prompt, max_new_tokens=max_new)
+    got = [t for (_, t, *_) in cb.step()]      # the admission's dispatch
+    fetched, dispatched = cb.spec_host_syncs_total, cb.spec_dispatches_total
+    while cb.pending():
+        got += [t for (_, t, *_) in cb.step()]
+    spec = [d for d in cb.obs.dispatches if d["kind"] == "spec"]
+    assert len(spec) == cb.spec_dispatches_total > 2
+    assert {(d["program"], d["k"]) for d in spec} == {("_spec_rounds_chunk", 1)}
+    # past the admission's error barrier: a fetch a dispatch
+    assert (cb.spec_host_syncs_total - fetched
+            == cb.spec_dispatches_total - dispatched > 0)
+    assert cb.drafts_proposed > 0
+
+    gc = GenerationConfig(max_new_tokens=max_new, temperature=0.0,
+                          stop_tokens=(), pad_id=0)
+    want = np.asarray(generate(
+        params, jnp.asarray([prompt], jnp.int32),
+        jnp.ones((1, len(prompt)), bool), jax.random.PRNGKey(0),
+        config=config, gen_config=gc))[0, len(prompt):]
+    assert got == want.tolist()
+    assert rid == 0 and not cb.pending()
+
+
+def test_one_round_a_dispatch_logprobs_are_engine_scores_and_stay_on_the_device(models):
+    """Logprobs at ``spec_rounds=1``: every emitted token's is
+    ``engine.score``'s at its position, and the pending token's never
+    leaves the device outside the packed fetch — the admission counts the
+    one fetch it counts without logprobs (the prompt lengths'), and a whole
+    run as many fetches as without."""
+    import jax.numpy as jnp
+
+    from jax_llama_tpu.engine import score
+
+    params, config, draft_params, draft_config = models
+    prompt, max_new = [5, 17, 99, 3, 42, 8, 61, 2], 8
+
+    def run(logprobs):
+        cb = ContinuousBatcher(
+            params, config, n_slots=1, max_len=64, logprobs=logprobs,
+            draft_params=draft_params, draft_config=draft_config, n_draft=3,
+        )
+        cb.submit(prompt, max_new_tokens=max_new)
+        cb._admit()
+        admission = cb.host_syncs_total
+        toks, lps = [], []
+        while cb.pending():
+            for _, tok, _, *rest in cb.step():
+                toks.append(tok)
+                lps += rest
+        return admission, cb.host_syncs_total, toks, lps
+
+    admission, total, toks, lps = run(True)
+    plain_admission, plain_total, plain_toks, _ = run(False)
+    assert admission == plain_admission == 1
+    assert total == plain_total
+    assert toks == plain_toks and len(lps) == len(toks) == max_new
+    sc = np.asarray(score(
+        params, jnp.asarray([prompt + toks], jnp.int32), config=config))[0]
+    want = [float(sc[len(prompt) + i - 1]) for i in range(len(toks))]
+    np.testing.assert_allclose(lps, want, atol=1e-4, rtol=1e-4)
 
 
 @pytest.mark.slow  # interpret-mode Pallas / long decode on CPU; out of the tier-1 budget (plain `pytest tests/` still runs it)
@@ -305,7 +385,7 @@ def _spec_matrix(models, R, *, logprobs=False, stop=(), int8=False,
 
 # Both cells ride the slow tier (r06 rebalanced R=2 out; r08 moved
 # R=4 too — at ~30 s it was the single heaviest tier-1 test while the
-# suite sat within 1% of its 870 s budget).  The R>1 ≡ classic
+# suite sat within 1% of its 870 s budget).  The R>1 ≡ R=1
 # identity class keeps tier-1 coverage through the stop-token /
 # non-finite mid-chunk cells below and the perf-smoke spec matrix;
 # the full greedy+sampled+acceptance-pattern matrix still runs in the
@@ -316,8 +396,8 @@ def _spec_matrix(models, R, *, logprobs=False, stop=(), int8=False,
 ])
 def test_spec_rounds_token_identity_greedy_and_sampled(models, R):
     """R ∈ {2, 4} × {greedy, seeded-sampled} × max_new mid-chunk:
-    tokens AND the acceptance pattern identical to the classic
-    per-round loop (which the tests above pin against standalone
+    tokens AND the acceptance pattern identical to one round a
+    dispatch (which the tests above pin against standalone
     engine/spec oracles)."""
     base, _, base_acc = _spec_matrix(models, 1)
     got, _, got_acc = _spec_matrix(models, R)
@@ -327,8 +407,8 @@ def test_spec_rounds_token_identity_greedy_and_sampled(models, R):
 
 @pytest.mark.slow  # interpret-mode Pallas / long decode on CPU; out of the tier-1 budget (plain `pytest tests/` still runs it)
 def test_spec_rounds_token_identity_logprobs(models):
-    """logprobs ride the packed fetch bitcast: same values as the
-    classic loop, token for token, for carried-tau, accepted-draft and
+    """logprobs ride the packed fetch bitcast: same values as one round
+    a dispatch, token for token, for carried-tau, accepted-draft and
     replacement/bonus emissions alike."""
     base, base_lp, _ = _spec_matrix(models, 1, logprobs=True)
     got, got_lp, _ = _spec_matrix(models, 4, logprobs=True)
@@ -372,7 +452,7 @@ def test_spec_rounds_stop_token_mid_chunk(models):
 def test_spec_rounds_int8_kv(models):
     """The int8 pools' quantized branches (per-round scale-plane writes
     for BOTH the target and draft pools inside the scan) must match
-    their classic per-round emissions."""
+    their emissions at one round a dispatch."""
     base, _, base_acc = _spec_matrix(models, 1, int8=True)
     got, _, got_acc = _spec_matrix(models, 4, int8=True)
     assert got == base
@@ -382,8 +462,7 @@ def test_spec_rounds_int8_kv(models):
 def test_spec_rounds_nonfinite_mid_chunk(models):
     """NaN target logits under round fusion: the verify's -1 acceptance
     sentinel folds the row out mid-chunk, the round is never committed,
-    and exactly that request fails — same contract as the classic
-    loop's guard."""
+    and exactly that request fails."""
     params, config, _, _ = models
     bad = dict(params)
     bad["lm_head"] = params["lm_head"] * float("nan")
@@ -441,7 +520,7 @@ def reference(models):
 
 @pytest.mark.faults
 # slow (r06 budget rebalance, ~12 s): still in `make faults` / `make
-# chaos`; the classic-path spec fault drills keep tier-1 coverage.
+# chaos`; the R = 1 spec drills (tests/test_degrade.py) keep tier-1 coverage.
 @pytest.mark.slow
 def test_chunked_spec_fault_recovers_token_exact(models, reference):
     """A spec_decode-site fault mid-chunk (the site fires once per

@@ -232,9 +232,10 @@ def test_model_decode_on_chip_flash_vs_xla():
     assert (out_auto[:, : 32 + 4] == out_xla[:, : 32 + 4]).all()
 
 
-def test_paged_decode_step_no_full_pool_copies_compiled():
-    """Two r4 wins, pinned against regression in the COMPILED decode
-    step's optimized HLO:
+def test_paged_decode_chunk_no_full_pool_copies_compiled():
+    """Three wins, pinned against regression in the COMPILED decode
+    chunk's optimized HLO (n_iter=4, the device-resident state args the
+    batcher actually dispatches):
 
     * the batched pool scatter used to make XLA:TPU relayout the whole
       KV pool to a KVH-minor layout and back every step (four full-pool
@@ -243,58 +244,18 @@ def test_paged_decode_step_no_full_pool_copies_compiled():
     * the layer scan used to materialize every layer's pool plane as a
       dynamic-slice copy feeding the kernel's custom-call operand
       (~3x the kernel's own time at 16k) — replaced by the
-      layer-indexed kernel reading the full pool in place.
+      layer-indexed kernel reading the full pool in place;
+    * the pool rides the decode scan as a donated carry, and the classic
+      way THAT breaks is XLA materializing a pool-sized copy at the scan
+      boundary — which would double KV HBM and regress ~ms/step
+      silently.
 
-    Either regression reappears as a `copy` / dynamic-slice fusion of a
+    Each regression reappears as a `copy` / dynamic-slice fusion of a
     pool-sized [L, KVH, NB, BLK, d] (or one-layer [KVH, NB, BLK, d])
-    array in the HLO text, so assert there is none.
-    """
-    from jax_llama_tpu import get_config, init_params
-    from jax_llama_tpu.serving import ContinuousBatcher
-
-    # bf16 params: the serving dtype.  (An fp32 pool additionally gets a
-    # pair of async memory-space staging copies from XLA:TPU that are
-    # unrelated to either regression guarded here.)
-    cfg = get_config(
-        "tiny", dim=256, n_layers=4, n_heads=4, n_kv_heads=2,
-        vocab_size=512, max_seq_len=256, param_dtype="bfloat16",
-    )
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    cb = ContinuousBatcher(params, cfg, n_slots=4, max_len=256,
-                           block_size=32)
-    rng = np.random.RandomState(5)
-    for _ in range(4):
-        cb.submit(list(rng.randint(1, cfg.vocab_size, 100)),
-                  max_new_tokens=4)
-    cb.step()  # admission; decode-step program now has concrete args
-
-    from jax_llama_tpu import serving as srv
-
-    L, KVH = cfg.n_layers, cfg.kv_heads
-    NB, BLK = cb.pool.pos.shape
-    d = cfg.head_dim
-    lowered = srv._paged_decode_step.lower(
-        cb.params, cb.pool, jnp.array(cb.table), jnp.array(cb.n_alloc),
-        jnp.array(cb.fill), cb.tau, jnp.array(cb.pos),
-        jnp.array(cb.active), cb.keys, jnp.array(cb.temp_arr),
-        jnp.array(cb.top_p_arr), jnp.array(cb.top_k_arr),
-        config=cb.config, all_greedy=True, mesh=None, allow_kernel=True,
-        with_logprobs=False,
-    )
-    txt = lowered.compile().as_text()
-    offenders = _pool_copy_offenders(txt, (L, KVH, NB, BLK, d))
-    assert not offenders, offenders
-
-
-def test_paged_decode_chunk_no_full_pool_copies_compiled():
-    """The fused K-iteration chunk program (the serving hot path since
-    chunked decode) must uphold the same no-full-pool-copy invariant as
-    the single-step program above: the pool rides the decode scan as a
-    donated carry, and the classic way THAT breaks is XLA materializing
-    a pool-sized copy at the scan boundary — which would double KV HBM
-    and regress ~ms/step silently.  Same HLO-text assertion, against the
-    n_iter=4 chunk executable with the device-resident state args the
-    batcher actually dispatches."""
+    array in the HLO text, so assert there is none.  bf16 params: the
+    serving dtype.  (An fp32 pool additionally gets a pair of async
+    memory-space staging copies from XLA:TPU that are unrelated to the
+    regressions guarded here.)"""
     from jax_llama_tpu import get_config, init_params
     from jax_llama_tpu.serving import ContinuousBatcher
 
