@@ -167,6 +167,22 @@ class LLaMAConfig:
     ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_multipliers: Tuple[float, ...] = (1.0, 1.0)
 
+    # --- learned sparse attention: a query attends the `index_topk` keys an
+    # indexer scores highest.  0: none.  > 0 selects the block of
+    # models/dsa_moe.py (KeyeVL2's language model: DeepSeek-Sparse-Attention
+    # inside rotary GQA with per-head q/k norms): beside q, k, v every layer
+    # projects `index_n_heads` index queries of `index_head_dim`, ONE index
+    # key and a weight a head; I[t, s] = sum_j w[t, j] relu(qI[t, j] . kI[s])
+    # ranks the causal keys and attention is the softmax over the top
+    # `index_topk` (ties to the lower position; every key while the context
+    # is no longer than that).  The index keys are a third cache plane a
+    # layer beside K and V.  Every layer's FFN is routed experts
+    # (`first_k_dense` 0, no shared expert), scored by `moe_score_func`.
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    moe_score_func: str = "sigmoid"       # "sigmoid" | "softmax" (ops/moe.route)
+
     def __post_init__(self):
         # JSON (a checkpoint's config.json) hands a tuple back as a list,
         # which neither compares equal nor hashes as a static argument.
@@ -267,6 +283,11 @@ class LLaMAConfig:
         return self.window_layers is not None
 
     @property
+    def sparse_attention(self) -> bool:
+        """Learned key selection: an index-key plane beside K and V."""
+        return self.index_topk > 0
+
+    @property
     def latent_attention(self) -> bool:
         return self.kv_lora_rank > 0
 
@@ -276,10 +297,13 @@ class LLaMAConfig:
 
     @property
     def cache_heads(self) -> int:
-        """Heads of one cached row: the KV heads, 1 for the latent, or the
-        KV head PAIRS of differential attention (`[k1 | k2]` a row)."""
+        """Heads of one cached row: the KV heads, 1 for the latent and for
+        sparse attention, or the KV head PAIRS of differential attention
+        (`[k1 | k2]` a row)."""
         if self.mb_per_layer > 0:
             return self.kv_heads // 2
+        if self.sparse_attention:
+            return 1      # a token's KV heads side by side: see `cache_width`
         return 1 if self.latent_attention else self.kv_heads
 
     @property
@@ -297,6 +321,12 @@ class LLaMAConfig:
             return -(-self.latent_dim // 128) * 128
         if self.mb_per_layer > 0:
             return 2 * self.head_dim
+        if self.sparse_attention:
+            # One row a token holds every KV head's key (or value): a decode
+            # row gathers the slots its selection chose, and a gather costs by
+            # the row — 2,048 rows of 1 KiB here where head-major planes would
+            # make it 8,192 of 256 B (v5e: ~10 ns a row, PERF.md section 6).
+            return self.kv_heads * self.head_dim
         return self.head_dim
 
     @property
@@ -329,7 +359,9 @@ class LLaMAConfig:
         assert self.head_size is not None or self.dim % self.n_heads == 0, (
             "n_heads must divide dim (or head_size be given)"
         )
-        if self.parallel_mixer:
+        if self.sparse_attention:
+            self._validate_sparse()
+        elif self.parallel_mixer:
             self._validate_parallel_mixer()
         elif self.recurrent_state:
             self._validate_recurrent()
@@ -364,6 +396,8 @@ class LLaMAConfig:
         """The block beside the dense one a configuration selects, as error
         messages name it; None for the dense block.  None of them gets a
         mesh, int8, speculation or the train step yet."""
+        if self.sparse_attention:
+            return "learned sparse attention"
         if self.parallel_mixer:
             return "parallel mixer and attention layers"
         if self.recurrent_state:
@@ -402,6 +436,44 @@ class LLaMAConfig:
                 "tie_word_embeddings / use_scaled_rope are not supported "
                 f"with {block}"
             )
+
+    def _validate_sparse(self) -> None:
+        """The block with learned sparse attention: what it needs, and what
+        it does not get yet — refused by name here, never served wrongly."""
+        block = self.expert_block
+        if (self.recurrent_state or self.latent_attention
+                or self.windowed_attention):
+            raise ValueError(
+                "index_topk beside mamba_d_ssm / mb_per_layer / kv_lora_rank / "
+                "window_layers: two blocks in one configuration")
+        for name in ("index_n_heads", "index_head_dim", "n_routed_experts",
+                     "n_experts_per_tok", "moe_intermediate_size"):
+            if getattr(self, name) <= 0:
+                raise ValueError(f"{block} needs {name} > 0")
+        if self.index_head_dim % 4:
+            raise ValueError(
+                f"index_head_dim: {self.index_head_dim!r}; the leading half "
+                "of an index head is rotated (a multiple of 4)")
+        if self.first_k_dense or self.n_shared_experts:
+            raise ValueError(
+                f"the block with {block} has routed experts in every layer "
+                "and no shared expert (first_k_dense and n_shared_experts 0)")
+        if self.n_experts_per_tok > self.n_routed_experts:
+            raise ValueError("n_experts_per_tok exceeds n_routed_experts")
+        if self.moe_score_func not in ("sigmoid", "softmax"):
+            raise ValueError(
+                f"moe_score_func: {self.moe_score_func!r} is not in the "
+                "program; a router scores by 'sigmoid' or 'softmax'")
+        if self.kv_cache_dtype == "int8":
+            raise ValueError(
+                f"kv_cache_dtype='int8' is not supported with {block}: the "
+                "index-key plane has no int8 form")
+        if self.attn_impl == "ring":
+            raise ValueError(f"attn_impl='ring' is not supported with {block}")
+        if self.tie_word_embeddings or self.use_scaled_rope:
+            raise ValueError(
+                "tie_word_embeddings / use_scaled_rope are not supported "
+                f"with {block}")
 
     def _validate_recurrent(self) -> None:
         """The block with recurrent state layers: what it needs, and what
@@ -679,6 +751,110 @@ _PUBLISHED_PARALLEL_FIXED = {
 _PUBLISHED_PARALLEL_LISTS = {"ssm_multipliers": 5, "mlp_multipliers": 2}
 
 
+# the KeyeVL2 block (learned sparse attention inside rotary GQA, softmax-routed
+# experts in every layer): published key -> field.  Every key is needed.
+_PUBLISHED_SPARSE = {
+    "hidden_size": "dim", "num_hidden_layers": "n_layers",
+    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
+    "head_dim": "head_size", "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta", "rms_norm_eps": "rms_norm_eps",
+    "tie_word_embeddings": "tie_word_embeddings",
+    "num_experts": "n_routed_experts",
+    "num_experts_per_tok": "n_experts_per_tok",
+    "moe_intermediate_size": "moe_intermediate_size",
+}
+# `sa_config`'s keys -> fields; its tiling keys are accepted as published
+_PUBLISHED_SPARSE_INDEXER = {
+    "topk": "index_topk", "indexer_num_heads": "index_n_heads",
+    "indexer_head_dim": "index_head_dim",
+}
+_PUBLISHED_SPARSE_INDEXER_FIXED = {
+    "indexer_num_kv_heads": 1, "q_chunk_size": 512, "kv_chunk_size": 512,
+}
+# its keys with ONE accepted value: the block as the program computes it.
+# With text positions the three rope streams of `mrope_section` are equal, so
+# the published `rope_scaling` object is plain rope and is accepted at
+# exactly that value; `intermediate_size` is the width of the dense layers
+# `mlp_only_layers` would name, of which there are none.
+_PUBLISHED_SPARSE_FIXED = {
+    "model_type": "KeyeVL2", "hidden_act": "silu", "attention_bias": False,
+    "norm_topk_prob": True, "decoder_sparse_step": 1, "mlp_only_layers": [],
+    "tie_word_embeddings": False, "sliding_window": None,
+    "use_sliding_window": False,
+    "rope_scaling": {"mrope_section": [16, 24, 24], "rope_type": "default",
+                     "type": "default"},
+}
+# accepted at any value, unused: the server's own `max_seq_len` bounds a row,
+# no layer is a window layer (`use_sliding_window` false) or a dense one
+_PUBLISHED_SPARSE_UNUSED = ("max_position_embeddings", "max_window_layers",
+                            "intermediate_size")
+
+
+def _from_published_sparse(raw, *, max_seq_len: int, attn_impl: str) -> "LLaMAConfig":
+    """`from_published` for a file with `sa_config` (the KeyeVL2 block), as
+    strict as the others."""
+    fields = _PUBLISHED_SPARSE
+    known = (set(fields) | set(_PUBLISHED_SPARSE_FIXED)
+             | set(_PUBLISHED_SPARSE_UNUSED)
+             | {"sa_config", "num_local_experts", "torch_dtype"})
+    unknown = sorted(set(raw) - known)
+    if unknown:
+        raise ValueError(f"the program understands no published key {', '.join(map(repr, unknown))}")
+    missing = sorted(k for k in (*fields, *_PUBLISHED_SPARSE_FIXED, "sa_config",
+                                 "torch_dtype") if k not in raw)
+    if missing:
+        raise ValueError(
+            f"published key {missing[0]!r} is missing (a file with "
+            "'sa_config' is the block with learned sparse attention, which "
+            "needs it)")
+    for key, only in _PUBLISHED_SPARSE_FIXED.items():
+        if raw[key] != only or isinstance(raw[key], bool) != isinstance(only, bool):
+            raise ValueError(
+                f"{key}: {raw[key]!r} is not in the program; its block with "
+                f"learned sparse attention computes {only!r} only")
+    sa = raw["sa_config"]
+    if not isinstance(sa, dict):
+        raise ValueError(f"sa_config: {sa!r} is not an object")
+    sa_known = set(_PUBLISHED_SPARSE_INDEXER) | set(_PUBLISHED_SPARSE_INDEXER_FIXED)
+    if set(sa) != sa_known:
+        odd = sorted(set(sa) ^ sa_known)
+        raise ValueError(
+            f"sa_config.{odd[0]}: the indexer is given by exactly "
+            f"{sorted(sa_known)}")
+    for key, only in _PUBLISHED_SPARSE_INDEXER_FIXED.items():
+        if sa[key] != only or isinstance(sa[key], bool):
+            raise ValueError(
+                f"sa_config.{key}: {sa[key]!r} is not in the program; its "
+                f"indexer computes {only!r} only")
+    for where, keys in ((raw, ("hidden_size", "num_hidden_layers",
+                               "num_attention_heads", "num_key_value_heads",
+                               "head_dim", "vocab_size", "num_experts",
+                               "num_experts_per_tok", "moe_intermediate_size")),
+                        (sa, tuple(_PUBLISHED_SPARSE_INDEXER))):
+        for key in keys:
+            v = where[key]
+            if not isinstance(v, int) or isinstance(v, bool) or v <= 0:
+                name = key if where is raw else f"sa_config.{key}"
+                raise ValueError(f"{name}: {v!r} is not an int > 0")
+    if raw.get("num_local_experts", raw["num_experts"]) != raw["num_experts"]:
+        raise ValueError(
+            f"num_local_experts: {raw['num_local_experts']!r} is not "
+            f"num_experts = {raw['num_experts']!r}")
+    if raw["head_dim"] % 2:
+        raise ValueError(f"head_dim: {raw['head_dim']!r} is not an even size > 0")
+    if raw["num_attention_heads"] % raw["num_key_value_heads"]:
+        raise ValueError("num_key_value_heads does not divide num_attention_heads")
+    if raw["torch_dtype"] not in _PUBLISHED_DTYPES:
+        raise ValueError(f"torch_dtype {raw['torch_dtype']!r} is not one the program serves in")
+    return LLaMAConfig(
+        **{ours: raw[theirs] for theirs, ours in fields.items()},
+        **{ours: sa[theirs] for theirs, ours in _PUBLISHED_SPARSE_INDEXER.items()},
+        moe_score_func="softmax",
+        dtype=raw["torch_dtype"], param_dtype=raw["torch_dtype"],
+        max_seq_len=max_seq_len, attn_impl=attn_impl,
+    )
+
+
 def _is_number(v) -> bool:
     return isinstance(v, (int, float)) and not isinstance(v, bool)
 
@@ -771,10 +947,16 @@ def from_published(raw, *, max_seq_len: int, attn_impl: str) -> LLaMAConfig:
     `max_position_embeddings` is accepted and unused: a server serves at its
     own `max_seq_len`.  A file with `kv_lora_rank` is the deepseek_v3 block,
     one with `layer_types` the afmoe block, one with `mb_per_layer` the
-    phi4flash block, one with `mamba_d_ssm` the falcon_h1 block; every
-    other file is the dense block."""
+    phi4flash block, one with `mamba_d_ssm` the falcon_h1 block, one with
+    `sa_config` the KeyeVL2 block; every other file is the dense block."""
     latent = "kv_lora_rank" in raw
     windowed = "layer_types" in raw
+    if "sa_config" in raw or raw.get("model_type") == "KeyeVL2":
+        if latent or windowed or "mb_per_layer" in raw or "mamba_d_ssm" in raw:
+            raise ValueError(
+                "sa_config beside kv_lora_rank / layer_types / mb_per_layer / "
+                "mamba_d_ssm: two blocks in one file")
+        return _from_published_sparse(raw, max_seq_len=max_seq_len, attn_impl=attn_impl)
     if "mamba_d_ssm" in raw or raw.get("model_type") == "falcon_h1":
         if latent or windowed or "mb_per_layer" in raw:
             raise ValueError(

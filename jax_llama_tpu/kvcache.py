@@ -953,7 +953,7 @@ def make_prefix_store(mode: str, host_blocks: int = 0, on_event=None):
 # Slab array names in pool order; the draft pool's twins carry the
 # ``d_`` prefix.  ``pos`` is per-block [BLK]; k/v are [L, KVH, BLK, hd];
 # scales (int8 pools only) are [L, KVH, BLK].
-_POOL_FIELDS = ("k", "v", "pos", "k_scale", "v_scale")
+_POOL_FIELDS = ("k", "v", "pos", "k_scale", "v_scale", "idx")
 
 
 def _pool_names(pool) -> Tuple[str, ...]:

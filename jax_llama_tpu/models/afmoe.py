@@ -72,7 +72,7 @@ from ..ops.attention import attention_bias, sdpa, sdpa_cached
 from ..ops.flash_attention import flash_attention
 from ..ops.norm import rms_norm
 from ..ops.rope import apply_rope_rows, rope_rows
-from .mla_moe import INIT_STD, ROUTER_BIAS_STD, _ffn_moe
+from .mla_moe import INIT_STD, ROUTER_BIAS_STD, routed_ffn
 
 Params = Dict[str, Any]
 
@@ -326,7 +326,7 @@ def forward(
     experts = (scanned.pop("experts_gate_up"), scanned.pop("experts_down"))
 
     def ffn_moe(h, lp, li):
-        return _ffn_moe(h, lp, experts, li - config.first_k_dense, valid, config)
+        return routed_ffn(h, lp, experts, li - config.first_k_dense, valid, config)
 
     x, ((k_d, v_d), _) = stack(x, params["dense_layers"], 0, ffn_dense)
     x, ((k_m, v_m), stats) = stack(x, scanned, config.first_k_dense, ffn_moe)

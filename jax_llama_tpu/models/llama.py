@@ -118,7 +118,7 @@ def qeinsum(
 @functools.partial(
     jax.tree_util.register_dataclass,
     data_fields=["k", "v", "pos", "index", "k_scale", "v_scale", "stats",
-                 "conv", "ssm"],
+                 "conv", "ssm", "idx"],
     meta_fields=[],
 )
 @dataclasses.dataclass
@@ -150,6 +150,12 @@ class KVCache:
     them ``conv`` [Ls, B, 3 * Di] (the mixers' last conv inputs) and ``ssm``
     [Ls, B, N, Di] float32 (their state-space state): fixed-size, advanced by
     a row's live tokens only, never paged.  None for every other block.
+
+    Learned sparse attention (``config.sparse_attention``,
+    models/dsa_moe.py) keeps a THIRD plane beside ``k`` and ``v``: ``idx``
+    [L, B, S_max, 1, index_head_dim], the indexer's one key a token a layer
+    (normed, its leading half rotated), by which a query ranks the keys it
+    may attend.  None for every other block.
     """
 
     k: jnp.ndarray
@@ -161,6 +167,7 @@ class KVCache:
     stats: Optional[jnp.ndarray] = None
     conv: Optional[jnp.ndarray] = None
     ssm: Optional[jnp.ndarray] = None
+    idx: Optional[jnp.ndarray] = None
 
     @property
     def max_len(self) -> int:
@@ -182,7 +189,7 @@ class KVCache:
     jax.tree_util.register_dataclass,
     data_fields=[
         "k", "v", "pos", "table", "fill", "k_scale", "v_scale", "stats",
-        "conv", "ssm",
+        "conv", "ssm", "idx",
     ],
     meta_fields=[],
 )
@@ -208,6 +215,8 @@ class PagedKVCache:
     Latent attention: ``k`` is the one latent plane [L, 1, NB, BLK, w] and
     ``v`` is None; ``stats`` as in ``KVCache``.  Recurrent state layers:
     ``conv`` / ``ssm`` as in ``KVCache``, a row of them a table row.
+    Learned sparse attention: ``idx`` [L, 1, NB, BLK, index_head_dim], the
+    index-key plane under the same block table as ``k`` and ``v``.
     """
 
     k: jnp.ndarray
@@ -220,6 +229,7 @@ class PagedKVCache:
     stats: Optional[jnp.ndarray] = None
     conv: Optional[jnp.ndarray] = None
     ssm: Optional[jnp.ndarray] = None
+    idx: Optional[jnp.ndarray] = None
 
     @property
     def n_blocks(self) -> int:
@@ -567,7 +577,12 @@ def cache_stats_zero(config: LLaMAConfig) -> Optional[jnp.ndarray]:
     """Empty counters of a cache or a pool: the routing counts
     (``ops.moe.STATS``) of a block with routed experts, then the window
     block's attention step counts (``afmoe.ATTN_STATS``); None for the
-    dense block, which counts nothing on the device."""
+    dense block, which counts nothing on the device.  The sparse-attention
+    block appends its selection counts (``dsa_moe.SELECT_STATS``)."""
+    if config.sparse_attention:
+        from .dsa_moe import N_STATS
+
+        return jnp.zeros((N_STATS,), jnp.int32)
     if config.windowed_attention or config.recurrent_state:
         # The recurrent block counts its attention steps in the window
         # block's layout; its routing counts stay zero.
@@ -614,6 +629,8 @@ def init_cache(
         index=jnp.zeros((), dtype=jnp.int32),
         k_scale=jnp.zeros(shape[:-1], jnp.float32) if int8_kv else None,
         v_scale=jnp.zeros(shape[:-1], jnp.float32) if int8_kv else None,
+        idx=(jnp.zeros(shape[:3] + (1, config.index_head_dim), dtype)
+             if config.sparse_attention else None),
     )
 
 
@@ -626,6 +643,10 @@ def init_params(rng: jax.Array, config: LLaMAConfig) -> Params:
     embeddings; Lecun-style fan-in scaling for projections).  The block
     follows from the configuration (see ``forward``)."""
     config.validate()
+    if config.sparse_attention:
+        from . import dsa_moe
+
+        return dsa_moe.init_params(rng, config)
     if config.latent_attention:
         from . import mla_moe
 
@@ -1186,15 +1207,17 @@ def forward(
       (logits, cache, aux).
     """
     if (config.latent_attention or config.windowed_attention
-            or config.recurrent_state):
+            or config.recurrent_state or config.sparse_attention):
         # The block follows from the configuration: latent attention over
         # a latent cache, window and full attention layers over the K/V
         # cache (routed experts behind leading dense layers), or recurrent
         # state layers beside window / full / cross attention, or a mixer
-        # beside attention in every layer.
-        from . import afmoe, falcon_h1, mla_moe, sambay
+        # beside attention in every layer, or a learned key selection
+        # inside attention over routed experts.
+        from . import afmoe, dsa_moe, falcon_h1, mla_moe, sambay
 
-        block = (falcon_h1 if config.parallel_mixer
+        block = (dsa_moe if config.sparse_attention
+                 else falcon_h1 if config.parallel_mixer
                  else sambay if config.recurrent_state
                  else mla_moe if config.latent_attention else afmoe)
         return block.forward(

@@ -235,7 +235,7 @@ def attend_tiled(q_nope, q_rope, latent, kv_b, q_pos, new_pos, cache, layer,
     return out.astype(adt)
 
 
-def _ffn_moe(h, lp, experts, layer, valid, config: LLaMAConfig):
+def routed_ffn(h, lp, experts, layer, valid, config: LLaMAConfig):
     """`experts` are ALL expert layers' (gate_up, down), not this layer's
     slice: the grouped matmul picks `layer` itself (`ops.moe.grouped_matmul`)."""
     from .llama import _swiglu
@@ -243,8 +243,9 @@ def _ffn_moe(h, lp, experts, layer, valid, config: LLaMAConfig):
     B, T, D = h.shape
     routed, stats = moe.routed_experts(
         h.reshape(B * T, D), None if valid is None else valid.reshape(B * T),
-        lp["router"], lp["router_bias"], *experts, layer,
+        lp["router"], lp.get("router_bias"), *experts, layer,
         top_k=config.n_experts_per_tok, scale=config.routed_scaling_factor,
+        score_func=config.moe_score_func,
     )
     out = routed.reshape(B, T, D)
     if "shared_gate_up" in lp:
@@ -400,7 +401,7 @@ def forward(
     experts = (scanned.pop("experts_gate_up"), scanned.pop("experts_down"))
 
     def ffn_moe(h, lp, li):
-        return _ffn_moe(h, lp, experts, li - config.first_k_dense, valid, config)
+        return routed_ffn(h, lp, experts, li - config.first_k_dense, valid, config)
 
     x, (lat_d, _) = stack(x, params["dense_layers"], 0, ffn_dense)
     x, (lat_m, stats) = stack(x, scanned, config.first_k_dense, ffn_moe)

@@ -74,6 +74,7 @@ from ..config import LLaMAConfig
 from ..ops import moe, ssm
 from ..ops.attention import attention_bias, sdpa, sdpa_cached
 from ..ops.flash_attention import flash_attention
+from ..ops.norm import layer_norm
 from .afmoe import ATTN_STATS
 from .falcon_h1 import _conv  # the causal depthwise conv behind a row's held inputs
 from .llama import _swiglu, qeinsum  # `llama` reaches this module inside its functions only
@@ -162,16 +163,6 @@ def init_params(rng: jax.Array, config: LLaMAConfig) -> Params:
                          "attn": attention(keys[6], P1 - 1, own_kv=False)},
         "final_norm": jnp.ones((D,), wd), "final_norm_bias": jnp.zeros((D,), wd),
     }
-
-
-def layer_norm(x, w, b, eps):
-    """LayerNorm over the last axis with weight and bias, a float32 island."""
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    xc = xf - mu
-    var = jnp.mean(xc * xc, axis=-1, keepdims=True)
-    out = xc * lax.rsqrt(var + eps) * w.astype(jnp.float32) + b.astype(jnp.float32)
-    return out.astype(x.dtype)
 
 
 def pad_query_pairs(q):

@@ -508,6 +508,17 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "Live grid steps of the paged decode kernel in full "
                    "attention layers, summed over rows, layers and "
                    "iterations"),
+    # -- learned sparse attention (models/dsa_moe.py; zero without) ----------
+    "attn_selected_slots_total": _reg(
+        "counter", "Slots the paged decode rows attended under their "
+                   "learned key selection, summed over rows, layers and "
+                   "iterations"),
+    "attn_candidate_slots_total": _reg(
+        "counter", "Live slots the paged decode rows' selection chose "
+                   "from (decode rows x layers x context)"),
+    "attn_select_dense_rows_total": _reg(
+        "counter", "Paged decode rows (x layers) whose context was no "
+                   "longer than the selection's k: every live key attended"),
     # -- recurrent state layers (models/sambay.py, models/falcon_h1.py; zero
     # without) ---------------------------------------------------------------
     "ssm_snapshots_taken_total": _reg(

@@ -179,7 +179,7 @@ def _tri_gate(qp, kp, bq_s, bk_s, quantized=False):
 def _flash_tri_tile_update(
     q_ref, k_ref, v_ref, seed_ref,
     m_ref, l_ref, acc_ref, qp, kp, bi, hi, qi, ki,
-    *, scale, dropout_rate, window=None,
+    *, scale, dropout_rate, window=None, mask_ref=None,
 ):
     """Diagonal-crossing tile update with RAGGED sub-tile dots: k sub-tile
     ``i`` computes only query rows ``[i·rq:]`` — ``_KSUB`` shrinking dots
@@ -205,6 +205,10 @@ def _flash_tri_tile_update(
         # The window masks rows the ragged body still computes; what it
         # skips is skipped on causal grounds alone.
         allowed = allowed & (kp > qp - window)
+    if mask_ref is not None:
+        # The selection only removes pairs: the ragged body's causal skips
+        # stay sound.
+        allowed = allowed & (mask_ref[0].astype(jnp.int32) != 0)
     m_prev = m_ref[:, :1]  # [bq, 1]
 
     s_parts = []  # s_i: [bq - i*rq, ksub]
@@ -280,6 +284,7 @@ def _flash_kernel(
     quantized: bool = False,
     dropout_rate: float = 0.0,
     windowed: bool = False,
+    masked: bool = False,
 ):
     if windowed:
         # [B * nq] int32: the first kv block of this q block's sweep, and
@@ -291,6 +296,11 @@ def _flash_kernel(
     else:
         seed_ref = None
     q_pos_ref, kv_pos_ref, q_ref, k_ref, v_ref, *rest = args
+    if masked:
+        # [1, bq, bk] int8: nonzero where the query's selection holds the key
+        mask_ref, *rest = rest
+    else:
+        mask_ref = None
     # q_pos_ref: [1, bq, 1] int32 (narrow-lane view)
     # kv_pos_ref: [1, 1, bk] int32 (narrow-sublane view)
     # q_ref: [1, 1, bq, d]; k_ref/v_ref: [1, 1, bk, d] (int8 when quantized)
@@ -374,6 +384,7 @@ def _flash_kernel(
                 q_ref, k_ref, v_ref, seed_ref,
                 m_ref, l_ref, acc_ref, qp, kp, bi, hi, qi, ki,
                 scale=scale, dropout_rate=dropout_rate, window=window,
+                mask_ref=mask_ref,
             )
     else:
         full_live = block_live
@@ -430,6 +441,8 @@ def _flash_kernel(
         allowed = kp <= qp  # [bq, bk]
         if windowed:
             allowed = allowed & (kp > qp - window)
+        if masked:
+            allowed = allowed & (mask_ref[0].astype(jnp.int32) != 0)
         s_parts = []
         for i in range(nsub):
             cols = slice(i * ksub, (i + 1) * ksub)
@@ -558,6 +571,7 @@ def flash_attention(
     dropout_rate: float = 0.0,
     dropout_seed: Optional[jnp.ndarray] = None,
     window: Optional[jnp.ndarray] = None,
+    mask: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     """Blockwise attention; drop-in for ``ops.attention.sdpa`` + bias.
 
@@ -597,6 +611,12 @@ def flash_attention(
         wholly before it are neither fetched nor computed.  Inference
         only (no VJP, no dropout).  None: the program without a window,
         unchanged.
+      mask: optional [B, T, S] int8 operand, nonzero where query t may see
+        slot s BESIDE the positional rule (a learned selection,
+        models/dsa_moe.py): every head of a query shares its row.  The k
+        sweep and its bounds stay the causal ones; a tile of the mask rides
+        each (q block, k block) step.  Inference only.  None: the program
+        without one, unchanged.
     Returns:
       [B, T, H, d] in q.dtype.
     """
@@ -604,8 +624,11 @@ def flash_attention(
     H, KVH = q.shape[2], k.shape[2]
     assert H % KVH == 0, (H, KVH)
     group = H // KVH
-    if window is not None and dropout_rate > 0.0:
-        raise ValueError("the window form of flash_attention is inference-only")
+    if (window is not None or mask is not None) and dropout_rate > 0.0:
+        raise ValueError(
+            "the window and mask forms of flash_attention are inference-only")
+    if window is not None and mask is not None:
+        raise ValueError("flash_attention takes a window or a mask, not both")
     if not 0.0 <= dropout_rate < 1.0:
         # Validate BEFORE the >0 branch: a negative rate must raise, not
         # silently train without dropout.
@@ -630,10 +653,10 @@ def flash_attention(
             q.reshape(B, T, KVH, group, -1), 3, 1
         ).reshape(B, group * T, KVH, -1)
         pos_p = jnp.tile(q_pos, (1, group))
-        if window is not None:
+        if window is not None or mask is not None:
             out = _flash_forward(
                 qp, k, v, pos_p, kv_pos, block_q, block_k, interpret,
-                window=window,
+                window=window, mask=mask,
             )
         else:
             out = _flash(
@@ -644,10 +667,10 @@ def flash_attention(
             out.reshape(B, group, T, KVH, -1), 1, 3
         ).reshape(B, T, H, -1)
         return out
-    if window is not None:
+    if window is not None or mask is not None:
         return _flash_forward(
             q, k, v, q_pos, kv_pos, block_q, block_k, interpret,
-            window=window,
+            window=window, mask=mask,
         )
     return _flash(
         q, k, v, q_pos, kv_pos, seed, block_q, block_k, interpret,
@@ -926,7 +949,7 @@ def _window_bounds(q_pos_p, kv_pos_p, T, block_q, block_k, window):
 def _flash_forward(
     q, k, v, q_pos, kv_pos, block_q, block_k, interpret, need_lse=False,
     k_scale=None, v_scale=None, dropout_rate=0.0, dropout_seed=None,
-    window=None,
+    window=None, mask=None,
 ):
     B, T, H, d = q.shape
     S, KVH = k.shape[1], k.shape[2]
@@ -945,6 +968,22 @@ def _flash_forward(
     # runs in base 2 (bare VPU exp2 per element, no hidden wide multiply).
     scale = (1.0 / (d ** 0.5)) * float(np.log2(np.e))
     interpret = _resolve_interpret(interpret)
+    masked = mask is not None
+    assert not (masked and (quantized or with_dropout or windowed)), (
+        "the mask form is bf16/f32 inference only, without a window"
+    )
+    mask_blocks = None
+    if masked:
+        # The mask has one row a QUERY; the packed rows (GQA: group x Tm,
+        # row g * Tm + t) share it.  Where a q block never spans two heads
+        # (block_q divides Tm) the mask's row block is qi modulo its own
+        # count; otherwise the mask is tiled to the packed rows.
+        Tm = mask.shape[1]
+        bq = min(block_q, Tm)
+        if Tm % bq == 0 and (interpret or bq % _SUBLANES == 0 or bq == T):
+            block_q, mask_blocks = bq, Tm // bq
+        else:
+            mask = jnp.tile(mask, (1, T // Tm, 1))
     block_q, block_k = _clamp_blocks(T, S, block_q, block_k, interpret)
 
     # Pad sequence axes up to tile multiples OUTSIDE the kernel: Pallas
@@ -1041,6 +1080,16 @@ def _flash_forward(
         ),
     ]
     operands = [q_pos_r, kv_pos_r, qt, kt, vt]
+    if masked:
+        mask_p = _pad_to(_pad_to(mask.astype(jnp.int8), 1, block_q), 2, block_k)
+        n_mask = mask_blocks or nq
+        in_specs.append(pl.BlockSpec(
+            (1, block_q, block_k),
+            lambda b, h, qi, ki, bound, *_: (
+                b, qi % n_mask, _clamp_ki(b, qi, ki, bound, *_)
+            ),
+        ))
+        operands.append(mask_p)
     if quantized:
         # Narrow-sublane per-slot scale views [B, KVH, 1, Sp] — free
         # expand_dims, blocked along the kv axis like kv_pos.
@@ -1068,7 +1117,7 @@ def _flash_forward(
         functools.partial(
             _flash_kernel, scale=scale, with_lse=need_lse,
             quantized=quantized, dropout_rate=dropout_rate,
-            windowed=windowed,
+            windowed=windowed, masked=masked,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),

@@ -1,10 +1,12 @@
-"""Routed experts: a sigmoid router, top-k with a selection-only bias, and a
-grouped matmul over the experts a batch touches.
+"""Routed experts: a router in one of two forms behind one argument, top-k
+with a selection-only bias, and a grouped matmul over the experts a batch
+touches.
 
-The layer (deepseek_v3's, as `config.from_published` maps it):
+The layer (deepseek_v3's and KeyeVL2's, as `config.from_published` maps them):
 
-    s = sigmoid(h @ W_g)                      float32, [N, E]
-    selected = top_k(s + b)                   b moves the SELECTION only
+    s = sigmoid(h @ W_g)  or  softmax(h @ W_g)    float32, [N, E]; `score_func`,
+                                              which a configuration's block fixes
+    selected = top_k(s + b)                   b moves the SELECTION only (None: no bias)
     w = s[selected] / sum(s[selected]) * routed_scaling_factor
     y = sum_e w_e * down_e(silu(gate_e h) * up_e h)
 
@@ -43,19 +45,24 @@ N_STATS = len(STATS)
 def route(
     h: jnp.ndarray,            # [N, D]
     w_gate: jnp.ndarray,       # [D, E]
-    bias: jnp.ndarray,         # [E] selection-only correction
+    bias: Optional[jnp.ndarray],  # [E] selection-only correction, or None
     *,
     top_k: int,
     scale: float,
+    score_func: str = "sigmoid",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(expert ids [N, k] int32, weights [N, k] float32).  Scores and
     weights are float32 whatever the activations are: the sixth and seventh
-    scores of 128 lie close, and a bfloat16 sigmoid would pick by rounding."""
-    s = jax.nn.sigmoid(jnp.einsum(
+    scores of 128 lie close, and a bfloat16 sigmoid would pick by rounding.
+    `score_func`: "sigmoid", or "softmax" over the experts (the weights are
+    the chosen probabilities renormalised, `norm_topk_prob`)."""
+    score = {"sigmoid": jax.nn.sigmoid,
+             "softmax": lambda x: jax.nn.softmax(x, axis=-1)}[score_func]
+    s = score(jnp.einsum(
         "nd,de->ne", h.astype(jnp.float32), w_gate.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     ))
-    _, idx = lax.top_k(s + bias.astype(jnp.float32), top_k)
+    _, idx = lax.top_k(s if bias is None else s + bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(s, idx, axis=1)
     w = w / jnp.sum(w, axis=1, keepdims=True) * scale
     return idx.astype(jnp.int32), w
@@ -101,13 +108,14 @@ def routed_experts(
     h: jnp.ndarray,            # [N, D] normed hidden states
     valid: Optional[jnp.ndarray],  # [N] bool: rows that are tokens
     w_gate: jnp.ndarray,       # [D, E]
-    bias: jnp.ndarray,         # [E]
+    bias: Optional[jnp.ndarray],  # [E], or None
     gate_up: jnp.ndarray,      # [L, E, D, 2F]  (gate | up on the last axis)
     down: jnp.ndarray,         # [L, E, F, D]
     layer: jnp.ndarray,        # int32: which of the L expert layers
     *,
     top_k: int,
     scale: float,
+    score_func: str = "sigmoid",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The routed experts' sum [N, D] and the call's routing statistics
     [N_STATS] int32 (see `STATS`)."""
@@ -115,7 +123,8 @@ def routed_experts(
     E = w_gate.shape[1]
     F = down.shape[2]
     with jax.named_scope("moe.route"):
-        idx, w = route(h, w_gate, bias, top_k=top_k, scale=scale)
+        idx, w = route(h, w_gate, bias, top_k=top_k, scale=scale,
+                       score_func=score_func)
         if valid is not None:
             idx = jnp.where(valid[:, None], idx, E)  # no expert: sorts last
         flat = idx.reshape(-1)                               # [N*k]

@@ -783,3 +783,75 @@ def _paged_decode_local(
     ) / denom[..., None]
     out = jnp.swapaxes(out, 1, 2).reshape(B, T, H, dv)
     return out.astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Attention over SELECTED slots of the pool (learned sparse attention,
+# models/dsa_moe.py): no kernel.  A row attends `topk` slots wherever they
+# lie in its blocks, so what is read is a gather of that many K and V rows
+# from the pool — 2,048 x 2 KiB a row a layer whatever the context —
+# and the table and the window bound no longer decide alone what a decode
+# iteration reads.
+# ---------------------------------------------------------------------------
+
+def paged_rows(plane: jnp.ndarray, table: jnp.ndarray, layer) -> jnp.ndarray:
+    """One layer's rows of a ONE-head pool plane [L, 1, NB, BLK, d] for each
+    table row [B, MB], in sequence order: [B, MB * BLK, d].  An unused table
+    entry (the sentinel NB) reads a real block; the caller masks by
+    position."""
+    L, _, NB, BLK, d = plane.shape
+    ids = layer * NB + jnp.minimum(table, NB - 1)
+    got = jnp.take(plane.reshape(L * NB, BLK, d), ids, axis=0)
+    return got.reshape(table.shape[0], -1, d)
+
+
+def paged_slot_positions(pool_pos: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
+    """[B, MB * BLK] int32 position of each slot of each table row, -1 for
+    an empty slot and for every slot of an unused table entry."""
+    NB, BLK = pool_pos.shape
+    pos = jnp.take(pool_pos, jnp.minimum(table, NB - 1), axis=0)
+    pos = jnp.where((table < NB)[:, :, None], pos, -1)
+    return pos.reshape(table.shape[0], -1)
+
+
+def paged_sparse_attention(
+    q: jnp.ndarray,           # [B, T, H, hd]
+    k_new: jnp.ndarray,       # [B, T, KVH, hd] this step's own keys
+    v_new: jnp.ndarray,
+    chosen: jnp.ndarray,      # [B, T, k] int32 candidate ids (see below)
+    chosen_live: jnp.ndarray,  # [B, T, k] bool
+    k_pool: jnp.ndarray,      # [L, 1, NB, BLK, KVH * hd]: a token's heads in one row
+    v_pool: jnp.ndarray,
+    table: jnp.ndarray,       # [B, MB]
+    layer,
+) -> jnp.ndarray:
+    """softmax(q . K_chosen / sqrt(hd)) V_chosen, [B, T, H, hd].  A
+    candidate id below MB * BLK is that slot of the row's table (sequence
+    order); MB * BLK + j is the step's own token j.  Dead choices (a row
+    with fewer live candidates than k) carry no weight."""
+    B, T, H, hd = q.shape
+    L, _, NB, BLK, width = k_pool.shape
+    KVH = width // hd
+    S = table.shape[1] * BLK
+    G = H // KVH
+    own = chosen >= S
+    slot = jnp.minimum(chosen, S - 1)
+    blk = jnp.take_along_axis(
+        jnp.minimum(table, NB - 1)[:, None, :], slot // BLK, axis=2)
+    ids = (layer * NB + blk) * BLK + slot % BLK                   # [B, T, k]
+    j = jnp.clip(chosen - S, 0, T - 1)[..., None]
+
+    def rows(pool, new):
+        got = jnp.take(pool.reshape(L * NB * BLK, width), ids, axis=0)
+        mine = jnp.take_along_axis(new.reshape(B, 1, T, width), j, axis=2)
+        got = jnp.where(own[..., None], mine.astype(got.dtype), got)
+        return got.reshape(B, T, -1, KVH, hd)
+
+    kc, vc = rows(k_pool, k_new), rows(v_pool, v_new)
+    qg = q.reshape(B, T, KVH, G, hd)
+    s = jnp.einsum("btcgd,btkcd->btcgk", qg, kc.astype(q.dtype),
+                   preferred_element_type=jnp.float32) / np.sqrt(hd)
+    s = jnp.where(chosen_live[:, :, None, None, :], s, MASK_VALUE)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    out = jnp.einsum("btcgk,btkcd->btcgd", p, vc.astype(q.dtype))
+    return out.reshape(B, T, H, hd)

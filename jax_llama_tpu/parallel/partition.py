@@ -44,8 +44,8 @@ def param_partition_specs(
     """
     f = "fsdp" if fsdp else None
     s = "stage" if pp else None
-    if config.recurrent_state:
-        return _recurrent_block_specs(config)
+    if config.recurrent_state or config.sparse_attention:
+        return _whole_leaf_specs(config)
     if config.expert_block:
         return _expert_block_specs(config)
     specs: Dict[str, Any] = {
@@ -80,10 +80,11 @@ def param_partition_specs(
 EXPERT_AXIS = "tensor"
 
 
-def _recurrent_block_specs(config: LLaMAConfig) -> Dict[str, Any]:
-    """Specs mirroring `models.sambay.init_params` or, for a mixer beside
-    attention in every layer, `models.falcon_h1.init_params`: every leaf
-    whole on its chip.  `validate_tp` holds both blocks to one chip, so
+def _whole_leaf_specs(config: LLaMAConfig) -> Dict[str, Any]:
+    """Specs mirroring `models.sambay.init_params`, for a mixer beside
+    attention in every layer `models.falcon_h1.init_params`, or for learned
+    sparse attention `models.dsa_moe.init_params`: every leaf whole on its
+    chip.  `validate_tp` holds both blocks to one chip, so
     nothing is split yet: the mixers' channels (or heads, whose groups of
     `B` / `C` must divide with them) and the attention heads over ``tensor``
     need the per-slot state and the snapshot pool split with them."""

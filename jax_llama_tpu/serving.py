@@ -225,6 +225,7 @@ from .models.llama import (
 from .models.mla_moe import ctx_tiles
 from .ops.moe import STATS as _MOE_STATS
 from .models.afmoe import ATTN_STATS as _ATTN_STATS
+from .models.dsa_moe import SELECT_STATS as _SELECT_STATS
 from .ops.attention import NEG_INF
 from .ops.sampling import stop_token_hits
 from .parallel.mesh import use_mesh
@@ -245,7 +246,7 @@ from .spec_decode import (
 @functools.partial(
     jax.tree_util.register_dataclass,
     data_fields=["k", "v", "pos", "k_scale", "v_scale", "stats",
-                 "conv", "ssm", "snap_conv", "snap_ssm"],
+                 "conv", "ssm", "snap_conv", "snap_ssm", "idx"],
     meta_fields=[],
 )
 @dataclasses.dataclass
@@ -277,6 +278,12 @@ class BlockPool:
     block boundary of its prompt under an id the prefix store hangs on that
     block's radix node, copied back in by a prefix hit that ends there.
     None for every other block.
+
+    Learned sparse attention (models/dsa_moe.py) keeps a third plane:
+    ``idx`` [L, 1, n_blocks, block_size, index_head_dim], the indexer's one
+    key a token a layer, under the same block table as ``k`` and ``v``: a
+    cached prefix block brings its index keys, and every program that writes
+    K/V writes them (they are one of ``_PLANES``).  None for every other.
     """
 
     k: jnp.ndarray
@@ -289,6 +296,7 @@ class BlockPool:
     ssm: Optional[jnp.ndarray] = None
     snap_conv: Optional[jnp.ndarray] = None
     snap_ssm: Optional[jnp.ndarray] = None
+    idx: Optional[jnp.ndarray] = None
 
     @property
     def n_blocks(self) -> int:
@@ -326,6 +334,9 @@ def init_pool(
         state["conv"], state["ssm"] = init_state(config, n_slots)
         state["snap_conv"], state["snap_ssm"] = init_state(
             config, max(1, n_snapshots))
+    if config.sparse_attention:
+        state["idx"] = jnp.zeros(
+            (shape[0], 1) + shape[2:4] + (config.index_head_dim,), dtype)
     return BlockPool(
         **state,
         k=jnp.zeros(shape, dtype=dtype),
@@ -338,7 +349,7 @@ def init_pool(
 
 
 # The per-(layer, head) planes a pool may have; ``pos`` is per block only.
-_PLANES = ("k", "v", "k_scale", "v_scale")
+_PLANES = ("k", "v", "k_scale", "v_scale", "idx")
 # State snapshots a slot (recurrent state layers): the snapshot pool holds
 # this many times n_slots, but no more bytes than the K/V pool beside it
 # (``snapshot_pool_size``).
@@ -663,7 +674,8 @@ def _mixed_pass(config, quantized_pool, mesh, use_kernel, n_iter) -> bool:
     return bool(
         use_kernel and n_iter >= 2 and not quantized_pool
         and (mesh is None or mesh.size == 1)
-        and not (config.latent_attention or config.windowed_attention)
+        and not (config.latent_attention or config.windowed_attention
+                 or config.sparse_attention)
     )
 
 
@@ -1417,7 +1429,6 @@ def _paged_insert(
         # failed by the host at the next emit boundary.
         tau = jnp.where(finite_rows(logits_last), tau, -1)
 
-        L, KVH = pool.k.shape[:2]
         nb = P // BLK
 
         def land(plane, a):
@@ -1425,7 +1436,7 @@ def _paged_insert(
             # [k, nb] and its sentinel entries (NB) drop their update.
             return plane.at[:, :, block_ids].set(
                 jnp.moveaxis(a, 3, 1).reshape(
-                    (L, KVH, k_rows, nb, BLK) + a.shape[4:]
+                    plane.shape[:2] + (k_rows, nb, BLK) + a.shape[4:]
                 ),
                 mode="drop",
             )
@@ -1543,7 +1554,7 @@ def _pool_as_cache(pool: BlockPool, table, fill) -> PagedKVCache:
     return PagedKVCache(
         k=pool.k, v=pool.v, pos=pool.pos, table=table, fill=fill,
         k_scale=pool.k_scale, v_scale=pool.v_scale, stats=pool.stats,
-        conv=pool.conv, ssm=pool.ssm,
+        conv=pool.conv, ssm=pool.ssm, idx=pool.idx,
     )
 
 
@@ -1551,7 +1562,7 @@ def _cache_into_pool(pool: BlockPool, pcache: PagedKVCache) -> BlockPool:
     return dataclasses.replace(
         pool, k=pcache.k, v=pcache.v, pos=pcache.pos,
         k_scale=pcache.k_scale, v_scale=pcache.v_scale, stats=pcache.stats,
-        conv=pcache.conv, ssm=pcache.ssm,
+        conv=pcache.conv, ssm=pcache.ssm, idx=pcache.idx,
     )
 
 
@@ -2367,6 +2378,11 @@ class ContinuousBatcher:
                 "--host-kv-blocks (the host tier) is not supported with "
                 f"{config.expert_block}: a demoted node's state snapshot "
                 "does not demote with it")
+        if config.sparse_attention and host_kv_blocks > 0:
+            raise ValueError(
+                "--host-kv-blocks (the host tier) is not supported with "
+                f"{config.expert_block}: the index-key plane has not been "
+                "through a demotion and a restore")
         self.n_snapshots = (
             snapshot_pool_size(
                 self.config, n_slots, self.n_blocks, self.block_size)
@@ -2634,7 +2650,9 @@ class ContinuousBatcher:
         # Window and full attention layers: the paged decode kernel's live
         # grid steps by layer kind (``afmoe.ATTN_STATS``), behind the
         # routing counts in the same fetch.  Zero on a configuration without.
-        self.attn_step_totals = dict.fromkeys(_ATTN_STATS, 0)
+        # Learned sparse attention: what the paged decode rows' selection
+        # chose from (``dsa_moe.SELECT_STATS``), behind those again.
+        self.attn_step_totals = dict.fromkeys(_ATTN_STATS + _SELECT_STATS, 0)
         self.prefill_ctx_slots_attended_total = 0
         self.prefill_ctx_slots_view_total = 0
         self.prefill_blocks_written_total = 0
@@ -3540,7 +3558,7 @@ class ContinuousBatcher:
             moe_counts = counts[:len(_MOE_STATS)]
             for name, v in zip(_MOE_STATS, moe_counts):
                 self.moe_totals[name] += v
-            for name, v in zip(_ATTN_STATS, counts[len(_MOE_STATS):]):
+            for name, v in zip(self.attn_step_totals, counts[len(_MOE_STATS):]):
                 self.attn_step_totals[name] += v
         if pf_ctx is not None:
             self.prefill_ctx_slots_attended_total += pf_ctx[0]
