@@ -33,10 +33,14 @@ How the new tokens attend:
   made `Q_TILE` queries at a time from the index scores of every live key
   (`key_selection.select_mask`); a chunk of more queries is walked in tiles
   (`lax.map`), so a 32,768-token whole-prompt insert holds one tile's scores.
-* decode rows over the paged pool: each row scores its own blocks' index
-  keys (a paged read of the one-head plane), takes an exact top-k
-  (`key_selection.select_slots`) and attends the chosen slots gathered from
-  the pool (`ops.paged_attention.paged_sparse_attention`): no kernel.
+* decode rows over the paged pool: each row ranks its own LIVE blocks' index
+  keys in two Pallas kernels a layer (`key_selection.paged_select_slots`: the
+  one-head plane read in place by the block table and scored on the MXU, the
+  k-th-value search over all rows' scores in VMEM, an exact top-k), and
+  attends the chosen slots gathered from the pool by XLA
+  (`ops.paged_attention.paged_sparse_attention`: no kernel there yet).
+  Several tokens a row over the pool, which no cell dispatches, keep the XLA
+  ranking over the row's whole table (`paged_rows`, `select_slots`).
 * everything else (cache-free, decode-sized steps over a `KVCache`): the
   mask as an additive bias of the XLA attention.
 
@@ -58,7 +62,8 @@ Parameters are one stacked tree, scanned:
 Every call counts into the cache's `stats` (`N_STATS` int32): the routing
 counts of `ops.moe.STATS`, the window block's two step counts (zero here: the
 layout of the fetch's tail is shared), then `SELECT_STATS` of the paged decode
-rows, summed over rows and layers.
+rows, summed over rows and layers (the last two: the ranking kernel's live
+grid steps, and the steps the rows' whole tables would take).
 """
 
 from __future__ import annotations
@@ -73,7 +78,10 @@ from jax import lax
 from ..config import LLaMAConfig
 from ..ops.attention import NEG_INF, sdpa
 from ..ops.flash_attention import flash_attention
-from ..ops.key_selection import index_scores, select_mask, select_slots
+from ..ops.key_selection import (
+    index_plan, index_scores, paged_select_slots, plan_row_steps, select_mask,
+    select_slots,
+)
 from ..ops.norm import layer_norm, rms_norm
 from ..ops.rope import apply_rope_rows, rope_rows
 from . import afmoe
@@ -83,8 +91,10 @@ Params = Dict[str, Any]
 
 # What a call counts of its paged decode rows' selection, after the window
 # block's counters: slots attended, live slots they were chosen from, and
-# rows whose context was no longer than `topk` (which took all of it).
-SELECT_STATS = ("selected_slots", "candidate_slots", "select_dense_rows")
+# rows whose context was no longer than `topk` (which took all of it); then
+# the grid steps the ranking kernel ran and those a full table would take.
+SELECT_STATS = ("selected_slots", "candidate_slots", "select_dense_rows",
+                "index_steps_run", "index_steps_table")
 N_STATS = afmoe.N_STATS + len(SELECT_STATS)
 
 # Queries a selection pass: one mask tile of the flash kernel's q block.
@@ -156,6 +166,7 @@ def forward(
     over a `KVCache` (scalar or per-row index) or over a `PagedKVCache`."""
     from ..ops.paged_attention import (
         paged_rows, paged_slot_positions, paged_sparse_attention,
+        plan_live_steps,
     )
     from .llama import (
         FLASH_MIN_SEQ, AuxOutput, KVCache, PagedKVCache, lm_head_logits,
@@ -199,9 +210,16 @@ def forward(
         cand_pos = jnp.concatenate([slot_pos, q_pos], axis=1)       # [B, S + T]
         cand_live = (cand_pos[:, None, :] >= 0) & (cand_pos[:, None, :] <= q_pos[:, :, None])
         n_live = jnp.sum(cand_live, axis=-1)                         # [B, T]
+        index_steps = (0, 0)
+        if T == 1:
+            # One token a row (what the serving programs dispatch) ranks by
+            # the kernels; their step list does not depend on the layer.
+            rank_plan = index_plan(cache.pos, cache.table, q_pos[:, 0])
+            index_steps = (plan_live_steps(rank_plan),
+                           jnp.sum(row_active) * plan_row_steps(rank_plan, B))
         select_stats = config.n_layers * jnp.stack([
             jnp.sum(jnp.minimum(n_live, topk)), jnp.sum(n_live),
-            jnp.sum(valid & (n_live <= topk)),
+            jnp.sum(valid & (n_live <= topk)), *index_steps,
         ]).astype(jnp.int32)
     else:
         valid = attn_mask
@@ -277,12 +295,17 @@ def forward(
             k_idx = _rope_half(k_idx[:, :, None, :], cos_i, sin_i)[:, :, 0]
             w = qeinsum(a, lp["index_w"], "btd,dh->bth", adt).astype(jnp.float32)
         if paged:
-            with jax.named_scope("attn.index"):
-                keys = jnp.concatenate(
-                    [paged_rows(cache.idx, cache.table, li).astype(adt), k_idx],
-                    axis=1)
-                scores = index_scores(q_idx, w, keys)
-            chosen, chosen_live = select_slots(scores, cand_live, topk)
+            if T == 1:
+                chosen, chosen_live = paged_select_slots(
+                    q_idx, w, k_idx, cache.idx, cache.table, rank_plan,
+                    q_pos[:, 0], li, topk)
+            else:
+                with jax.named_scope("attn.index"):
+                    keys = jnp.concatenate(
+                        [paged_rows(cache.idx, cache.table, li).astype(adt), k_idx],
+                        axis=1)
+                    scores = index_scores(q_idx, w, keys)
+                chosen, chosen_live = select_slots(scores, cand_live, topk)
             with jax.named_scope("attn.sparse"):
                 out = paged_sparse_attention(
                     q, k, v, chosen, chosen_live, cache.k, cache.v,

@@ -519,6 +519,13 @@ METRICS: Dict[str, Tuple[str, str]] = {
     "attn_select_dense_rows_total": _reg(
         "counter", "Paged decode rows (x layers) whose context was no "
                    "longer than the selection's k: every live key attended"),
+    "attn_index_steps_run_total": _reg(
+        "counter", "Live grid steps of the paged index-ranking kernel, "
+                   "summed over rows, layers and iterations"),
+    "attn_index_steps_table_total": _reg(
+        "counter", "Grid steps the decoding rows' whole block tables would "
+                   "take in the paged index-ranking kernel (rows x layers "
+                   "x steps a table)"),
     # -- recurrent state layers (models/sambay.py, models/falcon_h1.py; zero
     # without) ---------------------------------------------------------------
     "ssm_snapshots_taken_total": _reg(

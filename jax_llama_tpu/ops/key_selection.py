@@ -16,9 +16,18 @@ Two forms of one rule, both EXACT (`approx_max_k` is not the model):
   tiles of `K_TILE` keys, and every pass walks the tiles up to the last one
   any query of the block may see: the count is a value, so a chunk early in a
   32,768-slot view pays for its context and not for the view.
-* `select_slots`, for decode rows: the same search over each row's candidate
-  scores, then the chosen compacted to a list of slots for a gather, by
-  running counts at two levels (no sort, no scatter).
+* `paged_select_slots`, for decode rows over the paged pool: two Pallas
+  kernels a layer (`paged_index_rank`).  One reads the index-key plane in
+  place by the block table, live blocks only (`paged_attention._fetch_plan`'s
+  step list, several blocks a grid step), and scores them on the MXU; one
+  holds every row's bit images in VMEM and runs the same search there.  What
+  XLA still does is the mask from the k-th value and the chosen compacted to
+  a list of slots for a gather, by running counts at two levels (no sort, no
+  scatter).  As five XLA stages sized by the TABLE the ranking cost thirty
+  times its bytes (PERF.md section 6, PR 49).
+* `select_slots`: the decode rows' rule over candidate scores that are
+  already an array (several tokens a row over the pool, which no cell
+  dispatches, and the tests' reference for the kernel).
 
 A context no longer than k selects every live key, and the mask is the
 causal mask.
@@ -26,11 +35,17 @@ causal mask.
 
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import _LANES, _SUBLANES, _resolve_interpret
+from .paged_attention import _FIRST, _LIVE, _fetch_plan
 
 K_TILE = 2048    # keys a step of a pass over the scores
 
@@ -56,20 +71,25 @@ def _sortable(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(0x80000000))
 
 
-def _topk_mask(u: jnp.ndarray, topk: int, count) -> jnp.ndarray:
-    """[..., N] bool: the `topk` largest live entries of the bit images `u`
-    (0: not live), equal values from the lowest index up; every live entry
-    of a row that has no more than `topk`.  `count(pred, ref)` says how many
-    entries of each row satisfy `pred(u, ref[..., None])`."""
+def _kth_value(u: jnp.ndarray, topk: int, count):
+    """(kth, above, over) of the bit images `u` [..., N] (0: not live): the
+    largest value that `topk` live entries of a row reach (0 for a row with
+    fewer, which then takes every live entry), how many entries lie above it,
+    and whether the row has more entries at it than `topk` leaves room for.
+    `count(pred, ref)` says how many entries of each row satisfy
+    `pred(u, ref[..., None])`."""
     def bit(i, t):
         cand = t | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
         return jnp.where(count(jnp.greater_equal, cand) >= topk, cand, t)
-    # The largest value that `topk` live scores reach: the k-th largest (0
-    # for a row with fewer, which then takes every live entry).
     kth = lax.fori_loop(0, 32, bit, jnp.zeros(u.shape[:-1], jnp.uint32))
     above = count(jnp.greater, kth)
-    over = (kth > 0) & (above + count(jnp.equal, kth) > topk)
+    return kth, above, (kth > 0) & (above + count(jnp.equal, kth) > topk)
 
+
+def _mask_of(u: jnp.ndarray, topk: int, kth, above, over) -> jnp.ndarray:
+    """[..., N] bool from `_kth_value`'s answers: the `topk` largest live
+    entries of `u`, equal values from the lowest index up; every live entry
+    of a row that has no more than `topk`."""
     def by_rank():
         # Equal scores at the k-th value: the lowest indices first.  Behind a
         # cond because float32 scores all but never tie there and a decode
@@ -82,6 +102,11 @@ def _topk_mask(u: jnp.ndarray, topk: int, count) -> jnp.ndarray:
         return (u > kth[..., None]) | (tie & (rank <= (topk - above)[..., None]))
 
     return lax.cond(jnp.any(over), by_rank, lambda: u >= kth[..., None]) & (u > 0)
+
+
+def _topk_mask(u: jnp.ndarray, topk: int, count) -> jnp.ndarray:
+    """`_mask_of` the `_kth_value` of `u`: both halves, in XLA."""
+    return _mask_of(u, topk, *_kth_value(u, topk, count))
 
 
 def select_mask(
@@ -179,3 +204,238 @@ def select_slots(
         count = lambda pred, ref: jnp.sum(  # noqa: E731
             pred(u, ref[..., None]), axis=-1, dtype=jnp.int32)
         return _compact(_topk_mask(u, topk, count), min(topk, scores.shape[-1]))
+
+
+# ---------------------------------------------------------------------------
+# Decode rows over the paged pool: the ranking as two Pallas kernels a layer.
+# ---------------------------------------------------------------------------
+
+# Tokens a grid step of the scoring kernel holds.  One 512-token block of the
+# index plane is 64 KiB, 0.08 us of HBM time beside the ~1-1.5 us a grid step
+# costs (ops/paged_attention.py's header), so a step takes several table
+# entries: 8 at 512-token blocks, 512 KiB a step.  The unroll cap bounds the
+# kernel body (one product an entry).
+INDEX_STEP_TOKENS = 4096
+_INDEX_STEP_UNROLL = 8
+
+_INT_MIN = -(1 << 31)
+
+
+def _index_entries(blk: int, mb: int) -> int:
+    """Table entries a grid step of the ranking kernel covers, evened out
+    over the steps a row takes (as `paged_attention._blocks_per_step`)."""
+    p = max(1, min(-(-INDEX_STEP_TOKENS // blk), _INDEX_STEP_UNROLL, mb))
+    return -(-mb // -(-mb // p))
+
+
+def index_plan(pool_pos, table, q_pos):
+    """The ranking kernel's step list for one-token rows at `q_pos` [B] (-1:
+    an inactive row): `paged_attention._fetch_plan` at this kernel's entries
+    a step.  It does not depend on the layer: derive it once an iteration,
+    outside the layer scan."""
+    entries = _index_entries(pool_pos.shape[1], table.shape[1])
+    return _fetch_plan(pool_pos, table, q_pos.astype(jnp.int32), 1, entries)
+
+
+def plan_row_steps(plan, n_rows: int) -> int:
+    """Steps a row with every table entry live would take under `plan`."""
+    return plan[4].shape[0] // n_rows
+
+
+def _score_kernel(
+    fetch_ref,  # [S * P] int32 scalar-prefetch: `_fetch_plan`'s, as the paged
+    flag_ref,   # [S]       attention kernel reads them
+    src_ref,    # [S]
+    qpos_ref,   # [B] int32 the row's query position (-1: inactive)
+    layer_ref,  # [1] int32 pool layer
+    q_ref,      # [1, Hi, di] index queries
+    w_ref,      # [1, Hi, 1] float32 head weights
+    *rest,      # P key refs [1, 1, 1, BLK, di]; pos ref [1, P, BLK] int32
+    #             (-1: a dead entry's slots); u_ref [1, NS * P, BLK] int32
+    n_entries: int,
+    row_steps: int,
+):
+    """One row's index scores over its live table entries, `n_entries` a grid
+    step, as order-preserving int32 images in the row's output block, which
+    stays in VMEM through the row's steps and leaves once.
+
+    An image is the score's float32 bits made monotone under SIGNED compare
+    (negative floats have their magnitude bits flipped); `_INT_MIN`, below
+    every score, is "not live".  XOR with the sign bit gives `_sortable`'s
+    uint32 image (0: not live)."""
+    P = n_entries
+    k_refs, pos_ref, u_ref = rest[:P], rest[P], rest[P + 1]
+    step = pl.program_id(0)
+    flags = flag_ref[step]
+    src = src_ref[step]
+
+    @pl.when(flags & _FIRST != 0)
+    def _init():
+        u_ref[...] = jnp.full_like(u_ref, _INT_MIN)
+
+    # The grid holds a row's live steps (and one step of a row that has
+    # none); a dead entry inside a live step holds whatever block its operand
+    # fetched last, and its positions are all -1.
+    @pl.when(flags & _LIVE != 0)
+    def _score():
+        qp = qpos_ref[src // row_steps]
+        q = q_ref[0]
+        w = w_ref[0]
+        images = []
+        for j in range(P):
+            s = lax.dot_general(
+                q, k_refs[j][0, 0, 0].astype(q.dtype), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)               # [Hi, BLK]
+            score = jnp.sum(jnp.maximum(s, 0.0) * w, axis=0, keepdims=True)
+            # -0.0 and 0.0 are one score (`index_scores`).
+            score = jnp.where(score == 0.0, 0.0, score)
+            b = lax.bitcast_convert_type(score, jnp.int32)
+            kp = pos_ref[0, j:j + 1, :]
+            images.append(jnp.where(
+                (kp >= 0) & (kp <= qp),
+                jnp.where(b < 0, b ^ jnp.int32(0x7FFFFFFF), b), _INT_MIN))
+        at = pl.multiple_of((src % row_steps) * P, P)
+        u_ref[0, pl.ds(at, P), :] = jnp.concatenate(images, axis=0)
+
+
+def _search_kernel(u_ref, own_ref, found_ref, *, topk: int):
+    """`_kth_value`'s 32 compare-and-count passes and its two counts over
+    every row's images at once, all of them in VMEM: u_ref [B, R, C] int32
+    images (`_score_kernel`'s), own_ref [B, 1, 1] the image of each row's own
+    token; found_ref [B, 8, 128] int32, lines 0, 1, 2 of a row its k-th value
+    (as `_sortable`'s uint32 bits), the count above it and the count at it."""
+    u = u_ref[...]
+    own = own_ref[...]
+
+    def count(pred, ref):                                          # [B, 1, 1]
+        n = jnp.sum(pred(u, ref).astype(jnp.int32), axis=1, keepdims=True)
+        return jnp.sum(n, axis=2, keepdims=True) + pred(own, ref).astype(jnp.int32)
+
+    # The candidate is built in the uint32 image's domain, compared in int32's.
+    def bit(i, t):
+        cand = t | (jnp.int32(1) << (31 - i))
+        enough = count(jnp.greater_equal, cand ^ _INT_MIN) >= topk
+        return jnp.where(enough, cand, t)
+
+    kth = lax.fori_loop(0, 32, bit, jnp.zeros(own.shape, jnp.int32))
+    above = count(jnp.greater, kth ^ _INT_MIN)
+    equal = count(jnp.equal, kth ^ _INT_MIN)
+    line = lax.broadcasted_iota(jnp.int32, found_ref.shape, 1)
+    found_ref[...] = jnp.where(
+        line == 0, kth, jnp.where(line == 1, above, equal))
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "interpret"))
+def paged_index_rank(
+    q_idx: jnp.ndarray,    # [B, Hi, di] index queries (rotated), one a row
+    w: jnp.ndarray,        # [B, Hi] float32 head weights
+    own: jnp.ndarray,      # [B] uint32 image of the row's own token's score
+    #                        (candidate S; 0 for an inactive row)
+    plane: jnp.ndarray,    # [L, 1, NB, BLK, di] the index-key plane, in place
+    plan,                  # `index_plan`'s
+    q_pos: jnp.ndarray,    # [B] int32 query positions; -1: inactive row
+    layer,                 # int32 pool layer
+    *,
+    topk: int,
+    interpret=None,
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """(u [B, S] uint32, kth [B] uint32, above [B] int32, equal [B] int32),
+    S = MB * BLK: `_sortable(index_scores(...))` of every slot of each row's
+    table in sequence order, 0 where the slot is not live for the row, and
+    `_kth_value`'s answers over those and `own` — the largest image `topk`
+    candidates reach, how many lie above it and how many at it.
+
+    Two kernels.  The scores walk the plane through the block table, live
+    entries only, several a grid step, and never copy it.  The search takes
+    every row's images (1 MB for eight rows of 32,768 slots) whole into VMEM,
+    so a pass is one compare-and-count over all rows and nothing leaves the
+    vector unit between passes: 12 us where XLA's passes over HBM take 81
+    (and 34 us as the epilogue of each row's last scoring step, a row at a
+    time; v5e, PERF.md section 6, PR 49)."""
+    B, Hi, di = q_idx.shape
+    BLK = plane.shape[3]
+    n_steps, fetch, flags, src, kpos = plan
+    P = kpos.shape[1]
+    NS = plan_row_steps(plan, B)
+    interpret = _resolve_interpret(interpret)
+
+    def row_map(t, fetch, flags, src, *_):
+        return (src[t] // NS, 0, 0)
+
+    def key_map(j):
+        def index(t, fetch, flags, src, qpos, layer):
+            f = fetch[t * P + j]
+            return (layer[0], 0, jnp.where(f < 0, -1 - f, f), 0, 0)
+        return index
+
+    def pos_map(t, fetch, flags, src, *_):
+        return (src[t], 0, 0)
+
+    # The plane goes in as it is declared, [.., BLK, di], which is the layout
+    # the serving programs' loops carry it in (the pool writes decide that): a
+    # block is then a 128-lane-padded tile.  Handing in the transposed view
+    # [.., di, BLK] — the device's own layout for the pool argument, 64 KiB a
+    # block and a plain product — makes XLA copy the whole plane once a decode
+    # iteration to get from one to the other, as the parent's gather did
+    # (compiled for a described v5e, PERF.md section 6, PR 49).
+    u = pl.pallas_call(
+        functools.partial(_score_kernel, n_entries=P, row_steps=NS),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(n_steps,),
+            in_specs=[
+                pl.BlockSpec((1, Hi, di), row_map),
+                pl.BlockSpec((1, Hi, 1), row_map),
+                *[pl.BlockSpec((1, 1, 1, BLK, di), key_map(j)) for j in range(P)],
+                pl.BlockSpec((1, P, BLK), pos_map),
+            ],
+            out_specs=pl.BlockSpec((1, NS * P, BLK), row_map),
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, NS * P, BLK), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="paged_index_scores",
+    )(fetch, flags, src, q_pos.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q_idx, w.astype(jnp.float32)[:, :, None], *[plane] * P, kpos)
+    sign = jnp.uint32(1 << 31)
+    found = pl.pallas_call(
+        functools.partial(_search_kernel, topk=topk),
+        out_shape=jax.ShapeDtypeStruct((B, _SUBLANES, _LANES), jnp.int32),
+        interpret=interpret,
+        name="paged_index_search",
+    )(u, lax.bitcast_convert_type(own ^ sign, jnp.int32).reshape(B, 1, 1))
+    u = (lax.bitcast_convert_type(u, jnp.uint32) ^ sign).reshape(B, NS * P * BLK)
+    return (u, lax.bitcast_convert_type(found[:, 0, 0], jnp.uint32),
+            found[:, 1, 0], found[:, 2, 0])
+
+
+def paged_select_slots(
+    q_idx: jnp.ndarray,    # [B, 1, Hi, di]
+    w: jnp.ndarray,        # [B, 1, Hi] float32
+    k_idx: jnp.ndarray,    # [B, 1, di] the step's own index keys
+    plane: jnp.ndarray,    # [L, 1, NB, BLK, di]
+    table: jnp.ndarray,    # [B, MB]
+    plan,                  # `index_plan`'s
+    q_pos: jnp.ndarray,    # [B] int32; -1: inactive row
+    layer,
+    topk: int,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """`select_slots` for one-token rows over the paged pool: (chosen
+    candidates [B, 1, k] int32 in ascending order, which of them are live),
+    k = min(topk, S + 1).  Candidate ids below S = MB * BLK are the slots of
+    the row's table in sequence order, S is the step's own token (always
+    live for an active row)."""
+    S = table.shape[1] * plane.shape[3]
+    with jax.named_scope("attn.index"):
+        own = jnp.where(
+            q_pos >= 0, _sortable(index_scores(q_idx, w, k_idx)[:, 0, 0]), 0)
+        u, kth, above, equal = paged_index_rank(
+            q_idx[:, 0], w[:, 0], own, plane, plan, q_pos, layer, topk=topk)
+    with jax.named_scope("attn.select"):
+        # The plan's last step may pad the table: candidate S is the own token.
+        u = jnp.concatenate([u[:, :S], own[:, None]], axis=1)
+        mask = _mask_of(u, topk, kth, above, (kth > 0) & (above + equal > topk))
+        return _compact(mask[:, None], min(topk, S + 1))

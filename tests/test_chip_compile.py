@@ -352,6 +352,72 @@ def test_paged_window_decode_compiles_for_v5e(sds, t_tokens):
         q_pos, sds((), jnp.int32), window))
 
 
+def test_paged_index_rank_compiles_for_v5e(sds):
+    """The decode rows' ranking kernel at Keye-VL-2.0-30B-A3B's indexer
+    geometry and the benchmark cell's pool: 8 rows x 64 blocks of 512 tokens,
+    16 index heads of 64 over a one-head bfloat16 plane of 6 layers, top-2048;
+    8 table entries a grid step, the step list derived outside."""
+    from jax_llama_tpu.ops import key_selection as ks
+
+    rows, mb, blk, layers, hi, di = 8, 64, 512, 6, 16, 64
+    nb = rows * mb
+
+    def rank(q, w, own, plane, pos, table, q_pos, layer):
+        plan = ks.index_plan(pos, table, q_pos)
+        assert plan[4].shape == (rows * 8, 8, blk)
+        return ks.paged_index_rank(
+            q, w, own, plane, plan, q_pos, layer, topk=2048, interpret=False)
+
+    _assert_mosaic(jax.jit(rank).lower(
+        sds((rows, hi, di), jnp.bfloat16), sds((rows, hi), jnp.float32),
+        sds((rows,), jnp.uint32), sds((layers, 1, nb, blk, di), jnp.bfloat16),
+        sds((nb, blk), jnp.int32), sds((rows, mb), jnp.int32),
+        sds((rows,), jnp.int32), sds((), jnp.int32)))
+
+
+def test_sparse_decode_chunk_copies_no_index_plane_an_iteration_for_v5e(sds, monkeypatch):
+    """`_paged_decode_chunk` of the learned-sparse-attention block at the
+    cell's widths (depth 2), 8 iterations over 8 rows x 64 blocks of 512: the
+    ranking kernel reads the index plane as the decode scan carries it, so
+    the only plane-sized copies are the pool argument's at entry and exit
+    (the device keeps it with the longer dimension minor) — none in the
+    scan's body, where the XLA gather it replaces made one an iteration."""
+    import json
+    import re
+    from pathlib import Path
+
+    from test_serving_fused import fused_chunk_operand_shapes
+
+    from jax_llama_tpu import config as config_mod, init_params, serving
+
+    for name in ("flash_attention", "paged_attention", "key_selection"):
+        monkeypatch.setattr(
+            importlib.import_module(f"jax_llama_tpu.ops.{name}"),
+            "_resolve_interpret", lambda _=None: False)
+    raw = json.loads((Path(__file__).resolve().parent.parent / "benchmark" / "configs"
+                      / "Keye-VL-2.0-30B-A3B.json").read_text())
+    keys = {k: v for k, v in raw.items() if k not in (
+        "source", "architecture", "reference", "reduced", "assumed", "deployment")}
+    layers, rows, mb, blk = 2, 8, 64, 512
+    cfg = config_mod.from_published(
+        dict(keys, num_hidden_layers=layers), max_seq_len=mb * blk, attn_impl="auto")
+    place = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: sds(a.shape, a.dtype), tree)
+    params = place(jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pool = place(jax.eval_shape(lambda: serving.init_pool(cfg, rows * mb, blk)))
+    text = serving._paged_decode_chunk.lower(
+        params, pool, *fused_chunk_operand_shapes(sds, rows, mb, 2048)[:13],
+        config=cfg, n_iter=8, all_greedy=True, mesh=None,
+        allow_kernel=True, with_logprobs=False,
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+    plane = re.escape(f"bf16[{layers},1,{rows * mb},{blk},64]")
+    copies = [line.strip()[:120] for line in text.splitlines()
+              if re.search(rf"= {plane}\S* copy\(", line)]
+    entry = text[text.index("\nENTRY "):]
+    assert all(line[:40] in entry for line in copies) and len(copies) <= 2, copies
+
+
 # --- the recurrent block at its cell's shapes (phi4flash-reason-sessions) ----
 
 def _recurrent_cell(sds, monkeypatch, layers):

@@ -132,16 +132,26 @@ def test_prefill_then_decode_through_the_paged_cache(tiny, use_kernel):
         params, pool, ids, toks, jnp.ones((1, P), bool), keys,
         one(0.0, f32), one(1.0, f32), one(0, i32), config=cfg)
     table = jnp.full((1, 8), NB, i32).at[0, :7].set(jnp.arange(7))
+    # the decode program ranks by the kernel, under its scope, exactly when
+    # it runs over the paged cache
+    text = serving._paged_decode_chunk.lower(
+        params, pool, table, one(7, i32), one(P, i32), tau, one(0.0, f32),
+        one(P, i32), jnp.ones((1,), bool), one(G, i32), jnp.full((1, 1), -1, i32),
+        keys, one(0.0, f32), one(1.0, f32), one(0, i32), config=cfg, n_iter=1,
+        all_greedy=True, allow_kernel=use_kernel).as_text(debug_info=True)
+    assert ('"attn.index/jit(paged_index_rank)"' in text) == use_kernel
     served, _, stats = decode_row(
         params, cfg, pool, table, 7, P, int(tau[0]), G - 1, use_kernel=use_kernel)
     assert _deficit(params, raw, [int(t) for t in toks[0]], served).max() < TOL
-    selected, candidates, dense_rows = stats[-3:]
+    selected, candidates, dense_rows, steps_run, steps_table = stats[-5:]
     if use_kernel:
         # 7 iterations x 2 layers, contexts 97..103, 16 chosen of each
         assert selected == 7 * 2 * TOPK and dense_rows == 0
         assert candidates == 2 * sum(range(97, 104))
+        # the eight table entries are one grid step of the ranking kernel
+        assert steps_run == steps_table == 7 * 2
     else:
-        assert selected == candidates == dense_rows == 0
+        assert selected == candidates == dense_rows == steps_run == steps_table == 0
 
 
 @pytest.mark.parametrize("use_kernel", [True, False], ids=["paged", "gathered-view"])
@@ -178,14 +188,20 @@ def test_served_through_the_fused_lane_and_a_prefix_hit(tiny, use_kernel):
     from jax_llama_tpu.obs import metric_meta
 
     for name in ("attn_selected_slots_total", "attn_candidate_slots_total",
-                 "attn_select_dense_rows_total", "moe_assignments_total"):
+                 "attn_select_dense_rows_total", "attn_index_steps_run_total",
+                 "attn_index_steps_table_total", "moe_assignments_total"):
         assert metric_meta(name)[0] == "counter" and name in stats
     assert stats["moe_layer_calls_total"] > 0
     if use_kernel:
         assert 0 < stats["attn_selected_slots_total"] < stats["attn_candidate_slots_total"]
         assert stats["attn_select_dense_rows_total"] == 0   # every context past 16
+        # the 16 table entries are two grid steps of the ranking kernel and
+        # most contexts end in the first (an idle row counts on neither side)
+        rows = stats["attn_selected_slots_total"] // TOPK           # x layers
+        assert stats["attn_index_steps_table_total"] == 2 * rows
+        assert rows <= stats["attn_index_steps_run_total"] < 2 * rows
     else:
-        assert stats["attn_candidate_slots_total"] == 0
+        assert stats["attn_candidate_slots_total"] == stats["attn_index_steps_table_total"] == 0
     assert stats["host_syncs_per_token"] < 1
 
 
@@ -296,6 +312,124 @@ def test_ties_go_to_the_lower_index():
     # the k-th value IS tied: a rule that took every equal score would pass k
     kth = np.sort(scores[0])[::-1][k - 1]
     assert (scores[0] == kth).sum() > 1 or (scores[1] == np.sort(scores[1])[::-1][k - 1]).sum() > 1
+
+
+def _paged_rows(ctx, blk, mb, di, seed=0, keys_of=None):
+    """A one-layer-of-two index plane under a table of `mb` entries a row for
+    rows of `ctx` cached tokens (None: an inactive row), blocks dealt in
+    turn so that a row's blocks are not consecutive; unused entries hold the
+    sentinel.  (plane, pool_pos, table, q_pos)."""
+    rng = np.random.RandomState(seed)
+    B = len(ctx)
+    nb = B * mb
+    plane = rng.standard_normal((2, 1, nb, blk, di)).astype(np.float32)
+    table = np.full((B, mb), nb, np.int32)
+    pos = np.full((nb, blk), -1, np.int32)
+    for b, c in enumerate(ctx):
+        for j in range(-(-(c or 0) // blk)):
+            table[b, j] = j * B + b
+            n = min(blk, c - j * blk)
+            pos[j * B + b, :n] = np.arange(j * blk, j * blk + n)
+            if keys_of is not None:
+                plane[1, 0, j * B + b, :n] = keys_of(b, np.arange(j * blk, j * blk + n))
+    q_pos = np.asarray([-1 if c is None else c for c in ctx], np.int32)
+    return jnp.asarray(plane), jnp.asarray(pos), jnp.asarray(table), jnp.asarray(q_pos)
+
+
+def _xla_ranking(q_idx, w, k_idx, plane, pos, table, q_pos, layer):
+    """The decode rows' candidates as the XLA stages make them: bit images
+    [B, 1, S + 1] with 0 for a slot that is not live."""
+    from jax_llama_tpu.ops.paged_attention import paged_rows, paged_slot_positions
+
+    keys = jnp.concatenate([paged_rows(plane, table, layer), k_idx], axis=1)
+    cand = jnp.concatenate([paged_slot_positions(pos, table), q_pos[:, None]], axis=1)
+    live = (cand >= 0) & (cand <= q_pos[:, None])
+    return jnp.where(live[:, None], key_selection._sortable(
+        key_selection.index_scores(q_idx, w, keys)), 0)
+
+
+@pytest.mark.parametrize("step_tokens", [32, 4096], ids=["two-entries-a-step", "one-step-a-row"])
+def test_the_ranking_kernel_scores_live_blocks_and_finds_the_kth_value(monkeypatch, step_tokens):
+    """`paged_index_rank` on rows of different contexts in one call — one
+    shorter than `topk` (every live slot), one ending mid-block, one
+    inactive, one whose table is full — against `index_scores` and
+    `_kth_value` over the row's whole table: the same bit images (0 where
+    a slot is not live, and in every step the grid never visits), the same
+    k-th value and counts, the same chosen slots."""
+    monkeypatch.setattr(key_selection, "INDEX_STEP_TOKENS", step_tokens)
+    BLKS, MB, DI, HI, LAYER = 16, 6, 16, 4, 1
+    ctx = [9, 53, None, 96, 70]
+    plane, pos, table, q_pos = _paged_rows(ctx, BLKS, MB, DI)
+    B, S = len(ctx), MB * BLKS
+    rng = np.random.RandomState(1)
+    q_idx = jnp.asarray(rng.standard_normal((B, 1, HI, DI)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((B, 1, HI)), jnp.float32)   # either sign
+    k_idx = jnp.asarray(rng.standard_normal((B, 1, DI)), jnp.float32)
+    plan = key_selection.index_plan(pos, table, q_pos)
+    entries = 2 if step_tokens == 32 else MB
+    assert plan[4].shape[1] == entries and key_selection.plan_row_steps(plan, B) == MB // entries
+    # live steps and one for the inactive row
+    assert int(plan[0]) == sum(-(-(c or 1) // (entries * BLKS)) for c in ctx)
+
+    want = _xla_ranking(q_idx, w, k_idx, plane, pos, table, q_pos, LAYER)
+    own = want[:, 0, S]
+    u, kth, above, equal = key_selection.paged_index_rank(
+        q_idx[:, 0], w[:, 0], own, plane, plan, q_pos, LAYER, topk=TOPK)
+    got, want = np.asarray(u[:, :S]).astype(np.int64), np.asarray(want[:, 0]).astype(np.int64)
+    assert ((got == 0) == (want[:, :S] == 0)).all()
+    # the images are float32 bits: the order of a sum of four heads is all
+    # that may differ
+    assert np.abs(got - want[:, :S]).max() <= 2
+    assert (got[2] == 0).all() and (got[0] != 0).sum() == 9 and (got[4] != 0).sum() == 70
+
+    # the search in VMEM against XLA's passes over the kernel's own images
+    mine = jnp.concatenate([u[:, :S], own[:, None]], axis=1)[:, None]
+    count = lambda pred, ref: jnp.sum(pred(mine, ref[..., None]), axis=-1, dtype=jnp.int32)  # noqa: E731
+    kth_x, above_x, over_x = key_selection._kth_value(mine, TOPK, count)
+    assert (np.asarray(kth) == np.asarray(kth_x[:, 0])).all()
+    assert (np.asarray(above) == np.asarray(above_x[:, 0])).all()
+    assert (np.asarray(equal) == np.asarray(count(jnp.equal, kth_x)[:, 0])).all()
+    assert int(kth[0]) == int(kth[2]) == 0 and (np.asarray(kth)[[1, 3, 4]] > 0).all()
+
+    chosen, chosen_live = key_selection.paged_select_slots(
+        q_idx, w, k_idx, plane, table, plan, q_pos, LAYER, TOPK)
+    ref, ref_live = key_selection._compact(key_selection._topk_mask(mine, TOPK, count), TOPK)
+    assert (np.asarray(chosen_live) == np.asarray(ref_live)).all()
+    assert (np.asarray(chosen) == np.asarray(ref))[np.asarray(ref_live)].all()
+    assert np.asarray(chosen_live).sum(axis=-1)[:, 0].tolist() == [10, TOPK, 0, TOPK, TOPK]
+    # the step's own token is candidate S, and the shortest row takes it
+    assert int(chosen[0, 0, 9]) == S
+
+
+def test_ties_go_to_the_lower_slot_through_the_ranking_kernel():
+    """Index keys that repeat in the pool give exactly equal scores in the
+    kernel too, and the chosen are the lowest slots among the equals."""
+    BLKS, MB, DI, HI, k = 8, 3, 8, 2, 4
+    rng = np.random.RandomState(0)
+    base = rng.standard_normal((3, DI)).astype(np.float32)
+    which = np.asarray([0, 1, 1, 2, 1, 0, 1, 2, 1, 1, 0, 2, 1, 1, 0, 2, 1, 0, 2])
+    plane, pos, table, q_pos = _paged_rows(
+        [19, 12], BLKS, MB, DI, keys_of=lambda b, at: base[which[at]])
+    q_idx = jnp.asarray(rng.standard_normal((2, 1, HI, DI)), jnp.float32)
+    w = jnp.asarray(np.abs(rng.standard_normal((2, 1, HI))), jnp.float32)
+    # the step's own token is scored by another product (XLA's, outside the
+    # kernel): it competes, and ties with nothing
+    own = rng.standard_normal((1, DI)).astype(np.float32)
+    k_idx = jnp.asarray(own[[0, 0]])[:, None]
+    plan = key_selection.index_plan(pos, table, q_pos)
+    chosen, chosen_live = key_selection.paged_select_slots(
+        q_idx, w, k_idx, plane, table, plan, q_pos, 1, k)
+    assert np.asarray(chosen_live).all()
+    tied = 0
+    for b, n in enumerate((19, 12)):
+        keys = jnp.asarray(np.concatenate([base[which[:n]], own]))[None]
+        scores = np.asarray(key_selection.index_scores(q_idx[b:b + 1], w[b:b + 1], keys))[0, 0]
+        order = np.argsort(-scores, kind="stable")[:k]
+        # candidate ids: a slot's place in the table, MB * BLKS the own token
+        want = sorted(MB * BLKS if i == n else int(i) for i in order)
+        assert np.asarray(chosen[b, 0]).tolist() == want, b
+        tied += (scores == np.sort(scores)[::-1][k - 1]).sum() > 1
+    assert tied    # the k-th value IS tied: taking every equal score would pass k
 
 
 @pytest.mark.parametrize("n,k", [(5, 3), (40, 16), (1100, 64), (1024, 2048)])
@@ -490,6 +624,14 @@ def test_scopes_are_in_the_lowered_programs(tiny):
         params, toks[:, :1], pos[:, :1], cache).as_text(debug_info=True)
     for scope in ("attn.proj", "attn.index", "attn.select", "attn.sparse"):
         assert scope in text, scope
+    # the ranking kernel is one call under the index scope, and the XLA stage
+    # it replaces (the gather of the table's index keys) is not in the step
+    assert '"attn.index/jit(paged_index_rank)"' in text
+    assert '"attn.index/jit(_take)"' not in text
+    # several tokens a row over the pool keep the XLA stages
+    text = jax.jit(lambda p, t, q, c: jlt.forward(p, t, q, cfg, cache=c)[0]).lower(
+        params, toks[:, :2], pos[:, :2], cache).as_text(debug_info=True)
+    assert "paged_index_rank" not in text and '"attn.index/jit(_take)"' in text
 
 
 def test_every_parameter_has_a_partition_rule(tiny):
@@ -511,7 +653,7 @@ def test_the_pool_has_a_third_plane_under_the_same_table(tiny):
     # a token's two KV heads side by side in one row: a gather costs by the row
     assert pool.k.shape == pool.v.shape == (2, 1, 8, BLK, 32)
     assert pool.idx.shape == (2, 1, 8, BLK, 16)
-    assert pool.k_scale is None and pool.stats.shape == (dsa_moe.N_STATS,) == (9,)
+    assert pool.k_scale is None and pool.stats.shape == (dsa_moe.N_STATS,) == (11,)
     cache = jlt.init_cache(cfg, batch=2, max_len=32)
     assert cache.k.shape == (2, 2, 32, 1, 32) and cache.idx.shape == (2, 2, 32, 1, 16)
     from jax_llama_tpu.kvcache import pool_block_bytes
