@@ -35,9 +35,10 @@ How the new tokens attend:
   (`lax.map`), so a 32,768-token whole-prompt insert holds one tile's scores.
 * decode rows over the paged pool: each row ranks its own LIVE blocks' index
   keys in two Pallas kernels a layer (`key_selection.paged_select_slots`: the
-  one-head plane read in place by the block table and scored on the MXU, the
-  k-th-value search over all rows' scores in VMEM, an exact top-k), and
-  attends the chosen slots gathered from the pool by XLA
+  one-head plane read in place by the block table and scored on the MXU;
+  then the k-th-value search over all rows' scores, the mask and the list of
+  the chosen slots, all in VMEM: an exact top-k that leaves the chip as a
+  list), and attends the chosen slots gathered from the pool by XLA
   (`ops.paged_attention.paged_sparse_attention`: no kernel there yet).
   Several tokens a row over the pool, which no cell dispatches, keep the XLA
   ranking over the row's whole table (`paged_rows`, `select_slots`).
@@ -62,8 +63,10 @@ Parameters are one stacked tree, scanned:
 Every call counts into the cache's `stats` (`N_STATS` int32): the routing
 counts of `ops.moe.STATS`, the window block's two step counts (zero here: the
 layout of the fetch's tail is shared), then `SELECT_STATS` of the paged decode
-rows, summed over rows and layers (the last two: the ranking kernel's live
-grid steps, and the steps the rows' whole tables would take).
+rows, summed over rows and layers (the scoring kernel's live grid steps and
+the steps the rows' whole tables would take; last the rows whose k-th value
+was shared by more candidates than it had room for, the one count the layers
+make themselves).
 """
 
 from __future__ import annotations
@@ -92,9 +95,11 @@ Params = Dict[str, Any]
 # What a call counts of its paged decode rows' selection, after the window
 # block's counters: slots attended, live slots they were chosen from, and
 # rows whose context was no longer than `topk` (which took all of it); then
-# the grid steps the ranking kernel ran and those a full table would take.
+# the grid steps the ranking kernel ran and those a full table would take;
+# last the rows whose k-th value was shared by more candidates than it had
+# room for (the list kernel's ties by slot order decided something).
 SELECT_STATS = ("selected_slots", "candidate_slots", "select_dense_rows",
-                "index_steps_run", "index_steps_table")
+                "index_steps_run", "index_steps_table", "select_tie_rows")
 N_STATS = afmoe.N_STATS + len(SELECT_STATS)
 
 # Queries a selection pass: one mask tile of the flash kernel's q block.
@@ -197,7 +202,9 @@ def forward(
     use_flash = (not paged and T > FLASH_MIN_SEQ
                  and config.attn_impl in ("flash", "auto")
                  and not (cache is not None and cache.per_row_index))
-    select_stats = jnp.zeros((len(SELECT_STATS),), jnp.int32)
+    # All of `SELECT_STATS` but the last follow from shapes and positions;
+    # the ties are the layers' own count.
+    select_stats = jnp.zeros((len(SELECT_STATS) - 1,), jnp.int32)
     if paged:
         NB, BLK = cache.pos.shape
         # A row is active or not as a whole (see `llama.paged_forward`).
@@ -294,11 +301,13 @@ def forward(
                 lp["index_k_norm"], lp["index_k_bias"], eps)
             k_idx = _rope_half(k_idx[:, :, None, :], cos_i, sin_i)[:, :, 0]
             w = qeinsum(a, lp["index_w"], "btd,dh->bth", adt).astype(jnp.float32)
+        ties = jnp.int32(0)
         if paged:
             if T == 1:
-                chosen, chosen_live = paged_select_slots(
+                chosen, chosen_live, tied = paged_select_slots(
                     q_idx, w, k_idx, cache.idx, cache.table, rank_plan,
                     q_pos[:, 0], li, topk)
+                ties = jnp.sum(tied, dtype=jnp.int32)
             else:
                 with jax.named_scope("attn.index"):
                     keys = jnp.concatenate(
@@ -315,7 +324,7 @@ def forward(
                 q, q_idx, w, with_new(ck, k), with_new(cv, v), with_new(ci, k_idx))
         with jax.named_scope("attn.proj"):
             out = qeinsum(out, lp["o"], "bthk,hkd->btd", adt)
-        return out, (k, v, k_idx)
+        return out, (k, v, k_idx), ties
 
     # The experts stay out of the scanned tree (see mla_moe.forward).
     scanned = dict(params["moe_layers"])
@@ -328,24 +337,25 @@ def forward(
 
     def body(x, xs):
         lp, li, *planes = xs
-        out, kept = attention(x, lp, *(planes or (None, None, None)), li)
+        out, kept, ties = attention(x, lp, *(planes or (None, None, None)), li)
         x = x + out
         f, stats = routed_ffn(rms_norm(x, lp["mlp_norm"], eps), lp, experts, li,
                             valid, config)
-        return x + f, (kept, stats)
+        return x + f, (kept, stats, ties)
 
     if config.scan_layers:
-        x, ((new_k, new_v, new_i), stats) = lax.scan(
+        x, ((new_k, new_v, new_i), stats, ties) = lax.scan(
             body, x, xs, unroll=config.scan_unroll)
     else:
         outs = []
         for i in range(L):
             x, ys = body(x, jax.tree.map(lambda a: a[i], xs))
             outs.append(ys)
-        (new_k, new_v, new_i), stats = jax.tree.map(lambda *a: jnp.stack(a), *outs)
+        (new_k, new_v, new_i), stats, ties = jax.tree.map(lambda *a: jnp.stack(a), *outs)
     stats = jnp.concatenate([
         jnp.sum(stats, axis=0),
-        jnp.zeros((len(afmoe.ATTN_STATS),), jnp.int32), select_stats])
+        jnp.zeros((len(afmoe.ATTN_STATS),), jnp.int32), select_stats,
+        jnp.sum(ties, keepdims=True)])
     # One head a plane: [L, B, T, 1, width].
     new_k, new_v, new_i = (
         a.reshape(a.shape[:3] + (1, -1)) for a in (new_k, new_v, new_i))

@@ -526,6 +526,10 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "Grid steps the decoding rows' whole block tables would "
                    "take in the paged index-ranking kernel (rows x layers "
                    "x steps a table)"),
+    "attn_select_tie_rows_total": _reg(
+        "counter", "Paged decode rows (x layers) whose k-th index score was "
+                   "shared by more candidates than the selection had room "
+                   "for: the lower slots among the equals were taken"),
     # -- recurrent state layers (models/sambay.py, models/falcon_h1.py; zero
     # without) ---------------------------------------------------------------
     "ssm_snapshots_taken_total": _reg(

@@ -17,17 +17,22 @@ Two forms of one rule, both EXACT (`approx_max_k` is not the model):
   any query of the block may see: the count is a value, so a chunk early in a
   32,768-slot view pays for its context and not for the view.
 * `paged_select_slots`, for decode rows over the paged pool: two Pallas
-  kernels a layer (`paged_index_rank`).  One reads the index-key plane in
-  place by the block table, live blocks only (`paged_attention._fetch_plan`'s
-  step list, several blocks a grid step), and scores them on the MXU; one
-  holds every row's bit images in VMEM and runs the same search there.  What
-  XLA still does is the mask from the k-th value and the chosen compacted to
-  a list of slots for a gather, by running counts at two levels (no sort, no
-  scatter).  As five XLA stages sized by the TABLE the ranking cost thirty
-  times its bytes (PERF.md section 6, PR 49).
+  kernels a layer.  `paged_index_scores` reads the index-key plane in place
+  by the block table, live blocks only (`paged_attention._fetch_plan`'s step
+  list, several blocks a grid step), and scores them on the MXU;
+  `paged_index_select` holds every row's bit images in VMEM, runs the same
+  search there, and on the images it just read makes the mask from the k-th
+  value (ties by the slots' order) and compacts the chosen to a list of slots
+  for a gather: running counts as products with 0/1 triangles on the MXU, a
+  place's slot by compare-and-count, no sort and no scatter (`_list_row`).
+  The list leaves the chip's VMEM as a list: as XLA stages over HBM the mask
+  and the compaction cost 0.30 ms a layer for eight rows of 32,768 slots, a
+  33.5 MB intermediate among them; in the kernel 0.017 (v5e, PERF.md
+  section 6, PR 50; the scores and the search: PR 49).
 * `select_slots`: the decode rows' rule over candidate scores that are
-  already an array (several tokens a row over the pool, which no cell
-  dispatches, and the tests' reference for the kernel).
+  already an array, in XLA (`_mask_of`, `_compact`): several tokens a row
+  over the pool, which no cell dispatches, and the tests' reference for the
+  kernels.
 
 A context no longer than k selects every live key, and the mask is the
 causal mask.
@@ -298,15 +303,16 @@ def _score_kernel(
         u_ref[0, pl.ds(at, P), :] = jnp.concatenate(images, axis=0)
 
 
-def _search_kernel(u_ref, own_ref, found_ref, *, topk: int):
-    """`_kth_value`'s 32 compare-and-count passes and its two counts over
-    every row's images at once, all of them in VMEM: u_ref [B, R, C] int32
-    images (`_score_kernel`'s), own_ref [B, 1, 1] the image of each row's own
-    token; found_ref [B, 8, 128] int32, lines 0, 1, 2 of a row its k-th value
-    (as `_sortable`'s uint32 bits), the count above it and the count at it."""
-    u = u_ref[...]
-    own = own_ref[...]
+# Slots a sub-line of the list kernel: a line (one table entry, BLK slots) is
+# walked in sub-lines whose running count, at most 256, is exact in bfloat16.
+_SUB = 256
+_LIST_TILE = 512     # places of the list a step: [_SUB, _LIST_TILE] temporaries
 
+
+def _kth_images(u, own, topk: int):
+    """`_kth_value` over int32 images `u` [B, R, C] and `own` [B, 1, 1] (see
+    `_score_kernel`), all rows at once: (kth, above, equal) [B, 1, 1], the
+    k-th value as `_sortable`'s uint32 bits."""
     def count(pred, ref):                                          # [B, 1, 1]
         n = jnp.sum(pred(u, ref).astype(jnp.int32), axis=1, keepdims=True)
         return jnp.sum(n, axis=2, keepdims=True) + pred(own, ref).astype(jnp.int32)
@@ -318,46 +324,149 @@ def _search_kernel(u_ref, own_ref, found_ref, *, topk: int):
         return jnp.where(enough, cand, t)
 
     kth = lax.fori_loop(0, 32, bit, jnp.zeros(own.shape, jnp.int32))
-    above = count(jnp.greater, kth ^ _INT_MIN)
-    equal = count(jnp.equal, kth ^ _INT_MIN)
+    return (kth, count(jnp.greater, kth ^ _INT_MIN),
+            count(jnp.equal, kth ^ _INT_MIN))
+
+
+def _ones(cond):
+    """A 0/1 bfloat16 operand of the MXU from a mask."""
+    return jnp.where(cond, 1.0, 0.0).astype(jnp.bfloat16)
+
+
+def _count(a, b):
+    """a @ b of 0/1 (or small whole-number) bfloat16 operands, float32."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _lanes(row, n: int):
+    """A lane-replicated [1, 128] row at `n` lanes (Mosaic broadcasts along
+    one dimension at a time: a [1, 1] value does not meet a [R, C] one)."""
+    return row[:, :n] if n <= _LANES else jnp.tile(row, (1, n // _LANES))
+
+
+def _list_row(u, found, tri_c, tri_w, *, topk: int, n_slots: int, n_places: int):
+    """One row's chosen list, `_mask_of` + `_compact` on values in VMEM: `u`
+    [R, C] int32 images (a line is a table entry), `found` [8, 128] the
+    row's lines of `_select_kernel`'s found_ref; `tri_c` [C, C] and `tri_w`
+    [W, W] the 0/1 triangles (below).  Returns (ids, live) [1, n_places]
+    int32, `n_places` whole tiles of `_LIST_TILE`.
+
+    Running counts are products with 0/1 triangles on the MXU (bfloat16
+    operands, float32 sums: exact).  The mask: an image above the k-th value,
+    and those AT it in slot order while room is left, by the ties' own running
+    count (a line's, plus the lines' before it); the own token is the last
+    candidate.  The list: the lines are cut into sub-lines of W <= 256 slots,
+    stacked [R * C / W, W]; place j belongs to the sub-line whose running
+    total passes j (`hot`, one compare pair a sub-line a place), a product of
+    the sub-lines' running counts with `hot` fetches that sub-line's counts
+    for every place of a tile, and the slot is how many of them are <= the
+    place's rank inside the sub-line.  No sort, no scatter, and nothing the
+    size of [k, C] outlives a tile."""
+    R, C = u.shape
+    W = tri_w.shape[0]
+    f32 = jnp.float32
+    lane_sum = lambda a: jnp.sum(a, axis=1, keepdims=True)   # noqa: E731
+    all_sum = lambda a: jnp.sum(lane_sum(a), axis=0, keepdims=True)  # noqa: E731
+    lower = _ones(lax.broadcasted_iota(jnp.int32, (R, R), 1)
+                  < lax.broadcasted_iota(jnp.int32, (R, R), 0))    # [r, r']: r' < r
+    kth, own = found[0:1] ^ _INT_MIN, found[3:4]                   # [1, 128] images
+    room = (topk - found[1:2]).astype(f32)
+
+    tie = (u == _lanes(kth, C)) & (u != _INT_MIN)
+    tie_b = _ones(tie)
+    rank = lane_sum(_count(lower, tie_b)) + _count(tie_b, tri_c)   # [R, C]
+    mask = (u > _lanes(kth, C)) | (tie & (rank <= _lanes(room, C)))
+    own_in = (own > kth) | ((own == kth) & (own != _INT_MIN)
+                            & (all_sum(tie_b.astype(f32)) + 1.0 <= room))
+    m = _ones(mask)
+    total = all_sum(m.astype(f32)) + jnp.zeros_like(room)          # [1, 128]
+    n_live = _lanes(total + jnp.where(own_in, 1.0, 0.0), _LIST_TILE)
+    total = _lanes(total, _LIST_TILE)
+
+    # Sub-lines, stacked half by half: sub-line h of line r is row h * R + r.
+    halves = [m[:, h * W:(h + 1) * W] for h in range(C // W)]
+    before = lane_sum(_count(lower, m))                            # [R, 1] lines
+    starts, ends, firsts = [], [], []
+    line = lax.broadcasted_iota(jnp.int32, (R, 1), 0).astype(f32) * C
+    for h, half in enumerate(halves):
+        starts.append(before)
+        before = before + lane_sum(half.astype(f32))
+        ends.append(before)
+        firsts.append(line + h * W)
+    start, end, first = (jnp.concatenate(a, axis=0) for a in (starts, ends, firsts))
+    # inside[c, s]: set entries of sub-line s at or before slot c.
+    inside = lax.dot_general(
+        tri_w, jnp.concatenate(halves, axis=0), (((1,), (1,)), ((), ())),
+        preferred_element_type=f32).astype(jnp.bfloat16)           # [W, R * C / W]
+
+    ids, live = [], []
+    for t in range(n_places // _LIST_TILE):
+        j = (lax.broadcasted_iota(jnp.int32, (1, _LIST_TILE), 1)
+             + t * _LIST_TILE).astype(f32)
+        hot = (start <= j) & (j < end)                             # [subs, tile]
+        down = lambda a: jnp.sum(jnp.where(hot, a, 0.0), axis=0, keepdims=True)  # noqa: E731
+        counts = _count(inside, _ones(hot))                        # [W, tile]
+        slot = down(first) + jnp.sum(
+            jnp.where(counts <= down(j - start), 1.0, 0.0), axis=0, keepdims=True)
+        ids.append(jnp.where(j < total, slot, float(n_slots)))
+        live.append(j < n_live)
+    return tuple(jnp.concatenate(a, axis=1).astype(jnp.int32) for a in (ids, live))
+
+
+def _select_kernel(u_ref, own_ref, found_ref, chosen_ref, live_ref,
+                   tri_c_ref, tri_w_ref, *, topk: int, n_slots: int):
+    """The decode rows' selection from their images, all of it in VMEM:
+    u_ref [B, R, C] int32 images (`_score_kernel`'s), own_ref [B, 1, 1] the
+    image of each row's own token.  First `_kth_value`'s 32 compare-and-count
+    passes and its two counts over every row at once (found_ref [B, 8, 128]
+    int32, lines 0 to 3 of a row its k-th value as `_sortable`'s uint32
+    bits, the count above it, the count at it and the own token's image);
+    then a row at a time the chosen list (`_list_row`): chosen_ref /
+    live_ref [B, K] int32, K the list's length rounded up to whole tiles."""
+    own = own_ref[...]
+    kth, above, equal = _kth_images(u_ref[...], own, topk)
     line = lax.broadcasted_iota(jnp.int32, found_ref.shape, 1)
     found_ref[...] = jnp.where(
-        line == 0, kth, jnp.where(line == 1, above, equal))
+        line == 0, kth, jnp.where(line == 1, above, jnp.where(line == 2, equal, own)))
+
+    C, W = tri_c_ref.shape[0], tri_w_ref.shape[0]
+    iota = lambda n, d: lax.broadcasted_iota(jnp.int32, (n, n), d)   # noqa: E731
+    tri_c_ref[...] = _ones(iota(C, 0) <= iota(C, 1))    # [c', c]: c' <= c
+    tri_w_ref[...] = _ones(iota(W, 1) <= iota(W, 0))    # [c, c']: c' <= c
+
+    def row(b, carry):
+        chosen_ref[pl.ds(b, 1), :], live_ref[pl.ds(b, 1), :] = _list_row(
+            u_ref[b], found_ref[b], tri_c_ref[...], tri_w_ref[...],
+            topk=topk, n_slots=n_slots, n_places=chosen_ref.shape[1])
+        return carry
+
+    lax.fori_loop(0, u_ref.shape[0], row, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("topk", "interpret"))
-def paged_index_rank(
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def paged_index_scores(
     q_idx: jnp.ndarray,    # [B, Hi, di] index queries (rotated), one a row
     w: jnp.ndarray,        # [B, Hi] float32 head weights
-    own: jnp.ndarray,      # [B] uint32 image of the row's own token's score
-    #                        (candidate S; 0 for an inactive row)
     plane: jnp.ndarray,    # [L, 1, NB, BLK, di] the index-key plane, in place
     plan,                  # `index_plan`'s
     q_pos: jnp.ndarray,    # [B] int32 query positions; -1: inactive row
     layer,                 # int32 pool layer
     *,
-    topk: int,
     interpret=None,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """(u [B, S] uint32, kth [B] uint32, above [B] int32, equal [B] int32),
-    S = MB * BLK: `_sortable(index_scores(...))` of every slot of each row's
-    table in sequence order, 0 where the slot is not live for the row, and
-    `_kth_value`'s answers over those and `own` — the largest image `topk`
-    candidates reach, how many lie above it and how many at it.
+) -> jnp.ndarray:
+    """[B, R, BLK] int32 images (`_score_kernel`) of
+    `index_scores(...)` over every slot of each row's table in sequence
+    order, a table entry a line (R >= MB: the plan's last step may pad the
+    table, with lines that are not live), `_INT_MIN` where the slot is not
+    live for the row.
 
-    Two kernels.  The scores walk the plane through the block table, live
-    entries only, several a grid step, and never copy it.  The search takes
-    every row's images (1 MB for eight rows of 32,768 slots) whole into VMEM,
-    so a pass is one compare-and-count over all rows and nothing leaves the
-    vector unit between passes: 12 us where XLA's passes over HBM take 81
-    (and 34 us as the epilogue of each row's last scoring step, a row at a
-    time; v5e, PERF.md section 6, PR 49)."""
+    The kernel walks the plane through the block table, live entries only,
+    several a grid step, and never copies it."""
     B, Hi, di = q_idx.shape
     BLK = plane.shape[3]
     n_steps, fetch, flags, src, kpos = plan
     P = kpos.shape[1]
     NS = plan_row_steps(plan, B)
-    interpret = _resolve_interpret(interpret)
 
     def row_map(t, fetch, flags, src, *_):
         return (src[t] // NS, 0, 0)
@@ -378,7 +487,7 @@ def paged_index_rank(
     # block and a plain product — makes XLA copy the whole plane once a decode
     # iteration to get from one to the other, as the parent's gather did
     # (compiled for a described v5e, PERF.md section 6, PR 49).
-    u = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_score_kernel, n_entries=P, row_steps=NS),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
@@ -395,20 +504,51 @@ def paged_index_rank(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
-        interpret=interpret,
+        interpret=_resolve_interpret(interpret),
         name="paged_index_scores",
     )(fetch, flags, src, q_pos.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1),
       q_idx, w.astype(jnp.float32)[:, :, None], *[plane] * P, kpos)
-    sign = jnp.uint32(1 << 31)
-    found = pl.pallas_call(
-        functools.partial(_search_kernel, topk=topk),
-        out_shape=jax.ShapeDtypeStruct((B, _SUBLANES, _LANES), jnp.int32),
-        interpret=interpret,
-        name="paged_index_search",
-    )(u, lax.bitcast_convert_type(own ^ sign, jnp.int32).reshape(B, 1, 1))
-    u = (lax.bitcast_convert_type(u, jnp.uint32) ^ sign).reshape(B, NS * P * BLK)
-    return (u, lax.bitcast_convert_type(found[:, 0, 0], jnp.uint32),
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "n_slots", "interpret"))
+def paged_index_select(
+    u: jnp.ndarray,        # [B, R, BLK] int32 images (`paged_index_scores`)
+    own: jnp.ndarray,      # [B] uint32 image of the row's own token's score
+    #                        (candidate `n_slots`; 0 for an inactive row)
+    *,
+    topk: int,
+    n_slots: int,          # S = MB * BLK: the slots of a row's table
+    interpret=None,
+):
+    """(chosen [B, k] int32, chosen_live [B, k] bool, kth [B] uint32, above
+    [B] int32, equal [B] int32), k = min(topk, S + 1): `_kth_value`'s answers
+    over the images and `own`, and `_compact(_mask_of(...), k)` of them — the
+    chosen candidates in ascending order, `n_slots` the own token — in ONE
+    grid-less kernel that holds every row's images (1 MB for eight rows of
+    32,768 slots) in VMEM.  A pass of the search is one compare-and-count
+    over all rows, and nothing leaves the vector unit between passes: 12 us
+    where XLA's passes over HBM take 81 (PERF.md section 6, PR 49); the mask
+    and the list follow on the same images a row at a time.  A place past the
+    row's count holds `n_slots` where `_compact` holds its clamp: compare
+    under `chosen_live`."""
+    B, R, C = u.shape
+    if C > _SUB and C % _SUB:
+        raise ValueError(f"a block of {C} slots is not whole sub-lines of {_SUB}")
+    k = min(topk, n_slots + 1)
+    K = -(-k // _LIST_TILE) * _LIST_TILE
+    found, chosen, live = pl.pallas_call(
+        functools.partial(_select_kernel, topk=topk, n_slots=n_slots),
+        out_shape=[jax.ShapeDtypeStruct((B, _SUBLANES, _LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((B, K), jnp.int32),
+                   jax.ShapeDtypeStruct((B, K), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((C, C), jnp.bfloat16),
+                        pltpu.VMEM((min(C, _SUB),) * 2, jnp.bfloat16)],
+        interpret=_resolve_interpret(interpret),
+        name="paged_index_select",
+    )(u, lax.bitcast_convert_type(own ^ jnp.uint32(1 << 31), jnp.int32).reshape(B, 1, 1))
+    return (chosen[:, :k], live[:, :k] != 0,
+            lax.bitcast_convert_type(found[:, 0, 0], jnp.uint32),
             found[:, 1, 0], found[:, 2, 0])
 
 
@@ -422,20 +562,20 @@ def paged_select_slots(
     q_pos: jnp.ndarray,    # [B] int32; -1: inactive row
     layer,
     topk: int,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """`select_slots` for one-token rows over the paged pool: (chosen
-    candidates [B, 1, k] int32 in ascending order, which of them are live),
-    k = min(topk, S + 1).  Candidate ids below S = MB * BLK are the slots of
-    the row's table in sequence order, S is the step's own token (always
-    live for an active row)."""
+    candidates [B, 1, k] int32 in ascending order, which of them are live,
+    the rows [B] whose k-th value more candidates shared than it had room
+    for), k = min(topk, S + 1).  Candidate ids below S = MB * BLK are the
+    slots of the row's table in sequence order, S is the step's own token
+    (always live for an active row)."""
     S = table.shape[1] * plane.shape[3]
     with jax.named_scope("attn.index"):
         own = jnp.where(
             q_pos >= 0, _sortable(index_scores(q_idx, w, k_idx)[:, 0, 0]), 0)
-        u, kth, above, equal = paged_index_rank(
-            q_idx[:, 0], w[:, 0], own, plane, plan, q_pos, layer, topk=topk)
+        u = paged_index_scores(q_idx[:, 0], w[:, 0], plane, plan, q_pos, layer)
     with jax.named_scope("attn.select"):
-        # The plan's last step may pad the table: candidate S is the own token.
-        u = jnp.concatenate([u[:, :S], own[:, None]], axis=1)
-        mask = _mask_of(u, topk, kth, above, (kth > 0) & (above + equal > topk))
-        return _compact(mask[:, None], min(topk, S + 1))
+        chosen, chosen_live, kth, above, equal = paged_index_select(
+            u, own, topk=topk, n_slots=S)
+        return (chosen[:, None], chosen_live[:, None],
+                (kth > 0) & (above + equal > topk))

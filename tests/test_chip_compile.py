@@ -17,6 +17,7 @@ live in THIS file (a second file could land on another worker, whose
 fixture would then skip), and compile in the test's own process.
 """
 
+import functools
 import importlib
 import os
 import sys
@@ -352,27 +353,39 @@ def test_paged_window_decode_compiles_for_v5e(sds, t_tokens):
         q_pos, sds((), jnp.int32), window))
 
 
-def test_paged_index_rank_compiles_for_v5e(sds):
-    """The decode rows' ranking kernel at Keye-VL-2.0-30B-A3B's indexer
+def test_paged_index_scores_compile_for_v5e(sds):
+    """The decode rows' scoring kernel at Keye-VL-2.0-30B-A3B's indexer
     geometry and the benchmark cell's pool: 8 rows x 64 blocks of 512 tokens,
-    16 index heads of 64 over a one-head bfloat16 plane of 6 layers, top-2048;
-    8 table entries a grid step, the step list derived outside."""
+    16 index heads of 64 over a one-head bfloat16 plane of 6 layers; 8 table
+    entries a grid step, the step list derived outside."""
     from jax_llama_tpu.ops import key_selection as ks
 
     rows, mb, blk, layers, hi, di = 8, 64, 512, 6, 16, 64
     nb = rows * mb
 
-    def rank(q, w, own, plane, pos, table, q_pos, layer):
+    def scores(q, w, plane, pos, table, q_pos, layer):
         plan = ks.index_plan(pos, table, q_pos)
         assert plan[4].shape == (rows * 8, 8, blk)
-        return ks.paged_index_rank(
-            q, w, own, plane, plan, q_pos, layer, topk=2048, interpret=False)
+        return ks.paged_index_scores(q, w, plane, plan, q_pos, layer, interpret=False)
 
-    _assert_mosaic(jax.jit(rank).lower(
+    _assert_mosaic(jax.jit(scores).lower(
         sds((rows, hi, di), jnp.bfloat16), sds((rows, hi), jnp.float32),
-        sds((rows,), jnp.uint32), sds((layers, 1, nb, blk, di), jnp.bfloat16),
+        sds((layers, 1, nb, blk, di), jnp.bfloat16),
         sds((nb, blk), jnp.int32), sds((rows, mb), jnp.int32),
         sds((rows,), jnp.int32), sds((), jnp.int32)))
+
+
+def test_paged_index_select_compiles_for_v5e(sds):
+    """The search for the k-th value, the mask and the chosen list as one
+    kernel over the cell's images: 8 rows x 64 lines of 512 slots in VMEM,
+    top-2048 (lines of two 256-slot sub-lines, four tiles of the list)."""
+    from jax_llama_tpu.ops import key_selection as ks
+
+    rows, mb, blk = 8, 64, 512
+    lowered = jax.jit(functools.partial(
+        ks.paged_index_select, topk=2048, n_slots=mb * blk, interpret=False,
+    )).lower(sds((rows, mb, blk), jnp.int32), sds((rows,), jnp.uint32))
+    _assert_mosaic(lowered)
 
 
 def test_sparse_decode_chunk_copies_no_index_plane_an_iteration_for_v5e(sds, monkeypatch):

@@ -139,19 +139,22 @@ def test_prefill_then_decode_through_the_paged_cache(tiny, use_kernel):
         one(P, i32), jnp.ones((1,), bool), one(G, i32), jnp.full((1, 1), -1, i32),
         keys, one(0.0, f32), one(1.0, f32), one(0, i32), config=cfg, n_iter=1,
         all_greedy=True, allow_kernel=use_kernel).as_text(debug_info=True)
-    assert ('"attn.index/jit(paged_index_rank)"' in text) == use_kernel
+    assert ('"attn.select/jit(paged_index_select)"' in text) == use_kernel
     served, _, stats = decode_row(
         params, cfg, pool, table, 7, P, int(tau[0]), G - 1, use_kernel=use_kernel)
     assert _deficit(params, raw, [int(t) for t in toks[0]], served).max() < TOL
-    selected, candidates, dense_rows, steps_run, steps_table = stats[-5:]
+    selected, candidates, dense_rows, steps_run, steps_table, tie_rows = stats[-6:]
     if use_kernel:
+        # two of the fourteen row-layers find their k-th value at 0.0, the
+        # score of every key all four index heads clip: shared, and counted
+        assert tie_rows == 2
         # 7 iterations x 2 layers, contexts 97..103, 16 chosen of each
         assert selected == 7 * 2 * TOPK and dense_rows == 0
         assert candidates == 2 * sum(range(97, 104))
         # the eight table entries are one grid step of the ranking kernel
         assert steps_run == steps_table == 7 * 2
     else:
-        assert selected == candidates == dense_rows == steps_run == steps_table == 0
+        assert selected == candidates == dense_rows == steps_run == steps_table == tie_rows == 0
 
 
 @pytest.mark.parametrize("use_kernel", [True, False], ids=["paged", "gathered-view"])
@@ -189,7 +192,8 @@ def test_served_through_the_fused_lane_and_a_prefix_hit(tiny, use_kernel):
 
     for name in ("attn_selected_slots_total", "attn_candidate_slots_total",
                  "attn_select_dense_rows_total", "attn_index_steps_run_total",
-                 "attn_index_steps_table_total", "moe_assignments_total"):
+                 "attn_index_steps_table_total", "attn_select_tie_rows_total",
+                 "moe_assignments_total"):
         assert metric_meta(name)[0] == "counter" and name in stats
     assert stats["moe_layer_calls_total"] > 0
     if use_kernel:
@@ -200,8 +204,12 @@ def test_served_through_the_fused_lane_and_a_prefix_hit(tiny, use_kernel):
         rows = stats["attn_selected_slots_total"] // TOPK           # x layers
         assert stats["attn_index_steps_table_total"] == 2 * rows
         assert rows <= stats["attn_index_steps_run_total"] < 2 * rows
+        # seeded scores tie at the k-th place only at 0.0, the score of a key
+        # all four index heads clip: a row or two of these (x layers)
+        assert 0 <= stats["attn_select_tie_rows_total"] < rows / 10
     else:
         assert stats["attn_candidate_slots_total"] == stats["attn_index_steps_table_total"] == 0
+        assert stats["attn_select_tie_rows_total"] == 0
     assert stats["host_syncs_per_token"] < 1
 
 
@@ -348,14 +356,34 @@ def _xla_ranking(q_idx, w, k_idx, plane, pos, table, q_pos, layer):
         key_selection.index_scores(q_idx, w, keys)), 0)
 
 
+def _images_as_xla(u, n_slots):
+    """`paged_index_scores`' int32 images [B, R, C] as `_sortable`'s uint32
+    ones over the table's slots [B, S] (0: not live)."""
+    u = jax.lax.bitcast_convert_type(u, jnp.uint32) ^ jnp.uint32(1 << 31)
+    return u.reshape(u.shape[0], -1)[:, :n_slots]
+
+
+def _xla_selection(images, own, topk):
+    """`_kth_value`, `_mask_of` and `_compact` over candidates [B, S] and the
+    own token's image [B]: (chosen [B, k], live [B, k], kth, above, equal,
+    over), the rule the list kernel is held to."""
+    mine = jnp.concatenate([images, own[:, None]], axis=1)[:, None]
+    count = lambda pred, ref: jnp.sum(pred(mine, ref[..., None]), axis=-1, dtype=jnp.int32)  # noqa: E731
+    kth, above, over = key_selection._kth_value(mine, topk, count)
+    chosen, live = key_selection._compact(
+        key_selection._mask_of(mine, topk, kth, above, over), min(topk, mine.shape[-1]))
+    return tuple(np.asarray(a[:, 0]) for a in (
+        chosen, live, kth, above, count(jnp.equal, kth), over))
+
+
 @pytest.mark.parametrize("step_tokens", [32, 4096], ids=["two-entries-a-step", "one-step-a-row"])
 def test_the_ranking_kernel_scores_live_blocks_and_finds_the_kth_value(monkeypatch, step_tokens):
-    """`paged_index_rank` on rows of different contexts in one call — one
+    """The two kernels on rows of different contexts in one call — one
     shorter than `topk` (every live slot), one ending mid-block, one
-    inactive, one whose table is full — against `index_scores` and
-    `_kth_value` over the row's whole table: the same bit images (0 where
-    a slot is not live, and in every step the grid never visits), the same
-    k-th value and counts, the same chosen slots."""
+    inactive, one whose table is full — against `index_scores`, `_kth_value`,
+    `_mask_of` and `_compact` over the row's whole table: the same bit images
+    (not live where a slot is not, and in every step the grid never visits),
+    the same k-th value and counts, the same list element for element."""
     monkeypatch.setattr(key_selection, "INDEX_STEP_TOKENS", step_tokens)
     BLKS, MB, DI, HI, LAYER = 16, 6, 16, 4, 1
     ctx = [9, 53, None, 96, 70]
@@ -373,32 +401,83 @@ def test_the_ranking_kernel_scores_live_blocks_and_finds_the_kth_value(monkeypat
 
     want = _xla_ranking(q_idx, w, k_idx, plane, pos, table, q_pos, LAYER)
     own = want[:, 0, S]
-    u, kth, above, equal = key_selection.paged_index_rank(
-        q_idx[:, 0], w[:, 0], own, plane, plan, q_pos, LAYER, topk=TOPK)
-    got, want = np.asarray(u[:, :S]).astype(np.int64), np.asarray(want[:, 0]).astype(np.int64)
+    images = key_selection.paged_index_scores(
+        q_idx[:, 0], w[:, 0], plane, plan, q_pos, LAYER)
+    assert images.shape == (B, MB, BLKS) and images.dtype == jnp.int32
+    u = _images_as_xla(images, S)
+    got, want = np.asarray(u).astype(np.int64), np.asarray(want[:, 0]).astype(np.int64)
     assert ((got == 0) == (want[:, :S] == 0)).all()
     # the images are float32 bits: the order of a sum of four heads is all
     # that may differ
     assert np.abs(got - want[:, :S]).max() <= 2
     assert (got[2] == 0).all() and (got[0] != 0).sum() == 9 and (got[4] != 0).sum() == 70
 
-    # the search in VMEM against XLA's passes over the kernel's own images
-    mine = jnp.concatenate([u[:, :S], own[:, None]], axis=1)[:, None]
-    count = lambda pred, ref: jnp.sum(pred(mine, ref[..., None]), axis=-1, dtype=jnp.int32)  # noqa: E731
-    kth_x, above_x, over_x = key_selection._kth_value(mine, TOPK, count)
-    assert (np.asarray(kth) == np.asarray(kth_x[:, 0])).all()
-    assert (np.asarray(above) == np.asarray(above_x[:, 0])).all()
-    assert (np.asarray(equal) == np.asarray(count(jnp.equal, kth_x)[:, 0])).all()
-    assert int(kth[0]) == int(kth[2]) == 0 and (np.asarray(kth)[[1, 3, 4]] > 0).all()
-
-    chosen, chosen_live = key_selection.paged_select_slots(
+    # the search and the list in VMEM against XLA's stages over the kernel's
+    # own images
+    chosen, chosen_live, tied = key_selection.paged_select_slots(
         q_idx, w, k_idx, plane, table, plan, q_pos, LAYER, TOPK)
-    ref, ref_live = key_selection._compact(key_selection._topk_mask(mine, TOPK, count), TOPK)
-    assert (np.asarray(chosen_live) == np.asarray(ref_live)).all()
-    assert (np.asarray(chosen) == np.asarray(ref))[np.asarray(ref_live)].all()
+    _, _, kth, above, equal = key_selection.paged_index_select(
+        images, own, topk=TOPK, n_slots=S)
+    ref, ref_live, kth_x, above_x, equal_x, over_x = _xla_selection(u, own, TOPK)
+    assert (np.asarray(kth) == kth_x).all() and (np.asarray(above) == above_x).all()
+    assert (np.asarray(equal) == equal_x).all() and (np.asarray(tied) == over_x).all()
+    assert int(kth[0]) == int(kth[2]) == 0 and (np.asarray(kth)[[1, 3, 4]] > 0).all()
+    assert (np.asarray(chosen_live[:, 0]) == ref_live).all()
+    assert (np.asarray(chosen[:, 0]) == ref)[ref_live].all()
+    # a place past the row's count holds the own token's id
+    assert (np.asarray(chosen[:, 0])[~ref_live] == S).all()
     assert np.asarray(chosen_live).sum(axis=-1)[:, 0].tolist() == [10, TOPK, 0, TOPK, TOPK]
     # the step's own token is candidate S, and the shortest row takes it
     assert int(chosen[0, 0, 9]) == S
+
+
+def _synthetic_images(B, R, C, MB, topk, n_values, seed):
+    """Images [B, R, C] int32 of scores drawn from `n_values` distinct ones
+    (few: ties at every rank, inside a line, across lines, with the own
+    token) for rows of random contexts: the first full, the second three
+    short of `topk`, the third inactive."""
+    rng = np.random.RandomState(seed)
+    S = MB * C
+    values = np.sort(rng.standard_normal(n_values).astype(np.float32))
+    bits = values[rng.randint(0, n_values, size=(B, R, C))].view(np.int32)
+    ctx = rng.randint(1, S + 1, size=B)
+    ctx[:3] = S, max(1, topk - 3), 0
+    live = (np.arange(R * C)[None] < ctx[:, None]).reshape(B, R, C)
+    u = np.where(live, np.where(bits < 0, bits ^ 0x7FFFFFFF, bits), -(1 << 31)).astype(np.int32)
+    own = np.asarray(key_selection._sortable(jnp.asarray(values[rng.randint(0, n_values, size=B)])))
+    return jnp.asarray(u), jnp.asarray(np.where(ctx > 0, own, 0).astype(np.uint32)), S
+
+
+@pytest.mark.parametrize("B,R,C,MB,topk,n_values", [
+    (5, 6, 16, 6, 16, 5), (5, 6, 16, 5, 16, 3), (4, 3, 8, 3, 4, 2), (5, 4, 512, 4, 256, 7),
+    (4, 2, 512, 2, 2048, 50), (3, 8, 256, 7, 600, 4), (4, 4, 128, 4, 100, 1 << 20),
+], ids=["blocks-of-16", "a-padded-table", "blocks-of-8-two-values", "two-sub-lines-a-line",
+        "topk-past-the-table", "several-tiles-of-the-list", "no-ties"])
+def test_the_list_kernel_is_the_mask_and_the_compaction_element_for_element(B, R, C, MB, topk, n_values):
+    """`paged_index_select` on images with ties everywhere — at the k-th
+    value inside one line, across lines and with the own token among them —
+    over block lengths that are one sub-line, two, or not 512 at all, a
+    table the plan padded (R > MB), `topk` past the table, a list of more
+    than one tile: `_compact(_mask_of(...))`'s list and liveness element for
+    element, and `_kth_value`'s answers."""
+    tied = 0
+    for seed in range(2):
+        u, own, S = _synthetic_images(B, R, C, MB, topk, n_values, seed)
+        chosen, live, kth, above, equal = key_selection.paged_index_select(
+            u, own, topk=topk, n_slots=S)
+        ref, ref_live, kth_x, above_x, equal_x, over_x = _xla_selection(
+            _images_as_xla(u, S), own, topk)
+        assert chosen.shape == live.shape == (B, min(topk, S + 1))
+        assert (np.asarray(kth) == kth_x).all() and (np.asarray(above) == above_x).all()
+        # (a row that takes every live candidate has no k-th value, and the
+        # count "at" 0 is of the slots that are not live, padded lines too)
+        assert (np.asarray(equal) == equal_x)[kth_x > 0].all()
+        assert (np.asarray(live) == ref_live).all()
+        assert (np.asarray(chosen) == ref)[ref_live].all()
+        assert (np.asarray(chosen)[~ref_live] == S).all()
+        assert not ref_live[2].any() and ref_live[1].sum() == min(S, max(1, topk - 3)) + 1
+        tied += over_x.sum()
+    assert (tied > 0) == (n_values < 1000 and topk <= S)
 
 
 def test_ties_go_to_the_lower_slot_through_the_ranking_kernel():
@@ -417,7 +496,7 @@ def test_ties_go_to_the_lower_slot_through_the_ranking_kernel():
     own = rng.standard_normal((1, DI)).astype(np.float32)
     k_idx = jnp.asarray(own[[0, 0]])[:, None]
     plan = key_selection.index_plan(pos, table, q_pos)
-    chosen, chosen_live = key_selection.paged_select_slots(
+    chosen, chosen_live, tie_rows = key_selection.paged_select_slots(
         q_idx, w, k_idx, plane, table, plan, q_pos, 1, k)
     assert np.asarray(chosen_live).all()
     tied = 0
@@ -430,6 +509,7 @@ def test_ties_go_to_the_lower_slot_through_the_ranking_kernel():
         assert np.asarray(chosen[b, 0]).tolist() == want, b
         tied += (scores == np.sort(scores)[::-1][k - 1]).sum() > 1
     assert tied    # the k-th value IS tied: taking every equal score would pass k
+    assert np.asarray(tie_rows).sum() == tied      # and the counter says so
 
 
 @pytest.mark.parametrize("n,k", [(5, 3), (40, 16), (1100, 64), (1024, 2048)])
@@ -624,14 +704,18 @@ def test_scopes_are_in_the_lowered_programs(tiny):
         params, toks[:, :1], pos[:, :1], cache).as_text(debug_info=True)
     for scope in ("attn.proj", "attn.index", "attn.select", "attn.sparse"):
         assert scope in text, scope
-    # the ranking kernel is one call under the index scope, and the XLA stage
-    # it replaces (the gather of the table's index keys) is not in the step
-    assert '"attn.index/jit(paged_index_rank)"' in text
-    assert '"attn.index/jit(_take)"' not in text
+    # the scoring kernel is one call under the index scope, the search and
+    # the list one under the selection's; the XLA stages they replace (the
+    # gather of the table's index keys, the running counts of `_compact`) are
+    # not in the step
+    assert '"attn.index/jit(paged_index_scores)"' in text
+    assert '"attn.select/jit(paged_index_select)"' in text
+    assert '"attn.index/jit(_take)"' not in text and '"attn.select/jit(cumsum)"' not in text
     # several tokens a row over the pool keep the XLA stages
     text = jax.jit(lambda p, t, q, c: jlt.forward(p, t, q, cfg, cache=c)[0]).lower(
         params, toks[:, :2], pos[:, :2], cache).as_text(debug_info=True)
-    assert "paged_index_rank" not in text and '"attn.index/jit(_take)"' in text
+    assert "paged_index_" not in text
+    assert '"attn.index/jit(_take)"' in text and '"attn.select/jit(cumsum)"' in text
 
 
 def test_every_parameter_has_a_partition_rule(tiny):
@@ -653,7 +737,7 @@ def test_the_pool_has_a_third_plane_under_the_same_table(tiny):
     # a token's two KV heads side by side in one row: a gather costs by the row
     assert pool.k.shape == pool.v.shape == (2, 1, 8, BLK, 32)
     assert pool.idx.shape == (2, 1, 8, BLK, 16)
-    assert pool.k_scale is None and pool.stats.shape == (dsa_moe.N_STATS,) == (11,)
+    assert pool.k_scale is None and pool.stats.shape == (dsa_moe.N_STATS,) == (12,)
     cache = jlt.init_cache(cfg, batch=2, max_len=32)
     assert cache.k.shape == (2, 2, 32, 1, 32) and cache.idx.shape == (2, 2, 32, 1, 16)
     from jax_llama_tpu.kvcache import pool_block_bytes
