@@ -456,6 +456,7 @@ def _make_batcher_stub():
     s.prefill_chunks_total = 0
     s.moe_totals = {}
     s.attn_step_totals = {}
+    s.hc_totals = {}
     s.ssm_snapshots_taken_total = 0
     s.ssm_snapshots_restored_total = 0
     s.ssm_match_tokens_cut_total = 0

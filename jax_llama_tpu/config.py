@@ -112,6 +112,18 @@ class LLaMAConfig:
     moe_intermediate_size: int = 0        # width of one expert
     routed_scaling_factor: float = 1.0
     first_k_dense: int = 0                # leading layers with a dense FFN
+    q_lora_rank: int = 0                  # > 0: q = RMSNorm(h W_qa) W_qb
+    # YaRN rope for the latent block (`ops.rope.yarn_inv_freq`): (factor,
+    # original_max_position_embeddings, beta_fast, beta_slow,
+    # mscale_all_dim); the softmax scale takes `yarn_mscale`^2.  None: plain.
+    rope_yarn: Optional[Tuple[float, ...]] = None
+    # The residual of the latent block as `hc_mult` streams mixed around every
+    # attention and FFN by manifold-constrained hyper-connections
+    # (`ops/mhc.py`).  1: x + F(norm(x)) on one stream.
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     # --- window and full attention layers in one stack.  None: every layer
     # attends its whole causal context.  A tuple of n_layers flags selects
@@ -186,7 +198,8 @@ class LLaMAConfig:
     def __post_init__(self):
         # JSON (a checkpoint's config.json) hands a tuple back as a list,
         # which neither compares equal nor hashes as a static argument.
-        for name in ("window_layers", "ssm_multipliers", "mlp_multipliers"):
+        for name in ("window_layers", "ssm_multipliers", "mlp_multipliers",
+                     "rope_yarn", "hc_clamp"):
             value = getattr(self, name)
             if isinstance(value, list):
                 object.__setattr__(self, name, tuple(value))
@@ -585,6 +598,23 @@ class LLaMAConfig:
         """The latent-attention block (see `_validate_experts`)."""
         self._validate_experts(
             needs=("qk_nope_head_dim", "qk_rope_head_dim", "v_head_dim"))
+        if self.q_lora_rank < 0:
+            raise ValueError(f"q_lora_rank={self.q_lora_rank} must be >= 0")
+        if self.rope_yarn is not None and (
+                len(self.rope_yarn) != 5 or self.rope_yarn[0] < 1
+                or self.rope_yarn[1] <= 0):
+            raise ValueError(
+                f"rope_yarn={self.rope_yarn!r} is not (factor >= 1, original "
+                "length > 0, beta_fast, beta_slow, mscale_all_dim)")
+        if self.hc_mult < 1:
+            raise ValueError(f"hc_mult={self.hc_mult} must be >= 1")
+        if self.hc_mult > 1 and not (
+                self.hc_sinkhorn_iters >= 1 and self.hc_eps > 0
+                and len(self.hc_clamp) == 2 and self.hc_clamp[0] < self.hc_clamp[1]):
+            raise ValueError(
+                "hc_mult > 1 needs hc_sinkhorn_iters >= 1, hc_eps > 0 and "
+                f"hc_clamp (min < max); got {self.hc_sinkhorn_iters}, "
+                f"{self.hc_eps}, {self.hc_clamp!r}")
 
 
 # ---------------------------------------------------------------------------
@@ -626,6 +656,61 @@ _PUBLISHED_LATENT_FIXED = {
     "q_lora_rank": None, "rope_interleave": True, "rope_scaling": None,
     "scoring_func": "sigmoid", "topk_method": "noaux_tc",
 }
+
+# the same block under `model_type: xing4_0`: a low-rank query, YaRN rope and
+# the residual as `hc_mult` streams (manifold-constrained hyper-connections).
+# Mapped by `_latent_variant`; these have ONE accepted value.
+_PUBLISHED_MHC_FIXED = dict(
+    {k: v for k, v in _PUBLISHED_LATENT_FIXED.items()
+     if k not in ("q_lora_rank", "rope_scaling")},
+    model_type="xing4_0",
+    # a drafting / training head the next-token logits do not read: built
+    # nowhere, accepted at the published count only
+    num_nextn_predict_layers=1,
+    ep_size=1,
+)
+_PUBLISHED_MHC = ("hc_mult", "hc_sinkhorn_iters", "hc_eps", "mhc_h_res_clamp_min",
+                  "mhc_h_res_clamp_max", "q_lora_rank", "rope_scaling")
+_PUBLISHED_YARN = ("type", "factor", "original_max_position_embeddings",
+                   "beta_fast", "beta_slow", "mscale", "mscale_all_dim")
+
+
+def _latent_variant(raw) -> dict:
+    """The fields of `model_type: xing4_0`'s own keys, each held to what the
+    block computes and refused by name otherwise."""
+    def number(key, ok, what, whole=False):
+        v = raw[key]
+        if isinstance(v, bool) or not isinstance(v, int if whole else (int, float)) or not ok(v):
+            raise ValueError(f"{key}: {v!r} is not {what}")
+        return v
+
+    scaling = raw["rope_scaling"]
+    if not isinstance(scaling, dict) or scaling.get("type") != "yarn":
+        raise ValueError(
+            f"rope_scaling: {scaling!r} is not in the program; with model_type "
+            "'xing4_0' its latent-attention block computes type 'yarn' only")
+    odd = sorted(set(scaling) ^ set(_PUBLISHED_YARN))
+    if odd:
+        raise ValueError(f"rope_scaling: key {odd[0]!r} is missing or not understood")
+    yarn = [scaling[k] for k in _PUBLISHED_YARN[1:]]
+    if any(isinstance(v, bool) or not isinstance(v, (int, float)) for v in yarn) or (
+            yarn[0] < 1 or yarn[1] <= 0):
+        raise ValueError(f"rope_scaling: {scaling!r} is not a YaRN factor >= 1 over a length > 0")
+    if scaling["mscale"] != scaling["mscale_all_dim"]:
+        raise ValueError(
+            "rope_scaling: mscale != mscale_all_dim (a factor on cos / sin) is "
+            "not in the program")
+    lo = number("mhc_h_res_clamp_min", lambda v: True, "a number")
+    hi = number("mhc_h_res_clamp_max", lambda v: v > lo, "above mhc_h_res_clamp_min")
+    return dict(
+        hc_mult=number("hc_mult", lambda v: v >= 1, "a stream count >= 1", whole=True),
+        hc_sinkhorn_iters=number("hc_sinkhorn_iters", lambda v: v >= 1,
+                                 "an iteration count >= 1", whole=True),
+        hc_eps=float(number("hc_eps", lambda v: v > 0, "a number > 0")),
+        hc_clamp=(float(lo), float(hi)),
+        q_lora_rank=number("q_lora_rank", lambda v: v > 0, "a rank > 0", whole=True),
+        rope_yarn=tuple(float(v) for v in (*yarn[:4], scaling["mscale_all_dim"])),
+    )
 
 
 # the afmoe block (window and full attention layers, routed + shared experts)
@@ -945,7 +1030,9 @@ def from_published(raw, *, max_seq_len: int, attn_impl: str) -> LLaMAConfig:
     """The `LLaMAConfig` of a model's published `config.json` keys `raw`, or
     `ValueError` naming the key that stands in the way.
     `max_position_embeddings` is accepted and unused: a server serves at its
-    own `max_seq_len`.  A file with `kv_lora_rank` is the deepseek_v3 block,
+    own `max_seq_len`.  A file with `kv_lora_rank` is the deepseek_v3 block
+    (under `model_type: xing4_0` with a low-rank query, YaRN rope and a
+    multi-stream residual),
     one with `layer_types` the afmoe block, one with `mb_per_layer` the
     phi4flash block, one with `mamba_d_ssm` the falcon_h1 block, one with
     `sa_config` the KeyeVL2 block; every other file is the dense block."""
@@ -972,15 +1059,22 @@ def from_published(raw, *, max_seq_len: int, attn_impl: str) -> LLaMAConfig:
     fields = dict(_PUBLISHED, **(_PUBLISHED_LATENT if latent else {}),
                   **(_PUBLISHED_WINDOWED if windowed else {}))
     known = set(fields) | set(_PUBLISHED_OTHER)
+    if latent and raw.get("model_type", "deepseek_v3") not in ("deepseek_v3", "xing4_0"):
+        raise ValueError(
+            f"model_type: {raw['model_type']!r} is not in the program; its "
+            "latent-attention block computes 'deepseek_v3' and 'xing4_0' only")
+    mhc = latent and raw.get("model_type") == "xing4_0"
+    latent_fixed = _PUBLISHED_MHC_FIXED if mhc else _PUBLISHED_LATENT_FIXED
     if latent:
-        known |= set(_PUBLISHED_LATENT_FIXED) | {"qk_head_dim"}
+        known |= set(latent_fixed) | {"qk_head_dim"} | set(_PUBLISHED_MHC if mhc else ())
     if windowed:
         known |= (set(_PUBLISHED_WINDOWED_FIXED) | set(_PUBLISHED_WINDOWED_UNUSED)
                   | {"layer_types", "global_attn_every_n_layers"})
     unknown = sorted(set(raw) - known)
     if unknown:
         raise ValueError(f"the program understands no published key {', '.join(map(repr, unknown))}")
-    missing = sorted(k for k in (*fields, "torch_dtype") if k not in raw)
+    missing = sorted(k for k in (*fields, "torch_dtype", *(_PUBLISHED_MHC if mhc else ()))
+                     if k not in raw)
     if missing:
         raise ValueError(
             f"published key {missing[0]!r} is missing" + (
@@ -1011,7 +1105,7 @@ def from_published(raw, *, max_seq_len: int, attn_impl: str) -> LLaMAConfig:
     if raw["torch_dtype"] not in _PUBLISHED_DTYPES:
         raise ValueError(f"torch_dtype {raw['torch_dtype']!r} is not one the program serves in")
     if latent:
-        for key, only in _PUBLISHED_LATENT_FIXED.items():
+        for key, only in latent_fixed.items():
             if key in raw and raw[key] != only:
                 raise ValueError(
                     f"{key}: {raw[key]!r} is not in the program; its "
@@ -1022,7 +1116,7 @@ def from_published(raw, *, max_seq_len: int, attn_impl: str) -> LLaMAConfig:
             raise ValueError("qk_head_dim != qk_nope_head_dim + qk_rope_head_dim")
         if raw["num_key_value_heads"] != heads:
             raise ValueError("num_key_value_heads != num_attention_heads under latent attention")
-    extra = {}
+    extra = _latent_variant(raw) if mhc else {}
     if windowed:
         for key, only in _PUBLISHED_WINDOWED_FIXED.items():
             if key in raw and raw[key] != only:

@@ -73,7 +73,6 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from ..config import LLaMAConfig
 from ..ops.attention import attention_bias, dropout as _dropout, sdpa, sdpa_cached
 from ..ops.flash_attention import flash_attention, flash_attention_sharded
-from ..ops.moe import N_STATS as _MOE_N_STATS
 from ..ops.norm import rms_norm
 from ..ops.quant import QuantizedTensor as _QuantizedTensor
 from ..ops.quant import matmul as _quant_matmul
@@ -578,7 +577,9 @@ def cache_stats_zero(config: LLaMAConfig) -> Optional[jnp.ndarray]:
     (``ops.moe.STATS``) of a block with routed experts, then the window
     block's attention step counts (``afmoe.ATTN_STATS``); None for the
     dense block, which counts nothing on the device.  The sparse-attention
-    block appends its selection counts (``dsa_moe.SELECT_STATS``)."""
+    block appends its selection counts (``dsa_moe.SELECT_STATS``); the latent
+    block with a multi-stream residual its units' (``ops.mhc.STATS``) right
+    behind the routing counts."""
     if config.sparse_attention:
         from .dsa_moe import N_STATS
 
@@ -590,7 +591,9 @@ def cache_stats_zero(config: LLaMAConfig) -> Optional[jnp.ndarray]:
 
         return jnp.zeros((N_STATS,), jnp.int32)
     if config.latent_attention:
-        return jnp.zeros((_MOE_N_STATS,), jnp.int32)
+        from .mla_moe import n_stats
+
+        return jnp.zeros((n_stats(config),), jnp.int32)
     return None
 
 

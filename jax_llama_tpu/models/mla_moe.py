@@ -6,9 +6,21 @@ Per layer: x += MLA(RMSNorm(x)); x += FFN(RMSNorm(x)).  The FFN of the
 leading `first_k_dense` layers is the dense block's SwiGLU (`llama._swiglu`);
 every later layer's is `ops.moe.routed_experts` plus a shared SwiGLU.
 
+With `config.hc_mult` = n > 1 (`model_type: xing4_0`) the residual is n
+streams: the layer stack carries `[B, T, n, C]`, begun as n copies of the
+embedding and summed over n before `final_norm`, and each of a layer's two
+`+=` is one mHC unit (`ops/mhc.py`) around the same inner function F:
+
+    H_pre, H_post, H_res = coefficients(X)       per token, float32
+    y = F(sum_i H_pre[i] X[i])                   F = MLA(RMSNorm(.)) or FFN(RMSNorm(.))
+    X[i] <- sum_j H_res[i, j] X[j] + H_post[i] y
+
+At n = 1 nothing of this is traced: the block lowers to x + F(norm(x)).
+
 Attention (H heads, q/k width nope + rope, v width dv, latent rank r):
 
     q = h W_q -> [H, nope | rope]        h W_kva -> [r | rope]
+    (`q_lora_rank` rq > 0:  q = RMSNorm(h W_qa; q_a_norm) W_qb)
     c = RMSNorm(h W_kva[:r])             k_rope = rope(h W_kva[r:])  ONE head
     c W_kvb -> [H, nope | dv] = k_nope | v per head
     score_n = (q_nope_n . k_nope_n + rope(q_rope_n) . k_rope) / sqrt(nope + rope)
@@ -28,6 +40,9 @@ no `v` plane.  Two forms of the same attention:
   themselves as one shared key/value head (`paged_decode_attention` with
   `v_width`): decode reads r + rope values a slot and never a per-head K/V.
 
+`config.rope_yarn`: the rope tables take `ops.rope.yarn_inv_freq` and the
+softmax scale `yarn_mscale`^2 (`softmax_scale`).
+
 Rotary pairing: column i of a rope part pairs with column i + rope/2
 (`ops.rope`, as everywhere in the program).  The published layout stores the
 pair as adjacent columns (`rope_interleave`); a converter permutes the rope
@@ -45,13 +60,20 @@ Parameters are two stacked trees, one per layer kind, each scanned:
      "final_norm": [D], "lm_head": [D, V]}
     <attention> = "attn_norm" [L,D], "q" [L,H,D,nope+rope], "kv_a" [L,D,r+rope],
                   "kv_norm" [L,r], "kv_b" [L,H,r,nope+dv], "o" [L,H,dv,D]
+    with `q_lora_rank`, in place of "q":
+                  "q_a" [L,D,rq], "q_a_norm" [L,rq], "q_b" [L,H,rq,nope+rope]
+    with `hc_mult` n > 1, in both layer trees, float32 (`ops.mhc.init_unit`):
+                  "hc_attn", "hc_ffn" = {"phi" [L,nC,n*n+2n], "b" [L,n*n+2n],
+                                         "alpha" [L,3]}
 
 Every call also counts its routing (`ops.moe.N_STATS` int32) into the
-cache's `stats`, which the serving loop returns with its packed fetch.
+cache's `stats`, which the serving loop returns with its packed fetch; with
+`hc_mult` > 1 the units' `ops.mhc.STATS` follow them there (`n_stats`).
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Any, Dict, Optional
@@ -61,13 +83,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..config import LLaMAConfig
-from ..ops import moe
+from ..ops import mhc, moe
 from ..ops.attention import attention_bias, sdpa
 from ..ops.flash_attention import (
     flash_attention, flash_attention_lse, merge_attention,
 )
 from ..ops.norm import rms_norm
-from ..ops.rope import apply_rope
+from ..ops.rope import apply_rope, rope_table, yarn_inv_freq, yarn_mscale
 
 Params = Dict[str, Any]
 
@@ -99,9 +121,11 @@ def init_params(rng: jax.Array, config: LLaMAConfig) -> Params:
     def dense(key, shape):
         return (jax.random.normal(key, shape, jnp.float32) * INIT_STD).astype(wd)
 
-    def attention(key, L):
+    rq, n = config.q_lora_rank, config.hc_mult
+
+    def attention(key, L, first_layer):
         k = jax.random.split(key, 4)
-        return {
+        tree = {
             "attn_norm": jnp.ones((L, D), wd),
             "q": dense(k[0], (L, H, D, dn + dr)),
             "kv_a": dense(k[1], (L, D, r + dr)),
@@ -110,18 +134,28 @@ def init_params(rng: jax.Array, config: LLaMAConfig) -> Params:
             "o": dense(k[3], (L, H, dv, D)),
             "mlp_norm": jnp.ones((L, D), wd),
         }
+        if rq:
+            ka, kb = jax.random.split(k[0])
+            del tree["q"]
+            tree.update(q_a=dense(ka, (L, D, rq)), q_a_norm=jnp.ones((L, rq), wd),
+                        q_b=dense(kb, (L, H, rq, dn + dr)))
+        if n > 1:
+            ka, kf = jax.random.split(jax.random.fold_in(key, 1))
+            tree.update(hc_attn=mhc.init_unit(ka, L, n, D, 2 * first_layer),
+                        hc_ffn=mhc.init_unit(kf, L, n, D, 2 * first_layer + 1))
+        return tree
 
     keys = jax.random.split(rng, 12)
     F = config.ffn_dim
     params: Params = {
         "embed": {"embedding": dense(keys[0], (V, D))},
         "dense_layers": dict(
-            attention(keys[1], Ld),
+            attention(keys[1], Ld, 0),
             gate_up=dense(keys[2], (Ld, 2, D, F)),
             down=dense(keys[3], (Ld, F, D)),
         ),
         "moe_layers": dict(
-            attention(keys[4], Lm),
+            attention(keys[4], Lm, Ld),
             router=dense(keys[5], (Lm, D, E)),
             router_bias=jax.random.normal(keys[6], (Lm, E), jnp.float32) * ROUTER_BIAS_STD,
             experts_gate_up=dense(keys[7], (Lm, E, D, 2 * Fe)),
@@ -135,6 +169,29 @@ def init_params(rng: jax.Array, config: LLaMAConfig) -> Params:
     if not config.n_shared_experts:
         del params["moe_layers"]["shared_gate_up"], params["moe_layers"]["shared_down"]
     return params
+
+
+def n_stats(config: LLaMAConfig) -> int:
+    """Counters a forward adds to a cache's `stats`: the routing counts, and
+    behind them the mHC units' where the residual has streams."""
+    return moe.N_STATS + (mhc.N_STATS if config.hc_mult > 1 else 0)
+
+
+def softmax_scale(config: LLaMAConfig) -> float:
+    """(nope + rope)^(-1/2), times YaRN's `mscale_all_dim` temperature squared."""
+    scale = 1.0 / math.sqrt(config.qk_head_dim)
+    if config.rope_yarn is not None:
+        factor, _, _, _, mscale_all_dim = config.rope_yarn
+        scale *= yarn_mscale(factor, mscale_all_dim) ** 2
+    return scale
+
+
+def _yarn_tables(config: LLaMAConfig, max_positions: int):
+    factor, original, beta_fast, beta_slow, _ = config.rope_yarn
+    return rope_table(
+        config.rope_dim, max_positions, config.rope_theta,
+        inv_freq=yarn_inv_freq(config.rope_dim, config.rope_theta, factor,
+                               int(original), beta_fast, beta_slow))
 
 
 def _pad_last(x: jnp.ndarray, width: int) -> jnp.ndarray:
@@ -168,12 +225,23 @@ def _decompress(latent, kv_b, config: LLaMAConfig):
     return jnp.concatenate([kv[..., :dn], k_rope], axis=-1), kv[..., dn:]
 
 
+def _query(q_nope, q_rope, config: LLaMAConfig):
+    """The full-width query of the decompressed forms, whose kernels divide by
+    sqrt(nope + rope) themselves: what `softmax_scale` has beyond that (YaRN's
+    temperature) is folded into the query."""
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    if config.rope_yarn is None:
+        return q
+    gain = softmax_scale(config) * math.sqrt(config.qk_head_dim)
+    return (q.astype(jnp.float32) * gain).astype(q.dtype)
+
+
 def attend_decompressed(q_nope, q_rope, latent, kv_b, q_pos, kv_pos, bias,
                         config: LLaMAConfig, use_flash: bool):
     """Multi-head attention over K/V rebuilt from latent rows [B,S,w];
     [B,T,H,dv].  `bias` is used by the XLA path, the positions by flash."""
     dv = config.v_head_dim
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    q = _query(q_nope, q_rope, config)
     k, v = _decompress(latent, kv_b, config)
     if not use_flash:
         return sdpa(q, k, _pad_last(v, q.shape[-1]), bias,
@@ -207,7 +275,7 @@ def attend_tiled(q_nope, q_rope, latent, kv_b, q_pos, new_pos, cache, layer,
     `ctx_tiles` trips, a value.  Nothing of the view's width is rebuilt;
     the dead slots of the last tile are masked by their position, -1."""
     dv, adt = config.v_head_dim, latent.dtype
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    q = _query(q_nope, q_rope, config)
 
     def part(rows, kv_pos):
         k, v = _decompress(rows, kv_b, config)
@@ -285,7 +353,7 @@ def forward(
     B, T = tokens.shape
     adt = config.activation_dtype
     r, dn = config.kv_lora_rank, config.qk_nope_head_dim
-    scale = 1.0 / math.sqrt(config.qk_head_dim)
+    scale = softmax_scale(config)
     paged = isinstance(cache, PagedKVCache)
     if attn_mask is None:
         attn_mask = positions >= 0
@@ -309,8 +377,11 @@ def forward(
     else:
         span = cache.max_len if cache is not None else 0
         valid = attn_mask
-    cos, sin = _rope_tables(
-        config.rope_dim, max(2 * config.max_seq_len, span), config.rope_theta, False)
+    n_positions = max(2 * config.max_seq_len, span)
+    if config.rope_yarn is None:
+        cos, sin = _rope_tables(config.rope_dim, n_positions, config.rope_theta, False)
+    else:
+        cos, sin = _yarn_tables(config, n_positions)
 
     # What the new tokens attend besides themselves, layer-independent.
     absorbed = cache is not None and T <= FLASH_MIN_SEQ
@@ -324,11 +395,33 @@ def forward(
         bias = None if use_flash else attention_bias(q_positions, kv_pos, kv_pos >= 0)
 
     x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(adt)
+    n_streams = config.hc_mult
+    if n_streams > 1:
+        x = jnp.broadcast_to(x[:, :, None, :], (B, T, n_streams, x.shape[-1]))
 
-    def layer(x, lp, li, ffn):
+    def unit(x, hp, inner, scope=None):
+        """One residual step around `inner` (u -> (y, aux)): x + y on one
+        stream (the add under `scope`, where the parent put it), an mHC unit
+        on several.  Returns (x, aux, the unit's counters or None)."""
+        if n_streams == 1:
+            y, aux = inner(x)
+            with jax.named_scope(scope) if scope else contextlib.nullcontext():
+                return x + y, aux, None
+        h_pre, h_post, h_res, counts = mhc.coefficients(
+            x, hp, iters=config.hc_sinkhorn_iters, eps=config.hc_eps,
+            clamp=config.hc_clamp, valid=valid)
+        y, aux = inner(mhc.pre(x, h_pre))
+        return mhc.post(x, y, h_res, h_post), aux, counts
+
+    def attention(x, lp, li):
         h = rms_norm(x, lp["attn_norm"], config.rms_norm_eps)
         with jax.named_scope("mla.project"):
-            q = qeinsum(h, lp["q"], "btd,hdk->bthk", adt)
+            if config.q_lora_rank:
+                q_a = rms_norm(qeinsum(h, lp["q_a"], "btd,dr->btr", adt),
+                               lp["q_a_norm"], config.rms_norm_eps)
+                q = qeinsum(q_a, lp["q_b"], "btr,hrk->bthk", adt)
+            else:
+                q = qeinsum(h, lp["q"], "btd,hdk->bthk", adt)
             kva = qeinsum(h, lp["kv_a"], "btd,dk->btk", adt)
             c = rms_norm(kva[..., :r], lp["kv_norm"], config.rms_norm_eps)
             k_rope = apply_rope(kva[:, :, None, r:], cos, sin, q_positions)[:, :, 0]
@@ -367,10 +460,17 @@ def forward(
             with jax.named_scope("mla.project"):
                 attn = jnp.einsum("bthc,hck->bthk", o_lat, kv_b[..., dn:].astype(adt))
         with jax.named_scope("mla.project"):
-            x = x + qeinsum(attn, lp["o"], "bthk,hkd->btd", adt)
-        h = rms_norm(x, lp["mlp_norm"], config.rms_norm_eps)
-        out, stats = ffn(h, lp, li)
-        return x + out, latent, stats
+            return qeinsum(attn, lp["o"], "bthk,hkd->btd", adt), latent
+
+    def layer(x, lp, li, ffn):
+        x, latent, hc_a = unit(
+            x, lp.get("hc_attn"), lambda u: attention(u, lp, li), "mla.project")
+        x, stats, hc_f = unit(
+            x, lp.get("hc_ffn"),
+            lambda u: ffn(rms_norm(u, lp["mlp_norm"], config.rms_norm_eps), lp, li))
+        if n_streams > 1:
+            stats = jnp.concatenate([stats, hc_a + hc_f])
+        return x, latent, stats
 
     def stack(x, lp, first: int, ffn):
         n = next(iter(lp.values())).shape[0]
@@ -403,10 +503,14 @@ def forward(
     def ffn_moe(h, lp, li):
         return routed_ffn(h, lp, experts, li - config.first_k_dense, valid, config)
 
-    x, (lat_d, _) = stack(x, params["dense_layers"], 0, ffn_dense)
+    x, (lat_d, stats_d) = stack(x, params["dense_layers"], 0, ffn_dense)
     x, (lat_m, stats) = stack(x, scanned, config.first_k_dense, ffn_moe)
     new_lat = jnp.concatenate([lat_d, lat_m], axis=0)            # [L, B, T, w]
     stats = jnp.sum(stats, axis=0)  # every statistic adds up over layer calls
+    if n_streams > 1:
+        # the dense layers route nothing, and count their units
+        stats = stats + jnp.sum(stats_d, axis=0)
+        x = jnp.sum(x.astype(jnp.float32), axis=2).astype(adt)
 
     aux = None
     if output_last_hidden:

@@ -121,6 +121,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from .degrade import FEATURES
 from .faults import SITES
 from .ops.moe import STATS as _MOE_STATS
+from .ops.mhc import STATS as _HC_STATS
 
 # Dispatch kinds serving.py records — each owns a labeled dispatch_ms
 # histogram series.  record_dispatch VALIDATES against this set: a
@@ -530,6 +531,13 @@ METRICS: Dict[str, Tuple[str, str]] = {
         "counter", "Paged decode rows (x layers) whose k-th index score was "
                    "shared by more candidates than the selection had room "
                    "for: the lower slots among the equals were taken"),
+    # -- a multi-stream residual (ops/mhc.py; zero without) ------------------
+    "hc_unconverged_total": _reg(
+        "counter", "Tokens x mHC units whose Sinkhorn-normalised mixing "
+                   "matrix ended with a row or column sum farther than 1e-3 "
+                   "from 1"),
+    "hc_units_total": _reg(
+        "counter", "Tokens x mHC units counted (two units a layer a token)"),
     # -- recurrent state layers (models/sambay.py, models/falcon_h1.py; zero
     # without) ---------------------------------------------------------------
     "ssm_snapshots_taken_total": _reg(
@@ -1597,6 +1605,7 @@ class Observability:
         program: Optional[str] = None,
         then: Optional[str] = None,
         moe: Optional[Sequence[int]] = None,
+        hc: Optional[Sequence[int]] = None,
         prefill_ctx: Optional[Tuple[int, int]] = None,
         prefill_write: Optional[Dict[str, int]] = None,
         queued: Optional[int] = None,
@@ -1678,6 +1687,8 @@ class Observability:
             rec["program"] = program
         if moe is not None:
             rec["moe"] = dict(zip(_MOE_STATS, map(int, moe)))
+        if hc is not None:
+            rec["hc"] = dict(zip(_HC_STATS, map(int, hc)))
         if prefill_ctx is not None:
             rec["prefill_ctx"] = {
                 "attended": int(prefill_ctx[0]), "view": int(prefill_ctx[1]),

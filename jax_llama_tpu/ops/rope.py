@@ -28,7 +28,8 @@ of activation dtype.
 
 from __future__ import annotations
 
-from typing import Tuple
+import math
+from typing import Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -57,11 +58,45 @@ def llama3_scale_inv_freq(
     return np.where(in_band, mid, out)
 
 
+def yarn_inv_freq(
+    head_dim: int,
+    theta: float,
+    factor: float,
+    original_max_len: int,
+    beta_fast: float = 32.0,
+    beta_slow: float = 1.0,
+) -> np.ndarray:
+    """YaRN's inverse frequencies (the HF deepseek_v3 form): pair i keeps its
+    frequency f_i = theta^(-2i/d) below `low`, is divided by `factor` above
+    `high`, and is blended linearly between — `low` / `high` the pair indices
+    that make `beta_fast` / `beta_slow` turns over `original_max_len`
+    positions, floored / ceiled and clipped to [0, d - 1]."""
+    f = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+
+    def turns(beta):
+        return head_dim * math.log(original_max_len / (beta * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), head_dim - 1)
+    span = (high - low) or 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low) / span, 0.0, 1.0)
+    return f / factor * ramp + f * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: 0.1 mscale ln(factor) + 1 (1 at
+    `factor` <= 1).  With `mscale_all_dim` the softmax scale is multiplied by
+    its square."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def rope_table(
     head_dim: int,
     max_positions: int,
     theta: float = 10000.0,
     use_scaled_rope: bool = False,
+    inv_freq: Optional[np.ndarray] = None,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Precompute (cos, sin) tables, each [max_positions, head_dim // 2], fp32.
 
@@ -69,10 +104,12 @@ def rope_table(
     host-side precompute, model.py:156-161): bit-stable across backends, and
     safe to memoize — a cached jnp array created inside a jit trace would
     leak a tracer into later traces; a numpy array is a fresh constant in
-    every trace.
+    every trace.  `inv_freq` replaces the plain theta^(-2i/d) frequencies
+    (`yarn_inv_freq`).
     """
     assert head_dim % 2 == 0
-    inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
     if use_scaled_rope:
         inv_freq = llama3_scale_inv_freq(inv_freq)
     t = np.arange(max_positions, dtype=np.float64)

@@ -111,6 +111,16 @@ def _expert_block_specs(config: LLaMAConfig) -> Dict[str, Any]:
             "kv_b": P(None, t, None, None), "o": P(None, t, None, None),
             "mlp_norm": norm,
         }
+        if config.q_lora_rank:
+            # the low-rank query: the shared down-projection replicated
+            # like kv_a, the per-head up-projection split like q
+            del attention["q"]
+            attention.update(q_a=P(None, None, None), q_a_norm=norm,
+                             q_b=P(None, t, None, None))
+        if config.hc_mult > 1:
+            # an mHC unit's small float32 parameters, whole on every chip
+            unit = {"phi": P(None, None, None), "b": norm, "alpha": norm}
+            attention.update(hc_attn=unit, hc_ffn=dict(unit))
     else:
         attention = {
             "attn_norm": norm, "post_attn_norm": norm, "mlp_norm": norm,

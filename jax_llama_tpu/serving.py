@@ -224,6 +224,7 @@ from .models.llama import (
 )
 from .models.mla_moe import ctx_tiles
 from .ops.moe import STATS as _MOE_STATS
+from .ops.mhc import STATS as _HC_STATS
 from .models.afmoe import ATTN_STATS as _ATTN_STATS
 from .models.dsa_moe import SELECT_STATS as _SELECT_STATS
 from .ops.attention import NEG_INF
@@ -2653,6 +2654,10 @@ class ContinuousBatcher:
         # Learned sparse attention: what the paged decode rows' selection
         # chose from (``dsa_moe.SELECT_STATS``), behind those again.
         self.attn_step_totals = dict.fromkeys(_ATTN_STATS + _SELECT_STATS, 0)
+        # A multi-stream residual: tokens x mHC units whose mixing matrix
+        # ended off doubly stochastic, and those counted (``ops.mhc.STATS``),
+        # right behind the routing counts in that block's fetch.
+        self.hc_totals = dict.fromkeys(_HC_STATS, 0)
         self.prefill_ctx_slots_attended_total = 0
         self.prefill_ctx_slots_view_total = 0
         self.prefill_blocks_written_total = 0
@@ -3065,6 +3070,7 @@ class ContinuousBatcher:
             ),
             **{f"moe_{k}_total": v for k, v in self.moe_totals.items()},
             **{f"attn_{k}_total": v for k, v in self.attn_step_totals.items()},
+            **{f"hc_{k}_total": v for k, v in self.hc_totals.items()},
             "ssm_snapshots_taken_total": self.ssm_snapshots_taken_total,
             "ssm_snapshots_restored_total": self.ssm_snapshots_restored_total,
             "ssm_snapshots_evicted_total": getattr(
@@ -3548,7 +3554,7 @@ class ContinuousBatcher:
         arr = np.asarray(packed)
         self.host_syncs_total += 1
         now_obs = time.monotonic()
-        moe_counts = None
+        moe_counts = hc_counts = None
         if self.pool.stats is not None:
             # Trailing planes of the same fetch (``_pack_stats``).
             counts = [
@@ -3558,8 +3564,14 @@ class ContinuousBatcher:
             moe_counts = counts[:len(_MOE_STATS)]
             for name, v in zip(_MOE_STATS, moe_counts):
                 self.moe_totals[name] += v
-            for name, v in zip(self.attn_step_totals, counts[len(_MOE_STATS):]):
-                self.attn_step_totals[name] += v
+            tail = counts[len(_MOE_STATS):]
+            if self.config.hc_mult > 1:
+                hc_counts = tail
+                for name, v in zip(_HC_STATS, tail):
+                    self.hc_totals[name] += v
+            else:
+                for name, v in zip(self.attn_step_totals, tail):
+                    self.attn_step_totals[name] += v
         if pf_ctx is not None:
             self.prefill_ctx_slots_attended_total += pf_ctx[0]
             self.prefill_ctx_slots_view_total += pf_ctx[1]
@@ -3570,7 +3582,7 @@ class ContinuousBatcher:
             wall_ms=(now_obs - t0_obs) * 1000.0,
             fetch_ms=(now_obs - tf_obs) * 1000.0,
             swap_inflight=len(self._restoring), rids=obs_rids,
-            program=prog, then="emit", moe=moe_counts,
+            program=prog, then="emit", moe=moe_counts, hc=hc_counts,
             prefill_ctx=pf_ctx,
             prefill_write=pf_write,
             queued=queued,

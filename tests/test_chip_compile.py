@@ -596,3 +596,38 @@ def test_parallel_mixer_fused_chunk_for_v5e(sds, monkeypatch):
     copies = [l.strip()[:140] for l in text.splitlines()
               if re.search(rf" = {whole}\S* copy\(", l)]
     assert not copies, copies
+
+
+# --- the multi-stream residual's unit at its cell's shapes
+# (xing4-freshdocs-asks): 4 streams of 3584 --------------------------------
+
+@pytest.mark.parametrize("tokens", [16, 2048], ids=["decode-rows", "prompt-chunk"])
+def test_a_streams_unit_compiles_for_v5e(sds, monkeypatch, tokens):
+    """One mHC unit (`ops/mhc.py`) around a stand-in inner product at
+    Xing4.0's widths: Sinkhorn's 20 rounds are ONE Mosaic call, and no
+    float32 array of the stream's size stands in the compiled program."""
+    import re
+
+    from jax_llama_tpu.ops import mhc
+
+    monkeypatch.setattr(mhc, "_resolve_interpret", lambda _=None: False)
+    n, C = 4, 3584
+    K = n * n + 2 * n
+
+    def unit(X, hp, w):
+        h_pre, h_post, h_res, stats = mhc.coefficients(
+            X, hp, iters=20, eps=1e-6, clamp=(-30.0, 30.0))
+        y = jnp.einsum("btc,cd->btd", mhc.pre(X, h_pre), w)
+        return mhc.post(X, y, h_res, h_post), stats
+
+    hp = {"phi": sds((n * C, K), jnp.float32), "b": sds((K,), jnp.float32),
+          "alpha": sds((3,), jnp.float32)}
+    text = jax.jit(unit).lower(
+        sds((1, tokens, n, C), jnp.bfloat16), hp, sds((C, C), jnp.bfloat16)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "hc_sinkhorn" in text
+    # what a fusion, a copy or a product WRITES (a convert inside a fusion
+    # lives in registers)
+    wide = [line.strip()[:120] for line in text.splitlines()
+            if re.match(rf"\s*(ROOT )?%\S+ = f32\[(1,)?({tokens},)?(4,)?({tokens},)?({C}|{n * C})\]", line)
+            and re.search(r" (fusion|copy|convolution|custom-call)\(", line)]
+    assert not wide, wide
