@@ -17,6 +17,14 @@ product is the upstream Pallas grouped matmul (`megablox.gmm`), which visits
 only the non-empty groups, so a decode batch of 8 rows reads the ~40 experts
 it touches and not all 128; everywhere else it is `lax.ragged_dot`.
 
+The kernel's tile follows from the product's shape (`_tiling`): the whole
+contraction and the widest slice of the output whose blocks fit a budget of
+vector memory.  Its grid walks, for each slice of N, the (row tile, expert)
+pairs in order with the contraction innermost; with ONE step of K the pairs
+of one expert ask for the same `[K, tn]` weight block one after another and
+the pipeline copies it once, so an expert's weights cross HBM once a product
+however many row tiles its rows straddle, in N / tn large copies.
+
 Rows that are not tokens (a masked decode row, prompt padding) are routed to
 no expert: they sort behind every real pair, belong to no group, and get a
 zero result.  They count in none of the routing statistics.
@@ -31,10 +39,14 @@ import jax.numpy as jnp
 from jax import lax
 
 # Rows of one grouped-matmul tile.  Every (non-empty expert, row tile) pair
-# is one pass over that expert's weight, computed at the full tile whatever
-# the rows it holds: at ~96 rows an expert (a 2048-token chunk over 128
-# experts) a wider tile multiplies mostly masked rows.
+# is one product with that expert's resident weight block, computed at the
+# full tile whatever the rows it holds: at ~96-128 rows an expert (a
+# 2048-token chunk) a wider tile multiplies mostly masked rows.
 _TILE_M = 128
+# Bytes of vector memory a grid step's blocks may take (`_block_bytes`):
+# three quarters of the 16 MiB a kernel gets on a v5e without asking for
+# more, which the upstream kernel does not.
+_BLOCK_BUDGET = 12 * 2 ** 20
 # What a call counts of its routing, in this order: (token, expert) pairs,
 # distinct experts touched, the call itself, and the largest per-expert
 # count — each summed over calls by whoever accumulates them.
@@ -68,10 +80,29 @@ def route(
     return idx.astype(jnp.int32), w
 
 
-def _tiling(k: int, n: int) -> Tuple[int, int, int]:
-    tk = next(t for t in (1024, 768, 512, 256, 128, k) if k % t == 0)
-    tn = next(t for t in (512, 256, 128, n) if n % t == 0)
-    return _TILE_M, tk, tn
+def _block_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """What one grid step of the grouped matmul holds in vector memory: the
+    weight, row and output blocks twice (the pipeline's two buffers) and
+    the float32 accumulator."""
+    return 2 * (tk * tn + tm * tk + tm * tn) * itemsize + 4 * tm * tn
+
+
+def _tiling(k: int, n: int, dtype) -> Tuple[int, int, int]:
+    """The tile (tm, tk, tn) of a grouped product [., k] x [k, n], from its
+    shape alone: the whole contraction and the widest output tile (a divisor
+    of n by 128s) whose blocks fit `_BLOCK_BUDGET`; a K too long for that at
+    any width by the largest of the old ladder that divides it.  The rows
+    do not enter: one row tile of a decode iteration and a chunk's 64-128
+    want the same tile (v5e, `tests/tools/gmm_tiles.py`; PERF.md section 6,
+    PR 52)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    widths = [t for t in range(n - n % 128, 0, -128) if n % t == 0] or [n]
+    depths = [k] + [t for t in (1024, 768, 512, 256, 128) if t < k and k % t == 0]
+    for tk in depths:
+        for tn in widths:
+            if _block_bytes(_TILE_M, tk, tn, itemsize) <= _BLOCK_BUDGET:
+                return _TILE_M, tk, tn
+    return _TILE_M, depths[-1], widths[-1]
 
 
 def grouped_matmul(
@@ -100,7 +131,8 @@ def grouped_matmul(
         jnp.zeros((L * E,), jnp.int32), group_sizes, (layer * E,))
     return gmm(
         x, w.reshape((L * E,) + w.shape[2:]), sizes,
-        preferred_element_type=x.dtype, tiling=_tiling(w.shape[2], w.shape[3]),
+        preferred_element_type=x.dtype,
+        tiling=_tiling(w.shape[2], w.shape[3], x.dtype),
     )
 
 

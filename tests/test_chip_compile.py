@@ -202,24 +202,47 @@ def test_latent_paged_decode_compiles_for_v5e(sds):
 
 
 @pytest.mark.parametrize(
-    "m,k,n", [(128, 2048, 1536), (12416, 2048, 1536), (12416, 768, 2048)],
-    ids=["decode-gate_up", "chunk-gate_up", "chunk-down"],
+    "m,k,n,groups",
+    [
+        (128, 2048, 1536, 7 * 128), (12416, 2048, 1536, 7 * 128),
+        (12416, 768, 2048, 7 * 128),
+        (128, 3584, 2048, 5 * 64), (8192, 3584, 2048, 5 * 64),
+        (128, 1024, 3584, 5 * 64), (8192, 1024, 3584, 5 * 64),
+        (2048, 3584, 2048, 5 * 64), (2048, 1024, 3584, 5 * 64),
+        (128, 2048, 2048, 4 * 128), (16384, 2048, 2048, 4 * 128),
+        (128, 1024, 2048, 4 * 128), (16384, 1024, 2048, 4 * 128),
+        (4096, 2048, 2048, 4 * 128), (3072, 768, 2048, 7 * 128),
+    ],
+    ids=[
+        "decode-gate_up", "chunk-gate_up", "chunk-down",
+        "xing4-decode-gate_up", "xing4-chunk-gate_up",
+        "xing4-decode-down", "xing4-chunk-down",
+        "xing4-chunk512-gate_up", "xing4-chunk512-down",
+        "trinity-decode-gate_up", "trinity-chunk-gate_up",
+        "trinity-decode-down", "trinity-chunk-down",
+        "trinity-chunk512-gate_up", "kanana-chunk512-down",
+    ],
 )
-def test_grouped_expert_matmul_compiles_for_v5e(sds, m, k, n):
-    """The grouped matmul of ops/moe.py (upstream megablox) at the tilings
-    `_tiling` picks for kanana-2-30b-a3b's experts (128 of 2048 x 1536 and
-    768 x 2048 in each of 7 expert layers, all handed to the kernel): 48
-    decode rows padded to one tile, and a 2048-token chunk's 12,336 (token,
-    expert) pairs."""
+def test_grouped_expert_matmul_compiles_for_v5e(sds, m, k, n, groups):
+    """The grouped matmul of ops/moe.py (upstream megablox) at the tiles
+    `_tiling` picks for the published experts, all layers' experts handed
+    to the kernel: kanana-2-30b-a3b's (128 of 2048 x 1536 and 768 x 2048
+    in each of 7 layers; 48 decode rows padded to one tile, a 2048-token
+    chunk's 12,336 pairs; Keye-VL-2.0's are the same products),
+    Xing4.0-29B-A4B's (64 of 3584 x 2048 and 1024 x 3584 in 5 layers; 4
+    pairs a token) and Trinity-Mini's (128 of 2048 x 2048 and 1024 x 2048
+    in 4 layers; 8 pairs a token), with a 512-token chunk's rows.  The
+    compile sees the vector-memory limit the rule's budget stands under."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     from jax_llama_tpu.ops.moe import _tiling
 
     fn = jax.jit(lambda x, w, g: gmm(
-        x, w, g, preferred_element_type=jnp.bfloat16, tiling=_tiling(k, n)))
+        x, w, g, preferred_element_type=jnp.bfloat16,
+        tiling=_tiling(k, n, jnp.bfloat16)))
     _assert_mosaic(fn.lower(
-        sds((m, k), jnp.bfloat16), sds((7 * 128, k, n), jnp.bfloat16),
-        sds((7 * 128,), jnp.int32),
+        sds((m, k), jnp.bfloat16), sds((groups, k, n), jnp.bfloat16),
+        sds((groups,), jnp.int32),
     ))
 
 
