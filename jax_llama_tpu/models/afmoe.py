@@ -152,7 +152,8 @@ def forward(
     `PagedKVCache`."""
     from .llama import (
         FLASH_MIN_SEQ, AuxOutput, KVCache, PagedKVCache, _swiglu,
-        lm_head_logits, paged_pool_write, paged_write_indices, qeinsum,
+        embed_tokens, layer_scan, lm_head_logits, paged_pool_write,
+        paged_write_indices, qeinsum,
     )
 
     if dropout_rng is not None:
@@ -228,7 +229,7 @@ def forward(
     # The tokens' rotary rows, once a call, outside the scans and branches.
     cos, sin = rope_rows(q_positions, hd, config.rope_theta)
 
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
+    x = embed_tokens(params, tokens)
     x = (x.astype(jnp.float32) * math.sqrt(config.dim)).astype(adt)
 
     def attend(q, k, v, ck, cv, li, window):
@@ -308,7 +309,7 @@ def forward(
             return y, (kept, stats)
 
         if config.scan_layers:
-            return lax.scan(body, x, xs, unroll=config.scan_unroll)
+            return layer_scan(body, x, xs, unroll=config.scan_unroll)
         outs = []
         for i in range(n):
             x, ys = body(x, jax.tree.map(lambda a: a[i], xs))

@@ -174,8 +174,9 @@ def forward(
         plan_live_steps,
     )
     from .llama import (
-        FLASH_MIN_SEQ, AuxOutput, KVCache, PagedKVCache, lm_head_logits,
-        paged_pool_write, paged_write_indices, qeinsum,
+        FLASH_MIN_SEQ, AuxOutput, KVCache, PagedKVCache, embed_tokens,
+        layer_scan, lm_head_logits, paged_pool_write, paged_write_indices,
+        qeinsum,
     )
 
     if dropout_rng is not None:
@@ -244,7 +245,7 @@ def forward(
     cos, sin = rope_rows(q_positions, hd, config.rope_theta)
     cos_i, sin_i = rope_rows(q_positions, di // 2, config.rope_theta)
 
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(adt)
+    x = embed_tokens(params, tokens).astype(adt)
 
     def with_new(old, new):
         """A layer's cache slice [B, S, ...] (one head: [B, S, width]) with
@@ -344,7 +345,7 @@ def forward(
         return x + f, (kept, stats, ties)
 
     if config.scan_layers:
-        x, ((new_k, new_v, new_i), stats, ties) = lax.scan(
+        x, ((new_k, new_v, new_i), stats, ties) = layer_scan(
             body, x, xs, unroll=config.scan_unroll)
     else:
         outs = []

@@ -70,7 +70,7 @@ from ..ops.flash_attention import flash_attention
 from ..ops.norm import rms_norm
 from ..ops.rope import apply_rope_rows, rope_rows
 from .afmoe import ATTN_STATS
-from .llama import qeinsum  # `llama` reaches this module inside its functions only
+from .llama import embed_tokens, layer_scan, qeinsum  # `llama` reaches this module inside its functions only
 
 Params = Dict[str, Any]
 
@@ -313,7 +313,7 @@ def _ffn(x, lp, config):
 def _scan_layers(layer, carry, xs, config):
     """The stack as ONE scan of `layer`, or unrolled (`scan_layers` off)."""
     if config.scan_layers:
-        return lax.scan(layer, carry, xs, unroll=config.scan_unroll)
+        return layer_scan(layer, carry, xs, unroll=config.scan_unroll)
     outs = []
     for i in range(config.n_layers):
         carry, y = layer(carry, jax.tree.map(lambda a: a[i], xs))
@@ -430,7 +430,7 @@ def forward(
         attended, k, v = _attention(a, lp, config, cos, sin, attend)
         return (_ffn(x + mixed + attended, lp, config), conv_all, ssm_all), (k, v)
 
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
+    x = embed_tokens(params, tokens)
     x = _scaled(x.astype(adt), config.embedding_multiplier, adt)
     per_layer = (params["layers"], jnp.arange(config.n_layers, dtype=jnp.int32))
     if cached:  # read-only through the scan: one write after it
@@ -567,9 +567,8 @@ def mixed_forward(
         return ((x, conv_c, ssm_c, conv_r, ssm_r),
                 (k[:, :C], v[:, :C], riders(k), riders(v)))
 
-    x = jnp.take(
-        params["embed"]["embedding"],
-        jnp.concatenate([tokens, rider_tokens[None]], axis=1), axis=0)
+    x = embed_tokens(
+        params, jnp.concatenate([tokens, rider_tokens[None]], axis=1))
     x = _scaled(x.astype(adt), config.embedding_multiplier, adt)
     per_layer = (params["layers"], jnp.arange(config.n_layers, dtype=jnp.int32),
                  cache.k, cache.v)

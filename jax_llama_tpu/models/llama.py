@@ -419,61 +419,62 @@ def paged_pool_write(
     upd: matching [L, KVH, B, T, d] / [L, KVH, B, T] / [B, T].
     blk, off: [B, T] int32 physical coordinates (sentinel NB = drop).
     """
-    B, T = blk.shape
-    plane = _constrain_pool_plane(plane)
-    # The update slabs carry the same [L, KVH, ...] head axis: pin them
-    # too, or their (replicated) sharding drags the slab re-reads — and
-    # with them the whole plane — replicated through the `where`.
-    upd = _constrain_pool_plane(upd)
-    if B * T > _POOL_WRITE_UNROLL_MAX:
-        # Batched scatter: mode="drop" discards the sentinel NB pairs,
-        # matching the chain's contract exactly.
-        if plane.ndim == 5 or plane.ndim == 4:
-            return _constrain_pool_plane(plane.at[:, :, blk, off].set(
-                upd.astype(plane.dtype), mode="drop"
-            ))
-        return plane.at[blk, off].set(upd.astype(plane.dtype), mode="drop")
-    if plane.ndim == 5:
-        L, KVH, NB, BLK, d = plane.shape
-        nb_ax, slab = 2, (L, KVH, 1, 1, d)
-        pick = lambda b, t: upd[:, :, b, t][:, :, None, None, :]
-    elif plane.ndim == 4:
-        L, KVH, NB, BLK = plane.shape
-        nb_ax, slab = 2, (L, KVH, 1, 1)
-        pick = lambda b, t: upd[:, :, b, t][:, :, None, None]
-    else:
-        NB, BLK = plane.shape
-        nb_ax, slab = 0, (1, 1)
-        pick = lambda b, t: upd[b, t][None, None]
-    live = blk < NB  # off is always in range (contract above)
-    zero = jnp.int32(0)
-    if rolled:
-        def one(i, plane):
-            b, t = i // T, i % T
-            at = lambda *bt: (
-                (zero,) * nb_ax + bt + (zero,) * (plane.ndim - nb_ax - 2)
-            )
-            start = at(blk[b, t], off[b, t])
-            cur = lax.dynamic_slice(plane, start, slab)
-            new = lax.dynamic_slice(upd, at(b, t), slab)
-            u = jnp.where(live[b, t], new.astype(plane.dtype), cur)
-            return _constrain_pool_plane(
-                lax.dynamic_update_slice(plane, u, start)
-            )
+    with jax.named_scope("cache.write"):
+        B, T = blk.shape
+        plane = _constrain_pool_plane(plane)
+        # The update slabs carry the same [L, KVH, ...] head axis: pin them
+        # too, or their (replicated) sharding drags the slab re-reads — and
+        # with them the whole plane — replicated through the `where`.
+        upd = _constrain_pool_plane(upd)
+        if B * T > _POOL_WRITE_UNROLL_MAX:
+            # Batched scatter: mode="drop" discards the sentinel NB pairs,
+            # matching the chain's contract exactly.
+            if plane.ndim == 5 or plane.ndim == 4:
+                return _constrain_pool_plane(plane.at[:, :, blk, off].set(
+                    upd.astype(plane.dtype), mode="drop"
+                ))
+            return plane.at[blk, off].set(upd.astype(plane.dtype), mode="drop")
+        if plane.ndim == 5:
+            L, KVH, NB, BLK, d = plane.shape
+            nb_ax, slab = 2, (L, KVH, 1, 1, d)
+            pick = lambda b, t: upd[:, :, b, t][:, :, None, None, :]
+        elif plane.ndim == 4:
+            L, KVH, NB, BLK = plane.shape
+            nb_ax, slab = 2, (L, KVH, 1, 1)
+            pick = lambda b, t: upd[:, :, b, t][:, :, None, None]
+        else:
+            NB, BLK = plane.shape
+            nb_ax, slab = 0, (1, 1)
+            pick = lambda b, t: upd[b, t][None, None]
+        live = blk < NB  # off is always in range (contract above)
+        zero = jnp.int32(0)
+        if rolled:
+            def one(i, plane):
+                b, t = i // T, i % T
+                at = lambda *bt: (
+                    (zero,) * nb_ax + bt + (zero,) * (plane.ndim - nb_ax - 2)
+                )
+                start = at(blk[b, t], off[b, t])
+                cur = lax.dynamic_slice(plane, start, slab)
+                new = lax.dynamic_slice(upd, at(b, t), slab)
+                u = jnp.where(live[b, t], new.astype(plane.dtype), cur)
+                return _constrain_pool_plane(
+                    lax.dynamic_update_slice(plane, u, start)
+                )
 
-        return _pin_pool_layout(lax.fori_loop(0, B * T, one, plane))
-    for b in range(B):
-        for t in range(T):
-            start = (
-                (zero,) * nb_ax + (blk[b, t], off[b, t])
-                + (zero,) * (plane.ndim - nb_ax - 2)
-            )
-            cur = lax.dynamic_slice(plane, start, slab)
-            u = jnp.where(live[b, t], pick(b, t).astype(plane.dtype), cur)
-            plane = _constrain_pool_plane(
-                lax.dynamic_update_slice(plane, u, start)
-            )
-    return _pin_pool_layout(plane)
+            return _pin_pool_layout(lax.fori_loop(0, B * T, one, plane))
+        for b in range(B):
+            for t in range(T):
+                start = (
+                    (zero,) * nb_ax + (blk[b, t], off[b, t])
+                    + (zero,) * (plane.ndim - nb_ax - 2)
+                )
+                cur = lax.dynamic_slice(plane, start, slab)
+                u = jnp.where(live[b, t], pick(b, t).astype(plane.dtype), cur)
+                plane = _constrain_pool_plane(
+                    lax.dynamic_update_slice(plane, u, start)
+                )
+        return _pin_pool_layout(plane)
 
 
 def paged_pool_write_blocks(
@@ -506,23 +507,43 @@ def paged_pool_write_blocks(
     upd: matching [L, KVH, n, BLK, d] / [L, KVH, n, BLK] / [n, BLK].
     blk: [n] int32 physical block ids (sentinel NB = drop).
     """
-    (n,) = blk.shape
-    plane = _constrain_pool_plane(plane)
-    upd = _constrain_pool_plane(upd)  # see paged_pool_write
-    nb_ax = 0 if plane.ndim == 2 else 2
-    live = blk < plane.shape[nb_ax]
-    zero = jnp.int32(0)
-    for j in range(n):
-        start = (
-            (zero,) * nb_ax + (blk[j],) + (zero,) * (plane.ndim - nb_ax - 1)
-        )
-        new = lax.slice_in_dim(upd, j, j + 1, axis=nb_ax)
-        cur = lax.dynamic_slice(plane, start, new.shape)
-        u = jnp.where(live[j], new.astype(plane.dtype), cur)
-        plane = _constrain_pool_plane(
-            lax.dynamic_update_slice(plane, u, start)
-        )
-    return _pin_pool_layout(plane)
+    with jax.named_scope("cache.write"):
+        (n,) = blk.shape
+        plane = _constrain_pool_plane(plane)
+        upd = _constrain_pool_plane(upd)  # see paged_pool_write
+        nb_ax = 0 if plane.ndim == 2 else 2
+        live = blk < plane.shape[nb_ax]
+        zero = jnp.int32(0)
+        for j in range(n):
+            start = (
+                (zero,) * nb_ax + (blk[j],)
+                + (zero,) * (plane.ndim - nb_ax - 1)
+            )
+            new = lax.slice_in_dim(upd, j, j + 1, axis=nb_ax)
+            cur = lax.dynamic_slice(plane, start, new.shape)
+            u = jnp.where(live[j], new.astype(plane.dtype), cur)
+            plane = _constrain_pool_plane(
+                lax.dynamic_update_slice(plane, u, start)
+            )
+        return _pin_pool_layout(plane)
+
+
+def layer_scan(body, carry, xs, **kwargs):
+    """``lax.scan`` over a stack of layers — every block's — under the
+    scope ``layers``: what the scan itself does a layer (the slices of
+    the stacked weights and cache planes it hands ``body``, the stacking
+    of what ``body`` returns) and what a layer does outside its
+    sub-blocks' own scopes (norms, residual adds) read under it; the
+    sub-blocks keep theirs, which are innermost."""
+    with jax.named_scope("layers"):
+        return lax.scan(body, carry, xs, **kwargs)
+
+
+def embed_tokens(params: Params, tokens: jnp.ndarray) -> jnp.ndarray:
+    """The token embedding lookup of every block's forward, [..., D] in
+    the table's dtype, under the scope ``embed``."""
+    with jax.named_scope("embed"):
+        return jnp.take(params["embed"]["embedding"], tokens, axis=0)
 
 
 def lm_head_logits(
@@ -1272,7 +1293,7 @@ def forward(
         config.use_scaled_rope,
     )
 
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(adt)
+    x = embed_tokens(params, tokens).astype(adt)
     x = constrain(x, "data", "seq", None)
 
     layers_rng = None
@@ -1448,7 +1469,7 @@ def forward(
 
             if config.remat:
                 one = _remat(one, config)
-            y, _ = lax.scan(one, xx, stage_layers)
+            y, _ = layer_scan(one, xx, stage_layers)
             return y
 
         x = pipeline_blocks(
@@ -1481,7 +1502,7 @@ def forward(
                 )
                 return y, (ck, cv, cks, cvs)
 
-            x, (new_k, new_v, nks, nvs) = lax.scan(
+            x, (new_k, new_v, nks, nvs) = layer_scan(
                 scan_fn, x,
                 (lp, cache.k, cache.v, cache.k_scale, cache.v_scale),
                 unroll=config.scan_unroll,
@@ -1503,7 +1524,7 @@ def forward(
                 )
                 return y, (ck, cv)
 
-            x, (new_k, new_v) = lax.scan(
+            x, (new_k, new_v) = layer_scan(
                 scan_fn, x, (lp, cache.k, cache.v),
                 unroll=config.scan_unroll,
             )
@@ -1519,7 +1540,7 @@ def forward(
                 )
                 return y, None
 
-            x, _ = lax.scan(
+            x, _ = layer_scan(
                 scan_fn, x, (lp, layer_rngs), unroll=config.scan_unroll
             )
         else:
@@ -1527,7 +1548,7 @@ def forward(
                 y, *_ = block(carry, layer_params, None, None)
                 return y, None
 
-            x, _ = lax.scan(scan_fn, x, lp, unroll=config.scan_unroll)
+            x, _ = layer_scan(scan_fn, x, lp, unroll=config.scan_unroll)
     elif pp_stages <= 1:
         unroll_rngs = (
             jax.random.split(layers_rng, config.n_layers)
@@ -1686,7 +1707,7 @@ def paged_forward(
         config.use_scaled_rope,
     )
 
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(adt)
+    x = embed_tokens(params, tokens).astype(adt)
     # The kernel derives token t's mask/position from positions[:, 0] + t
     # (sublane iota) and treats a row as live or dead as a whole, so the
     # T > 1 contract above is enforced by DEFINITION rather than trust:
@@ -1737,7 +1758,7 @@ def paged_forward(
             ys = (ck, cv, cks, cvs) if cache.quantized else (ck, cv)
             return y, ys
 
-        x, ys = lax.scan(
+        x, ys = layer_scan(
             scan_fn, x, (lp, layer_idx), unroll=config.scan_unroll
         )
         if cache.quantized:
@@ -1864,9 +1885,8 @@ def mixed_forward(
         config.head_dim, max(2 * config.max_seq_len, cache.max_len),
         config.rope_theta, config.use_scaled_rope,
     )
-    x = jnp.take(
-        params["embed"]["embedding"],
-        jnp.concatenate([tokens, rider_tokens[None]], axis=1), axis=0,
+    x = embed_tokens(
+        params, jnp.concatenate([tokens, rider_tokens[None]], axis=1)
     ).astype(config.activation_dtype)
 
     impl = config.attn_impl
@@ -1915,7 +1935,7 @@ def mixed_forward(
         jnp.arange(config.n_layers, dtype=jnp.int32),
     )
     if config.scan_layers:
-        x, ys = lax.scan(layer, x, xs, unroll=config.scan_unroll)
+        x, ys = layer_scan(layer, x, xs, unroll=config.scan_unroll)
     else:
         per_layer = []
         for i in range(config.n_layers):

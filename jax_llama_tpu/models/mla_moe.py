@@ -339,7 +339,8 @@ def forward(
     over a `KVCache` (scalar or per-row index) or over a `PagedKVCache`."""
     from .llama import (
         FLASH_MIN_SEQ, AuxOutput, KVCache, PagedKVCache, _rope_tables, _swiglu,
-        lm_head_logits, paged_pool_write, paged_write_indices, qeinsum,
+        embed_tokens, layer_scan, lm_head_logits, paged_pool_write,
+        paged_write_indices, qeinsum,
     )
 
     if dropout_rng is not None:
@@ -394,7 +395,7 @@ def forward(
             [cache.pos, new_pos], axis=1)
         bias = None if use_flash else attention_bias(q_positions, kv_pos, kv_pos >= 0)
 
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(adt)
+    x = embed_tokens(params, tokens).astype(adt)
     n_streams = config.hc_mult
     if n_streams > 1:
         x = jnp.broadcast_to(x[:, :, None, :], (B, T, n_streams, x.shape[-1]))
@@ -481,8 +482,9 @@ def forward(
             return y, (latent, stats)
 
         if config.scan_layers:
-            return lax.scan(body, x, (lp, first + jnp.arange(n, dtype=jnp.int32)),
-                            unroll=config.scan_unroll)
+            return layer_scan(
+                body, x, (lp, first + jnp.arange(n, dtype=jnp.int32)),
+                unroll=config.scan_unroll)
         outs = []
         for i in range(n):
             x, ys = body(x, (jax.tree.map(lambda a: a[i], lp), jnp.int32(first + i)))

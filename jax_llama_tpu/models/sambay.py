@@ -77,7 +77,7 @@ from ..ops.flash_attention import flash_attention
 from ..ops.norm import layer_norm
 from .afmoe import ATTN_STATS
 from .falcon_h1 import _conv  # the causal depthwise conv behind a row's held inputs
-from .llama import _swiglu, qeinsum  # `llama` reaches this module inside its functions only
+from .llama import _swiglu, embed_tokens, layer_scan, qeinsum  # `llama` reaches this module inside its functions only
 from .mla_moe import INIT_STD
 
 Params = Dict[str, Any]
@@ -338,7 +338,7 @@ def _layers(params, x, config, state, planes, mixer, attender):
 
     def scan(body, x, xs):
         if config.scan_layers:
-            return lax.scan(body, x, xs, unroll=config.scan_unroll)
+            return layer_scan(body, x, xs, unroll=config.scan_unroll)
         n = jax.tree_util.tree_leaves(xs)[0].shape[0]
         outs = []
         for i in range(n):
@@ -484,7 +484,7 @@ def forward(
         return attend_rows(k, v, ck, cv, windowed), (k, v)
 
     cached = cache is not None and not paged
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0).astype(adt)
+    x = embed_tokens(params, tokens).astype(adt)
     x, (new_k, new_v), (new_conv, new_ssm) = _layers(
         params, x, config, (conv0, ssm0),
         (cache.k, cache.v) if cached else (), mixer, attender)
@@ -610,9 +610,8 @@ def mixed_forward(
         rode = attend_paged(*kept[2:], plane, windowed)
         return lambda q: rejoin(chunk(q[:, :C]), rode(riders(q))), kept
 
-    x = jnp.take(
-        params["embed"]["embedding"],
-        jnp.concatenate([tokens, rider_tokens[None]], axis=1), axis=0,
+    x = embed_tokens(
+        params, jnp.concatenate([tokens, rider_tokens[None]], axis=1),
     ).astype(config.activation_dtype)
     x, (new_k, new_v, rider_k, rider_v), (conv_c, ssm_c, conv_r, ssm_r) = _layers(
         params, x, config, (cache.conv, cache.ssm, pool.conv, pool.ssm),

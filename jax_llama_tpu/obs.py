@@ -176,6 +176,69 @@ LOOP_SPANS: Dict[str, str] = {
     "emit.free": "emit.replay",    # a finished row's slot free
 }
 
+# The device side's spans: every ``jax.named_scope`` the package opens, a
+# closed set (tests/test_scopes.py holds the source to it both ways).  A
+# scope is a name on the operations traced under it and costs nothing at
+# run time; the profiler puts an operation's path in its ``tf_op`` stat,
+# on the clock the host spans above share.  LANES say which half of a
+# dispatch an operation served — a reader takes the LAST lane of a path
+# (``lane.mixed`` opens inside ``lane.chunk``); the insert programs open
+# none, their module name is their lane.  The others are LEAF scopes:
+# what the operation did, the innermost one of a path
+# (``utils.profiling.lane_and_scope``).  A scope added to an unchanged
+# program shows only in executables compiled after it: the compile
+# cache's key leaves names out.
+DEVICE_LANES: Dict[str, str] = {
+    "lane.chunk": "_fused_chunk's in-flight admission: state pick-up, the "
+                  "view's gather, the chunk's forward, landing, the sample",
+    "lane.mixed": "the mixed branch's shared pass: emit, mixed_forward, the "
+                  "shared head product, the riders' draw and advance",
+    "lane.decode": "_chunk_scan's lax.scan: every decode iteration of "
+                   "_paged_decode_chunk and of _fused_chunk",
+}
+DEVICE_SCOPES: Dict[str, str] = {
+    **DEVICE_LANES,
+    # serving.py
+    "cache.gather": "_gather_cache: a row's contiguous view cut from the pool",
+    "cache.land": "_land_chunk / _scatter_back / an insert's landing: new "
+                  "entries of a view or a prefill cache into pool blocks",
+    "state.move": "recurrent state between slots, snapshots and a view",
+    "sample": "argmax or warp and draw, the logprob, the finite guard",
+    "emit": "_emit, _advance, _pack_stats and the packed block's transposes",
+    "admit.sample": "_admission_sample: the completing prompt's head and draw",
+    # models/llama.py, shared by every block
+    "cache.write": "paged_pool_write(_blocks): the dynamic_update_slice chain",
+    "embed": "embed_tokens: the token embedding lookup",
+    "layers": "layer_scan: the layer scan's own slices and stacking, and a "
+              "layer's norms and residual adds outside its sub-blocks",
+    "head": "lm_head_logits: the final norm and the vocabulary product",
+    "dense.attention": "the dense block's attention, norm to output product",
+    "dense.ffn": "a SwiGLU feed-forward (dense layers of every block)",
+    # models/mla_moe.py, ops/mhc.py
+    "mla.project": "latent attention's q / kv_a / kv_b / o products and rope",
+    "mla.attend_decode": "absorbed attention over the latent cache",
+    "mla.attend_prefill": "decompressed or tiled attention of a prompt chunk",
+    "hc.coeff": "an mHC unit's coefficients (Sinkhorn)",
+    "hc.pre": "an mHC unit's read of the streams",
+    "hc.post": "an mHC unit's write back into the streams",
+    # ops/moe.py, models/mla_moe.py
+    "moe.route": "the router's scores, top-k and sort",
+    "moe.experts": "the grouped expert products",
+    "moe.shared": "the shared expert",
+    # models/afmoe.py, sambay.py, falcon_h1.py, dsa_moe.py, ops/key_selection.py
+    "attn.window": "a window attention layer",
+    "attn.full": "a full attention layer",
+    "attn.cross": "a cross layer over the full layer's keys",
+    "attn.proj": "sparse attention's q / k / v / o products, norms, rope",
+    "attn.index": "the index keys' scores",
+    "attn.select": "the k-th value and the chosen list",
+    "attn.sparse": "the gather of the chosen keys and attention over them",
+    "ssm.mix": "a mixer, projections to output (holds ssm.scan / ssm.step)",
+    "ssm.scan": "the recurrence over a prompt chunk",
+    "ssm.step": "the recurrence's one-token step",
+    "gmu.mix": "a gated memory unit",
+}
+
 # Why the queue's head stayed queued (record field ``blocked``, counter
 # admit_blocked_total{reason}): the prefill lane is taken, the pool lacks
 # blocks for its reservation, no slot is free, or a completed swap-in

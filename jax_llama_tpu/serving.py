@@ -395,7 +395,8 @@ def _snapshot_rows(pool: BlockPool, ids: jnp.ndarray):
         live = (ids >= 0).reshape((1, -1) + (1,) * (a.ndim - 2))
         return jnp.where(live, got, jnp.zeros_like(got))
 
-    return take(pool.snap_conv), take(pool.snap_ssm)
+    with jax.named_scope("state.move"):
+        return take(pool.snap_conv), take(pool.snap_ssm)
 
 
 def _state_into_rows(pool: BlockPool, view, rows: Optional[jnp.ndarray]):
@@ -406,10 +407,11 @@ def _state_into_rows(pool: BlockPool, view, rows: Optional[jnp.ndarray]):
         return {}
     if rows is None:
         return {"conv": view.conv, "ssm": view.ssm}
-    return {
-        n: getattr(pool, n).at[:, rows].set(getattr(view, n), mode="drop")
-        for n in _STATE
-    }
+    with jax.named_scope("state.move"):
+        return {
+            n: getattr(pool, n).at[:, rows].set(getattr(view, n), mode="drop")
+            for n in _STATE
+        }
 
 
 def _gather_cache(
@@ -432,20 +434,21 @@ def _gather_cache(
     # block's finite values — the default "fill" mode would inject NaN,
     # which survives the additive -inf mask (NaN + -inf = NaN) and poisons
     # the softmax.  Clipped garbage is masked via n_alloc below.
-    take = functools.partial(jnp.take, mode="clip")
+    with jax.named_scope("cache.gather"):
+        take = functools.partial(jnp.take, mode="clip")
 
-    def g(a):  # [L, KVH, NB, BLK, ...] -> [L, B, MB*BLK, KVH, ...]
-        out = take(a, table, axis=2)  # [L, KVH, B, MB, BLK, ...]
-        out = out.reshape(a.shape[:2] + (B, MB * BLK) + a.shape[4:])
-        return jnp.moveaxis(out, 1, 3)
+        def g(a):  # [L, KVH, NB, BLK, ...] -> [L, B, MB*BLK, KVH, ...]
+            out = take(a, table, axis=2)  # [L, KVH, B, MB, BLK, ...]
+            out = out.reshape(a.shape[:2] + (B, MB * BLK) + a.shape[4:])
+            return jnp.moveaxis(out, 1, 3)
 
-    posg = take(pool.pos, table, axis=0).reshape(B, MB * BLK)
-    valid = jnp.arange(MB, dtype=jnp.int32)[None, :] < n_alloc[:, None]
-    posg = jnp.where(jnp.repeat(valid, BLK, axis=1), posg, -1)
-    view = KVCache(
-        **{"v": None, **_map_planes(g, pool)}, pos=posg, index=fill,
-        stats=pool.stats, **dict(zip(_STATE, state or ())),
-    )
+        posg = take(pool.pos, table, axis=0).reshape(B, MB * BLK)
+        valid = jnp.arange(MB, dtype=jnp.int32)[None, :] < n_alloc[:, None]
+        posg = jnp.where(jnp.repeat(valid, BLK, axis=1), posg, -1)
+        view = KVCache(
+            **{"v": None, **_map_planes(g, pool)}, pos=posg, index=fill,
+            stats=pool.stats, **dict(zip(_STATE, state or ())),
+        )
     if placed:
         # Pin the gathered view to the pool's own KV-head sharding:
         # left unconstrained, GSPMD may satisfy the block gather by
@@ -477,30 +480,31 @@ def _scatter_back(
     oracle this is (tests/test_serving_fused.py)."""
     NB, BLK = pool.pos.shape
     B, MB = table.shape
-    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
-    # Shared write-back contract (same function paged_forward uses);
-    # safe_cols is the matching clamped view column for each slot.
-    blk, off, safe_cols = paged_write_indices(
-        table, fill, active, T, NB, BLK
-    )
-    npos = view.pos[rows, safe_cols]       # [B, T]
-    # view slices are [L, B, T, KVH, ...]; the pool wants KVH-major
-    # ([L, KVH, B, T, ...]).  paged_pool_write = unrolled in-place
-    # dynamic_update_slices; the batched scatter form forced four
-    # full-pool layout copies per step (see its docstring).
-    return dataclasses.replace(
-        pool,
-        **_map_planes(
-            lambda plane, seen: paged_pool_write(
-                plane, jnp.moveaxis(seen[:, rows, safe_cols], 3, 1),
-                blk, off,
+    with jax.named_scope("cache.land"):
+        rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+        # Shared write-back contract (same function paged_forward uses);
+        # safe_cols is the matching clamped view column for each slot.
+        blk, off, safe_cols = paged_write_indices(
+            table, fill, active, T, NB, BLK
+        )
+        npos = view.pos[rows, safe_cols]       # [B, T]
+        # view slices are [L, B, T, KVH, ...]; the pool wants KVH-major
+        # ([L, KVH, B, T, ...]).  paged_pool_write = unrolled in-place
+        # dynamic_update_slices; the batched scatter form forced four
+        # full-pool layout copies per step (see its docstring).
+        return dataclasses.replace(
+            pool,
+            **_map_planes(
+                lambda plane, seen: paged_pool_write(
+                    plane, jnp.moveaxis(seen[:, rows, safe_cols], 3, 1),
+                    blk, off,
+                ),
+                pool, view,
             ),
-            pool, view,
-        ),
-        pos=paged_pool_write(pool.pos, npos, blk, off),
-        stats=view.stats,
-        **_state_into_rows(pool, view, None),
-    )
+            pos=paged_pool_write(pool.pos, npos, blk, off),
+            stats=view.stats,
+            **_state_into_rows(pool, view, None),
+        )
 
 
 def _land_chunk(
@@ -518,23 +522,25 @@ def _land_chunk(
     blocks and keeps ``write_at + C`` inside the view; table entries
     past the row's reservation hold the sentinel and drop."""
     NB, BLK = pool.pos.shape
-    n = C // BLK
-    col = write_at // BLK + jnp.arange(n, dtype=jnp.int32)
-    blk = jnp.take(table_r[0], col, mode="fill", fill_value=NB)
+    with jax.named_scope("cache.land"):
+        n = C // BLK
+        col = write_at // BLK + jnp.arange(n, dtype=jnp.int32)
+        blk = jnp.take(table_r[0], col, mode="fill", fill_value=NB)
 
-    def land(plane, seen):
-        # [L, 1, MB*BLK, KVH, ...] -> the chunk, [L, KVH, n, BLK, ...]
-        new = lax.dynamic_slice_in_dim(seen[:, 0], write_at, C, axis=1)
-        new = new.reshape(new.shape[:1] + (n, BLK) + new.shape[2:])
-        return paged_pool_write_blocks(plane, jnp.moveaxis(new, 3, 1), blk)
+        def land(plane, seen):
+            # [L, 1, MB*BLK, KVH, ...] -> the chunk, [L, KVH, n, BLK, ...]
+            new = lax.dynamic_slice_in_dim(seen[:, 0], write_at, C, axis=1)
+            new = new.reshape(new.shape[:1] + (n, BLK) + new.shape[2:])
+            return paged_pool_write_blocks(plane, jnp.moveaxis(new, 3, 1), blk)
 
-    npos = lax.dynamic_slice_in_dim(view.pos[0], write_at, C).reshape(n, BLK)
-    return dataclasses.replace(
-        pool,
-        **_map_planes(land, pool, view),
-        pos=paged_pool_write_blocks(pool.pos, npos, blk),
-        stats=view.stats,
-    )
+        npos = lax.dynamic_slice_in_dim(
+            view.pos[0], write_at, C).reshape(n, BLK)
+        return dataclasses.replace(
+            pool,
+            **_map_planes(land, pool, view),
+            pos=paged_pool_write_blocks(pool.pos, npos, blk),
+            stats=view.stats,
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -743,22 +749,23 @@ def _sample_step(
     its model logprob or None, carried keys).  Key chains split once an
     iteration for all B rows whatever their liveness (never under
     ``all_greedy``)."""
-    if all_greedy:
-        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-    else:
-        keys, subs = _split_rows(keys)
-        nxt = sample_rows(subs, logits[:, -1], temperature, top_p, top_k)
-    # with_logprobs is static (trace-time specialization, like
-    # all_greedy): without it the fp32 [B, V] cast + logsumexp never
-    # enter the compiled program.
-    lp = _token_logprob(logits[:, -1], nxt) if with_logprobs else None
-    # Non-finite guard: a row whose raw logits contain NaN/Inf gets
-    # the -1 token sentinel instead of a draw from garbage; the host
-    # emit scan fails just that request (tokens are never negative,
-    # so the sentinel cannot collide).  Folding the flag into tau
-    # keeps the guard free of extra device->host fetches.
-    nxt = jnp.where(finite_rows(logits[:, -1]), nxt, -1)
-    return nxt, lp, keys
+    with jax.named_scope("sample"):
+        if all_greedy:
+            nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        else:
+            keys, subs = _split_rows(keys)
+            nxt = sample_rows(subs, logits[:, -1], temperature, top_p, top_k)
+        # with_logprobs is static (trace-time specialization, like
+        # all_greedy): without it the fp32 [B, V] cast + logsumexp never
+        # enter the compiled program.
+        lp = _token_logprob(logits[:, -1], nxt) if with_logprobs else None
+        # Non-finite guard: a row whose raw logits contain NaN/Inf gets
+        # the -1 token sentinel instead of a draw from garbage; the host
+        # emit scan fails just that request (tokens are never negative,
+        # so the sentinel cannot collide).  Folding the flag into tau
+        # keeps the guard free of extra device->host fetches.
+        nxt = jnp.where(finite_rows(logits[:, -1]), nxt, -1)
+        return nxt, lp, keys
 
 
 def _admission_sample(
@@ -893,27 +900,29 @@ def _emit(tau, tau_lp, active, remaining, stops):
     """The host emit scan, on device — steps 1 and 2 of an iteration
     (``_paged_decode_chunk``): (this column's tokens [B], their logprobs,
     ``active`` with the rows that just ended folded out, ``remaining``)."""
-    nonfinite = tau < 0
-    hit_stop = stop_token_hits(tau, stops)
-    out_tok = jnp.where(
-        active,
-        jnp.where(nonfinite, -1, tau),
-        _CHUNK_PAD,
-    ).astype(jnp.int32)
-    out_lp = tau_lp
-    done = active & (nonfinite | hit_stop | (remaining <= 1))
-    remaining = remaining - active.astype(jnp.int32)
-    active = active & ~done
-    return out_tok, out_lp, active, remaining
+    with jax.named_scope("emit"):
+        nonfinite = tau < 0
+        hit_stop = stop_token_hits(tau, stops)
+        out_tok = jnp.where(
+            active,
+            jnp.where(nonfinite, -1, tau),
+            _CHUNK_PAD,
+        ).astype(jnp.int32)
+        out_lp = tau_lp
+        done = active & (nonfinite | hit_stop | (remaining <= 1))
+        remaining = remaining - active.astype(jnp.int32)
+        active = active & ~done
+        return out_tok, out_lp, active, remaining
 
 
 def _advance(tau, tau_lp, fill, pos, active, nxt, lp):
     """Step 3's tail: the surviving rows take their draw (``lp`` None:
     no logprobs) and move one slot on."""
-    tau = jnp.where(active, nxt, tau)
-    if lp is not None:
-        tau_lp = jnp.where(active, lp, tau_lp)
-    return tau, tau_lp, fill + active, pos + active
+    with jax.named_scope("emit"):
+        tau = jnp.where(active, nxt, tau)
+        if lp is not None:
+            tau_lp = jnp.where(active, lp, tau_lp)
+        return tau, tau_lp, fill + active, pos + active
 
 
 def _chunk_scan(
@@ -952,44 +961,49 @@ def _chunk_scan(
             (out_tok, out_lp),
         )
 
-    carry, (toks, lps) = lax.scan(
-        body,
-        (pool, tau, tau_lp, fill, pos, active, remaining, keys),
-        None,
-        length=n_iter,
-    )
-    pool, tau, tau_lp, fill, pos, active, remaining, keys = carry
-    if emitted is not None:
-        toks, lps = (
-            jnp.concatenate([first[None], rest])
-            for first, rest in zip(emitted, (toks, lps))
+    # One lane for the scan and for the packing of what it emitted: every
+    # operation of a decode dispatch, and of a fused one past its chunk.
+    with jax.named_scope("lane.decode"):
+        carry, (toks, lps) = lax.scan(
+            body,
+            (pool, tau, tau_lp, fill, pos, active, remaining, keys),
+            None,
+            length=n_iter,
         )
-    # Serving-mesh placement (parallel/serve_mesh.py): pin the carried
-    # state and pool outputs to their canonical shardings so the
-    # donated inputs (placed the same way at construction) alias
-    # shard-locally instead of resharding per dispatch.  ``placed``
-    # is the CTOR's placement decision threaded through as a static
-    # arg — every program a batcher dispatches constrains (or not)
-    # consistently, so pool sharding can never ping-pong between an
-    # insert and a chunk dispatch.  Trace-time no-op when False.
-    if placed:
-        (tau, tau_lp, fill, pos, active, remaining,
-         keys) = smesh.constrain_rows(
-            tau, tau_lp, fill, pos, active, remaining, keys
-        )
-        pool = smesh.constrain_pool(pool)
-    toks = jnp.swapaxes(toks, 0, 1)  # [B, K]
-    if with_logprobs:
-        # One packed transfer: fp32 logprobs ride bitcast to int32
-        # alongside the tokens, so logprobs mode still pays exactly
-        # one device->host fetch per chunk.
-        lp_bits = lax.bitcast_convert_type(
-            jnp.swapaxes(lps, 0, 1).astype(jnp.float32), jnp.int32
-        )
-        packed = jnp.stack([toks, lp_bits])  # [2, B, K]
-    else:
-        packed = toks[None]  # [1, B, K]
-    packed, pool = _pack_stats(packed, pool)
+        pool, tau, tau_lp, fill, pos, active, remaining, keys = carry
+        if emitted is not None:
+            with jax.named_scope("emit"):
+                toks, lps = (
+                    jnp.concatenate([first[None], rest])
+                    for first, rest in zip(emitted, (toks, lps))
+                )
+        # Serving-mesh placement (parallel/serve_mesh.py): pin the carried
+        # state and pool outputs to their canonical shardings so the
+        # donated inputs (placed the same way at construction) alias
+        # shard-locally instead of resharding per dispatch.  ``placed``
+        # is the CTOR's placement decision threaded through as a static
+        # arg — every program a batcher dispatches constrains (or not)
+        # consistently, so pool sharding can never ping-pong between an
+        # insert and a chunk dispatch.  Trace-time no-op when False.
+        if placed:
+            (tau, tau_lp, fill, pos, active, remaining,
+             keys) = smesh.constrain_rows(
+                tau, tau_lp, fill, pos, active, remaining, keys
+            )
+            pool = smesh.constrain_pool(pool)
+        with jax.named_scope("emit"):
+            toks = jnp.swapaxes(toks, 0, 1)  # [B, K]
+            if with_logprobs:
+                # One packed transfer: fp32 logprobs ride bitcast to int32
+                # alongside the tokens, so logprobs mode still pays exactly
+                # one device->host fetch per chunk.
+                lp_bits = lax.bitcast_convert_type(
+                    jnp.swapaxes(lps, 0, 1).astype(jnp.float32), jnp.int32
+                )
+                packed = jnp.stack([toks, lp_bits])  # [2, B, K]
+            else:
+                packed = toks[None]  # [1, B, K]
+        packed, pool = _pack_stats(packed, pool)
     return (
         packed, tau, tau_lp, fill, pos, active, remaining, keys, pool
     )
@@ -1105,135 +1119,149 @@ def _fused_chunk(
         B = tau.shape[0]
         C = pf_chunk
         NB, BLK = pool.pos.shape
-        (pf_row, pf_base, pf_len, pf_key, pf_off,
-         pf_toks) = _unpack_prefill(pf_vec)
-        if pf_snap is not None:
-            pf_snap_in, pf_snap_out = pf_snap[0], pf_snap[1]
-        # ---- one bounded prefill chunk for the in-flight admission ----
-        table_r = lax.dynamic_slice_in_dim(table, pf_row, 1, axis=0)
-        n_alloc_r = lax.dynamic_slice_in_dim(n_alloc, pf_row, 1, axis=0)
-        write_at = (pf_base + pf_off).astype(jnp.int32)
-        state = None
-        if pool.conv is not None:
-            state = tuple(
-                jnp.where(
-                    pf_off == 0, start,
-                    lax.dynamic_slice_in_dim(held, pf_row, 1, axis=1))
-                for start, held in zip(
-                    _snapshot_rows(pool, pf_snap_in[None]),
-                    (pool.conv, pool.ssm))
+        # Everything the in-flight admission costs is one lane; the mixed
+        # branch's shared pass opens its own inside it (a reader takes a
+        # path's LAST lane), the decode scan its own below.
+        with jax.named_scope("lane.chunk"):
+            (pf_row, pf_base, pf_len, pf_key, pf_off,
+             pf_toks) = _unpack_prefill(pf_vec)
+            if pf_snap is not None:
+                pf_snap_in, pf_snap_out = pf_snap[0], pf_snap[1]
+            # ---- one bounded prefill chunk for the in-flight admission ----
+            table_r = lax.dynamic_slice_in_dim(table, pf_row, 1, axis=0)
+            n_alloc_r = lax.dynamic_slice_in_dim(n_alloc, pf_row, 1, axis=0)
+            write_at = (pf_base + pf_off).astype(jnp.int32)
+            state = None
+            if pool.conv is not None:
+                with jax.named_scope("state.move"):
+                    state = tuple(
+                        jnp.where(
+                            pf_off == 0, start,
+                            lax.dynamic_slice_in_dim(held, pf_row, 1, axis=1))
+                        for start, held in zip(
+                            _snapshot_rows(pool, pf_snap_in[None]),
+                            (pool.conv, pool.ssm))
+                    )
+            view = _gather_cache(
+                pool, table_r, n_alloc_r, write_at[None], placed=placed,
+                state=state,
             )
-        view = _gather_cache(
-            pool, table_r, n_alloc_r, write_at[None], placed=placed,
-            state=state,
-        )
-        # Scalar index (ONE prefilling row): keeps the view off the
-        # per-row-index must-xla path, so "auto" runs flash over the
-        # chunk; the host-side _pf_chunk clamp guarantees
-        # write_at + C <= MB * BLK (dynamic_update_slice would otherwise
-        # clamp its start and scribble over the reused prefix KV — the
-        # _suffix_pad hazard).
-        view = dataclasses.replace(view, index=write_at)
-        toks_c = lax.dynamic_slice_in_dim(pf_toks, pf_off, C)[None]
-        positions, real = window_positions(pf_base, pf_off, C, pf_len)
-        use_kernel = allow_kernel and _kernel_eligible(
-            pool.block_size, mesh, config.kv_heads, B
-        )
-        mixed = _mixed_pass(config, pool.quantized, mesh, use_kernel, n_iter)
-        emitted = None
-        if mixed:
-            # Iteration 1 of the decode scan, its forward merged into the
-            # chunk's: emit and stop-detect first (``_emit`` does not
-            # depend on the chunk), then ONE pass over the weights for the
-            # chunk's C tokens and the B decode tokens, one head product
-            # over the chunk's last hidden state and the decode rows'.
-            *emitted, active, remaining = _emit(
-                tau, tau_lp, active, remaining, stops
+            # Scalar index (ONE prefilling row): keeps the view off the
+            # per-row-index must-xla path, so "auto" runs flash over the
+            # chunk; the host-side _pf_chunk clamp guarantees
+            # write_at + C <= MB * BLK (dynamic_update_slice would otherwise
+            # clamp its start and scribble over the reused prefix KV — the
+            # _suffix_pad hazard).
+            view = dataclasses.replace(view, index=write_at)
+            toks_c = lax.dynamic_slice_in_dim(pf_toks, pf_off, C)[None]
+            positions, real = window_positions(pf_base, pf_off, C, pf_len)
+            use_kernel = allow_kernel and _kernel_eligible(
+                pool.block_size, mesh, config.kv_heads, B
             )
-            hidden, view, pcache = mixed_forward(
-                params, toks_c, positions, config, view, real,
-                tau, jnp.where(active, pos, -1),
-                _pool_as_cache(pool, table, fill),
-            )
-            pool = _cache_into_pool(pool, pcache)
-            idx = pf_len - 1 - pf_off  # as below
-            h_last = jnp.take_along_axis(
-                hidden, jnp.clip(idx, 0, C - 1)[None, None, None], axis=1
-            )
-            logits = lm_head_logits(
-                params, jnp.concatenate([h_last, hidden[:, C:]], axis=1),
-                config, normed=True,
-            )[0]
+            mixed = _mixed_pass(
+                config, pool.quantized, mesh, use_kernel, n_iter)
+            emitted = None
+            if mixed:
+                with jax.named_scope("lane.mixed"):
+                    # Iteration 1 of the decode scan, its forward merged
+                    # into the chunk's: emit and stop-detect first (``_emit``
+                    # does not depend on the chunk), then ONE pass over the
+                    # weights for the chunk's C tokens and the B decode
+                    # tokens, one head product over the chunk's last hidden
+                    # state and the decode rows'.
+                    *emitted, active, remaining = _emit(
+                        tau, tau_lp, active, remaining, stops
+                    )
+                    hidden, view, pcache = mixed_forward(
+                        params, toks_c, positions, config, view, real,
+                        tau, jnp.where(active, pos, -1),
+                        _pool_as_cache(pool, table, fill),
+                    )
+                    pool = _cache_into_pool(pool, pcache)
+                    idx = pf_len - 1 - pf_off  # as below
+                    h_last = jnp.take_along_axis(
+                        hidden, jnp.clip(idx, 0, C - 1)[None, None, None],
+                        axis=1,
+                    )
+                    logits = lm_head_logits(
+                        params,
+                        jnp.concatenate([h_last, hidden[:, C:]], axis=1),
+                        config, normed=True,
+                    )[0]
 
-            def logits_last():  # a row of the shared head product
-                return logits[:1]
+                    def logits_last():  # a row of the shared head product
+                        return logits[:1]
 
-            nxt, lp, keys = _sample_step(
-                logits[1:, None], keys, temperature, top_p, top_k,
+                    nxt, lp, keys = _sample_step(
+                        logits[1:, None], keys, temperature, top_p, top_k,
+                        all_greedy=all_greedy, with_logprobs=with_logprobs,
+                    )
+                    tau, tau_lp, fill, pos = _advance(
+                        tau, tau_lp, fill, pos, active, nxt, lp
+                    )
+            else:
+                _, view, aux = forward(
+                    params, toks_c, positions, config, cache=view,
+                    attn_mask=real, compute_logits=False,
+                    output_last_hidden=True,
+                )
+                idx = pf_len - 1 - pf_off  # in [0, C) iff the last chunk
+                h_last = jnp.take_along_axis(
+                    aux.last_hidden_state,
+                    jnp.clip(idx, 0, C - 1)[None, None, None], axis=1,
+                )[:, 0]
+
+                def logits_last():
+                    return lm_head_logits(
+                        params, h_last[:, None], config, normed=True
+                    )[:, 0]
+            pool = _land_chunk(pool, view, table_r, write_at, C)
+            if pool.conv is not None:
+                # The chunk's end state into the row's slot, and into snapshot
+                # ``pf_snap_out``; with no snapshot asked for, the slab at the
+                # (clamped) id is written back as read.
+                put = lax.dynamic_update_slice_in_dim
+                with jax.named_scope("state.move"):
+                    keep, at = pf_snap_out >= 0, jnp.maximum(pf_snap_out, 0)
+                    pool = dataclasses.replace(
+                        pool,
+                        **{n: put(getattr(pool, n), getattr(view, n), pf_row,
+                                  axis=1)
+                           for n in _STATE},
+                        **{"snap_" + n: put(
+                            snaps,
+                            jnp.where(
+                                keep, getattr(view, n),
+                                lax.dynamic_slice_in_dim(
+                                    snaps, at, 1, axis=1)),
+                            at, axis=1)
+                           for n, snaps in (("conv", pool.snap_conv),
+                                            ("ssm", pool.snap_ssm))},
+                    )
+            # The admission sample — evaluated only in the dispatch where the
+            # prompt completes, and persisted below (the split/sample topology
+            # is exactly _paged_insert's, so the row's stream is bit-identical
+            # to the classic admit-then-decode path).
+            done = pf_off + C >= pf_len
+            kc, sub = _split_rows(pf_key[None])
+            first, first_lp = _admission_sample(
+                logits_last, done, sub,
+                *(lax.dynamic_slice_in_dim(a, pf_row, 1, axis=0)
+                  for a in (temperature, top_p, top_k)),
                 all_greedy=all_greedy, with_logprobs=with_logprobs,
             )
-            tau, tau_lp, fill, pos = _advance(
-                tau, tau_lp, fill, pos, active, nxt, lp
+            fold = (jnp.arange(B, dtype=jnp.int32) == pf_row) & done
+            active = active | fold
+            tau = jnp.where(fold, first[0], tau)
+            if with_logprobs:
+                tau_lp = jnp.where(fold, first_lp[0], tau_lp)
+            fill_done = pf_base + ((pf_len + BLK - 1) // BLK) * BLK
+            fill = jnp.where(fold, fill_done, fill)
+            pos = jnp.where(fold, pf_base + pf_len, pos)
+            keys = jnp.where(fold[:, None], kc, keys)
+            pf_vec = lax.dynamic_update_slice_in_dim(
+                pf_vec, (pf_off + C)[None], _PF_OFF, axis=0
             )
-        else:
-            _, view, aux = forward(
-                params, toks_c, positions, config, cache=view,
-                attn_mask=real, compute_logits=False,
-                output_last_hidden=True,
-            )
-            idx = pf_len - 1 - pf_off  # in [0, C) iff this is the last chunk
-            h_last = jnp.take_along_axis(
-                aux.last_hidden_state,
-                jnp.clip(idx, 0, C - 1)[None, None, None], axis=1,
-            )[:, 0]
-
-            def logits_last():
-                return lm_head_logits(
-                    params, h_last[:, None], config, normed=True
-                )[:, 0]
-        pool = _land_chunk(pool, view, table_r, write_at, C)
-        if pool.conv is not None:
-            # The chunk's end state into the row's slot, and into snapshot
-            # ``pf_snap_out``; with no snapshot asked for, the slab at the
-            # (clamped) id is written back as read.
-            keep, at = pf_snap_out >= 0, jnp.maximum(pf_snap_out, 0)
-            put = lax.dynamic_update_slice_in_dim
-            pool = dataclasses.replace(
-                pool,
-                **{n: put(getattr(pool, n), getattr(view, n), pf_row, axis=1)
-                   for n in _STATE},
-                **{"snap_" + n: put(
-                    snaps,
-                    jnp.where(keep, getattr(view, n),
-                              lax.dynamic_slice_in_dim(snaps, at, 1, axis=1)),
-                    at, axis=1)
-                   for n, snaps in (("conv", pool.snap_conv),
-                                    ("ssm", pool.snap_ssm))},
-            )
-        # The admission sample — evaluated only in the dispatch where the
-        # prompt completes, and persisted below (the split/sample topology
-        # is exactly _paged_insert's, so the row's stream is bit-identical
-        # to the classic admit-then-decode path).
-        done = pf_off + C >= pf_len
-        kc, sub = _split_rows(pf_key[None])
-        first, first_lp = _admission_sample(
-            logits_last, done, sub,
-            *(lax.dynamic_slice_in_dim(a, pf_row, 1, axis=0)
-              for a in (temperature, top_p, top_k)),
-            all_greedy=all_greedy, with_logprobs=with_logprobs,
-        )
-        fold = (jnp.arange(B, dtype=jnp.int32) == pf_row) & done
-        active = active | fold
-        tau = jnp.where(fold, first[0], tau)
-        if with_logprobs:
-            tau_lp = jnp.where(fold, first_lp[0], tau_lp)
-        fill_done = pf_base + ((pf_len + BLK - 1) // BLK) * BLK
-        fill = jnp.where(fold, fill_done, fill)
-        pos = jnp.where(fold, pf_base + pf_len, pos)
-        keys = jnp.where(fold[:, None], kc, keys)
-        pf_vec = lax.dynamic_update_slice_in_dim(
-            pf_vec, (pf_off + C)[None], _PF_OFF, axis=0
-        )
         # ---- the standard decode scan: K iterations, or the K - 1 after
         # the mixed pass's ----
         out = _chunk_scan(
@@ -1421,14 +1449,16 @@ def _paged_insert(
         logits_last = lm_head_logits(
             params, h_last[:, None], config, normed=True
         )[:, 0]
-        keys, subkeys = _split_rows(keys)
-        tau = sample_rows(subkeys, logits_last, temperature, top_p, top_k)
-        tau_lp = (
-            _token_logprob(logits_last, tau) if with_logprobs else None
-        )
-        # Non-finite guard (see _sample_step): -1 sentinel rows are
-        # failed by the host at the next emit boundary.
-        tau = jnp.where(finite_rows(logits_last), tau, -1)
+        with jax.named_scope("sample"):
+            keys, subkeys = _split_rows(keys)
+            tau = sample_rows(
+                subkeys, logits_last, temperature, top_p, top_k)
+            tau_lp = (
+                _token_logprob(logits_last, tau) if with_logprobs else None
+            )
+            # Non-finite guard (see _sample_step): -1 sentinel rows are
+            # failed by the host at the next emit boundary.
+            tau = jnp.where(finite_rows(logits_last), tau, -1)
 
         nb = P // BLK
 
@@ -1442,15 +1472,16 @@ def _paged_insert(
                 mode="drop",
             )
 
-        pool = dataclasses.replace(
-            pool,
-            **_map_planes(land, pool, sub),
-            pos=pool.pos.at[block_ids].set(
-                sub.pos.reshape(k_rows, nb, BLK), mode="drop"
-            ),
-            stats=_add_stats(pool.stats, sub.stats),
-            **_state_into_rows(pool, sub, state_rows),
-        )
+        with jax.named_scope("cache.land"):
+            pool = dataclasses.replace(
+                pool,
+                **_map_planes(land, pool, sub),
+                pos=pool.pos.at[block_ids].set(
+                    sub.pos.reshape(k_rows, nb, BLK), mode="drop"
+                ),
+                stats=_add_stats(pool.stats, sub.stats),
+                **_state_into_rows(pool, sub, state_rows),
+            )
         # Serving-mesh placement: the donated pool leaves the insert
         # with the same canonical sharding it arrived with (``placed``
         # is the ctor's decision — the SAME predicate every other
@@ -1532,12 +1563,13 @@ def _paged_suffix_insert(
         pool = _scatter_back(
             pool, view, table_row, fill0, jnp.ones((B1,), bool), T
         )
-        keys, sub = _split_rows(keys)
-        tau = sample_rows(sub, logits_last, temperature, top_p, top_k)
-        lp = _token_logprob(logits_last, tau) if with_logprobs else None
-        # Non-finite guard (see _sample_step): -1 sentinel rows are
-        # failed by the host at the next emit boundary.
-        tau = jnp.where(finite_rows(logits_last), tau, -1)
+        with jax.named_scope("sample"):
+            keys, sub = _split_rows(keys)
+            tau = sample_rows(sub, logits_last, temperature, top_p, top_k)
+            lp = _token_logprob(logits_last, tau) if with_logprobs else None
+            # Non-finite guard (see _sample_step): -1 sentinel rows are
+            # failed by the host at the next emit boundary.
+            tau = jnp.where(finite_rows(logits_last), tau, -1)
         # Serving-mesh placement: see _paged_insert's epilogue.
         if placed:
             pool = smesh.constrain_pool(pool)
@@ -1602,15 +1634,16 @@ def _pack_stats(packed: jnp.ndarray, pool: BlockPool):
     start again from zero.  Every other pool: unchanged."""
     if pool.stats is None:
         return packed, pool
-    _, B, K = packed.shape
-    n = -(-pool.stats.shape[0] // (B * K))
-    planes = jnp.pad(
-        pool.stats, (0, n * B * K - pool.stats.shape[0])
-    ).reshape(n, B, K)
-    return (
-        jnp.concatenate([packed, planes], axis=0),
-        dataclasses.replace(pool, stats=jnp.zeros_like(pool.stats)),
-    )
+    with jax.named_scope("emit"):
+        _, B, K = packed.shape
+        n = -(-pool.stats.shape[0] // (B * K))
+        planes = jnp.pad(
+            pool.stats, (0, n * B * K - pool.stats.shape[0])
+        ).reshape(n, B, K)
+        return (
+            jnp.concatenate([packed, planes], axis=0),
+            dataclasses.replace(pool, stats=jnp.zeros_like(pool.stats)),
+        )
 
 
 def _spec_round_core(

@@ -166,6 +166,74 @@ def innermost(
     return out
 
 
+def lane_and_scope(tf_op: str) -> Tuple[str, str]:
+    """One device operation's (lane, leaf scope) from its scope path — the
+    ``tf_op`` stat of its event metadata,
+    ``jit(_fused_chunk)/lane.decode/while/body/moe.experts/...`` — by the
+    closed set ``obs.DEVICE_SCOPES``: the LAST lane of the path
+    (``lane.mixed`` opens inside ``lane.chunk``) without its prefix, else
+    the program's name (an insert program's module name is its lane),
+    else ``none``; and the INNERMOST leaf scope (``admit.sample/.../head``
+    is ``head``), else ``unscoped``."""
+    from ..obs import DEVICE_LANES, DEVICE_SCOPES
+
+    parts = [p.rstrip(":") for p in tf_op.split("/")]
+    lane = next(
+        (p[len("lane."):] for p in reversed(parts) if p in DEVICE_LANES),
+        None,
+    )
+    if lane is None and parts[0].startswith("jit(") and parts[0][-1] == ")":
+        lane = parts[0][len("jit("):-1]
+    scope = next(
+        (p for p in reversed(parts)
+         if p in DEVICE_SCOPES and p not in DEVICE_LANES),
+        "unscoped",
+    )
+    return lane or "none", scope
+
+
+def busy_by_lane_and_scope(path: str):
+    """({lane: ms}, {leaf scope: ms}) of the first device's "XLA Ops" in
+    the capture ``path``, by SELF time (an operation's duration less the
+    operations nested in it: a ``while`` counts nothing of its body), so
+    each adds up to the device's busy time.  The scope path is not in
+    what ``jax.profiler.ProfileData`` shows: this reads the file with the
+    profiler's protos, and returns None where they do not import."""
+    try:
+        from tensorflow.tsl.profiler.protobuf import xplane_pb2
+    except ImportError:
+        return None
+    space = xplane_pb2.XSpace()
+    with open(path, "rb") as f:
+        space.ParseFromString(f.read())
+    for plane in sorted(space.planes, key=lambda p: p.name):
+        if not any(t in plane.name for t in ("TPU", "GPU")):
+            continue
+        line = next((ln for ln in plane.lines if ln.name == "XLA Ops"), None)
+        if line is None:
+            continue
+        stat = {k: v.name for k, v in plane.stat_metadata.items()}
+        label = {}
+        for k, md in plane.event_metadata.items():
+            tf_op = next(
+                (st.str_value for st in md.stats
+                 if stat.get(st.metadata_id) == "tf_op"), "",
+            )
+            label[k] = "\x00".join(lane_and_scope(tf_op))
+        lanes: Dict[str, float] = collections.defaultdict(float)
+        scopes: Dict[str, float] = collections.defaultdict(float)
+        # offsets in picoseconds: exact, whatever the line's own start
+        for name, s, e in innermost([
+            (label.get(ev.metadata_id, "none\x00unscoped"), ev.offset_ps,
+             ev.offset_ps + ev.duration_ps) for ev in line.events
+        ]):
+            lane, scope = name.split("\x00")
+            lanes[lane] += (e - s) / 1e9
+            scopes[scope] += (e - s) / 1e9
+        return dict(lanes), dict(scopes)
+    return None
+
+
 def summarize_xplane(log_dir: str) -> Dict[str, object]:
     """Aggregate the newest xplane capture under ``log_dir``, read with
     ``jax.profiler.ProfileData`` alone.
@@ -183,8 +251,13 @@ def summarize_xplane(log_dir: str) -> Dict[str, object]:
     idle time to host work comes from the running server with no clock
     join — and ``idle_by_span_ms``, the same gaps with each part given
     to the innermost ``llm.span.<name>`` event over it (by span name),
-    else to its phase, else ``in dispatch``, else ``unnamed``.  Raises FileNotFoundError when ``log_dir`` holds no capture —
-    the /debug/profile/summary endpoint maps it to a clean 404.
+    else to its phase, else ``in dispatch``, else ``unnamed``.  Where the
+    profiler's protos import, also ``busy_by_lane_ms`` and
+    ``busy_by_scope_ms``: the first device's busy time by the lane and by
+    the leaf scope of its operations (:func:`busy_by_lane_and_scope`);
+    both are left out where they do not.  Raises FileNotFoundError when
+    ``log_dir`` holds no capture — the /debug/profile/summary endpoint
+    maps it to a clean 404.
     """
     from jax.profiler import ProfileData
 
@@ -236,7 +309,13 @@ def summarize_xplane(log_dir: str) -> Dict[str, object]:
                     sink[prog] += e.duration_ns / 1e6
     busy_ns, gaps = busy_and_gaps(ops)
     programs = sorted(set(device_ms) | set(host_ms))
+    by = busy_by_lane_and_scope(path) if ops else None
+    named = {} if by is None else {
+        key: {k: round(v, 3) for k, v in sorted(d.items())}
+        for key, d in zip(("busy_by_lane_ms", "busy_by_scope_ms"), by)
+    }
     return {
+        **named,
         "xplane": path,
         "programs": {
             p: {
