@@ -31,10 +31,12 @@ head, the row zero-padded to the 128 lanes (`config.cache_width`); there is
 no `v` plane.  Two forms of the same attention:
 
 - decompressed (prompt chunks): k_nope and v of every attendable slot are
-  rebuilt from the cached latent, then ordinary multi-head attention — the
-  flash kernel with v zero-padded to the q/k width, or plain XLA.  Behind a
-  cache with a scalar index the flash form rebuilds the live context only,
-  a tile at a time for a trip count that is a value (`attend_tiled`);
+  rebuilt from the cached latent, then ordinary multi-head attention — a
+  head's K/V a tile at a time in vector memory, inside the flash kernel
+  that consumes the latent rows (`ops.flash_attention.latent_flash_attention`),
+  or whole in plain XLA.  Behind a cache with a scalar index the flash form
+  walks the live context only, tile by tile from the cache where it lies,
+  for a trip count that is a value (`attend_tiled`);
 - absorbed (decode): W_kvb's key half is folded into the query and its
   value half applied after the sum, so the 32 heads attend the latent rows
   themselves as one shared key/value head (`paged_decode_attention` with
@@ -85,9 +87,7 @@ from jax import lax
 from ..config import LLaMAConfig
 from ..ops import mhc, moe
 from ..ops.attention import attention_bias, sdpa
-from ..ops.flash_attention import (
-    flash_attention, flash_attention_lse, merge_attention,
-)
+from ..ops.flash_attention import latent_flash_attention
 from ..ops.norm import rms_norm
 from ..ops.rope import apply_rope, rope_table, yarn_inv_freq, yarn_mscale
 
@@ -225,11 +225,10 @@ def _decompress(latent, kv_b, config: LLaMAConfig):
     return jnp.concatenate([kv[..., :dn], k_rope], axis=-1), kv[..., dn:]
 
 
-def _query(q_nope, q_rope, config: LLaMAConfig):
-    """The full-width query of the decompressed forms, whose kernels divide by
+def _fold_temperature(q, config: LLaMAConfig):
+    """A query part for the decompressed forms, whose kernels divide by
     sqrt(nope + rope) themselves: what `softmax_scale` has beyond that (YaRN's
     temperature) is folded into the query."""
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
     if config.rope_yarn is None:
         return q
     gain = softmax_scale(config) * math.sqrt(config.qk_head_dim)
@@ -239,27 +238,29 @@ def _query(q_nope, q_rope, config: LLaMAConfig):
 def attend_decompressed(q_nope, q_rope, latent, kv_b, q_pos, kv_pos, bias,
                         config: LLaMAConfig, use_flash: bool):
     """Multi-head attention over K/V rebuilt from latent rows [B,S,w];
-    [B,T,H,dv].  `bias` is used by the XLA path, the positions by flash."""
-    dv = config.v_head_dim
-    q = _query(q_nope, q_rope, config)
+    [B,T,H,dv].  `bias` is used by the XLA path, the positions by flash,
+    which rebuilds a head's K/V a tile at a time inside its kernel."""
+    q_nope, q_rope = (_fold_temperature(q, config) for q in (q_nope, q_rope))
+    if use_flash:
+        return latent_flash_attention(
+            q_nope, q_rope, latent, kv_b.astype(latent.dtype), q_pos, kv_pos)
     k, v = _decompress(latent, kv_b, config)
-    if not use_flash:
-        return sdpa(q, k, _pad_last(v, q.shape[-1]), bias,
-                    softmax_dtype=jnp.dtype(config.attn_softmax_dtype))[..., :dv]
-    # One width for q, k and v: the value zero-padded to the q/k width,
-    # which is also the width the published scale divides by.
-    out = flash_attention(q, k, _pad_last(v, q.shape[-1]), q_pos, kv_pos)
-    return out[..., :dv]
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    # sdpa takes one width for q, k and v: the value zero-padded to the q/k
+    # width, which is also the width the published scale divides by.
+    return sdpa(q, k, _pad_last(v, q.shape[-1]), bias,
+                softmax_dtype=jnp.dtype(config.attn_softmax_dtype)
+                )[..., :config.v_head_dim]
 
 
-# The context walk's tile: the flash kernel's key block.
+# The context walk's tile: the latent kernel's key block.
 CTX_TILE = 2048
 
 
 def ctx_tiles(index, view: int):
     """(tile, trips) of the walk over a cached context: fixed tiles of
     `CTX_TILE` slots (the whole view where that is narrower), as many as
-    hold a slot below `index`.  One rule for the device's loop (`index`
+    hold a slot below `index`.  One rule for the device's kernel (`index`
     traced) and the host's counters (`index` an int)."""
     tile = min(CTX_TILE, view)
     return tile, (index + tile - 1) // tile
@@ -268,39 +269,19 @@ def ctx_tiles(index, view: int):
 def attend_tiled(q_nope, q_rope, latent, kv_b, q_pos, new_pos, cache, layer,
                  config: LLaMAConfig):
     """`attend_decompressed`'s flash form for a chunk [B,T] behind a cache
-    with a scalar index, doing work for the live context only: the chunk
-    attends itself, then the cached rows below `cache.index` a tile at a
-    time — each tile sliced from the cache, decompressed, attended by the
-    flash kernel and merged by its row log-sum-exp (float32) — for
-    `ctx_tiles` trips, a value.  Nothing of the view's width is rebuilt;
-    the dead slots of the last tile are masked by their position, -1."""
-    dv, adt = config.v_head_dim, latent.dtype
-    q = _query(q_nope, q_rope, config)
-
-    def part(rows, kv_pos):
-        k, v = _decompress(rows, kv_b, config)
-        out, lse = flash_attention_lse(
-            q, k, _pad_last(v, q.shape[-1]), q_pos, kv_pos)
-        return out[..., :dv], lse
-
-    B, view, w = q.shape[0], cache.max_len, cache.k.shape[-1]
-    tile, trips = ctx_tiles(cache.index, view)
-
-    def trip(t, acc):
-        # A view that is no multiple of the tile: the last tile is moved
-        # back inside it, and the slots it then shares with the tile before
-        # are masked.
-        start = jnp.minimum(t * tile, view - tile)
-        rows = lax.dynamic_slice(
-            cache.k, (layer, 0, start, 0, 0), (1, B, tile, 1, w))[0, :, :, 0]
-        pos = lax.dynamic_slice(cache.pos, (0, start), (B, tile))
-        own = start + jnp.arange(tile, dtype=jnp.int32) >= t * tile
-        return merge_attention(
-            *acc, *part(rows.astype(adt), jnp.where(own[None], pos, -1)))
-
-    out, lse = part(latent, new_pos)
-    out, _ = lax.fori_loop(0, trips, trip, (out.astype(jnp.float32), lse))
-    return out.astype(adt)
+    with a scalar index, doing work for the live context only, in one
+    kernel: the cached rows below `cache.index` are read from the cache a
+    tile at a time for `ctx_tiles` trips, a value, then the chunk's own
+    rows, under one running softmax.  Nothing of the view's width is
+    rebuilt; the dead slots of the last tile are masked by their position,
+    -1."""
+    tile, trips = ctx_tiles(cache.index, cache.max_len)
+    planes, B, view = cache.k.shape[:3]
+    return latent_flash_attention(
+        _fold_temperature(q_nope, config), _fold_temperature(q_rope, config),
+        latent, kv_b.astype(latent.dtype), q_pos, new_pos,
+        ctx=cache.k.reshape(planes, B, view, -1), ctx_pos=cache.pos,
+        layer=layer, ctx_tiles=trips, ctx_tile=tile)
 
 
 def routed_ffn(h, lp, experts, layer, valid, config: LLaMAConfig):

@@ -37,12 +37,17 @@ path before reaching this kernel) and the view-capacity clamp on the
 write window (``serving.ContinuousBatcher._pf_chunk``).  The serving
 fault drills exercise this path through the same ``_maybe_fault``
 trace hook as ordinary prefill.
+
+The latent-attention block's prompt chunks have a kernel of their own,
+``latent_flash_attention``: the same online softmax over LATENT rows, a
+head's K/V rebuilt in vector memory a key tile at a time, walking a cache's
+live tiles in place and then the chunk's own rows (models/mla_moe.py).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -139,6 +144,30 @@ def _dropout_keep(seed_lo, seed_hi, b, h, row0, col0, bq, bk, rate):
     return bits >= threshold
 
 
+def _tri_ok(bq_s, bk_s, quantized=False) -> bool:
+    """`_tri_gate`'s static half: the tile shapes the ragged bodies take."""
+    return (
+        not quantized
+        and _KSUB >= 2  # the safety fold is vacuous at 1 sub-tile
+        and bk_s % _KSUB == 0 and bk_s > _KSUB
+        and bq_s % _KSUB == 0 and bq_s > _KSUB
+        and (bq_s // _KSUB) % _SUBLANES == 0
+        and (bk_s // _KSUB) % _SUBLANES == 0
+    )
+
+
+def _tri_safe(q_pos_p, kv_pos_p, bq_s, bk_s):
+    """`_tri_gate`'s dynamic half for every (q block, kv block) at once, from
+    the padded position planes [B, Tp] / [B, Sp] (dead kv slots at +INT_MAX):
+    [B, nq, nk] bool, for a kernel that gates on scalars."""
+    B = q_pos_p.shape[0]
+    q_sub = q_pos_p.reshape(B, -1, _KSUB, bq_s // _KSUB).max(axis=3)
+    k_sub = kv_pos_p.reshape(B, -1, _KSUB, bk_s // _KSUB).min(axis=3)
+    q_upto = jax.lax.cummax(q_sub, axis=2)  # max(qp[:(i + 1) * rq])
+    return jnp.all(
+        q_upto[:, :, None, :-1] < k_sub[:, None, :, 1:], axis=3)
+
+
 def _tri_gate(qp, kp, bq_s, bk_s, quantized=False):
     """Shared gate for the three kernels' ragged diagonal bodies:
     ``(tri_ok, safe)`` where ``tri_ok`` is the STATIC shape check (sub-
@@ -155,15 +184,7 @@ def _tri_gate(qp, kp, bq_s, bk_s, quantized=False):
     (+INT_MAX padding slots never lower a block min, so padding can
     never unsoundly enable a skip.)
     """
-    tri_ok = (
-        not quantized
-        and _KSUB >= 2  # the safety fold is vacuous at 1 sub-tile
-        and bk_s % _KSUB == 0 and bk_s > _KSUB
-        and bq_s % _KSUB == 0 and bq_s > _KSUB
-        and (bq_s // _KSUB) % _SUBLANES == 0
-        and (bk_s // _KSUB) % _SUBLANES == 0
-    )
-    if not tri_ok:
+    if not _tri_ok(bq_s, bk_s, quantized):
         return False, None
     rq = bq_s // _KSUB
     ksub = bk_s // _KSUB
@@ -678,53 +699,389 @@ def flash_attention(
     )
 
 
+def _sub_tiles(bk: int):
+    """(count, width) of a key tile's sub-tiles: `_flash_kernel`'s rule."""
+    nsub = _KSUB if (bk % _KSUB == 0 and bk > _KSUB) else 1
+    return nsub, bk // nsub
+
+
+def _latent_rebuild(rows_ref, kvb_ref, k_ref, kr_ref, v_ref, n_valid=None):
+    """K and V of this head for one key tile, rebuilt in vector memory from
+    the tile's latent rows [bk, w] (c | rope key | lane padding) into the
+    scratch the softmax bodies read: ``c @ kv_b[h]`` on the MXU a sub-tile
+    at a time, rounded to the activation dtype, split into the nope key and
+    the value; the rows' rope columns beside them.  ``n_valid`` (a value):
+    rows of the tile at or past it lie outside the array, and their values
+    are zeroed (their scores are masked by position)."""
+    bk, r = rows_ref.shape[0], kvb_ref.shape[0]
+    dn, dr = k_ref.shape[1], kr_ref.shape[1]
+    nsub, ksub = _sub_tiles(bk)
+    for i in range(nsub):
+        cols = slice(i * ksub, (i + 1) * ksub)
+        kv = jax.lax.dot_general(
+            rows_ref[cols, :r].astype(k_ref.dtype), kvb_ref[...],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        ).astype(k_ref.dtype)  # [ksub, nope | dv]
+        v_i = kv[:, dn:]
+        if n_valid is not None:
+            inside = i * ksub + jax.lax.broadcasted_iota(
+                jnp.int32, (ksub, 1), 0) < n_valid
+            v_i = jnp.where(inside, v_i, jnp.zeros_like(v_i))
+        k_ref[cols, :] = kv[:, :dn]
+        v_ref[cols, :] = v_i
+        kr_ref[cols, :] = rows_ref[cols, r:r + dr].astype(kr_ref.dtype)
+
+
+def _latent_tile_update(
+    qn_ref, qr_ref, k_ref, kr_ref, v_ref, m_ref, l_ref, acc_ref, qp, kp,
+    *, scale, ragged,
+):
+    """The online-softmax update of one key tile whose K/V stand rebuilt in
+    scratch (rows [:bk], bk = kp's width).  The score is two products, nope
+    against the rebuilt key and rope against the tile's one rope key, and
+    the value keeps its own width.  ``_flash_kernel``'s two bodies in one:
+    uniform (every query row against every sub-tile, one joint row max) or,
+    with ``ragged``, ``_flash_tri_tile_update``'s shrinking dots on a
+    triangle-safe diagonal tile (sub-tile i computes query rows [i*rq:]
+    only)."""
+    qn, qr = qn_ref[...], qr_ref[...]
+    bq, bk = qn.shape[0], kp.shape[1]
+    nsub, ksub = _sub_tiles(bk)
+    rq = bq // nsub if ragged else 0  # sub-tile i starts at query row i*rq
+    nt = (((1,), (1,)), ((), ()))
+    allowed = kp <= qp  # [bq, bk]
+
+    s_parts, m_parts = [], []
+    for i in range(nsub):
+        cols = slice(i * ksub, (i + 1) * ksub)
+        s_i = jax.lax.dot_general(
+            qn[i * rq:], k_ref[cols, :], nt,
+            preferred_element_type=jnp.float32,
+        ) + jax.lax.dot_general(
+            qr[i * rq:], kr_ref[cols, :], nt,
+            preferred_element_type=jnp.float32,
+        )  # [bq - i*rq, ksub]
+        s_i = jnp.where(allowed[i * rq:, cols], s_i * scale, MASK_VALUE)
+        s_parts.append(s_i)
+        m_parts.append(s_i.max(axis=-1, keepdims=True))
+
+    # Row blocks (lo, hi, the sub-tiles that touch them): one block under
+    # every sub-tile, or block j under the sub-tiles i <= j.
+    if ragged:
+        blocks = [(j * rq, (j + 1) * rq, range(j + 1)) for j in range(nsub)]
+    else:
+        blocks = [(0, bq, range(nsub))]
+    m_prev = m_ref[:, :1]
+    m_blocks = []
+    for lo, hi, parts in blocks:
+        mj = m_prev[lo:hi]
+        for i in parts:
+            mj = jnp.maximum(mj, m_parts[i][lo - i * rq:hi - i * rq])
+        m_blocks.append(mj)
+
+    r_parts, d_parts = [], []  # row sums, fp32 PV partials of rows [i*rq:]
+    for i in range(nsub):
+        cols = slice(i * ksub, (i + 1) * ksub)
+        m_rows = jnp.concatenate(m_blocks[i:], axis=0) if ragged else m_blocks[0]
+        p = jnp.exp2(s_parts[i] - m_rows)
+        r_i = jnp.sum(p, axis=-1, keepdims=True)
+        d_i = jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[cols, :], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if ragged or i == 0:
+            r_parts.append(r_i)
+            d_parts.append(d_i)
+        else:
+            # One row block: summed as they come (nsub [bq, dv] fp32
+            # partials held to the end cost `_flash_kernel` its schedule).
+            r_parts[0], d_parts[0] = r_parts[0] + r_i, d_parts[0] + d_i
+    if not ragged:
+        blocks = [(0, bq, range(1))]
+
+    for (lo, hi, parts), mj in zip(blocks, m_blocks):
+        alpha = jnp.exp2(m_prev[lo:hi] - mj)
+        l_j = alpha * l_ref[lo:hi, :1]
+        acc_j = alpha * acc_ref[lo:hi]
+        for i in parts:
+            sub = slice(lo - i * rq, hi - i * rq)
+            l_j = l_j + r_parts[i][sub]
+            acc_j = acc_j + d_parts[i][sub]
+        acc_ref[lo:hi] = acc_j
+        m_ref[lo:hi] = jnp.broadcast_to(mj, (hi - lo, m_ref.shape[1]))
+        l_ref[lo:hi] = jnp.broadcast_to(l_j, (hi - lo, l_ref.shape[1]))
+
+
+def _latent_kernel(
+    sched_ref,  # [2] int32: the cache's layer, its live context tiles
+    bound_ref,  # [B * nq] int32: new-row key blocks of a q block's sweep
+    qmax_ref,   # [B * nq] int32: a q block's largest position
+    kmin_ref,   # [B * (n_ctx + nk)] int32: a key block's smallest position
+    safe_ref,   # [B * nq * nk] int32: `_tri_gate`'s fold, (q block, new block)
+    *args,  # q_pos, kv_pos, q_nope, q_rope, kv_b, rows, [ctx_pos, ctx] refs;
+    #         o_ref; then k/kr/v and m/l/acc scratch
+    scale: float,
+    n_ctx: int,
+    ctx_rows: int,
+    tri_ok: bool,
+):
+    """Grid (B, H, q blocks, n_ctx + key blocks), the key axis innermost:
+    steps below ``n_ctx`` walk the cached context's tiles, live while below
+    ``sched_ref[1]``; the rest walk the new rows' blocks, live while below
+    the q block's causal bound.  A live step rebuilds its tile's K/V into
+    scratch, from the cache's tile or from the new rows', and runs ONE
+    softmax body on the scratch whichever it came from: the diagonal's
+    ragged body or the uniform one (with a third whole-tile body, one a
+    source, the diagonal's ran 11 times slower on a v5e: PERF.md section 6,
+    PR 54).  Dead steps clamp their index maps (no DMA) and skip everything
+    on scalars alone; m, l and the accumulator stay in scratch from the
+    first step to the last, which normalises and writes once."""
+    q_pos_ref, kv_pos_ref, qn_ref, qr_ref, kvb_ref, rows_ref, *args = args
+    if n_ctx:
+        ctx_pos_ref, ctx_ref, *args = args
+    o_ref, k_ref, kr_ref, v_ref, m_ref, l_ref, acc_ref = args
+    bi, ki = pl.program_id(0), pl.program_id(3)
+    nk = pl.num_programs(3) - n_ctx
+    row_block = bi * pl.num_programs(2) + pl.program_id(2)
+    qmax = qmax_ref[row_block]
+
+    @pl.when(ki == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, MASK_VALUE)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    qp = q_pos_ref[:, :1]  # [bq, 1]
+    kp = kv_pos_ref[:1, :]  # [1, bk]
+    bk = kp.shape[1]
+    rebuild = functools.partial(
+        _latent_rebuild, kvb_ref=kvb_ref, k_ref=k_ref, kr_ref=kr_ref,
+        v_ref=v_ref)
+    update = functools.partial(
+        _latent_tile_update, qn_ref, qr_ref, k_ref, kr_ref, v_ref,
+        m_ref, l_ref, acc_ref, qp, scale=scale)
+
+    # A block none of whose slots a query may attend (all past the q block's
+    # last position, or all dead: +INT_MAX) is skipped like one out of bound.
+    si = jnp.clip(ki - n_ctx, 0, nk - 1)
+    new_live = (ki >= n_ctx) & (si < bound_ref[row_block]) & (
+        kmin_ref[bi * (n_ctx + nk) + n_ctx + si] <= qmax)
+
+    @pl.when(new_live)
+    def _rebuild_new():
+        rebuild(rows_ref)
+
+    if tri_ok:
+        safe = safe_ref[row_block * nk + si] != 0
+
+        @pl.when(new_live & safe)
+        def _diagonal():
+            update(kp, ragged=True)
+
+        new_live = new_live & jnp.logical_not(safe)
+
+    if n_ctx:
+        cp = ctx_pos_ref[:1, :]  # [1, tile]
+        tile = cp.shape[1]
+        ctx_live = (ki < sched_ref[1]) & (
+            kmin_ref[bi * (n_ctx + nk) + jnp.minimum(ki, n_ctx - 1)] <= qmax)
+        # A view that is no multiple of the tile: the last tile's rows past
+        # the view are whatever the buffer held.
+        n_valid = ctx_rows - ki * tile if ctx_rows % tile else None
+
+        @pl.when(ctx_live)
+        def _rebuild_context():
+            rebuild(ctx_ref, n_valid=n_valid)
+
+        if tile == bk:
+            kp = jnp.where(ki < n_ctx, cp, kp)
+            new_live = new_live | ctx_live
+        else:
+            @pl.when(ctx_live)
+            def _context():
+                update(cp, ragged=False)
+
+    @pl.when(new_live)
+    def _uniform():
+        update(kp, ragged=False)
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _finalize():
+        l = l_ref[:, :1]
+        # l == 0: the row never saw a live slot; 0, not 0/0.
+        o_ref[...] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
 @functools.partial(
-    jax.jit, static_argnames=("block_q", "block_k", "interpret")
+    jax.jit, static_argnames=("block", "ctx_tile", "interpret")
 )
-def flash_attention_lse(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
+def latent_flash_attention(
+    q_nope: jnp.ndarray,
+    q_rope: jnp.ndarray,
+    rows: jnp.ndarray,
+    kv_b: jnp.ndarray,
     q_pos: jnp.ndarray,
     kv_pos: jnp.ndarray,
-    block_q: int = 2048,
-    block_k: int = 2048,
+    ctx: Optional[jnp.ndarray] = None,
+    ctx_pos: Optional[jnp.ndarray] = None,
+    layer=0,
+    ctx_tiles=0,
+    block: int = 2048,
+    ctx_tile: int = 2048,
     interpret: Optional[bool] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """``flash_attention``'s forward over ONE part of a row's keys, with
-    what merging the parts needs: ([B, T, H, d] in q.dtype, the row
-    log-sum-exp [B, T, H] fp32 of the scaled, masked scores — the one the
-    backward kernels already get).  Inference-only (no VJP, no dropout) and
-    one KV head per query head; ``merge_attention`` joins two parts.  A row
-    that attends nothing in this part reads ~``MASK_VALUE`` (finite), so
-    it weighs nothing against a part it does attend.
+) -> jnp.ndarray:
+    """Prefill attention of the latent-attention block (models/mla_moe.py)
+    over LATENT rows, in one kernel: per head, K and V of a key tile are
+    rebuilt in vector memory (``c @ kv_b[h]``, never in HBM), the score is
+    ``q_nope . k_nope + q_rope . k_rope`` with the rows' one rope key under
+    every head, the value keeps its own width, and the running softmax is
+    carried in scratch over the cached context's live tiles and then the
+    new rows.  Inference only.  The scores are divided by sqrt(nope + rope);
+    any further temperature is the caller's, folded into the query.
+
+    Args:
+      q_nope [B, T, H, nope], q_rope [B, T, H, rope] (rotated): the queries.
+      rows: [B, S, w] latent rows of the new keys: ``c`` in columns [:r],
+        the rotated rope key in [r:r + rope], lane padding behind.
+      kv_b: [H, r, nope + dv] in the activation dtype.
+      q_pos [B, T], kv_pos [B, S]: positions, -1 a dead key (``flash_attention``).
+      ctx: [L, B, view, w] a cache's latent plane, read in place a tile at
+        a time; ``ctx_pos`` [B, view] its positions; ``layer`` and
+        ``ctx_tiles`` int32 VALUES: the plane's layer, and how many tiles of
+        ``ctx_tile`` slots from slot 0 are walked (dead slots inside them
+        are masked by their position; a tile at or past the count costs
+        neither a copy nor compute).  None: the new rows alone.
+      block: query rows and new-key rows a grid step.
+    Returns:
+      [B, T, H, dv] in q_nope.dtype.
     """
     _maybe_fault()
-    if q.shape[2] != k.shape[2]:
-        raise ValueError(
-            f"flash_attention_lse takes one KV head per query head, got "
-            f"{q.shape[2]} and {k.shape[2]}"
-        )
-    out, lse = _flash_forward(
-        q, k, v, q_pos, kv_pos, block_q, block_k, interpret, need_lse=True
-    )
-    return out, jnp.swapaxes(lse[:, :, : q.shape[1], 0], 1, 2)
+    B, T, H, dn = q_nope.shape
+    S, w = rows.shape[1:]
+    dr, dv = q_rope.shape[-1], kv_b.shape[-1] - dn
+    interpret = _resolve_interpret(interpret)
+    scale = (1.0 / ((dn + dr) ** 0.5)) * float(np.log2(np.e))
+    block_q, block_k = _clamp_blocks(T, S, block, block, interpret)
+    imax = jnp.iinfo(jnp.int32).max
 
+    def dead_to_imax(pos, mult):
+        pos = _pad_to(pos.astype(jnp.int32), 1, mult, value=-1)
+        return jnp.where(pos < 0, imax, pos)
 
-def merge_attention(out_a, lse_a, out_b, lse_b):
-    """Softmax attention over two disjoint key sets from each set's own
-    (out [..., d], lse [...]): exact in real arithmetic, computed in fp32.
-    Returns the merged (out fp32, lse).  The weights are normalized by
-    their own sum, so two parts a row attends nothing in (both lse
-    ~``MASK_VALUE``, where adding log 2 rounds away) average, not add."""
-    m = jnp.maximum(lse_a, lse_b)
-    w_a, w_b = jnp.exp(lse_a - m), jnp.exp(lse_b - m)
-    den = w_a + w_b
-    out = (
-        (w_a / den)[..., None] * out_a.astype(jnp.float32)
-        + (w_b / den)[..., None] * out_b.astype(jnp.float32)
+    # The new rows are padded to whole blocks here (a few rows of [.., w]);
+    # the cache is not: it is read where it lies.
+    qn = _pad_to(jnp.swapaxes(q_nope, 1, 2), 2, block_q)  # [B, H, Tp, nope]
+    qr = _pad_to(jnp.swapaxes(q_rope, 1, 2), 2, block_q)
+    rows_p = _pad_to(rows, 1, block_k)
+    q_pos_p = _pad_to(q_pos.astype(jnp.int32), 1, block_q)
+    kv_pos_p = dead_to_imax(kv_pos, block_k)
+    Tp, Sp = qn.shape[2], rows_p.shape[1]
+    nq, nk = Tp // block_q, Sp // block_k
+
+    # What the grid's dead steps are skipped on, as scalars: the live
+    # context tiles, a q block's causal bound over the new rows' blocks and
+    # its largest position, a key block's smallest, and `_tri_gate`'s fold.
+    qmax = jnp.max(q_pos_p.reshape(B, nq, block_q), axis=2)
+    kmin_new = jnp.min(kv_pos_p.reshape(B, nk, block_k), axis=2)
+    if ctx is None:
+        n_ctx = view = 0
+        kmin = kmin_new
+    else:
+        view = ctx.shape[2]
+        ctx_tile = min(ctx_tile, view)
+        n_ctx = -(-view // ctx_tile)
+        ctx_pos_p = dead_to_imax(ctx_pos, ctx_tile)
+        kmin = jnp.concatenate([
+            jnp.min(ctx_pos_p.reshape(B, n_ctx, ctx_tile), axis=2), kmin_new,
+        ], axis=1)  # [B, n_ctx + nk]
+    sched = jnp.stack([
+        jnp.asarray(layer, jnp.int32),
+        jnp.minimum(jnp.asarray(ctx_tiles, jnp.int32), n_ctx),
+    ])
+    bound = 1 + jnp.max(
+        jnp.where(
+            kmin_new[:, None, :] <= qmax[:, :, None],
+            jnp.arange(nk, dtype=jnp.int32)[None, None, :], -1,
+        ),
+        axis=2,
     )
-    return out, m + jnp.log(den)
+    tri_ok = _tri_ok(block_q, block_k)
+    safe = (
+        _tri_safe(q_pos_p, kv_pos_p, block_q, block_k) if tri_ok
+        else jnp.zeros((1,), jnp.int32)
+    )
+    prefetch = [sched, bound.reshape(-1), qmax.reshape(-1), kmin.reshape(-1),
+                safe.reshape(-1).astype(jnp.int32)]
+
+    def ctx_block(ki, sched):
+        return jnp.minimum(ki, jnp.maximum(sched[1] - 1, 0))
+
+    def new_block(b, qi, ki, bound):
+        return jnp.clip(ki - n_ctx, 0, jnp.maximum(bound[b * nq + qi] - 1, 0))
+
+    def q_row(b, h, qi, ki, *_):
+        return (b, h, qi, 0)
+
+    in_specs = [
+        pl.BlockSpec((None, block_q, 1), lambda b, h, qi, ki, *_: (b, qi, 0)),
+        pl.BlockSpec(
+            (None, 1, block_k),
+            lambda b, h, qi, ki, sched, bound, *_: (
+                b, 0, new_block(b, qi, ki, bound))),
+        pl.BlockSpec((None, None, block_q, dn), q_row),
+        pl.BlockSpec((None, None, block_q, dr), q_row),
+        pl.BlockSpec(
+            (None,) + kv_b.shape[1:], lambda b, h, qi, ki, *_: (h, 0, 0)),
+        pl.BlockSpec(
+            (None, block_k, w),
+            lambda b, h, qi, ki, sched, bound, *_: (
+                b, new_block(b, qi, ki, bound), 0)),
+    ]
+    operands = [q_pos_p[:, :, None], kv_pos_p[:, None, :], qn, qr, kv_b, rows_p]
+    if n_ctx:
+        in_specs += [
+            pl.BlockSpec(
+                (None, 1, ctx_tile),
+                lambda b, h, qi, ki, sched, *_: (b, 0, ctx_block(ki, sched))),
+            pl.BlockSpec(
+                (None, None, ctx_tile, w),
+                lambda b, h, qi, ki, sched, *_: (
+                    sched[0], b, ctx_block(ki, sched), 0)),
+        ]
+        operands += [ctx_pos_p[:, None, :], ctx]
+
+    adt = q_nope.dtype
+    key_rows = max(block_k, ctx_tile if n_ctx else 0)
+    out = pl.pallas_call(
+        functools.partial(
+            _latent_kernel, scale=scale, n_ctx=n_ctx, ctx_rows=view,
+            tri_ok=tri_ok),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(B, H, nq, n_ctx + nk),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, None, block_q, dv), q_row),
+            scratch_shapes=[
+                pltpu.VMEM((key_rows, dn), adt),
+                pltpu.VMEM((key_rows, dr), adt),
+                pltpu.VMEM((key_rows, dv), adt),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, dv), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, H, Tp, dv), adt),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary"),
+            # as `_flash_forward`'s, plus the two latent tiles' buffers
+            vmem_limit_bytes=96 * 1024 * 1024,
+        ),
+        interpret=interpret,
+        name="latent_flash_attention",
+    )(*prefetch, *operands)
+    return jnp.swapaxes(out[:, :, :T, :], 1, 2)  # [B, T, H, dv]
 
 
 @functools.partial(

@@ -161,25 +161,37 @@ def test_flash_fused_chunk_compiles_for_v5e(sds, view):
 
 
 @pytest.mark.parametrize(
-    "queries,keys", [(2048, 2048), (16384, 16384)],
-    ids=["chunk-over-tile", "whole-prompt-insert"],
+    "queries,keys,view",
+    [(2048, 2048, 16384), (16384, 16384, 16384), (2048, 16384 + 2048, 0)],
+    ids=["chunk-over-8-tile-view", "whole-prompt-insert", "suffix-over-gathered-view"],
 )
-def test_flash_lse_latent_width_compiles_for_v5e(sds, queries, keys):
-    """`flash_attention_lse` at kanana-2-30b-a3b's decompressed width (128
-    nope + 64 rope = 192, values padded to it, one KV head per query head)
-    in the two forms `mla_moe.attend_tiled` gives it in `kanana2-docqa-long`:
-    a 2,048-token chunk over one 2,048-slot tile (`_fused_chunk`), and a
-    whole prompt of the 16,384 bucket over itself (`_paged_insert`, the
-    window's first request)."""
-    from jax_llama_tpu.ops.flash_attention import flash_attention_lse
+def test_latent_flash_compiles_for_v5e(sds, queries, keys, view):
+    """`latent_flash_attention` at kanana-2-30b-a3b's widths (32 heads,
+    r 512, 128 nope + 64 rope, values 128, cache row 640) in the forms
+    `models/mla_moe.py` gives it in `kanana2-docqa-long`: a 2,048-token
+    chunk over a 16,384-slot view of 8 tiles with its live-tile count and
+    its layer traced values (`attend_tiled` in `_fused_chunk`); a whole
+    prompt of the 16,384 bucket behind an empty cache of its own length
+    (`_paged_insert`, the window's first request); and, with no cache
+    operand, a 2,048-token suffix over a row's whole gathered view and
+    itself as new rows (`attend_decompressed` in `_paged_suffix_insert`).
+    K and V of a tile are rebuilt inside the kernel: its vector memory is
+    what this compile holds to the limit."""
+    from jax_llama_tpu.ops.flash_attention import latent_flash_attention
 
-    fn = jax.jit(lambda q, k, v, qp, kp: flash_attention_lse(
-        q, k, v, qp, kp, interpret=False))
-    kv = sds((1, keys, 32, 192), jnp.bfloat16)
-    _assert_mosaic(fn.lower(
-        sds((1, queries, 32, 192), jnp.bfloat16), kv, kv,
-        sds((1, queries), jnp.int32), sds((1, keys), jnp.int32),
-    ))
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    cache = dict(
+        ctx=sds((8, 1, view, 640), bf16), ctx_pos=sds((1, view), i32),
+        layer=sds((), i32), ctx_tiles=sds((), i32)) if view else {}
+    fn = jax.jit(functools.partial(latent_flash_attention, interpret=False))
+    lowered = fn.lower(
+        sds((1, queries, 32, 128), bf16), sds((1, queries, 32, 64), bf16),
+        sds((1, keys, 640), bf16), sds((32, 512, 256), bf16),
+        sds((1, queries), i32), sds((1, keys), i32), **cache)
+    _assert_mosaic(lowered)
+    # the latent rows are operands of the kernel itself: no slice of the
+    # cache, no K/V of the heads' width in HBM
+    assert f"{max(view, keys)},32" not in lowered.as_text()
 
 
 def test_latent_paged_decode_compiles_for_v5e(sds):
@@ -248,9 +260,9 @@ def test_grouped_expert_matmul_compiles_for_v5e(sds, m, k, n, groups):
 
 def test_latent_tiled_prefill_compiles_for_v5e(sds, monkeypatch):
     """`mla_moe.attend_tiled` at kanana-2-30b-a3b's widths and the benchmark
-    cell's view (16,384 slots, a 2048-token chunk, 8 layers): the flash
-    kernel with a row log-sum-exp inside a loop whose trip count is a value,
-    and no operand of the view's width times the heads in the program."""
+    cell's view (16,384 slots, a 2048-token chunk, 8 layers): one latent
+    flash kernel whose live-tile count is a value, and no operand of the
+    view's width times the heads in the program."""
     from jax_llama_tpu import config as config_mod
     from jax_llama_tpu.models import mla_moe
     from jax_llama_tpu.models.llama import KVCache

@@ -152,16 +152,52 @@ def _context(cfg, params, index, seed):
     return cache, toks[:, index:], pos[:, index:]
 
 
-@pytest.mark.parametrize("index,pad", [
-    (0, 0), (TILE - 1, 0), (TILE, 0), (TILE + TILE // 2, 3), (VIEW - CHUNK, 0), (VIEW - CHUNK, 3),
-], ids=["empty", "tile-1", "one-tile", "mid-second-tile-padded-tail", "whole-view", "whole-view-padded-tail"])
-def test_tiled_prefill_equals_the_one_piece_form(tiny, monkeypatch, index, pad):
-    """A chunk behind a scalar-index cache: the flash form, which walks the
-    live context by tiles, against the plain XLA form over the whole view —
-    logits of the real rows and the rows written.  A read of a dead slot
-    (1e4) would show; so would a slot attended twice where the last tile is
-    moved back inside the view (`whole-view`: 50 live slots, tiles of 16)."""
-    _, cfg, params = tiny
+def _variant(name, tiny):
+    """(config, params) of the walk's parity cases: the file's tiny block; the
+    same with a value as wide as the whole key (24 = 16 + 8, the widest the
+    XLA form's padded value takes) or narrower than its nope part (8); the `streams` tiny block of tests/test_mhc_mla_moe.py
+    (four residual streams, a low-rank query, YaRN's temperature folded into
+    the query)."""
+    if name == "tiny":
+        return tiny[1], tiny[2]
+    if name == "yarn-streams":
+        import test_mhc_mla_moe as streams
+
+        raw = dict(json.loads(streams.CONFIG_FILE.read_text()), **streams.TINY)
+        bookkeeping = streams.BOOKKEEPING
+    else:
+        raw = dict(json.loads(CONFIG_FILE.read_text()),
+                   **dict(TINY, v_head_dim={"wide-value": 24, "narrow-value": 8}[name]))
+        bookkeeping = BOOKKEEPING
+    cfg = config_mod.from_published(
+        {k: v for k, v in raw.items() if k not in bookkeeping},
+        max_seq_len=128, attn_impl="auto")
+    cfg.validate()
+    return cfg, jlt.init_params(jax.random.PRNGKey(3), cfg)
+
+
+@pytest.mark.parametrize("block,index,pad", [
+    ("tiny", 0, 0), ("tiny", TILE - 1, 0), ("tiny", TILE, 0),
+    ("tiny", TILE + TILE // 2, 3), ("tiny", VIEW - CHUNK, 0), ("tiny", VIEW - CHUNK, 3),
+    ("tiny", 2 * TILE, 3), ("wide-value", 0, 0), ("wide-value", TILE + 3, 0),
+    ("narrow-value", VIEW - CHUNK, 3), ("yarn-streams", 0, 0),
+    ("yarn-streams", TILE + TILE // 2, 3), ("yarn-streams", VIEW - CHUNK, 0),
+], ids=[
+    "empty", "tile-1", "one-tile", "mid-second-tile-padded-tail", "whole-view",
+    "whole-view-padded-tail", "two-tiles-exactly-padded-tail", "wide-value-empty",
+    "wide-value-second-tile", "narrow-value-whole-view-padded-tail", "yarn-streams-empty",
+    "yarn-streams-mid-second-tile-padded-tail", "yarn-streams-whole-view",
+])
+def test_tiled_prefill_equals_the_one_piece_form(tiny, monkeypatch, block, index, pad):
+    """A chunk behind a scalar-index cache: the flash form, one kernel that
+    walks the live context by tiles and then the chunk's own rows, against
+    the plain XLA form (float32 softmax) over the whole view — logits of the
+    real rows and the rows written.  A read of a dead slot (1e4) would show;
+    so would a slot past the view, where the last tile hangs over it
+    (`whole-view`: 50 live slots, tiles of 16, a view of 60), a value that
+    did not keep its own width, or a temperature left out of one of the
+    score's two products."""
+    cfg, params = _variant(block, tiny)
     monkeypatch.setattr(mla_moe, "CTX_TILE", TILE)
     cache, toks, pos = _context(cfg, params, index, seed=index)
     real = jnp.arange(CHUNK)[None] < CHUNK - pad
@@ -176,6 +212,47 @@ def test_tiled_prefill_equals_the_one_piece_form(tiny, monkeypatch, index, pad):
     assert np.abs(wrote(got_cache) - wrote(ref_cache)).max() < 1e-4 * np.abs(wrote(ref_cache)).max()
     assert int(got_cache.index) == index + CHUNK
     np.testing.assert_array_equal(np.asarray(got_cache.pos), np.asarray(ref_cache.pos))
+
+
+@pytest.mark.parametrize("index", [0, 32, 50, 96, 112], ids=[
+    "no-context", "one-tile", "mid-second-tile", "three-tiles", "into-the-overhang"])
+def test_latent_kernel_over_blocks_rows_and_diagonals(index):
+    """`latent_flash_attention` itself at blocks the chunk tests do not
+    reach: two rows, 64 queries in two blocks of 32 (so the new rows' own
+    tiles are swept to a causal bound a q block, and the diagonal tiles take
+    the ragged body: 32 / 4 = 8 rows a sub-tile), behind a 112-slot view in
+    tiles of 32 whose last hangs 16 slots over it, at a layer that is a
+    value.  Against the one-piece XLA form with a float32 softmax."""
+    from jax_llama_tpu.ops.attention import attention_bias
+    from jax_llama_tpu.ops.flash_attention import latent_flash_attention
+
+    rng = np.random.RandomState(index)
+    cfg = config_mod.LLaMAConfig(
+        n_heads=2, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=20, attn_softmax_dtype="float32")
+    B, T, H, r, dn, dr, dv, view, layers = 2, 64, 2, 32, 16, 8, 20, 112, 3
+    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)  # noqa: E731
+    q_nope, q_rope, kv_b = f(B, T, H, dn), f(B, T, H, dr), f(H, r, dn + dv) / 6
+    rows = mla_moe._pad_last(f(B, T, r + dr), cfg.cache_width)
+    plane = mla_moe._pad_last(f(layers, B, view, r + dr), cfg.cache_width)
+    live = jnp.arange(view)[None] < index
+    plane = jnp.where(live[None, :, :, None], plane, 1e4)   # dead slots: garbage
+    ctx_pos = jnp.where(live, jnp.arange(view)[None], -1).astype(jnp.int32)
+    ctx_pos = jnp.tile(ctx_pos, (B, 1))
+    q_pos = jnp.tile(index + jnp.arange(T, dtype=jnp.int32)[None], (B, 1))
+    new_pos = q_pos.at[1, T - 5:].set(-1)                   # row 1: a padded tail
+    got = jax.jit(lambda layer, tiles: latent_flash_attention(
+        q_nope, q_rope, rows, kv_b, q_pos, new_pos, ctx=plane, ctx_pos=ctx_pos,
+        layer=layer, ctx_tiles=tiles, block=32, ctx_tile=32))(
+            jnp.int32(1), jnp.int32(-(-index // 32)))
+    seen = jnp.concatenate([plane[1], rows], axis=1)
+    kv_pos = jnp.concatenate([ctx_pos, new_pos], axis=1)
+    ref = mla_moe.attend_decompressed(
+        q_nope, q_rope, seen, kv_b, q_pos, kv_pos,
+        attention_bias(q_pos, kv_pos, kv_pos >= 0), cfg, use_flash=False)
+    real = np.asarray(new_pos >= 0)
+    assert got.shape == (B, T, H, dv)
+    assert np.abs(np.asarray(got - ref))[real].max() < 2e-5
 
 
 def test_tile_rule_is_one_for_the_loop_and_the_counters(monkeypatch):
@@ -207,29 +284,35 @@ def test_nothing_of_the_views_width_is_decompressed(tiny, monkeypatch):
     assert any(view + CHUNK in s for s in wide(cfg.replace(attn_impl="xla")))
 
 
-def test_dense_prefill_program_does_not_see_the_new_flash_entry():
-    """The dense block's prefill lowers `flash_attention` as it did: the new
-    entry (`flash_attention_lse`) is beside it, not under it, and the
-    kernel it lowers to has no log-sum-exp output."""
-    from jax_llama_tpu.ops.flash_attention import flash_attention, flash_attention_lse
+def test_dense_prefill_program_does_not_see_the_latent_flash_entry(tiny):
+    """The dense block's prefill lowers `flash_attention` as it did: the
+    latent walk's kernel (`latent_flash_attention`) is an entry of its own
+    beside it, not a form of it, and only the latent block's programs hold
+    it — one call a layer stack, with no log-sum-exp beside its output."""
+    from jax_llama_tpu.ops.flash_attention import flash_attention, latent_flash_attention
 
     dense = jlt.get_config("tiny", attn_impl="auto")
     dp = jlt.init_params(jax.random.PRNGKey(0), dense)
     toks, pos = _tokens(1, 16)
     text = jax.jit(lambda p, t, q: jlt.forward(p, t, q, dense)[0]).lower(
         dp, toks % dense.vocab_size, pos).as_text()
-    assert "@flash_attention(" in text and "flash_attention_lse" not in text
+    assert "@flash_attention(" in text and "latent_flash_attention" not in text
+    _, cfg, params = tiny
+    text = jax.jit(lambda p, t, q, c: jlt.forward(p, t, q, cfg, cache=c)[0]).lower(
+        params, toks, pos, jlt.init_cache(cfg, 1, 64)).as_text()
+    assert "@latent_flash_attention(" in text and "@flash_attention(" not in text
     q = jnp.zeros((1, 16, 4, 16), jnp.float32)
     p = jnp.tile(jnp.arange(16)[None], (1, 1))
-    n_out = lambda f: len(jax.tree.leaves(jax.eval_shape(f, q, q, q, p, p)))  # noqa: E731
-    assert (n_out(flash_attention), n_out(flash_attention_lse)) == (1, 2)
-    out, lse = flash_attention_lse(q, q, q, p, p)
-    assert lse.shape == (1, 16, 4) and lse.dtype == jnp.float32
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(flash_attention(q, q, q, p, p)), atol=1e-6)
-    # q = k = 0: every score is 0, so a row's log-sum-exp is log(slots it attends)
-    np.testing.assert_allclose(
-        np.asarray(lse[0, :, 0]), np.log(np.arange(1, 17)), rtol=1e-5)
+    n_out = lambda f, *a: len(jax.tree.leaves(jax.eval_shape(f, *a)))  # noqa: E731
+    rows, kv_b = jnp.zeros((1, 16, 128), jnp.float32), jnp.zeros((4, 32, 16 + 8), jnp.float32)
+    assert n_out(flash_attention, q, q, q, p, p) == 1
+    assert n_out(latent_flash_attention, q, q[..., :8], rows, kv_b, p, p) == 1
+    # q = 0 and a value of 1 in every column: every score is 0, and a row's
+    # output is the mean of ones over the slots it attends, whatever their count
+    kv_b = kv_b.at[:, 0, 16:].set(1.0)
+    out = latent_flash_attention(q, q[..., :8], rows.at[..., 0].set(1.0), kv_b, p, p)
+    assert out.shape == (1, 16, 4, 8) and out.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out), 1.0, atol=1e-6)
 
 
 @pytest.mark.parametrize("block", ["latent", "dense"])
